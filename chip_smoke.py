@@ -1,0 +1,704 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that mmlspark_tpu still starts on the chip.
+
+One process, JAX initialised once. Drives the three paths the paper is about
+through their public entry points at real width, each compared with a plain
+reference computed in the same process:
+
+  A. transform — ResNet-50 ONNX through ``ONNXModel`` / ``DataFrame.transform``
+  B. decode    — the 12-layer 768-wide decoder behind ``GenerationEngine``
+                 (HTTP ``/generate``), then int8 KV pages on ``ContinuousDecoder``
+  C. train     — ``LightGBMClassifier`` on a HIGGS-shaped 1M x 28 frame
+
+``--chips 4`` runs instead ONLY the two paths that exist across chips and what
+each is compared with: D. data-parallel GBDT over a 4-device ``data`` mesh,
+E. the paged decoder mounted on a ``dp2 x tp2`` mesh.
+
+No phase's failure is caught: the first failing check raises and the exit code
+is non-zero. Each phase prints one JSON line (seconds are smoke timings of one
+run, set-up/compile apart from the run — never a benchmark result); the LAST
+line of stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}`` as
+JAX reports the device. The script refuses to run unless the platform is
+``tpu``. ``--small`` is the one stated exemption: the same phases at toy sizes
+for a CPU rehearsal in interpret mode, whose device line says ``cpu``.
+"""
+
+import argparse
+import contextlib
+import functools
+import glob
+import json
+import os
+import shutil
+import sys
+import tempfile
+import threading
+import time
+import urllib.request
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, REPO)
+
+AUC_TOL = 0.002          # Pallas vs segment_sum, 4 devices vs 1
+TIE_TOL = 0.1            # a near-tie of two logits, in std-devs of the row
+QUANT_ERR_BOUND = 0.05   # tests/test_kv_quant.py's bound on the int8 probe
+LOGIT_TOL = 0.06         # bf16 ResNet-50 logits vs float32, relative to max|ref|
+
+
+def emit(**fields):
+    print(json.dumps(fields), flush=True)
+
+
+class Checks:
+    """A phase's checks: every one is evaluated, the phase's line lists the
+    ones that failed, and main() then raises on them."""
+
+    def __init__(self):
+        self.failed = []
+
+    def require(self, cond, message):
+        if not cond:
+            self.failed.append(message)
+
+
+def refuse(message):
+    print(f"chip_smoke.py: {message}", file=sys.stderr, flush=True)
+    raise SystemExit(2)
+
+
+class CacheEvents:
+    """Counts JAX's persistent-compilation-cache hits and misses."""
+
+    def __init__(self):
+        self.hits = self.misses = 0
+
+    def __call__(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+
+def drain(decoder, tickets):
+    """Step the decoder until every ticket is done. step() is called here,
+    not serve_forever(): that loop contains a failing tick and carries on."""
+    while not all(t.done for t in tickets):
+        decoder.step()
+
+
+def tick_text(decoder, compiled=False):
+    """Lowered (or compiled) text of the decoder's greedy decode tick, from
+    the arguments its last dispatch left behind."""
+    lowered = decoder._tick.lower(
+        decoder._params, decoder._tok, decoder._pos, decoder._active,
+        decoder._kv.buffers, decoder._bt, decoder._remaining)
+    return lowered.compile().as_text() if compiled else lowered.as_text()
+
+
+# ---------------------------------------------------------------------------
+# sizes: the real ones, and the toy ones of --small
+
+
+def sizes(small):
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.zoo.resnet import RESNET50, ResNetConfig
+    from mmlspark_tpu.models.zoo.transformer import TransformerConfig
+    if small:
+        return dict(
+            resnet=ResNetConfig([1, 1, 1, 1], num_classes=10, width=8),
+            image=32, rows=32, batch=8, ref_rows=4,
+            decoder=TransformerConfig(vocab=256, layers=2, d_model=64,
+                                      heads=4, d_ff=128, max_len=128,
+                                      causal=True, norm="rmsnorm",
+                                      position="rope", dtype=jnp.bfloat16),
+            slots=4, max_len=128, max_new=6,
+            engine_kw=dict(prefill_chunk=16),
+            prompt_lens=[5, 5, 9, 9, 12, 12, 40, 40],
+            gbdt_rows=4096, gbdt_test=1024, gbdt_bins=255, gbdt_iters=5)
+    return dict(
+        resnet=RESNET50, image=224, rows=1024, batch=512, ref_rows=8,
+        # scripts/bench_decode.py's real-width decoder: GPT-2-small-class,
+        # Llama-style (RMSNorm + RoPE), bf16
+        decoder=TransformerConfig(vocab=32000, layers=12, d_model=768,
+                                  heads=12, d_ff=3072, max_len=2048,
+                                  causal=True, norm="rmsnorm",
+                                  position="rope", dtype=jnp.bfloat16),
+        slots=16, max_len=2048, max_new=32, engine_kw={},
+        # one group is longer than the engine's default prefill_chunk (256)
+        prompt_lens=[12, 12, 12, 64, 64, 64, 300, 300],
+        gbdt_rows=1_000_000, gbdt_test=100_000, gbdt_bins=255, gbdt_iters=5)
+
+
+# ---------------------------------------------------------------------------
+# A. transform
+
+
+def phase_transform(sz, seed, small):
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.core import DataFrame
+    from mmlspark_tpu.models.onnx_model import ONNXModel
+    from mmlspark_tpu.models.zoo.resnet import (ResNetConfig,
+                                                export_resnet_onnx,
+                                                init_resnet, resnet_apply)
+    from mmlspark_tpu.ops.compile_cache import M_STEADY_RECOMPILES
+
+    t0 = time.perf_counter()
+    cfg, side, n, batch = sz["resnet"], sz["image"], sz["rows"], sz["batch"]
+    mean, std = [0.485, 0.456, 0.406], [0.229, 0.224, 0.225]
+    model = ONNXModel(export_resnet_onnx(cfg, seed=seed, input_size=side),
+                      feed_dict={"input": "image"},
+                      fetch_dict={"logits": "logits"},
+                      argmax_dict={"pred": "logits"},
+                      transpose_dict={"input": [0, 3, 1, 2]},
+                      normalize_dict={"input": {"scale": 1.0 / 255.0,
+                                                "mean": mean, "std": std}},
+                      mini_batch_size=batch, compute_dtype="bfloat16")
+    images = np.random.default_rng(seed).integers(
+        0, 256, (n, side, side, 3), dtype=np.uint8)
+    col = np.empty(n, dtype=object)
+    for i in range(n):
+        col[i] = images[i]
+    df = DataFrame({"image": col}, npartitions=2)
+    warm = model.warm_up(batch_sizes=[batch],
+                         input_specs={"input": (np.uint8, (side, side, 3))})
+    setup_s = time.perf_counter() - t0
+
+    recompiles_before = M_STEADY_RECOMPILES.labels().get()
+    t0 = time.perf_counter()
+    out = model.transform(df)
+    logits = np.stack([np.asarray(r, np.float32) for r in out["logits"]])
+    pred = np.asarray(out["pred"]).astype(np.int64)
+    run_s = time.perf_counter() - t0
+    recompiles = M_STEADY_RECOMPILES.labels().get() - recompiles_before
+
+    # plain reference: the zoo's own NHWC forward in float32, same weights
+    k = sz["ref_rows"]
+    ref_cfg = ResNetConfig(cfg.stage_sizes, cfg.num_classes, cfg.width,
+                           dtype=jnp.float32)
+    x = (images[:k].astype(np.float32) / 255.0 - np.asarray(mean, np.float32)
+         ) / np.asarray(std, np.float32)
+    t0 = time.perf_counter()
+    with jax.default_matmul_precision("float32"):
+        ref = np.asarray(jax.jit(lambda w, im: resnet_apply(w, im, ref_cfg))(
+            init_resnet(cfg, seed), jnp.asarray(x)))
+    reference_s = time.perf_counter() - t0
+    err = float(np.max(np.abs(logits[:k] - ref)) / np.max(np.abs(ref)))
+    # a top-1 flip counts only where the reference itself separates the two
+    # classes by more than the logit tolerance
+    picked = ref[np.arange(k), pred[:k]]
+    margin = float(np.max(ref.max(axis=1) - picked) / np.max(np.abs(ref)))
+
+    ck = Checks()
+    ck.require(logits.shape == (n, cfg.num_classes), f"logits {logits.shape}")
+    ck.require(np.isfinite(logits).all(), "non-finite logits")
+    ck.require((pred == logits.argmax(axis=1)).all(), "pred != argmax(logits)")
+    ck.require(err < LOGIT_TOL,
+               f"logits off the float32 reference by {err:.4f}")
+    ck.require(margin < LOGIT_TOL, f"top-1 off the reference by {margin:.4f}")
+    ck.require(recompiles == 0, f"{recompiles} steady-state recompiles")
+    return ck, dict(setup_s=setup_s, run_s=run_s, reference_s=reference_s,
+                    rows=n, batch=batch,
+                    partitions=df.npartitions, warm_up=warm,
+                    logits_rel_err=err, top1_margin=margin,
+                    top1_equal=int((pred[:k] == ref.argmax(axis=1)).sum()),
+                    ref_rows=k, steady_state_recompiles=int(recompiles))
+
+
+# ---------------------------------------------------------------------------
+# B. serve / decode
+
+
+def make_prompts(sz, seed):
+    rng = np.random.default_rng(seed + 1)
+    return [rng.integers(1, sz["decoder"].vocab, n).astype(np.int32)
+            for n in sz["prompt_lens"]]
+
+
+def oracle_tokens(params, cfg, prompts, max_new):
+    """``generate_cached`` (the parity oracle), one call per prompt length."""
+    from mmlspark_tpu.models.zoo.transformer import generate_cached
+    want = [None] * len(prompts)
+    for n in sorted({len(p) for p in prompts}):
+        idx = [i for i, p in enumerate(prompts) if len(p) == n]
+        ids = np.asarray(generate_cached(
+            params, np.stack([prompts[i] for i in idx]), cfg,
+            max_new_tokens=max_new))
+        for row, i in enumerate(idx):
+            want[i] = [int(t) for t in ids[row, n:]]
+    return want
+
+
+def first_divergence(got, want):
+    return [next((j for j, (a, b) in enumerate(zip(g, w)) if a != b), None)
+            if g != w else None for g, w in zip(got, want)]
+
+
+@functools.lru_cache(maxsize=None)
+def reference_forward(cfg):
+    """Jitted float32 ``transformer_apply`` for ``cfg``, built once so the
+    engine's and the oracle's tokens share one compile."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.zoo.transformer import transformer_apply
+    f32 = cfg._replace(dtype=jnp.float32)
+    return jax.jit(lambda w, x: transformer_apply(w, x, f32))
+
+
+def reference_gaps(params, cfg, prompts, outputs):
+    """The plain reference for greedy decoding: one float32 causal forward
+    of ``transformer_apply`` over prompt + emitted tokens (teacher forcing).
+    Per request, the largest amount by which an emitted token's logit falls
+    short of the row's best, in standard deviations of the row: 0.0 means
+    every token is the float32 argmax given the same history."""
+    import jax
+    import jax.numpy as jnp
+    width = max(len(p) + len(o) for p, o in zip(prompts, outputs))
+    ids = np.zeros((len(prompts), width), np.int32)
+    for i, (p, o) in enumerate(zip(prompts, outputs)):
+        ids[i, :len(p) + len(o)] = np.concatenate([p, np.asarray(o, np.int32)])
+    with jax.default_matmul_precision("float32"):
+        hidden = reference_forward(cfg)(params, jnp.asarray(ids))
+        gaps = []
+        for i, (p, o) in enumerate(zip(prompts, outputs)):
+            rows = hidden[i, len(p) - 1:len(p) - 1 + len(o)]
+            logits = np.asarray(rows.astype(jnp.float32)
+                                @ jnp.asarray(params["lm_head"]["w"]))
+            short = logits.max(axis=1) - logits[np.arange(len(o)), o]
+            gaps.append(float(np.max(short / logits.std(axis=1))))
+    return gaps
+
+
+def post_generate(url, prompts, max_new):
+    results = [None] * len(prompts)
+
+    def client(i):
+        req = urllib.request.Request(
+            url, data=json.dumps({"tokens": [int(t) for t in prompts[i]],
+                                  "max_new": max_new}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=900.0) as r:
+            results[i] = (r.status, json.loads(r.read()))
+
+    threads = [threading.Thread(target=client, args=(i,))
+               for i in range(len(prompts))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return results
+
+
+def phase_decode(sz, seed, small):
+    import jax
+
+    from mmlspark_tpu.models.zoo.transformer import init_transformer
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    from mmlspark_tpu.serving.generation import GenerationEngine
+
+    ck = Checks()
+    t0 = time.perf_counter()
+    cfg, max_new = sz["decoder"], sz["max_new"]
+    params = jax.device_put(init_transformer(cfg, seed=seed))
+    prompts = make_prompts(sz, seed)
+    want = oracle_tokens(params, cfg, prompts, max_new)
+    oracle_s = time.perf_counter() - t0
+
+    # every choice of implementation left at its default (paged_attn unset)
+    t0 = time.perf_counter()
+    engine = GenerationEngine(params, cfg, max_slots=sz["slots"],
+                              max_len=sz["max_len"], reply_timeout=900.0,
+                              **sz["engine_kw"])
+    with engine:
+        # set-up: one short round over the same prompt lengths compiles the
+        # prefill buckets and the tick
+        post_generate(engine.address, prompts, 2)
+        setup_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        replies = post_generate(engine.address, prompts, max_new)
+        run_s = time.perf_counter() - t0
+        decoder = engine.decoder
+        impl = decoder._attn_impl
+        kernel_compiled = "tpu_custom_call" in tick_text(decoder)
+        ticks = dict(kernel=decoder._kv.stats.get("attn_ticks_kernel", 0),
+                     gather=decoder._kv.stats.get("attn_ticks_gather", 0))
+    if not all(r is not None and r[0] == 200 for r in replies):
+        raise RuntimeError(f"HTTP replies: {[r and r[0] for r in replies]}")
+    got = [r[1]["tokens"] for r in replies]
+    # bf16 rounds the engine's chunked prefill and the oracle's token-by-token
+    # prefill differently, so two near-tied logits may swap: a request may
+    # leave generate_cached's tokens only at such a tie, which the float32
+    # reference decides — every token of BOTH must be its argmax or within
+    # TIE_TOL of it
+    gaps = reference_gaps(params, cfg, prompts, got)
+    oracle_gaps = reference_gaps(params, cfg, prompts, want)
+    ck.require(impl == "kernel" and ticks["kernel"] > 0
+               and ticks["gather"] == 0,
+               f"default decoder did not run the paged kernel: {impl} {ticks}")
+    ck.require(kernel_compiled or small, "no tpu_custom_call in the lowered "
+               "tick: the kernel ran interpreted")
+    ck.require(all(len(g) == max_new for g in got), "short replies")
+    ck.require(max(gaps) <= TIE_TOL and max(oracle_gaps) <= TIE_TOL,
+               "tokens leave the float32 reference by more than a tie: "
+               f"engine {gaps}, generate_cached {oracle_gaps}; first index "
+               f"differing from generate_cached {first_divergence(got, want)}")
+
+    # int8 KV pages: the engine has no kv_dtype argument, so drive the
+    # decoder directly; the quant-error probe is the check
+    t0 = time.perf_counter()
+    quant = ContinuousDecoder(params, cfg, max_slots=sz["slots"],
+                              max_len=sz["max_len"], kv_dtype="int8",
+                              quant_probe=1, **sz["engine_kw"])
+    reqs = [quant.submit(p, max_new_tokens=max_new) for p in prompts]
+    drain(quant, reqs)
+    int8_s = time.perf_counter() - t0
+    stats = quant._kv.stats
+    q_compiled = "tpu_custom_call" in tick_text(quant)
+    q_got = [[int(t) for t in r.tokens] for r in reqs]
+    quant.stop()
+    ck.require(stats["quant_error_probes"] >= 1, "the quant probe never ran")
+    ck.require(0.0 < (stats["quant_error_last"] or 0.0) < QUANT_ERR_BOUND
+               and stats["quant_error_max"] < QUANT_ERR_BOUND,
+               f"int8 quant error {stats['quant_error_last']} "
+               f"(max {stats['quant_error_max']})")
+    ck.require(quant._attn_impl == "kernel" and (q_compiled or small),
+               "int8 decoder did not run the compiled paged kernel")
+    ck.require(all(len(g) == max_new for g in q_got), "short int8 outputs")
+    return ck, dict(
+        setup_s=setup_s, run_s=run_s, oracle_s=oracle_s, int8_s=int8_s,
+        requests=len(prompts), max_new=max_new,
+        prompt_lens=sz["prompt_lens"], slots=sz["slots"], paged_attn=impl,
+        attn_ticks=ticks, kernel_compiled=kernel_compiled,
+        tokens_equal_oracle=sum(g == w for g, w in zip(got, want)),
+        first_divergence=first_divergence(got, want),
+        reference_gap_max=max(gaps), oracle_reference_gap_max=max(oracle_gaps),
+        int8_kernel_compiled=q_compiled,
+        int8_quant_error_last=stats["quant_error_last"],
+        int8_quant_error_max=stats["quant_error_max"],
+        int8_quant_probes=stats["quant_error_probes"],
+        int8_tokens_equal_bf16=sum(g == w for g, w in zip(q_got, got)))
+
+
+# ---------------------------------------------------------------------------
+# C. train
+
+
+def make_higgs_like(n, seed, f=28):
+    """HIGGS-shaped synthetic frame (scripts/bench_gbdt_higgs.py's recipe)."""
+    rng = np.random.default_rng(seed)
+    y = (rng.random(n) < 0.53).astype(np.float64)
+    X = rng.normal(0, 1, (n, f)).astype(np.float32)
+    X[y == 1] += (0.3 * rng.normal(1, 0.2, f)).astype(np.float32)
+    return X, y
+
+
+def higgs_split(sz, seed):
+    """((X, y) to fit on, (X, y) held out)."""
+    X, y = make_higgs_like(sz["gbdt_rows"] + sz["gbdt_test"], seed)
+    n = sz["gbdt_rows"]
+    return (X[:n], y[:n]), (X[n:], y[n:])
+
+
+class HistogramCalls:
+    """Counts trace-time calls of the Pallas histogram builder and records
+    whether each was asked for interpret mode. A fit whose tree builder is
+    already in jit's cache traces nothing, so wrap the first fit too."""
+
+    def __init__(self):
+        from mmlspark_tpu.ops import pallas_kernels
+        self._mod = pallas_kernels
+        self._orig = pallas_kernels.level_histogram_pallas
+        self.calls, self.interpreted = 0, 0
+
+    def __enter__(self):
+        def counted(*a, **kw):
+            self.calls += 1
+            self.interpreted += bool(kw.get("interpret", False))
+            return self._orig(*a, **kw)
+        self._mod.level_histogram_pallas = counted
+        return self
+
+    def __exit__(self, *exc):
+        self._mod.level_histogram_pallas = self._orig
+        return False
+
+
+@contextlib.contextmanager
+def env_var(name, value):
+    prev = os.environ.get(name)
+    if value is None:
+        os.environ.pop(name, None)
+    else:
+        os.environ[name] = value
+    try:
+        yield
+    finally:
+        if prev is None:
+            os.environ.pop(name, None)
+        else:
+            os.environ[name] = prev
+
+
+def fit_gbdt(sz, train, test, iterations=None, **estimator_kw):
+    """(auc on the held-out frame, model string, fit seconds)."""
+    from mmlspark_tpu.core import DataFrame
+    from mmlspark_tpu.models.gbdt.estimators import LightGBMClassifier
+    from mmlspark_tpu.train.metrics import roc_auc
+    (X, y), (Xt, yt) = train, test
+    est = LightGBMClassifier(num_iterations=iterations or sz["gbdt_iters"],
+                             max_bin=sz["gbdt_bins"], seed=0, **estimator_kw)
+    t0 = time.perf_counter()
+    model = est.fit(DataFrame({"features": X, "label": y}))
+    fit_s = time.perf_counter() - t0
+    out = model.transform(DataFrame({"features": Xt, "label": yt}))
+    prob = np.asarray([p[1] for p in out["probability"]], np.float64)
+    return roc_auc(yt, prob), model.booster.to_string(), fit_s
+
+
+def phase_train(sz, seed, small):
+    import jax
+
+    from mmlspark_tpu import native
+    ck = Checks()
+    ck.require(native.available(),
+               f"native fast path unavailable: {native.build_error()}")
+    train, test = higgs_split(sz, seed)
+
+    # the chip run leaves MMLSPARK_TPU_PALLAS unset (refused otherwise in
+    # main); the CPU rehearsal forces the kernel on, in interpret mode
+    with env_var("MMLSPARK_TPU_PALLAS", "1" if small else None), \
+            HistogramCalls() as calls:
+        # set-up: a one-iteration fit compiles the step; train() jits a new
+        # closure per call, so the real fit finds it in the persistent cache
+        _, _, setup_s = fit_gbdt(sz, train, test, iterations=1)
+        auc_pallas, _, fit_s = fit_gbdt(sz, train, test)
+    jax.clear_caches()   # the builder choice is read at trace time
+    with env_var("MMLSPARK_TPU_PALLAS", "0"), HistogramCalls() as ref_calls:
+        auc_ref, _, ref_s = fit_gbdt(sz, train, test)
+    ck.require(calls.calls > 0,
+               "the Pallas histogram builder was never called")
+    ck.require(small or calls.interpreted == 0,
+               "the Pallas histogram ran in interpret mode")
+    ck.require(ref_calls.calls == 0,
+               "the reference fit used the Pallas builder")
+    ck.require(0.5 < auc_pallas <= 1.0, f"held-out AUC {auc_pallas}")
+    ck.require(abs(auc_pallas - auc_ref) <= AUC_TOL,
+               f"AUC {auc_pallas:.5f} (Pallas) vs {auc_ref:.5f} (segment_sum)")
+    return ck, dict(setup_s=setup_s, run_s=fit_s, reference_fit_s=ref_s,
+                    rows=len(train[0]), features=train[0].shape[1],
+                    bins=sz["gbdt_bins"],
+                    iterations=sz["gbdt_iters"], held_out=sz["gbdt_test"],
+                    auc_pallas=auc_pallas, auc_segment_sum=auc_ref,
+                    pallas_histogram_traces=calls.calls,
+                    pallas_interpreted=calls.interpreted)
+
+
+# ---------------------------------------------------------------------------
+# --chips 4: D. data-parallel GBDT, E. mesh-mounted decode
+
+
+def dumped_collectives(dump_dir, module_pattern):
+    """Collectives in the optimised HLO XLA dumped for modules matching
+    ``module_pattern`` (the compiled text of the program that really ran)."""
+    from mmlspark_tpu.parallel.collective_audit import count_collectives
+    found = {}
+    for path in sorted(glob.glob(os.path.join(
+            dump_dir, f"*{module_pattern}*after_optimizations.txt"))):
+        with open(path) as fh:
+            for kind, row in count_collectives(fh.read()).items():
+                found[kind] = found.get(kind, 0) + row["ops"]
+    return found
+
+
+def phase_gbdt_mesh(sz, seed, small, devices, dump_dir):
+    from mmlspark_tpu.parallel.mesh import MeshContext
+    ck = Checks()
+    train, test = higgs_split(sz, seed)
+    with env_var("MMLSPARK_TPU_PALLAS", "1" if small else None):
+        auc_1, trees_1, fit_1 = fit_gbdt(sz, train, test)
+        with MeshContext({"data": 4}, devices=devices), \
+                HistogramCalls() as calls:
+            auc_4, trees_4, fit_4 = fit_gbdt(
+                sz, train, test, parallelism="data_parallel")
+    collectives = dumped_collectives(dump_dir, "fused_step")
+    ck.require(calls.calls > 0, "the data-parallel fit never built a "
+               "histogram with the Pallas kernel")
+    ck.require(small or calls.interpreted == 0,
+               "the Pallas histogram ran in interpret mode")
+    ck.require(collectives.get("all-reduce", 0) > 0,
+               "no all-reduce in the compiled data-parallel step: "
+               f"{collectives}")
+    # the psum adds four partial histograms in another order than one device
+    # adds its rows, so near-tied splits may differ; AUC is the criterion
+    ck.require(abs(auc_4 - auc_1) <= AUC_TOL,
+               f"AUC {auc_4:.5f} on 4 devices vs {auc_1:.5f} on one")
+    return ck, dict(setup_s=0.0, run_s=fit_4, one_device_fit_s=fit_1,
+                    auc_4_devices=auc_4, auc_1_device=auc_1,
+                    same_trees=trees_4 == trees_1, collectives=collectives,
+                    pallas_histogram_traces=calls.calls)
+
+
+def phase_decode_mesh(sz, seed, small, devices):
+    from jax.sharding import Mesh
+
+    from mmlspark_tpu.models.zoo.transformer import init_transformer
+    from mmlspark_tpu.parallel.collective_audit import count_collectives
+    from mmlspark_tpu.parallel.mesh import mesh_shape
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+
+    ck = Checks()
+    cfg, max_new = sz["decoder"], sz["max_new"]
+    params = init_transformer(cfg, seed=seed)
+    prompts = make_prompts(sz, seed)
+    mesh = Mesh(np.array(devices).reshape(2, 2), ("dp", "tp"))
+
+    def run(m):
+        t0 = time.perf_counter()
+        dec = ContinuousDecoder(params, cfg, max_slots=sz["slots"],
+                                max_len=sz["max_len"], mesh=m,
+                                **sz["engine_kw"])
+        reqs = [dec.submit(p, max_new_tokens=max_new) for p in prompts]
+        drain(dec, reqs)
+        seconds = time.perf_counter() - t0
+        toks = [[int(t) for t in r.tokens] for r in reqs]
+        text = tick_text(dec, compiled=True)
+        impl = dec._attn_impl
+        dec.stop()
+        return toks, seconds, text, impl
+
+    want, one_s, _, _ = run(None)
+    got, mesh_s, text, impl = run(mesh)
+    collectives = {k: v["ops"] for k, v in count_collectives(text).items()}
+    # the tp all-reduce sums partial products in another order than one
+    # device does, so near-tied logits may swap: as in phase B, the float32
+    # reference decides whether a differing token is such a tie
+    gaps = reference_gaps(params, cfg, prompts, got)
+    one_gaps = reference_gaps(params, cfg, prompts, want)
+    ck.require(impl == "kernel" and ("tpu_custom_call" in text or small),
+               "the mesh decoder did not run the compiled paged kernel")
+    ck.require(collectives.get("all-reduce", 0) > 0,
+               f"no all-reduce in the compiled mesh tick: {collectives}")
+    ck.require(all(len(g) == max_new for g in got), "short outputs")
+    ck.require(max(gaps) <= TIE_TOL and max(one_gaps) <= TIE_TOL,
+               "tokens leave the float32 reference by more than a tie: "
+               f"mesh {gaps}, single device {one_gaps}; first index "
+               f"differing {first_divergence(got, want)}")
+    return ck, dict(setup_s=0.0, run_s=mesh_s, one_device_s=one_s,
+                    mesh=mesh_shape(mesh), requests=len(prompts),
+                    max_new=max_new, paged_attn=impl, collectives=collectives,
+                    kernel_compiled="tpu_custom_call" in text,
+                    tokens_equal_single_device=sum(
+                        g == w for g, w in zip(got, want)),
+                    first_divergence=first_divergence(got, want),
+                    reference_gap_max=max(gaps),
+                    single_device_reference_gap_max=max(one_gaps))
+
+
+# ---------------------------------------------------------------------------
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--small", action="store_true",
+                    help="toy sizes for a CPU rehearsal in interpret mode; "
+                         "never a chip result")
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the two cross-chip paths (D, E)")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--phase", action="append", choices=list("ABCDE"),
+                    help="run only this phase (repeatable; for fault-finding)")
+    args = ap.parse_args(argv)
+
+    if os.environ.get("MMLSPARK_TPU_FORCE_PLATFORM"):
+        refuse("MMLSPARK_TPU_FORCE_PLATFORM is set; the smoke reads the "
+               "device JAX really has")
+    if not args.small and os.environ.get("MMLSPARK_TPU_PALLAS"):
+        refuse("MMLSPARK_TPU_PALLAS is set; the smoke checks the default "
+               "builder choice")
+    dump_dir = None
+    if args.chips == 4:
+        # the compiled text of the data-parallel step, as XLA itself dumps it
+        dump_dir = tempfile.mkdtemp(prefix="chip_smoke_hlo_")
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "") + f" --xla_dump_to={dump_dir}"
+            " --xla_dump_hlo_as_text --xla_dump_hlo_module_re=.*fused_step.*"
+        ).strip()
+
+    t_start = time.perf_counter()
+    import jax
+    devices = jax.devices()
+    dev = devices[0]
+    if not args.small:
+        if dev.platform != "tpu":
+            refuse(f"needs a TPU; JAX found platform {dev.platform!r} "
+                   f"({dev.device_kind}). --small rehearses on the CPU.")
+        import bench
+        bench.peak_flops(dev.device_kind)   # an unknown kind is an error
+    if len(devices) < args.chips:
+        refuse(f"--chips {args.chips} needs {args.chips} devices, JAX has "
+               f"{len(devices)}")
+
+    from mmlspark_tpu.ops.compile_cache import enable_persistent_cache
+    cache_dir = enable_persistent_cache()
+    if dump_dir:
+        # XLA dumps a program's text only when it compiles it
+        jax.config.update("jax_enable_compilation_cache", False)
+        cache_dir = None
+    events = CacheEvents()
+    jax.monitoring.register_event_listener(events)
+
+    from mmlspark_tpu import native
+    # read before anything loads the fast path: False means it is built from
+    # fastpath.cpp in this run, as in a checkout of what git commits
+    native_so_preexisting = os.path.exists(native._SO)
+    sz = sizes(args.small)
+    if args.chips == 4:
+        four = devices[:4]
+        phases = {"D": ("D.gbdt_data_parallel", lambda: phase_gbdt_mesh(
+                      sz, args.seed, args.small, four, dump_dir)),
+                  "E": ("E.decode_mesh", lambda: phase_decode_mesh(
+                      sz, args.seed, args.small, four))}
+    else:
+        phases = {"A": ("A.transform", lambda: phase_transform(
+                      sz, args.seed, args.small)),
+                  "B": ("B.decode", lambda: phase_decode(
+                      sz, args.seed, args.small)),
+                  "C": ("C.train", lambda: phase_train(
+                      sz, args.seed, args.small))}
+    for key, (name, run) in phases.items():
+        if args.phase and key not in args.phase:
+            continue
+        hits, misses = events.hits, events.misses
+        t0 = time.perf_counter()
+        checks, detail = run()
+        stats = [d.memory_stats() or {} for d in devices[:args.chips]]
+        emit(phase=name, ok=not checks.failed, failed=checks.failed,
+             small=args.small,
+             platform=dev.platform, device_kind=dev.device_kind,
+             wall_s=time.perf_counter() - t0,
+             cache_dir=cache_dir, cache_hits=events.hits - hits,
+             cache_misses=events.misses - misses,
+             native_available=native.available(),
+             native_so_preexisting=native_so_preexisting,
+             hbm_peak_bytes=[s.get("peak_bytes_in_use") for s in stats],
+             hbm_bytes_in_use=[s.get("bytes_in_use") for s in stats],
+             **detail)
+        if checks.failed:
+            raise RuntimeError(f"chip_smoke phase {name} failed: "
+                               + "; ".join(checks.failed))
+    if dump_dir:
+        shutil.rmtree(dump_dir, ignore_errors=True)
+    emit(total_s=time.perf_counter() - t_start, cache_dir=cache_dir,
+         cache_hits=events.hits, cache_misses=events.misses)
+    emit(ok=True, device={"platform": dev.platform, "kind": dev.device_kind,
+                          "count": len(devices)})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

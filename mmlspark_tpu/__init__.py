@@ -14,11 +14,10 @@ import os as _os
 from .core import (DataFrame, Estimator, Model, Pipeline, PipelineModel,
                    PipelineStage, Transformer, concat)
 
-if _os.environ.get("MMLSPARK_TPU_COMPILE_CACHE") \
-        or _os.environ.get("MMLSPARK_TPU_COMPILE_CACHE_DIR"):
-    # opt-in persistent compilation cache: compiled executables survive
-    # across processes (repeat jobs skip the multi-second XLA warmup)
-    from .utils.jit_cache import enable_persistent_cache as _epc
+if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    # the cache is placed from outside and JAX reads the variable itself;
+    # this only zeroes its size/time gates so small programs are kept too
+    from .ops.compile_cache import enable_persistent_cache as _epc
     _epc()
 
 __all__ = ["DataFrame", "concat", "PipelineStage", "Transformer", "Estimator",

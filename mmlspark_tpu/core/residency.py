@@ -1,7 +1,8 @@
 """Device residency — columns that *live on device* across pipeline stages.
 
-BENCH_r04 measured the gap this module closes: 11,529 img/s device-resident
-vs 268 img/s host-fed on a v5e, with h2d crawling at 0.098 GB/s. The
+The gap this module closes is the one between the device-resident and the
+host-fed rate of one model (an earlier round's record, deleted in PR 23, had
+them 43x apart on a v5e). The
 reference stack's L3 mini-batch layer shuttles every stage through host
 memory; the compiled-region literature (Julia-to-TPU arXiv:1810.09868, TVM
 arXiv:1802.04799) shows the win is keeping tensors resident across the whole

@@ -484,14 +484,21 @@ def run_scenario(scenario: Scenario, cluster, *,
 
     # quiesce, then heartbeat every worker so the driver's federated
     # counters all describe the same instant — the exact-reconciliation
-    # contract the federation tests pin down
-    time.sleep(0.25)
-    for w in cluster.workers:
-        try:
-            w.heartbeat()
-        except Exception:
-            pass
-    after = counters_snapshot()
+    # contract the federation tests pin down. A straggler counted while
+    # the sweep runs (late replies on a starved host) would give the
+    # workers different instants, so the sweep repeats, bounded, until no
+    # request was counted while it ran.
+    for _ in range(20):
+        time.sleep(0.25)
+        settled = counters_snapshot()
+        for w in cluster.workers:
+            try:
+                w.heartbeat()
+            except Exception:
+                pass
+        after = counters_snapshot()
+        if after.get("serving_requests") == settled.get("serving_requests"):
+            break
     cluster_view: Optional[dict] = None
     merged = None
     debug = _fetch_json(cluster.driver.url.rstrip("/") + "/debug/cluster")

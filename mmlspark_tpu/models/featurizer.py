@@ -67,7 +67,7 @@ class ImageFeaturizer(Model, HasInputCol, HasOutputCol):
         the graph (the inner ONNXModel's transpose/normalize prep) — a
         uint8 image crosses the host→device link at 1/4 the bytes of the
         float32 tensor this method used to build, and the link is the
-        bottleneck (BASELINE.md: config #4 was transfer-bound)."""
+        bottleneck."""
         if cell is None:
             return None
         if isinstance(cell, (bytes, bytearray)):

@@ -413,9 +413,8 @@ class ONNXModel(Model):
         if device is None:
             # normalize to the concrete default device so pinned and
             # unpinned callers share one cached weight copy
-            devs = local_devices()
-            device = devs[0] if devs else None
-        key = id(device) if device is not None else None
+            device = local_devices()[0]
+        key = id(device)
         with self._params_lock:
             if key not in self._device_params:
                 cm = self._ensure_converted()
@@ -544,7 +543,7 @@ class ONNXModel(Model):
         every placement real traffic can hit (each pinned chip, or the
         default mesh), so neither bench nor serving eats a compile stall
         mid-stream — and, with the persistent compilation cache enabled
-        (``MMLSPARK_TPU_COMPILE_CACHE_DIR``), neither does the *next*
+        (``JAX_COMPILATION_CACHE_DIR``), neither does the *next*
         process.
 
         ``batch_sizes`` defaults to ``[mini_batch_size]``; pass the expected

@@ -3,12 +3,14 @@
 ``fastpath.cpp`` is compiled on demand with the system C++ toolchain into a
 CPython extension (no pybind11 needed). If compilation is unavailable the
 same API is served by numpy/pure-Python implementations, so the package has
-no hard native dependency — mirroring the reference's NativeLoader pattern
-(``core/.../core/env/NativeLoader.java``) of shipping a loadable native
-payload behind a stable interface.
+no hard native dependency — but never in silence: :func:`build_error` says
+why, and ``chip_smoke.py`` fails on it. Mirrors the reference's NativeLoader
+pattern (``core/.../core/env/NativeLoader.java``) of shipping a loadable
+native payload behind a stable interface.
 
 API:
     available() -> bool
+    build_error() -> str | None     (why available() is False)
     murmur3(data: bytes, seed: int) -> int
     murmur3_batch(seq_of_bytes, seed, mask) -> np.uint32[n]
     pad_sparse(rows, K) -> (np.int32[n,K], np.float32[n,K])
@@ -25,40 +27,47 @@ import sysconfig
 
 import numpy as np
 
-__all__ = ["available", "bin_columns", "murmur3", "murmur3_batch",
-           "pad_sparse", "parse_libsvm", "stack_rows"]
+__all__ = ["available", "bin_columns", "build_error", "murmur3",
+           "murmur3_batch", "pad_sparse", "parse_libsvm", "stack_rows"]
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "fastpath.cpp")
 _SO = os.path.join(_HERE, f"_fastpath{sysconfig.get_config_var('EXT_SUFFIX')}")
 
 _impl = None
+_build_error = None
 
 
 def _compile() -> bool:
-    """Build the extension in place; returns success."""
+    """Build the extension in place; returns success. A failure's reason
+    (the compiler's own words) is kept for :func:`build_error`."""
+    global _build_error
+    include_py = sysconfig.get_paths()["include"]
+    include_np = np.get_include()
+    # build to a unique temp name, then atomically publish: concurrent
+    # importers on a shared filesystem never see a half-written .so
+    tmp = f"{_SO}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
+           f"-I{include_py}", f"-I{include_np}", _SRC, "-o", tmp]
     try:
-        include_py = sysconfig.get_paths()["include"]
-        include_np = np.get_include()
-        # build to a unique temp name, then atomically publish: concurrent
-        # importers on a shared filesystem never see a half-written .so
-        tmp = f"{_SO}.{os.getpid()}.tmp"
-        cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-               f"-I{include_py}", f"-I{include_np}", _SRC, "-o", tmp]
         res = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-        if res.returncode != 0 or not os.path.exists(tmp):
-            return False
-        os.replace(tmp, _SO)
-        return True
-    except Exception:
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _build_error = f"{type(e).__name__}: {e}"
         return False
+    if res.returncode != 0 or not os.path.exists(tmp):
+        _build_error = (f"g++ exited {res.returncode}: "
+                        f"{res.stderr.strip()[-2000:]}")
+        return False
+    os.replace(tmp, _SO)
+    return True
 
 
 def _load():
-    global _impl
+    global _impl, _build_error
     if _impl is not None:
         return _impl
     if os.environ.get("MMLSPARK_TPU_NO_NATIVE") == "1":
+        _build_error = "disabled by MMLSPARK_TPU_NO_NATIVE=1"
         _impl = False
         return _impl
     # a shipped .so without the source is fine — only rebuild when the
@@ -66,14 +75,20 @@ def _load():
     usable = os.path.exists(_SO) and (
         not os.path.exists(_SRC)
         or os.path.getmtime(_SO) >= os.path.getmtime(_SRC))
-    if not usable and not (os.path.exists(_SRC) and _compile()):
-        _impl = False
-        return _impl
+    if not usable:
+        if not os.path.exists(_SRC):
+            _build_error = f"neither {_SO} nor {_SRC} exists"
+            _impl = False
+            return _impl
+        if not _compile():
+            _impl = False
+            return _impl
     try:
         sys.path.insert(0, _HERE)
         import _fastpath  # noqa
         _impl = _fastpath
-    except Exception:
+    except ImportError as e:
+        _build_error = f"import of {_SO} failed: {e}"
         _impl = False
     finally:
         if _HERE in sys.path:
@@ -83,6 +98,13 @@ def _load():
 
 def available() -> bool:
     return bool(_load())
+
+
+def build_error():
+    """Why :func:`available` is False (the compiler's stderr, a missing
+    source, the opt-out knob), or None when the fast path loaded."""
+    _load()
+    return _build_error
 
 
 # -- dispatching wrappers ----------------------------------------------------

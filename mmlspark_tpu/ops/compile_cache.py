@@ -5,10 +5,10 @@ that compile lands — multi-second for real graphs — on whatever request is
 unlucky enough to arrive first in each padding bucket. This module removes the
 stall from both ends:
 
-* **Persistent compilation cache** — :func:`enable_persistent_cache` wires
-  JAX's on-disk executable cache (env ``MMLSPARK_TPU_COMPILE_CACHE_DIR``), so
-  a process restart deserializes yesterday's executables instead of
-  recompiling them. TVM (arxiv 1802.04799) and ONNX-MLIR (arxiv 2008.08272)
+* **Persistent compilation cache** — :func:`enable_persistent_cache` turns on
+  JAX's on-disk executable cache (placed by ``JAX_COMPILATION_CACHE_DIR``,
+  else one fixed directory inside the checkout), so a process restart
+  deserializes yesterday's executables instead of recompiling them. TVM (arxiv 1802.04799) and ONNX-MLIR (arxiv 2008.08272)
   both land on the same conclusion: once the graph is static, inference
   performance is decided at the compile-cache and host↔device boundary.
 * **AOT warm-up** — :func:`warm_up_jitted` drives a jitted program through
@@ -39,11 +39,18 @@ from ..observability import watch as _watch
 from .padding import bucket_size
 
 __all__ = ["enable_persistent_cache", "persistent_cache_dir", "StageCounters",
-           "jit_cache_size", "warm_up_jitted", "warm_up_model",
+           "jit_cache_size", "jitted", "warm_up_jitted", "warm_up_model",
            "resolve_input_specs"]
 
-#: environment variable naming the persistent compilation cache directory
-CACHE_DIR_ENV = "MMLSPARK_TPU_COMPILE_CACHE_DIR"
+#: JAX's own variable: where it is set, the cache lives there and this
+#: module sets no directory in code
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: where the cache lives otherwise — one fixed path inside the checkout (the
+#: path is part of the cache key, so a directory that moves never hits)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 # Registry mirrors (docs/observability.md has the catalog). Stage counters
 # stay per-model objects for snapshot parity with the reference; every
@@ -77,60 +84,64 @@ M_WARMUP_SECONDS = _metric_counter(
     "mmlspark_compile_cache_warmup_seconds_total",
     "Wall-clock spent in AOT warm-up")
 
-_cache_lock = new_lock("ops.compile_cache._cache_lock")
-_cache_dir: Optional[str] = None
+def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-
-def enable_persistent_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
-
-    Resolution order: explicit argument → ``MMLSPARK_TPU_COMPILE_CACHE_DIR``
-    → legacy ``MMLSPARK_TPU_COMPILE_CACHE`` (the package-import knob in
-    :mod:`mmlspark_tpu.utils.jit_cache`, which now delegates here) →
-    ``JAX_COMPILATION_CACHE_DIR`` (which JAX honors on its own; we only
-    record it). Returns the active directory, or ``None`` when no directory
-    is configured anywhere. Idempotent and thread-safe; the min-compile-time
-    and min-entry-size gates are zeroed so small graphs (unit-test MLPs,
-    per-bucket variants of one model) are cached too — the default 1 s gate
-    would silently skip exactly the programs serving warm-up cares about.
+    The directory is placed from outside: where ``JAX_COMPILATION_CACHE_DIR``
+    is set, JAX has already read it and this function writes no directory
+    into ``jax.config`` (``cache_dir`` is ignored). Where it is not set, the
+    cache goes to ``cache_dir``, default :data:`DEFAULT_CACHE_DIR` — never a
+    path built from a temporary name, a pid or the time. Idempotent; the
+    min-compile-time and min-entry-size gates are zeroed so small graphs
+    (unit-test MLPs, per-bucket variants of one model) are cached too — the
+    default 1 s gate would silently skip exactly the programs serving
+    warm-up cares about.
     """
-    global _cache_dir
-    with _cache_lock:
-        path = (cache_dir or os.environ.get(CACHE_DIR_ENV)
-                or os.environ.get("MMLSPARK_TPU_COMPILE_CACHE")
-                or os.environ.get("JAX_COMPILATION_CACHE_DIR"))
-        if not path:
-            return None
-        if _cache_dir == path:
-            return _cache_dir
-        os.makedirs(path, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        for knob, val in [("jax_persistent_cache_min_compile_time_secs", 0.0),
-                          ("jax_persistent_cache_min_entry_size_bytes", 0)]:
-            try:
-                jax.config.update(knob, val)
-            except Exception:
-                pass  # knob renamed/absent on this jax version
-        _cache_dir = path
-        return _cache_dir
+    import jax
+    path = os.environ.get(CACHE_DIR_ENV)
+    if not path:
+        path = cache_dir or DEFAULT_CACHE_DIR
+        if jax.config.jax_compilation_cache_dir != path:
+            os.makedirs(path, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
 
 
 def persistent_cache_dir() -> Optional[str]:
-    """The directory wired by :func:`enable_persistent_cache`, if any."""
-    return _cache_dir
+    """The directory JAX's persistent compilation cache writes to, if any
+    (``jax.config`` is the single source of truth)."""
+    import jax
+    return jax.config.jax_compilation_cache_dir or None
 
 
 def jit_cache_size(jitted) -> Optional[int]:
-    """Entries in a jitted callable's in-process executable cache.
-
-    ``None`` when the introspection hook is unavailable (older/newer jax) —
-    callers must treat that as "unknown", not zero.
+    """Entries in a jitted callable's in-process executable cache; ``None``
+    for a callable that is not a ``jax.jit`` product — callers must treat
+    that as "unknown", not zero.
     """
-    try:
-        return int(jitted._cache_size())
-    except Exception:
-        return None
+    size = getattr(jitted, "_cache_size", None)
+    return int(size()) if size is not None else None
+
+
+_JITTED: Dict[str, Callable] = {}
+
+
+def jitted(name: str, fn: Callable,
+           static_argnums: Optional[Tuple[int, ...]] = None) -> Callable:
+    """Return a jitted version of ``fn`` cached process-wide under ``name``.
+
+    Per-call ``@jax.jit`` closures create a fresh function object every
+    invocation, so jax's jit cache never hits and every transform
+    recompiles; stages register their kernels here once, keyed by a stable
+    name. The first caller's ``fn`` wins — callers must pass a pure function
+    whose behavior is fully determined by its arguments (+ static args)."""
+    if name not in _JITTED:
+        import jax
+        _JITTED[name] = (jax.jit(fn, static_argnums=static_argnums)
+                         if static_argnums is not None else jax.jit(fn))
+    return _JITTED[name]
 
 
 class StageCounters:
@@ -256,8 +267,9 @@ def warm_up_jitted(jitted, params, specs: Dict[str, Tuple[np.dtype, tuple]],
     That single throwaway execution is what populates jax's in-process jit
     cache — a bare ``lower().compile()`` produces an executable but leaves
     the cache cold, so the first real batch would still pay tracing +
-    compile. With :func:`enable_persistent_cache` active the compile also
-    lands on disk for the next process.
+    compile. With the persistent cache on (:func:`enable_persistent_cache`,
+    or ``JAX_COMPILATION_CACHE_DIR``) the compile also lands on disk for the
+    next process.
 
     ``buckets`` is the runner's padding ladder (``None`` = power-of-two):
     warm-up derives each padded size through the *same* ladder, so it
@@ -279,7 +291,6 @@ def warm_up_jitted(jitted, params, specs: Dict[str, Tuple[np.dtype, tuple]],
     # lazy: ops must stay importable without pulling the parallel package
     from ..parallel import collective_audit as _collective_audit
 
-    enable_persistent_cache()
     if put is None:
         put = jax.device_put
     ladder = None if not buckets else tuple(sorted({int(b)

@@ -55,33 +55,21 @@ _QMAX_FP8 = 448.0
 
 
 def supports_fp8() -> bool:
-    """Whether this jax build can hold and convert ``float8_e4m3fn``
-    arrays (gates ``kv_dtype="fp8"`` — no new deps, just a probe)."""
-    if not hasattr(jnp, "float8_e4m3fn"):
-        return False
-    try:
-        jnp.zeros((1,), jnp.float8_e4m3fn).astype(jnp.float32)
-        return True
-    except Exception:
-        return False
+    """Whether ``kv_dtype="fp8"`` is available: the installed jax has
+    ``float8_e4m3fn``."""
+    return True
 
 
 def resolve_kv_dtype(kv_dtype) -> Optional[str]:
     """Canonicalize a ``kv_dtype`` knob value to ``"int8"``, ``"fp8"``
-    or None (bf16 pages). Raises on unknown names and on ``"fp8"`` when
-    the platform lacks ``float8_e4m3fn``."""
+    or None (bf16 pages). Raises on unknown names."""
     key = kv_dtype
     if isinstance(key, str):
         key = key.strip().lower()
     if key not in _CANON:
         raise ValueError(
             f"unknown kv_dtype {kv_dtype!r} (choose 'bf16', 'int8' or 'fp8')")
-    canon = _CANON[key]
-    if canon == "fp8" and not supports_fp8():
-        raise ValueError(
-            "kv_dtype='fp8' needs jax.numpy.float8_e4m3fn, which this "
-            "platform build lacks — use kv_dtype='int8'")
-    return canon
+    return _CANON[key]
 
 
 def kv_store_dtype(kv_dtype: Optional[str]):
@@ -102,7 +90,7 @@ def kv_qmax(dtype) -> float:
     d = jnp.dtype(dtype)
     if d == jnp.dtype(jnp.int8):
         return _QMAX_INT8
-    if hasattr(jnp, "float8_e4m3fn") and d == jnp.dtype(jnp.float8_e4m3fn):
+    if d == jnp.dtype(jnp.float8_e4m3fn):
         return _QMAX_FP8
     raise ValueError(f"not a quantized KV store dtype: {dtype!r}")
 

@@ -501,14 +501,23 @@ def _grid_spec(n_scalar, B, n_pages, in_specs, out_specs, H, Wp, hd):
         ])
 
 
+#: scoped-VMEM ceiling handed to Mosaic. The compiler's default (16 MiB on
+#: v5e) is below what a chunked-prefill window needs: the in-window score
+#: block is (H, W, W) f32, and at W = prefill_chunk = 256, 12 heads, the int8
+#: fused kernel's stack is 24.1 MiB (refused on the chip and by the described
+#: compile in tests/test_chip_compile.py). A v5e core has 128 MiB of VMEM.
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
 def _compiler_params(interpret: bool):
     if interpret:
         return None
     from jax.experimental.pallas import tpu as pltpu
     # both grid dims carry loop state (online-softmax accumulators and
     # the write-on-index-change page outputs) — never parallelizable
-    return pltpu.TPUCompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))

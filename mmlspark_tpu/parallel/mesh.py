@@ -25,33 +25,18 @@ __all__ = ["local_devices", "device_for_partition", "make_mesh",
 
 
 def local_devices():
-    """Process-local devices, degrading instead of crashing.
-
-    Backend init can fail transiently (e.g. the TPU plugin is briefly
-    unavailable); the reference's device pinning is best-effort too
-    (``ONNXModel.scala:293-303`` falls through when no GPU resource is
-    present). Order: default backend → explicit CPU backend → [].
-    """
-    try:
-        return jax.local_devices()
-    except Exception:
-        pass
-    try:
-        return jax.devices("cpu")
-    except Exception:
-        return []
+    """Process-local devices of the default backend. A backend that fails
+    to come up raises — CPU devices are never handed back in its place."""
+    return jax.local_devices()
 
 
 def device_for_partition(partition_index: int):
     """Pin a data partition to a process-local chip, round-robin.
 
     TPU-native stand-in for ``TaskContext.resources("gpu")`` pinning
-    (``ONNXModel.scala:293-303``). Returns ``None`` (= default placement)
-    when no backend is reachable, so callers degrade rather than crash.
+    (``ONNXModel.scala:293-303``).
     """
     devs = local_devices()
-    if not devs:
-        return None
     return devs[partition_index % len(devs)]
 
 
@@ -180,15 +165,7 @@ class MeshContext:
 
 
 def get_shard_map():
-    """The supported shard_map entry point across jax versions (new
-    ``jax.shard_map`` with ``check_vma``, else the experimental one with
-    ``check_rep``). Returns (shard_map_fn, uncheck_kwargs) where
-    ``uncheck_kwargs`` disables the replication/vma check for bodies with
-    per-shard control flow."""
-    import jax
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, {"check_vma": False}
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy, {"check_rep": False}
+    """``jax.shard_map`` plus the kwargs that disable its varying-axes
+    check, for bodies with per-shard control flow. Returns
+    (shard_map_fn, uncheck_kwargs)."""
+    return jax.shard_map, {"check_vma": False}

@@ -19,7 +19,7 @@ from ..core.pipeline import Estimator, Model
 
 __all__ = ["SAR", "SARModel"]
 
-from ..utils.jit_cache import jitted as _jitted
+from ..ops.compile_cache import jitted as _jitted
 
 
 class SAR(Estimator):

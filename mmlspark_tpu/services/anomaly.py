@@ -113,7 +113,7 @@ class SimpleDetectAnomalies(AnomalyBase):
                   .with_column(self.get("error_col"), errs))
 
     def _local_transform(self, df: DataFrame) -> DataFrame:
-        from ..utils.jit_cache import jitted
+        from ..ops.compile_cache import jitted
 
         def mad_z(v):
             import jax.numpy as jnp

@@ -33,7 +33,7 @@ __all__ = ["TUNING_DIR_ENV", "Observation", "ObservationStore", "get_store",
            "harvest_collectives"]
 
 #: environment variable naming the persisted-observation directory (the
-#: tuning analogue of ``MMLSPARK_TPU_COMPILE_CACHE_DIR``)
+#: tuning analogue of ``JAX_COMPILATION_CACHE_DIR``)
 TUNING_DIR_ENV = "MMLSPARK_TPU_TUNING_DIR"
 
 STORE_FILENAME = "observations.jsonl"

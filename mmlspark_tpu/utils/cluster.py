@@ -32,9 +32,8 @@ def process_index() -> int:
 
 def local_devices() -> List:
     """Chips attached to this host (reference: tasks-per-executor,
-    ``ClusterUtil.getNumTasksPerExecutor:20``). Shares the degrading
-    implementation in ``parallel.mesh`` — backend-init failure must never
-    crash callers."""
+    ``ClusterUtil.getNumTasksPerExecutor:20``). Shares the implementation
+    in ``parallel.mesh``: a backend that fails to come up raises."""
     from ..parallel.mesh import local_devices as _ld
     return _ld()
 
@@ -74,7 +73,7 @@ def device_for_partition(part_index: int):
 
     Replaces the reference's GPU pinning from task resources
     (``ONNXModel.scala:293-303`` — ``selectGpuDevice(TaskContext.resources)``).
-    Shares the degrading implementation in ``parallel.mesh``.
+    Shares the implementation in ``parallel.mesh``.
     """
     from ..parallel.mesh import device_for_partition as _dfp
     return _dfp(part_index)

@@ -75,13 +75,8 @@ def _make_pass_fn(loss: str, quantile_tau: float, n_passes: int,
         if axis is not None:
             # entering shard_map replicated; updates indexed by sharded rows
             # make the carry device-varying, so mark it varying up front
-            pvary = getattr(jax.lax, "pvary", None)
-            if pvary is not None:
-                w = pvary(w, (axis,))
-                G = pvary(G, (axis,))
-            else:
-                w = jax.lax.pcast(w, (axis,), to="varying")
-                G = jax.lax.pcast(G, (axis,), to="varying")
+            w = jax.lax.pcast(w, (axis,), to="varying")
+            G = jax.lax.pcast(G, (axis,), to="varying")
 
         def minibatch_step(carry, xs):
             w, G, t = carry
@@ -114,9 +109,7 @@ def _make_pass_fn(loss: str, quantile_tau: float, n_passes: int,
                                         (idx, val, y, sw))
             if axis is not None:
                 w = jax.lax.pmean(w, axis)   # per-pass AllReduce (VW parity)
-                pvary = getattr(jax.lax, "pvary", None)
-                w = (pvary(w, (axis,)) if pvary is not None
-                     else jax.lax.pcast(w, (axis,), to="varying"))
+                w = jax.lax.pcast(w, (axis,), to="varying")
             return (w, G, t), None
 
         (w, G, _), _ = jax.lax.scan(one_pass, (w, G, 0.0), None,

@@ -134,8 +134,10 @@ FOOTER = """\
     # doctest file against the INSTALLED package (the executable slice of
     # the reference's packagePython/testPython, CodegenPlugin.scala:55-67)
     script: bash scripts/test_packaging.sh
-  - name: bench-smoke
-    script: BENCH_ROWS=64 BENCH_BATCH=32 python bench.py
+  - name: chip-smoke-rehearsal
+    # every chip_smoke.py phase at toy sizes on the CPU in interpret mode;
+    # bench.py and the full chip_smoke.py need a TPU and exit non-zero here
+    script: python chip_smoke.py --small
   - name: scenario-smoke
     # the smoke scenario open-loop against an in-process 3-worker
     # cluster: seeded arrivals, CPU-only, wall-clock-bounded by the

@@ -1,7 +1,7 @@
 """Micro-op timing for the GBDT iteration's device ops.
 
 Times each candidate hot op standalone at HIGGS-like scale so the
-per-iteration cost model (BASELINE.md, VERDICT r4 weak #1) is grounded in
+per-iteration cost model is grounded in
 measured per-op numbers instead of the summed-kernel guess:
 
   * level histogram (Pallas kernel) per level at several node counts

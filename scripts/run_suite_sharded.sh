@@ -1,7 +1,7 @@
 #!/bin/bash
 # Full test suite, one pytest process per test file, with one automatic
 # retry when a shard dies on the environment's XLA-CPU-compiler SEGFAULT
-# (see VERDICT_RESPONSE.md: nondeterministic native crashes in
+# (nondeterministic native crashes in
 # backend_compile_and_load on an otherwise idle host; not repo code — a
 # monolithic run loses ~an hour per crash, a shard loses one file).
 #

@@ -1,6 +1,6 @@
 """Open-loop, rate-controlled HTTP load driver — run as its OWN process.
 
-Round-3 lesson (BASELINE.md): thread-burst clients co-located in the server
+Thread-burst clients co-located in the server
 process measure the client as much as the server. This driver (a) lives in a
 separate process so the server's GIL is not shared, and (b) is open-loop:
 each connection sends on a fixed schedule (target_rate/connections per

@@ -9,6 +9,7 @@ last batch) under prefetch; ``StageCounters`` account the pipeline stages;
 warm-up hook before draining traffic.
 """
 
+import os
 import threading
 import time
 
@@ -105,37 +106,86 @@ class TestStageCounters:
 def cache_config_guard():
     """Restore the persistent-cache wiring after a test mutates it."""
     import jax
-    prev_dir = cc._cache_dir
-    prev_cfg = jax.config.jax_compilation_cache_dir
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_entry_size_bytes,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
     yield
-    cc._cache_dir = prev_dir
-    jax.config.update("jax_compilation_cache_dir", prev_cfg)
+    jax.config.update("jax_compilation_cache_dir", prev[0])
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", prev[1])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", prev[2])
+
+
+@pytest.fixture
+def config_writes(monkeypatch):
+    """Names the program writes through ``jax.config.update``."""
+    import jax
+    written = []
+    real = jax.config.update
+
+    def spy(name, value):
+        written.append(name)
+        return real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    return written
 
 
 class TestPersistentCache:
-    def test_explicit_dir(self, tmp_path, cache_config_guard):
+    def test_explicit_dir(self, tmp_path, monkeypatch, cache_config_guard):
         import jax
+        monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
         d = str(tmp_path / "xla-cache")
         assert enable_persistent_cache(d) == d
         assert cc.persistent_cache_dir() == d
         assert jax.config.jax_compilation_cache_dir == d
+        assert os.path.isdir(d)
         # idempotent re-enable
         assert enable_persistent_cache(d) == d
 
-    def test_env_var_resolution(self, tmp_path, monkeypatch,
-                                cache_config_guard):
-        d = str(tmp_path / "from-env")
-        monkeypatch.setenv(cc.CACHE_DIR_ENV, d)
-        cc._cache_dir = None
-        assert enable_persistent_cache() == d
-        import os
-        assert os.path.isdir(d)
-
-    def test_no_dir_configured(self, monkeypatch, cache_config_guard):
+    def test_default_is_one_fixed_path_in_the_checkout(
+            self, monkeypatch, cache_config_guard, config_writes):
+        import jax
         monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        cc._cache_dir = None
-        assert enable_persistent_cache() is None
+        monkeypatch.setattr(os, "makedirs", lambda *a, **kw: None)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        assert enable_persistent_cache() == cc.DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == cc.DEFAULT_CACHE_DIR
+        assert "jax_compilation_cache_dir" in config_writes
+
+    def test_jax_variable_places_the_cache(self, tmp_path, monkeypatch,
+                                           cache_config_guard,
+                                           config_writes):
+        """Where JAX_COMPILATION_CACHE_DIR is set the program uses it and
+        sets no directory in code — not even an explicit argument."""
+        d = str(tmp_path / "from-outside")
+        monkeypatch.setenv(cc.CACHE_DIR_ENV, d)
+        assert enable_persistent_cache() == d
+        assert enable_persistent_cache(str(tmp_path / "ignored")) == d
+        assert "jax_compilation_cache_dir" not in config_writes
+        assert not os.path.exists(str(tmp_path / "ignored"))
+        # the size/time gates are still zeroed so small programs are kept
+        assert "jax_persistent_cache_min_entry_size_bytes" in config_writes
+
+    @pytest.mark.parametrize("name", ["MMLSPARK_TPU_COMPILE_CACHE_DIR",
+                                      "MMLSPARK_TPU_COMPILE_CACHE"])
+    def test_private_names_are_gone(self, name, tmp_path, monkeypatch,
+                                    cache_config_guard):
+        monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+        monkeypatch.setenv(name, str(tmp_path / "private"))
+        monkeypatch.setattr(os, "makedirs", lambda *a, **kw: None)
+        assert enable_persistent_cache() == cc.DEFAULT_CACHE_DIR
+        assert not os.path.exists(str(tmp_path / "private"))
+
+    def test_warm_up_leaves_the_cache_alone(self, monkeypatch,
+                                            cache_config_guard,
+                                            config_writes):
+        """warm_up compiles; it does not decide where (or whether) the
+        persistent cache lives — the entry points do."""
+        monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+        m, _ = mlp_onnx_model(mini_batch_size=4)
+        m.warm_up(batch_sizes=[4])
+        assert "jax_compilation_cache_dir" not in config_writes
 
 
 class TestResolveInputSpecs:

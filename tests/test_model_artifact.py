@@ -104,15 +104,14 @@ class TestSaveLoad:
                 np.stack([np.asarray(df[c]) for c in
                           ("f0", "f1", "f2", "f3", "label")]))
         code = (
-            "import os, sys, numpy as np\n"
-            "os.environ.pop('JAX_PLATFORMS', None)\n"
-            "import jax; jax.config.update('jax_platforms', 'cpu')\n"
+            "import sys, numpy as np\n"
             "from mmlspark_tpu.mlflow import load_model\n"
             f"cols = np.load({str(tmp_path / 'inputs.npy')!r})\n"
             "data = dict(zip(('f0','f1','f2','f3','label'), cols))\n"
             f"out = load_model({p!r}).predict(data)\n"
             "np.save(sys.argv[1], np.asarray(out['prediction']))\n")
         outp = str(tmp_path / "pred.npy")
+        # JAX_PLATFORMS=cpu is inherited from conftest's os.environ
         env = {**os.environ, "PYTHONPATH": REPO}
         r = subprocess.run([sys.executable, "-c", code, outp],
                            capture_output=True, text=True, env=env,

@@ -291,3 +291,48 @@ def test_libsvm_negative_index_rejected_both_parsers():
             nat.parse_libsvm(b"1 -1:2.0\n")
     finally:
         nat._impl = prev
+
+
+class TestBuildError:
+    """A fast path that could not be built says why (PR 23): the pure-Python
+    path still serves the API, but never in silence."""
+
+    @pytest.fixture
+    def fresh_loader(self, monkeypatch, tmp_path):
+        """The loader as a checkout finds it: no built ``.so`` on disk."""
+        monkeypatch.setattr(native, "_impl", None)
+        monkeypatch.setattr(native, "_build_error", None)
+        monkeypatch.setattr(native, "_SO", str(tmp_path / "_fastpath_t.so"))
+        monkeypatch.delenv("MMLSPARK_TPU_NO_NATIVE", raising=False)
+        return tmp_path
+
+    def test_none_when_loaded(self):
+        assert native.available()
+        assert native.build_error() is None
+
+    def test_compiler_stderr_is_reported(self, fresh_loader, monkeypatch):
+        bad = fresh_loader / "broken.cpp"
+        bad.write_text("this is not C++\n")
+        monkeypatch.setattr(native, "_SRC", str(bad))
+        assert native.available() is False
+        err = native.build_error()
+        assert err.startswith("g++ exited") and "error" in err
+
+    def test_missing_compiler_is_reported(self, fresh_loader, monkeypatch):
+        def no_gxx(*a, **kw):
+            raise FileNotFoundError("g++")
+        monkeypatch.setattr(native.subprocess, "run", no_gxx)
+        assert native.available() is False
+        assert "FileNotFoundError" in native.build_error()
+
+    def test_opt_out_is_reported(self, fresh_loader, monkeypatch):
+        monkeypatch.setenv("MMLSPARK_TPU_NO_NATIVE", "1")
+        assert native.available() is False
+        assert "MMLSPARK_TPU_NO_NATIVE" in native.build_error()
+
+    def test_builds_from_source_with_the_so_absent(self, fresh_loader):
+        # _SO points at a path that does not exist yet: the loader must
+        # compile fastpath.cpp there, as in a fresh checkout
+        assert not native.os.path.exists(native._SO)
+        assert native._compile() is True
+        assert native.os.path.exists(native._SO)

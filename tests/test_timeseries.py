@@ -514,7 +514,7 @@ def test_chaos_timeline_and_queue_saturation_alert_e2e(monkeypatch):
     # queue depth (3 x 4) far below sender concurrency: guaranteed backlog
     cluster = ServingCluster(3, reply_timeout=5.0, max_queue=4)
     stop = threading.Event()
-    echo = cluster_echo_engine(cluster, stop, service_s=0.04, batch=4)
+    echo = cluster_echo_engine(cluster, stop, service_s=0.08, batch=4)
     try:
         card = run_scenario(scenario, cluster, senders=32)
         # quiesce: traffic over, echo engine still draining; the global
