@@ -35,6 +35,7 @@ from typing import Callable, Dict, Iterable, Iterator, List, Optional, Sequence,
 import numpy as np
 
 from ..observability.tracing import propagate as _propagate
+from ..observability.tracing import span as _span
 from .residency import DeviceColumn, HostMirror, is_device_array, record_hit
 
 __all__ = ["DataFrame", "concat", "object_col"]
@@ -488,23 +489,28 @@ class DataFrame:
         if max_workers is None:
             max_workers = int(os.environ.get("MMLSPARK_TPU_PARTITION_THREADS", "0")) \
                 or min(len(parts), 8)
+        def task(p, i):
+            with _span("partition", pidx=i, rows=len(p)):
+                return fn(p, i)
+
         if len(parts) <= 1 or max_workers <= 1 \
                 or getattr(_IN_POOL, "active", False):
-            results = [fn(p, i) for i, p in enumerate(parts)]
+            results = [task(p, i) for i, p in enumerate(parts)]
         else:
             def wrapped(p, i):
                 _IN_POOL.active = True
                 try:
-                    return fn(p, i)
+                    return task(p, i)
                 finally:
                     _IN_POOL.active = False
             ex = _shared_pool(max_workers)
             # pool workers are long-lived and start with an empty context:
-            # re-install the caller's (active trace span, SpanTracer) around
-            # each partition call so spans recorded there stay attributable
+            # re-install the caller's active trace span around each
+            # partition call so spans recorded there stay attributable
             results = list(ex.map(_propagate(wrapped), parts,
                                   range(len(parts))))
-        out = concat(results, npartitions=self._npartitions)
+        with _span("frame.concat", parts=len(results)):
+            out = concat(results, npartitions=self._npartitions)
         # per-partition result sizes become the output boundaries, so uneven
         # splits (parquet row groups) survive a map_partitions round
         if len(results) > 1:

@@ -18,6 +18,7 @@ import logging
 import time
 from typing import List, Optional, Sequence
 
+from ..observability.tracing import span as _span
 from .dataframe import DataFrame
 from .params import ComplexParam, Params
 
@@ -62,8 +63,7 @@ class Transformer(PipelineStage):
     def transform(self, df: DataFrame, params: Optional[dict] = None) -> DataFrame:
         stage = self.copy(params) if params else self
         t0 = time.perf_counter()
-        from ..utils.profiling import span
-        with span(f"{type(stage).__name__}.transform"):
+        with _span(f"{type(stage).__name__}.transform"):
             out = stage._transform(df)
         _log_event(stage, "transform", rows=len(df),
                    millis=round(1e3 * (time.perf_counter() - t0), 3))
@@ -120,8 +120,7 @@ class Estimator(PipelineStage):
     def fit(self, df: DataFrame, params: Optional[dict] = None) -> "Model":
         est = self.copy(params) if params else self
         t0 = time.perf_counter()
-        from ..utils.profiling import span
-        with span(f"{type(est).__name__}.fit"):
+        with _span(f"{type(est).__name__}.fit"):
             model = est._fit(df)
         _log_event(est, "fit", rows=len(df),
                    millis=round(1e3 * (time.perf_counter() - t0), 3))

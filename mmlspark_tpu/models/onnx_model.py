@@ -32,6 +32,7 @@ from ..onnx.convert import ConvertedModel, convert_model
 from ..ops.compile_cache import (StageCounters, resolve_input_specs,
                                  warm_up_model)
 from ..core.residency import DeviceColumn
+from ..observability import tracing as _tracing
 from ..parallel.mesh import feed_placement, local_devices
 from .runner import BatchRunner, StagingSlabPool
 
@@ -522,15 +523,18 @@ class ONNXModel(Model):
         pending = runner.run_and_drain(len(part))
 
         out = part
-        for col_name in self._out_col_names:
-            chunks = [outs[col_name][:b] for outs, b in pending]
-            arr = np.concatenate(chunks) if chunks \
-                else np.zeros((0,), dtype=np.float32)
-            if arr.dtype == jnp.bfloat16:
-                arr = arr.astype(np.float32)
-            if col_name in self._argmax_cols:
-                arr = arr.astype(np.int64)
-            out = out.with_column(col_name, arr)
+        # slice off the padding, join the batches, widen bf16: host work on
+        # the partition's thread while the device has nothing of its own
+        with _tracing.span("onnx.collect", batches=len(pending)):
+            for col_name in self._out_col_names:
+                chunks = [outs[col_name][:b] for outs, b in pending]
+                arr = np.concatenate(chunks) if chunks \
+                    else np.zeros((0,), dtype=np.float32)
+                if arr.dtype == jnp.bfloat16:
+                    arr = arr.astype(np.float32)
+                if col_name in self._argmax_cols:
+                    arr = arr.astype(np.int64)
+                out = out.with_column(col_name, arr)
         return out
 
     # -- AOT warm-up ---------------------------------------------------------

@@ -45,7 +45,6 @@ from ..ops.compile_cache import (M_CACHE_HITS, M_CACHE_MISSES,
                                  jit_cache_size)
 from ..ops.padding import bucket_size, pad_axis, pad_axis_device
 from ..stages.batching import PrefetchIterator, batch_slices
-from ..utils.profiling import span as _span
 
 __all__ = ["BatchRunner", "StagingSlabPool"]
 
@@ -215,10 +214,10 @@ class BatchRunner:
                  ) -> Tuple[Dict[str, np.ndarray], int, int, float]:
         c = self.counters
         t_prep = time.perf_counter()
-        with c.timer("coerce"), _span("runner.coerce"):
+        with c.timer("coerce", span="runner.coerce"):
             feeds = self.coerce(sl)
         b = 0
-        with c.timer("pad"), _span("runner.pad"):
+        with c.timer("pad", span="runner.pad"):
             padded_feeds = {}
             padded = 0
             for name, arr in feeds.items():
@@ -247,8 +246,8 @@ class BatchRunner:
             # batch k+1's coerce/pad overlaps batch k's h2d + dispatch; the
             # depth bound caps host memory at that many prepared batches.
             # The worker thread starts with an empty context — propagate()
-            # carries the active trace + installed SpanTracer across, so
-            # coerce/pad spans land in the request's trace
+            # carries the active trace across, so coerce/pad spans land in
+            # the request's trace
             prepare = _tracing.propagate(self._prepare)
             return PrefetchIterator((prepare(sl) for sl in slices),
                                     depth=self.prefetch_depth)
@@ -266,7 +265,7 @@ class BatchRunner:
         if self.tuning == "auto" and not self._tuned:
             self._resolve_auto(n_rows)
         pending: List[Tuple[dict, int]] = []
-        with _span("runner.run", rows=n_rows):
+        with _tracing.span("runner.run", rows=n_rows):
             batches = self._prepared_batches(n_rows)
             # prefetch_wait: time the dispatch thread blocks on the coerce/
             # pad worker — zero when host prep fully overlaps device work;
@@ -274,13 +273,19 @@ class BatchRunner:
             prefetching = isinstance(batches, PrefetchIterator)
             it = iter(batches)
             while True:
-                t0 = time.perf_counter()
-                try:
-                    feeds_host, b, padded, prep_s = next(it)
-                except StopIteration:
+                # the span is around every next(); the counter counts the
+                # waits that brought a batch (not the exhausted one), and
+                # only a worker's: inline, the wait IS coerce + pad, which
+                # have their own counters
+                with _tracing.span("runner.next"):
+                    t0 = time.perf_counter()
+                    prepared = next(it, None)
+                    waited = time.perf_counter() - t0
+                if prepared is None:
                     break
                 if prefetching:
-                    c.add("prefetch_wait", time.perf_counter() - t0)
+                    c.add("prefetch_wait", waited)
+                feeds_host, b, padded, prep_s = prepared
                 device_fed = [k for k, v in feeds_host.items()
                               if is_device_array(v)]
                 if device_fed:
@@ -291,36 +296,40 @@ class BatchRunner:
                 # feed bytes to the ambient trace's workload class
                 _ledger_charge("padding_waste_rows", padded - b)
                 _ledger_charge("h2d_bytes", nbytes)
-                with c.timer("h2d", nbytes):
+                with c.timer("h2d", nbytes, span="runner.h2d", bytes=nbytes,
+                             resident=len(device_fed)):
                     # put() is placement-aware; for an already-resident feed
                     # it is a same-device no-op (or an on-chip move), never
                     # a host round-trip
                     feeds = {k: self.put(v) for k, v in feeds_host.items()}
                 before = jit_cache_size(self.jitted)
-                t0 = time.perf_counter()
-                outs = self.jitted(self.params, feeds)
-                elapsed = time.perf_counter() - t0
+                with _tracing.span("runner.dispatch", padded=padded) as sp:
+                    t0 = time.perf_counter()
+                    outs = self.jitted(self.params, feeds)
+                    elapsed = time.perf_counter() - t0
                 after = jit_cache_size(self.jitted)
-                if before is not None and after is not None \
-                        and after > before:
+                compiled = (after - before if before is not None
+                            and after is not None else 0)
+                if sp is not None:
+                    sp.set(compiled=compiled)
+                if compiled > 0:
                     # the dispatch call blocked on trace+compile — a bucket
                     # the warm-up vocabulary missed; attribute the stall
                     # honestly
-                    c.add("compile", elapsed, count=after - before)
+                    c.add("compile", elapsed, count=compiled)
                     _ledger_charge("compile_seconds", elapsed)
-                    M_CACHE_MISSES.inc(after - before)
-                    M_STEADY_RECOMPILES.inc(after - before)
-                    _tracing.add_event("cache_miss", compiles=after - before,
+                    M_CACHE_MISSES.inc(compiled)
+                    M_STEADY_RECOMPILES.inc(compiled)
+                    _tracing.add_event("cache_miss", compiles=compiled,
                                        seconds=elapsed)
                     self._note_sample(padded, b, batches=1,
                                       prep_seconds=prep_s,
                                       compile_seconds=elapsed,
-                                      compiles=after - before)
+                                      compiles=compiled)
                 else:
                     c.add("dispatch", elapsed)
                     _ledger_charge("device_seconds", elapsed)
                     M_CACHE_HITS.inc()
-                    _tracing.add_event("cache_hit")
                     self._note_sample(padded, b, batches=1, seconds=elapsed,
                                       prep_seconds=prep_s)
                 if self.staging is not None:
@@ -357,7 +366,8 @@ class BatchRunner:
         t0 = time.perf_counter()
         # device_get is where a wedged device parks the dispatcher forever
         # — the watchdog turns that silent hang into a diagnostic bundle
-        with _span("runner.d2h", batches=len(pending)), _watch("runner_drain"):
+        with _tracing.span("runner.d2h", batches=len(pending)), \
+                _watch("runner_drain"):
             host = jax.device_get([outs for outs, _ in pending])
         elapsed = time.perf_counter() - t0
         nbytes = sum(a.nbytes for outs in host for a in outs.values())

@@ -1,8 +1,8 @@
 """Process-global metrics registry: Counter, Gauge, Histogram with labels.
 
 The unified telemetry substrate for the whole package — `StageCounters`
-(ops/compile_cache.py), `_PhaseProf` (models/gbdt/train.py) and
-`SpanTracer` (utils/profiling.py) all mirror into it, and the serving
+(ops/compile_cache.py) and `_PhaseProf` (models/gbdt/train.py) mirror into
+it, and the serving
 plane scrapes it at ``GET /metrics`` (see serving/server.py). Design
 constraints, in order:
 

@@ -15,8 +15,13 @@ Design constraints, matching the rest of observability/:
 - **contextvars, not threading.local** — the serving plane hops threads
   constantly (transport → dispatcher → prefetch worker → partition pool);
   a context is captured once with :func:`propagate` and re-installed in the
-  worker, so spans opened there land in the right trace. The same
-  ContextVar carries the installed ``SpanTracer`` (utils/profiling.py).
+  worker, so spans opened there land in the right trace.
+- **one span primitive** — :func:`span` is what library code calls at a
+  layer boundary: a ``jax.profiler.TraceAnnotation`` (so the span is on the
+  profiler's timeline beside the device's operations) and a child span of
+  the active request trace when there is one. (A stopgap rides on it: one
+  row of :func:`span_log`, until the benchmark's captures record host
+  events.)
 - **cheap when idle** — with no active trace, ``start_span`` returns an
   inert context manager and ``add_event`` is a dict lookup + None check;
   traces are only ever minted explicitly (:func:`start_trace`).
@@ -60,9 +65,7 @@ __all__ = [
     "current_span",
     "current_trace_id",
     "current_request_id",
-    "install_tracer",
-    "uninstall_tracer",
-    "installed_tracer",
+    "span",
     "set_exemplars",
     "exemplars_enabled",
     "get_flight_recorder",
@@ -75,14 +78,11 @@ __all__ = [
 MAX_SPANS_PER_TRACE = 512
 MAX_EVENTS_PER_SPAN = 64
 
-#: The active span (one per logical request flow) and the installed
-#: SpanTracer. contextvars so that ``contextvars.copy_context()`` captures
-#: both in one shot for propagate(), and nested activations unwind
-#: correctly on the same thread.
+#: The active span (one per logical request flow). A ContextVar so that
+#: ``contextvars.copy_context()`` captures it for propagate(), and nested
+#: activations unwind correctly on the same thread.
 _SPAN: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
     "mmlspark_active_span", default=None)
-_TRACER: "contextvars.ContextVar[Optional[object]]" = contextvars.ContextVar(
-    "mmlspark_span_tracer", default=None)
 
 
 # -- id minting (THE place request/trace/span ids come from: tpulint TPU008
@@ -183,6 +183,11 @@ class Span:
                 "name": name, "ts": time.time(),
                 **({"fields": fields} if fields else {})})
 
+    def set(self, **attrs: object) -> None:
+        """Add attributes to a span that is still open or already closed."""
+        with self.trace._lock:
+            self.attrs.update(attrs)
+
     def end(self, **attrs: object) -> bool:
         """Close the span; False when it was already closed (exactly-once).
         Ending the root span records the whole trace."""
@@ -257,8 +262,8 @@ class Trace:
         return dict(self.summary(), roots=top)
 
     def to_chrome(self) -> dict:
-        """Chrome-trace JSON — same shape ``SpanTracer.export`` writes, so
-        one tooling path (chrome://tracing / Perfetto) reads both."""
+        """Chrome-trace JSON (chrome://tracing / Perfetto): the one Chrome
+        export of host spans; a profiler capture holds the same names."""
         spans = self.spans
         t0 = min((s.start_ts for s in spans), default=0.0)
         threads: Dict[str, int] = {}
@@ -380,8 +385,8 @@ def current_request_id() -> Optional[str]:
 
 
 def propagate(fn: Callable) -> Callable:
-    """Capture the CURRENT context (active span + installed tracer + any
-    other ContextVars) and re-install it around every call of ``fn``.
+    """Capture the CURRENT context (the active span and any other
+    ContextVars) and re-install it around every call of ``fn``.
 
     The explicit bridge across thread hops: plain ``threading.Thread`` /
     pool workers start with an EMPTY context, so spans opened there would
@@ -408,24 +413,77 @@ def propagate(fn: Callable) -> Callable:
     return wrapped
 
 
-# -- SpanTracer installation (utils/profiling.py) -----------------------------
-def install_tracer(tracer: object) -> "contextvars.Token":
-    """Install a ``SpanTracer``-shaped object (has a ``span(name, **args)``
-    context manager) as the context's active tracer."""
-    return _TRACER.set(tracer)
+# -- the span primitive -------------------------------------------------------
+#: ``jax.profiler.TraceAnnotation``, looked up at the first span (this module
+#: imports before jax); False where jax is not installed.
+_ANNOTATION: object = None
+
+#: STOPGAP, not an operator feature. The benchmark's captures are taken at
+#: ``host_tracer_level=0`` (no TraceMe recorded, so no annotation either),
+#: and its harness is not this module's to change: until it records host
+#: events, or brackets its capture with a start/stop hook, the last closed
+#: spans are kept here, ``(name, thread ident, start, end)`` in
+#: ``time.time_ns()``, the clock the profiler stamps its events with, so a
+#: device-only trace can be laid over them. One constant ring, no knob:
+#: memory is bounded, appends take no lock. Goes when its one reader
+#: (``benchmarks/idle_gaps.py``) reads the trace's host plane instead.
+_SPAN_LOG: deque = deque(maxlen=65536)
 
 
-def uninstall_tracer(token: "contextvars.Token") -> None:
-    try:
-        _TRACER.reset(token)
-    except ValueError:
-        # token minted in another context (enter/exit crossed threads) —
-        # clearing beats leaving a dead tracer installed forever
-        _TRACER.set(None)
+def span_log() -> List[Tuple[str, int, int, int]]:
+    """``[(name, thread ident, start_ns, end_ns), ...]`` of the last closed
+    spans, in closing order (an inner span closes before the one around
+    it). See ``_SPAN_LOG``: a stopgap for the benchmark."""
+    return list(_SPAN_LOG)
 
 
-def installed_tracer() -> Optional[object]:
-    return _TRACER.get()
+class _Span:
+    """``with span(name, **attrs):`` — see :func:`span`."""
+
+    __slots__ = ("_name", "_attrs", "_annotation", "_scope", "_t0")
+
+    def __init__(self, name: str, attrs: dict):
+        self._name = name
+        self._attrs = attrs
+
+    def __enter__(self) -> Optional[Span]:
+        global _ANNOTATION
+        if _ANNOTATION is None:
+            try:
+                from jax.profiler import TraceAnnotation
+                _ANNOTATION = TraceAnnotation
+            except Exception:
+                _ANNOTATION = False
+        # outside a trace not even the inert scope is made
+        self._scope = (start_span(self._name, **self._attrs)
+                       if _SPAN.get() is not None else None)
+        child = self._scope.__enter__() if self._scope is not None else None
+        self._annotation = (_ANNOTATION(self._name, **self._attrs)
+                            if _ANNOTATION else None)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.time_ns()
+        return child
+
+    def __exit__(self, *exc: object) -> None:
+        _SPAN_LOG.append((self._name, threading.get_ident(), self._t0,
+                          time.time_ns()))
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        if self._scope is not None:
+            self._scope.__exit__(*exc)
+
+
+def span(name: str, **attrs: object) -> _Span:
+    """THE span primitive, for every layer boundary of a hot path.
+
+    The block is a ``jax.profiler.TraceAnnotation`` (``attrs`` are its
+    keyword arguments), so a profiler capture that records host events shows
+    it beside the device's operations, and when a request trace is active it
+    is also a child span of it (the block's value, else None). Outside a
+    trace no :class:`Span` is made. Worker threads inherit the request trace
+    through :func:`propagate`."""
+    return _Span(name, attrs)
 
 
 # -- exemplars ----------------------------------------------------------------

@@ -174,22 +174,32 @@ class StageCounters:
             M_STAGE_BYTES.inc(nbytes, stage=stage)
 
     class _Timer:
-        __slots__ = ("_c", "_stage", "_nbytes", "_t0")
+        __slots__ = ("_c", "_stage", "_nbytes", "_span", "_t0")
 
-        def __init__(self, counters, stage, nbytes):
+        def __init__(self, counters, stage, nbytes, span):
             self._c, self._stage, self._nbytes = counters, stage, nbytes
+            self._span = span
 
         def __enter__(self):
+            if self._span is not None:
+                self._span.__enter__()
             self._t0 = time.perf_counter()
             return self
 
         def __exit__(self, *exc):
             self._c.add(self._stage, time.perf_counter() - self._t0,
                         self._nbytes)
+            if self._span is not None:
+                self._span.__exit__(*exc)
             return False
 
-    def timer(self, stage: str, nbytes: int = 0) -> "StageCounters._Timer":
-        return self._Timer(self, stage, nbytes)
+    def timer(self, stage: str, nbytes: int = 0, span: Optional[str] = None,
+              **attrs: object) -> "StageCounters._Timer":
+        """Time the block into ``stage``; with ``span`` the same statement
+        opens that span (``attrs`` are its attributes), so a boundary never
+        has the counter without the span or the span without the counter."""
+        return self._Timer(self, stage, nbytes,
+                           _tracing.span(span, **attrs) if span else None)
 
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         with self._lock:

@@ -75,7 +75,6 @@ from ..observability import tracing as _tracing
 from ..observability.slo import get_tracker as _slo_tracker
 from ..reliability import get_injector as _get_injector
 from ..reliability.lock_sanitizer import new_lock
-from ..utils.profiling import span as _prof_span
 from ..models.zoo.transformer import (TransformerConfig,
                                       _warp_scaled_rows,
                                       decode_step_ragged,
@@ -95,6 +94,18 @@ _M_DRAIN_SECONDS = _metric_histogram(
     "mmlspark_continuous_drain_seconds",
     "Host fetch latency of one outstanding (k, S) token block — the only "
     "host<->device sync on the decode path")
+#: always on, for operators: 25 ms steps through the 50-500 ms where a
+#: first token usually lands, then coarser
+_TIMELINE_BUCKETS = tuple(0.025 * i for i in range(1, 21)) + (
+    0.75, 1.0, 1.5, 2.0, 3.0, 5.0, 10.0, 30.0)
+_M_QUEUE_WAIT = _metric_histogram(
+    "mmlspark_generation_queue_wait_seconds",
+    "Submit to slot: how long a generation request waited for admission",
+    buckets=_TIMELINE_BUCKETS)
+_M_TTFT = _metric_histogram(
+    "mmlspark_generation_ttft_seconds",
+    "Submit to first token on the host (queue wait + prefill + first "
+    "drain), on the engine's clock", buckets=_TIMELINE_BUCKETS)
 _M_LIVE_SLOTS = _metric_gauge(
     "mmlspark_continuous_live_slots",
     "Occupied decode slots at the latest step (batch size on device)")
@@ -108,7 +119,8 @@ _M_PREFIX_HITS = _metric_counter(
 
 class _Request:
     __slots__ = ("rid", "prompt", "max_new", "tokens", "done", "event",
-                 "submitted_at", "first_token_at", "finished_at",
+                 "submitted_at", "admitted_at", "first_token_at",
+                 "finished_at", "span",
                  "temperature", "top_k", "top_p", "seed",
                  "prefix_key", "prefix_len", "error",
                  "cost_cls", "cost_trace",
@@ -129,9 +141,17 @@ class _Request:
         self.tokens: List[int] = []
         self.done = False
         self.event = threading.Event()
+        #: the request's timeline, ``time.perf_counter()`` seconds: queued,
+        #: given a slot (the last time, if the pool sent it back to the
+        #: queue), first token on the host, done
         self.submitted_at = time.perf_counter()
+        self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
+        #: the span ``submit`` ran under (the HTTP request's root, when the
+        #: engine submits for one): engine-thread ticks run outside any
+        #: request's context, so the ticket carries it
+        self.span = _tracing.current_span()
         # cost-ledger workload class + trace, captured at submit time
         # (engine-thread ticks run outside the request's trace context)
         self.cost_cls, self.cost_trace = _resolve_cost_ctx()
@@ -143,6 +163,15 @@ class _Request:
         self.pre_emitted: List[int] = []
         #: how many of ``tokens`` have reached the journal tail
         self.journaled = 0
+
+    def timeline(self) -> Dict[str, object]:
+        """The stamps and sizes a reply's root span closes with."""
+        return {"submitted_at": self.submitted_at,
+                "admitted_at": self.admitted_at,
+                "first_token_at": self.first_token_at,
+                "finished_at": self.finished_at,
+                "prompt_tokens": int(self.prompt.size),
+                "new_tokens": len(self.tokens)}
 
 
 def _sample_rows(logits, temp, top_k, top_p, keys):
@@ -972,6 +1001,13 @@ class ContinuousDecoder:
         self._key = self._zeros((self._S, 2), jnp.uint32)
 
     # ---- client surface ----
+    @staticmethod
+    def _note_admitted(slot: int, req: _Request) -> None:
+        """Stamp a request where it is given a slot."""
+        req.admitted_at = time.perf_counter()
+        if req.span is not None:
+            req.span.event("admitted", slot=slot)
+
     def submit(self, prompt_ids, max_new_tokens: int = 32, *,
                temperature: float = 0.0, top_k: int = 0,
                top_p: float = 1.0, seed: int = 0,
@@ -1235,6 +1271,7 @@ class ContinuousDecoder:
                 req.session_id = str(sid)
             req.pre_emitted = list(emitted)
             self._slot_req[slot] = req
+            self._note_admitted(slot, req)
             self._slot_pages[slot] = adopted + extra
             self._set_bt_row(slot, adopted + extra)
             # device state: the last emitted token is the next input, at
@@ -1286,6 +1323,7 @@ class ContinuousDecoder:
                     group = [(free[i], reqs[off + i]) for i in range(m)]
                     for slot, req in group:
                         self._slot_req[slot] = req
+                        self._note_admitted(slot, req)
                 if not self._insert_rows(
                         group, logits[off:off + m],
                         [{kk: c[kk][off:off + m] for kk in ("k", "v")}
@@ -1308,6 +1346,7 @@ class ContinuousDecoder:
                     slot = free.pop(0)
                     req = self._waiting.pop(0)
                     self._slot_req[slot] = req
+                    self._note_admitted(slot, req)
                     batch.append((slot, req))
             if not batch:
                 if staged_any:
@@ -1382,7 +1421,7 @@ class ContinuousDecoder:
         identical). Returns (logits, row_cache); rows past ``len(reqs)``
         are pad garbage."""
         padded = self._bucket(max(r.prompt.size for r in reqs))
-        with _prof_span("continuous.prefill", requests=len(reqs),
+        with _tracing.span("continuous.prefill", requests=len(reqs),
                         bucket=padded):
             k = 1 << (len(reqs) - 1).bit_length()
             ids = np.zeros((k, padded), np.int32)
@@ -1790,7 +1829,7 @@ class ContinuousDecoder:
         w = min(self._chunk_budget(), P - off)
         ids = self._padded_ids(req.prompt[off:off + w], self._L - off)
         t0 = time.perf_counter()
-        with _prof_span("continuous.prefill_chunk", slot=slot,
+        with _tracing.span("continuous.prefill_chunk", slot=slot,
                         offset=off, tokens=w):
             w_logits, bufs = self._extend_paged(
                 self._params, jnp.asarray(ids),
@@ -1824,6 +1863,11 @@ class ContinuousDecoder:
         now = time.perf_counter()
         if req.first_token_at is None:
             req.first_token_at = now
+            _M_TTFT.observe(now - req.submitted_at)
+            if req.admitted_at is not None:
+                _M_QUEUE_WAIT.observe(req.admitted_at - req.submitted_at)
+            if req.span is not None:
+                req.span.event("first_token")
         req.tokens.append(tok)
         if ((self._eos is not None and tok == self._eos)
                 or len(req.tokens) >= req.max_new):
@@ -1858,18 +1902,19 @@ class ContinuousDecoder:
         device program order serializes them."""
         if not self._kv.should_compact(self._defrag_thr):
             return
-        remap = self._kv.compact()
-        if remap is None:
-            return
-        perm = np.empty_like(remap)
-        perm[remap] = np.arange(remap.size)
-        self._kv.buffers = self._compact_j(
-            self._kv.buffers, jnp.asarray(perm, jnp.int32))
-        self._bt_host = remap[self._bt_host].astype(np.int32)
-        self._slot_pages = [
-            None if p is None else [int(remap[x]) for x in p]
-            for p in self._slot_pages]
-        self._upload_bt()
+        with _tracing.span("decoder.compact"):
+            remap = self._kv.compact()
+            if remap is None:
+                return
+            perm = np.empty_like(remap)
+            perm[remap] = np.arange(remap.size)
+            self._kv.buffers = self._compact_j(
+                self._kv.buffers, jnp.asarray(perm, jnp.int32))
+            self._bt_host = remap[self._bt_host].astype(np.int32)
+            self._slot_pages = [
+                None if p is None else [int(remap[x]) for x in p]
+                for p in self._slot_pages]
+            self._upload_bt()
         _tracing.add_event("kv_compact",
                            pages_in_use=self._kv.pages_in_use)
 
@@ -1877,7 +1922,7 @@ class ContinuousDecoder:
         """One engine tick; returns the number of live slots stepped.
         Serialized against :meth:`cancel_all` (the only other slot-table
         mutator callable from another thread)."""
-        with self._engine_lock:
+        with _tracing.span("decoder.step"), self._engine_lock:
             return self._step_locked()
 
     def _step_locked(self) -> int:
@@ -1902,7 +1947,8 @@ class ContinuousDecoder:
                            for i in range(self._S))
                    and self._retirement_in_flight()):
                 self._drain_one()
-        self._admit()
+        with _tracing.span("decoder.admit"):
+            self._admit()
         # one prefill chunk per tick, interleaved with the decode below —
         # this IS the chunked-prefill scheduler: long prompts never run
         # more than chunk-budget prefill work in any one tick
@@ -1934,6 +1980,40 @@ class ContinuousDecoder:
                 self._drain_one()
             return len(live)
         tick_t0 = time.perf_counter()
+        with _tracing.span("decoder.tick", live=len(decode_live), k=self._k):
+            toks = self._dispatch_tick(decode_live)
+        # one dispatch covers every live decode slot: apportion its wall
+        # time equally across the requests that rode it
+        _get_ledger().charge_shares(
+            "device_seconds", time.perf_counter() - tick_t0,
+            [(self._slot_req[i].cost_cls, self._slot_req[i].cost_trace, 1.0)
+             for i in decode_live])
+        # per-dispatch attention accounting: k paged calls rode this
+        # dispatch; only the gather impl moves materialization bytes
+        self._kv.note_attn_tick(
+            self._attn_impl, calls=self._k,
+            gather_bytes=(self._k * self._gather_bytes_tick
+                          if self._attn_impl == "gather" else 0))
+        # snapshot slot→REQUEST (not indices): by the time this block is
+        # drained, a slot may have been freed and re-admitted; tokens must
+        # go to the request that occupied the slot at DISPATCH time (its
+        # done guard discards the inactive-slot repeats)
+        self._pending.append((toks, {i: (i, self._slot_req[i])
+                                     for i in decode_live}))
+        # prefill-ahead: with the decode block dispatched (device busy for
+        # k steps), background-prefill waiting prompts into the stage
+        if self._stage_cap:
+            with _tracing.span("decoder.stage_prefills"):
+                self._stage_prefills()
+        # the ONLY host↔device sync on the decode path: fetch the oldest
+        # block once `depth` newer dispatches are already queued on device
+        while len(self._pending) > self._depth_now():
+            self._drain_one()
+        return len(live)
+
+    def _dispatch_tick(self, decode_live):
+        """Enqueue one decode block for the live slots (no host sync);
+        returns the device token block."""
         if self._spec:
             gamma_now = (self._tuner.gamma if self._tuner is not None
                          else self._gamma)
@@ -1977,33 +2057,7 @@ class ContinuousDecoder:
                     self._params, self._tok, self._pos, self._active,
                     self._kv.buffers, self._bt, self._remaining)
             self._kv.buffers = bufs
-        # one dispatch covers every live decode slot: apportion its wall
-        # time equally across the requests that rode it
-        _get_ledger().charge_shares(
-            "device_seconds", time.perf_counter() - tick_t0,
-            [(self._slot_req[i].cost_cls, self._slot_req[i].cost_trace, 1.0)
-             for i in decode_live])
-        # per-dispatch attention accounting: k paged calls rode this
-        # dispatch; only the gather impl moves materialization bytes
-        self._kv.note_attn_tick(
-            self._attn_impl, calls=self._k,
-            gather_bytes=(self._k * self._gather_bytes_tick
-                          if self._attn_impl == "gather" else 0))
-        # snapshot slot→REQUEST (not indices): by the time this block is
-        # drained, a slot may have been freed and re-admitted; tokens must
-        # go to the request that occupied the slot at DISPATCH time (its
-        # done guard discards the inactive-slot repeats)
-        self._pending.append((toks, {i: (i, self._slot_req[i])
-                                     for i in decode_live}))
-        # prefill-ahead: with the decode block dispatched (device busy for
-        # k steps), background-prefill waiting prompts into the stage
-        if self._stage_cap:
-            self._stage_prefills()
-        # the ONLY host↔device sync on the decode path: fetch the oldest
-        # block once `depth` newer dispatches are already queued on device
-        while len(self._pending) > self._depth_now():
-            self._drain_one()
-        return len(live)
+        return toks
 
     def _depth_now(self) -> int:
         """The live pipeline-depth bound: the autotuner's pick when it is
@@ -2035,7 +2089,7 @@ class ContinuousDecoder:
         # the np.asarray is the decode path's only host↔device sync — the
         # exact line a wedged device parks forever, so the watchdog covers it
         drain_t0 = time.perf_counter()
-        with _M_DRAIN_SECONDS.time(), _prof_span("continuous.drain"), \
+        with _M_DRAIN_SECONDS.time(), _tracing.span("continuous.drain"), \
                 _watch("decoder_drain"):
             toks = np.asarray(toks_dev)
         _get_ledger().charge_shares(
@@ -2167,7 +2221,7 @@ class ContinuousDecoder:
 
     def start(self) -> threading.Thread:
         # the decoder thread starts with an empty context — propagate()
-        # carries whatever tracer/trace is active at start() into it, so
+        # carries whatever trace is active at start() into it, so
         # prefill/drain spans stay attributable
         t = threading.Thread(target=_tracing.propagate(self.serve_forever),
                              daemon=True, name="continuous-decoder")

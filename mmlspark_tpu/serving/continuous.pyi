@@ -18,13 +18,16 @@ class _Request:
     done: bool
     event: threading.Event
     submitted_at: float
+    admitted_at: Optional[float]
     first_token_at: Optional[float]
     finished_at: Optional[float]
+    span: Any
     cost_cls: Any
     cost_trace: Optional[str]
     session_id: str
     pre_emitted: List[int]
     journaled: int
+    def timeline(self) -> Dict[str, object]: ...
 
 class ContinuousDecoder:
     stats: Dict[str, int]

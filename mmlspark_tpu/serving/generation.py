@@ -20,17 +20,35 @@ import json
 import logging
 import threading
 import traceback
+from collections import deque
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..observability import tracing as _tracing
 from .continuous import ContinuousDecoder
 from .server import StreamingReply, WorkerServer
 
-__all__ = ["GenerationEngine"]
+__all__ = ["GenerationEngine", "recent_timelines", "RECENT_TIMELINES"]
 
 _log = logging.getLogger("mmlspark_tpu.serving")
+
+#: How many requests' timelines :func:`recent_timelines` reaches back.
+RECENT_TIMELINES = 4096
+_RECENT: "deque[Dict[str, object]]" = deque(maxlen=RECENT_TIMELINES)
+
+
+def recent_timelines() -> List[Dict[str, object]]:
+    """The timelines (``submitted_at``, ``admitted_at``, ``first_token_at``,
+    ``finished_at`` in ``time.perf_counter()`` seconds, ``prompt_tokens``,
+    ``new_tokens``) of the last :data:`RECENT_TIMELINES` requests this
+    process's engines replied to, oldest first: what each request's root
+    span closed with. The histograms keep the distribution since start and
+    the flight recorder the traces it judges worth an operator's look;
+    a percentile over one stretch of traffic needs every request of it,
+    and a list shorter than :data:`RECENT_TIMELINES` has dropped none."""
+    return list(_RECENT)
 
 
 @dataclass
@@ -108,14 +126,17 @@ class GenerationEngine:
             mn = int(body.get("max_new", self.default_max_new))
             pl = body.get("prefix_len")
             stream = bool(body.get("stream", False))
-            ticket = self.decoder.submit(
-                np.asarray(toks, np.int32), mn,
-                temperature=float(body.get("temperature", 0.0)),
-                top_k=int(body.get("top_k", 0)),
-                top_p=float(body.get("top_p", 1.0)),
-                seed=int(body.get("seed", 0)),
-                prefix_key=body.get("prefix_key"),
-                prefix_len=int(pl) if pl is not None else None)
+            # under the request's root span: the ticket keeps it, and the
+            # decoder marks admission and first token on it
+            with _tracing.activate(cached.trace_span):
+                ticket = self.decoder.submit(
+                    np.asarray(toks, np.int32), mn,
+                    temperature=float(body.get("temperature", 0.0)),
+                    top_k=int(body.get("top_k", 0)),
+                    top_p=float(body.get("top_p", 1.0)),
+                    seed=int(body.get("seed", 0)),
+                    prefix_key=body.get("prefix_key"),
+                    prefix_len=int(pl) if pl is not None else None)
         except Exception as e:
             self.server.reply_json(rid, {"error": str(e)}, status=400)
             return
@@ -146,14 +167,21 @@ class GenerationEngine:
             f = self._inflight.pop(drid)
             rid, ticket, handle = f.rid, f.ticket, f.stream
             err = getattr(ticket, "error", None)
+            timeline = ticket.timeline()
+            _RECENT.append(timeline)
+            # the root span closes with the request's timeline: at the
+            # stream's close, or with the one reply
             if handle is not None:
                 if err is not None:
                     handle.send_event({"error": str(err)})
                 else:
                     handle.send_event({"done": True,
                                        "tokens": list(ticket.tokens)})
-                handle.close()
-            elif err is not None:
+                handle.close(**timeline)
+                continue
+            if ticket.span is not None:
+                ticket.span.set(**timeline)
+            if err is not None:
                 # per-request admit failure (e.g. prefix mismatch): 400s
                 # this client alone, the batch keeps decoding
                 self.server.reply_json(rid, {"error": str(err)},
@@ -164,14 +192,19 @@ class GenerationEngine:
             self.server.commit_epoch()
 
     def _loop(self) -> None:
+        span = _tracing.span
         while not self._stop.is_set():
             try:
-                self._admit_http(idle=not self._inflight)
+                with span("engine.admit_http"):
+                    self._admit_http(idle=not self._inflight)
                 stepped = self.decoder.step()
-                self._pump_streams()
-                self._reply_finished()
+                with span("engine.pump_streams"):
+                    self._pump_streams()
+                with span("engine.reply_finished"):
+                    self._reply_finished()
                 if stepped == 0 and not self._inflight:
-                    self._stop.wait(0.005)
+                    with span("engine.idle"):
+                        self._stop.wait(0.005)
             except Exception:
                 _log.error("generation engine tick failed:\n%s",
                            traceback.format_exc())

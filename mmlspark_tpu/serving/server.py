@@ -130,8 +130,11 @@ class StreamingReply:
 
     _CLOSE = object()
 
-    def __init__(self, content_type: str = "text/event-stream"):
+    def __init__(self, content_type: str = "text/event-stream",
+                 trace_span: Optional[object] = None):
         self.content_type = content_type
+        #: the request's root span: it ends when the stream closes
+        self._trace_span = trace_span
         self._q: "queue.Queue" = queue.Queue()
         self._notify = None
         self._lock = new_lock("serving.server.StreamingReply._lock")
@@ -155,7 +158,9 @@ class StreamingReply:
         import json as _json
         self.send(f"data: {_json.dumps(payload)}\n\n")
 
-    def close(self) -> None:
+    def close(self, **attrs: object) -> None:
+        """End the stream, and with it the request's trace: ``attrs`` (the
+        owner's account of the request) close the root span."""
         with self._lock:
             if self._closed:
                 return
@@ -163,6 +168,9 @@ class StreamingReply:
             # unbounded queue — see send()
             self._q.put(StreamingReply._CLOSE)  # tpulint: disable=TPU014
             notify = self._notify
+        if self._trace_span is not None:
+            # idempotent: a transport that timed the request out closed it
+            self._trace_span.end(status=200, streaming=True, **attrs)
         if notify is not None:
             notify()
 
@@ -969,7 +977,7 @@ class WorkerServer:
         (newest first, slow-kept traces ahead of the ring);
         ``GET /debug/traces/{trace_id}`` returns one full span tree, or
         Chrome-trace JSON with ``?format=chrome`` (loadable in
-        chrome://tracing / Perfetto, same shape SpanTracer.export writes).
+        chrome://tracing / Perfetto).
 
         Registered in ``control_routes`` ahead of any catch-all (the
         distributed forwarder appends "/" LAST), so it stays reachable on
@@ -1488,11 +1496,9 @@ class WorkerServer:
         cached = self._take_answered(request_id)
         if cached is None:
             return None
-        if cached.trace_span is not None:
-            # the trace covers accept → stream OPEN (chunk timing belongs
-            # to the stream itself, which may outlive the span tree)
-            cached.trace_span.end(status=200, streaming=True)
-        stream = StreamingReply(content_type)
+        # the trace covers accept → stream CLOSE: queueing, prefill and
+        # every token are inside it
+        stream = StreamingReply(content_type, trace_span=cached.trace_span)
         cached.respond(stream)
         return stream
 

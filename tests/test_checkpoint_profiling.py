@@ -92,10 +92,11 @@ def test_gbdt_checkpoint_noop_when_complete(tmp_path):
     assert b1.num_trees == b2.num_trees == 5
 
 
-def test_profiling_annotate_and_stopwatch():
-    from mmlspark_tpu.utils.profiling import StopWatch, annotate
-    with annotate("test.scope"):
-        pass   # must not raise outside a trace
+def test_profiling_span_and_stopwatch():
+    from mmlspark_tpu.observability.tracing import span
+    from mmlspark_tpu.utils.profiling import StopWatch
+    with span("test.scope"):
+        pass   # must not raise outside a trace and a profile
     sw = StopWatch()
     sw.measure(lambda: sum(range(1000)))
     assert sw.elapsed_ns >= 0
@@ -183,41 +184,55 @@ class TestShardedCheckpointer:
             np.testing.assert_allclose(np.asarray(back["w"]), 1.0)
 
 
-class TestSpanTracer:
+class TestSpan:
+    """The one span primitive (observability/tracing.py) where the
+    SpanTracer stood: nesting and the Chrome export through a request
+    trace, stages traced with nothing installed."""
+
     def test_spans_nest_and_export(self, tmp_path):
         import json
         import time
-        from mmlspark_tpu.utils.profiling import SpanTracer, span
-        with SpanTracer() as t:
-            with span("outer"):
-                with span("inner", detail="x"):
+        from mmlspark_tpu.observability import tracing as tr
+        root = tr.start_trace("run")
+        with tr.activate(root):
+            with tr.span("outer"):
+                with tr.span("inner", detail="x") as inner:
                     time.sleep(0.01)
-        names = [e["name"] for e in t.events]
-        assert names == ["inner", "outer"]  # completion order
-        assert t.total("inner") >= 0.01
-        assert t.total("outer") >= t.total("inner")
-        p = t.export(str(tmp_path / "run.trace.json"))
+        root.end()
+        spans = {s.name: s for s in root.trace.spans}
+        assert list(spans) == ["run", "outer", "inner"]  # opening order
+        assert inner is spans["inner"]
+        assert spans["inner"].parent_id == spans["outer"].span_id
+        assert spans["inner"].duration >= 0.01
+        assert spans["outer"].duration >= spans["inner"].duration
+        p = tmp_path / "run.trace.json"
+        p.write_text(json.dumps(root.trace.to_chrome()))
         doc = json.load(open(p))
         assert doc["traceEvents"][0]["ph"] == "X"
-        assert doc["traceEvents"][0]["args"] == {"detail": "x"}
+        assert doc["traceEvents"][2]["args"]["detail"] == "x"
 
     def test_pipeline_stages_traced_automatically(self):
         import numpy as np
         from mmlspark_tpu.core import DataFrame
         from mmlspark_tpu.models.gbdt.estimators import LightGBMClassifier
-        from mmlspark_tpu.utils.profiling import SpanTracer
+        from mmlspark_tpu.observability import tracing as tr
         rng = np.random.default_rng(0)
         df = DataFrame({"features": [rng.normal(0, 1, 4).astype(np.float32)
                                      for _ in range(30)],
                         "label": rng.integers(0, 2, 30).astype(np.float64)})
-        with SpanTracer() as t:
+        tr._SPAN_LOG.clear()
+        root = tr.start_trace("run")
+        with tr.activate(root):
             model = LightGBMClassifier(num_iterations=2, num_leaves=4).fit(df)
             model.transform(df)
-        names = {e["name"] for e in t.events}
-        assert "LightGBMClassifier.fit" in names
-        assert any(n.endswith(".transform") for n in names)
+        root.end()
+        for names in ({s.name for s in root.trace.spans},
+                      {name for name, *_ in tr.span_log()}):
+            assert "LightGBMClassifier.fit" in names
+            assert any(n.endswith(".transform") for n in names)
 
-    def test_span_noop_without_tracer(self):
-        from mmlspark_tpu.utils.profiling import span
-        with span("orphan"):
-            pass  # must not raise
+    def test_span_is_inert_without_a_trace(self):
+        from mmlspark_tpu.observability import tracing as tr
+        with tr.span("orphan") as child:
+            assert child is None  # must not raise, opens no trace
+        assert tr.current_span() is None

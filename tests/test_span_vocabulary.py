@@ -1,0 +1,182 @@
+"""The span vocabulary (docs/observability.md): every name fires on a CPU
+run of the transform path (``ONNXModel`` over ``BatchRunner``) and of a tiny
+``GenerationEngine``; a streamed request's trace ends when the stream
+closes and carries the request's timeline; a span outside a trace and a
+profile allocates nothing but its row of the span log."""
+
+import json
+import threading
+import urllib.request
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from mmlspark_tpu.observability import tracing as tr
+
+TRANSFORM_SPANS = ["ONNXModel.transform", "partition", "runner.run",
+                   "runner.next", "runner.coerce", "runner.pad",
+                   "runner.h2d", "runner.dispatch", "runner.d2h",
+                   "onnx.collect", "frame.concat"]
+GENERATION_SPANS = ["engine.admit_http", "engine.pump_streams",
+                    "engine.reply_finished", "engine.idle", "decoder.step",
+                    "decoder.admit", "decoder.tick",
+                    "decoder.stage_prefills", "decoder.compact",
+                    "continuous.prefill", "continuous.prefill_chunk",
+                    "continuous.drain"]
+
+
+def _stream(url, payload):
+    req = urllib.request.Request(
+        url, data=json.dumps(dict(payload, stream=True)).encode(),
+        headers={"Content-Type": "application/json"})
+    events = []
+    with urllib.request.urlopen(req, timeout=120) as r:
+        for line in r:
+            if line.startswith(b"data: "):
+                events.append(json.loads(line[6:]))
+    return events
+
+
+@pytest.fixture(scope="module")
+def transform_run():
+    """One two-partition transform under a request trace: the names the
+    span log saw, and the trace's spans."""
+    import mmlspark_tpu.onnx as O
+    from mmlspark_tpu.core import DataFrame
+    from mmlspark_tpu.models.onnx_model import ONNXModel
+    rng = np.random.default_rng(0)
+    graph = O.make_graph(
+        [O.make_node("MatMul", ["x", "w"], ["logits"])], "linear",
+        inputs=[O.make_tensor_value_info("x", np.float32, ["N", 8])],
+        outputs=[O.make_tensor_value_info("logits", np.float32, ["N", 3])],
+        initializers={"w": rng.normal(0, 0.5, (8, 3)).astype(np.float32)})
+    model = ONNXModel(O.make_model(graph), feed_dict={"x": "feats"},
+                      fetch_dict={"logits": "logits"}, pin_devices=False,
+                      mini_batch_size=4)
+    X = rng.normal(0, 1, (16, 8)).astype(np.float32)
+    df = DataFrame({"feats": list(X)}, npartitions=2)
+    tr._SPAN_LOG.clear()
+    root = tr.start_trace("transform")
+    with tr.activate(root):
+        out = model.transform(df)
+    root.end()
+    assert len(out) == 16
+    return {name for name, *_ in tr.span_log()}, root.trace.spans
+
+
+@pytest.fixture(scope="module")
+def generation_run():
+    """On the decoder before the engine's thread starts, a short request
+    retires under a long one (the pool compacts: no race to lose); then
+    three streamed requests on two slots over HTTP: one long enough to
+    prefill in chunks, a third that waits for a slot (prefill-ahead)."""
+    from mmlspark_tpu.models.zoo.transformer import (TransformerConfig,
+                                                     init_transformer)
+    from mmlspark_tpu.serving.generation import GenerationEngine
+    cfg = TransformerConfig(vocab=128, layers=2, d_model=64, heads=4,
+                            d_ff=128, max_len=64, causal=True,
+                            norm="rmsnorm", position="rope",
+                            dtype=jnp.float32)
+    tr._SPAN_LOG.clear()
+    tr.get_flight_recorder().clear()
+    rng = np.random.default_rng(3)
+    jobs = [(5, 3), (20, 24), (6, 4)]
+    replies = {}
+    eng = GenerationEngine(init_transformer(cfg, seed=0), cfg, max_slots=2,
+                           max_len=48, page_size=4, prefill_chunk=8,
+                           prefill_ahead=1)
+    eng.decoder._defrag_thr = 1
+    pair = [eng.decoder.submit(rng.integers(1, cfg.vocab, n).astype(np.int32),
+                               m) for n, m in ((5, 3), (9, 24))]
+    while not all(t.done for t in pair):
+        eng.decoder.step()
+    with eng:
+        def client(i, n, m):
+            prompt = [int(t) for t in rng.integers(1, cfg.vocab, n)]
+            replies[i] = _stream(eng.address, {"tokens": prompt,
+                                               "max_new": m})
+        threads = [threading.Thread(target=client, args=(i, n, m))
+                   for i, (n, m) in enumerate(jobs)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+    assert all(replies[i][-1].get("done") for i in range(len(jobs)))
+    return ({name for name, *_ in tr.span_log()},
+            tr.get_flight_recorder().traces())
+
+
+@pytest.mark.parametrize("name", TRANSFORM_SPANS + GENERATION_SPANS)
+def test_span_fires(name, request):
+    run = "transform_run" if name in TRANSFORM_SPANS else "generation_run"
+    names, _ = request.getfixturevalue(run)
+    assert name in names
+
+
+def test_transform_spans_join_the_request_trace(transform_run):
+    _, spans = transform_run
+    names = [s.name for s in spans]
+    # both partitions, and the worker-thread stages under them
+    assert names.count("partition") == 2 and names.count("runner.run") == 2
+    dispatch = [s for s in spans if s.name == "runner.dispatch"]
+    assert len(dispatch) == 4 and all("compiled" in s.attrs
+                                      for s in dispatch)
+    assert "cache_hit" not in [e["name"] for s in spans for e in s.events]
+
+
+def test_streamed_trace_ends_at_close_with_the_timeline(generation_run):
+    _, traces = generation_run
+    roots = [t.root for t in traces if t.root.attrs.get("streaming")]
+    assert len(roots) == 3
+    for root in roots:
+        a = root.attrs
+        assert a["status"] == 200
+        stamps = [a["submitted_at"], a["admitted_at"], a["first_token_at"],
+                  a["finished_at"]]
+        assert stamps == sorted(stamps)
+        # the root covers the whole generation, not just the stream's open
+        assert root.duration >= a["finished_at"] - a["submitted_at"]
+        assert a["new_tokens"] in (3, 24, 4) and a["prompt_tokens"] >= 5
+        events = [e["name"] for e in root.events]
+        assert events.index("admitted") < events.index("first_token")
+
+
+def test_replied_requests_keep_their_timelines(generation_run):
+    """What each root span closed with is also on the engine's own list,
+    whole, whatever the flight recorder kept."""
+    from mmlspark_tpu.serving import generation
+    _, traces = generation_run
+    kept = generation.recent_timelines()
+    assert len(kept) < generation.RECENT_TIMELINES
+    for root in (t.root for t in traces if t.root.attrs.get("streaming")):
+        mine = [a for a in kept
+                if a["submitted_at"] == root.attrs["submitted_at"]]
+        assert len(mine) == 1
+        assert all(root.attrs[k] == v for k, v in mine[0].items())
+
+
+def test_generation_timeline_histograms(generation_run):
+    from mmlspark_tpu import observability as obs
+    snap = obs.snapshot()
+    for name in ("mmlspark_generation_queue_wait_seconds",
+                 "mmlspark_generation_ttft_seconds"):
+        assert snap[name]["series"][0]["count"] >= 3
+
+
+def test_span_outside_trace_and_profile_allocates_no_span(monkeypatch):
+    made = []
+    real = tr.Span.__init__
+
+    def counting(self, *a, **kw):
+        made.append(self)
+        real(self, *a, **kw)
+    monkeypatch.setattr(tr.Span, "__init__", counting)
+    tr._SPAN_LOG.clear()
+    with tr.span("orphan", detail="x") as child:
+        assert child is None and tr.current_span() is None
+    assert made == []
+    (name, thread, t0, t1), = tr.span_log()
+    assert name == "orphan"
+    assert thread == threading.get_ident() and t0 <= t1

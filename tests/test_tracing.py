@@ -154,7 +154,7 @@ def test_propagate_carries_context_into_plain_thread():
 
 
 # ---------------------------------------------------------------------------
-# prefetch-worker regression (utils/profiling.py satellite)
+# prefetch-worker regression (the span primitive off the dispatch thread)
 
 
 def _make_runner(mini_batch_size=2, prefetch_depth=2, n=8):
@@ -174,19 +174,30 @@ def _make_runner(mini_batch_size=2, prefetch_depth=2, n=8):
                        prefetch_depth=prefetch_depth), n
 
 
-def test_prefetch_worker_spans_land_in_parent_tracer():
-    """The regression the contextvars migration fixes: coerce/pad run on
-    the PrefetchIterator worker thread, and a SpanTracer installed on the
-    dispatch thread must still record them (threading.local lost them)."""
-    from mmlspark_tpu.utils.profiling import SpanTracer
+def test_prefetch_worker_spans_land_in_span_log():
+    """coerce/pad run on the PrefetchIterator worker thread; the one span
+    primitive records them there with no tracer to install (the old
+    threading.local tracer lost them), under the worker's thread ident."""
+    import threading
     runner, n = _make_runner(mini_batch_size=2, prefetch_depth=2, n=8)
-    with SpanTracer() as t:
-        out = runner.run_and_drain(n)
+    tr._SPAN_LOG.clear()
+    out = runner.run_and_drain(n)
     assert sum(b for _, b in out) == n
-    names = [e["name"] for e in t.events]
+    log = tr.span_log()
+    names = [name for name, *_ in log]
     assert names.count("runner.coerce") == 4
     assert names.count("runner.pad") == 4
+    assert names.count("runner.next") == 5      # the last finds it exhausted
+    # the counter counts the waits that brought a batch, as before the span
+    assert runner.counters.snapshot()["prefetch_wait"]["calls"] == 4
+    assert names.count("runner.h2d") == names.count("runner.dispatch") == 4
     assert "runner.run" in names and "runner.d2h" in names
+    me = threading.get_ident()
+    assert {t for name, t, *_ in log if name == "runner.coerce"} != {me}
+    assert {t for name, t, *_ in log if name == "runner.dispatch"} == {me}
+    # a stage's span and its counter come from one statement
+    stages = runner.counters.snapshot()
+    assert stages["h2d"]["calls"] == 4 and stages["coerce"]["calls"] == 4
 
 
 def test_prefetch_worker_spans_join_request_trace():
@@ -203,7 +214,8 @@ def test_prefetch_worker_spans_join_request_trace():
     assert {s.thread for s in coerce} != {root.thread}
     events = [e["name"] for s in spans for e in s.events]
     assert "pad_bucket" in events
-    assert "cache_hit" in events or "cache_miss" in events
+    # the first batch compiled; a warm batch is not an event any more
+    assert "cache_miss" in events and "cache_hit" not in events
 
 
 # ---------------------------------------------------------------------------
