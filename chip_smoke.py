@@ -29,6 +29,7 @@ import functools
 import glob
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -97,6 +98,74 @@ def tick_text(decoder, compiled=False):
     return lowered.compile().as_text() if compiled else lowered.as_text()
 
 
+def pool_programs(cfg, audit, shape=None):
+    """``({program: lowered}, pool buffer shapes)``: the decode tick, one
+    chunked-prefill extension and one group insertion as an engine of
+    ``audit["slots"]`` slots compiles them, lowered at abstract shapes (no
+    weights, no pool on the device). ``shape(dims, dtype)`` makes an
+    argument's shape; a test passes one that places it on a described chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.zoo.transformer import init_transformer
+    from mmlspark_tpu.serving import continuous as progs
+    from mmlspark_tpu.serving.kv_pool import PagedKVPool
+    shape = shape or jax.ShapeDtypeStruct
+    slots, max_len, page = audit["slots"], audit["max_len"], audit["page"]
+    per_slot = -(-max_len // page)
+    params = jax.tree.map(
+        lambda a: shape(a.shape, cfg.dtype),
+        jax.eval_shape(lambda: init_transformer(cfg, seed=0)))
+    pool = PagedKVPool(cfg, page_size=page, residency=False, make_buffer=shape,
+                       num_pages=1 + slots * per_slot + max(per_slot, slots))
+    i32, f32, hd = jnp.int32, jnp.float32, cfg.d_model // cfg.heads
+    ints = lambda *dims: shape(dims, i32)                   # noqa: E731
+    g, n = audit["group"], audit["rows_len"]
+    rows = [{kk: shape((g, cfg.heads, n, hd), cfg.dtype) for kk in "kv"}
+            for _ in range(cfg.layers)]
+    sample = lambda b: (shape((b,), f32), ints(b), shape((b,), f32),  # noqa: E731
+                        shape((b, 2), jnp.uint32))
+    lowered = {
+        "jit_tick": progs._tick_program(
+            cfg, page, max_len, 1, None, False, True).lower(
+                params, ints(slots), ints(slots), shape((slots,), bool),
+                pool.buffers, ints(slots, per_slot), ints(slots)),
+        "jit__extend": progs._extend_program(cfg, page, max_len, True).lower(
+            params, ints(1, audit["chunk"]), ints(1), pool.buffers,
+            ints(1, per_slot)),
+        "jit__insert_group": progs._insert_group_program(page, True).lower(
+            pool.buffers, [], ints(g), rows, [], ints(g, -(-n // page)),
+            ints(slots), ints(slots), shape((slots,), bool), ints(slots),
+            ints(g), ints(g), ints(g), sample(slots), sample(g))}
+    return lowered, {(b.dtype.name, b.shape)
+                     for c in pool.buffers for b in c.values() if b.ndim == 4}
+
+
+_COPY = re.compile(r"= (\w+)\[([\d,]+)\]\S* (?:copy|copy-start)\(")
+
+
+def pool_copies(text, pool_shapes):
+    """The ``copy`` instructions of an optimised HLO module whose result has
+    the shape of a page-pool buffer: the pool copied, or laid out anew, by a
+    program that was to update it in place."""
+    names = {"bfloat16": "bf16", "float32": "f32", "int8": "s8",
+             "float8_e4m3fn": "f8e4m3fn"}
+    want = {(names.get(dt, dt), ",".join(map(str, dims)))
+            for dt, dims in pool_shapes}
+    return [line.strip() for line in text.splitlines()
+            if (m := _COPY.search(line)) and m.groups() in want]
+
+
+def phase_pool_in_place(sz, small):
+    """Compile the three programs that carry the pool at GPT-2 XL's shapes
+    and count the pool-sized copies in each: the in-place property's guard
+    on the chip. On the CPU (``--small``) nothing is donated and the count
+    says nothing, so it is printed and not required."""
+    lowered, shapes = pool_programs(sz["pool_decoder"], sz["pool_audit"])
+    return {name: len(pool_copies(low.compile().as_text(), shapes))
+            for name, low in lowered.items()}
+
+
 # ---------------------------------------------------------------------------
 # sizes: the real ones, and the toy ones of --small
 
@@ -116,6 +185,11 @@ def sizes(small):
                                       position="rope", dtype=jnp.bfloat16),
             slots=4, max_len=128, max_new=6,
             engine_kw=dict(prefill_chunk=16),
+            pool_decoder=TransformerConfig(vocab=256, layers=2, d_model=64,
+                                           heads=4, d_ff=128, max_len=64,
+                                           causal=True, dtype=jnp.bfloat16),
+            pool_audit=dict(slots=2, max_len=64, page=16, chunk=16, group=2,
+                            rows_len=32),
             prompt_lens=[5, 5, 9, 9, 12, 12, 40, 40],
             gbdt_rows=4096, gbdt_test=1024, gbdt_bins=255, gbdt_iters=5)
     return dict(
@@ -127,6 +201,15 @@ def sizes(small):
                                   causal=True, norm="rmsnorm",
                                   position="rope", dtype=jnp.bfloat16),
         slots=16, max_len=2048, max_new=32, engine_kw={},
+        # the generation cell's decoder and engine (benchmarks/configs/
+        # gpt2_xl.json, workloads/gpt2xl_generate_closed.json): GPT-2 XL,
+        # 8 slots of 1024 positions in pages of 16, 256-token chunks
+        pool_decoder=TransformerConfig(vocab=50257, layers=48, d_model=1600,
+                                       heads=25, d_ff=6400, max_len=1024,
+                                       causal=True, norm="layernorm",
+                                       position="learned", dtype=jnp.bfloat16),
+        pool_audit=dict(slots=8, max_len=1024, page=16, chunk=256, group=2,
+                        rows_len=128),
         # one group is longer than the engine's default prefill_chunk (256)
         prompt_lens=[12, 12, 12, 64, 64, 64, 300, 300],
         gbdt_rows=1_000_000, gbdt_test=100_000, gbdt_bins=255, gbdt_iters=5)
@@ -369,7 +452,12 @@ def phase_decode(sz, seed, small):
     ck.require(quant._attn_impl == "kernel" and (q_compiled or small),
                "int8 decoder did not run the compiled paged kernel")
     ck.require(all(len(g) == max_new for g in q_got), "short int8 outputs")
+    t0 = time.perf_counter()
+    copies = phase_pool_in_place(sz, small)
+    ck.require(small or not any(copies.values()),
+               f"programs copy a pool-sized buffer: {copies}")
     return ck, dict(
+        pool_copies=copies, pool_copies_s=time.perf_counter() - t0,
         setup_s=setup_s, run_s=run_s, oracle_s=oracle_s, int8_s=int8_s,
         requests=len(prompts), max_new=max_new,
         prompt_lens=sz["prompt_lens"], slots=sz["slots"], paged_attn=impl,

@@ -699,8 +699,8 @@ def decode_window_ragged(params: Dict, tokens: jnp.ndarray,
 
 
 # ---- paged KV cache (vLLM-style PagedAttention, XLA-level) -----------------
-# The physical cache is a pool of fixed-size PAGES — per layer a
-# (num_pages, H, page_size, hd) buffer pair — and each batch row owns a
+# The physical cache is a pool of fixed-size PAGES — per layer one
+# (num_pages, H, page_size, 2*hd) buffer, K beside V — and each batch row owns a
 # BLOCK TABLE row mapping its logical pages to physical ones. A decode/
 # window step gathers the row's pages into the familiar contiguous
 # (B, H, L, hd) layout, runs the EXACT ragged-step math on it (reusing
@@ -723,22 +723,22 @@ def decode_window_ragged(params: Dict, tokens: jnp.ndarray,
 
 def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                      page_size: int, kv_dtype=None):
-    """Per-layer (num_pages, H, page_size, hd) k/v page pools (page 0 is
-    the trash page — allocators must never hand it out). With
+    """Per-layer page pools (page 0 is the trash page — allocators must
+    never hand it out): one ``(num_pages, H, page_size, 2*hd)`` buffer
+    ``"kv"`` a layer, K in ``[..., :hd]`` and V in ``[..., hd:]``
+    (``ops.paged_attention.pack_kv``; why: that module's docstring). With
     ``kv_dtype`` ("int8"/"fp8") pages store quantized values and each
     layer dict gains ``(num_pages, H, page_size)`` ``k_scale``/
     ``v_scale`` arrays (see ``ops/kv_quant.py``)."""
     from ...ops.kv_quant import SCALE_DTYPE, kv_store_dtype
     hd = cfg.d_model // cfg.heads
-    shape = (num_pages, cfg.heads, page_size, hd)
+    shape = (num_pages, cfg.heads, page_size, 2 * hd)
     store = kv_store_dtype(kv_dtype)
     if store is None:
-        return [{"k": jnp.zeros(shape, cfg.dtype),
-                 "v": jnp.zeros(shape, cfg.dtype)}
+        return [{"kv": jnp.zeros(shape, cfg.dtype)}
                 for _ in range(cfg.layers)]
     sshape = shape[:3]
-    return [{"k": jnp.zeros(shape, store),
-             "v": jnp.zeros(shape, store),
+    return [{"kv": jnp.zeros(shape, store),
              "k_scale": jnp.ones(sshape, SCALE_DTYPE),
              "v_scale": jnp.ones(sshape, SCALE_DTYPE)}
             for _ in range(cfg.layers)]
@@ -760,12 +760,13 @@ def paged_gather(cache_pages, block_tables, length: int, out_dtype=None):
     their gathered scales (in ``out_dtype``, default f32) — this is the
     oracle path the quant-error gauge measures the kernel against."""
     from ...ops.kv_quant import dequantize_kv
+    from ...ops.paged_attention import split_kv
     out = []
     for c in cache_pages:
         quant = _is_quant_cache(c)
+        halves = split_kv(c["kv"][block_tables])  # 2 x (B, P, H, page, hd)
         row = {}
-        for kk in ("k", "v"):
-            g = c[kk][block_tables]              # (B, P, H, page, hd)
+        for kk, g in zip(("k", "v"), halves):
             B, Pp, H, pg, hd = g.shape
             if quant:
                 s = c[kk + "_scale"][block_tables]   # (B, P, H, page)
@@ -778,6 +779,15 @@ def paged_gather(cache_pages, block_tables, length: int, out_dtype=None):
     return out
 
 
+def _pool_rows(c, k, v):
+    """``{key: rows}`` ready to store in the page-pool layer ``c`` from K
+    and V rows ``(..., hd)``: packed, and for a quantized pool quantized
+    with their scale rows alongside (``ops.paged_attention.stored_kv``)."""
+    from ...ops.paged_attention import stored_kv
+    keys = ("kv", "k_scale", "v_scale") if _is_quant_cache(c) else ("kv",)
+    return dict(zip(keys, stored_kv(k, v, *(c[kk] for kk in keys))))
+
+
 def paged_scatter_rows(cache_pages, rows, block_tables, page_size: int):
     """Write full contiguous (B, H, L, hd) k/v rows (a prefill output)
     into the pool through each row's block table. Logical pages past a
@@ -785,30 +795,20 @@ def paged_scatter_rows(cache_pages, rows, block_tables, page_size: int):
     their writes collide harmlessly there. Quantized pools quantize each
     position through the sanctioned ``quantize_kv`` and scatter the
     per-head scales alongside."""
-    from ...ops.kv_quant import quantize_kv
     n_pages = (rows[0]["k"].shape[2] + page_size - 1) // page_size
     dest = block_tables[:, :n_pages].reshape(-1)         # (B*n_pages,)
-    out = []
-    for c, rc in zip(cache_pages, rows):
-        quant = _is_quant_cache(c)
-        row = {}
-        for kk in ("k", "v"):
-            r = rc[kk]                                   # (B, H, L, hd)
-            B, H, L, hd = r.shape
-            r = jnp.pad(r, ((0, 0), (0, 0),
-                            (0, n_pages * page_size - L), (0, 0)))
-            r = r.reshape(B, H, n_pages, page_size, hd)
-            r = r.transpose(0, 2, 1, 3, 4).reshape(
-                B * n_pages, H, page_size, hd)
-            if quant:
-                q, sc = quantize_kv(r, c[kk].dtype)
-                row[kk] = c[kk].at[dest].set(q)
-                row[kk + "_scale"] = c[kk + "_scale"].at[dest].set(
-                    sc.astype(c[kk + "_scale"].dtype))
-            else:
-                row[kk] = c[kk].at[dest].set(r)
-        out.append(row)
-    return out
+
+    def paged(r):                # (B, H, L, hd) -> (B*n_pages, H, page, hd)
+        B, H, L, hd = r.shape
+        r = jnp.pad(r, ((0, 0), (0, 0),
+                        (0, n_pages * page_size - L), (0, 0)))
+        r = r.reshape(B, H, n_pages, page_size, hd)
+        return r.transpose(0, 2, 1, 3, 4).reshape(
+            B * n_pages, H, page_size, hd)
+
+    return [{kk: c[kk].at[dest].set(new) for kk, new in _pool_rows(
+                c, paged(rc["k"]), paged(rc["v"])).items()}
+            for c, rc in zip(cache_pages, rows)]
 
 
 def _paged_writeback(cache_pages, new_cache, block_tables, wpos,
@@ -820,31 +820,21 @@ def _paged_writeback(cache_pages, new_cache, block_tables, wpos,
     reference pages that were freed and reallocated to another request.
     Quantized pools write ``quantize_kv``'d bytes plus scales — the same
     helper every other writer uses, so the bytes agree bit-for-bit."""
-    from ...ops.kv_quant import quantize_kv
     B, W = wpos.shape
     phys = jnp.take_along_axis(block_tables, wpos // page_size, axis=1)
     if active is not None:
         phys = jnp.where(active[:, None], phys, 0)
     pf = phys.reshape(-1)
     of = (wpos % page_size).reshape(-1)
-    out = []
-    for c, nc in zip(cache_pages, new_cache):
-        quant = _is_quant_cache(c)
-        row = {}
-        for kk in ("k", "v"):
-            vals = jnp.take_along_axis(
-                nc[kk], wpos[:, None, :, None], axis=2)  # (B, H, W, hd)
-            H, hd = vals.shape[1], vals.shape[3]
-            vals = vals.transpose(0, 2, 1, 3).reshape(B * W, H, hd)
-            if quant:
-                q, sc = quantize_kv(vals, c[kk].dtype)
-                row[kk] = c[kk].at[pf, :, of].set(q)
-                row[kk + "_scale"] = c[kk + "_scale"].at[pf, :, of].set(
-                    sc.astype(c[kk + "_scale"].dtype))
-            else:
-                row[kk] = c[kk].at[pf, :, of].set(vals)
-        out.append(row)
-    return out
+
+    def written(t):              # (B, H, L, hd) -> (B*W, H, hd)
+        vals = jnp.take_along_axis(t, wpos[:, None, :, None], axis=2)
+        H, hd = vals.shape[1], vals.shape[3]
+        return vals.transpose(0, 2, 1, 3).reshape(B * W, H, hd)
+
+    return [{kk: c[kk].at[pf, :, of].set(new) for kk, new in _pool_rows(
+                c, written(nc["k"]), written(nc["v"])).items()}
+            for c, nc in zip(cache_pages, new_cache)]
 
 
 def _decode_window_paged_kernel(params: Dict, tokens: jnp.ndarray,
@@ -886,20 +876,13 @@ def _decode_window_paged_kernel(params: Dict, tokens: jnp.ndarray,
         if cfg.position == "rope":
             q = _rot_half(q, cos, sin)
             k = _rot_half(k, cos, sin)
-        if _is_quant_cache(c):
-            ctx, kp, vp, ks, vs = paged_attention_window(
-                q, k.astype(dt), v.astype(dt), c["k"], c["v"],
-                block_tables, pos, active=active,
-                k_scale=c["k_scale"], v_scale=c["v_scale"], mesh=mesh,
-                slot_axis=slot_axis, head_axis=head_axis)
-            new_pages.append({"k": kp, "v": vp,
-                              "k_scale": ks, "v_scale": vs})
-        else:
-            ctx, kp, vp = paged_attention_window(
-                q, k.astype(dt), v.astype(dt), c["k"], c["v"],
-                block_tables, pos, active=active, mesh=mesh,
-                slot_axis=slot_axis, head_axis=head_axis)
-            new_pages.append({"k": kp, "v": vp})
+        scales = ({"k_scale": c["k_scale"], "v_scale": c["v_scale"]}
+                  if _is_quant_cache(c) else {})
+        ctx, *pools = paged_attention_window(
+            q, k.astype(dt), v.astype(dt), c["kv"], block_tables, pos,
+            active=active, mesh=mesh, slot_axis=slot_axis,
+            head_axis=head_axis, **scales)
+        new_pages.append(dict(zip(("kv", *scales), pools)))
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, W, cfg.d_model)
         h = h + ctx @ lp["out"]["w"].astype(dt) + lp["out"]["b"].astype(dt)
         x = _norm(h.astype(jnp.float32), lp["ln2"], cfg).astype(dt)

@@ -14,9 +14,24 @@ materialization.
 
 Design notes (TPU-first):
 
+* THE POOL'S LAYOUT: one buffer a layer, ``(num_pages, H, page, 2*hd)``,
+  K in lanes ``[0, hd)`` and V in ``[hd, 2*hd)`` of the minor axis
+  (:func:`pack_kv` / :func:`split_kv`). Not two ``(N, H, page, hd)``
+  buffers: at ``hd = 64`` their minor axis is half a 128-lane vector
+  register, the TPU keeps such an array with the PAGE INDEX minor-most
+  (``{0,3,2,1}``: row-major would pad every row to twice its bytes) and
+  the Mosaic call takes row-major operands only, so XLA relaid the whole
+  pool in and out of every call that aliased it: four pool-sized copies
+  a layer in the tick, in a prefill chunk and in an insertion, 69% of the
+  generation cell's device time (PERF.md, PR 28). Packed, the minor axis
+  is a whole register at every ``hd`` that is a multiple of 64, the pool
+  stays row-major on the device, and the aliased call and the donated
+  programs update it in place. The ``(N, H, page)`` scale pools of a
+  quantized pool keep their shape (1/64 of the bytes).
 * grid = (B, P_max) with the page sweep innermost. Blocks carry the full
-  head dimension — a page block is ``(1, H, page, hd)`` — so each page is
-  DMA'd ONCE per row per layer, not once per head.
+  head dimension — a page block is ``(1, H, page, 2*hd)``, K and V of one
+  page in one DMA — so each page is DMA'd ONCE per row per layer, not once
+  per head; the kernel splits the block on the lane axis in VMEM.
 * the physical page for grid step ``(b, p)`` comes from a
   scalar-prefetched block table: the BlockSpec index_map reads
   ``bt[b, p]`` (``PrefetchScalarGridSpec``), which is exactly the
@@ -30,12 +45,12 @@ Design notes (TPU-first):
   rather than NaN.
 * the FUSED variant (:func:`paged_attention_window`) also scatters the
   window's fresh K/V rows into their pages in the same launch, replacing
-  the separate per-tick writeback. The window rows ride along as direct
-  ``(B, H, W, hd)`` inputs folded into the online softmax under an
-  in-window causal mask, so pages only ever supply keys strictly before
+  the separate per-tick writeback. The window rows ride along as one
+  direct ``(B, H, W, 2*hd)`` input, packed like a page, folded into the
+  online softmax under an in-window causal mask, so pages only ever supply keys strictly before
   ``pos[b]`` — reading each page's *pre-scatter* content is therefore
   exact. The scatter itself goes through ``input_output_aliases``: the
-  page-pool outputs alias the inputs and their index_map redirects every
+  page-pool output aliases the input and its index_map redirects every
   page outside the row's write range to trash page 0, so Pallas's
   write-on-index-change semantics make the real page writes O(1) per row
   instead of O(context).
@@ -63,7 +78,7 @@ Design notes (TPU-first):
   kernel's in-launch scatter.
 
 Tiling contract: the page dimension sits in the SUBLANE slot of the
-``(1, H, page, hd)`` block, so on a real TPU ``page_size`` must be a
+``(1, H, page, 2*hd)`` block, so on a real TPU ``page_size`` must be a
 multiple of the dtype's sublane tile — 8 (f32), 16 (bf16), 32 (int8);
 see :func:`sublane_multiple` / :func:`aligned_page_size` and
 ``PagedKVPool.kernel_aligned_page_size``. Interpret mode (the CI path on
@@ -88,7 +103,8 @@ from .pallas_kernels import _LANE, _round_up
 from .kv_quant import quantize_kv
 
 __all__ = ["paged_attention", "paged_attention_window", "resolve_impl",
-           "sublane_multiple", "aligned_page_size"]
+           "sublane_multiple", "aligned_page_size", "pack_kv", "split_kv",
+           "stored_kv"]
 
 _NEG = -1e30
 
@@ -120,7 +136,7 @@ def resolve_impl(override: Optional[str] = None) -> str:
 
 def sublane_multiple(dtype) -> int:
     """The TPU sublane tile for ``dtype`` — the unit ``page_size`` must
-    divide into for the kernel's ``(1, H, page, hd)`` page blocks."""
+    divide into for the kernel's ``(1, H, page, 2*hd)`` page blocks."""
     itemsize = jnp.dtype(dtype).itemsize
     return max(8, 32 // max(1, itemsize))
 
@@ -139,6 +155,18 @@ def _auto_interpret() -> bool:
 def _vmem(shape, dtype):
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.VMEM(shape, dtype)
+
+
+def pack_kv(k, v):
+    """K and V ``(..., hd)`` side by side on the minor axis,
+    ``(..., 2*hd)``: the layout of a page-pool buffer (module docstring)."""
+    return jnp.concatenate([k, v], axis=-1)
+
+
+def split_kv(kv):
+    """The ``(k, v)`` halves of a packed ``(..., 2*hd)`` array."""
+    hd = kv.shape[-1] // 2
+    return kv[..., :hd], kv[..., hd:]
 
 
 def _fold(m_scr, l_scr, acc_scr, s, valid, v):
@@ -170,323 +198,179 @@ def _finalize(o_ref, l_scr, acc_scr):
                 jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def _page_scores(q, kp_ref, scale):
-    kp = kp_ref[0].astype(jnp.float32)                  # (H, page, hd)
-    return jax.lax.dot_general(
-        q, kp, (((2,), (2,)), ((0,), (0,))),
+def _page_kv(kv_ref, ks_ref=None, vs_ref=None):
+    """One ``(1, H, page, 2*hd)`` page block as float32 ``(k, v)``, each
+    ``(H, page, hd)``. With the page's two ``(1, H, page)`` scale blocks
+    this is the IN-KERNEL dequant: they arrived through the same
+    block-table index_map, so the multiply happens in VMEM right after
+    the page DMA and the quantized bytes are all HBM ever moves."""
+    k, v = split_kv(kv_ref[0])
+    k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+    if ks_ref is not None:
+        k = k * ks_ref[0].astype(jnp.float32)[:, :, None]
+        v = v * vs_ref[0].astype(jnp.float32)[:, :, None]
+    return k, v
+
+
+def _init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, _NEG)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _pages_fold(m_scr, l_scr, acc_scr, q_ref, kv, p, bound, scale, page):
+    """Fold page ``p``'s keys ``p*page ..`` strictly below ``bound``."""
+    k, v = kv
+    q = q_ref[0].astype(jnp.float32)                    # (H, W, hd)
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale     # (H, W, page)
+    t = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
+    _fold(m_scr, l_scr, acc_scr, s, t < bound, v)
 
 
-def _deq_block(p_ref, s_ref):
-    """Dequantize one (1, H, page, hd) page block with its (1, H, page)
-    scale block — the IN-KERNEL dequant: both blocks arrived through the
-    same block-table index_map, so this multiply happens in VMEM right
-    after the page DMA and the quantized bytes are all HBM ever moves."""
-    return (p_ref[0].astype(jnp.float32) *
-            s_ref[0].astype(jnp.float32)[:, :, None])   # (H, page, hd)
-
-
-def _page_scores_q(q, kp_ref, ks_ref, scale):
-    return jax.lax.dot_general(
-        q, _deq_block(kp_ref, ks_ref), (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale     # (H, W, page)
-
-
-def _pa_read_kernel(bt_ref, len_ref, q_ref, kp_ref, vp_ref, o_ref,
-                    m_scr, l_scr, acc_scr, *, scale, page, n_pages):
-    """One (b, p) grid step of the read-only page sweep: attend the
-    queries over page ``p``'s keys, bounded by ``len_ref[b]``."""
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    bound = len_ref[b]
-
-    @pl.when(p * page < bound)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                # (H, W, hd)
-        s = _page_scores(q, kp_ref, scale)
-        t = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page), 2)
-        _fold(m_scr, l_scr, acc_scr, s, t < bound,
-              vp_ref[0].astype(jnp.float32))
-
-    @pl.when(p == n_pages - 1)
-    def _fin():
-        _finalize(o_ref, l_scr, acc_scr)
-
-
-def _pa_fused_kernel(bt_ref, pos_ref, wlo_ref, whi_ref, q_ref, kn_ref,
-                     vn_ref, kp_ref, vp_ref, o_ref, ko_ref, vo_ref,
-                     m_scr, l_scr, acc_scr, *, scale, page, W, n_pages):
-    """One (b, p) grid step of the fused decode-window sweep.
-
-    Page keys are masked STRICTLY below ``pos[b]`` — the window's own
-    rows arrive as the direct (H, W, hd) ``kn``/``vn`` inputs, folded
-    once at p == 0 under the in-window causal mask, so the page blocks
-    are always read pre-scatter. Pages inside the row's write range get
-    their fresh rows overlaid and written back through the aliased
-    page-pool outputs; every other grid step leaves its (trash-directed)
-    output block untouched."""
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    pos = pos_ref[b]
-    Wp = q_ref.shape[2]
-
-    @pl.when(p == 0)
-    def _init_and_window():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        q = q_ref[0].astype(jnp.float32)                # (H, Wp, hd)
-        kn = kn_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kn, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # (H, Wp, Wp)
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 1)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 2)
-        # query j sees window keys j' <= j; padding key rows never
-        # (padding QUERY rows keep every real key — they need a nonzero
-        # denominator and their output is sliced off host-side)
-        valid = jnp.logical_and(
-            jnp.logical_or(col <= row, row >= W), col < W)
-        _fold(m_scr, l_scr, acc_scr, s, valid,
-              vn_ref[0].astype(jnp.float32))
-
-    @pl.when(p * page < pos)
-    def _pages():
-        q = q_ref[0].astype(jnp.float32)
-        s = _page_scores(q, kp_ref, scale)
-        t = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page), 2)
-        _fold(m_scr, l_scr, acc_scr, s, t < pos,
-              vp_ref[0].astype(jnp.float32))
-
-    in_write_range = jnp.logical_and(p >= wlo_ref[b], p <= whi_ref[b])
-
-    @pl.when(in_write_range)
-    def _scatter():
-        # overlay the window rows that land in THIS page, in the pool
-        # dtype (no f32 round-trip: the written bytes are bit-identical
-        # to _paged_writeback's)
-        kblk = kp_ref[0]                                # (H, page, hd)
-        vblk = vp_ref[0]
-        ridx = jax.lax.broadcasted_iota(jnp.int32, (1, page, 1), 1)
-        for j in range(W):                              # W static, small
-            tgt = pos + j - p * page
-            hit = ridx == tgt                           # all-False if out
-            kblk = jnp.where(hit, kn_ref[0, :, j:j + 1, :], kblk)
-            vblk = jnp.where(hit, vn_ref[0, :, j:j + 1, :], vblk)
-        ko_ref[0] = kblk
-        vo_ref[0] = vblk
-
-    @pl.when(p == n_pages - 1)
-    def _fin():
-        _finalize(o_ref, l_scr, acc_scr)
-
-
-def _pa_window_kernel(bt_ref, pos_ref, q_ref, kn_ref, vn_ref, kp_ref,
-                      vp_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                      scale, page, W, n_pages):
-    """One (b, p) grid step of the READ-ONLY decode-window sweep — the
-    shard_map-mounted variant. Identical online-softmax math to
-    :func:`_pa_fused_kernel` (window rows folded once at p == 0 under
-    the in-window causal mask, pages masked strictly below ``pos[b]``),
-    minus the in-kernel page scatter: under a mesh the fresh rows are
-    written outside the mount (:func:`_pool_write_rows`), so only two
-    scalar-prefetch operands (block table, pos) remain and no output
-    aliases the pool."""
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    pos = pos_ref[b]
-    Wp = q_ref.shape[2]
-
-    @pl.when(p == 0)
-    def _init_and_window():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        q = q_ref[0].astype(jnp.float32)                # (H, Wp, hd)
-        kn = kn_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kn, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # (H, Wp, Wp)
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 1)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 2)
-        valid = jnp.logical_and(
-            jnp.logical_or(col <= row, row >= W), col < W)
-        _fold(m_scr, l_scr, acc_scr, s, valid,
-              vn_ref[0].astype(jnp.float32))
-
-    @pl.when(p * page < pos)
-    def _pages():
-        q = q_ref[0].astype(jnp.float32)
-        s = _page_scores(q, kp_ref, scale)
-        t = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page), 2)
-        _fold(m_scr, l_scr, acc_scr, s, t < pos,
-              vp_ref[0].astype(jnp.float32))
-
-    @pl.when(p == n_pages - 1)
-    def _fin():
-        _finalize(o_ref, l_scr, acc_scr)
-
-
-# ---- quantized kernels ------------------------------------------------------
-#
-# Same grid, same online-softmax state, same masks as the bf16 kernels
-# above — the only differences are (a) two extra (1, H, page) scale
-# blocks riding the SAME block-table index_map as their page blocks,
-# dequantized in VMEM by _deq_block before the dot, and (b) the fused
-# variant's in-kernel scatter quantizing each window row through
-# quantize_kv (the sanctioned helper — bit-identical to what
-# _pool_write_rows/_paged_writeback write, so every writer agrees).
-
-def _pa_read_kernel_q(bt_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref,
-                      vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                      scale, page, n_pages):
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    bound = len_ref[b]
-
-    @pl.when(p * page < bound)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                # (H, W, hd)
-        s = _page_scores_q(q, kp_ref, ks_ref, scale)
-        t = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page), 2)
-        _fold(m_scr, l_scr, acc_scr, s, t < bound,
-              _deq_block(vp_ref, vs_ref))
-
-    @pl.when(p == n_pages - 1)
-    def _fin():
-        _finalize(o_ref, l_scr, acc_scr)
-
-
-def _window_fold(m_scr, l_scr, acc_scr, q_ref, kn_ref, vn_ref, scale, W):
-    """The p == 0 window fold shared by the fused/window kernels: fresh
-    rows arrive unquantized (they are direct inputs, not pages), folded
-    under the in-window causal mask."""
+def _window_fold(m_scr, l_scr, acc_scr, q_ref, kvn_ref, scale, W):
+    """The p == 0 window fold shared by the fused/window kernels: the
+    fresh rows arrive unquantized and packed like a page
+    (``(1, H, Wp, 2*hd)``: direct inputs, not pages), folded under the
+    in-window causal mask."""
     Wp = q_ref.shape[2]
     q = q_ref[0].astype(jnp.float32)                    # (H, Wp, hd)
-    kn = kn_ref[0].astype(jnp.float32)
+    kn, vn = split_kv(kvn_ref[0])
     s = jax.lax.dot_general(
-        q, kn, (((2,), (2,)), ((0,), (0,))),
+        q, kn.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale     # (H, Wp, Wp)
     row = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 1)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 2)
+    # query j sees window keys j' <= j; padding key rows never
+    # (padding QUERY rows keep every real key — they need a nonzero
+    # denominator and their output is sliced off host-side)
     valid = jnp.logical_and(
         jnp.logical_or(col <= row, row >= W), col < W)
-    _fold(m_scr, l_scr, acc_scr, s, valid,
-          vn_ref[0].astype(jnp.float32))
+    _fold(m_scr, l_scr, acc_scr, s, valid, vn.astype(jnp.float32))
 
 
-def _pa_fused_kernel_q(bt_ref, pos_ref, wlo_ref, whi_ref, q_ref, kn_ref,
-                       vn_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref,
-                       ko_ref, vo_ref, kso_ref, vso_ref,
-                       m_scr, l_scr, acc_scr, *, scale, page, W, n_pages):
+def _overlay(blk, new_ref, pos, p, page, W, ridx):
+    """``blk`` (one page of a pool buffer, page positions on axis 1) with
+    the window rows that land in page ``p`` laid over it, in the pool's
+    dtype: no float32 round-trip, so the written bytes are bit-identical
+    to ``_paged_writeback``'s. ``ridx`` is the iota over axis 1."""
+    for j in range(W):                                  # W static, small
+        hit = ridx == pos + j - p * page                # all-False if out
+        blk = jnp.where(hit, new_ref[0, :, j:j + 1], blk)
+    return blk
+
+
+# Four kernels, one contract. Grid (b, p), page sweep innermost; the page
+# block is (1, H, page, 2*hd), K in lanes [0, hd) and V in [hd, 2*hd); a
+# quantized pool adds two (1, H, page) scale blocks riding the SAME
+# block-table index_map (``quant``: they follow the page block among the
+# operands). The *read* kernel attends ``len_ref[b]`` cached keys; the
+# *window* kernel folds the window's own rows once at p == 0 and masks
+# page keys STRICTLY below ``pos[b]``, so page blocks are always read
+# pre-scatter; the two *fused* kernels (plain and quantized pages) also
+# write the window's rows into their pages through the aliased pool
+# outputs (every grid step outside the row's write range leaves its
+# trash-directed output block untouched). The mesh mount runs the window
+# kernel: under a mesh the fresh rows are written outside the mount
+# (:func:`_pool_write_rows`).
+
+def _pa_read_kernel(bt_ref, len_ref, q_ref, kv_ref, *rest,
+                    scale, page, n_pages, quant):
     from jax.experimental import pallas as pl
 
-    b = pl.program_id(0)
-    p = pl.program_id(1)
+    scales, (o_ref, m_scr, l_scr, acc_scr) = rest[:2 * quant], rest[2 * quant:]
+    b, p = pl.program_id(0), pl.program_id(1)
+    pl.when(p == 0)(lambda: _init(m_scr, l_scr, acc_scr))
+    bound = len_ref[b]
+
+    @pl.when(p * page < bound)
+    def _compute():
+        _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref, *scales),
+                    p, bound, scale, page)
+
+    pl.when(p == n_pages - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
+
+
+def _pa_window_kernel(bt_ref, pos_ref, q_ref, kvn_ref, kv_ref, *rest,
+                      scale, page, W, n_pages, quant):
+    from jax.experimental import pallas as pl
+
+    scales, (o_ref, m_scr, l_scr, acc_scr) = rest[:2 * quant], rest[2 * quant:]
+    b, p = pl.program_id(0), pl.program_id(1)
     pos = pos_ref[b]
 
     @pl.when(p == 0)
     def _init_and_window():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        _window_fold(m_scr, l_scr, acc_scr, q_ref, kn_ref, vn_ref,
-                     scale, W)
+        _init(m_scr, l_scr, acc_scr)
+        _window_fold(m_scr, l_scr, acc_scr, q_ref, kvn_ref, scale, W)
 
     @pl.when(p * page < pos)
     def _pages():
-        q = q_ref[0].astype(jnp.float32)
-        s = _page_scores_q(q, kp_ref, ks_ref, scale)
-        t = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page), 2)
-        _fold(m_scr, l_scr, acc_scr, s, t < pos,
-              _deq_block(vp_ref, vs_ref))
+        _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref, *scales),
+                    p, pos, scale, page)
 
-    in_write_range = jnp.logical_and(p >= wlo_ref[b], p <= whi_ref[b])
+    pl.when(p == n_pages - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
 
-    @pl.when(in_write_range)
+
+def _pa_fused_kernel(bt_ref, pos_ref, wlo_ref, whi_ref, q_ref, kvn_ref,
+                     kv_ref, o_ref, kvo_ref, m_scr, l_scr, acc_scr, *,
+                     scale, page, W, n_pages):
+    from jax.experimental import pallas as pl
+
+    b, p = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+
+    @pl.when(p == 0)
+    def _init_and_window():
+        _init(m_scr, l_scr, acc_scr)
+        _window_fold(m_scr, l_scr, acc_scr, q_ref, kvn_ref, scale, W)
+
+    @pl.when(p * page < pos)
+    def _pages():
+        _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref),
+                    p, pos, scale, page)
+
+    @pl.when(jnp.logical_and(p >= wlo_ref[b], p <= whi_ref[b]))
     def _scatter():
-        kblk = kp_ref[0]                                # (H, page, hd)
-        vblk = vp_ref[0]
-        ksblk = ks_ref[0]                               # (H, page)
-        vsblk = vs_ref[0]
+        ridx = jax.lax.broadcasted_iota(jnp.int32, (1, page, 1), 1)
+        kvo_ref[0] = _overlay(kv_ref[0], kvn_ref, pos, p, page, W, ridx)
+
+    pl.when(p == n_pages - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
+
+
+def _pa_fused_kernel_q(bt_ref, pos_ref, wlo_ref, whi_ref, q_ref, kvn_ref,
+                       kvq_ref, ksn_ref, vsn_ref, kv_ref, ks_ref, vs_ref,
+                       o_ref, kvo_ref, kso_ref, vso_ref,
+                       m_scr, l_scr, acc_scr, *, scale, page, W, n_pages):
+    """The quantized fused kernel folds the window's UNQUANTIZED rows
+    (``kvn``) and writes their quantized twins (``kvq`` with the per-head
+    scales ``ksn``/``vsn``, all through :func:`quantize_kv` in the
+    caller: the sanctioned helper, so every writer agrees bit for bit)."""
+    from jax.experimental import pallas as pl
+
+    b, p = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+
+    @pl.when(p == 0)
+    def _init_and_window():
+        _init(m_scr, l_scr, acc_scr)
+        _window_fold(m_scr, l_scr, acc_scr, q_ref, kvn_ref, scale, W)
+
+    @pl.when(p * page < pos)
+    def _pages():
+        _pages_fold(m_scr, l_scr, acc_scr, q_ref,
+                    _page_kv(kv_ref, ks_ref, vs_ref), p, pos, scale, page)
+
+    @pl.when(jnp.logical_and(p >= wlo_ref[b], p <= whi_ref[b]))
+    def _scatter():
         ridx = jax.lax.broadcasted_iota(jnp.int32, (1, page, 1), 1)
         sidx = jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        for j in range(W):                              # W static, small
-            tgt = pos + j - p * page
-            hit = ridx == tgt                           # all-False if out
-            shit = sidx == tgt
-            kq, ksc = quantize_kv(kn_ref[0, :, j, :], kblk.dtype)
-            vq, vsc = quantize_kv(vn_ref[0, :, j, :], vblk.dtype)
-            kblk = jnp.where(hit, kq[:, None, :], kblk)
-            vblk = jnp.where(hit, vq[:, None, :], vblk)
-            ksblk = jnp.where(shit, ksc[:, None].astype(ksblk.dtype), ksblk)
-            vsblk = jnp.where(shit, vsc[:, None].astype(vsblk.dtype), vsblk)
-        ko_ref[0] = kblk
-        vo_ref[0] = vblk
-        kso_ref[0] = ksblk
-        vso_ref[0] = vsblk
+        kvo_ref[0] = _overlay(kv_ref[0], kvq_ref, pos, p, page, W, ridx)
+        kso_ref[0] = _overlay(ks_ref[0], ksn_ref, pos, p, page, W, sidx)
+        vso_ref[0] = _overlay(vs_ref[0], vsn_ref, pos, p, page, W, sidx)
 
-    @pl.when(p == n_pages - 1)
-    def _fin():
-        _finalize(o_ref, l_scr, acc_scr)
-
-
-def _pa_window_kernel_q(bt_ref, pos_ref, q_ref, kn_ref, vn_ref, kp_ref,
-                        vp_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr,
-                        acc_scr, *, scale, page, W, n_pages):
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    pos = pos_ref[b]
-
-    @pl.when(p == 0)
-    def _init_and_window():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        _window_fold(m_scr, l_scr, acc_scr, q_ref, kn_ref, vn_ref,
-                     scale, W)
-
-    @pl.when(p * page < pos)
-    def _pages():
-        q = q_ref[0].astype(jnp.float32)
-        s = _page_scores_q(q, kp_ref, ks_ref, scale)
-        t = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page), 2)
-        _fold(m_scr, l_scr, acc_scr, s, t < pos,
-              _deq_block(vp_ref, vs_ref))
-
-    @pl.when(p == n_pages - 1)
-    def _fin():
-        _finalize(o_ref, l_scr, acc_scr)
+    pl.when(p == n_pages - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
 
 
 def _grid_spec(n_scalar, B, n_pages, in_specs, out_specs, H, Wp, hd):
@@ -520,284 +404,162 @@ def _compiler_params(interpret: bool):
         vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
+def _row_map(b, p, *_):
+    return (b, 0, 0, 0)
+
+
+def _srow_map(b, p, *_):
+    return (b, 0, 0)
+
+
+def _page_map(b, p, bt, *_):
+    return (bt[b, p], 0, 0, 0)
+
+
+def _scale_map(b, p, bt, *_):
+    return (bt[b, p], 0, 0)
+
+
+def _write_page(b, p, bt, pos_, wlo_, whi_):
+    # pages outside the row's write range redirect to trash page 0:
+    # Pallas only writes an output block back when its index CHANGES,
+    # so the real page-pool writes stay O(1) per row per layer
+    inr = jnp.logical_and(p >= wlo_[b], p <= whi_[b])
+    return jnp.where(inr, bt[b, p], 0)
+
+
+def _write_map(b, p, *scalars):
+    return (_write_page(b, p, *scalars), 0, 0, 0)
+
+
+def _swrite_map(b, p, *scalars):
+    return (_write_page(b, p, *scalars), 0, 0)
+
+
+def _block_specs(q, kv_pages, scales):
+    """``(row spec of q and the output, row spec of the packed window
+    rows, page spec, [scale spec] * len(scales))`` of one call."""
+    from jax.experimental import pallas as pl
+
+    _, H, Wp, hd = q.shape
+    page = kv_pages.shape[2]
+    return (pl.BlockSpec((1, H, Wp, hd), _row_map),
+            pl.BlockSpec((1, H, Wp, 2 * hd), _row_map),
+            pl.BlockSpec((1, H, page, 2 * hd), _page_map),
+            [pl.BlockSpec((1, H, page), _scale_map)] * len(scales))
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _pa_read_call(q, k_pages, v_pages, block_tables, lengths, *,
+def _pa_read_call(q, kv_pages, block_tables, lengths, *scales,
                   scale, interpret):
     from jax.experimental import pallas as pl
 
     B, H, Wp, hd = q.shape
-    page = k_pages.shape[2]
     n_pages = block_tables.shape[1]
-    kernel = functools.partial(_pa_read_kernel, scale=scale, page=page,
-                               n_pages=n_pages)
-
-    def _q_map(b, p, bt, lens):
-        return (b, 0, 0, 0)
-
-    def _page_map(b, p, bt, lens):
-        return (bt[b, p], 0, 0, 0)
-
-    def _o_map(b, p, bt, lens):
-        return (b, 0, 0, 0)
-
+    kernel = functools.partial(_pa_read_kernel, scale=scale,
+                               page=kv_pages.shape[2], n_pages=n_pages,
+                               quant=bool(scales))
+    row, _, pages, scale_specs = _block_specs(q, kv_pages, scales)
     call = pl.pallas_call(
         kernel,
-        grid_spec=_grid_spec(
-            2, B, n_pages,
-            in_specs=[
-                pl.BlockSpec((1, H, Wp, hd), _q_map),
-                pl.BlockSpec((1, H, page, hd), _page_map),
-                pl.BlockSpec((1, H, page, hd), _page_map),
-            ],
-            out_specs=pl.BlockSpec((1, H, Wp, hd), _o_map),
-            H=H, Wp=Wp, hd=hd),
+        grid_spec=_grid_spec(2, B, n_pages,
+                             in_specs=[row, pages, *scale_specs],
+                             out_specs=row, H=H, Wp=Wp, hd=hd),
         out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )
-    return call(block_tables, lengths, q, k_pages, v_pages)
+    return call(block_tables, lengths, q, kv_pages, *scales)
 
 
 @functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
-def _pa_fused_call(q, k_new, v_new, k_pages, v_pages, block_tables,
-                   pos, wlo, whi, *, W, scale, interpret):
+def _pa_window_read_call(q, kv_new, kv_pages, block_tables, pos, *scales,
+                         W, scale, interpret):
     from jax.experimental import pallas as pl
 
     B, H, Wp, hd = q.shape
-    page = k_pages.shape[2]
+    n_pages = block_tables.shape[1]
+    kernel = functools.partial(_pa_window_kernel, scale=scale,
+                               page=kv_pages.shape[2], W=W, n_pages=n_pages,
+                               quant=bool(scales))
+    row, new, pages, scale_specs = _block_specs(q, kv_pages, scales)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=_grid_spec(2, B, n_pages,
+                             in_specs=[row, new, pages, *scale_specs],
+                             out_specs=row, H=H, Wp=Wp, hd=hd),
+        out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+    )
+    return call(block_tables, pos, q, kv_new, kv_pages, *scales)
+
+
+@functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
+def _pa_fused_call(q, kv_new, kv_pages, block_tables, pos, wlo, whi, *,
+                   W, scale, interpret):
+    from jax.experimental import pallas as pl
+
+    B, H, Wp, hd = q.shape
+    page = kv_pages.shape[2]
     n_pages = block_tables.shape[1]
     kernel = functools.partial(_pa_fused_kernel, scale=scale, page=page,
                                W=W, n_pages=n_pages)
-
-    def _row_map(b, p, bt, pos_, wlo_, whi_):
-        return (b, 0, 0, 0)
-
-    def _page_map(b, p, bt, pos_, wlo_, whi_):
-        return (bt[b, p], 0, 0, 0)
-
-    def _write_map(b, p, bt, pos_, wlo_, whi_):
-        # pages outside the row's write range redirect to trash page 0:
-        # Pallas only writes an output block back when its index CHANGES,
-        # so the real page-pool writes stay O(1) per row per layer
-        inr = jnp.logical_and(p >= wlo_[b], p <= whi_[b])
-        return (jnp.where(inr, bt[b, p], 0), 0, 0, 0)
-
-    pool_shape = jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype)
+    row, new, pages, _ = _block_specs(q, kv_pages, ())
     call = pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
-            4, B, n_pages,
-            in_specs=[
-                pl.BlockSpec((1, H, Wp, hd), _row_map),   # q
-                pl.BlockSpec((1, H, Wp, hd), _row_map),   # k_new
-                pl.BlockSpec((1, H, Wp, hd), _row_map),   # v_new
-                pl.BlockSpec((1, H, page, hd), _page_map),  # k pages
-                pl.BlockSpec((1, H, page, hd), _page_map),  # v pages
-            ],
-            out_specs=[
-                pl.BlockSpec((1, H, Wp, hd), _row_map),
-                pl.BlockSpec((1, H, page, hd), _write_map),
-                pl.BlockSpec((1, H, page, hd), _write_map),
-            ],
+            4, B, n_pages, in_specs=[row, new, pages],
+            out_specs=[row, pl.BlockSpec((1, H, page, 2 * hd), _write_map)],
             H=H, Wp=Wp, hd=hd),
         out_shape=[jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
-                   pool_shape, pool_shape],
-        # operand indices COUNT the 4 scalar-prefetch args: k_pages is
-        # operand 7, v_pages operand 8 — aliased onto outputs 1/2 so the
-        # pool updates in place
-        input_output_aliases={7: 1, 8: 2},
+                   jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype)],
+        # operand indices COUNT the 4 scalar-prefetch args: the pool is
+        # operand 6, aliased onto output 1 so it updates in place
+        input_output_aliases={6: 1},
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )
-    return call(block_tables, pos, wlo, whi, q, k_new, v_new,
-                k_pages, v_pages)
+    return call(block_tables, pos, wlo, whi, q, kv_new, kv_pages)
 
 
 @functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
-def _pa_window_read_call(q, k_new, v_new, k_pages, v_pages, block_tables,
-                         pos, *, W, scale, interpret):
+def _pa_fused_call_q(q, kv_new, kvq_new, ks_new, vs_new, kv_pages, k_scale,
+                     v_scale, block_tables, pos, wlo, whi, *,
+                     W, scale, interpret):
     from jax.experimental import pallas as pl
 
     B, H, Wp, hd = q.shape
-    page = k_pages.shape[2]
-    n_pages = block_tables.shape[1]
-    kernel = functools.partial(_pa_window_kernel, scale=scale, page=page,
-                               W=W, n_pages=n_pages)
-
-    def _row_map(b, p, bt, pos_):
-        return (b, 0, 0, 0)
-
-    def _page_map(b, p, bt, pos_):
-        return (bt[b, p], 0, 0, 0)
-
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=_grid_spec(
-            2, B, n_pages,
-            in_specs=[
-                pl.BlockSpec((1, H, Wp, hd), _row_map),     # q
-                pl.BlockSpec((1, H, Wp, hd), _row_map),     # k_new
-                pl.BlockSpec((1, H, Wp, hd), _row_map),     # v_new
-                pl.BlockSpec((1, H, page, hd), _page_map),  # k pages
-                pl.BlockSpec((1, H, page, hd), _page_map),  # v pages
-            ],
-            out_specs=pl.BlockSpec((1, H, Wp, hd), _row_map),
-            H=H, Wp=Wp, hd=hd),
-        out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )
-    return call(block_tables, pos, q, k_new, v_new, k_pages, v_pages)
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _pa_read_call_q(q, k_pages, v_pages, k_scale, v_scale, block_tables,
-                    lengths, *, scale, interpret):
-    from jax.experimental import pallas as pl
-
-    B, H, Wp, hd = q.shape
-    page = k_pages.shape[2]
-    n_pages = block_tables.shape[1]
-    kernel = functools.partial(_pa_read_kernel_q, scale=scale, page=page,
-                               n_pages=n_pages)
-
-    def _q_map(b, p, bt, lens):
-        return (b, 0, 0, 0)
-
-    def _page_map(b, p, bt, lens):
-        return (bt[b, p], 0, 0, 0)
-
-    def _scale_map(b, p, bt, lens):
-        return (bt[b, p], 0, 0)
-
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=_grid_spec(
-            2, B, n_pages,
-            in_specs=[
-                pl.BlockSpec((1, H, Wp, hd), _q_map),
-                pl.BlockSpec((1, H, page, hd), _page_map),
-                pl.BlockSpec((1, H, page, hd), _page_map),
-                pl.BlockSpec((1, H, page), _scale_map),
-                pl.BlockSpec((1, H, page), _scale_map),
-            ],
-            out_specs=pl.BlockSpec((1, H, Wp, hd), _q_map),
-            H=H, Wp=Wp, hd=hd),
-        out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )
-    return call(block_tables, lengths, q, k_pages, v_pages,
-                k_scale, v_scale)
-
-
-@functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
-def _pa_fused_call_q(q, k_new, v_new, k_pages, v_pages, k_scale, v_scale,
-                     block_tables, pos, wlo, whi, *, W, scale, interpret):
-    from jax.experimental import pallas as pl
-
-    B, H, Wp, hd = q.shape
-    page = k_pages.shape[2]
+    page = kv_pages.shape[2]
     n_pages = block_tables.shape[1]
     kernel = functools.partial(_pa_fused_kernel_q, scale=scale, page=page,
                                W=W, n_pages=n_pages)
-
-    def _row_map(b, p, bt, pos_, wlo_, whi_):
-        return (b, 0, 0, 0)
-
-    def _page_map(b, p, bt, pos_, wlo_, whi_):
-        return (bt[b, p], 0, 0, 0)
-
-    def _scale_map(b, p, bt, pos_, wlo_, whi_):
-        return (bt[b, p], 0, 0)
-
-    def _write_map(b, p, bt, pos_, wlo_, whi_):
-        inr = jnp.logical_and(p >= wlo_[b], p <= whi_[b])
-        return (jnp.where(inr, bt[b, p], 0), 0, 0, 0)
-
-    def _swrite_map(b, p, bt, pos_, wlo_, whi_):
-        inr = jnp.logical_and(p >= wlo_[b], p <= whi_[b])
-        return (jnp.where(inr, bt[b, p], 0), 0, 0)
-
-    pool_shape = jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype)
+    row, new, pages, scale_specs = _block_specs(q, kv_pages,
+                                                (k_scale, v_scale))
+    srow = pl.BlockSpec((1, H, Wp), _srow_map)
+    swrite = pl.BlockSpec((1, H, page), _swrite_map)
     scale_shape = jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype)
     call = pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
             4, B, n_pages,
-            in_specs=[
-                pl.BlockSpec((1, H, Wp, hd), _row_map),     # q
-                pl.BlockSpec((1, H, Wp, hd), _row_map),     # k_new
-                pl.BlockSpec((1, H, Wp, hd), _row_map),     # v_new
-                pl.BlockSpec((1, H, page, hd), _page_map),  # k pages
-                pl.BlockSpec((1, H, page, hd), _page_map),  # v pages
-                pl.BlockSpec((1, H, page), _scale_map),     # k scales
-                pl.BlockSpec((1, H, page), _scale_map),     # v scales
-            ],
-            out_specs=[
-                pl.BlockSpec((1, H, Wp, hd), _row_map),
-                pl.BlockSpec((1, H, page, hd), _write_map),
-                pl.BlockSpec((1, H, page, hd), _write_map),
-                pl.BlockSpec((1, H, page), _swrite_map),
-                pl.BlockSpec((1, H, page), _swrite_map),
-            ],
+            in_specs=[row, new, new, srow, srow, pages, *scale_specs],
+            out_specs=[row, pl.BlockSpec((1, H, page, 2 * hd), _write_map),
+                       swrite, swrite],
             H=H, Wp=Wp, hd=hd),
         out_shape=[jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
-                   pool_shape, pool_shape, scale_shape, scale_shape],
-        # operand indices count the 4 scalar-prefetch args: k/v pages are
-        # operands 7/8, their scale pools 9/10 — all four alias their
+                   jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype),
+                   scale_shape, scale_shape],
+        # operand indices count the 4 scalar-prefetch args: the pool is
+        # operand 9, its scale pools 10/11 — all three alias their
         # outputs so pages AND scales update in place through the same
         # trash-redirected write maps
-        input_output_aliases={7: 1, 8: 2, 9: 3, 10: 4},
+        input_output_aliases={9: 1, 10: 2, 11: 3},
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )
-    return call(block_tables, pos, wlo, whi, q, k_new, v_new,
-                k_pages, v_pages, k_scale, v_scale)
-
-
-@functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
-def _pa_window_read_call_q(q, k_new, v_new, k_pages, v_pages, k_scale,
-                           v_scale, block_tables, pos, *, W, scale,
-                           interpret):
-    from jax.experimental import pallas as pl
-
-    B, H, Wp, hd = q.shape
-    page = k_pages.shape[2]
-    n_pages = block_tables.shape[1]
-    kernel = functools.partial(_pa_window_kernel_q, scale=scale, page=page,
-                               W=W, n_pages=n_pages)
-
-    def _row_map(b, p, bt, pos_):
-        return (b, 0, 0, 0)
-
-    def _page_map(b, p, bt, pos_):
-        return (bt[b, p], 0, 0, 0)
-
-    def _scale_map(b, p, bt, pos_):
-        return (bt[b, p], 0, 0)
-
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=_grid_spec(
-            2, B, n_pages,
-            in_specs=[
-                pl.BlockSpec((1, H, Wp, hd), _row_map),     # q
-                pl.BlockSpec((1, H, Wp, hd), _row_map),     # k_new
-                pl.BlockSpec((1, H, Wp, hd), _row_map),     # v_new
-                pl.BlockSpec((1, H, page, hd), _page_map),  # k pages
-                pl.BlockSpec((1, H, page, hd), _page_map),  # v pages
-                pl.BlockSpec((1, H, page), _scale_map),     # k scales
-                pl.BlockSpec((1, H, page), _scale_map),     # v scales
-            ],
-            out_specs=pl.BlockSpec((1, H, Wp, hd), _row_map),
-            H=H, Wp=Wp, hd=hd),
-        out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )
-    return call(block_tables, pos, q, k_new, v_new, k_pages, v_pages,
-                k_scale, v_scale)
+    return call(block_tables, pos, wlo, whi, q, kv_new, kvq_new, ks_new,
+                vs_new, kv_pages, k_scale, v_scale)
 
 
 # ---- mesh mount (shard_map) -------------------------------------------------
@@ -833,54 +595,77 @@ def _check_mount(mesh, B, H, slot_axis, head_axis):
                 f"batch {B} not divisible by mesh {slot_axis}={dp}")
 
 
-def _pool_write_rows(pool, rows, block_tables, pos, active):
+def _write_index(pool, W, block_tables, pos, active):
+    """``(physical page, offset in page)`` of each of the ``B*W`` window
+    positions ``pos[b] + j``, flattened row-major; inactive rows redirect
+    to trash page 0, like every other writer."""
+    page = pool.shape[2]
+    wpos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)       # (B, W)
+    phys = jnp.take_along_axis(block_tables, wpos // page, axis=1)
+    if active is not None:
+        phys = jnp.where(active[:, None], phys, 0)
+    return phys.reshape(-1), (wpos % page).reshape(-1)
+
+
+def _flat_rows(rows):
+    """(B, H, W, d) window rows as (B*W, H, d), one row per position."""
+    B, H, W, d = rows.shape
+    return rows.transpose(0, 2, 1, 3).reshape(B * W, H, d)
+
+
+def stored_kv(k, v, pool, k_scale=None, v_scale=None):
+    """What ``pool`` stores for K and V rows ``(..., hd)``: ``(packed
+    values,)``, or for a quantized pool ``(packed values, k scales, v
+    scales)``, each row through :func:`quantize_kv` — the one helper every
+    writer of pages shares, so their bytes agree bit for bit."""
+    if k_scale is None:
+        return (pack_kv(k, v).astype(pool.dtype),)
+    (kq, ks), (vq, vs) = (quantize_kv(r, pool.dtype) for r in (k, v))
+    return (pack_kv(kq, vq), ks.astype(k_scale.dtype),
+            vs.astype(v_scale.dtype))
+
+
+def _pool_write_rows(pool, k_rows, v_rows, block_tables, pos, active,
+                     *scales):
     """Scatter each row's W fresh K/V rows into its pages — the mesh
     path's page write, OUTSIDE the shard_map mount. Plain ``.at[].set``
     indexing that GSPMD partitions on the untouched head axis, writing
-    bytes bit-identical to ``transformer._paged_writeback`` (same index
-    math: physical page via the block table, offset ``pos+j`` mod page).
-    Inactive rows redirect to trash page 0, like every other writer."""
-    B, H, W, hd = rows.shape
-    page = pool.shape[2]
-    wpos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)       # (B, W)
-    phys = jnp.take_along_axis(block_tables, wpos // page, axis=1)
-    if active is not None:
-        phys = jnp.where(active[:, None], phys, 0)
-    pf = phys.reshape(-1)
-    of = (wpos % page).reshape(-1)
-    vals = rows.transpose(0, 2, 1, 3).reshape(B * W, H, hd)
-    return pool.at[pf, :, of].set(vals.astype(pool.dtype))
-
-
-def _pool_write_rows_quant(pool, scales, rows, block_tables, pos, active):
-    """Quantizing twin of :func:`_pool_write_rows`: the same index math,
-    but each (H, hd) row goes through :func:`quantize_kv` first and its
-    per-head scale lands in the ``(N, H, page)`` scale pool at the same
-    (physical page, offset). Bit-identical bytes to the fused kernel's
-    in-launch quantized scatter and to ``_paged_writeback``'s quant
-    branch — same helper, same order of operations."""
-    B, H, W, hd = rows.shape
-    page = pool.shape[2]
-    wpos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)       # (B, W)
-    phys = jnp.take_along_axis(block_tables, wpos // page, axis=1)
-    if active is not None:
-        phys = jnp.where(active[:, None], phys, 0)
-    pf = phys.reshape(-1)
-    of = (wpos % page).reshape(-1)
-    vals = rows.transpose(0, 2, 1, 3).reshape(B * W, H, hd)
-    q, sc = quantize_kv(vals, pool.dtype)
-    return (pool.at[pf, :, of].set(q),
-            scales.at[pf, :, of].set(sc.astype(scales.dtype)))
+    bytes bit-identical to ``transformer._paged_writeback`` and to the
+    fused kernel's in-launch scatter (same index math: physical page via
+    the block table, offset ``pos+j`` mod page; same :func:`stored_kv`).
+    With the pool's two ``(N, H, page)`` scale pools as ``scales`` the
+    rows are quantized and their per-head scales land at the same
+    (physical page, offset). Returns ``(pool, *scales)`` updated."""
+    pf, of = _write_index(pool, k_rows.shape[2], block_tables, pos, active)
+    new = stored_kv(_flat_rows(k_rows), _flat_rows(v_rows), pool, *scales)
+    return tuple(buf.at[pf, :, of].set(rows)
+                 for buf, rows in zip((pool, *scales), new))
 
 
 def _pad_window(t, Wp):
+    """Zero-pad axis 2 (the window) of ``t`` up to ``Wp`` rows."""
     W = t.shape[2]
     if W == Wp:
         return t
-    return jnp.pad(t, ((0, 0), (0, 0), (0, Wp - W), (0, 0)))
+    return jnp.pad(t, [(0, 0), (0, 0), (0, Wp - W)] +
+                   [(0, 0)] * (t.ndim - 3))
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
+def _mounted(call, mesh, slot_axis, head_axis, n_rows, n_scales):
+    """``call(rows..., pool, block_tables, vector, scales...)`` mounted
+    via ``shard_map``: heads over ``head_axis``, batch rows over
+    ``slot_axis``, the pool replicated over slots."""
+    from ..parallel.mesh import get_shard_map
+    shard_map, unchecked = get_shard_map()
+    row, pool, bt_spec, vec = _mount_specs(slot_axis, head_axis)
+    return shard_map(
+        call, mesh=mesh,
+        in_specs=((row,) * n_rows + (pool, bt_spec, vec)
+                  + (_scale_mount_spec(head_axis),) * n_scales),
+        out_specs=row, **unchecked)
+
+
+def paged_attention(q, kv_pages, block_tables, lengths, *,
                     k_scale=None, v_scale=None,
                     scale: Optional[float] = None,
                     interpret: Optional[bool] = None,
@@ -888,12 +673,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     head_axis: Optional[str] = None):
     """Read-only paged attention: queries ``q`` (B, H, W, hd) attend the
     first ``lengths[b]`` cached keys of row ``b``, read in place from
-    the ``(N, H, page, hd)`` page pools through ``block_tables`` (B, P).
-    A row with ``lengths[b] == 0`` yields zeros (the flash convention
-    for fully-masked rows). Returns (B, H, W, hd) in ``q.dtype``.
+    the packed ``(N, H, page, 2*hd)`` page pool (:func:`pack_kv`) through
+    ``block_tables`` (B, P). A row with ``lengths[b] == 0`` yields zeros
+    (the flash convention for fully-masked rows). Returns (B, H, W, hd)
+    in ``q.dtype``.
 
     With ``k_scale``/``v_scale`` (the pool's ``(N, H, page)`` scale
-    arrays) the pools hold QUANTIZED values: the scale blocks ride the
+    arrays) the pool holds QUANTIZED values: the scale blocks ride the
     same block-table index_map as their pages and the kernel dequantizes
     in VMEM — HBM only ever moves the quantized bytes.
 
@@ -907,52 +693,20 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     B, H, W, hd = q.shape
     if scale is None:
         scale = float(1.0 / math.sqrt(hd))
-    Wp = _round_up(W, sublane_multiple(q.dtype))
-    qp = _pad_window(q, Wp)
-    bt = block_tables.astype(jnp.int32)
-    lens = lengths.astype(jnp.int32)
-    quant = k_scale is not None
-    if mesh is None:
-        if quant:
-            out = _pa_read_call_q(qp, k_pages, v_pages, k_scale, v_scale,
-                                  bt, lens, scale=scale,
-                                  interpret=bool(interpret))
-        else:
-            out = _pa_read_call(qp, k_pages, v_pages, bt, lens,
-                                scale=scale, interpret=bool(interpret))
-        return out[:, :, :W]
-    _check_mount(mesh, B, H, slot_axis, head_axis)
-    from ..parallel.mesh import get_shard_map
-    shard_map, unchecked = get_shard_map()
-    row, pool, bt_spec, vec = _mount_specs(slot_axis, head_axis)
-    if quant:
-        spool = _scale_mount_spec(head_axis)
-
-        def _shard_q(q_, kp_, vp_, ks_, vs_, bt_, len_):
-            return _pa_read_call_q(q_, kp_, vp_, ks_, vs_, bt_, len_,
-                                   scale=scale, interpret=bool(interpret))
-
-        out = shard_map(_shard_q, mesh=mesh,
-                        in_specs=(row, pool, pool, spool, spool,
-                                  bt_spec, vec),
-                        out_specs=row, **unchecked)(
-            qp, k_pages, v_pages, k_scale, v_scale, bt, lens)
-        return out[:, :, :W]
-
-    def _shard(q_, kp_, vp_, bt_, len_):
-        return _pa_read_call(q_, kp_, vp_, bt_, len_,
-                             scale=scale, interpret=bool(interpret))
-
-    out = shard_map(_shard, mesh=mesh,
-                    in_specs=(row, pool, pool, bt_spec, vec),
-                    out_specs=row, **unchecked)(
-        qp, k_pages, v_pages, bt, lens)
+    qp = _pad_window(q, _round_up(W, sublane_multiple(q.dtype)))
+    scales = () if k_scale is None else (k_scale, v_scale)
+    call = functools.partial(_pa_read_call, scale=scale,
+                             interpret=bool(interpret))
+    if mesh is not None:
+        _check_mount(mesh, B, H, slot_axis, head_axis)
+        call = _mounted(call, mesh, slot_axis, head_axis, 1, len(scales))
+    out = call(qp, kv_pages, block_tables.astype(jnp.int32),
+               lengths.astype(jnp.int32), *scales)
     return out[:, :, :W]
 
 
-def paged_attention_window(q, k_new, v_new, k_pages, v_pages,
-                           block_tables, pos, *, active=None,
-                           k_scale=None, v_scale=None,
+def paged_attention_window(q, k_new, v_new, kv_pages, block_tables, pos, *,
+                           active=None, k_scale=None, v_scale=None,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
                            mesh=None, slot_axis: Optional[str] = None,
@@ -961,77 +715,47 @@ def paged_attention_window(q, k_new, v_new, k_pages, v_pages,
 
     Row ``b``'s W queries sit at absolute positions
     ``pos[b] .. pos[b]+W-1``; they attend every cached key strictly
-    below ``pos[b]`` (read in place from the pools) plus the window's
+    below ``pos[b]`` (read in place from the pool) plus the window's
     own keys ``k_new``/``v_new`` (B, H, W, hd) under the in-window
     causal mask, and the fresh K/V rows are scattered into their pages
     in the same launch. Rows where ``active`` is False neither write
     their pages (their writes redirect to trash page 0) nor produce
-    meaningful context. Returns ``(ctx, k_pages, v_pages)`` with the
-    pool buffers updated in place (aliased).
+    meaningful context. Returns ``(ctx, kv_pages)`` with the pool
+    buffer updated in place (aliased).
 
     With ``k_scale``/``v_scale`` (the ``(N, H, page)`` scale pools) the
-    page pools hold QUANTIZED values: page reads dequantize in VMEM and
-    the in-launch scatter quantizes each fresh row through the
-    sanctioned :func:`~mmlspark_tpu.ops.kv_quant.quantize_kv` before
-    writing. The return grows to ``(ctx, k_pages, v_pages, k_scale,
-    v_scale)`` — scales alias and update in place exactly like pages.
+    page pool holds QUANTIZED values: page reads dequantize in VMEM and
+    the in-launch scatter writes each fresh row as the sanctioned
+    :func:`~mmlspark_tpu.ops.kv_quant.quantize_kv` made it. The return
+    grows to ``(ctx, kv_pages, k_scale, v_scale)`` — scales alias and
+    update in place exactly like pages.
 
     With ``mesh=`` the attention mounts via ``jax.shard_map`` (heads
     over ``head_axis``, rows optionally over ``slot_axis``) in
     READ-ONLY form, and the fresh rows are scattered by
-    :func:`_pool_write_rows` / :func:`_pool_write_rows_quant` outside
+    :func:`_pool_write_rows` outside
     the mount — the written bytes are bit-identical to the fused
     in-kernel scatter, so single-chip and mesh engines produce the same
     pages."""
     if interpret is None:
         interpret = _auto_interpret()
     B, H, W, hd = q.shape
-    page = k_pages.shape[2]
+    page = kv_pages.shape[2]
     if scale is None:
         scale = float(1.0 / math.sqrt(hd))
     pos = pos.astype(jnp.int32)
     Wp = _round_up(W, sublane_multiple(q.dtype))
     bt = block_tables.astype(jnp.int32)
-    quant = k_scale is not None
+    scales = () if k_scale is None else (k_scale, v_scale)
+    qp, kvn = _pad_window(q, Wp), _pad_window(pack_kv(k_new, v_new), Wp)
     if mesh is not None:
         _check_mount(mesh, B, H, slot_axis, head_axis)
-        from ..parallel.mesh import get_shard_map
-        shard_map, unchecked = get_shard_map()
-        row, pool, bt_spec, vec = _mount_specs(slot_axis, head_axis)
-        if quant:
-            spool = _scale_mount_spec(head_axis)
-
-            def _shard_q(q_, kn_, vn_, kp_, vp_, ks_, vs_, bt_, pos_):
-                return _pa_window_read_call_q(
-                    q_, kn_, vn_, kp_, vp_, ks_, vs_, bt_, pos_,
-                    W=W, scale=scale, interpret=bool(interpret))
-
-            ctx = shard_map(_shard_q, mesh=mesh,
-                            in_specs=(row, row, row, pool, pool,
-                                      spool, spool, bt_spec, vec),
-                            out_specs=row, **unchecked)(
-                _pad_window(q, Wp), _pad_window(k_new, Wp),
-                _pad_window(v_new, Wp), k_pages, v_pages,
-                k_scale, v_scale, bt, pos)
-            kp, ks = _pool_write_rows_quant(k_pages, k_scale, k_new,
-                                            bt, pos, active)
-            vp, vs = _pool_write_rows_quant(v_pages, v_scale, v_new,
-                                            bt, pos, active)
-            return ctx[:, :, :W], kp, vp, ks, vs
-
-        def _shard(q_, kn_, vn_, kp_, vp_, bt_, pos_):
-            return _pa_window_read_call(q_, kn_, vn_, kp_, vp_, bt_, pos_,
-                                        W=W, scale=scale,
-                                        interpret=bool(interpret))
-
-        ctx = shard_map(_shard, mesh=mesh,
-                        in_specs=(row, row, row, pool, pool, bt_spec, vec),
-                        out_specs=row, **unchecked)(
-            _pad_window(q, Wp), _pad_window(k_new, Wp),
-            _pad_window(v_new, Wp), k_pages, v_pages, bt, pos)
-        kp = _pool_write_rows(k_pages, k_new, bt, pos, active)
-        vp = _pool_write_rows(v_pages, v_new, bt, pos, active)
-        return ctx[:, :, :W], kp, vp
+        call = functools.partial(_pa_window_read_call, W=W, scale=scale,
+                                 interpret=bool(interpret))
+        ctx = _mounted(call, mesh, slot_axis, head_axis, 2, len(scales))(
+            qp, kvn, kv_pages, bt, pos, *scales)
+        return (ctx[:, :, :W], *_pool_write_rows(
+            kv_pages, k_new, v_new, bt, pos, active, *scales))
     wlo = pos // page
     whi = (pos + W - 1) // page
     if active is not None:
@@ -1039,16 +763,15 @@ def paged_attention_window(q, k_new, v_new, k_pages, v_pages,
         # of the row to trash and the overlay never fires
         wlo = jnp.where(active, wlo, 1)
         whi = jnp.where(active, whi, 0)
-    if quant:
-        out, kp, vp, ks, vs = _pa_fused_call_q(
-            _pad_window(q, Wp), _pad_window(k_new, Wp),
-            _pad_window(v_new, Wp), k_pages, v_pages, k_scale, v_scale,
-            bt, pos, wlo.astype(jnp.int32), whi.astype(jnp.int32),
+    wlo, whi = wlo.astype(jnp.int32), whi.astype(jnp.int32)
+    if scales:
+        out, *pools = _pa_fused_call_q(
+            qp, kvn, *(_pad_window(t, Wp) for t in stored_kv(
+                k_new, v_new, kv_pages, *scales)),
+            kv_pages, *scales, bt, pos, wlo, whi,
             W=W, scale=scale, interpret=bool(interpret))
-        return out[:, :, :W], kp, vp, ks, vs
-    out, kp, vp = _pa_fused_call(
-        _pad_window(q, Wp), _pad_window(k_new, Wp), _pad_window(v_new, Wp),
-        k_pages, v_pages, bt, pos,
-        wlo.astype(jnp.int32), whi.astype(jnp.int32),
-        W=W, scale=scale, interpret=bool(interpret))
-    return out[:, :, :W], kp, vp
+    else:
+        out, *pools = _pa_fused_call(qp, kvn, kv_pages, bt, pos, wlo, whi,
+                                     W=W, scale=scale,
+                                     interpret=bool(interpret))
+    return (out[:, :, :W], *pools)

@@ -6,9 +6,11 @@ memory, prefix reuse needs a device copy into the slot, and a retiring
 short request strands the tail of its region. This module supplies the
 PagedAttention answer (PAPERS.md: vLLM) at the allocator level:
 
-* **pages** — the physical cache is ``(num_pages, H, page_size, hd)`` per
-  layer (`models/zoo/transformer.init_paged_cache`); requests are sized in
-  pages for the tokens they can actually produce, not ``max_len``;
+* **pages** — the physical cache is one ``(num_pages, H, page_size, 2*hd)``
+  buffer per layer, K beside V on the minor axis
+  (`models/zoo/transformer.init_paged_cache`; why that layout:
+  `ops/paged_attention.py`); requests are sized in pages for the tokens
+  they can actually produce, not ``max_len``;
 * **block tables** — each slot owns a row of physical page ids; attention
   gathers through it (`decode_step_paged` / `decode_window_paged`) and the
   result is bitwise-equal to the contiguous path;
@@ -123,7 +125,8 @@ class PoolExhausted(RuntimeError):
 class PagedKVPool:
     """Page allocator + device buffer handle for one model's KV cache.
 
-    ``buffers`` is the per-layer list of ``{"k","v"}`` page arrays the
+    ``buffers`` is the per-layer list of ``{"kv"}`` page arrays (plus
+    ``{"k_scale","v_scale"}`` when quantized) the
     engine threads through its jitted steps (reassigning after every
     dispatch, since XLA returns fresh buffers). Everything else is host
     bookkeeping: a free min-heap over pages ``[1, num_pages)``, per-page
@@ -143,7 +146,10 @@ class PagedKVPool:
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         hd = cfg.d_model // cfg.heads
-        shape = (self.num_pages, cfg.heads, self.page_size, hd)
+        #: one K or V page as a session blob carries it, (H, page, hd);
+        #: the pool's buffer packs the two side by side on the minor axis
+        self._page_shape = (cfg.heads, self.page_size, hd)
+        shape = (self.num_pages, cfg.heads, self.page_size, 2 * hd)
         #: canonical quantized-page dtype name ("int8"/"fp8") or None for
         #: bf16 pages (the byte-exact oracle representation)
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
@@ -195,12 +201,11 @@ class PagedKVPool:
 
     def _make_buffers(self):
         """Fresh per-layer page buffers through ``make_buffer`` (so mesh
-        shardings apply): ``{"k","v"}`` in the value dtype, plus
+        shardings apply): ``{"kv"}`` in the value dtype, plus
         ``{"k_scale","v_scale"}`` when quantized."""
         layers = []
         for _ in range(self.cfg.layers):
-            c = {"k": self._mk(self._shape, self.value_dtype),
-                 "v": self._mk(self._shape, self.value_dtype)}
+            c = {"kv": self._mk(self._shape, self.value_dtype)}
             if self.scale_dtype is not None:
                 c["k_scale"] = self._mk(self._scale_shape, self.scale_dtype)
                 c["v_scale"] = self._mk(self._scale_shape, self.scale_dtype)
@@ -213,7 +218,7 @@ class PagedKVPool:
         what :func:`~mmlspark_tpu.core.residency.get_residency_manager`'s
         ``reserve()`` pins, so the budget sees the QUANTIZED itemsize: a
         fixed byte budget holds ~2x the pages under int8."""
-        nbytes = (2 * self.cfg.layers * int(np.prod(self._shape)) *
+        nbytes = (self.cfg.layers * int(np.prod(self._shape)) *
                   jnp.dtype(self.value_dtype).itemsize)
         if self.scale_dtype is not None:
             nbytes += (2 * self.cfg.layers *
@@ -429,20 +434,25 @@ class PagedKVPool:
         order — the caller runs the compact permutation first (the engine's
         ``_maybe_compact`` remap) and hands over the post-remap list, so
         the blob is position-ordered regardless of physical placement on
-        this pool. Quant scale pools (int8/fp8) ride along per layer under
-        the same page indices. ``length`` is the number of positions the
-        pages actually hold (prompt + written tokens); the receiver uses
-        it to rebuild the block-table row and resume mid-page."""
+        this pool. The blob (version 1) carries K and V apart, as
+        ``(n_pages, H, page, hd)`` each: the packed buffer is split here
+        and packed again by :meth:`adopt_session`, so a blob outlives the
+        pool's layout. Quant scale pools (int8/fp8) ride along per layer
+        under the same page indices. ``length`` is the number of positions
+        the pages actually hold (prompt + written tokens); the receiver
+        uses it to rebuild the block-table row and resume mid-page."""
         import base64
         pages = [int(p) for p in pages]
         idx = jnp.asarray(np.asarray(pages, np.int32))
         data = []
+        hd = self._page_shape[-1]
         for c in self.buffers:
-            entry = {}
-            for key, buf in c.items():
-                arr = np.asarray(buf[idx])
-                entry[key] = base64.b64encode(arr.tobytes()).decode("ascii")
-            data.append(entry)
+            kv = np.asarray(c["kv"][idx])
+            arrays = {"k": kv[..., :hd], "v": kv[..., hd:]}
+            arrays.update((key, np.asarray(buf[idx]))
+                          for key, buf in c.items() if key != "kv")
+            data.append({key: base64.b64encode(arr.tobytes()).decode("ascii")
+                         for key, arr in arrays.items()})
         self.stats["sessions_exported"] = \
             self.stats.get("sessions_exported", 0) + 1
         return {
@@ -455,7 +465,7 @@ class PagedKVPool:
             "scale_dtype": (np.dtype(self.scale_dtype).name
                             if self.scale_dtype is not None else None),
             "layers": int(self.cfg.layers),
-            "page_shape": [int(x) for x in self._shape[1:]],
+            "page_shape": [int(x) for x in self._page_shape],
             "data": data,
         }
 
@@ -477,7 +487,7 @@ class PagedKVPool:
             "scale_dtype": (np.dtype(self.scale_dtype).name
                             if self.scale_dtype is not None else None),
             "layers": int(self.cfg.layers),
-            "page_shape": [int(x) for x in self._shape[1:]],
+            "page_shape": [int(x) for x in self._page_shape],
         }
         got = {k: blob.get(k) for k in want}
         if got != want:
@@ -488,18 +498,22 @@ class PagedKVPool:
         try:
             idx = jnp.asarray(np.asarray(pages, np.int32))
             new_buffers = []
+
+            def decoded(entry, key):
+                scale = key.endswith("_scale")
+                dt = np.dtype(self.scale_dtype if scale else self.value_dtype)
+                tail = self._scale_shape[1:] if scale else self._page_shape
+                return np.frombuffer(base64.b64decode(entry[key]),
+                                     dtype=dt).reshape((n,) + tuple(tail))
+
             for c, entry in zip(self.buffers, blob["data"]):
-                nc = {}
-                for key, buf in c.items():
-                    dt = np.dtype(self.scale_dtype if key.endswith("_scale")
-                                  else self.value_dtype)
-                    tail = (self._scale_shape[1:]
-                            if key.endswith("_scale") else self._shape[1:])
-                    arr = np.frombuffer(
-                        base64.b64decode(entry[key]),
-                        dtype=dt).reshape((n,) + tuple(tail))
-                    nc[key] = buf.at[idx].set(jnp.asarray(arr, buf.dtype))
-                new_buffers.append(nc)
+                arrays = {"kv": np.concatenate(
+                    [decoded(entry, "k"), decoded(entry, "v")], axis=-1)}
+                arrays.update((key, decoded(entry, key))
+                              for key in c if key != "kv")
+                new_buffers.append(
+                    {key: buf.at[idx].set(jnp.asarray(arrays[key], buf.dtype))
+                     for key, buf in c.items()})
             self.buffers = new_buffers
         except Exception:
             self.free(pages)
@@ -534,7 +548,7 @@ class PagedKVPool:
         """Sublane tile the Pallas paged-attention kernel needs
         ``page_size`` to be a multiple of on a real TPU: 8 (f32),
         16 (bf16), 32 (int8) — the page dimension sits in the sublane
-        slot of the kernel's ``(1, heads, page, head_dim)`` blocks."""
+        slot of the kernel's ``(1, heads, page, 2*head_dim)`` blocks."""
         from ..ops.paged_attention import sublane_multiple
         return sublane_multiple(dtype)
 

@@ -9,6 +9,7 @@ land on another worker, where the fixture skips in silence.
 """
 
 import functools
+import importlib.util
 import os
 
 import jax
@@ -75,7 +76,7 @@ def _pool(shape, kv):
     page = PAGE[kv]
     per_row = MAX_LEN // page
     n_pages = SLOTS * per_row + 1
-    pages = shape((n_pages, HEADS, page, HD),
+    pages = shape((n_pages, HEADS, page, 2 * HD),
                   jnp.int8 if kv == "int8" else jnp.bfloat16)
     scales = ([shape((n_pages, HEADS, page), jnp.bfloat16)] * 2
               if kv == "int8" else [])
@@ -93,11 +94,11 @@ def test_paged_read_kernel_compiles(one_chip, kv):
     bt = one_chip((SLOTS, per_row), jnp.int32)
     lens = one_chip((SLOTS,), jnp.int32)
 
-    def read(q, kp, vp, bt, lens, *scales):
-        return paged_attention(q, kp, vp, bt, lens, interpret=False,
+    def read(q, kvp, bt, lens, *scales):
+        return paged_attention(q, kvp, bt, lens, interpret=False,
                                **_scale_kw(scales))
 
-    _compiled_text(read, q, pages, pages, bt, lens, *scales)
+    _compiled_text(read, q, pages, bt, lens, *scales)
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -110,13 +111,12 @@ def test_paged_fused_window_kernel_compiles(one_chip, kv, rows, window):
     pos = one_chip((rows,), jnp.int32)
     active = one_chip((rows,), jnp.bool_)
 
-    def fused(q, kn, vn, kp, vp, bt, pos, active, *scales):
-        return paged_attention_window(q, kn, vn, kp, vp, bt, pos,
+    def fused(q, kn, vn, kvp, bt, pos, active, *scales):
+        return paged_attention_window(q, kn, vn, kvp, bt, pos,
                                       active=active, interpret=False,
                                       **_scale_kw(scales))
 
-    _compiled_text(fused, row, row, row, pages, pages, bt, pos, active,
-                   *scales)
+    _compiled_text(fused, row, row, row, pages, bt, pos, active, *scales)
 
 
 def test_mesh_mounted_read_kernel_compiles(topo, one_chip):
@@ -131,17 +131,57 @@ def test_mesh_mounted_read_kernel_compiles(topo, one_chip):
 
     per_row = MAX_LEN // PAGE["bf16"]
     q = shape((SLOTS, HEADS, 1, HD), jnp.bfloat16, P("dp", "tp"))
-    pages = shape((SLOTS * per_row + 1, HEADS, PAGE["bf16"], HD),
+    pages = shape((SLOTS * per_row + 1, HEADS, PAGE["bf16"], 2 * HD),
                   jnp.bfloat16, P(None, "tp"))
     bt = shape((SLOTS, per_row), jnp.int32, P("dp"))
     lens = shape((SLOTS,), jnp.int32, P("dp"))
     text = _compiled_text(
         functools.partial(paged_attention, interpret=False, mesh=mesh,
                           slot_axis="dp", head_axis="tp"),
-        q, pages, pages, bt, lens)
+        q, pages, bt, lens)
     for collective in ("all-reduce", "all-gather", "all-to-all",
                        "collective-permute"):
         assert collective not in text, f"{collective} inside the mount"
+
+
+def test_programs_update_the_page_pool_in_place(one_chip):
+    """``chip_smoke.py``'s guard at no chip time: the decode tick, a 256-token
+    extension and a group insertion at GPT-2 XL's widths (two layers of the
+    48: the copies were four a layer) hold no copy of a pool-sized buffer.
+    The chip keeps a ``(pages, heads, page, 64)`` bf16 buffer with the page
+    index minor-most, a layout the Mosaic call cannot take, so every program
+    copied the pool in and out; packed 128 lanes wide it stays row-major."""
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(os.path.dirname(__file__), os.pardir,
+                                   "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    sz = smoke.sizes(small=False)
+    from mmlspark_tpu.ops import paged_attention as pa
+    from mmlspark_tpu.serving import continuous
+    interpret = pa._auto_interpret
+    # the engine's programs ask the backend: compile the kernel, and the
+    # tick under its TPU options (a name this compiler lacks fails here)
+    pa._auto_interpret = continuous._pa_auto_interpret = lambda: False
+    try:
+        lowered, shapes = smoke.pool_programs(
+            sz["pool_decoder"]._replace(layers=2), sz["pool_audit"], one_chip)
+        texts = {name: low.compile().as_text()
+                 for name, low in lowered.items()}
+    finally:
+        pa._auto_interpret = continuous._pa_auto_interpret = interpret
+        continuous._tick_program.cache_clear()
+    assert shapes == {("bfloat16", (577, 25, 16, 128))}
+    assert "tpu_custom_call" in texts["jit_tick"]
+    assert "slice-start" not in texts["jit_tick"]   # one slice a prefetch
+    assert "slice-start" in texts["jit__extend"]
+    copies = {name: smoke.pool_copies(text, shapes)
+              for name, text in texts.items()}
+    assert not any(copies.values()), copies
+    # the check can see one: the parent's layout, copied in by the compiler
+    assert smoke.pool_copies(
+        "%copy.1 = bf16[577,25,16,128]{3,2,1,0:T(8,128)(2,1)} copy(%p)",
+        shapes)
 
 
 @pytest.mark.parametrize("stats", [None, "bfloat16"])

@@ -21,10 +21,14 @@ The invariants this file pins, in order of importance:
    live decodes instead of freezing them.
 """
 
+import base64
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from mmlspark_tpu.models.zoo.transformer import (
     TransformerConfig, decode_step_paged, decode_step_ragged,
@@ -199,14 +203,14 @@ class TestPagedParity:
         pages = paged_scatter_rows(
             init_paged_cache(CFG, 1 + B * n_pages, page),
             [{"k": c["k"], "v": c["v"]} for c in contig], bt, page)
-        before = [np.asarray(c["k"]).copy() for c in pages]
+        before = [np.asarray(c["kv"]).copy() for c in pages]
         tok = jnp.asarray(rng.integers(0, CFG.vocab, B))
         active = jnp.asarray([True, False])
         _, pages = decode_step_paged(
             params, tok, jnp.full((B,), 3, jnp.int32), pages, bt, CFG,
             page_size=page, length=L, active=active, impl="gather")
         for lyr, b4 in zip(pages, before):
-            after = np.asarray(lyr["k"])
+            after = np.asarray(lyr["kv"])
             # row 1's pages are untouched; only row 0's write position and
             # the trash page may differ
             assert np.array_equal(after[1 + n_pages:], b4[1 + n_pages:])
@@ -424,6 +428,106 @@ class TestDefrag:
         assert rl.tokens == list(np.asarray(want)[0, len(p_long):])
         assert eng._kv.stats["defrag_moves"] > 0
         assert eng._kv.pages_in_use == 0
+
+
+def _engine(params, kind, **kw):
+    """A decoder on one device, or on the ``dp4 x tp2`` mesh of tier-1's
+    eight virtual devices (four slots: they split over ``dp``)."""
+    if kind == "mesh":
+        if jax.device_count() < 8:
+            pytest.skip("the mesh mount needs 8 (simulated) devices")
+        kw["mesh"] = Mesh(np.array(jax.devices()[:8]).reshape(4, 2),
+                          ("dp", "tp"))
+    return ContinuousDecoder(params, CFG, max_slots=4, max_len=48,
+                             page_size=4, **kw)
+
+
+@pytest.mark.parametrize("kind", ["single", "mesh"])
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+class TestPackedPoolLifecycle:
+    """One pool through everything that touches its buffers: insertion,
+    ticks, defragmentation, copy-on-write, a session's export and adopt."""
+
+    def _drive(self, params, kind, kv_dtype, impl):
+        eng = _engine(params, kind, kv_dtype=kv_dtype, paged_attn=impl,
+                      defrag_threshold=1)
+        cow = []
+        copy_pages = eng._copy_pages_j
+        eng._copy_pages_j = lambda *a: (cow.append(1), copy_pages(*a))[1]
+        rng = np.random.default_rng(31)
+        prefix = rng.integers(1, CFG.vocab, 10).astype(np.int32)  # 2.5 pages
+        short = eng.submit(rng.integers(1, CFG.vocab, 5).astype(np.int32),
+                           max_new_tokens=2)
+        first = eng.submit(prefix, max_new_tokens=8, prefix_key="sys")
+        while not short.done:       # insertion, ticks; retiring `short`
+            eng.step()              # leaves a hole: the pool compacts
+        second = eng.submit(
+            np.concatenate([prefix,
+                            rng.integers(1, CFG.vocab, 3).astype(np.int32)]),
+            max_new_tokens=6, prefix_key="sys")
+        while not (first.done and second.done):
+            eng.step()
+        assert all(r.error is None for r in (short, first, second))
+        return eng, cow, [short.tokens, first.tokens, second.tokens]
+
+    def test_insert_tick_defrag_cow_on_one_pool(self, params, kv_dtype,
+                                                kind):
+        eng, cow, tokens = self._drive(params, kind, kv_dtype, "kernel")
+        stats = eng._kv.stats
+        assert stats["attn_ticks_kernel"] > 0
+        assert stats["attn_ticks_gather"] == 0
+        assert stats["defrag_moves"] > 0, "the pool never compacted"
+        assert stats["prefix_share_hits"] >= 2 and cow, \
+            "no page was shared and none copied on write"
+        assert stats["alloc_failures"] == 0
+        # the gather oracle through the same life, on the same layout
+        oracle, _, want = self._drive(params, kind, kv_dtype, "gather")
+        assert tokens == want
+        for kk, buf in eng._kv.buffers[0].items():
+            assert np.array_equal(np.asarray(buf)[1:],
+                                  np.asarray(oracle._kv.buffers[0][kk])[1:]), kk
+
+    def test_adopts_a_session_blob_of_the_parent_layout(self, params,
+                                                        kv_dtype, kind):
+        """A version-1 blob as the parent wrote it, K and V apart as
+        ``(n_pages, H, page, hd)`` each, built here from separate arrays:
+        adopted bit for bit, and exported again with the bytes it had."""
+        pool = _engine(params, kind, kv_dtype=kv_dtype)._kv
+        rng = np.random.default_rng(41)
+        n, tail = 3, (CFG.heads, 4, CFG.d_model // CFG.heads)
+        quant = kv_dtype is not None
+
+        def b64(arr):
+            return base64.b64encode(arr.tobytes()).decode("ascii")
+
+        parts, data = [], []
+        for _ in range(CFG.layers):
+            layer = {kk: np.asarray(jnp.asarray(
+                rng.integers(-127, 128, (n,) + tail) if quant
+                else rng.standard_normal((n,) + tail), pool.value_dtype))
+                for kk in ("k", "v")}
+            if quant:
+                layer.update({kk + "_scale": np.asarray(jnp.asarray(
+                    rng.uniform(0.01, 1.0, (n,) + tail[:2]),
+                    pool.scale_dtype)) for kk in ("k", "v")})
+            parts.append(layer)
+            data.append({kk: b64(arr) for kk, arr in layer.items()})
+        blob = {"v": 1, "page_size": 4, "n_pages": n, "length": 10,
+                "kv_dtype": kv_dtype,
+                "value_dtype": np.dtype(pool.value_dtype).name,
+                "scale_dtype": (np.dtype(pool.scale_dtype).name
+                                if quant else None),
+                "layers": CFG.layers, "page_shape": list(tail), "data": data}
+        pages = pool.adopt_session(blob)
+        idx = jnp.asarray(pages)
+        for layer, c in zip(parts, pool.buffers):
+            want = np.concatenate([layer["k"], layer["v"]], axis=-1)
+            assert np.asarray(c["kv"][idx]).tobytes() == want.tobytes()
+            for kk in c:
+                if kk != "kv":
+                    assert (np.asarray(c[kk][idx]).tobytes()
+                            == layer[kk].tobytes()), kk
+        assert pool.export_session(pages, length=10) == blob
 
 
 class TestChunkedPrefill:

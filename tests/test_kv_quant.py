@@ -42,7 +42,7 @@ from mmlspark_tpu.ops.kv_quant import (SCALE_DTYPE, dequantize_kv,
                                        kv_bytes_per_position, kv_qmax,
                                        kv_store_dtype, quantize_kv,
                                        resolve_kv_dtype, supports_fp8)
-from mmlspark_tpu.ops.paged_attention import (_pool_write_rows_quant,
+from mmlspark_tpu.ops.paged_attention import (_pool_write_rows,
                                               paged_attention_window)
 from mmlspark_tpu.serving.continuous import ContinuousDecoder
 from mmlspark_tpu.serving.kv_pool import PagedKVPool
@@ -259,7 +259,7 @@ class TestEngineParity:
         pool = PagedKVPool(CFG, num_pages=8, page_size=4,
                            residency=False)
         assert pool.kv_dtype is None and pool.scale_dtype is None
-        assert set(pool.buffers[0]) == {"k", "v"}
+        assert set(pool.buffers[0]) == {"kv"}
         B, L, page = 2, 8, 4
         rng = np.random.default_rng(2)
         cache = init_kv_cache(CFG, B, L)
@@ -290,20 +290,19 @@ class TestWriterAgreement:
         q = jnp.asarray(rng.normal(size=(B, H, W, hd)), jnp.float32)
         kn = jnp.asarray(rng.normal(size=(B, H, W, hd)), jnp.float32)
         vn = jnp.asarray(rng.normal(size=(B, H, W, hd)), jnp.float32)
-        kp = jnp.zeros((NP, H, page, hd), store)
-        vp = jnp.zeros((NP, H, page, hd), store)
+        kvp = jnp.zeros((NP, H, page, 2 * hd), store)
         ks = jnp.ones((NP, H, page), SCALE_DTYPE)
         vs = jnp.ones((NP, H, page), SCALE_DTYPE)
         bt = jnp.asarray(1 + 2 * np.arange(B)[:, None] + np.arange(2),
                          jnp.int32)
         pos = jnp.asarray([0, 3, 6], jnp.int32)
         active = jnp.asarray([True, True, True])
-        _, kp1, vp1, ks1, vs1 = paged_attention_window(
-            q, kn, vn, kp, vp, bt, pos, active=active,
+        _, kvp1, ks1, vs1 = paged_attention_window(
+            q, kn, vn, kvp, bt, pos, active=active,
             k_scale=ks, v_scale=vs)
-        kp2, ks2 = _pool_write_rows_quant(kp, ks, kn, bt, pos, active)
-        vp2, vs2 = _pool_write_rows_quant(vp, vs, vn, bt, pos, active)
-        for a, b in ((kp1, kp2), (vp1, vp2), (ks1, ks2), (vs1, vs2)):
+        kvp2, ks2, vs2 = _pool_write_rows(kvp, kn, vn, bt, pos, active,
+                                          ks, vs)
+        for a, b in ((kvp1, kvp2), (ks1, ks2), (vs1, vs2)):
             # trash page 0 is scratch for both paths — exclude it
             assert np.array_equal(np.asarray(a)[1:], np.asarray(b)[1:])
 
@@ -397,8 +396,8 @@ class TestSharingAndDefrag:
         pool.alloc(3)
         pool.reset()
         assert pool.pages_in_use == 0
-        assert set(pool.buffers[0]) == {"k", "v", "k_scale", "v_scale"}
-        assert pool.buffers[0]["k"].dtype == jnp.int8
+        assert set(pool.buffers[0]) == {"kv", "k_scale", "v_scale"}
+        assert pool.buffers[0]["kv"].dtype == jnp.int8
         assert pool.buffers[0]["k_scale"].dtype == SCALE_DTYPE
 
 

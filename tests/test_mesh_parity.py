@@ -120,7 +120,7 @@ class TestEngineMeshParity:
         assert eng_k._kv.stats["gather_bytes"] == 0
         assert eng_k._attn_impl == "kernel"
         # layer-0 page pools bitwise-identical modulo trash page 0
-        for kk in ("k", "v"):
+        for kk in ("kv",):
             a = np.asarray(eng_k._kv.buffers[0][kk])[1:]
             b = np.asarray(eng_g._kv.buffers[0][kk])[1:]
             assert np.array_equal(a, b), f"layer-0 {kk} pages differ"
@@ -231,7 +231,7 @@ class TestQuantizedMeshParity:
         # between the fused in-kernel scatter and the gather-impl
         # writeback, modulo trash page 0 — a scale that didn't ride the
         # same block-table index_map would break this
-        for kk in ("k", "v", "k_scale", "v_scale"):
+        for kk in ("kv", "k_scale", "v_scale"):
             a = np.asarray(engs["kernel"]._kv.buffers[0][kk])[1:]
             b = np.asarray(engs["gather"]._kv.buffers[0][kk])[1:]
             assert np.array_equal(a, b), f"layer-0 {kk} differs"
@@ -256,17 +256,17 @@ class TestOpMountParity:
                          .astype(np.float32))
         bt = jnp.asarray((1 + np.arange(B)[:, None] * P
                           + np.arange(P)[None, :]).astype(np.int32))
-        return kp, vp, bt
+        return jnp.concatenate([kp, vp], axis=-1), bt
 
     def test_read_mount_matches_unmounted(self):
         rng = np.random.default_rng(0)
         B, H, page, hd, P = 8, 4, 8, 8, 3
-        kp, vp, bt = self._pool(rng, B, H, page, hd, P)
+        kvp, bt = self._pool(rng, B, H, page, hd, P)
         q = jnp.asarray(rng.normal(size=(B, H, 1, hd)).astype(np.float32))
         lens = jnp.asarray(
             rng.integers(0, page * P, B).astype(np.int32)).at[0].set(0)
-        ref = paged_attention(q, kp, vp, bt, lens)
-        got = paged_attention(q, kp, vp, bt, lens,
+        ref = paged_attention(q, kvp, bt, lens)
+        got = paged_attention(q, kvp, bt, lens,
                               mesh=make_mesh("dp4xtp2"),
                               slot_axis="dp", head_axis="tp")
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -277,31 +277,30 @@ class TestOpMountParity:
     def test_window_mount_pages_bitwise_vs_fused(self):
         rng = np.random.default_rng(1)
         B, H, page, hd, P, W = 8, 4, 8, 8, 3, 4
-        kp, vp, bt = self._pool(rng, B, H, page, hd, P)
+        kvp, bt = self._pool(rng, B, H, page, hd, P)
         q, kn, vn = (jnp.asarray(rng.normal(size=(B, H, W, hd))
                                  .astype(np.float32)) for _ in range(3))
         pos = jnp.asarray(
             np.array([0, 5, 8, 2, 17, 3, 9, 1], np.int32))
         active = jnp.asarray(
             np.array([1, 1, 0, 1, 1, 1, 1, 1], bool))
-        ctx_f, kf, vf = paged_attention_window(q, kn, vn, kp, vp, bt,
-                                               pos, active=active)
-        ctx_m, km, vm = paged_attention_window(
-            q, kn, vn, kp, vp, bt, pos, active=active,
+        ctx_f, kvf = paged_attention_window(q, kn, vn, kvp, bt,
+                                            pos, active=active)
+        ctx_m, kvm = paged_attention_window(
+            q, kn, vn, kvp, bt, pos, active=active,
             mesh=make_mesh("dp4xtp2"), slot_axis="dp", head_axis="tp")
         np.testing.assert_allclose(np.asarray(ctx_m), np.asarray(ctx_f),
                                    atol=1e-5)
         # scattered pages bitwise modulo the trash page write sink
-        assert np.array_equal(np.asarray(km)[1:], np.asarray(kf)[1:])
-        assert np.array_equal(np.asarray(vm)[1:], np.asarray(vf)[1:])
+        assert np.array_equal(np.asarray(kvm)[1:], np.asarray(kvf)[1:])
 
     def test_mount_rejects_indivisible_axes(self):
         rng = np.random.default_rng(2)
         B, H, page, hd, P = 3, 4, 8, 8, 2
-        kp, vp, bt = self._pool(rng, B, H, page, hd, P)
+        kvp, bt = self._pool(rng, B, H, page, hd, P)
         q = jnp.asarray(rng.normal(size=(B, H, 1, hd)).astype(np.float32))
         lens = jnp.full((B,), 4, jnp.int32)
         with pytest.raises(ValueError, match="divisible"):
-            paged_attention(q, kp, vp, bt, lens,
+            paged_attention(q, kvp, bt, lens,
                             mesh=make_mesh("dp4xtp2"),
                             slot_axis="dp", head_axis="tp")

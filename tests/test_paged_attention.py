@@ -26,7 +26,9 @@ import os
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from mmlspark_tpu.models.zoo.transformer import (
     TransformerConfig, decode_step_paged, decode_step_ragged,
@@ -34,7 +36,8 @@ from mmlspark_tpu.models.zoo.transformer import (
     init_paged_cache, init_transformer, paged_gather, paged_scatter_rows)
 from mmlspark_tpu.ops.compile_cache import jit_cache_size
 from mmlspark_tpu.ops.paged_attention import (
-    ENV_KNOB, aligned_page_size, paged_attention, paged_attention_window,
+    ENV_KNOB, aligned_page_size, pack_kv, paged_attention,
+    paged_attention_window, split_kv,
     resolve_impl, sublane_multiple)
 from mmlspark_tpu.serving.continuous import ContinuousDecoder
 
@@ -58,7 +61,18 @@ def _contig_state(params, B, L, steps, rng):
     return cache
 
 
-def _paged_state(params, B, L, page, steps, rng):
+def mount(kind):
+    """Keyword arguments of the kernel's mount: none on one device, the
+    ``dp4 x tp2`` mesh of tier-1's eight virtual devices otherwise."""
+    if kind == "single":
+        return {}
+    if jax.device_count() < 8:
+        pytest.skip("the mesh mount needs 8 (simulated) devices")
+    return dict(mesh=Mesh(np.array(jax.devices()[:8]).reshape(4, 2),
+                          ("dp", "tp")), slot_axis="dp", head_axis="tp")
+
+
+def _paged_state(params, B, L, page, steps, rng, kv_dtype=None):
     """Contiguous warm-up scattered into a dense page pool + block table."""
     contig = _contig_state(params, B, L, steps, rng)
     n_pages = L // page
@@ -66,7 +80,7 @@ def _paged_state(params, B, L, page, steps, rng):
         1 + np.arange(B)[:, None] * n_pages + np.arange(n_pages),
         jnp.int32)
     pages = paged_scatter_rows(
-        init_paged_cache(CFG, 1 + B * n_pages, page),
+        init_paged_cache(CFG, 1 + B * n_pages, page, kv_dtype=kv_dtype),
         [{"k": c["k"], "v": c["v"]} for c in contig], bt, page)
     return pages, bt
 
@@ -140,7 +154,8 @@ class TestOpsKernel:
         # 11 = crosses two boundaries into the last page's tail
         lengths = jnp.asarray([0, 3, 4, 11], jnp.int32)
         q = jnp.asarray(rng.normal(0, 1, (B, H, 1, hd)), jnp.float32)
-        got = paged_attention(q, kp, vp, bt, lengths, interpret=True)
+        got = paged_attention(q, pack_kv(kp, vp), bt, lengths,
+                              interpret=True)
         kc = np.asarray(kp)[np.asarray(bt)].transpose(0, 2, 1, 3, 4)
         kc = kc.reshape(B, H, P * page, hd)
         vc = np.asarray(vp)[np.asarray(bt)].transpose(0, 2, 1, 3, 4)
@@ -162,8 +177,9 @@ class TestOpsKernel:
         q = jnp.asarray(rng.normal(0, 1, (B, H, W, hd)), jnp.float32)
         kn = jnp.asarray(rng.normal(0, 1, (B, H, W, hd)), jnp.float32)
         vn = jnp.asarray(rng.normal(0, 1, (B, H, W, hd)), jnp.float32)
-        ctx, kp2, vp2 = paged_attention_window(
-            q, kn, vn, kp, vp, bt, pos, interpret=True)
+        ctx, kvp2 = paged_attention_window(
+            q, kn, vn, pack_kv(kp, vp), bt, pos, interpret=True)
+        kp2, vp2 = split_kv(kvp2)
         # reference: contiguous overlay of window rows at pos..pos+W-1
         kc = np.asarray(kp)[np.asarray(bt)].transpose(0, 2, 1, 3, 4)
         kc = kc.reshape(B, H, P * page, hd).copy()
@@ -201,9 +217,10 @@ class TestOpsKernel:
         q = jnp.asarray(rng.normal(0, 1, (B, H, W, hd)), jnp.float32)
         kn = jnp.asarray(rng.normal(0, 1, (B, H, W, hd)), jnp.float32)
         vn = jnp.asarray(rng.normal(0, 1, (B, H, W, hd)), jnp.float32)
-        _, kp2, _ = paged_attention_window(
-            q, kn, vn, kp, vp, bt, pos, active=active, interpret=True)
-        after_k = np.asarray(kp2)
+        _, kvp2 = paged_attention_window(
+            q, kn, vn, pack_kv(kp, vp), bt, pos, active=active,
+            interpret=True)
+        after_k = np.asarray(split_kv(kvp2)[0])
         # row 1's pages (ids 3..4) are untouched; only row 0's pages and
         # the trash page may differ
         assert np.array_equal(after_k[1 + P:], before_k[1 + P:])
@@ -232,13 +249,11 @@ class TestDecodeParity:
                               np.argmax(np.asarray(want), -1))
         # layer 0's page writes are bitwise (same projection inputs);
         # deeper layers inherit the context drift, tolerance there
-        assert np.array_equal(np.asarray(got_pages[0]["k"]),
-                              np.asarray(want_pages[0]["k"]))
-        assert np.array_equal(np.asarray(got_pages[0]["v"]),
-                              np.asarray(want_pages[0]["v"]))
+        assert np.array_equal(np.asarray(got_pages[0]["kv"]),
+                              np.asarray(want_pages[0]["kv"]))
         for g, w in zip(got_pages[1:], want_pages[1:]):
-            np.testing.assert_allclose(np.asarray(g["k"]),
-                                       np.asarray(w["k"]),
+            np.testing.assert_allclose(np.asarray(g["kv"]),
+                                       np.asarray(w["kv"]),
                                        rtol=1e-5, atol=1e-5)
 
     @pytest.mark.parametrize("gamma", [1, 4, 16])
@@ -261,23 +276,83 @@ class TestDecodeParity:
                                    rtol=2e-5, atol=2e-5)
         assert np.array_equal(np.argmax(np.asarray(got), -1),
                               np.argmax(np.asarray(want), -1))
-        assert np.array_equal(np.asarray(got_pages[0]["k"]),
-                              np.asarray(want_pages[0]["k"]))
+        assert np.array_equal(np.asarray(got_pages[0]["kv"]),
+                              np.asarray(want_pages[0]["kv"]))
 
     def test_inactive_rows_write_trash_not_pages_kernel(self, params):
         B, L, page = 2, 16, 4
         rng = np.random.default_rng(2)
         pages, bt = _paged_state(params, B, L, page, 3, rng)
         n_pages = L // page
-        before = [np.asarray(c["k"]).copy() for c in pages]
+        before = [np.asarray(c["kv"]).copy() for c in pages]
         tok = jnp.asarray(rng.integers(0, CFG.vocab, B))
         active = jnp.asarray([True, False])
         _, pages = decode_step_paged(
             params, tok, jnp.full((B,), 3, jnp.int32), pages, bt, CFG,
             page_size=page, length=L, active=active, impl="kernel")
         for lyr, b4 in zip(pages, before):
-            after = np.asarray(lyr["k"])
+            after = np.asarray(lyr["kv"])
             assert np.array_equal(after[1 + n_pages:], b4[1 + n_pages:])
+
+
+@pytest.mark.parametrize("kind", ["single", "mesh"])
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+class TestPackedPool:
+    """The pool's layout, K beside V on the minor axis of one buffer a
+    layer: every kernel reads it and every writer fills it alike, plain or
+    quantized pages, on one device or mounted on the mesh."""
+
+    def test_layout_is_k_beside_v(self, params, kv_dtype, kind):
+        B, L, page = 4, 16, 4
+        pages, bt = _paged_state(params, B, L, page, 6,
+                                 np.random.default_rng(3), kv_dtype)
+        hd = CFG.d_model // CFG.heads
+        assert pages[0]["kv"].shape == (1 + B * (L // page), CFG.heads,
+                                        page, 2 * hd)
+        assert set(pages[0]) == ({"kv"} if kv_dtype is None
+                                 else {"kv", "k_scale", "v_scale"})
+        k, v = split_kv(pages[0]["kv"])
+        assert np.array_equal(np.asarray(pack_kv(k, v)),
+                              np.asarray(pages[0]["kv"]))
+        # position t of row b sits in page bt[b, t // page] at t % page,
+        # its K in lanes [0, hd) and its V in [hd, 2*hd)
+        for got in paged_gather(pages[:1], bt, L):
+            t, b = 5, 2
+            pg = int(bt[b, t // page])
+            want = np.asarray(pages[0]["kv"])[pg, :, t % page]
+            if kv_dtype is None:
+                assert np.array_equal(np.asarray(got["k"])[b, :, t],
+                                      want[:, :hd])
+                assert np.array_equal(np.asarray(got["v"])[b, :, t],
+                                      want[:, hd:])
+
+    def test_kernel_matches_gather_oracle_and_writeback_bytes(
+            self, params, kv_dtype, kind):
+        B, L, page, W = 4, 32, 4, 3
+        rng = np.random.default_rng(21)
+        pages, bt = _paged_state(params, B, L, page, 14, rng, kv_dtype)
+        wtoks = jnp.asarray(rng.integers(0, CFG.vocab, (B, W)))
+        # a page-crossing window, a fresh slot, mid-page, a page's start
+        pos = jnp.asarray([7, 0, 13, 8], jnp.int32)
+        want, want_pages = decode_window_paged(
+            params, wtoks, pos, pages, bt, CFG, page_size=page, length=L,
+            impl="gather")
+        got, got_pages = decode_window_paged(
+            params, wtoks, pos, pages, bt, CFG, page_size=page, length=L,
+            impl="kernel", **mount(kind))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=5e-5, atol=5e-5)
+        assert np.array_equal(np.argmax(np.asarray(got), -1),
+                              np.argmax(np.asarray(want), -1))
+        # layer 0 projects the same inputs on both paths: the kernel's
+        # in-launch scatter (one device) and _pool_write_rows (mesh) write
+        # _paged_writeback's bytes, values and scales, off the trash page
+        assert set(got_pages[0]) == set(want_pages[0])
+        for kk in want_pages[0]:
+            assert np.array_equal(np.asarray(got_pages[0][kk])[1:],
+                                  np.asarray(want_pages[0][kk])[1:]), kk
+        assert not np.array_equal(np.asarray(got_pages[0]["kv"])[1:],
+                                  np.asarray(pages[0]["kv"])[1:])
 
 
 class TestEngineSmoke:

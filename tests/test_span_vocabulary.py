@@ -6,6 +6,7 @@ profile allocates nothing but its row of the span log."""
 
 import json
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -103,6 +104,12 @@ def generation_run():
             t.start()
         for t in threads:
             t.join(timeout=300)
+        # the loop's idle round comes once nothing is in flight: on a loaded
+        # machine the engine would otherwise be stopped before it
+        deadline = time.monotonic() + 30.0
+        while (time.monotonic() < deadline and
+               not any(name == "engine.idle" for name, *_ in tr.span_log())):
+            time.sleep(0.01)
     assert all(replies[i][-1].get("done") for i in range(len(jobs)))
     return ({name for name, *_ in tr.span_log()},
             tr.get_flight_recorder().traces())
