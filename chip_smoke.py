@@ -9,6 +9,10 @@ reference computed in the same process:
   B. decode    — the 12-layer 768-wide decoder behind ``GenerationEngine``
                  (HTTP ``/generate``), then int8 KV pages on ``ContinuousDecoder``
   C. train     — ``LightGBMClassifier`` on a HIGGS-shaped 1M x 28 frame
+  F. hybrid    — two layers of MiniCPM-SALA at their published widths (one
+                 block-sparse, one lightning) on ``ContinuousDecoder``: a
+                 document longer than ``dense_len`` registered as a prefix,
+                 then a hit decodes a few tokens past it
 
 ``--chips 4`` runs instead ONLY the two paths that exist across chips and what
 each is compared with: D. data-parallel GBDT over a 4-device ``data`` mesh,
@@ -185,6 +189,18 @@ def sizes(small):
                                       position="rope", dtype=jnp.bfloat16),
             slots=4, max_len=128, max_new=6,
             engine_kw=dict(prefill_chunk=16),
+            # phase F at toy widths: the tests' tiny hybrid, dense_len 64
+            hybrid=dict(hidden_size=64, intermediate_size=128,
+                        num_attention_heads=4, num_key_value_heads=2,
+                        head_dim=16, lightning_nh=4, lightning_nkv=4,
+                        lightning_head_dim=16, vocab_size=256,
+                        dim_model_base=16, compute_dtype="float32",
+                        param_dtype="float32",
+                        sparse_config=dict(kernel_size=4, kernel_stride=2,
+                                           block_size=8, topk=6,
+                                           window_size=16, init_blocks=1,
+                                           dense_len=64)),
+            hybrid_len=160,
             pool_decoder=TransformerConfig(vocab=256, layers=2, d_model=64,
                                            heads=4, d_ff=128, max_len=64,
                                            causal=True, dtype=jnp.bfloat16),
@@ -201,6 +217,8 @@ def sizes(small):
                                   causal=True, norm="rmsnorm",
                                   position="rope", dtype=jnp.bfloat16),
         slots=16, max_len=2048, max_new=32, engine_kw={},
+        # phase F: 8,384-token document (dense_len 8,192), pages of 64
+        hybrid_len=8704,
         # the generation cell's decoder and engine (benchmarks/configs/
         # gpt2_xl.json, workloads/gpt2xl_generate_closed.json): GPT-2 XL,
         # 8 slots of 1024 positions in pages of 16, 256-token chunks
@@ -213,6 +231,68 @@ def sizes(small):
         # one group is longer than the engine's default prefill_chunk (256)
         prompt_lens=[12, 12, 12, 64, 64, 64, 300, 300],
         gbdt_rows=1_000_000, gbdt_test=100_000, gbdt_bins=255, gbdt_iters=5)
+
+
+# ---------------------------------------------------------------------------
+# F. hybrid (the cell sala_docqa_closed8's model, two layers of it)
+
+
+def phase_hybrid(sz, seed, small):
+    """A document past ``dense_len`` goes in as a prefix miss (chunked
+    prefill, pages and state snapshot stored), then the same document with
+    another question is a hit: snapshot restored, pages shared, the sparse
+    layer selecting blocks in every tick. The hit's tokens are judged by the
+    benchmark's plain float32 reference, teacher-forced."""
+    from benchmarks import run as bench_run
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    ck = Checks()
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "minicpm_sala_l8.json")) as fh:
+        config = json.load(fh)
+    config.update(num_hidden_layers=2, mixer_types=config["mixer_types"][:2])
+    if small:
+        config.update(sz["hybrid"])
+    reference = bench_run.load_by_path("references", config["reference"])
+    cfg = bench_run.load_by_path("drivers", "generate_docs").program_config(
+        config, sz["hybrid_len"])
+    t0 = time.perf_counter()
+    params = reference.make_weights(config, seed)
+    dec = ContinuousDecoder(params, cfg, max_slots=2,
+                            max_len=sz["hybrid_len"],
+                            page_size=config["sparse_config"]["block_size"],
+                            **sz["engine_kw"])
+    rng = np.random.default_rng(seed)
+    dense_len = config["sparse_config"]["dense_len"]
+    doc = rng.integers(1, cfg.vocab, dense_len + 3 * dec._page).astype(
+        np.int32)
+    served = []
+    for n in (24, 40):                          # a miss, then a hit
+        prompt = np.concatenate(
+            [doc, rng.integers(1, cfg.vocab, n).astype(np.int32)])
+        req = dec.submit(prompt, sz["max_new"], prefix_key="doc",
+                         prefix_len=doc.size)
+        drain(dec, [req])
+        dec.result(req)
+        served.append((prompt, req.tokens))
+    run_s = time.perf_counter() - t0
+    stats = dec._kv.stats
+    ck.require(dec.stats["prefix_hits"] == 1
+               and stats.get("state_snapshots_restored") == 1,
+               f"the second request was no restored hit: {dec.stats}")
+    ck.require(stats["attn_ticks_sparse"] > 0 and not stats["attn_ticks_gather"],
+               f"no tick selected blocks on the kernel: {stats}")
+    t0 = time.perf_counter()
+    gaps = np.concatenate([reference.served_token_gaps(
+        params, config, p, o, sz["hybrid_len"]) for p, o in served])
+    ck.require(float(gaps.max()) <= TIE_TOL,
+               f"a served token lies {gaps.max():.3f} std-devs under the "
+               f"float32 reference's best (limit {TIE_TOL})")
+    return ck, dict(hybrid_run_s=run_s,
+                    hybrid_reference_s=time.perf_counter() - t0,
+                    context=int(doc.size), gap_max=float(gaps.max()),
+                    gap_mean=float(gaps.mean()),
+                    attn_ticks_sparse=stats["attn_ticks_sparse"],
+                    snapshot_bytes=dec._kv.snapshot_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -698,7 +778,7 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the two cross-chip paths (D, E)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--phase", action="append", choices=list("ABCDE"),
+    ap.add_argument("--phase", action="append", choices=list("ABCDEF"),
                     help="run only this phase (repeatable; for fault-finding)")
     args = ap.parse_args(argv)
 
@@ -757,6 +837,8 @@ def main(argv=None):
                   "B": ("B.decode", lambda: phase_decode(
                       sz, args.seed, args.small)),
                   "C": ("C.train", lambda: phase_train(
+                      sz, args.seed, args.small)),
+                  "F": ("F.hybrid", lambda: phase_hybrid(
                       sz, args.seed, args.small))}
     for key, (name, run) in phases.items():
         if args.phase and key not in args.phase:

@@ -25,12 +25,28 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["TransformerConfig", "init_transformer", "transformer_apply",
+__all__ = ["TransformerConfig", "SparseAttention", "init_transformer", "transformer_apply",
            "train_step", "param_shardings", "BERT_BASE", "BERT_MINI",
            "DECODER_MINI", "generate", "generate_cached",
            "decode_step", "init_kv_cache", "decode_window_ragged",
            "init_paged_cache", "paged_gather", "paged_scatter_rows",
            "decode_step_paged", "decode_window_paged"]
+
+
+class SparseAttention(NamedTuple):
+    """InfLLM-V2 block-sparse attention's sizes (the ``sparse`` mixer):
+    compressed keys are means over ``kernel_size`` positions every
+    ``kernel_stride``; a query past ``dense_len`` of context attends the
+    first ``init_blocks`` blocks of ``block_size`` positions, the blocks
+    over its last ``window_size`` positions and the best-scored of the
+    rest, ``topk`` in all, chosen once per KV group."""
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    block_size: int = 64
+    topk: int = 64
+    window_size: int = 2048
+    init_blocks: int = 1
+    dense_len: int = 8192
 
 
 class TransformerConfig(NamedTuple):
@@ -65,6 +81,25 @@ class TransformerConfig(NamedTuple):
     norm: str = "layernorm"        # "layernorm" | "rmsnorm"
     position: str = "learned"      # "learned" | "rope"
     rope_theta: float = 10000.0
+    #: a HYBRID decoder (``models/zoo/hybrid.py``): one mixer kind a layer,
+    #: ``"lightning"`` (linear attention over a recurrent (hd x hd) float32
+    #: state a head, RoPE) or ``"sparse"`` (grouped-query block-sparse
+    #: softmax attention, no positions). Non-empty selects that block:
+    #: bias-free projections, per-head QK RMSNorm, a sigmoid output gate,
+    #: SwiGLU, RMSNorm, and the three scalings below. Empty keeps the dense
+    #: multi-head block above, unchanged.
+    mixers: tuple = ()
+    #: KV heads of the sparse layers (0 = ``heads``) and an explicit head
+    #: size (0 = ``d_model // heads``)
+    kv_heads: int = 0
+    head_dim: int = 0
+    sparse: Optional[SparseAttention] = None
+    #: muP: the embedding is multiplied by ``embed_scale``, every residual
+    #: branch by ``residual_scale``, the final hidden state by
+    #: ``logit_scale`` before the head
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
     def is_moe_layer(self, i: int) -> bool:
         return (self.moe_experts > 0 and self.moe_every > 0
@@ -81,6 +116,9 @@ BERT_MINI = TransformerConfig(vocab=1024, layers=4, d_model=256, heads=8,
 
 
 def init_transformer(cfg: TransformerConfig, seed: int = 0) -> Dict:
+    if cfg.mixers:
+        from .hybrid import init_hybrid
+        return init_hybrid(cfg, seed)
     rng = np.random.default_rng(seed)
 
     def dense(din, dout, scale=None):
@@ -225,6 +263,18 @@ def transformer_apply(params: Dict, ids: jnp.ndarray,
     ``dropped``: over-capacity token count} — a functional return, not an
     out-parameter, so it survives jit (a mutated-dict argument would be a
     trace-local copy)."""
+    if cfg.mixers:
+        # a hybrid decoder (models/zoo/hybrid.py): the window over an empty
+        # cache, one device; its hidden states carry muP's logit scaling
+        if mesh is not None or mask is not None:
+            raise ValueError("a hybrid decoder takes no mesh and no mask")
+        from .hybrid import init_hybrid_cache, window_contiguous
+        B, S = ids.shape
+        hidden, _ = window_contiguous(
+            params, ids, jnp.zeros((B,), jnp.int32),
+            init_hybrid_cache(cfg, B, S), cfg)
+        aux = {"balance": jnp.float32(0.0), "dropped": jnp.float32(0.0)}
+        return (hidden, aux) if return_aux else hidden
     if cfg.norm not in ("layernorm", "rmsnorm"):
         raise ValueError(f"cfg.norm {cfg.norm!r} (layernorm | rmsnorm)")
     if cfg.position not in ("learned", "rope"):
@@ -445,7 +495,11 @@ def generate(params: Dict, prompt_ids, cfg: TransformerConfig,
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int):
-    """Per-layer (B, H, L, D) key/value buffers for incremental decoding."""
+    """Per-layer (B, H, L, D) key/value buffers for incremental decoding
+    (a hybrid decoder's entries: ``hybrid.init_hybrid_cache``)."""
+    if cfg.mixers:
+        from .hybrid import init_hybrid_cache
+        return init_hybrid_cache(cfg, batch, max_len)
     hd = cfg.d_model // cfg.heads
     shape = (batch, cfg.heads, max_len, hd)
     return [{"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
@@ -481,6 +535,11 @@ def decode_step_ragged(params: Dict, tokens: jnp.ndarray, pos: jnp.ndarray,
     are per-row RoPE/learned-position gathers, a vmapped per-row cache
     scatter, and the per-row key mask ``arange(L) <= pos[:, None]``.
     """
+    if cfg.mixers:
+        from .hybrid import head, window_contiguous
+        hidden, cache = window_contiguous(params, tokens[:, None], pos,
+                                          cache, cfg, active=active)
+        return head(params, hidden[:, 0]), cache
     if cfg.moe_experts:
         raise ValueError("cached decoding does not support MoE layers")
     dt = cfg.dtype
@@ -552,6 +611,14 @@ def prefill_cache(params: Dict, ids: jnp.ndarray, length,
     token-by-token prefill — the standard serving split (prefill batched,
     decode incremental).
     """
+    if cfg.mixers:
+        from .hybrid import head, init_hybrid_cache, window_contiguous
+        B = ids.shape[0]
+        hidden, cache = window_contiguous(
+            params, ids, jnp.zeros((B,), jnp.int32),
+            init_hybrid_cache(cfg, B, max_len), cfg, n_valid=length,
+            last_only=True)
+        return head(params, hidden), cache
     if cfg.moe_experts:
         raise ValueError("cached decoding does not support MoE layers")
     dt = cfg.dtype
@@ -638,6 +705,11 @@ def decode_window_ragged(params: Dict, tokens: jnp.ndarray,
     window's K/V land at ``pos[b]..pos[b]+W-1`` in that row's cache —
     exactly :func:`decode_window` per row with a scalar start.
     """
+    if cfg.mixers:
+        from .hybrid import head, window_contiguous
+        hidden, cache = window_contiguous(params, tokens, pos, cache, cfg,
+                                          active=active)
+        return head(params, hidden), cache
     if cfg.moe_experts:
         raise ValueError("cached decoding does not support MoE layers")
     dt = cfg.dtype
@@ -731,6 +803,9 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
     layer dict gains ``(num_pages, H, page_size)`` ``k_scale``/
     ``v_scale`` arrays (see ``ops/kv_quant.py``)."""
     from ...ops.kv_quant import SCALE_DTYPE, kv_store_dtype
+    if cfg.mixers:
+        raise ValueError("a hybrid decoder's pool also holds a state row a "
+                         "slot: hybrid.init_hybrid_pool")
     hd = cfg.d_model // cfg.heads
     shape = (num_pages, cfg.heads, page_size, 2 * hd)
     store = kv_store_dtype(kv_dtype)
@@ -915,6 +990,12 @@ def decode_step_paged(params: Dict, tokens: jnp.ndarray, pos: jnp.ndarray,
       exactly 0). ``length`` is the logical cache length (the contiguous
       L); every ``pos`` must be < length."""
     from ...ops.paged_attention import resolve_impl
+    if cfg.mixers:
+        logits, pages = decode_window_paged(
+            params, tokens[:, None], pos, cache_pages, block_tables, cfg,
+            page_size=page_size, length=length, active=active, impl=impl,
+            mesh=mesh)
+        return logits[:, 0], pages
     if resolve_impl(impl) == "kernel":
         logits, pages = _decode_window_paged_kernel(
             params, tokens[:, None], pos.astype(jnp.int32), cache_pages,
@@ -937,14 +1018,32 @@ def decode_window_paged(params: Dict, tokens: jnp.ndarray,
                         length: int,
                         active: Optional[jnp.ndarray] = None,
                         impl: Optional[str] = None,
-                        mesh=None, slot_axis=None, head_axis=None):
+                        mesh=None, slot_axis=None, head_axis=None,
+                        n_valid=None, slot=None, last_only: bool = False):
     """Paged window decode — the speculative verify and chunked-prefill
     primitive. Row b's window writes positions ``pos[b]..pos[b]+W-1``
     into its pages; every such position must be < ``length`` (the engine
     sizes allocations so windows never clamp). ``impl`` selects the
     Pallas kernel (default) or PR 7's gather path exactly as in
-    :func:`decode_step_paged`."""
+    :func:`decode_step_paged`.
+
+    A hybrid decoder (``cfg.mixers``) also carries state rows in
+    ``cache_pages`` and takes three more arguments, which the dense block
+    refuses: ``n_valid`` (B,), the real lanes of each row (padding must not
+    reach a state); ``slot``, the state row of a one-row prefill window;
+    ``last_only``, logits (B, vocab) of lane ``n_valid - 1`` alone."""
     from ...ops.paged_attention import resolve_impl
+    if cfg.mixers:
+        if mesh is not None:
+            raise ValueError("a hybrid decoder takes no mesh")
+        from .hybrid import window_paged
+        return window_paged(params, tokens, pos, cache_pages, block_tables,
+                            cfg, page_size=page_size,
+                            impl=resolve_impl(impl), n_valid=n_valid,
+                            active=active, slot=slot, last_only=last_only)
+    if n_valid is not None or slot is not None or last_only:
+        raise ValueError("n_valid, slot and last_only belong to a hybrid "
+                         "decoder's window")
     W = tokens.shape[1]
     pos = pos.astype(jnp.int32)
     if resolve_impl(impl) == "kernel":
@@ -1058,6 +1157,9 @@ def generate_beam(params: Dict, prompt_ids, cfg: TransformerConfig,
         raise ValueError("generate_beam() needs cfg.causal=True")
     if num_beams < 1:
         raise ValueError("num_beams must be >= 1")
+    if cfg.mixers:
+        raise ValueError("generate_beam() reorders K/V rows; a hybrid "
+                         "decoder's cache is not rows of K/V alone")
     if num_beams > cfg.vocab:
         raise ValueError(f"num_beams {num_beams} exceeds vocab {cfg.vocab} "
                          "(only vocab distinct first tokens exist)")

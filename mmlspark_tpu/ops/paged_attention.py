@@ -102,7 +102,8 @@ import jax.numpy as jnp
 from .pallas_kernels import _LANE, _round_up
 from .kv_quant import quantize_kv
 
-__all__ = ["paged_attention", "paged_attention_window", "resolve_impl",
+__all__ = ["paged_attention", "paged_attention_window",
+           "paged_attention_selected", "resolve_impl",
            "sublane_multiple", "aligned_page_size", "pack_kv", "split_kv",
            "stored_kv"]
 
@@ -560,6 +561,90 @@ def _pa_fused_call_q(q, kv_new, kvq_new, ks_new, vs_new, kv_pages, k_scale,
     )
     return call(block_tables, pos, wlo, whi, q, kv_new, kvq_new, ks_new,
                 vs_new, kv_pages, k_scale, v_scale)
+
+
+# ---- block selection (grouped-query sparse decode) --------------------------
+# One query a row, and for each (row, KV head) a LIST of logical pages to
+# attend: the blocks a sparse layer's scorer chose (models/zoo/hybrid.py)
+# instead of the row's whole block table. Grid (rows, KV heads, listed
+# pages); the page block is ONE head's (1, 1, page, 2*hd) slice of a page,
+# its physical index read through the block table from the scalar-prefetched
+# list; the ``hg`` query heads that share the KV head are the kernel's
+# window, so one page DMA serves them all. An entry below 0 is no page: the
+# step folds nothing and its DMA is the previous step's page again. Read
+# only: the engine writes the token's K/V row before it selects.
+
+def _pa_select_kernel(bt_ref, sel_ref, len_ref, q_ref, kv_ref, o_ref,
+                      m_scr, l_scr, acc_scr, *, scale, page, n_sel, G):
+    from jax.experimental import pallas as pl
+
+    b, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    pl.when(j == 0)(lambda: _init(m_scr, l_scr, acc_scr))
+    lp = sel_ref[b * G + g, j]
+
+    @pl.when(lp >= 0)
+    def _compute():
+        # the blocks are one head's: H = 1, the window the hg query heads
+        _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref),
+                    lp, len_ref[b], scale, page)
+
+    pl.when(j == n_sel - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _pa_select_call(q, kv_pages, block_tables, sel, lengths, *,
+                    scale, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, G, hg, hd = q.shape
+    page, n_sel = kv_pages.shape[2], sel.shape[1]
+    kernel = functools.partial(_pa_select_kernel, scale=scale, page=page,
+                               n_sel=n_sel, G=G)
+    row = pl.BlockSpec((1, 1, hg, hd), lambda b, g, j, *_: (b, g, 0, 0))
+
+    def page_of(b, g, j, bt, sel_, *_):
+        return (bt[b, jnp.maximum(sel_[b * G + g, j], 0)], g, 0, 0)
+
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, G, n_sel),
+            in_specs=[row, pl.BlockSpec((1, 1, page, 2 * hd), page_of)],
+            out_specs=row,
+            scratch_shapes=[_vmem((1, hg, _LANE), jnp.float32),
+                            _vmem((1, hg, _LANE), jnp.float32),
+                            _vmem((1, hg, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret)
+    return call(block_tables, sel, lengths, q, kv_pages)
+
+
+def paged_attention_selected(q, kv_pages, block_tables, sel_pages, lengths,
+                             *, scale: Optional[float] = None,
+                             interpret: Optional[bool] = None):
+    """Grouped-query decode attention over LISTED pages, read in place.
+
+    ``q`` (B, G, hg, hd): one query a row, ``hg`` query heads for each of
+    the ``G`` KV heads of the packed pool ``kv_pages`` (N, G, page, 2*hd).
+    ``sel_pages`` (B, G, n) int32 holds, for each (row, KV head), the
+    LOGICAL pages to attend (their order is free, an entry below 0 is
+    skipped); they are mapped to physical pages through ``block_tables``
+    (B, P). Keys at positions ``>= lengths[b]`` are masked, so the page that
+    holds the row's newest token may be listed whole. A row with nothing
+    listed yields zeros. Returns (B, G, hg, hd) in ``q.dtype``."""
+    if interpret is None:
+        interpret = _auto_interpret()
+    B, G, hg, hd = q.shape
+    if scale is None:
+        scale = float(1.0 / math.sqrt(hd))
+    return _pa_select_call(
+        q, kv_pages, block_tables.astype(jnp.int32),
+        sel_pages.reshape(B * G, -1).astype(jnp.int32),
+        lengths.astype(jnp.int32), scale=scale, interpret=bool(interpret))
 
 
 # ---- mesh mount (shard_map) -------------------------------------------------

@@ -82,6 +82,7 @@ from ..models.zoo.transformer import (TransformerConfig,
                                       decode_window_paged,
                                       paged_scatter_rows,
                                       prefill_cache, shardings_for)
+from ..models.zoo.hybrid import SLOT_KEYS as _SLOT_KEYS
 from ..ops.padding import bucket_size
 from ..ops.paged_attention import (resolve_impl as _resolve_paged_attn,
                                    _auto_interpret as _pa_auto_interpret)
@@ -283,11 +284,21 @@ def _extend_program(cfg, page, L, donate, attn="kernel",
     kernel impl reads pages in place (f32-accumulation tolerance).
     Under a mesh only heads shard (slot_axis stays None: the extension
     operates on a single B=1 row, which cannot split over dp)."""
-    def _extend(params, ids, start, bufs, bt_row):
-        return decode_window_paged(params, ids, start, bufs, bt_row,
-                                   cfg, page_size=page, length=L,
-                                   active=None, impl=attn, mesh=mesh,
-                                   slot_axis=None, head_axis=head_axis)
+    if cfg.mixers:
+        # a hybrid decoder's window also names the slot whose state rows it
+        # continues and how many of its lanes are real (padding must not
+        # reach a state), and returns the logits of the last real lane only
+        def _extend(params, ids, start, bufs, bt_row, slot, n_valid):
+            return decode_window_paged(
+                params, ids, start, bufs, bt_row, cfg, page_size=page,
+                length=L, impl=attn, n_valid=n_valid, slot=slot,
+                last_only=True)
+    else:
+        def _extend(params, ids, start, bufs, bt_row):
+            return decode_window_paged(params, ids, start, bufs, bt_row,
+                                       cfg, page_size=page, length=L,
+                                       active=None, impl=attn, mesh=mesh,
+                                       slot_axis=None, head_axis=head_axis)
 
     return jax.jit(_extend, donate_argnums=(3,) if donate else ())
 
@@ -301,7 +312,8 @@ def _copy_pages_program(donate):
     dim 0, for every buffer), so CoW admission needs no quant-specific
     path."""
     def _copy(bufs, src, dst):
-        return [{kk: c[kk].at[dst].set(c[kk][src])
+        return [{kk: c[kk] if kk in _SLOT_KEYS
+                 else c[kk].at[dst].set(c[kk][src])
                  for kk in c} for c in bufs]
 
     return jax.jit(_copy, donate_argnums=(0,) if donate else ())
@@ -314,9 +326,29 @@ def _compact_program(donate):
     meaningless without its scale row, so they remap through the SAME
     permutation in the same dispatch)."""
     def _compact(bufs, perm):
-        return [{kk: c[kk][perm] for kk in c} for c in bufs]
+        return [{kk: c[kk] if kk in _SLOT_KEYS else c[kk][perm] for kk in c}
+                for c in bufs]
 
     return jax.jit(_compact, donate_argnums=(0,) if donate else ())
+
+
+@functools.lru_cache(maxsize=None)
+def _state_programs(donate):
+    """A hybrid decoder's prefix is pages plus a snapshot: ``snapshot``
+    copies one slot's row out of every buffer that holds a row a slot (a
+    linear-attention state, a sparse layer's compressed keys: a dict a
+    layer), ``restore`` copies such a list back into a slot's rows, in
+    place."""
+    def _snapshot(bufs, slot):
+        return [{kk: c[kk][slot] for kk in c if kk in _SLOT_KEYS}
+                for c in bufs]
+
+    def _restore(bufs, snap, slot):
+        return [{kk: c[kk].at[slot].set(rows[kk]) if kk in rows else c[kk]
+                 for kk in c} for c, rows in zip(bufs, snap)]
+
+    return (jax.jit(_snapshot),
+            jax.jit(_restore, donate_argnums=(0,) if donate else ()))
 
 
 @functools.lru_cache(maxsize=None)
@@ -628,6 +660,26 @@ class ContinuousDecoder:
             raise ValueError("continuous decoding does not support MoE")
         if not cfg.causal:
             raise ValueError("ContinuousDecoder needs cfg.causal=True")
+        #: a hybrid decoder (``cfg.mixers``: linear-attention state beside
+        #: sparse-attention pages) runs the same tick, chunk program,
+        #: insertion and prefix store; what it cannot do yet it refuses here
+        self._hybrid = bool(cfg.mixers)
+        if self._hybrid:
+            from ..models.zoo.hybrid import check_config
+            from ..ops.kv_quant import resolve_kv_dtype
+            check_config(cfg)
+            for given, why in (
+                    (draft_params is not None,
+                     "a draft model: the verify window would have to roll "
+                     "rejected tokens back out of a linear-attention state"),
+                    (resolve_kv_dtype(kv_dtype) is not None,
+                     "kv_dtype: the sparse layers' compressed keys and the "
+                     "selected-block kernel read bf16 pages only"),
+                    (mesh is not None,
+                     "a mesh: the state rows and the two decode kernels "
+                     "have no mount")):
+                if given:
+                    raise ValueError(f"a hybrid decoder does not take {why}")
         #: speculative mode: a draft model proposes gamma greedy tokens per
         #: round PER SLOT; the target verifies all slots' windows in one
         #: ragged forward and each slot advances by its own accepted
@@ -847,7 +899,9 @@ class ContinuousDecoder:
                                page_size=self._page,
                                kv_dtype=self._kv_dtype,
                                make_buffer=_pool_buffer,
-                               sharding=pool_sharding)
+                               sharding=pool_sharding, slots=self._S,
+                               slot_positions=self._P_max * self._page,
+                               max_snapshots=int(prefix_cache_size))
         self._chunk = int(prefill_chunk)
         self._defrag_thr = (max(1, self._kv.num_pages // 4)
                             if defrag_threshold is None
@@ -965,6 +1019,8 @@ class ContinuousDecoder:
                                             _copy_pages_program(donate))
         self._compact_j = _audit_program("compact",
                                          _compact_program(donate))
+        if self._hybrid:
+            self._snapshot_j, self._restore_j = _state_programs(donate)
         #: key → (prefix token copy, pool prefix hash, prefix length);
         #: the PAGES live in the pool's prefix registry — this host map
         #: adds the engine-facing key, LRU promotion and FIFO eviction
@@ -994,6 +1050,9 @@ class ContinuousDecoder:
         #: slot → [request, prefill offset] for prompts mid-chunked-prefill
         #: (occupied but device-inactive until the final chunk activates)
         self._chunking: Dict[int, list] = {}
+        #: slot → prefix length at which a hybrid decoder's chunked prefill
+        #: owes the prefix store a registration (pages plus state snapshot)
+        self._registering: Dict[int, int] = {}
         #: recent chunk sizes in tokens (tests + bench assert the budget
         #: bound from this)
         self._chunk_trace: List[int] = []
@@ -1150,6 +1209,11 @@ class ContinuousDecoder:
                 "emitted": list(req.pre_emitted) + list(req.tokens),
             }
             kv = None
+            if export_kv and self._hybrid:
+                raise ValueError(
+                    "a hybrid decoder's session does not export its KV "
+                    "(the linear-attention state is not in the blob yet): "
+                    "checkpoint with export_kv=False and restore cold")
             if export_kv and not req.done and not self._spec:
                 slot = next((i for i in range(self._S)
                              if self._slot_req[i] is req), None)
@@ -1248,6 +1312,10 @@ class ContinuousDecoder:
     def _adopt_warm(self, sess, kv_blob, forced, remaining, temperature,
                     top_k, top_p, seed, sid, emitted) -> _Request:
         """Warm-path slot occupation for :meth:`restore_session`."""
+        if self._hybrid:
+            raise ValueError("warm adopt is not supported for a hybrid "
+                             "decoder (the linear-attention state is not "
+                             "in the blob yet); restore cold instead")
         if self._spec:
             raise ValueError("warm adopt is not supported on speculative "
                              "engines (the draft cache is not exported); "
@@ -1709,6 +1777,14 @@ class ContinuousDecoder:
             # is honored: reuse just that much (the window rewrites the
             # rest), so one stored key serves nested prefixes
             if req.prefix_len is not None:
+                if self._hybrid and req.prefix_len < plen:
+                    # a linear-attention state cannot be rolled back: the
+                    # snapshot exists at the stored length and nowhere else
+                    raise ValueError(
+                        f"prefix_key {req.prefix_key!r}: prefix_len "
+                        f"{req.prefix_len} is shorter than the stored "
+                        f"{plen}-token prefix, whose state snapshot a "
+                        f"hybrid decoder cannot shorten")
                 plen = min(plen, req.prefix_len)
             if P < plen or not np.array_equal(req.prompt[:plen],
                                               stored_toks[:plen]):
@@ -1716,8 +1792,9 @@ class ContinuousDecoder:
                     f"prefix_key {req.prefix_key!r}: prompt does not "
                     f"start with the stored {plen}-token prefix")
             # whole-prompt hits re-run the last prefix token — one row —
-            # to recover its logits
-            start = plen if P > plen else plen - 1
+            # to recover its logits (a hybrid decoder keeps them with the
+            # snapshot instead: its state is already past that token)
+            start = plen if P > plen or self._hybrid else plen - 1
             #: pages strictly below the write boundary are shared; the
             #: boundary page itself is COPIED (the suffix window writes
             #: into it, and shared pages are never written)
@@ -1743,6 +1820,22 @@ class ContinuousDecoder:
             # LRU promotion: the hit entry becomes the newest
             self._prefix_store[req.prefix_key] = \
                 self._prefix_store.pop(req.prefix_key)
+            if self._hybrid:
+                # the snapshot into the slot's state rows, then the suffix
+                # through the chunk scheduler (a state must not see a
+                # window's padding, and chunks interleave with the ticks)
+                with _tracing.span("decoder.state_restore", slot=slot,
+                                   tokens=plen):
+                    snap = self._kv.prefix_state(phash)
+                    self._kv.buffers = self._restore_j(
+                        self._kv.buffers, snap["rows"],
+                        jnp.asarray(slot, jnp.int32))
+                if P > plen:
+                    self._chunking[slot] = [req, plen]
+                else:
+                    self._insert_chunk_locked([(slot, req)], snap["logits"],
+                                              [], [])
+                return True
             # suffix window over the slot's own pages. Bucketed pad: the
             # garbage K/V a padded lane writes sits at positions the
             # engine overwrites before any mask ever exposes them (or
@@ -1763,6 +1856,17 @@ class ContinuousDecoder:
             self._insert_chunk_locked([(slot, req)], w_logits[:, Sn - 1], [],
                                self._draft_prompt_rows(req))
             return True
+        if self._hybrid:
+            # miss: the chunk scheduler prefills the prompt, with a chunk
+            # boundary at the prefix's end, where it registers the pages
+            # and the state snapshot (_advance_chunks)
+            if not self._begin_chunked(slot, req):
+                return False
+            self._kv.note_prefix_miss()
+            if self._prefix_store_cap > 0:
+                self._registering[slot] = (
+                    req.prefix_len if req.prefix_len is not None else P)
+            return True
         # miss: full prefill into the slot's own pages; cap the pad
         # bucket at max_len (a 40-token prompt in a 48-len cache must
         # not inflate to a 64-wide prefill)
@@ -1770,6 +1874,7 @@ class ContinuousDecoder:
             self._ensure_pages([(slot, req)])
         except PoolExhausted:
             return False
+        self._kv.note_prefix_miss()
         ids = self._padded_ids(req.prompt, self._L)
         logits, row_cache = self._prefill(
             self._params, jnp.asarray(ids), jnp.asarray([P], jnp.int32))
@@ -1787,18 +1892,24 @@ class ContinuousDecoder:
             # the trusted prefix region (the boundary page's tail may go
             # stale, but every joining request COPIES that page and
             # rewrites the tail before exposing it).
-            plen = req.prefix_len if req.prefix_len is not None else P
-            phash = _prefix_hash(req.prompt[:plen])
-            self._kv.register_prefix(
-                phash, self._slot_pages[slot][:-(-plen // self._page)],
-                plen)
-            if len(self._prefix_store) >= self._prefix_store_cap:
-                _, old_hash, _ = self._prefix_store.pop(
-                    next(iter(self._prefix_store)))
-                self._kv.release_prefix(old_hash)
-            self._prefix_store[req.prefix_key] = (
-                req.prompt[:plen].copy(), phash, plen)
+            self._store_prefix(
+                slot, req, req.prefix_len if req.prefix_len is not None else P)
         return True
+
+    def _store_prefix(self, slot: int, req: _Request, plen: int, state=None):
+        """Register the first ``plen`` positions of ``slot``'s pages (and a
+        hybrid decoder's ``state`` snapshot at exactly that length) under
+        the request's key, evicting the oldest key of a full store."""
+        phash = _prefix_hash(req.prompt[:plen])
+        self._kv.register_prefix(
+            phash, self._slot_pages[slot][:-(-plen // self._page)], plen,
+            state=state)
+        if len(self._prefix_store) >= self._prefix_store_cap:
+            _, old_hash, _ = self._prefix_store.pop(
+                next(iter(self._prefix_store)))
+            self._kv.release_prefix(old_hash)
+        self._prefix_store[req.prefix_key] = (
+            req.prompt[:plen].copy(), phash, plen)
 
     def _draft_prompt_rows(self, req: _Request):
         """Spec mode: the draft's full-prompt prefill rows (the draft
@@ -1820,8 +1931,11 @@ class ContinuousDecoder:
     def _needs_chunk(self, req: _Request) -> bool:
         """Long plain prompts prefill in budget-bounded chunks instead of
         one monolithic forward (prefix-cache requests keep the suffix
-        path — their windows are already short)."""
-        return req.prefix_key is None and req.prompt.size > self._chunk_budget()
+        path — their windows are already short). A hybrid decoder prefills
+        every prompt this way: the chunk program is the one that carries
+        its state from window to window."""
+        return req.prefix_key is None and (
+            self._hybrid or req.prompt.size > self._chunk_budget())
 
     def _begin_chunked(self, slot: int, req: _Request) -> bool:
         """Assign pages + block table and park the request in the chunk
@@ -1846,14 +1960,23 @@ class ContinuousDecoder:
         req, off = self._chunking[slot]
         P = req.prompt.size
         w = min(self._chunk_budget(), P - off)
+        boundary = self._registering.get(slot)
+        if boundary is not None:
+            w = min(w, boundary - off)      # a chunk ends where the prefix does
         ids = self._padded_ids(req.prompt[off:off + w], self._L - off)
         t0 = time.perf_counter()
         with _tracing.span("continuous.prefill_chunk", slot=slot,
                         offset=off, tokens=w):
-            w_logits, bufs = self._extend_paged(
-                self._params, jnp.asarray(ids),
-                jnp.asarray([off], jnp.int32),
-                self._kv.buffers, self._bt[slot:slot + 1])
+            args = (self._params, jnp.asarray(ids),
+                    jnp.asarray([off], jnp.int32),
+                    self._kv.buffers, self._bt[slot:slot + 1])
+            if self._hybrid:
+                last, bufs = self._extend_paged(
+                    *args, jnp.asarray(slot, jnp.int32),
+                    jnp.asarray([w], jnp.int32))
+            else:
+                w_logits, bufs = self._extend_paged(*args)
+                last = w_logits[:, w - 1]
         self._kv.buffers = bufs
         _ledger_charge("device_seconds", time.perf_counter() - t0,
                        cls=req.cost_cls, trace_id=req.cost_trace)
@@ -1861,11 +1984,21 @@ class ContinuousDecoder:
             self._attn_impl,
             gather_bytes=(self._gather_bytes_extend
                           if self._attn_impl == "gather" else 0))
+        self._note_sparse_ticks(off + w)
         self._kv.note_prefill_chunk(w)
         self._chunk_trace.append(w)
         _tracing.add_event("prefill_chunk", slot=slot, offset=off,
                            tokens=w)
         off += w
+        if off == boundary:
+            del self._registering[slot]
+            if req.prefix_key not in self._prefix_store:
+                with _tracing.span("decoder.state_snapshot", slot=slot,
+                                   tokens=off):
+                    self._store_prefix(slot, req, off, state={
+                        "rows": self._snapshot_j(
+                            self._kv.buffers, jnp.asarray(slot, jnp.int32)),
+                        "logits": last})
         if off < P:
             self._chunking[slot][1] = off
             return
@@ -1875,8 +2008,17 @@ class ContinuousDecoder:
         # first token from the last REAL lane of the final window —
         # logits after consuming prompt position P-1, sampled at emit
         # position P: generate_cached's exact schedule
-        self._insert_chunk_locked([(slot, req)], w_logits[:, w - 1], [],
+        self._insert_chunk_locked([(slot, req)], last, [],
                            self._draft_prompt_rows(req))
+
+    def _note_sparse_ticks(self, context: int, calls: int = 1) -> None:
+        """A model with sparse-attention layers counts each paged call a
+        second time, by path: ``sparse`` when the longest context it served
+        was past ``dense_len`` (blocks were selected), else ``dense``."""
+        sp = self._cfg.sparse
+        if self._hybrid and sp is not None:
+            self._kv.note_attn_tick(
+                "sparse" if context > sp.dense_len else "dense", calls=calls)
 
     def _note_token(self, req: _Request, tok: int):
         now = time.perf_counter()
@@ -1899,6 +2041,7 @@ class ContinuousDecoder:
         self._slot_req[slot] = None
         self._active = self._active.at[slot].set(False)
         self._chunking.pop(slot, None)
+        self._registering.pop(slot, None)
         pages = self._slot_pages[slot]
         if pages:
             # decref (prefix-shared pages survive under their registry
@@ -2013,6 +2156,9 @@ class ContinuousDecoder:
             self._attn_impl, calls=self._k,
             gather_bytes=(self._k * self._gather_bytes_tick
                           if self._attn_impl == "gather" else 0))
+        self._note_sparse_ticks(
+            max(self._slot_req[i].prompt.size + len(self._slot_req[i].tokens)
+                for i in decode_live), calls=self._k)
         # snapshot slot→REQUEST (not indices): by the time this block is
         # drained, a slot may have been freed and re-admitted; tokens must
         # go to the request that occupied the slot at DISPATCH time (its

@@ -93,8 +93,21 @@ M_GATHER_BYTES = _metric_counter(
     "reads pages in place)")
 M_KERNEL_TICKS = _metric_counter(
     "mmlspark_kvpool_kernel_ticks_total",
-    "Paged-attention decode calls dispatched, by implementation",
+    "Paged-attention decode calls dispatched, by implementation (kernel | "
+    "gather) and, for a model with sparse-attention layers, by whether the "
+    "call selected blocks (sparse) or every row was still under dense_len "
+    "(dense)",
     labelnames=("impl",))
+M_STATE_SNAPSHOTS = _metric_counter(
+    "mmlspark_kvpool_state_snapshots_total",
+    "Linear-attention state snapshots kept with a cached prefix, by event: "
+    "stored (a prefix registered), restored (a hit copied one into its "
+    "slot), evicted (the prefix was released)",
+    labelnames=("event",))
+M_STATE_SNAPSHOT_BYTES = _metric_counter(
+    "mmlspark_kvpool_state_snapshot_bytes_total",
+    "Device bytes of the state snapshots stored, restored and evicted",
+    labelnames=("event",))
 
 
 def prefix_hash(tokens: Sequence[int]) -> str:
@@ -126,7 +139,8 @@ class PagedKVPool:
     """Page allocator + device buffer handle for one model's KV cache.
 
     ``buffers`` is the per-layer list of ``{"kv"}`` page arrays (plus
-    ``{"k_scale","v_scale"}`` when quantized) the
+    ``{"k_scale","v_scale"}`` when quantized; for a hybrid decoder
+    ``{"kv","ck"}`` or ``{"state"}``, see ``models/zoo/hybrid.py``) the
     engine threads through its jitted steps (reassigning after every
     dispatch, since XLA returns fresh buffers). Everything else is host
     bookkeeping: a free min-heap over pages ``[1, num_pages)``, per-page
@@ -135,7 +149,9 @@ class PagedKVPool:
 
     def __init__(self, cfg, *, num_pages: int, page_size: int,
                  kv_dtype: Optional[str] = None, make_buffer=None,
-                 residency: bool = True, sharding=None):
+                 residency: bool = True, sharding=None,
+                 slots: int = 0, slot_positions: int = 0,
+                 max_snapshots: int = 0):
         from ..ops.kv_quant import (SCALE_DTYPE, kv_store_dtype,
                                     resolve_kv_dtype)
         if num_pages < 2:
@@ -145,11 +161,35 @@ class PagedKVPool:
         self.cfg = cfg
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        hd = cfg.d_model // cfg.heads
+        #: a hybrid decoder (``cfg.mixers``) keeps two kinds of cache in
+        #: this one manager: a sparse-attention layer's entry is pages plus
+        #: a row of the scorer's compressed keys a SLOT, a linear-attention
+        #: layer's is a float32 state row a slot, no pages; a cached prefix
+        #: is then pages plus a snapshot of those rows (``register_prefix``)
+        self.hybrid = bool(getattr(cfg, "mixers", ()))
+        if self.hybrid:
+            from ..models.zoo.hybrid import SLOT_KEYS, dims, pool_shapes
+            if kv_dtype is not None or sharding is not None:
+                raise ValueError("a hybrid decoder's pool is bf16 pages on "
+                                 "one device (no kv_dtype, no mesh)")
+            _, heads, hd = dims(cfg)
+            self._layer_shapes = pool_shapes(
+                cfg, self.num_pages, self.page_size, int(slots),
+                int(slot_positions))
+        else:
+            heads, hd = cfg.heads, cfg.d_model // cfg.heads
+            self._layer_shapes = None
+        #: device bytes of one prefix's state snapshot, and how many the
+        #: engine's prefix store may hold (the reservation counts them)
+        self.snapshot_bytes = sum(
+            int(np.prod(shape[1:])) * jnp.dtype(dt).itemsize
+            for layer in self._layer_shapes or ()
+            for key, (shape, dt) in layer.items() if key in SLOT_KEYS)
+        self.max_snapshots = int(max_snapshots)
         #: one K or V page as a session blob carries it, (H, page, hd);
         #: the pool's buffer packs the two side by side on the minor axis
-        self._page_shape = (cfg.heads, self.page_size, hd)
-        shape = (self.num_pages, cfg.heads, self.page_size, 2 * hd)
+        self._page_shape = (heads, self.page_size, hd)
+        shape = (self.num_pages, heads, self.page_size, 2 * hd)
         #: canonical quantized-page dtype name ("int8"/"fp8") or None for
         #: bf16 pages (the byte-exact oracle representation)
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
@@ -179,6 +219,9 @@ class PagedKVPool:
         self._alloc_t: Dict[int, float] = {}
         # phash -> (pages tuple, prefix length in tokens)
         self._prefixes: Dict[str, Tuple[Tuple[int, ...], int]] = {}
+        # phash -> the prefix's state snapshot (a hybrid decoder): whatever
+        # the engine stored with it, device arrays it restores from
+        self._snapshots: Dict[str, object] = {}
         # phash -> registration count. Two engine keys whose prefixes are
         # token-identical hash to the same entry; the entry (and its page
         # refs) must survive until EVERY registering key has released it.
@@ -203,6 +246,10 @@ class PagedKVPool:
         """Fresh per-layer page buffers through ``make_buffer`` (so mesh
         shardings apply): ``{"kv"}`` in the value dtype, plus
         ``{"k_scale","v_scale"}`` when quantized."""
+        if self._layer_shapes is not None:
+            return [{key: self._mk(shape, dt)
+                     for key, (shape, dt) in layer.items()}
+                    for layer in self._layer_shapes]
         layers = []
         for _ in range(self.cfg.layers):
             c = {"kv": self._mk(self._shape, self.value_dtype)}
@@ -217,7 +264,14 @@ class PagedKVPool:
         (possibly quantized) value dtype plus the scale arrays. This is
         what :func:`~mmlspark_tpu.core.residency.get_residency_manager`'s
         ``reserve()`` pins, so the budget sees the QUANTIZED itemsize: a
-        fixed byte budget holds ~2x the pages under int8."""
+        fixed byte budget holds ~2x the pages under int8. A hybrid
+        decoder's pool counts its pages, its compressed keys, its state
+        rows and the snapshots its prefix store may hold."""
+        if self._layer_shapes is not None:
+            return (self.max_snapshots * self.snapshot_bytes + sum(
+                int(np.prod(shape)) * jnp.dtype(dt).itemsize
+                for layer in self._layer_shapes
+                for shape, dt in layer.values()))
         nbytes = (self.cfg.layers * int(np.prod(self._shape)) *
                   jnp.dtype(self.value_dtype).itemsize)
         if self.scale_dtype is not None:
@@ -231,6 +285,13 @@ class PagedKVPool:
         (values + scales) — the unit the engine's per-tick byte
         accounting multiplies out."""
         from ..ops.kv_quant import kv_bytes_per_position
+        if self._layer_shapes is not None:
+            # K and V on the sparse layers
+            return sum(
+                int(np.prod(shape[1:])) * jnp.dtype(dt).itemsize
+                for layer in self._layer_shapes
+                for key, (shape, dt) in layer.items()
+                if key == "kv") // self.page_size
         hd = self.cfg.d_model // self.cfg.heads
         return self.cfg.layers * kv_bytes_per_position(
             self.cfg.heads, hd, self.value_dtype,
@@ -326,13 +387,15 @@ class PagedKVPool:
     # -- prefix sharing ------------------------------------------------------
 
     def register_prefix(self, phash: str, pages: Sequence[int],
-                        plen: int) -> None:
+                        plen: int, state=None) -> None:
         """Retain ``pages`` (incref) as the cached cache-content of a
         prompt prefix of ``plen`` tokens. Registrations are COUNTED per
         hash: a re-registration keeps the existing entry's pages but
         adds a release obligation, so the entry outlives every key that
         registered it (releasing one of two token-identical keys must
-        not dangle the other)."""
+        not dangle the other). ``state`` is a hybrid decoder's snapshot of
+        its linear-attention states after exactly ``plen`` tokens, kept
+        with the pages and dropped with them."""
         if phash in self._prefixes:
             self._prefix_regs[phash] += 1
             return
@@ -340,6 +403,22 @@ class PagedKVPool:
         self.incref(pages)
         self._prefixes[phash] = (pages, int(plen))
         self._prefix_regs[phash] = 1
+        if state is not None:
+            self._snapshots[phash] = state
+            self._note_snapshot("stored")
+
+    def _note_snapshot(self, event: str) -> None:
+        for key, metric, n in (
+                ("state_snapshots_", M_STATE_SNAPSHOTS, 1),
+                ("state_snapshot_bytes_", M_STATE_SNAPSHOT_BYTES,
+                 self.snapshot_bytes)):
+            self.stats[key + event] = self.stats.get(key + event, 0) + n
+            metric.inc(n, event=event)
+
+    def prefix_state(self, phash: str):
+        """The snapshot stored with a prefix, for a hit to restore."""
+        self._note_snapshot("restored")
+        return self._snapshots[phash]
 
     def lookup_prefix(self, phash: str):
         """``(pages, plen)`` or None."""
@@ -369,6 +448,8 @@ class PagedKVPool:
             return
         del self._prefix_regs[phash]
         pages, _ = self._prefixes.pop(phash)
+        if self._snapshots.pop(phash, None) is not None:
+            self._note_snapshot("evicted")
         self.free(pages)
 
     # -- defrag --------------------------------------------------------------
@@ -442,6 +523,10 @@ class PagedKVPool:
         the pages actually hold (prompt + written tokens); the receiver
         uses it to rebuild the block-table row and resume mid-page."""
         import base64
+        if self.hybrid:
+            raise ValueError("session export does not carry a hybrid "
+                             "decoder's linear-attention state yet: "
+                             "restore cold")
         pages = [int(p) for p in pages]
         idx = jnp.asarray(np.asarray(pages, np.int32))
         data = []
@@ -478,6 +563,10 @@ class PagedKVPool:
         ``PoolExhausted`` — with nothing leaked — when this pool lacks the
         pages."""
         import base64
+        if self.hybrid:
+            raise ValueError("session adopt does not carry a hybrid "
+                             "decoder's linear-attention state yet: "
+                             "restore cold")
         if blob.get("v") != 1:
             raise ValueError(f"unknown session blob version {blob.get('v')}")
         want = {
@@ -523,6 +612,11 @@ class PagedKVPool:
         return pages
 
     # -- misc ----------------------------------------------------------------
+
+    def note_prefix_miss(self) -> None:
+        """A ``prefix_key`` request admitted without a stored prefix: it
+        prefills whole (and registers, where the store has room)."""
+        self.stats["prefix_misses"] = self.stats.get("prefix_misses", 0) + 1
 
     def note_prefill_chunk(self, ntok: int) -> None:
         self.stats["prefill_chunks"] += 1
@@ -572,6 +666,7 @@ class PagedKVPool:
         self._alloc_t.clear()
         self._prefixes.clear()
         self._prefix_regs.clear()
+        self._snapshots.clear()
         M_PAGES_IN_USE.set(0)
 
     def close(self) -> None:

@@ -19,8 +19,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from mmlspark_tpu.ops.flash_attention import flash_attention
+from mmlspark_tpu.ops.lightning_attention import lightning_decode_step
 from mmlspark_tpu.ops.paged_attention import (aligned_page_size,
                                               paged_attention,
+                                              paged_attention_selected,
                                               paged_attention_window)
 from mmlspark_tpu.ops.pallas_kernels import (level_histogram_pallas,
                                              prepare_bins_lanes,
@@ -182,6 +184,114 @@ def test_programs_update_the_page_pool_in_place(one_chip):
     assert smoke.pool_copies(
         "%copy.1 = bf16[577,25,16,128]{3,2,1,0:T(8,128)(2,1)} copy(%p)",
         shapes)
+
+
+# the hybrid cell (benchmarks/workloads/sala_docqa_closed8.json): MiniCPM-SALA
+# at its published widths, 8 slots of 32,768 positions in pages of 64
+SALA = dict(slots=8, max_len=32768, page=64, chunk=256, heads=32, kv_heads=2,
+            hd=128, topk=64)
+
+
+def test_selected_block_kernel_compiles(one_chip):
+    """One query a row over 64 listed pages of one KV head each, 16 query
+    heads a KV head: the sparse layers' decode attention."""
+    z = SALA
+    per = z["max_len"] // z["page"]
+    text = _compiled_text(
+        functools.partial(paged_attention_selected, interpret=False),
+        one_chip((z["slots"], z["kv_heads"], z["heads"] // z["kv_heads"],
+                  z["hd"]), jnp.bfloat16),
+        one_chip((1 + z["slots"] * per, z["kv_heads"], z["page"],
+                  2 * z["hd"]), jnp.bfloat16),
+        one_chip((z["slots"], per), jnp.int32),
+        one_chip((z["slots"], z["kv_heads"], z["topk"]), jnp.int32),
+        one_chip((z["slots"],), jnp.int32))
+    assert "_pa_select_call" in text        # the name the benchmark's trace finds
+
+
+def test_lightning_step_kernel_compiles_in_place(one_chip):
+    """The linear-attention decode step on 8 slots x 32 heads of (128 x 128)
+    float32 state, the donated state aliased in and out: no copy of it."""
+    z = SALA
+    row = one_chip((z["slots"], z["heads"], z["hd"]), jnp.float32)
+    state = one_chip((z["slots"], z["heads"], z["hd"], z["hd"]), jnp.float32)
+    text = jax.jit(
+        functools.partial(lightning_decode_step, interpret=False),
+        donate_argnums=(3,)).lower(
+            row, row, row, state, one_chip((z["slots"],), bool)
+        ).compile().as_text()
+    assert "_lightning_step_call" in text and "tpu_custom_call" in text
+    assert " copy(" not in text
+
+
+@pytest.fixture(scope="module")
+def sala_programs(one_chip):
+    """The hybrid engine's programs lowered at the cell's shapes (the first
+    two layers of the eight: one sparse, one lightning), as
+    ``chip_smoke.pool_programs`` does it for the dense block."""
+    import json
+
+    from benchmarks import run as bench_run
+    from mmlspark_tpu.ops import paged_attention as pa
+    from mmlspark_tpu.serving import continuous as progs
+    from mmlspark_tpu.serving.kv_pool import PagedKVPool
+    z = SALA
+    with open(os.path.join(bench_run.HERE, "configs",
+                           "minicpm_sala_l8.json")) as fh:
+        config = json.load(fh)
+    config.update(num_hidden_layers=2, mixer_types=config["mixer_types"][:2])
+    cfg = bench_run.load_by_path("drivers", "generate_docs").program_config(
+        config, z["max_len"])
+    per = z["max_len"] // z["page"]
+    reference = bench_run.load_by_path("references", config["reference"])
+    params = jax.tree.map(          # the reference's weights are jnp: shapes
+        lambda a: one_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: reference.make_weights(config, 0)))
+    pool = PagedKVPool(cfg, page_size=z["page"], residency=False,
+                       make_buffer=one_chip, slots=z["slots"],
+                       slot_positions=z["max_len"],
+                       num_pages=1 + z["slots"] * per + per)
+    ints = lambda *dims: one_chip(dims, jnp.int32)          # noqa: E731
+    interpret = pa._auto_interpret
+    pa._auto_interpret = progs._pa_auto_interpret = lambda: False
+    try:
+        tick = progs._tick_program(
+            cfg, z["page"], z["max_len"], 1, None, False, True).lower(
+                params, ints(z["slots"]), ints(z["slots"]),
+                one_chip((z["slots"],), bool), pool.buffers,
+                ints(z["slots"], per), ints(z["slots"]))
+        extend = progs._extend_program(cfg, z["page"], z["max_len"], True)
+        lowered = {"tick": tick}
+        for name, width in (("chunk", z["chunk"]), ("suffix", 64)):
+            lowered[name] = extend.lower(
+                params, ints(1, width), ints(1), pool.buffers, ints(1, per),
+                ints(), ints(1))
+        yield {name: low.compile().as_text()
+               for name, low in lowered.items()}, pool
+    finally:
+        pa._auto_interpret = progs._pa_auto_interpret = interpret
+        progs._tick_program.cache_clear()
+        progs._extend_program.cache_clear()
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk", "suffix"])
+def test_hybrid_programs_compile_and_keep_the_pool_in_place(sala_programs,
+                                                            program):
+    """The hybrid tick, a 256-token prefill chunk and a 64-token suffix
+    window at the published widths (8 slots, 32,768 positions): they compile
+    for the chip, the tick holds both decode kernels, and none copies a
+    pool-sized buffer (pages, compressed keys or the state rows)."""
+    texts, pool = sala_programs
+    text = texts[program]
+    if program == "tick":
+        assert "_pa_select_call" in text and "_lightning_step_call" in text
+    names = {"bfloat16": "bf16", "float32": "f32"}
+    for layer in pool.buffers:
+        for buf in layer.values():
+            shape = f"{names[buf.dtype.name]}[{','.join(map(str, buf.shape))}]"
+            copies = [ln.strip()[:120] for ln in text.splitlines()
+                      if f"= {shape}" in ln and " copy(" in ln]
+            assert not copies, copies
 
 
 @pytest.mark.parametrize("stats", [None, "bfloat16"])

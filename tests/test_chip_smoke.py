@@ -68,7 +68,8 @@ def test_refuses_a_steered_run(var, monkeypatch, capsys):
 def test_small_runs_every_phase_in_interpret_mode(small_run):
     lines, _ = small_run
     phases = {ln["phase"]: ln for ln in lines if "phase" in ln}
-    assert sorted(phases) == ["A.transform", "B.decode", "C.train"]
+    assert sorted(phases) == ["A.transform", "B.decode", "C.train",
+                              "F.hybrid"]
     for ln in phases.values():
         assert ln["ok"] is True and ln["failed"] == []
         assert ln["small"] is True and ln["platform"] == "cpu"
@@ -78,6 +79,10 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     assert phases["B.decode"]["kernel_compiled"] is False   # interpreted
     assert phases["B.decode"]["attn_ticks"]["gather"] == 0
     assert 0 < phases["B.decode"]["int8_quant_error_last"] < 0.05
+    # F: a registered prefix past dense_len, then a restored hit whose
+    # tokens the plain float32 reference puts first
+    assert phases["F.hybrid"]["attn_ticks_sparse"] > 0
+    assert phases["F.hybrid"]["gap_max"] <= chip_smoke.TIE_TOL
     assert phases["C.train"]["pallas_histogram_traces"] > 0
     assert (phases["C.train"]["pallas_interpreted"]
             == phases["C.train"]["pallas_histogram_traces"])

@@ -26,6 +26,8 @@ GENERATION_SPANS = ["engine.admit_http", "engine.pump_streams",
                     "decoder.stage_prefills", "decoder.compact",
                     "continuous.prefill", "continuous.prefill_chunk",
                     "continuous.drain"]
+#: a hybrid decoder's prefix is pages plus a state snapshot
+HYBRID_SPANS = ["decoder.state_snapshot", "decoder.state_restore"]
 
 
 def _stream(url, payload):
@@ -115,11 +117,83 @@ def generation_run():
             tr.get_flight_recorder().traces())
 
 
-@pytest.mark.parametrize("name", TRANSFORM_SPANS + GENERATION_SPANS)
+@pytest.fixture(scope="module")
+def hybrid_run():
+    """A tiny hybrid decoder (one sparse-attention layer, one
+    linear-attention layer): a prefix registered by a miss, then a hit. The
+    names the span log saw and what the pool and its registry counted."""
+    from mmlspark_tpu import observability as obs
+    from mmlspark_tpu.models.zoo.transformer import (SparseAttention,
+                                                     TransformerConfig,
+                                                     init_transformer)
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    cfg = TransformerConfig(
+        vocab=64, layers=2, d_model=32, heads=2, d_ff=64, max_len=96,
+        causal=True, norm="rmsnorm", position="rope", dtype=jnp.float32,
+        mixers=("sparse", "lightning"), kv_heads=1, head_dim=16,
+        sparse=SparseAttention(kernel_size=4, kernel_stride=2, block_size=8,
+                               topk=4, window_size=8, init_blocks=1,
+                               dense_len=32))
+    tr._SPAN_LOG.clear()
+    before = obs.snapshot()
+    dec = ContinuousDecoder(init_transformer(cfg, seed=0), cfg, max_slots=2,
+                            max_len=96, page_size=8, prefill_chunk=16)
+    rng = np.random.default_rng(5)
+    doc = rng.integers(1, cfg.vocab, 40).astype(np.int32)
+    for n in (6, 9):                            # a miss, then a hit
+        req = dec.submit(np.concatenate(
+            [doc, rng.integers(1, cfg.vocab, n).astype(np.int32)]), 4,
+            prefix_key="doc", prefix_len=40)
+        while not req.done:
+            dec.step()
+        assert req.error is None
+    dec._alloc_with_pressure(dec._kv.num_pages - 1 - dec._kv.pages_in_use
+                             + 1)               # pressure evicts the prefix
+    return ({name for name, *_ in tr.span_log()}, dec._kv.stats,
+            before, obs.snapshot())
+
+
+@pytest.mark.parametrize("name", TRANSFORM_SPANS + GENERATION_SPANS
+                         + HYBRID_SPANS)
 def test_span_fires(name, request):
-    run = "transform_run" if name in TRANSFORM_SPANS else "generation_run"
-    names, _ = request.getfixturevalue(run)
+    run = ("transform_run" if name in TRANSFORM_SPANS else
+           "hybrid_run" if name in HYBRID_SPANS else "generation_run")
+    names, *_ = request.getfixturevalue(run)
     assert name in names
+
+
+@pytest.mark.parametrize("event", ["stored", "restored", "evicted"])
+def test_state_snapshot_counters(hybrid_run, event):
+    """One snapshot stored, restored and evicted, with its bytes (one
+    lightning layer, 2 heads of 16 x 16 float32, and one row of compressed
+    keys, 1 KV head x 96 / 2 entries of 16 float32), in the pool's stats and
+    in the registry."""
+    _, stats, before, after = hybrid_run
+    assert stats[f"state_snapshots_{event}"] == 1
+    assert stats[f"state_snapshot_bytes_{event}"] == 2048 + 48 * 16 * 4
+
+    def series(snap, name):
+        return sum(s["value"] for s in snap.get(name, {}).get("series", ())
+                   if s["labels"].get("event") == event)
+    for name, want in (("mmlspark_kvpool_state_snapshots_total", 1),
+                       ("mmlspark_kvpool_state_snapshot_bytes_total", 5120)):
+        assert series(after, name) - series(before, name) == want
+
+
+@pytest.mark.parametrize("label", ["sparse", "dense"])
+def test_attn_ticks_are_labelled_by_path(hybrid_run, label):
+    """Beside ``kernel`` / ``gather``: the prefill chunks and ticks under
+    ``dense_len`` (32) count ``dense``, those past it ``sparse``."""
+    _, stats, before, after = hybrid_run
+    assert stats[f"attn_ticks_{label}"] > 0
+    assert stats["attn_ticks_sparse"] + stats["attn_ticks_dense"] \
+        == stats["attn_ticks_kernel"]
+
+    def series(snap):
+        return sum(s["value"] for s in snap.get(
+            "mmlspark_kvpool_kernel_ticks_total", {}).get("series", ())
+            if s["labels"].get("impl") == label)
+    assert series(after) - series(before) == stats[f"attn_ticks_{label}"]
 
 
 def test_transform_spans_join_the_request_trace(transform_run):
