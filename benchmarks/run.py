@@ -17,12 +17,12 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+from concurrent import futures  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
-import threading  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -127,29 +127,44 @@ def init_jax():
 
 
 class Trace:
-    """The profiler over the start of the window: device planes only (the
-    host tracer slows the host's feed many times over), stopped at the
-    window's end or after ``seconds``, whichever comes first."""
+    """The profiler over the window, or over the start of it: device planes
+    only (the host tracer slows the host's feed many times over). Started and
+    stopped by the thread that drives the run, the main one: from a timer's
+    thread ``stop_trace`` took three times as long for the same events."""
 
-    def __init__(self, jax, trace_dir, seconds):
-        self.jax, self.dir, self.lock = jax, trace_dir, threading.Lock()
-        self.t0 = self.t1 = None
+    def __init__(self, jax, trace_dir):
+        self.jax = jax
+        self.t1 = self.stop_s = None
         shutil.rmtree(trace_dir, ignore_errors=True)
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=options)
         self.t0 = time.perf_counter()
-        self.timer = threading.Timer(seconds or 1e9, self.stop)
-        self.timer.daemon = True
-        self.timer.start()
 
     def stop(self):
-        self.timer.cancel()
-        with self.lock:
-            if self.t1 is None:
-                self.t1 = time.perf_counter()
-                self.jax.profiler.stop_trace()
+        self.t1 = time.perf_counter()
+        self.jax.profiler.stop_trace()
+        self.stop_s = time.perf_counter() - self.t1
+
+    def over(self, window, seconds, trace_seconds):
+        """``window(seconds)`` under the trace. A cell whose whole window
+        would be too large a trace names ``trace_seconds``: the window then
+        runs on a thread of its own (it sleeps, reads counters and joins
+        its callers) while this one waits that long from ``t0`` and stops
+        the profiler, however long the stop takes; what a traced run costs
+        then depends on the stretch traced, not on the window."""
+        if not trace_seconds:
+            result = window(seconds)
+            self.stop()
+            return result
+        with futures.ThreadPoolExecutor(1, "window") as pool:
+            running = pool.submit(window, seconds)
+            # returns early where the window is shorter than the stretch
+            futures.wait([running], max(
+                0.0, self.t0 + trace_seconds - time.perf_counter()))
+            self.stop()
+            return running.result()     # raises what the window raised
 
 
 def memory_peak(devices):
@@ -208,13 +223,13 @@ def main(argv=None, require_chip=True, driver_override=None):
                              warm_s=setup_s - t_built))
 
         trace_dir = os.path.join(ROOT, ".bench_trace", args.workload)
-        # a cell whose trace would be too large names a shorter traced
-        # stretch at the start of its window
-        trace = (Trace(jax, trace_dir, cell.get("trace_seconds"))
-                 if args.trace else None)
-        result = driver.window(args.seconds)
-        if trace:
-            trace.stop()
+        if args.trace:
+            trace = Trace(jax, trace_dir)
+            result = trace.over(driver.window, args.seconds,
+                                cell.get("trace_seconds"))
+        else:
+            trace = None
+            result = driver.window(args.seconds)
         in_window = {k: v - at_setup[k]
                      for k, v in events.snapshot().items()}
         per_device_peak, memory_peak_bytes = memory_peak(used)
@@ -244,11 +259,13 @@ def main(argv=None, require_chip=True, driver_override=None):
             for m in metrics_of(manifest, "end_to_end", args.workload,
                                 values)}
     else:
-        from benchmarks import trace_reduce
+        from benchmarks import idle_gaps, trace_reduce
         t_reduce = time.perf_counter()
         reduced = trace_reduce.reduce_trace(trace_dir, trace.t1 - trace.t0)
+        # what the trace cost: the stop's seconds grow with the events
         say(trace_bytes=os.path.getsize(trace_reduce.find_xplane(trace_dir)),
-            traced_s=trace.t1 - trace.t0,
+            traced_s=trace.t1 - trace.t0, stop_trace_s=trace.stop_s,
+            device_op_events=sum(n for _, n in reduced["ops"].values()),
             reduce_s=time.perf_counter() - t_reduce)
         counters = dict(result.get("counters", {}),
                         traced=dict(t0=trace.t0, t1=trace.t1),
@@ -264,8 +281,11 @@ def main(argv=None, require_chip=True, driver_override=None):
         line["metrics"] = metrics
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
+        # parsed once for the readers above; None where a run has no
+        # device plane, no start time or no span of the program's
+        gaps = idle_gaps.analysis(reduced, counters)
         line["breakdown"] = {"device_ops": trace_reduce.top_ops(reduced),
-                             "idle_gaps": []}
+                             "idle_gaps": gaps["top"] if gaps else []}
         say(trace_modules=sorted(
             ([k, v[0], v[1]] for k, v in reduced["modules"].items()),
             key=lambda r: -r[1])[:10], trace_devices=reduced["devices"])

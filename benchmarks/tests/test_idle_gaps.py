@@ -6,6 +6,7 @@ import pytest
 
 from benchmarks import idle_gaps, run, trace_reduce
 
+from . import tiny
 from .test_trace_reduce import XSPACE
 
 # device busy [1000, 5000] and [11000, 12000] ns (test_trace_reduce); a
@@ -133,6 +134,23 @@ def test_the_two_transform_shares_add_up_to_the_idle_share(profile, found,
         longer, {}, {}, {}, None) for name in values]
     assert shares[0] + shares[1] == pytest.approx(100 * 11000 / 20000)
     assert shares[2] == pytest.approx(100 * 15000 / 20000)
+
+
+@pytest.mark.parametrize("named", [True, False])
+def test_the_last_line_carries_the_named_gaps(found, monkeypatch, named):
+    """``run.py`` puts the analysis' ``top`` into ``breakdown.idle_gaps``:
+    ``[span name, seconds]`` pairs, ``device_ops``' shape; ``[]`` only where
+    there is no analysis."""
+    monkeypatch.setattr(idle_gaps, "analysis",
+                        lambda trace, counters: found if named else None)
+    line, _ = tiny.run_cell("resnet50_transform_resident", trace=1)
+    gaps = line["breakdown"]["idle_gaps"]
+    assert gaps == (found["top"] if named else [])
+    if named:
+        assert 0 < len(gaps) <= 10 and sorted(n for n, _ in gaps) == [
+            "runner.coerce", "runner.d2h", "runner.next", "unattributed"]
+        assert [s for _, s in gaps] == sorted((s for _, s in gaps),
+                                              reverse=True)
 
 
 def test_span_log_spans_are_laid_over_a_device_only_trace(monkeypatch):

@@ -17,8 +17,9 @@ HERE = run.HERE
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTHS = re.compile(r"(_dim|_rank)$|hidden|intermediate|latent|state|proj"
-                    r"|head_size|n_embd|n_inner|d_model|d_ff|width")
+# a width, which `reduced` may never name; `num_hidden_layers` is a depth
+WIDTHS = re.compile(r"(_dim|_rank)$|hidden_size|intermediate|latent|state"
+                    r"|proj|head_size|n_embd|n_inner|d_model|d_ff|width")
 
 
 @pytest.fixture(scope="module")
@@ -107,6 +108,15 @@ def test_every_name_has_its_file_and_every_file_its_entry(manifest):
     assert readers == {m["name"] for m in manifest["per_layer"]}
     assert sum(w["chips"] == 4 for w in cells.values()) \
         <= max(1, len(cells) // 4)
+
+
+@pytest.mark.parametrize("key,refused", [
+    ("hidden_size", True), ("intermediate_size", True), ("hidden_dim", True),
+    ("head_dim", True), ("q_lora_rank", True), ("ssm_state_size", True),
+    ("num_hidden_layers", False), ("mixer_types", False),
+    ("num_experts", False)])
+def test_reduced_may_name_a_depth_and_never_a_width(key, refused):
+    assert bool(WIDTHS.search(key)) is refused
 
 
 def test_each_cell_reports_setup_another_metric_and_a_layer(manifest):
