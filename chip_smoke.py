@@ -115,7 +115,8 @@ def pool_programs(cfg, audit, shape=None):
     from mmlspark_tpu.serving import continuous as progs
     from mmlspark_tpu.serving.kv_pool import PagedKVPool
     shape = shape or jax.ShapeDtypeStruct
-    slots, max_len, page = audit["slots"], audit["max_len"], audit["page"]
+    slots, max_len = audit["slots"], audit["max_len"]
+    page = progs.derived_page_size(cfg, max_len)    # as an engine built bare
     per_slot = -(-max_len // page)
     params = jax.tree.map(
         lambda a: shape(a.shape, cfg.dtype),
@@ -204,7 +205,7 @@ def sizes(small):
             pool_decoder=TransformerConfig(vocab=256, layers=2, d_model=64,
                                            heads=4, d_ff=128, max_len=64,
                                            causal=True, dtype=jnp.bfloat16),
-            pool_audit=dict(slots=2, max_len=64, page=16, chunk=16, group=2,
+            pool_audit=dict(slots=2, max_len=64, chunk=16, group=2,
                             rows_len=32),
             prompt_lens=[5, 5, 9, 9, 12, 12, 40, 40],
             gbdt_rows=4096, gbdt_test=1024, gbdt_bins=255, gbdt_iters=5)
@@ -221,12 +222,13 @@ def sizes(small):
         hybrid_len=8704,
         # the generation cell's decoder and engine (benchmarks/configs/
         # gpt2_xl.json, workloads/gpt2xl_generate_closed.json): GPT-2 XL,
-        # 8 slots of 1024 positions in pages of 16, 256-token chunks
+        # 8 slots of 1024 positions in the pages the decoder derives from
+        # that length (64: 16 a slot), 256-token chunks
         pool_decoder=TransformerConfig(vocab=50257, layers=48, d_model=1600,
                                        heads=25, d_ff=6400, max_len=1024,
                                        causal=True, norm="layernorm",
                                        position="learned", dtype=jnp.bfloat16),
-        pool_audit=dict(slots=8, max_len=1024, page=16, chunk=256, group=2,
+        pool_audit=dict(slots=8, max_len=1024, chunk=256, group=2,
                         rows_len=128),
         # one group is longer than the engine's default prefill_chunk (256)
         prompt_lens=[12, 12, 12, 64, 64, 64, 300, 300],
@@ -257,10 +259,12 @@ def phase_hybrid(sz, seed, small):
         config, sz["hybrid_len"])
     t0 = time.perf_counter()
     params = reference.make_weights(config, seed)
+    # no page_size: a model with sparse layers is served in pages of its
+    # sparse block
     dec = ContinuousDecoder(params, cfg, max_slots=2,
-                            max_len=sz["hybrid_len"],
-                            page_size=config["sparse_config"]["block_size"],
-                            **sz["engine_kw"])
+                            max_len=sz["hybrid_len"], **sz["engine_kw"])
+    ck.require(dec._page == config["sparse_config"]["block_size"],
+               f"page {dec._page} is not the sparse block")
     rng = np.random.default_rng(seed)
     dense_len = config["sparse_config"]["dense_len"]
     doc = rng.integers(1, cfg.vocab, dense_len + 3 * dec._page).astype(
@@ -490,6 +494,8 @@ def phase_decode(sz, seed, small):
         kernel_compiled = "tpu_custom_call" in tick_text(decoder)
         ticks = dict(kernel=decoder._kv.stats.get("attn_ticks_kernel", 0),
                      gather=decoder._kv.stats.get("attn_ticks_gather", 0))
+        geometry = {k: decoder._kv.stats[k]
+                    for k in ("page_size", "pages_per_slot")}
     if not all(r is not None and r[0] == 200 for r in replies):
         raise RuntimeError(f"HTTP replies: {[r and r[0] for r in replies]}")
     got = [r[1]["tokens"] for r in replies]
@@ -538,6 +544,7 @@ def phase_decode(sz, seed, small):
                f"programs copy a pool-sized buffer: {copies}")
     return ck, dict(
         pool_copies=copies, pool_copies_s=time.perf_counter() - t0,
+        **geometry,
         setup_s=setup_s, run_s=run_s, oracle_s=oracle_s, int8_s=int8_s,
         requests=len(prompts), max_new=max_new,
         prompt_lens=sz["prompt_lens"], slots=sz["slots"], paged_attn=impl,

@@ -622,6 +622,19 @@ def _spec_tick_program(cfg, d_cfg, page, Lc, k_steps, eos, gamma,
         donate_argnums=(2, 3, 4, 5, 7, 8) if donate else ())
 
 
+def derived_page_size(cfg: TransformerConfig, max_len: int) -> int:
+    """The page an engine built without ``page_size`` serves: sixteen pages
+    a slot at ``max_len``, held to ``[16, 256]`` tokens. The page is how
+    many keys one grid step of the decode kernel folds, and the kernel's
+    time is its count of steps: a block table 64 wide cost GPT-2 XL three
+    quarters of its tick (PERF.md section 6, PR 32). A model with sparse
+    layers keeps the page at its sparse block, which selection and the
+    compressed keys are laid out by."""
+    if "sparse" in cfg.mixers:
+        return cfg.sparse.block_size
+    return min(256, max(16, bucket_size(int(max_len)) // 16))
+
+
 class ContinuousDecoder:
     """Slot-pool continuous-batching engine over the zoo decoder.
 
@@ -646,7 +659,7 @@ class ContinuousDecoder:
                  draft_params: Optional[Dict] = None,
                  draft_cfg: Optional[TransformerConfig] = None,
                  gamma: int = 4,
-                 page_size: int = 16,
+                 page_size: Optional[int] = None,
                  prefill_chunk: int = 256,
                  kv_pages: Optional[int] = None,
                  autotune: bool = False,
@@ -822,6 +835,8 @@ class ContinuousDecoder:
         self._zeros = _zeros
 
         # ---- the paged KV pool + block tables ----
+        if page_size is None:
+            page_size = derived_page_size(cfg, self._L)
         if page_size < 1:
             raise ValueError("page_size must be >= 1")
         if prefill_chunk < 8:

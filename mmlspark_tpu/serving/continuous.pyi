@@ -29,6 +29,8 @@ class _Request:
     journaled: int
     def timeline(self) -> Dict[str, object]: ...
 
+def derived_page_size(cfg: Any, max_len: int) -> int: ...
+
 class ContinuousDecoder:
     stats: Dict[str, int]
     def __init__(self, params: Dict, cfg: Any, *,
@@ -42,7 +44,7 @@ class ContinuousDecoder:
                  draft_params: Optional[Dict] = ...,
                  draft_cfg: Optional[Any] = ...,
                  gamma: int = ...,
-                 page_size: int = ...,
+                 page_size: Optional[int] = ...,
                  prefill_chunk: int = ...,
                  kv_pages: Optional[int] = ...,
                  autotune: bool = ...,

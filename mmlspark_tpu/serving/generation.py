@@ -77,7 +77,7 @@ class GenerationEngine:
                  pipeline_depth: int = 2,
                  prefill_ahead: int = 0,
                  draft_params=None, draft_cfg=None, gamma: int = 4,
-                 page_size: int = 16, prefill_chunk: int = 256,
+                 page_size: Optional[int] = None, prefill_chunk: int = 256,
                  kv_pages: Optional[int] = None, autotune: bool = False,
                  paged_attn: Optional[str] = None, mesh=None):
         self.decoder = ContinuousDecoder(

@@ -63,6 +63,14 @@ M_PAGES_TOTAL = _metric_gauge(
 M_PAGES_IN_USE = _metric_gauge(
     "mmlspark_kvpool_pages_in_use",
     "KV pages currently referenced by a slot or a cached prefix")
+M_PAGE_SIZE = _metric_gauge(
+    "mmlspark_kvpool_page_size",
+    "Tokens a KV page holds: the keys one grid step of the paged decode "
+    "kernel folds (derived from max_len unless the engine was given one)")
+M_PAGES_PER_SLOT = _metric_gauge(
+    "mmlspark_kvpool_pages_per_slot",
+    "Width of a slot's block table: pages a slot spans at full length, the "
+    "decode kernel's grid steps a row")
 M_PREFIX_SHARE_HITS = _metric_counter(
     "mmlspark_kvpool_prefix_share_hits_total",
     "Physical pages shared into an admitted request from a cached prefix "
@@ -227,7 +235,11 @@ class PagedKVPool:
         # refs) must survive until EVERY registering key has released it.
         self._prefix_regs: Dict[str, int] = {}
         self.high_water = 0
-        self.stats = {"prefix_share_hits": 0, "defrag_moves": 0,
+        #: the counters the engine and the tests read; the geometry the
+        #: process serves stands beside them, set once
+        self.stats = {"page_size": self.page_size,
+                      "pages_per_slot": self.pages_per_slot(slot_positions),
+                      "prefix_share_hits": 0, "defrag_moves": 0,
                       "prefill_chunks": 0, "alloc_failures": 0,
                       "gather_bytes": 0, "attn_ticks_kernel": 0,
                       "attn_ticks_gather": 0, "quant_error_probes": 0,
@@ -235,6 +247,8 @@ class PagedKVPool:
                       "quant_error_max": 0.0}
         M_PAGES_TOTAL.set(self.num_pages - 1)
         M_PAGES_IN_USE.set(0)
+        M_PAGE_SIZE.set(self.stats["page_size"])
+        M_PAGES_PER_SLOT.set(self.stats["pages_per_slot"])
         self._reservation = None
         if residency:
             mgr = get_residency_manager()

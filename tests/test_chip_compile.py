@@ -28,12 +28,15 @@ from mmlspark_tpu.ops.pallas_kernels import (level_histogram_pallas,
                                              prepare_bins_lanes,
                                              tree_row_block)
 
-# phase B of chip_smoke.py: 12 heads x 64, 16 slots, max_len 2048; pages of
-# 16 positions, which the pool rounds up to the int8 sublane tile (32); the
-# decode tick's window is 1, a chunked-prefill extension's is prefill_chunk
+# phase B of chip_smoke.py: 12 heads x 64, 16 slots, max_len 2048; the
+# decode tick's window is 1, a chunked-prefill extension's is prefill_chunk.
+# Pages: the 128 positions the decoder derives from that max_len (2048 / 16),
+# and an explicit page_size=16, which the pool rounds up to the stored
+# dtype's sublane tile (32 for int8)
 SLOTS, HEADS, HD, MAX_LEN, CHUNK = 16, 12, 64, 2048, 256
-PAGE = {"bf16": aligned_page_size(16, jnp.bfloat16),
-        "int8": aligned_page_size(16, jnp.int8)}
+STORED = {"bf16": jnp.bfloat16, "int8": jnp.int8}
+by_page = pytest.mark.parametrize("page", [128, 16],
+                                  ids=["derived", "page16"])
 # phase C: HIGGS-shaped, 1M x 28, 255 bins, depth-6 trees (32 nodes deepest)
 ROWS, FEATS, BINS, NODES = 1_000_000, 28, 255, 32
 
@@ -73,13 +76,12 @@ def _compiled_text(fn, *args):
     return text
 
 
-def _pool(shape, kv):
+def _pool(shape, kv, page):
     """(pages, [k_scale, v_scale] or [], pages per row) at phase-B widths."""
-    page = PAGE[kv]
+    page = aligned_page_size(page, STORED[kv])
     per_row = MAX_LEN // page
     n_pages = SLOTS * per_row + 1
-    pages = shape((n_pages, HEADS, page, 2 * HD),
-                  jnp.int8 if kv == "int8" else jnp.bfloat16)
+    pages = shape((n_pages, HEADS, page, 2 * HD), STORED[kv])
     scales = ([shape((n_pages, HEADS, page), jnp.bfloat16)] * 2
               if kv == "int8" else [])
     return pages, scales, per_row
@@ -89,9 +91,10 @@ def _scale_kw(scales):
     return dict(zip(("k_scale", "v_scale"), scales))
 
 
+@by_page
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_paged_read_kernel_compiles(one_chip, kv):
-    pages, scales, per_row = _pool(one_chip, kv)
+def test_paged_read_kernel_compiles(one_chip, kv, page):
+    pages, scales, per_row = _pool(one_chip, kv, page)
     q = one_chip((SLOTS, HEADS, 1, HD), jnp.bfloat16)
     bt = one_chip((SLOTS, per_row), jnp.int32)
     lens = one_chip((SLOTS,), jnp.int32)
@@ -103,11 +106,12 @@ def test_paged_read_kernel_compiles(one_chip, kv):
     _compiled_text(read, q, pages, bt, lens, *scales)
 
 
+@by_page
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("rows,window", [(SLOTS, 1), (1, CHUNK)],
                          ids=["tick", "prefill_chunk"])
-def test_paged_fused_window_kernel_compiles(one_chip, kv, rows, window):
-    pages, scales, per_row = _pool(one_chip, kv)
+def test_paged_fused_window_kernel_compiles(one_chip, kv, rows, window, page):
+    pages, scales, per_row = _pool(one_chip, kv, page)
     row = one_chip((rows, HEADS, window, HD), jnp.bfloat16)
     bt = one_chip((rows, per_row), jnp.int32)
     pos = one_chip((rows,), jnp.int32)
@@ -121,7 +125,8 @@ def test_paged_fused_window_kernel_compiles(one_chip, kv, rows, window):
     _compiled_text(fused, row, row, row, pages, bt, pos, active, *scales)
 
 
-def test_mesh_mounted_read_kernel_compiles(topo, one_chip):
+@by_page
+def test_mesh_mounted_read_kernel_compiles(topo, one_chip, page):
     """The dp2 x tp2 mount of chip_smoke.py --chips 4: slots over dp, heads
     over tp, no collective inside the mount."""
     import numpy as np
@@ -131,9 +136,9 @@ def test_mesh_mounted_read_kernel_compiles(topo, one_chip):
         return jax.ShapeDtypeStruct(dims, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    per_row = MAX_LEN // PAGE["bf16"]
+    per_row = MAX_LEN // page
     q = shape((SLOTS, HEADS, 1, HD), jnp.bfloat16, P("dp", "tp"))
-    pages = shape((SLOTS * per_row + 1, HEADS, PAGE["bf16"], 2 * HD),
+    pages = shape((SLOTS * per_row + 1, HEADS, page, 2 * HD),
                   jnp.bfloat16, P(None, "tp"))
     bt = shape((SLOTS, per_row), jnp.int32, P("dp"))
     lens = shape((SLOTS,), jnp.int32, P("dp"))
@@ -149,7 +154,10 @@ def test_mesh_mounted_read_kernel_compiles(topo, one_chip):
 def test_programs_update_the_page_pool_in_place(one_chip):
     """``chip_smoke.py``'s guard at no chip time: the decode tick, a 256-token
     extension and a group insertion at GPT-2 XL's widths (two layers of the
-    48: the copies were four a layer) hold no copy of a pool-sized buffer.
+    48: the copies were four a layer), in the pages the decoder derives from
+    ``max_len`` 1024 (64 positions, 16 a slot), hold no copy of a pool-sized
+    buffer, and each kernel's scoped VMEM fits ``_VMEM_LIMIT_BYTES`` (the
+    compiler refuses one that does not).
     The chip keeps a ``(pages, heads, page, 64)`` bf16 buffer with the page
     index minor-most, a layout the Mosaic call cannot take, so every program
     copied the pool in and out; packed 128 lanes wide it stays row-major."""
@@ -173,7 +181,7 @@ def test_programs_update_the_page_pool_in_place(one_chip):
     finally:
         pa._auto_interpret = continuous._pa_auto_interpret = interpret
         continuous._tick_program.cache_clear()
-    assert shapes == {("bfloat16", (577, 25, 16, 128))}
+    assert shapes == {("bfloat16", (145, 25, 64, 128))}
     assert "tpu_custom_call" in texts["jit_tick"]
     assert "slice-start" not in texts["jit_tick"]   # one slice a prefetch
     assert "slice-start" in texts["jit__extend"]
@@ -182,7 +190,7 @@ def test_programs_update_the_page_pool_in_place(one_chip):
     assert not any(copies.values()), copies
     # the check can see one: the parent's layout, copied in by the compiler
     assert smoke.pool_copies(
-        "%copy.1 = bf16[577,25,16,128]{3,2,1,0:T(8,128)(2,1)} copy(%p)",
+        "%copy.1 = bf16[145,25,64,128]{3,2,1,0:T(8,128)(2,1)} copy(%p)",
         shapes)
 
 

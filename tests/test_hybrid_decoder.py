@@ -290,6 +290,24 @@ def decoder(params, cfg):
                              page_size=8, prefill_chunk=32)
 
 
+@pytest.mark.parametrize("max_len,dense_page",
+                         [(224, 16), (1024, 64), (32768, 256)])
+def test_a_sparse_model_is_served_in_pages_of_its_sparse_block(
+        params, cfg, max_len, dense_page):
+    """No ``page_size`` given: selection and the compressed keys are laid
+    out by the sparse block, so the page stays there whatever ``max_len``
+    would derive; the layer type decides, not a name."""
+    from mmlspark_tpu.serving.continuous import derived_page_size
+    assert derived_page_size(cfg, max_len) == SPARSE["block_size"]
+    assert derived_page_size(cfg._replace(mixers=("lightning",) * 4),
+                             max_len) == dense_page
+    if max_len == 224:
+        dec = ContinuousDecoder(params, cfg, max_slots=1, max_len=max_len,
+                                prefill_chunk=32)
+        assert dec._kv.stats["page_size"] == SPARSE["block_size"]
+        assert dec._kv.stats["pages_per_slot"] == 224 // 8
+
+
 def drain(decoder, reqs):
     while not all(r.done for r in reqs):
         decoder.step()

@@ -220,6 +220,28 @@ class TestDecoderFailover:
         assert _finish(eb, rb) == want
         assert eb.stats["prefills"] == 0   # warm: no re-prefilled tokens
 
+    def test_workers_of_one_max_len_derive_one_page(self, params):
+        """No ``page_size`` given: two workers of ``max_len`` 1024 both
+        serve pages of 64 and hand a session over warm; a worker of
+        ``max_len`` 256 serves pages of 16 and refuses the blob with the
+        two layouts in its reason."""
+        prompt = np.arange(3, 90, dtype=np.int32)     # into a second page
+        ea = ContinuousDecoder(params, CFG, max_slots=2, max_len=1024)
+        want = _finish(ea, ea.submit(prompt, 10))
+        ra = ea.submit(prompt, 10)
+        for _ in range(4):
+            ea.step()
+        ckpt = ea.checkpoint_session(ra)
+        assert ckpt["kv"]["page_size"] == 64 and ckpt["kv"]["n_pages"] == 2
+        eb = ContinuousDecoder(params, CFG, max_slots=2, max_len=1024)
+        rb = eb.restore_session(ckpt["session"], kv_blob=ckpt["kv"])
+        assert _finish(eb, rb) == want and eb.stats["prefills"] == 0
+        small = ContinuousDecoder(params, CFG, max_slots=2, max_len=256)
+        with pytest.raises(ValueError, match=(
+                r"layout mismatch: blob \{'page_size': 64.*"
+                r"pool \{'page_size': 16")):
+            small.restore_session(ckpt["session"], kv_blob=ckpt["kv"])
+
     def test_double_failover_round_trips(self, params):
         """checkpoint(restore(checkpoint(x))) stays canonical: a second
         hop neither re-forces the prompt nor loses emitted tokens."""
