@@ -38,7 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .transformer import (TransformerConfig, _rms, _rope_tables, _rot_half)
+from .transformer import (TransformerConfig, _embed, _rms, _rope_tables,
+                          _rot_half, head)
 
 __all__ = ["check_config", "dims", "init_hybrid", "init_hybrid_cache",
            "init_hybrid_pool", "lightning_rates", "lightning_chunk",
@@ -515,11 +516,6 @@ def _selected_decode(q, kv, bt, pos, n_valid, idx, sel_ok, cfg, page):
 
 # ---- the window -------------------------------------------------------------
 
-def _embed(params, tokens, cfg):
-    h = params["embed"]["tok"].astype(cfg.dtype)[tokens]
-    return h * jnp.asarray(cfg.embed_scale, cfg.dtype)
-
-
 def _finish(params, h, cfg, n_valid, last_only):
     """Final norm with muP's logit scaling folded in: hidden states of every
     lane, or with ``last_only`` of lane ``n_valid - 1`` alone."""
@@ -531,18 +527,13 @@ def _finish(params, h, cfg, n_valid, last_only):
     return hidden
 
 
-def head(params, hidden):
-    """float32 logits of final hidden states."""
-    return hidden.astype(F32) @ params["lm_head"]["w"]
-
-
 def _window(params, tokens, pos, cfg, n_valid, mixer, last_only):
     """The layer loop shared by both cache forms; ``mixer(kind, lp, x, wpos,
     layer index)`` returns the mixer's output and records its new cache."""
     dt = cfg.dtype
     W = tokens.shape[1]
     wpos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)
-    h = _embed(params, tokens, cfg)
+    h = _embed(params, tokens, cfg) * jnp.asarray(cfg.embed_scale, dt)
     rs = jnp.asarray(cfg.residual_scale, dt)
     for i, (kind, lp) in enumerate(zip(cfg.mixers, params["layers"])):
         x = _rms(h.astype(F32), lp["ln1"]).astype(dt)

@@ -251,6 +251,74 @@ def _rope(q, k, theta: float):
     return _rot_half(q, cos, sin), _rot_half(k, cos, sin)
 
 
+def _embed(params, tokens, cfg: TransformerConfig, wpos=None):
+    """Rows of the token table for ``tokens`` (B, W), in ``cfg.dtype``: the
+    one read of the table every forward makes, both blocks' and the CPU
+    oracles'. The dense block adds its learned position rows, at ``wpos``
+    (B, W) or, with None, at 0..W-1 (a slice, not a gather); the hybrid block
+    has none and scales the rows itself."""
+    dt = cfg.dtype
+    h = params["embed"]["tok"].astype(dt)[tokens]
+    if cfg.position == "learned" and not cfg.mixers:
+        rows = params["embed"]["pos"].astype(dt)
+        h = h + (rows[:tokens.shape[1]][None] if wpos is None else rows[wpos])
+    return h
+
+
+def head(params, hidden):
+    """float32 logits of final hidden states: the one output product of
+    cached decoding, both blocks'."""
+    return hidden.astype(jnp.float32) @ params["lm_head"]["w"]
+
+
+def _softmax_attend(q, k, v, ok, dt):
+    """Softmax attention of (B, H, W, hd) queries over (B, H, L, hd) keys
+    where ``ok`` (broadcast to (B, H, W, L)) allows: float32 scores,
+    weights and context in ``dt``."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) / np.sqrt(q.shape[-1])
+    s = jnp.where(ok, s, jnp.float32(-1e30))
+    p = jax.nn.softmax(s, axis=-1).astype(dt)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, preferred_element_type=dt)
+
+
+def _dense_window(params, h, wpos, cfg: TransformerConfig, attend):
+    """The dense block's layer walk for every cached entry point: embedded
+    rows ``h`` (B, W, D) at absolute positions ``wpos`` -> final hidden states
+    (B, W, D). ``attend(i, q, k, v)`` owns the cache form: it takes layer
+    ``i``'s (B, H, W, hd) heads (rotated under RoPE), records the layer's
+    new cache and returns the context. The caller embeds (:func:`_embed`)
+    and builds its masks before the walk: the order of a program's
+    operations, and with it its lowered text, stays the entry point's."""
+    if cfg.moe_experts:
+        raise ValueError("cached decoding does not support MoE layers")
+    dt = cfg.dtype
+    B, W, _ = h.shape
+    hd = cfg.d_model // cfg.heads
+    if cfg.position == "rope":
+        cos, sin = _rope_tables(wpos, hd, cfg.rope_theta, dt)  # (B, W, h/2)
+        cos, sin = cos[:, None], sin[:, None]                  # (B,1,W,·)
+
+    def heads(t):
+        return t.reshape(B, W, cfg.heads, hd).transpose(0, 2, 1, 3)
+
+    for i, lp in enumerate(params["layers"]):
+        x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
+        qkv = x @ lp["qkv"]["w"].astype(dt) + lp["qkv"]["b"].astype(dt)
+        q, k, v = (heads(t) for t in jnp.split(qkv, 3, axis=-1))
+        if cfg.position == "rope":
+            q = _rot_half(q, cos, sin)
+            k = _rot_half(k, cos, sin)
+        ctx = attend(i, q, k, v)
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, W, cfg.d_model)
+        h = h + ctx @ lp["out"]["w"].astype(dt) + lp["out"]["b"].astype(dt)
+        x = _norm(h.astype(jnp.float32), lp["ln2"], cfg).astype(dt)
+        y = jax.nn.gelu(x @ lp["w1"]["w"].astype(dt) + lp["w1"]["b"].astype(dt))
+        y = y @ lp["w2"]["w"].astype(dt) + lp["w2"]["b"].astype(dt)
+        h = h + y
+    return _norm(h.astype(jnp.float32), params["final_ln"], cfg).astype(dt)
+
+
 def transformer_apply(params: Dict, ids: jnp.ndarray,
                       cfg: TransformerConfig,
                       mesh: Optional[Mesh] = None,
@@ -288,9 +356,7 @@ def transformer_apply(params: Dict, ids: jnp.ndarray,
         return x
 
     moe_aux = {"balance": jnp.float32(0.0), "dropped": jnp.float32(0.0)}
-    h = params["embed"]["tok"].astype(dt)[ids]
-    if cfg.position == "learned":
-        h = h + params["embed"]["pos"].astype(dt)[:S][None, :, :]
+    h = _embed(params, ids, cfg)
     # sequence-parallel region: activations sharded (dp, tp) on (B, S)
     h = constrain(h, P("dp", "tp", None))
 
@@ -531,72 +597,11 @@ def decode_step_ragged(params: Dict, tokens: jnp.ndarray, pos: jnp.ndarray,
     ``active`` (B,) bool (inactive rows keep their cache untouched and
     their logits are don't-care) → (logits (B, vocab), updated cache).
 
-    Same math as :func:`decode_step` per row; the only structural deltas
-    are per-row RoPE/learned-position gathers, a vmapped per-row cache
-    scatter, and the per-row key mask ``arange(L) <= pos[:, None]``.
+    The ``W = 1`` window of :func:`decode_window_ragged`.
     """
-    if cfg.mixers:
-        from .hybrid import head, window_contiguous
-        hidden, cache = window_contiguous(params, tokens[:, None], pos,
-                                          cache, cfg, active=active)
-        return head(params, hidden[:, 0]), cache
-    if cfg.moe_experts:
-        raise ValueError("cached decoding does not support MoE layers")
-    dt = cfg.dtype
-    B = tokens.shape[0]
-    L = cache[0]["k"].shape[2]
-    hd = cfg.d_model // cfg.heads
-    pos = pos.astype(jnp.int32)
-    h = params["embed"]["tok"].astype(dt)[tokens][:, None, :]   # (B, 1, D)
-    if cfg.position == "learned":
-        h = h + params["embed"]["pos"].astype(dt)[pos][:, None, :]
-    if cfg.position == "rope":
-        cos, sin = _rope_tables(pos, hd, cfg.rope_theta, dt)    # (B, hd/2)
-        cos, sin = cos[:, None, None], sin[:, None, None]       # (B,1,1,·)
-
-    def scatter_row(buf, val, p):
-        # (H, L, hd) ← (H, 1, hd) at key-position p; vmapped over rows
-        return jax.lax.dynamic_update_slice(buf, val, (0, p, 0))
-
-    row_scatter = jax.vmap(scatter_row)
-    # decode_step's shared-pos path passes active=None: skip the masking
-    # entirely so the delegation costs nothing
-    keep = None if active is None else active[:, None, None, None]
-    key_mask = (jnp.arange(L)[None] <= pos[:, None])[:, None, None]  # B,1,1,L
-    new_cache = []
-    for lp, c in zip(params["layers"], cache):
-        x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
-        qkv = x @ lp["qkv"]["w"].astype(dt) + lp["qkv"]["b"].astype(dt)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def heads1(t):
-            return t.reshape(B, 1, cfg.heads, hd).transpose(0, 2, 1, 3)
-
-        q, k, v = heads1(q), heads1(k), heads1(v)
-        if cfg.position == "rope":
-            q = _rot_half(q, cos, sin)
-            k = _rot_half(k, cos, sin)
-        kc = row_scatter(c["k"], k.astype(dt), pos)
-        vc = row_scatter(c["v"], v.astype(dt), pos)
-        if keep is not None:
-            kc = jnp.where(keep, kc, c["k"])
-            vc = jnp.where(keep, vc, c["v"])
-        new_cache.append({"k": kc, "v": vc})
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, kc,
-                       preferred_element_type=jnp.float32) / np.sqrt(hd)
-        s = jnp.where(key_mask, s, jnp.float32(-1e30))
-        p = jax.nn.softmax(s, axis=-1).astype(dt)
-        ctx = jnp.einsum("bhqk,bhkd->bhqd", p, vc,
-                         preferred_element_type=dt)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, 1, cfg.d_model)
-        h = h + ctx @ lp["out"]["w"].astype(dt) + lp["out"]["b"].astype(dt)
-        x = _norm(h.astype(jnp.float32), lp["ln2"], cfg).astype(dt)
-        y = jax.nn.gelu(x @ lp["w1"]["w"].astype(dt) + lp["w1"]["b"].astype(dt))
-        y = y @ lp["w2"]["w"].astype(dt) + lp["w2"]["b"].astype(dt)
-        h = h + y
-    hidden = _norm(h.astype(jnp.float32), params["final_ln"], cfg).astype(dt)
-    logits = hidden[:, 0].astype(jnp.float32) @ params["lm_head"]["w"]
-    return logits, new_cache
+    logits, cache = decode_window_ragged(params, tokens[:, None], pos, cache,
+                                         cfg, active)
+    return logits[:, 0], cache
 
 
 def prefill_cache(params: Dict, ids: jnp.ndarray, length,
@@ -612,59 +617,36 @@ def prefill_cache(params: Dict, ids: jnp.ndarray, length,
     decode incremental).
     """
     if cfg.mixers:
-        from .hybrid import head, init_hybrid_cache, window_contiguous
+        from .hybrid import init_hybrid_cache, window_contiguous
         B = ids.shape[0]
         hidden, cache = window_contiguous(
             params, ids, jnp.zeros((B,), jnp.int32),
             init_hybrid_cache(cfg, B, max_len), cfg, n_valid=length,
             last_only=True)
         return head(params, hidden), cache
-    if cfg.moe_experts:
-        raise ValueError("cached decoding does not support MoE layers")
     dt = cfg.dtype
     B, P = ids.shape
     if P > max_len:
         raise ValueError(f"prompt {P} exceeds cache max_len {max_len}")
-    hd = cfg.d_model // cfg.heads
     length = length.astype(jnp.int32)
-    valid = jnp.arange(P)[None] < length[:, None]               # (B, P)
-    h = params["embed"]["tok"].astype(dt)[ids]
-    if cfg.position == "learned":
-        h = h + params["embed"]["pos"].astype(dt)[:P][None]
+    wpos = jnp.arange(P)[None]                  # every row starts at 0
+    valid = wpos < length[:, None]                              # (B, P)
+    h = _embed(params, ids, cfg)
     tri = jnp.tril(jnp.ones((P, P), bool))
     # causal AND key-valid: padded key columns never attend anywhere
     attn_ok = tri[None, None] & valid[:, None, None, :]
-    cache = []
-    for lp in params["layers"]:
-        x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
-        qkv = x @ lp["qkv"]["w"].astype(dt) + lp["qkv"]["b"].astype(dt)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+    pad = ((0, 0), (0, 0), (0, max_len - P), (0, 0))
+    cache = [None] * len(params["layers"])
 
-        def heads(t):
-            return t.reshape(B, P, cfg.heads, hd).transpose(0, 2, 1, 3)
+    def attend(i, q, k, v):
+        # fresh K/V: attended as they are, recorded padded to max_len
+        cache[i] = {"k": jnp.pad(k.astype(dt), pad),
+                    "v": jnp.pad(v.astype(dt), pad)}
+        return _softmax_attend(q, k, v, attn_ok, dt)
 
-        q, k, v = heads(q), heads(k), heads(v)
-        if cfg.position == "rope":
-            q, k = _rope(q, k, cfg.rope_theta)
-        kc = jnp.pad(k.astype(dt), ((0, 0), (0, 0), (0, max_len - P), (0, 0)))
-        vc = jnp.pad(v.astype(dt), ((0, 0), (0, 0), (0, max_len - P), (0, 0)))
-        cache.append({"k": kc, "v": vc})
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                       preferred_element_type=jnp.float32) / np.sqrt(hd)
-        s = jnp.where(attn_ok, s, jnp.float32(-1e30))
-        p = jax.nn.softmax(s, axis=-1).astype(dt)
-        ctx = jnp.einsum("bhqk,bhkd->bhqd", p, v,
-                         preferred_element_type=dt)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, P, cfg.d_model)
-        h = h + ctx @ lp["out"]["w"].astype(dt) + lp["out"]["b"].astype(dt)
-        x = _norm(h.astype(jnp.float32), lp["ln2"], cfg).astype(dt)
-        y = jax.nn.gelu(x @ lp["w1"]["w"].astype(dt) + lp["w1"]["b"].astype(dt))
-        y = y @ lp["w2"]["w"].astype(dt) + lp["w2"]["b"].astype(dt)
-        h = h + y
-    hidden = _norm(h.astype(jnp.float32), params["final_ln"], cfg).astype(dt)
+    hidden = _dense_window(params, h, wpos, cfg, attend)
     last = jnp.take_along_axis(hidden, (length - 1)[:, None, None], axis=1)
-    logits = last[:, 0].astype(jnp.float32) @ params["lm_head"]["w"]
-    return logits, cache
+    return head(params, last[:, 0]), cache
 
 
 def decode_window(params: Dict, tokens: jnp.ndarray, pos, cache,
@@ -706,27 +688,21 @@ def decode_window_ragged(params: Dict, tokens: jnp.ndarray,
     exactly :func:`decode_window` per row with a scalar start.
     """
     if cfg.mixers:
-        from .hybrid import head, window_contiguous
+        from .hybrid import window_contiguous
         hidden, cache = window_contiguous(params, tokens, pos, cache, cfg,
                                           active=active)
         return head(params, hidden), cache
-    if cfg.moe_experts:
-        raise ValueError("cached decoding does not support MoE layers")
     dt = cfg.dtype
-    B, W = tokens.shape
+    W = tokens.shape[1]
     L = cache[0]["k"].shape[2]
-    hd = cfg.d_model // cfg.heads
     pos = pos.astype(jnp.int32)
     wpos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)       # (B, W)
-    h = params["embed"]["tok"].astype(dt)[tokens]              # (B, W, D)
-    if cfg.position == "learned":
-        h = h + params["embed"]["pos"].astype(dt)[wpos]
-    if cfg.position == "rope":
-        cos, sin = _rope_tables(wpos, hd, cfg.rope_theta, dt)  # (B, W, h/2)
-        cos, sin = cos[:, None], sin[:, None]                  # (B,1,W,·)
+    h = _embed(params, tokens, cfg, wpos)
     # row b, query j sees cached keys at positions <= pos[b] + j
     key_ok = (jnp.arange(L)[None, None, :]
               <= wpos[:, :, None])[:, None]                    # (B,1,W,L)
+    # decode_step's shared-pos path passes active=None: skip the masking
+    # entirely so the delegation costs nothing
     keep = None if active is None else active[:, None, None, None]
 
     def scatter_row(buf, val, p):
@@ -734,64 +710,31 @@ def decode_window_ragged(params: Dict, tokens: jnp.ndarray,
         return jax.lax.dynamic_update_slice(buf, val, (0, p, 0))
 
     row_scatter = jax.vmap(scatter_row)
-    new_cache = []
-    for lp, c in zip(params["layers"], cache):
-        x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
-        qkv = x @ lp["qkv"]["w"].astype(dt) + lp["qkv"]["b"].astype(dt)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+    new_cache = [None] * len(cache)
 
-        def heads(t):
-            return t.reshape(B, W, cfg.heads, hd).transpose(0, 2, 1, 3)
-
-        q, k, v = heads(q), heads(k), heads(v)
-        if cfg.position == "rope":
-            q = _rot_half(q, cos, sin)
-            k = _rot_half(k, cos, sin)
+    def attend(i, q, k, v):
+        c = cache[i]
         kc = row_scatter(c["k"], k.astype(dt), pos)
         vc = row_scatter(c["v"], v.astype(dt), pos)
         if keep is not None:
             kc = jnp.where(keep, kc, c["k"])
             vc = jnp.where(keep, vc, c["v"])
-        new_cache.append({"k": kc, "v": vc})
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, kc,
-                       preferred_element_type=jnp.float32) / np.sqrt(hd)
-        s = jnp.where(key_ok, s, jnp.float32(-1e30))
-        p = jax.nn.softmax(s, axis=-1).astype(dt)
-        ctx = jnp.einsum("bhqk,bhkd->bhqd", p, vc,
-                         preferred_element_type=dt)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, W, cfg.d_model)
-        h = h + ctx @ lp["out"]["w"].astype(dt) + lp["out"]["b"].astype(dt)
-        x = _norm(h.astype(jnp.float32), lp["ln2"], cfg).astype(dt)
-        y = jax.nn.gelu(x @ lp["w1"]["w"].astype(dt) + lp["w1"]["b"].astype(dt))
-        y = y @ lp["w2"]["w"].astype(dt) + lp["w2"]["b"].astype(dt)
-        h = h + y
-    hidden = _norm(h.astype(jnp.float32), params["final_ln"], cfg).astype(dt)
-    logits = hidden.astype(jnp.float32) @ params["lm_head"]["w"]
-    return logits, new_cache
+        new_cache[i] = {"k": kc, "v": vc}
+        return _softmax_attend(q, kc, vc, key_ok, dt)
+
+    hidden = _dense_window(params, h, wpos, cfg, attend)
+    return head(params, hidden), new_cache
 
 
-# ---- paged KV cache (vLLM-style PagedAttention, XLA-level) -----------------
-# The physical cache is a pool of fixed-size PAGES — per layer one
-# (num_pages, H, page_size, 2*hd) buffer, K beside V — and each batch row owns a
-# BLOCK TABLE row mapping its logical pages to physical ones. A decode/
-# window step gathers the row's pages into the familiar contiguous
-# (B, H, L, hd) layout, runs the EXACT ragged-step math on it (reusing
-# decode_step_ragged / decode_window_ragged — the paged path is bitwise
-# equal to the contiguous path by construction: post-mask scores are
-# identical and masked lanes contribute exactly 0 to the f32 softmax),
-# and scatters only the freshly-written positions back into their pages.
-# Physical page 0 is reserved as the TRASH page: block-table entries for
-# unallocated logical pages point at it, and inactive rows' writebacks
-# are redirected there, so a retired slot can never corrupt pages that
-# were freed and handed to another request.
-#
-# Gathering costs one O(B·L) copy per step — the price of page-granular
-# allocation and cross-request prefix sharing (serving/kv_pool.py). The
-# fused Pallas paged-attention kernel (ops/paged_attention.py) reads
-# pages in place and eliminates that copy; under a mesh it mounts via
-# shard_map with heads split over tp and slots over dp, so the gather
-# path below survives only as the parity oracle and env-knob escape
-# hatch.
+# ---- paged KV cache (vLLM-style PagedAttention) ----------------------------
+# The physical cache is a pool of fixed-size PAGES, per layer one
+# (num_pages, H, page_size, 2*hd) buffer, K beside V; a BLOCK TABLE row maps
+# each batch row's logical pages to physical ones, and page 0 is the TRASH
+# page that unallocated entries and inactive rows' writes point at. The one
+# layer loop is ``_dense_window``; ``decode_window_paged`` gives it the
+# closure that reads and writes pages in place (ops/paged_attention.py).
+# ``impl="gather"`` is the parity oracle: ``paged_gather`` -> the contiguous
+# window -> ``_paged_writeback``, bitwise equal to the contiguous path.
 
 def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                      page_size: int, kv_dtype=None):
@@ -912,104 +855,21 @@ def _paged_writeback(cache_pages, new_cache, block_tables, wpos,
             for c, nc in zip(cache_pages, new_cache)]
 
 
-def _decode_window_paged_kernel(params: Dict, tokens: jnp.ndarray,
-                                pos: jnp.ndarray, cache_pages,
-                                block_tables, cfg: TransformerConfig,
-                                page_size: int,
-                                active: Optional[jnp.ndarray],
-                                mesh=None, slot_axis=None, head_axis=None):
-    """The Pallas paged-attention layer loop: identical embedding / rope /
-    projection / FFN math to :func:`decode_window_ragged`, but attention
-    reads K/V pages IN PLACE through the block table and scatters the
-    window's fresh rows in the same launch
-    (:func:`~mmlspark_tpu.ops.paged_attention.paged_attention_window`) —
-    no contiguous gather, no separate writeback. Page contents written
-    are bit-identical to ``_paged_writeback``'s; the context differs from
-    the gather path only by f32 online-softmax accumulation order."""
-    from ...ops.paged_attention import paged_attention_window
-    dt = cfg.dtype
-    B, W = tokens.shape
-    hd = cfg.d_model // cfg.heads
-    pos = pos.astype(jnp.int32)
-    wpos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)       # (B, W)
-    h = params["embed"]["tok"].astype(dt)[tokens]              # (B, W, D)
-    if cfg.position == "learned":
-        h = h + params["embed"]["pos"].astype(dt)[wpos]
-    if cfg.position == "rope":
-        cos, sin = _rope_tables(wpos, hd, cfg.rope_theta, dt)  # (B, W, h/2)
-        cos, sin = cos[:, None], sin[:, None]                  # (B,1,W,·)
-    new_pages = []
-    for lp, c in zip(params["layers"], cache_pages):
-        x = _norm(h.astype(jnp.float32), lp["ln1"], cfg).astype(dt)
-        qkv = x @ lp["qkv"]["w"].astype(dt) + lp["qkv"]["b"].astype(dt)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def heads(t):
-            return t.reshape(B, W, cfg.heads, hd).transpose(0, 2, 1, 3)
-
-        q, k, v = heads(q), heads(k), heads(v)
-        if cfg.position == "rope":
-            q = _rot_half(q, cos, sin)
-            k = _rot_half(k, cos, sin)
-        scales = ({"k_scale": c["k_scale"], "v_scale": c["v_scale"]}
-                  if _is_quant_cache(c) else {})
-        ctx, *pools = paged_attention_window(
-            q, k.astype(dt), v.astype(dt), c["kv"], block_tables, pos,
-            active=active, mesh=mesh, slot_axis=slot_axis,
-            head_axis=head_axis, **scales)
-        new_pages.append(dict(zip(("kv", *scales), pools)))
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, W, cfg.d_model)
-        h = h + ctx @ lp["out"]["w"].astype(dt) + lp["out"]["b"].astype(dt)
-        x = _norm(h.astype(jnp.float32), lp["ln2"], cfg).astype(dt)
-        y = jax.nn.gelu(x @ lp["w1"]["w"].astype(dt) + lp["w1"]["b"].astype(dt))
-        y = y @ lp["w2"]["w"].astype(dt) + lp["w2"]["b"].astype(dt)
-        h = h + y
-    hidden = _norm(h.astype(jnp.float32), params["final_ln"], cfg).astype(dt)
-    logits = hidden.astype(jnp.float32) @ params["lm_head"]["w"]
-    return logits, new_pages
-
-
 def decode_step_paged(params: Dict, tokens: jnp.ndarray, pos: jnp.ndarray,
                       cache_pages, block_tables, cfg: TransformerConfig, *,
                       page_size: int, length: int,
                       active: Optional[jnp.ndarray] = None,
                       impl: Optional[str] = None,
                       mesh=None, slot_axis=None, head_axis=None):
-    """One paged decode step. Two implementations, selected by ``impl``
-    (``None`` → the ``MMLSPARK_TPU_PAGED_ATTN`` env knob, default
-    ``"kernel"``):
-
-    * ``"kernel"`` — the Pallas paged-attention kernel attends directly
-      over the page pool through the block table and scatters the fresh
-      K/V row in the same launch. Page writes are bit-identical to the
-      gather path; logits agree to f32 accumulation-order tolerance.
-    * ``"gather"`` — PR 7's path: gather through the block table, run the
-      IDENTICAL ragged-step math, scatter the one new K/V position per
-      row back to its page. Logits are bitwise equal to the contiguous
-      path on the same cache contents (masked garbage lanes contribute
-      exactly 0). ``length`` is the logical cache length (the contiguous
-      L); every ``pos`` must be < length."""
-    from ...ops.paged_attention import resolve_impl
-    if cfg.mixers:
-        logits, pages = decode_window_paged(
-            params, tokens[:, None], pos, cache_pages, block_tables, cfg,
-            page_size=page_size, length=length, active=active, impl=impl,
-            mesh=mesh)
-        return logits[:, 0], pages
-    if resolve_impl(impl) == "kernel":
-        logits, pages = _decode_window_paged_kernel(
-            params, tokens[:, None], pos.astype(jnp.int32), cache_pages,
-            block_tables, cfg, page_size, active, mesh=mesh,
-            slot_axis=slot_axis, head_axis=head_axis)
-        return logits[:, 0], pages
-    gathered = paged_gather(cache_pages, block_tables, length,
-                            out_dtype=cfg.dtype)
-    logits, new = decode_step_ragged(params, tokens, pos.astype(jnp.int32),
-                                     gathered, cfg, active)
-    pages = _paged_writeback(cache_pages, new, block_tables,
-                             pos.astype(jnp.int32)[:, None], page_size,
-                             active)
-    return logits, pages
+    """One paged decode step: the ``W = 1`` window of
+    :func:`decode_window_paged`, which describes the two implementations
+    ``impl`` selects. ``tokens`` (B,), ``pos`` (B,), every ``pos`` <
+    ``length`` → (logits (B, vocab), updated pages)."""
+    logits, pages = decode_window_paged(
+        params, tokens[:, None], pos, cache_pages, block_tables, cfg,
+        page_size=page_size, length=length, active=active, impl=impl,
+        mesh=mesh, slot_axis=slot_axis, head_axis=head_axis)
+    return logits[:, 0], pages
 
 
 def decode_window_paged(params: Dict, tokens: jnp.ndarray,
@@ -1020,19 +880,32 @@ def decode_window_paged(params: Dict, tokens: jnp.ndarray,
                         impl: Optional[str] = None,
                         mesh=None, slot_axis=None, head_axis=None,
                         n_valid=None, slot=None, last_only: bool = False):
-    """Paged window decode — the speculative verify and chunked-prefill
-    primitive. Row b's window writes positions ``pos[b]..pos[b]+W-1``
-    into its pages; every such position must be < ``length`` (the engine
-    sizes allocations so windows never clamp). ``impl`` selects the
-    Pallas kernel (default) or PR 7's gather path exactly as in
-    :func:`decode_step_paged`.
+    """Paged window decode — the decode tick (``W = 1``), the speculative
+    verify and the chunked-prefill primitive. Row b's window writes
+    positions ``pos[b]..pos[b]+W-1`` into its pages; every such position
+    must be < ``length``, the logical cache length (the engine sizes
+    allocations so windows never clamp). Two implementations, selected by
+    ``impl`` (``None`` → the ``MMLSPARK_TPU_PAGED_ATTN`` env knob, default
+    ``"kernel"``):
+
+    * ``"kernel"`` — the Pallas paged-attention kernel attends over the page
+      pool IN PLACE through the block table and scatters the window's fresh
+      K/V rows in the same launch
+      (:func:`~mmlspark_tpu.ops.paged_attention.paged_attention_window`).
+      Page writes are bit-identical to the gather path's; logits agree to
+      f32 online-softmax accumulation order.
+    * ``"gather"`` — the parity oracle: gather through the block table to
+      the contiguous ``(B, H, length, hd)`` layout, run
+      :func:`decode_window_ragged` on it, scatter the window's positions
+      back to their pages. Logits are bitwise equal to the contiguous path
+      on the same cache contents (masked lanes contribute exactly 0).
 
     A hybrid decoder (``cfg.mixers``) also carries state rows in
     ``cache_pages`` and takes three more arguments, which the dense block
     refuses: ``n_valid`` (B,), the real lanes of each row (padding must not
     reach a state); ``slot``, the state row of a one-row prefill window;
     ``last_only``, logits (B, vocab) of lane ``n_valid - 1`` alone."""
-    from ...ops.paged_attention import resolve_impl
+    from ...ops.paged_attention import paged_attention_window, resolve_impl
     if cfg.mixers:
         if mesh is not None:
             raise ValueError("a hybrid decoder takes no mesh")
@@ -1044,22 +917,33 @@ def decode_window_paged(params: Dict, tokens: jnp.ndarray,
     if n_valid is not None or slot is not None or last_only:
         raise ValueError("n_valid, slot and last_only belong to a hybrid "
                          "decoder's window")
+    dt = cfg.dtype
     W = tokens.shape[1]
     pos = pos.astype(jnp.int32)
-    if resolve_impl(impl) == "kernel":
-        return _decode_window_paged_kernel(params, tokens, pos,
-                                           cache_pages, block_tables,
-                                           cfg, page_size, active,
-                                           mesh=mesh, slot_axis=slot_axis,
-                                           head_axis=head_axis)
-    wpos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)
-    gathered = paged_gather(cache_pages, block_tables, length,
-                            out_dtype=cfg.dtype)
-    logits, new = decode_window_ragged(params, tokens, pos, gathered,
-                                       cfg, active)
-    pages = _paged_writeback(cache_pages, new, block_tables, wpos,
-                             page_size, active)
-    return logits, pages
+    wpos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)       # (B, W)
+    if resolve_impl(impl) != "kernel":
+        gathered = paged_gather(cache_pages, block_tables, length,
+                                out_dtype=dt)
+        logits, new = decode_window_ragged(params, tokens, pos, gathered,
+                                           cfg, active)
+        return logits, _paged_writeback(cache_pages, new, block_tables,
+                                        wpos, page_size, active)
+    h = _embed(params, tokens, cfg, wpos)
+    new_pages = [None] * len(cache_pages)
+
+    def attend(i, q, k, v):
+        c = cache_pages[i]
+        scales = ({"k_scale": c["k_scale"], "v_scale": c["v_scale"]}
+                  if _is_quant_cache(c) else {})
+        ctx, *pools = paged_attention_window(
+            q, k.astype(dt), v.astype(dt), c["kv"], block_tables, pos,
+            active=active, mesh=mesh, slot_axis=slot_axis,
+            head_axis=head_axis, **scales)
+        new_pages[i] = dict(zip(("kv", *scales), pools))
+        return ctx
+
+    hidden = _dense_window(params, h, wpos, cfg, attend)
+    return head(params, hidden), new_pages
 
 
 def generate_cached(params: Dict, prompt_ids, cfg: TransformerConfig,

@@ -22,14 +22,39 @@ from mmlspark_tpu.ops.padding import pad_axis_device
 
 
 @pytest.fixture(autouse=True)
-def _clean_slate():
-    # drop any chunks earlier tests left resident, zero the counters, and
-    # run unbudgeted unless a test configures otherwise
-    get_residency_manager().spill_all()
-    configure_residency(0)
+def _clean_slate(monkeypatch):
+    # a manager of this file's own for the test's duration, unbudgeted unless
+    # a test configures otherwise, and the counters at zero. The process-wide
+    # manager also counts what is not this file's to drop: the reservation of
+    # a PagedKVPool that another file's engine still holds when xdist deals
+    # both files to one worker counts against any budget set here.
+    monkeypatch.setattr(R, "_MANAGER", R.ResidencyManager(0))
     reset_all()
-    yield
-    configure_residency(0)
+
+
+#: the manager the process's other owners reserve in
+PROCESS_WIDE = R.get_residency_manager()
+
+
+@pytest.fixture(scope="module")
+def live_pool():
+    """A page pool alive in the process-wide manager, as an engine of an
+    earlier test file is."""
+    from mmlspark_tpu.models.zoo.transformer import DECODER_MINI
+    from mmlspark_tpu.serving.kv_pool import PagedKVPool
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(R, "_MANAGER", PROCESS_WIDE)
+        pool = PagedKVPool(DECODER_MINI, num_pages=2, page_size=8)
+    assert PROCESS_WIDE.reserved_bytes() >= pool.device_bytes() > 32
+    yield pool
+    pool.close()
+
+
+@pytest.fixture(params=["alone", "beside_live_pool"])
+def beside(request):
+    """The spill tests run alone and beside a live pool of another owner."""
+    if request.param == "beside_live_pool":
+        request.getfixturevalue("live_pool")
 
 
 def _h2d(site):
@@ -118,7 +143,7 @@ def test_concat_of_resident_frames_stays_resident():
 # LRU spill under a device-memory budget
 
 
-def test_lru_spill_respects_budget_and_restages_on_access():
+def test_lru_spill_respects_budget_and_restages_on_access(beside):
     df = DataFrame({"x": np.zeros(16, dtype=np.float32)}, npartitions=4)
     df = df.device_put(["x"])        # 4 chunks x 16 bytes
     col = df.device_column("x")
@@ -138,7 +163,7 @@ def test_lru_spill_respects_budget_and_restages_on_access():
     assert _h2d("restage") > 0
 
 
-def test_spill_is_lru_ordered():
+def test_spill_is_lru_ordered(beside):
     df = DataFrame({"x": np.zeros(16, dtype=np.float32)}, npartitions=4)
     df = df.device_put(["x"])
     col = df.device_column("x")
