@@ -28,16 +28,29 @@ Design notes (TPU-first):
   stays row-major on the device, and the aliased call and the donated
   programs update it in place. The ``(N, H, page)`` scale pools of a
   quantized pool keep their shape (1/64 of the bytes).
-* grid = (B, P_max) with the page sweep innermost. Blocks carry the full
-  head dimension — a page block is ``(1, H, page, 2*hd)``, K and V of one
-  page in one DMA — so each page is DMA'd ONCE per row per layer, not once
-  per head; the kernel splits the block on the lane axis in VMEM.
-* the physical page for grid step ``(b, p)`` comes from a
-  scalar-prefetched block table: the BlockSpec index_map reads
-  ``bt[b, p]`` (``PrefetchScalarGridSpec``), which is exactly the
-  indirection ``paged_gather`` used to materialize. Unallocated logical
-  pages map to the TRASH page 0 in the table; their keys are masked out
-  by the per-row length bound anyway.
+* THE GRID IS THE BATCH'S LIVE PAGES: one ragged sweep of
+  ``sum over rows of (pages that row needs)`` steps, not ``slots x pages a
+  slot``. A step that fetches a page and folds nothing costs ~0.5 us, one
+  that moves nothing ~0.1 us, and under ``max_len`` 1024 ten of a row's 16
+  were such (PERF.md, PR 34). :func:`_schedule` builds, on the device and
+  once a tick (the layers' calls share it), two scalar-prefetched int32
+  vectors that say which ``(row, page)`` step ``s`` is, a row's pages in
+  order and rows in order; the number of steps is a TRACED grid bound, so
+  one compiled program serves every batch of contexts. A row needs the
+  pages that hold its keys and, in a fused call, the pages its window
+  writes (at ``pos % page == 0`` the write page lies after the last page
+  with keys); a row with nothing to read or write takes one step, which
+  initialises and writes out its (meaningless) context. Blocks carry the
+  full head dimension — a page block is ``(1, H, page, 2*hd)``, K and V of
+  one page in one DMA — so each page is DMA'd ONCE per row per layer, not
+  once per head; the kernel splits the block on the lane axis in VMEM.
+* the physical page for grid step ``s`` comes from the scalar-prefetched
+  block table: the BlockSpec index_map reads ``bt[row_of[s], page_of[s]]``
+  (``PrefetchScalarGridSpec``), which is exactly the indirection
+  ``paged_gather`` used to materialize. Every VMEM byte a step reads was
+  DMA'd for that step by the pipeline. Pages a row does not need are never
+  visited, whatever its block table holds there; the keys of its last page
+  past the row's length are masked by the per-row bound.
 * running ``m``/``l`` live in VMEM scratch shaped ``(H, W, LANE)``
   (lane-replicated, as in ``flash_attention.py``); the f32 context
   accumulator is ``(H, W, hd)``. Masked logits use ``-1e30`` — a fully
@@ -262,26 +275,39 @@ def _overlay(blk, new_ref, pos, p, page, W, ridx):
     return blk
 
 
-# Four kernels, one contract. Grid (b, p), page sweep innermost; the page
-# block is (1, H, page, 2*hd), K in lanes [0, hd) and V in [hd, 2*hd); a
-# quantized pool adds two (1, H, page) scale blocks riding the SAME
-# block-table index_map (``quant``: they follow the page block among the
-# operands). The *read* kernel attends ``len_ref[b]`` cached keys; the
-# *window* kernel folds the window's own rows once at p == 0 and masks
-# page keys STRICTLY below ``pos[b]``, so page blocks are always read
-# pre-scatter; the two *fused* kernels (plain and quantized pages) also
-# write the window's rows into their pages through the aliased pool
-# outputs (every grid step outside the row's write range leaves its
-# trash-directed output block untouched). The mesh mount runs the window
-# kernel: under a mesh the fresh rows are written outside the mount
-# (:func:`_pool_write_rows`).
+# Four kernels, one contract. The grid is ONE ragged sweep (:func:`_schedule`):
+# step ``s`` is page ``page_of[s]`` of row ``row_of[s]``, a row's pages in
+# order and only the pages it needs, and the number of steps is a traced
+# bound, so a call costs the batch's live pages whatever ``max_len`` is. A
+# row's first step (p == 0) initialises the accumulators, its last
+# (p == last_of[b]) writes the context out. The page block is
+# (1, H, page, 2*hd), K in lanes [0, hd) and V in [hd, 2*hd); a quantized
+# pool adds two (1, H, page) scale blocks riding the SAME block-table
+# index_map (``quant``: they follow the page block among the operands). The
+# *read* kernel attends ``len_ref[b]`` cached keys; the *window* kernel folds
+# the window's own rows once at p == 0 and masks page keys STRICTLY below
+# ``pos[b]``, so page blocks are always read pre-scatter; the two *fused*
+# kernels (plain and quantized pages) also write the window's rows into
+# their pages through the aliased pool outputs (every grid step outside the
+# row's write range leaves its trash-directed output block untouched). The
+# mesh mount runs the window kernel: under a mesh the fresh rows are written
+# outside the mount (:func:`_pool_write_rows`).
 
-def _pa_read_kernel(bt_ref, len_ref, q_ref, kv_ref, *rest,
-                    scale, page, n_pages, quant):
+def _step(row_ref, page_ref, last_ref):
+    """``(row, page, is the row's last step)`` of this grid step."""
+    from jax.experimental import pallas as pl
+
+    s = pl.program_id(0)
+    b, p = row_ref[s], page_ref[s]
+    return b, p, p == last_ref[b]
+
+
+def _pa_read_kernel(row_ref, page_ref, last_ref, bt_ref, len_ref, q_ref,
+                    kv_ref, *rest, scale, page, quant):
     from jax.experimental import pallas as pl
 
     scales, (o_ref, m_scr, l_scr, acc_scr) = rest[:2 * quant], rest[2 * quant:]
-    b, p = pl.program_id(0), pl.program_id(1)
+    b, p, last = _step(row_ref, page_ref, last_ref)
     pl.when(p == 0)(lambda: _init(m_scr, l_scr, acc_scr))
     bound = len_ref[b]
 
@@ -290,15 +316,15 @@ def _pa_read_kernel(bt_ref, len_ref, q_ref, kv_ref, *rest,
         _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref, *scales),
                     p, bound, scale, page)
 
-    pl.when(p == n_pages - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
+    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
 
 
-def _pa_window_kernel(bt_ref, pos_ref, q_ref, kvn_ref, kv_ref, *rest,
-                      scale, page, W, n_pages, quant):
+def _pa_window_kernel(row_ref, page_ref, last_ref, bt_ref, pos_ref, q_ref,
+                      kvn_ref, kv_ref, *rest, scale, page, W, quant):
     from jax.experimental import pallas as pl
 
     scales, (o_ref, m_scr, l_scr, acc_scr) = rest[:2 * quant], rest[2 * quant:]
-    b, p = pl.program_id(0), pl.program_id(1)
+    b, p, last = _step(row_ref, page_ref, last_ref)
     pos = pos_ref[b]
 
     @pl.when(p == 0)
@@ -311,15 +337,15 @@ def _pa_window_kernel(bt_ref, pos_ref, q_ref, kvn_ref, kv_ref, *rest,
         _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref, *scales),
                     p, pos, scale, page)
 
-    pl.when(p == n_pages - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
+    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
 
 
-def _pa_fused_kernel(bt_ref, pos_ref, wlo_ref, whi_ref, q_ref, kvn_ref,
-                     kv_ref, o_ref, kvo_ref, m_scr, l_scr, acc_scr, *,
-                     scale, page, W, n_pages):
+def _pa_fused_kernel(row_ref, page_ref, last_ref, bt_ref, pos_ref, wlo_ref,
+                     whi_ref, q_ref, kvn_ref, kv_ref, o_ref, kvo_ref,
+                     m_scr, l_scr, acc_scr, *, scale, page, W):
     from jax.experimental import pallas as pl
 
-    b, p = pl.program_id(0), pl.program_id(1)
+    b, p, last = _step(row_ref, page_ref, last_ref)
     pos = pos_ref[b]
 
     @pl.when(p == 0)
@@ -337,20 +363,20 @@ def _pa_fused_kernel(bt_ref, pos_ref, wlo_ref, whi_ref, q_ref, kvn_ref,
         ridx = jax.lax.broadcasted_iota(jnp.int32, (1, page, 1), 1)
         kvo_ref[0] = _overlay(kv_ref[0], kvn_ref, pos, p, page, W, ridx)
 
-    pl.when(p == n_pages - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
+    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
 
 
-def _pa_fused_kernel_q(bt_ref, pos_ref, wlo_ref, whi_ref, q_ref, kvn_ref,
-                       kvq_ref, ksn_ref, vsn_ref, kv_ref, ks_ref, vs_ref,
-                       o_ref, kvo_ref, kso_ref, vso_ref,
-                       m_scr, l_scr, acc_scr, *, scale, page, W, n_pages):
+def _pa_fused_kernel_q(row_ref, page_ref, last_ref, bt_ref, pos_ref, wlo_ref,
+                       whi_ref, q_ref, kvn_ref, kvq_ref, ksn_ref, vsn_ref,
+                       kv_ref, ks_ref, vs_ref, o_ref, kvo_ref, kso_ref,
+                       vso_ref, m_scr, l_scr, acc_scr, *, scale, page, W):
     """The quantized fused kernel folds the window's UNQUANTIZED rows
     (``kvn``) and writes their quantized twins (``kvq`` with the per-head
     scales ``ksn``/``vsn``, all through :func:`quantize_kv` in the
     caller: the sanctioned helper, so every writer agrees bit for bit)."""
     from jax.experimental import pallas as pl
 
-    b, p = pl.program_id(0), pl.program_id(1)
+    b, p, last = _step(row_ref, page_ref, last_ref)
     pos = pos_ref[b]
 
     @pl.when(p == 0)
@@ -371,13 +397,40 @@ def _pa_fused_kernel_q(bt_ref, pos_ref, wlo_ref, whi_ref, q_ref, kvn_ref,
         kso_ref[0] = _overlay(ks_ref[0], ksn_ref, pos, p, page, W, sidx)
         vso_ref[0] = _overlay(vs_ref[0], vsn_ref, pos, p, page, W, sidx)
 
-    pl.when(p == n_pages - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
+    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
 
 
-def _grid_spec(n_scalar, B, n_pages, in_specs, out_specs, H, Wp, hd):
+def _schedule(bound, whi, page, n_pages):
+    """The ragged sweep of one call: ``(row_of, page_of, last_of, total)``.
+
+    Row ``b`` needs ``n_b`` pages: those that hold a key below ``bound[b]``
+    and, for a fused call, those up to the last page its window writes
+    (``whi[b]``, below 0 for none: at ``pos % page == 0`` the write page
+    lies after the last page with keys), at least one, so that every row
+    initialises and writes out its context. Step ``s`` of the
+    ``total = sum(n_b)`` grid steps is page ``page_of[s]`` of row
+    ``row_of[s]``, rows in order and a row's pages in order, and
+    ``last_of[b] = n_b - 1``. The two ``(B * n_pages,)`` int32 vectors are
+    whole only up to ``total``: entries past it are never visited."""
+    B = bound.shape[0]
+    n = jnp.clip(jnp.maximum(-(-bound // page), whi + 1), 1,
+                 n_pages).astype(jnp.int32)
+    ends = jnp.cumsum(n)
+    steps = jnp.arange(B * n_pages, dtype=jnp.int32)
+    done = steps[:, None] >= ends[None, :]        # rows wholly before step s
+    row_of = jnp.minimum(jnp.sum(done, axis=1), B - 1).astype(jnp.int32)
+    page_of = jnp.minimum(
+        steps - jnp.sum(jnp.where(done, n[None, :], 0), axis=1),
+        n_pages - 1).astype(jnp.int32)
+    return row_of, page_of, n - 1, ends[-1]
+
+
+def _grid_spec(n_scalar, total, in_specs, out_specs, H, Wp, hd):
+    """``n_scalar`` counts the call's own scalar-prefetch operands; the
+    schedule's three vectors go before them."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_scalar, grid=(B, n_pages),
+        num_scalar_prefetch=3 + n_scalar, grid=(total,),
         in_specs=in_specs, out_specs=out_specs,
         scratch_shapes=[
             _vmem((H, Wp, _LANE), jnp.float32),   # running max m
@@ -398,43 +451,44 @@ def _compiler_params(interpret: bool):
     if interpret:
         return None
     from jax.experimental.pallas import tpu as pltpu
-    # both grid dims carry loop state (online-softmax accumulators and
-    # the write-on-index-change page outputs) — never parallelizable
+    # the sweep carries loop state (online-softmax accumulators and the
+    # write-on-index-change page outputs) — never parallelizable
     return pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"),
+        dimension_semantics=("arbitrary",),
         vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
-def _row_map(b, p, *_):
-    return (b, 0, 0, 0)
+def _row_map(s, row, *_):
+    return (row[s], 0, 0, 0)
 
 
-def _srow_map(b, p, *_):
-    return (b, 0, 0)
+def _srow_map(s, row, *_):
+    return (row[s], 0, 0)
 
 
-def _page_map(b, p, bt, *_):
-    return (bt[b, p], 0, 0, 0)
+def _page_map(s, row, pg, last, bt, *_):
+    return (bt[row[s], pg[s]], 0, 0, 0)
 
 
-def _scale_map(b, p, bt, *_):
-    return (bt[b, p], 0, 0)
+def _scale_map(s, row, pg, last, bt, *_):
+    return (bt[row[s], pg[s]], 0, 0)
 
 
-def _write_page(b, p, bt, pos_, wlo_, whi_):
+def _write_page(s, row, pg, last, bt, pos_, wlo_, whi_):
     # pages outside the row's write range redirect to trash page 0:
     # Pallas only writes an output block back when its index CHANGES,
     # so the real page-pool writes stay O(1) per row per layer
+    b, p = row[s], pg[s]
     inr = jnp.logical_and(p >= wlo_[b], p <= whi_[b])
     return jnp.where(inr, bt[b, p], 0)
 
 
-def _write_map(b, p, *scalars):
-    return (_write_page(b, p, *scalars), 0, 0, 0)
+def _write_map(s, *scalars):
+    return (_write_page(s, *scalars), 0, 0, 0)
 
 
-def _swrite_map(b, p, *scalars):
-    return (_write_page(b, p, *scalars), 0, 0)
+def _swrite_map(s, *scalars):
+    return (_write_page(s, *scalars), 0, 0)
 
 
 def _block_specs(q, kv_pages, scales):
@@ -456,21 +510,20 @@ def _pa_read_call(q, kv_pages, block_tables, lengths, *scales,
     from jax.experimental import pallas as pl
 
     B, H, Wp, hd = q.shape
-    n_pages = block_tables.shape[1]
-    kernel = functools.partial(_pa_read_kernel, scale=scale,
-                               page=kv_pages.shape[2], n_pages=n_pages,
+    page = kv_pages.shape[2]
+    *sweep, total = _schedule(lengths, -1, page, block_tables.shape[1])
+    kernel = functools.partial(_pa_read_kernel, scale=scale, page=page,
                                quant=bool(scales))
     row, _, pages, scale_specs = _block_specs(q, kv_pages, scales)
     call = pl.pallas_call(
         kernel,
-        grid_spec=_grid_spec(2, B, n_pages,
-                             in_specs=[row, pages, *scale_specs],
+        grid_spec=_grid_spec(2, total, in_specs=[row, pages, *scale_specs],
                              out_specs=row, H=H, Wp=Wp, hd=hd),
         out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )
-    return call(block_tables, lengths, q, kv_pages, *scales)
+    return call(*sweep, block_tables, lengths, q, kv_pages, *scales)
 
 
 @functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
@@ -479,21 +532,28 @@ def _pa_window_read_call(q, kv_new, kv_pages, block_tables, pos, *scales,
     from jax.experimental import pallas as pl
 
     B, H, Wp, hd = q.shape
-    n_pages = block_tables.shape[1]
-    kernel = functools.partial(_pa_window_kernel, scale=scale,
-                               page=kv_pages.shape[2], W=W, n_pages=n_pages,
-                               quant=bool(scales))
+    page = kv_pages.shape[2]
+    # under the mesh mount this is a shard's own schedule, of its own rows
+    *sweep, total = _schedule(pos, -1, page, block_tables.shape[1])
+    kernel = functools.partial(_pa_window_kernel, scale=scale, page=page,
+                               W=W, quant=bool(scales))
     row, new, pages, scale_specs = _block_specs(q, kv_pages, scales)
     call = pl.pallas_call(
         kernel,
-        grid_spec=_grid_spec(2, B, n_pages,
+        grid_spec=_grid_spec(2, total,
                              in_specs=[row, new, pages, *scale_specs],
                              out_specs=row, H=H, Wp=Wp, hd=hd),
         out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )
-    return call(block_tables, pos, q, kv_new, kv_pages, *scales)
+    return call(*sweep, block_tables, pos, q, kv_new, kv_pages, *scales)
+
+
+def _fused_schedule(pos, wlo, whi, page, n_pages):
+    """The sweep of a fused call: a row with an empty write range (an
+    inactive one) has nothing to read either, and takes one step."""
+    return _schedule(jnp.where(wlo <= whi, pos, 0), whi, page, n_pages)
 
 
 @functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
@@ -503,25 +563,25 @@ def _pa_fused_call(q, kv_new, kv_pages, block_tables, pos, wlo, whi, *,
 
     B, H, Wp, hd = q.shape
     page = kv_pages.shape[2]
-    n_pages = block_tables.shape[1]
-    kernel = functools.partial(_pa_fused_kernel, scale=scale, page=page,
-                               W=W, n_pages=n_pages)
+    *sweep, total = _fused_schedule(pos, wlo, whi, page,
+                                    block_tables.shape[1])
+    kernel = functools.partial(_pa_fused_kernel, scale=scale, page=page, W=W)
     row, new, pages, _ = _block_specs(q, kv_pages, ())
     call = pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
-            4, B, n_pages, in_specs=[row, new, pages],
+            4, total, in_specs=[row, new, pages],
             out_specs=[row, pl.BlockSpec((1, H, page, 2 * hd), _write_map)],
             H=H, Wp=Wp, hd=hd),
         out_shape=[jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
                    jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype)],
-        # operand indices COUNT the 4 scalar-prefetch args: the pool is
-        # operand 6, aliased onto output 1 so it updates in place
-        input_output_aliases={6: 1},
+        # operand indices COUNT the 7 scalar-prefetch args: the pool is
+        # operand 9, aliased onto output 1 so it updates in place
+        input_output_aliases={9: 1},
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )
-    return call(block_tables, pos, wlo, whi, q, kv_new, kv_pages)
+    return call(*sweep, block_tables, pos, wlo, whi, q, kv_new, kv_pages)
 
 
 @functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
@@ -532,9 +592,10 @@ def _pa_fused_call_q(q, kv_new, kvq_new, ks_new, vs_new, kv_pages, k_scale,
 
     B, H, Wp, hd = q.shape
     page = kv_pages.shape[2]
-    n_pages = block_tables.shape[1]
+    *sweep, total = _fused_schedule(pos, wlo, whi, page,
+                                    block_tables.shape[1])
     kernel = functools.partial(_pa_fused_kernel_q, scale=scale, page=page,
-                               W=W, n_pages=n_pages)
+                               W=W)
     row, new, pages, scale_specs = _block_specs(q, kv_pages,
                                                 (k_scale, v_scale))
     srow = pl.BlockSpec((1, H, Wp), _srow_map)
@@ -543,7 +604,7 @@ def _pa_fused_call_q(q, kv_new, kvq_new, ks_new, vs_new, kv_pages, k_scale,
     call = pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
-            4, B, n_pages,
+            4, total,
             in_specs=[row, new, new, srow, srow, pages, *scale_specs],
             out_specs=[row, pl.BlockSpec((1, H, page, 2 * hd), _write_map),
                        swrite, swrite],
@@ -551,16 +612,16 @@ def _pa_fused_call_q(q, kv_new, kvq_new, ks_new, vs_new, kv_pages, k_scale,
         out_shape=[jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
                    jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype),
                    scale_shape, scale_shape],
-        # operand indices count the 4 scalar-prefetch args: the pool is
-        # operand 9, its scale pools 10/11 — all three alias their
+        # operand indices count the 7 scalar-prefetch args: the pool is
+        # operand 12, its scale pools 13/14 — all three alias their
         # outputs so pages AND scales update in place through the same
         # trash-redirected write maps
-        input_output_aliases={9: 1, 10: 2, 11: 3},
+        input_output_aliases={12: 1, 13: 2, 14: 3},
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )
-    return call(block_tables, pos, wlo, whi, q, kv_new, kvq_new, ks_new,
-                vs_new, kv_pages, k_scale, v_scale)
+    return call(*sweep, block_tables, pos, wlo, whi, q, kv_new, kvq_new,
+                ks_new, vs_new, kv_pages, k_scale, v_scale)
 
 
 # ---- block selection (grouped-query sparse decode) --------------------------

@@ -1868,6 +1868,7 @@ class ContinuousDecoder:
                 self._attn_impl,
                 gather_bytes=(self._gather_bytes_extend
                               if self._attn_impl == "gather" else 0))
+            self._note_sweep([start], ids.shape[1], 1, 1)
             self._insert_chunk_locked([(slot, req)], w_logits[:, Sn - 1], [],
                                self._draft_prompt_rows(req))
             return True
@@ -2000,6 +2001,7 @@ class ContinuousDecoder:
             gather_bytes=(self._gather_bytes_extend
                           if self._attn_impl == "gather" else 0))
         self._note_sparse_ticks(off + w)
+        self._note_sweep([off], ids.shape[1], 1, 1)
         self._kv.note_prefill_chunk(w)
         self._chunk_trace.append(w)
         _tracing.add_event("prefill_chunk", slot=slot, offset=off,
@@ -2025,6 +2027,16 @@ class ContinuousDecoder:
         # position P: generate_cached's exact schedule
         self._insert_chunk_locked([(slot, req)], last, [],
                            self._draft_prompt_rows(req))
+
+    def _note_sweep(self, positions, window: int, rows: int,
+                    calls: int) -> None:
+        """The grid steps of ``calls`` successive calls of the dense block's
+        paged kernel (pool ``grid_steps``), each a position on: its live
+        rows' positions as the scheduler holds them, no device read."""
+        if self._attn_impl == "kernel" and not self._hybrid:
+            for j in range(calls):
+                self._kv.note_grid_steps([pos + j for pos in positions],
+                                         window, rows)
 
     def _note_sparse_ticks(self, context: int, calls: int = 1) -> None:
         """A model with sparse-attention layers counts each paged call a
@@ -2174,6 +2186,16 @@ class ContinuousDecoder:
         self._note_sparse_ticks(
             max(self._slot_req[i].prompt.size + len(self._slot_req[i].tokens)
                 for i in decode_live), calls=self._k)
+        # a row's device position: its drained tokens plus those of the
+        # blocks still in flight (a first-token block carries one, a tick's
+        # k; this tick's is not yet pending)
+        self._note_sweep(
+            [req.prompt.size + len(req.tokens) - 1
+             + sum(min(toks.shape[0], self._k)
+                   for toks, block in self._pending
+                   if any(r is req for _, r in block.values()))
+             for req in (self._slot_req[i] for i in decode_live)],
+            self._gamma + 1 if self._spec else 1, self._S, self._k)
         # snapshot slot→REQUEST (not indices): by the time this block is
         # drained, a slot may have been freed and re-admitted; tokens must
         # go to the request that occupied the slot at DISPATCH time (its

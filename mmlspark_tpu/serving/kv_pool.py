@@ -70,7 +70,12 @@ M_PAGE_SIZE = _metric_gauge(
 M_PAGES_PER_SLOT = _metric_gauge(
     "mmlspark_kvpool_pages_per_slot",
     "Width of a slot's block table: pages a slot spans at full length, the "
-    "decode kernel's grid steps a row")
+    "most grid steps the decode kernel takes for a row")
+M_GRID_STEPS_SHARE = _metric_gauge(
+    "mmlspark_kvpool_grid_steps_share",
+    "Grid steps the paged decode kernel's calls have swept (the pages their "
+    "rows needed, by the scheduler's positions) over slots x pages a slot, "
+    "since the pool was built")
 M_PREFIX_SHARE_HITS = _metric_counter(
     "mmlspark_kvpool_prefix_share_hits_total",
     "Physical pages shared into an admitted request from a cached prefix "
@@ -242,7 +247,8 @@ class PagedKVPool:
                       "prefix_share_hits": 0, "defrag_moves": 0,
                       "prefill_chunks": 0, "alloc_failures": 0,
                       "gather_bytes": 0, "attn_ticks_kernel": 0,
-                      "attn_ticks_gather": 0, "quant_error_probes": 0,
+                      "attn_ticks_gather": 0, "grid_steps": 0,
+                      "grid_steps_dense": 0, "quant_error_probes": 0,
                       "quant_error_last": None, "quant_error_sum": 0.0,
                       "quant_error_max": 0.0}
         M_PAGES_TOTAL.set(self.num_pages - 1)
@@ -648,6 +654,21 @@ class PagedKVPool:
         if gather_bytes:
             self.stats["gather_bytes"] += gather_bytes
             M_GATHER_BYTES.inc(gather_bytes)
+
+    def note_grid_steps(self, positions: Sequence[int], window: int,
+                        rows: int) -> None:
+        """Account one call of the paged kernel's ragged sweep, from the
+        scheduler's numbers: a live row at position ``pos`` sweeps the
+        pages up to the one its ``window`` ends in, each of the call's other
+        ``rows`` one step; ``grid_steps_dense`` is what ``rows x pages a
+        slot`` would have been."""
+        per = self.stats["pages_per_slot"]
+        self.stats["grid_steps"] += rows - len(positions) + sum(
+            min(per, (pos + window - 1) // self.page_size + 1)
+            for pos in positions)
+        self.stats["grid_steps_dense"] += rows * per
+        M_GRID_STEPS_SHARE.set(self.stats["grid_steps"]
+                               / self.stats["grid_steps_dense"])
 
     # -- kernel page-layout contract -----------------------------------------
 

@@ -125,6 +125,29 @@ def test_paged_fused_window_kernel_compiles(one_chip, kv, rows, window, page):
     _compiled_text(fused, row, row, row, pages, bt, pos, active, *scales)
 
 
+@pytest.mark.parametrize("rows,window", [(8, 1), (1, 256)],
+                         ids=["tick", "prefill_chunk"])
+def test_gpt2xl_fused_window_kernel_compiles(one_chip, rows, window):
+    """The generation cell's own shape (benchmarks/workloads/
+    gpt2xl_generate_closed.json): 8 slots, 25 heads of 64, pages of 64, 16 a
+    slot; the ragged sweep's bound is traced, so one program serves every
+    batch of contexts."""
+    heads, page, per_row = 25, 64, 16
+    row = one_chip((rows, heads, window, HD), jnp.bfloat16)
+
+    def fused(q, kn, vn, kvp, bt, pos, active):
+        return paged_attention_window(q, kn, vn, kvp, bt, pos, active=active,
+                                      interpret=False)
+
+    text = _compiled_text(
+        fused, row, row, row,
+        one_chip((1 + 8 * per_row + per_row, heads, page, 2 * HD),
+                 jnp.bfloat16),
+        one_chip((rows, per_row), jnp.int32), one_chip((rows,), jnp.int32),
+        one_chip((rows,), jnp.bool_))
+    assert "_pa_fused_call" in text         # the name the benchmark's trace finds
+
+
 @by_page
 def test_mesh_mounted_read_kernel_compiles(topo, one_chip, page):
     """The dp2 x tp2 mount of chip_smoke.py --chips 4: slots over dp, heads
@@ -185,6 +208,12 @@ def test_programs_update_the_page_pool_in_place(one_chip):
     assert "tpu_custom_call" in texts["jit_tick"]
     assert "slice-start" not in texts["jit_tick"]   # one slice a prefetch
     assert "slice-start" in texts["jit__extend"]
+    # the kernel's sweep is scheduled once a tick: both layers' calls take
+    # the same two step vectors (operands 1 and 2, after the traced bound)
+    calls = [ln.split("custom-call(")[1].split(", ")[:3]
+             for ln in texts["jit_tick"].splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 2 and calls[0] == calls[1], calls
     copies = {name: smoke.pool_copies(text, shapes)
               for name, text in texts.items()}
     assert not any(copies.values()), copies

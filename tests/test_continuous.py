@@ -860,6 +860,84 @@ class TestPipelinedDispatch:
         assert n4 <= n0 + 4 + 1, (n4, n0)
 
 
+class TestGridStepCounters:
+    """Pool ``grid_steps`` / ``grid_steps_dense``: the paged kernel's
+    ragged sweep counted from the scheduler's positions, no device read."""
+
+    @pytest.mark.parametrize("depth,k", [(0, 1), (2, 1), (2, 3)])
+    def test_counted_positions_are_the_devices(self, params, depth, k):
+        eng = ContinuousDecoder(params, CFG, max_slots=3, max_len=48,
+                                page_size=8, steps_per_dispatch=k,
+                                pipeline_depth=depth)
+        seen, noted = [], []
+        dispatch, note = eng._dispatch_tick, eng._kv.note_grid_steps
+
+        def dispatching(live):
+            on = np.asarray(eng._active)
+            seen.append([(j, int(np.asarray(eng._pos)[i]))
+                         for j, i in enumerate(live) if on[i]])
+            return dispatch(live)
+
+        def noting(positions, window, rows):
+            noted.append((list(positions), window, rows))
+            return note(positions, window, rows)
+
+        eng._dispatch_tick, eng._kv.note_grid_steps = dispatching, noting
+        rng = np.random.default_rng(31)
+        reqs = []
+        # the second pair arrives while blocks are in flight, as a closed
+        # loop's requests do: their first tokens queue behind those blocks
+        for pair in (((3, 14), (11, 9)), ((6, 12), (4, 5))):
+            reqs += [eng.submit(rng.integers(0, CFG.vocab, n),
+                                max_new_tokens=m) for n, m in pair]
+            eng.step()
+            eng.step()
+        while not all(r.done for r in reqs):
+            eng.step()
+        assert len(noted) == k * len(seen) > 0
+        for t, device in enumerate(seen):
+            positions, window, rows = noted[k * t]
+            assert (window, rows) == (1, 3)
+            # a row the device has retired ahead of the host is still
+            # counted at the position the host holds for it
+            assert all(positions[j] == pos for j, pos in device)
+            assert noted[k * t + k - 1][0] == [p + k - 1 for p in positions]
+        stats = eng._kv.stats
+        assert 0 < stats["grid_steps"] < stats["grid_steps_dense"]
+        assert stats["grid_steps_dense"] == len(noted) * 3 * 6
+
+    def test_equal_when_every_row_is_full(self, params):
+        """One slot of two pages, a prompt past the first page: every tick
+        sweeps both, and the share's gauge reads 1."""
+        from mmlspark_tpu.serving.kv_pool import M_GRID_STEPS_SHARE
+        eng = ContinuousDecoder(params, CFG, max_slots=1, max_len=16,
+                                page_size=8)
+        req = eng.submit(np.arange(1, 10), max_new_tokens=6)
+        while not req.done:
+            eng.step()
+        stats = eng._kv.stats
+        assert stats["grid_steps"] == stats["grid_steps_dense"] > 0
+        assert M_GRID_STEPS_SHARE.labels().get() == 1.0
+
+    def test_chunk_windows_and_the_gather_path(self, params):
+        """A chunked prefill's windows are counted too (one row, the pages
+        its window ends in); the gather path runs no grid."""
+        eng = ContinuousDecoder(params, CFG, max_slots=2, max_len=48,
+                                page_size=8, prefill_chunk=8)
+        req = eng.submit(np.arange(1, 30), max_new_tokens=2)
+        while not req.done:
+            eng.step()
+        stats = eng._kv.stats
+        assert stats["prefill_chunks"] >= 3
+        assert 0 < stats["grid_steps"] < stats["grid_steps_dense"]
+        eng = ContinuousDecoder(params, CFG, max_slots=2, max_len=48,
+                                page_size=8, paged_attn="gather")
+        req = eng.submit(np.arange(1, 9), max_new_tokens=4)
+        while not req.done:
+            eng.step()
+        assert eng._kv.stats["grid_steps_dense"] == 0
+
+
 class TestPrefillAhead:
     """``prefill_ahead=N``: waiting prompts prefill while every slot is
     occupied and park on device; a retiring wave re-fills with one insert

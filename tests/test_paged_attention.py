@@ -35,10 +35,11 @@ from mmlspark_tpu.models.zoo.transformer import (
     decode_window_paged, generate_cached, init_kv_cache,
     init_paged_cache, init_transformer, paged_gather, paged_scatter_rows)
 from mmlspark_tpu.ops.compile_cache import jit_cache_size
+from mmlspark_tpu.ops.kv_quant import SCALE_DTYPE, quantize_kv
 from mmlspark_tpu.ops.paged_attention import (
-    ENV_KNOB, aligned_page_size, pack_kv, paged_attention,
-    paged_attention_window, split_kv,
-    resolve_impl, sublane_multiple)
+    ENV_KNOB, _fused_schedule, _pool_write_rows, _schedule,
+    aligned_page_size, pack_kv, paged_attention, paged_attention_window,
+    split_kv, resolve_impl, sublane_multiple)
 from mmlspark_tpu.serving.continuous import ContinuousDecoder
 
 CFG = TransformerConfig(vocab=128, layers=2, d_model=64, heads=4, d_ff=128,
@@ -225,6 +226,235 @@ class TestOpsKernel:
         # the trash page may differ
         assert np.array_equal(after_k[1 + P:], before_k[1 + P:])
         assert not np.array_equal(after_k[1:1 + P], before_k[1:1 + P])
+
+
+def _steps_of(n):
+    """The sweep a Python loop builds from each row's page count."""
+    return ([b for b, nb in enumerate(n) for _ in range(nb)],
+            [p for nb in n for p in range(nb)])
+
+
+# (page, pages a slot, bound, whi or None): hand-written, the count of pages
+# each row needs beside them
+SCHEDULES = {
+    "one_step_for_an_empty_row": (4, 4, [0, 5, 0], None, [1, 2, 1]),
+    "a_key_page_ends_on_its_boundary": (4, 4, [4, 8, 16], None, [1, 2, 4]),
+    "the_write_page_lies_after_the_keys": (4, 4, [4, 8, 3], [1, 2, 0],
+                                           [2, 3, 1]),
+    "a_chunk_writes_five_pages": (64, 16, [32], [4], [5]),
+    "clipped_to_the_slot": (4, 3, [40, 2], [9, 0], [3, 1]),
+    "every_row_full": (8, 2, [16, 9, 15], [1, 1, 1], [2, 2, 2]),
+}
+
+
+@pytest.mark.parametrize("name", list(SCHEDULES))
+def test_schedule_is_the_rows_pages_in_order(name):
+    page, n_pages, bound, whi, want_n = SCHEDULES[name]
+    B = len(bound)
+    row_of, page_of, last_of, total = _schedule(
+        jnp.asarray(bound, jnp.int32),
+        -1 if whi is None else jnp.asarray(whi, jnp.int32), page, n_pages)
+    assert row_of.shape == page_of.shape == (B * n_pages,)
+    assert row_of.dtype == page_of.dtype == last_of.dtype == jnp.int32
+    rows, pages = _steps_of(want_n)
+    assert int(total) == len(rows) <= B * n_pages
+    assert list(np.asarray(row_of)[:len(rows)]) == rows
+    assert list(np.asarray(page_of)[:len(rows)]) == pages
+    assert list(np.asarray(last_of)) == [nb - 1 for nb in want_n]
+    # what lies past the sweep is never visited, and still a valid index
+    assert np.all(np.asarray(row_of) < B)
+    assert np.all(np.asarray(page_of) < n_pages)
+
+
+def test_fused_schedule_gives_an_inactive_row_one_step():
+    page, n_pages = 4, 4
+    pos = jnp.asarray([9, 13, 6, 0], jnp.int32)
+    active = jnp.asarray([True, False, True, False])
+    wlo = jnp.where(active, pos // page, 1)
+    whi = jnp.where(active, pos // page, 0)
+    row_of, page_of, last_of, total = _fused_schedule(pos, wlo, whi, page,
+                                                      n_pages)
+    rows, pages = _steps_of([3, 1, 2, 1])
+    assert int(total) == 7
+    assert list(np.asarray(row_of)[:7]) == rows
+    assert list(np.asarray(page_of)[:7]) == pages
+    assert list(np.asarray(last_of)) == [2, 0, 1, 0]
+
+
+# the edges of the ragged sweep: (page, pages a slot, W, pos, active)
+SWEEPS = {
+    # a chunk's first window: one step, p == 0 is window fold and write page
+    "first_window_w1": (4, 4, 1, [0, 0, 5], None),
+    "first_window_w8": (4, 6, 8, [0, 3, 0], None),
+    "first_window_w256": (64, 8, 256, [0, 0], None),
+    # pos % page == 0: the write page lies after the last page with keys
+    "page_start_w1": (4, 6, 1, [4, 8, 12, 20], None),
+    "page_start_w8": (4, 6, 8, [4, 8, 16], None),
+    "chunk_spans_five_pages_w256": (64, 8, 256, [32, 200], None),
+    "ends_at_max_len_w1": (4, 4, 1, [15, 3], None),
+    "ends_at_max_len_w8": (4, 4, 8, [8, 2], None),
+    "ends_at_max_len_w256": (64, 8, 256, [256, 0], None),
+    # the output and trash-page blocks across a row boundary
+    "inactive_between_active_w1": (4, 4, 1, [9, 13, 6, 2, 15],
+                                   [True, False, True, False, True]),
+    "inactive_first_and_last_w8": (4, 6, 8, [5, 9, 16, 0, 3],
+                                   [False, True, False, True, False]),
+    # total == B * n_pages
+    "every_row_full_w1": (4, 4, 1, [15, 15, 15], None),
+    "every_row_full_w8": (4, 4, 8, [8, 8], None),
+}
+KV = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
+
+
+class TestRaggedSweep:
+    """The grid visits the pages a row needs and no other: parity with a
+    gather oracle on every edge of the schedule, the written pages equal to
+    ``_pool_write_rows`` bit for bit, pages no row needs never read."""
+
+    H, HD = 2, 8
+
+    def _case(self, name, kv, poison=False, B=None):
+        page, P, W, pos, active = SWEEPS[name]
+        if B is not None:               # the mesh mount wants 8 rows
+            pos = (pos * B)[:B]
+            active = None if active is None else (active * B)[:B]
+        B, H, hd = len(pos), self.H, self.HD
+        rng = np.random.default_rng(sum(map(ord, name)))
+        dt = jnp.float32 if kv == "f32" else jnp.bfloat16
+        # rows' pages interleaved in the pool, page 0 the trash page
+        bt = 1 + np.arange(P)[None, :] * B + np.arange(B)[:, None]
+        vals = rng.normal(0, 1, (2, 1 + B * P, H, page, hd))
+        k_f, v_f = (jnp.asarray(t, jnp.float32) for t in vals)
+        if kv == "int8":
+            (kq, ks), (vq, vs) = (quantize_kv(t, jnp.int8)
+                                  for t in (k_f, v_f))
+            pool, scales = pack_kv(kq, vq), (ks, vs)
+            deq = [np.asarray(q_.astype(jnp.float32)
+                              * s_.astype(jnp.float32)[..., None])
+                   for q_, s_ in ((kq, ks), (vq, vs))]
+        else:
+            pool, scales = pack_kv(k_f, v_f).astype(KV[kv]), ()
+            deq = [np.asarray(t.astype(jnp.float32))
+                   for t in split_kv(pool)]
+        act = np.ones(B, bool) if active is None else np.asarray(active)
+        # the pages a live row needs: keys below pos, writes below pos + W
+        needed = np.zeros(1 + B * P, bool)
+        needed[0] = True
+        for b in np.flatnonzero(act):
+            needed[bt[b, :min(P, (pos[b] + W - 1) // page + 1)]] = True
+        if poison:
+            bad = jnp.asarray(~needed)[:, None, None]
+            if kv == "int8":
+                scales = tuple(jnp.where(bad, jnp.nan, s_) for s_ in scales)
+            else:
+                pool = jnp.where(bad[..., None], jnp.nan, pool)
+        q, kn, vn = (jnp.asarray(rng.normal(0, 1, (B, H, W, hd)), dt)
+                     for _ in range(3))
+        return dict(page=page, P=P, W=W, pos=np.asarray(pos), act=act,
+                    active=None if active is None else jnp.asarray(active),
+                    bt=jnp.asarray(bt, jnp.int32), pool=pool, scales=scales,
+                    deq=deq, q=q, kn=kn, vn=vn, needed=needed)
+
+    def _oracle(self, c):
+        """float32 softmax over the gathered keys below ``pos`` (as the
+        pool stores them) and the window's own rows, unquantized."""
+        q, kn, vn = (np.asarray(t.astype(jnp.float32))
+                     for t in (c["q"], c["kn"], c["vn"]))
+        B, H, W, hd = q.shape
+        bt = np.asarray(c["bt"])
+        out = np.zeros_like(q)
+        for b in np.flatnonzero(c["act"]):
+            n = int(c["pos"][b])
+            kc, vc = (t[bt[b]].transpose(1, 0, 2, 3).reshape(H, -1, hd)[:, :n]
+                      for t in c["deq"])
+            for j in range(W):
+                k = np.concatenate([kc, kn[b, :, :j + 1]], axis=1)
+                v = np.concatenate([vc, vn[b, :, :j + 1]], axis=1)
+                s = np.einsum("hd,hkd->hk", q[b, :, j], k) / np.sqrt(hd)
+                p = np.exp(s - s.max(-1, keepdims=True))
+                out[b, :, j] = np.einsum(
+                    "hk,hkd->hd", p / p.sum(-1, keepdims=True), v)
+        return out
+
+    def _run(self, c, **mounted):
+        kw = dict(zip(("k_scale", "v_scale"), c["scales"]))
+        return paged_attention_window(
+            c["q"], c["kn"], c["vn"], c["pool"], c["bt"],
+            jnp.asarray(c["pos"], jnp.int32), active=c["active"],
+            interpret=True, **kw, **mounted)
+
+    def _check(self, c, kv, ctx, pools):
+        tol = 2e-5 if kv == "f32" else 2e-2    # queries are bf16 but there
+        got = np.asarray(ctx.astype(jnp.float32))[c["act"]]
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got, self._oracle(c)[c["act"]],
+                                   rtol=tol, atol=tol)
+        want = _pool_write_rows(c["pool"], c["kn"], c["vn"], c["bt"],
+                                jnp.asarray(c["pos"], jnp.int32),
+                                c["active"], *c["scales"])
+        for g, w in zip(pools, want):
+            # bit for bit off the trash page, NaN where NaN was left
+            assert np.array_equal(np.asarray(g)[1:], np.asarray(w)[1:],
+                                  equal_nan=True)
+
+    @pytest.mark.parametrize("kv", list(KV))
+    @pytest.mark.parametrize("name", list(SWEEPS))
+    def test_sweep_matches_gather_oracle_and_writeback_bytes(self, name, kv):
+        c = self._case(name, kv)
+        ctx, *pools = self._run(c)
+        self._check(c, kv, ctx, pools)
+
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    @pytest.mark.parametrize("name", list(SWEEPS))
+    def test_pages_no_row_needs_are_never_read(self, name, kv):
+        """NaN in every page (every scale of a quantized page) outside the
+        live rows' needed range: one read of it would reach the output
+        through ``p @ v`` even under a zero weight."""
+        c = self._case(name, kv, poison=True)
+        assert not c["needed"].all() or name.startswith("every_row_full")
+        ctx, *pools = self._run(c)
+        self._check(c, kv, ctx, pools)
+
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    @pytest.mark.parametrize("name", ["page_start_w1", "first_window_w8",
+                                      "every_row_full_w1"])
+    def test_each_shard_of_the_mesh_mount_builds_its_own_schedule(
+            self, name, kv):
+        """``dp4 x tp2``: two rows a shard, their own pages, one head."""
+        c = self._case(name, kv, poison=True, B=8)
+        ctx, *pools = self._run(c, **mount("mesh"))
+        self._check(c, kv, ctx, pools)
+
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    def test_read_kernel_sweeps_each_rows_own_pages(self, kv):
+        """The read-only kernel: lengths 0 (one step, zeros), mid-page, a
+        page's end, the whole slot; the pages past a row's length NaN."""
+        lengths = [0, 3, 8, 16, 5]
+        c = self._case("first_window_w1", kv, B=len(lengths))
+        bt, page = np.asarray(c["bt"]), c["page"]
+        bad = np.ones(c["pool"].shape[0], bool)
+        for b, n in enumerate(lengths):
+            bad[bt[b, :-(-n // page)]] = False
+        bad = jnp.asarray(bad)[:, None, None]
+        if kv == "int8":
+            c["scales"] = tuple(jnp.where(bad, jnp.nan, s_)
+                                for s_ in c["scales"])
+        else:
+            c["pool"] = jnp.where(bad[..., None], jnp.nan, c["pool"])
+        got = paged_attention(
+            c["q"], c["pool"], c["bt"], jnp.asarray(lengths, jnp.int32),
+            interpret=True, **dict(zip(("k_scale", "v_scale"), c["scales"])))
+        got = np.asarray(got.astype(jnp.float32))
+        q = np.asarray(c["q"].astype(jnp.float32))
+        assert np.all(got[0] == 0.0)
+        for b, n in enumerate(lengths[1:], 1):
+            kc, vc = (t[bt[b]].transpose(1, 0, 2, 3).reshape(
+                self.H, -1, self.HD)[:, :n] for t in c["deq"])
+            s = np.einsum("hd,hkd->hk", q[b, :, 0], kc) / np.sqrt(self.HD)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want = np.einsum("hk,hkd->hd", p / p.sum(-1, keepdims=True), vc)
+            np.testing.assert_allclose(got[b, :, 0], want, rtol=2e-2,
+                                       atol=2e-2)
 
 
 class TestDecodeParity:
