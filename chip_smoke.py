@@ -13,6 +13,9 @@ reference computed in the same process:
                  block-sparse, one lightning) on ``ContinuousDecoder``: a
                  document longer than ``dense_len`` registered as a prefix,
                  then a hit decodes a few tokens past it
+  G. routed    — three layers of Ling-3.0-flash's share at their published
+                 widths (kda under the dense feed-forward, kda and mla under
+                 128 of 512 routed experts) on ``ContinuousDecoder``
 
 ``--chips 4`` runs instead ONLY the two paths that exist across chips and what
 each is compared with: D. data-parallel GBDT over a 4-device ``data`` mesh,
@@ -48,6 +51,11 @@ sys.path.insert(0, REPO)
 
 AUC_TOL = 0.002          # Pallas vs segment_sum, 4 devices vs 1
 TIE_TOL = 0.1            # a near-tie of two logits, in std-devs of the row
+#: phase G's limit on the MEAN gap: against the float32 reference a bf16
+#: forward swaps a near-tied 8th expert for some tokens, which moves single
+#: tokens by up to two std-devs, so the widest gap says nothing there. Three
+#: layers read 0.014-0.016 on the chip and the fp8 control 0.19 (PERF.md, PR 35)
+ROUTED_GAP_MEAN = 0.05
 QUANT_ERR_BOUND = 0.05   # tests/test_kv_quant.py's bound on the int8 probe
 LOGIT_TOL = 0.06         # bf16 ResNet-50 logits vs float32, relative to max|ref|
 
@@ -202,6 +210,18 @@ def sizes(small):
                                            window_size=16, init_blocks=1,
                                            dense_len=64)),
             hybrid_len=160,
+            # phase G at toy widths: the tests' tiny routed decoder
+            routed=dict(hidden_size=64, intermediate_size=128,
+                        num_attention_heads=4, head_dim=16,
+                        qk_nope_head_dim=16, qk_rope_head_dim=8,
+                        v_head_dim=16, kv_lora_rank=32,
+                        moe_intermediate_size=32,
+                        moe_shared_expert_intermediate_size=32, n_group=4,
+                        topk_group=2, num_experts_per_tok=4, num_experts=8,
+                        experts_held=[0, 8], published={"num_experts": 32},
+                        vocab_size=256, compute_dtype="float32",
+                        param_dtype="float32"),
+            routed_len=128, routed_prompts=[9, 40, 70],
             pool_decoder=TransformerConfig(vocab=256, layers=2, d_model=64,
                                            heads=4, d_ff=128, max_len=64,
                                            causal=True, dtype=jnp.bfloat16),
@@ -220,6 +240,8 @@ def sizes(small):
         slots=16, max_len=2048, max_new=32, engine_kw={},
         # phase F: 8,384-token document (dense_len 8,192), pages of 64
         hybrid_len=8704,
+        # phase G: prompts across a chunk's end, 4 slots of 1,024 positions
+        routed_len=1024, routed_prompts=[40, 300, 520],
         # the generation cell's decoder and engine (benchmarks/configs/
         # gpt2_xl.json, workloads/gpt2xl_generate_closed.json): GPT-2 XL,
         # 8 slots of 1024 positions in the pages the decoder derives from
@@ -297,6 +319,67 @@ def phase_hybrid(sz, seed, small):
                     gap_mean=float(gaps.mean()),
                     attn_ticks_sparse=stats["attn_ticks_sparse"],
                     snapshot_bytes=dec._kv.snapshot_bytes)
+
+
+# ---------------------------------------------------------------------------
+# G. routed (the cell lingflash_reason_closed32's model, three layers of it)
+
+
+def phase_routed(sz, seed, small):
+    """Layers 0, 10 and 11 of the routed configuration at its published
+    widths (a kda layer under the dense feed-forward, a kda and an mla layer
+    under 128 of 512 routed experts): three prompts prefill in chunks and
+    decode together, every tick on the delta-rule step, the absorbed latent
+    kernel and the grouped product, no pair dropped; the tokens are judged
+    by the benchmark's plain float32 reference, teacher-forced, in the mean
+    (:data:`ROUTED_GAP_MEAN`)."""
+    from benchmarks import run as bench_run
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    ck = Checks()
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "ling3_flash_ep4_l7.json")) as fh:
+        config = json.load(fh)
+    config.update(num_hidden_layers=3, layers_held=[0, 10, 11])
+    if small:
+        config.update(sz["routed"])
+    reference = bench_run.load_by_path("references", config["reference"])
+    cfg = bench_run.load_by_path("drivers", "generate_ling").program_config(
+        config, sz["routed_len"])
+    t0 = time.perf_counter()
+    params = reference.make_weights(config, seed)
+    dec = ContinuousDecoder(params, cfg, max_slots=4,
+                            max_len=sz["routed_len"], **sz["engine_kw"])
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(1, cfg.vocab, n).astype(np.int32)
+               for n in sz["routed_prompts"]]
+    reqs = [dec.submit(p, sz["max_new"]) for p in prompts]
+    drain(dec, reqs)
+    served = [(p, dec.result(r)) for p, r in zip(prompts, reqs)]
+    run_s = time.perf_counter() - t0
+    stats = dec._kv.stats
+    ck.require(stats.get("attn_ticks_kda", 0) > 0
+               and stats.get("attn_ticks_latent", 0) > 0
+               and not stats["attn_ticks_gather"],
+               f"a tick left the kda step or the latent kernel: {stats}")
+    ck.require(stats.get("moe_pairs_held", 0) > 0
+               and stats["moe_pairs_dropped"] == 0
+               and stats["moe_pairs_misplaced"] == 0,
+               f"routed pairs dropped or misplaced: {stats}")
+    t0 = time.perf_counter()
+    gaps = np.concatenate([reference.served_token_gaps(
+        params, config, p, o, sz["routed_len"]) for p, o in served])
+    ck.require(float(gaps.mean()) <= ROUTED_GAP_MEAN,
+               f"the served tokens lie {gaps.mean():.4f} std-devs under the "
+               f"float32 reference's best in the mean (limit "
+               f"{ROUTED_GAP_MEAN})")
+    return ck, dict(routed_run_s=run_s,
+                    routed_reference_s=time.perf_counter() - t0,
+                    gap_max=float(gaps.max()), gap_mean=float(gaps.mean()),
+                    page=dec._page,
+                    moe={k[4:]: int(v) for k, v in stats.items()
+                         if k.startswith("moe_")},
+                    attn_ticks_kda=stats.get("attn_ticks_kda", 0),
+                    attn_ticks_latent=stats.get("attn_ticks_latent", 0))
 
 
 # ---------------------------------------------------------------------------
@@ -785,7 +868,7 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the two cross-chip paths (D, E)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--phase", action="append", choices=list("ABCDEF"),
+    ap.add_argument("--phase", action="append", choices=list("ABCDEFG"),
                     help="run only this phase (repeatable; for fault-finding)")
     args = ap.parse_args(argv)
 
@@ -846,6 +929,8 @@ def main(argv=None):
                   "C": ("C.train", lambda: phase_train(
                       sz, args.seed, args.small)),
                   "F": ("F.hybrid", lambda: phase_hybrid(
+                      sz, args.seed, args.small)),
+                  "G": ("G.routed", lambda: phase_routed(
                       sz, args.seed, args.small))}
     for key, (name, run) in phases.items():
         if args.phase and key not in args.phase:

@@ -28,6 +28,32 @@ when ``cfg.mixers`` is set:
 
 Both mixers end in a sigmoid output gate; the block is bias-free with
 RMSNorm and SwiGLU, and carries muP's three scalings.
+
+Two more mixers and a second feed-forward (a decoder with routed experts,
+a delta-rule linear attention and latent attention; ``cfg.kda``,
+``cfg.latent``, ``cfg.ffn`` / ``cfg.routed``):
+
+* ``kda`` - per head, float32, on a ``(d x d)`` state: ``S_t = (I - beta_t
+  k_t k_t^T) diag(alpha_t) S_(t-1) + beta_t k_t v_t^T``, ``o_t = S_t^T q_t``;
+  q, k, v pass a causal depthwise convolution of ``conv_kernel`` taps and a
+  SiLU, q and k are l2-normalised (q also divided by ``sqrt(d)``),
+  ``alpha_t = exp(g_t)``, ``g_t = gate_floor * sigmoid(exp(A_h) (W_f x_t +
+  b))`` a channel, ``beta_t = sigmoid(W_b x_t)`` a head. No positions. The
+  cache entry is the state plus ``{"conv"}``: the last ``conv_kernel - 1``
+  pre-convolution rows of q, k and v, a row a slot. A window runs the
+  chunked form (:func:`kda_chunk`), the decode tick
+  ``ops.kda_attention.kda_decode_step``. Output: per-head RMSNorm, a
+  sigmoid gate a HEAD, ``W_o``.
+* ``mla`` - softmax attention whose cached row a token is the normed
+  ``latent``-wide key/value latent beside one rotated ``rope``-wide key all
+  heads share: pages ``(pages, 1, page, latent + rope)``. A window rebuilds
+  K and V from the latents it gathers (expanded); the decode tick folds
+  ``W_kvb``'s key half into the query and applies its value half after the
+  weighted sum of latents (absorbed), through
+  ``ops.paged_attention.paged_attention_latent``.
+* feed-forward ``"moe"`` - ``parallel.moe.moe_topk_held``: top-k dropless
+  routing over all experts, the held experts' part of the result, a shared
+  expert.
 """
 
 from __future__ import annotations
@@ -43,16 +69,19 @@ from .transformer import (TransformerConfig, _embed, _rms, _rope_tables,
 
 __all__ = ["check_config", "dims", "init_hybrid", "init_hybrid_cache",
            "init_hybrid_pool", "lightning_rates", "lightning_chunk",
-           "sparse_select", "head", "window_contiguous", "window_paged",
-           "SLOT_KEYS"]
+           "sparse_select", "kda_chunk", "head", "window_contiguous",
+           "window_paged", "SLOT_KEYS"]
 
 HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
 _NEG = -1e30
 #: keys of a pool layer dict whose axis 0 is the SLOT, not the physical page:
-#: a lightning layer's state and a sparse layer's compressed keys. A cached
-#: prefix keeps a snapshot of these rows beside its pages.
-SLOT_KEYS = ("state", "ck")
+#: a lightning or kda layer's state, a sparse layer's compressed keys, a kda
+#: layer's convolution tails. A cached prefix keeps a snapshot of these rows
+#: beside its pages.
+SLOT_KEYS = ("state", "ck", "conv")
+#: tokens a step of the chunked delta rule (:func:`kda_chunk`)
+KDA_CHUNK = 64
 #: keys of K/V a masked window folds at a time (a 32k context in one piece
 #: would hold a gigabyte of scores)
 _KEY_TILE = 2048
@@ -67,13 +96,42 @@ def dims(cfg: TransformerConfig):
 def check_config(cfg: TransformerConfig) -> None:
     if len(cfg.mixers) != cfg.layers:
         raise ValueError(f"{len(cfg.mixers)} mixers for {cfg.layers} layers")
-    unknown = set(cfg.mixers) - {"lightning", "sparse"}
+    unknown = set(cfg.mixers) - {"lightning", "sparse", "kda", "mla"}
     if unknown:
         raise ValueError(f"unknown mixer kinds {sorted(unknown)} "
-                         "(lightning | sparse)")
+                         "(lightning | sparse | kda | mla)")
     if not cfg.causal or cfg.moe_experts or cfg.use_flash:
-        raise ValueError("a hybrid decoder is causal, dense in its "
-                         "feed-forward and does not take use_flash")
+        raise ValueError("a hybrid decoder is causal, takes its routed "
+                         "feed-forward from cfg.ffn / cfg.routed (not "
+                         "moe_experts) and does not take use_flash")
+    if cfg.ffn:
+        if len(cfg.ffn) != cfg.layers or set(cfg.ffn) - {"dense", "moe"}:
+            raise ValueError(f"ffn {cfg.ffn}: one of dense | moe a layer")
+        if "moe" in cfg.ffn:
+            r = cfg.routed
+            if r is None or not r.d_expert:
+                raise ValueError("moe layers need cfg.routed")
+            if (r.experts % r.groups or r.groups_kept > r.groups
+                    or r.per_token > r.groups_kept * (r.experts // r.groups)):
+                raise ValueError(f"routing {r}: groups must divide the "
+                                 "experts and the kept groups hold per_token")
+            if r.first < 0 or r.first + r.held > r.experts:
+                raise ValueError(f"experts held [{r.first}, "
+                                 f"{r.first + r.held}) of {r.experts}")
+            if r.held < 8:
+                raise ValueError(f"{r.held} experts held: a share of a "
+                                 "routed layer is at least 8")
+            if any(r.swiglu_limits):
+                raise ValueError(
+                    f"swiglu limits {r.swiglu_limits}: a held layer names a "
+                    "clamp, whose form is not built (only limit 0)")
+    if "kda" in cfg.mixers and cfg.kda is None:
+        raise ValueError("kda layers need cfg.kda")
+    if "mla" in cfg.mixers:
+        if cfg.latent is None:
+            raise ValueError("mla layers need cfg.latent")
+        if cfg.latent.rope % 2:
+            raise ValueError(f"rope width {cfg.latent.rope}")
     H, Hkv, hd = dims(cfg)
     if H % Hkv or hd % 2:
         raise ValueError(f"heads {H} / kv_heads {Hkv} / head_dim {hd}")
@@ -91,6 +149,10 @@ def check_config(cfg: TransformerConfig) -> None:
                              f"blocks, more than topk {sp.topk}")
 
 
+def _ffn_kind(cfg: TransformerConfig, i: int) -> str:
+    return cfg.ffn[i] if cfg.ffn else "dense"
+
+
 def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
     """Random parameters in the pytree the hybrid block reads."""
     check_config(cfg)
@@ -105,18 +167,61 @@ def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
     def ones(n):
         return {"scale": np.ones(n, np.float32)}
 
+    def kda_layer():
+        K = cfg.kda.conv_kernel
+        return {"q": dense(D, H * hd), "k": dense(D, H * hd),
+                "v": dense(D, H * hd), "f": dense(D, H * hd),
+                "b": dense(D, H), "z": dense(D, H), "o": dense(H * hd, D),
+                "dt_bias": rng.normal(0, 0.5, H * hd).astype(np.float32),
+                "a_log": rng.normal(0, 0.5, H).astype(np.float32),
+                "conv": {n: rng.normal(0, K ** -0.5, (K, H * hd)).astype(
+                    np.float32) for n in "qkv"},
+                "o_norm": ones(hd)}
+
+    def mla_layer():
+        la = cfg.latent
+        return {"q": dense(D, H * (la.nope + la.rope)),
+                "kva": dense(D, la.latent + la.rope),
+                "c_norm": ones(la.latent),
+                "kvb": dense(la.latent, H * (la.nope + la.value)),
+                "z": dense(D, H), "o": dense(H * la.value, D)}
+
+    def moe_layer():
+        r = cfg.routed
+        F = r.d_expert
+        p = {"router": dense(D, r.experts),
+             "bias": rng.normal(0, 0.01, r.experts).astype(np.float32),
+             "experts": {
+                 "gate_up": rng.normal(0, np.sqrt(2.0 / (D + F)),
+                                       (r.held, D, 2 * F)).astype(np.float32),
+                 "down": rng.normal(0, np.sqrt(2.0 / (D + F)),
+                                    (r.held, F, D)).astype(np.float32)}}
+        if r.d_shared:
+            p["shared"] = {"gate": dense(D, r.d_shared),
+                           "up": dense(D, r.d_shared),
+                           "down": dense(r.d_shared, D)}
+        return p
+
     layers = []
-    for kind in cfg.mixers:
-        kv = H if kind == "lightning" else Hkv
-        lp = {"ln1": ones(D), "ln2": ones(D),
-              "q": dense(D, H * hd), "k": dense(D, kv * hd),
-              "v": dense(D, kv * hd), "g": dense(D, H * hd),
-              "o": dense(H * hd, D),
-              "q_norm": ones(hd), "k_norm": ones(hd),
-              "gate": dense(D, cfg.d_ff), "up": dense(D, cfg.d_ff),
-              "down": dense(cfg.d_ff, D)}
-        if kind == "lightning":
-            lp["o_norm"] = ones(H * hd)
+    for i, kind in enumerate(cfg.mixers):
+        lp = {"ln1": ones(D), "ln2": ones(D)}
+        if kind == "kda":
+            lp.update(kda_layer())
+        elif kind == "mla":
+            lp.update(mla_layer())
+        else:
+            kv = H if kind == "lightning" else Hkv
+            lp.update({"q": dense(D, H * hd), "k": dense(D, kv * hd),
+                       "v": dense(D, kv * hd), "g": dense(D, H * hd),
+                       "o": dense(H * hd, D),
+                       "q_norm": ones(hd), "k_norm": ones(hd)})
+            if kind == "lightning":
+                lp["o_norm"] = ones(H * hd)
+        if _ffn_kind(cfg, i) == "moe":
+            lp["moe"] = moe_layer()
+        else:
+            lp.update({"gate": dense(D, cfg.d_ff), "up": dense(D, cfg.d_ff),
+                       "down": dense(cfg.d_ff, D)})
         layers.append(lp)
     return {"embed": {"tok": dense(cfg.vocab, D, 0.02)["w"]},
             "layers": layers, "final_ln": ones(D),
@@ -129,16 +234,41 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+def latent_row(cfg) -> int:
+    """Values a cached row of an mla layer holds: ``latent + rope`` rounded
+    up to whole 128-lane registers (zeros). Unpadded, the chip keeps the pool
+    with the page offset minor-most to save the padding and relays it, in
+    and out, around every call of the kernel, which takes row-major
+    operands only: two pool-sized copies a tick (PERF.md, PRs 28 and 35)."""
+    return _round_up(cfg.latent.latent + cfg.latent.rope, 128)
+
+
+def _conv_shape(cfg, rows: int):
+    """A kda layer's convolution tails: the last ``conv_kernel - 1``
+    pre-convolution rows of q, k and v side by side, a row a slot."""
+    H, _, hd = dims(cfg)
+    return (rows, cfg.kda.conv_kernel - 1, 3 * H * hd)
+
+
 def init_hybrid_cache(cfg: TransformerConfig, batch: int, max_len: int):
     """Contiguous per-layer cache: ``{"state"}`` (B, H, hd, hd) float32 for
-    a lightning layer; ``{"k", "v"}`` (B, Hkv, L, hd) and the compressed
-    keys ``{"ck"}`` (B, Hkv, L / stride, hd) for a sparse one, ``L`` being
-    ``max_len`` rounded up to whole blocks."""
+    a lightning layer (a kda layer adds ``{"conv"}``, :func:`_conv_shape`);
+    ``{"k", "v"}`` (B, Hkv, L, hd) and the compressed keys ``{"ck"}``
+    (B, Hkv, L / stride, hd) for a sparse one, ``L`` being ``max_len``
+    rounded up to whole blocks; ``{"kv"}`` (B, 1, max_len,
+    :func:`latent_row`) latent rows for an mla layer."""
     H, Hkv, hd = dims(cfg)
     out = []
     for kind in cfg.mixers:
         if kind == "lightning":
             out.append({"state": jnp.zeros((batch, H, hd, hd), F32)})
+        elif kind == "kda":
+            out.append({"state": jnp.zeros((batch, H, hd, hd), F32),
+                        "conv": jnp.zeros(_conv_shape(cfg, batch),
+                                          cfg.dtype)})
+        elif kind == "mla":
+            out.append({"kv": jnp.zeros(
+                (batch, 1, max_len, latent_row(cfg)), cfg.dtype)})
         else:
             sp = cfg.sparse
             L = _round_up(max_len, sp.block_size)
@@ -153,12 +283,20 @@ def pool_shapes(cfg: TransformerConfig, num_pages: int, page_size: int,
     """Per layer ``{key: (shape, dtype)}`` of the engine's cache: pages
     (K beside V, as every pool) and a row of compressed keys a slot (for
     ``positions`` positions) for a sparse layer, one state row a slot for a
-    lightning layer."""
+    lightning layer; a kda layer adds its convolution tails a slot; an mla
+    layer holds latent pages ``(pages, 1, page, latent_row)``: one row a
+    token, nothing a head."""
     H, Hkv, hd = dims(cfg)
     out = []
     for kind in cfg.mixers:
         if kind == "lightning":
             out.append({"state": ((slots, H, hd, hd), F32)})
+        elif kind == "kda":
+            out.append({"state": ((slots, H, hd, hd), F32),
+                        "conv": (_conv_shape(cfg, slots), cfg.dtype)})
+        elif kind == "mla":
+            out.append({"kv": ((num_pages, 1, page_size, latent_row(cfg)),
+                        cfg.dtype)})
         else:
             s = cfg.sparse.kernel_stride
             if cfg.sparse.block_size % page_size:
@@ -336,11 +474,12 @@ def _allowed_keys(idx, ok, t, sp, L):
 
 
 def _masked_attention(q, k, v, allowed, t_max):
-    """Softmax attention of ``q`` (B, Hq, W, hd) over ``k``/``v``
-    (B, Hkv, L, hd) under ``allowed`` (B, Hkv, W, L), grouped-query, folded
-    a tile of keys at a time up to position ``t_max``. float32 out."""
+    """Softmax attention of ``q`` (B, Hq, W, hd) over ``k`` (B, Hkv, L,
+    hd) and ``v`` (B, Hkv, L, dv) under ``allowed`` (B, Hkv or 1, W, L),
+    grouped-query, folded a tile of keys at a time up to position
+    ``t_max``. float32 out."""
     B, Hq, W, hd = q.shape
-    G, L = k.shape[1], k.shape[2]
+    G, L, dv = k.shape[1], k.shape[2], v.shape[-1]
     qg = q.reshape(B, G, Hq // G, W, hd)
     scale = hd ** -0.5
     T = min(L, _KEY_TILE)
@@ -362,7 +501,7 @@ def _masked_attention(q, k, v, allowed, t_max):
 
     shape = (B, G, Hq // G, W)
     init = (jnp.full(shape, _NEG, F32), jnp.zeros(shape, F32),
-            jnp.zeros(shape + (hd,), F32))
+            jnp.zeros(shape + (dv,), F32))
     if L == T:
         _, l, acc = fold(init, k, v, allowed)
     else:
@@ -377,7 +516,7 @@ def _masked_attention(q, k, v, allowed, t_max):
             return fold(carry, tile(k, 2), tile(v, 2), tile(allowed, 3))
         _, l, acc = jax.lax.fori_loop(0, t_max // T + 1, body, init)
     out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
-    return out.reshape(B, Hq, W, hd)
+    return out.reshape(B, Hq, W, dv)
 
 
 def _sparse_contiguous(lp, x, wpos, pos, n_valid, c, cfg):
@@ -514,6 +653,262 @@ def _selected_decode(q, kv, bt, pos, n_valid, idx, sel_ok, cfg, page):
     return jax.lax.cond(wide, lambda: walk(n_dense), lambda: walk(K))
 
 
+# ---- kda --------------------------------------------------------------------
+
+def _l2norm(t, eps=1e-6):
+    return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + eps)
+
+
+def _kda_inputs(lp, x, tail, n_valid, cfg):
+    """What the delta rule reads of a window ``x`` (B, W, D) continuing the
+    convolution tails ``tail`` (B, K - 1, 3 H d): ``(q, k, v, g)`` (B, H, W,
+    d) float32, ``beta`` (B, H, W), and the tails after lane ``n_valid - 1``.
+    Padding lanes get ``beta = 0`` and ``g = 0``: they neither correct nor
+    decay a state."""
+    H, _, hd = dims(cfg)
+    dt = cfg.dtype
+    K = cfg.kda.conv_kernel
+    B, W, _ = x.shape
+    pre = jnp.concatenate([_proj(x, lp[n], dt) for n in "qkv"], axis=-1)
+    ext = jnp.concatenate([tail.astype(dt), pre], axis=1)   # (B, K-1+W, 3C)
+    taps = jnp.concatenate([lp["conv"][n] for n in "qkv"],
+                           axis=-1).astype(F32)             # (K, 3C)
+    mixed = jax.nn.silu(sum(ext[:, j:j + W].astype(F32) * taps[j]
+                            for j in range(K)))
+    if W == 1:
+        # the tick: every row shifts by its one lane or stays (a slice a
+        # row here is a gather, a sequential loop on the chip)
+        new_tail = jnp.where((n_valid > 0)[:, None, None], ext[:, 1:],
+                             ext[:, :K - 1])
+    else:
+        new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
+            e, n, K - 1, axis=0))(ext, n_valid)
+    q, k, v = (_heads(t, H, hd) for t in jnp.split(mixed, 3, axis=-1))
+    q = _l2norm(q) * hd ** -0.5
+    k = _l2norm(k)
+    f = (_proj(x, lp["f"], dt).astype(F32) + lp["dt_bias"].astype(F32))
+    g = cfg.kda.gate_floor * jax.nn.sigmoid(
+        jnp.exp(lp["a_log"].astype(F32))[None, :, None, None]
+        * _heads(f, H, hd))
+    beta = jax.nn.sigmoid(_proj(x, lp["b"], dt).astype(F32)).transpose(
+        0, 2, 1)                                            # (B, H, W)
+    live = (jnp.arange(W)[None] < n_valid[:, None])[:, None]    # (B, 1, W)
+    return (q, k, v, jnp.where(live[..., None], g, 0.0),
+            jnp.where(live, beta, 0.0), new_tail)
+
+
+def _unit_lower_inverse(n):
+    """``(I + n)^-1`` for a strictly lower triangular ``n`` (.., C, C):
+    ``n`` is nilpotent, so the inverse is the finite product ``(I - n)(I +
+    n^2)(I + n^4)..``; matrix products only, at full precision."""
+    C = n.shape[-1]
+    eye = jnp.eye(C, dtype=n.dtype)
+    m = -n
+    inv = eye + m
+    reach = 2
+    while reach < C:
+        m = jnp.matmul(m, m, precision=HI)
+        inv = jnp.matmul(inv, eye + m, precision=HI)
+        reach *= 2
+    return inv
+
+
+def kda_chunk(q, k, v, g, beta, state):
+    """The chunked delta rule over one window: ``q``, ``k``, ``v``, ``g``
+    (B, H, W, d) float32 (``g <= 0`` the channels' log-decays), ``beta``
+    (B, H, W), ``state`` (B, H, d, d) the state before the window. Returns
+    ``(o (B, H, W, d), state after the window)``.
+
+    :data:`KDA_CHUNK` tokens a step (the WY / UT-transform form). With ``G``
+    the log-decays cumulated inside the chunk, ``A[t, i] = sum_c k_t[c]
+    k_i[c] exp(G_t[c] - G_i[c])`` for ``i < t`` and ``B[t, i]`` the same
+    with ``q_t`` for ``i <= t``, the chunk's corrections are ``U = (I +
+    diag(beta) A)^-1 diag(beta) (V - (K * exp(G)) S)``, its outputs ``(Q *
+    exp(G)) S + B U`` and its closing state ``diag(exp(G_C)) S + (K *
+    exp(G_C - G))^T U``. Every decay is the exponential of a DIFFERENCE of
+    cumulated ``g`` that is at most 0, never a ratio of two powers (``exp(-G)``
+    overflows float32 inside one chunk at ``g = -5``)."""
+    B, H, W, d = q.shape
+    C = min(KDA_CHUNK, W)
+    short = -W % C
+    if short:
+        pad = ((0, 0), (0, 0), (0, short))
+        q, k, v, g = (jnp.pad(t, pad + ((0, 0),)) for t in (q, k, v, g))
+        beta = jnp.pad(beta, pad)
+    n = (W + short) // C
+
+    def chunks(t):          # (B, H, n*C, ..) -> (n, B, H, C, ..)
+        return jnp.moveaxis(t.reshape(B, H, n, C, *t.shape[3:]), 2, 0)
+
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+    # between[t, i, s]: step s lies after i and not after t. G_t - G_i is
+    # summed from the steps between them, not subtracted from two cumulated
+    # sums: those reach -320 in a chunk, where float32 resolves 3e-5, and
+    # the decays that matter are the ones whose exponent is small
+    j = jnp.arange(C)
+    between = ((j[None, :, None] < j[None, None, :])
+               & (j[None, None, :] <= j[:, None, None])).astype(F32)
+
+    def step(S, xs):
+        qc, kc, vc, gc, bc = xs
+        diff = jnp.einsum("tis,bhsd->bhtid", between, gc, precision=HI)
+        decay = jnp.exp(jnp.where(lower[:, :, None], diff, -jnp.inf))
+        kk = kc[:, :, None] * decay                          # (B,H,C,C,d)
+        A = jnp.where(strict, jnp.sum(kc[:, :, :, None] * kk, axis=-1), 0.0)
+        Bm = jnp.sum(qc[:, :, :, None] * kk, axis=-1)        # (B, H, C, C)
+        eG = jnp.exp(jnp.cumsum(gc, axis=2))                 # (B, H, C, d)
+        rhs = bc[..., None] * (vc - jnp.einsum(
+            "bhtd,bhde->bhte", kc * eG, S, precision=HI))
+        U = jnp.matmul(_unit_lower_inverse(bc[..., None] * A), rhs,
+                       precision=HI)
+        o = (jnp.einsum("bhtd,bhde->bhte", qc * eG, S, precision=HI)
+             + jnp.matmul(Bm, U, precision=HI))
+        S = (eG[:, :, -1, :, None] * S
+             + jnp.einsum("bhid,bhie->bhde", kc * decay[:, :, -1], U,
+                          precision=HI))
+        return S, o
+
+    state, o = jax.lax.scan(step, state,
+                            tuple(chunks(t) for t in (q, k, v, g, beta)))
+    o = jnp.moveaxis(o, 0, 2).reshape(B, H, n * C, d)
+    return o[:, :, :W], state
+
+
+def _head_gated_out(lp, x, o, cfg, norm: bool):
+    """``W_o(o * sigmoid(W_z x))`` with one gate a HEAD, ``o`` (B, H, W, dv)
+    float32, RMS-normed a head first on a kda layer."""
+    dt = cfg.dtype
+    B, H, W, dv = o.shape
+    if norm:
+        o = _head_rms(o, lp["o_norm"])
+    gate = jax.nn.sigmoid(_proj(x, lp["z"], dt).astype(F32))    # (B, W, H)
+    o = o.transpose(0, 2, 1, 3) * gate[..., None]
+    return o.reshape(B, W, H * dv).astype(dt) @ lp["o"]["w"].astype(dt)
+
+
+def _kda_layer(lp, x, c, pos, n_valid, cfg, kernel):
+    """A kda layer over its rows of the cache ``c`` (``state``, ``conv``;
+    the caller has sliced a prefill window's slot out): the decode tick
+    (``kernel``: one token a row, none at position 0) runs the Pallas step,
+    a window the chunked form from a state and tails zeroed at position 0."""
+    from ...ops.kda_attention import kda_decode_step
+    state, tail = c["state"], c["conv"]
+    if not kernel:
+        state, tail = _fresh(state, pos, n_valid), _fresh(tail, pos, n_valid)
+    q, k, v, g, beta, tail = _kda_inputs(lp, x, tail, n_valid, cfg)
+    if kernel:
+        o, state = kda_decode_step(q[:, :, 0], k[:, :, 0], v[:, :, 0],
+                                   jnp.exp(g[:, :, 0]), beta[:, :, 0],
+                                   state, n_valid > 0)
+        o = o[:, :, None]
+    else:
+        o, state = kda_chunk(q, k, v, g, beta, state)
+    return (_head_gated_out(lp, x, o, cfg, norm=True),
+            {"state": state, "conv": tail})
+
+
+# ---- mla --------------------------------------------------------------------
+
+def _mla_inputs(lp, x, wpos, cfg):
+    """``(q_n (B, H, W, nope), q_r (B, H, W, rope) rotated, row (B, W,
+    latent_row))``: the heads' queries and the one row a token caches, the
+    normed latent beside the rotated shared key (and zeros up to whole
+    registers), in the compute dtype."""
+    H = cfg.heads
+    la = cfg.latent
+    dt = cfg.dtype
+    q = _heads(_proj(x, lp["q"], dt), H, la.nope + la.rope)
+    ckr = _proj(x, lp["kva"], dt).astype(F32)
+    cos, sin = _rope_tables(wpos, la.rope, cfg.rope_theta, F32)
+    q_r = _rot_half(q[..., la.nope:].astype(F32), cos[:, None], sin[:, None])
+    row = jnp.concatenate(
+        [_rms(ckr[..., :la.latent], lp["c_norm"]),
+         _rot_half(ckr[..., la.latent:], cos, sin)], axis=-1).astype(dt)
+    row = jnp.pad(row, ((0, 0), (0, 0),
+                        (0, latent_row(cfg) - la.latent - la.rope)))
+    return q[..., :la.nope], q_r.astype(dt), row
+
+
+def _mla_kvb(lp, cfg):
+    """``W_kvb`` (latent, H, nope + value) in the compute dtype."""
+    la = cfg.latent
+    return lp["kvb"]["w"].astype(cfg.dtype).reshape(
+        la.latent, cfg.heads, la.nope + la.value)
+
+
+def _mla_expanded(lp, q_n, q_r, rows, wpos, cfg):
+    """Causal attention of queries at ``wpos`` (B, W) over cached rows
+    ``rows`` (B, L, latent_row) with K and V REBUILT from the latents: what
+    a window runs."""
+    la = cfg.latent
+    B, L, _ = rows.shape
+    allowed = (jnp.arange(L)[None, None] <= wpos[..., None])[:, None]
+    H = cfg.heads
+    kv = jnp.einsum("bld,dhn->bhln", rows[..., :la.latent], _mla_kvb(lp, cfg))
+    k = jnp.concatenate(
+        [kv[..., :la.nope], jnp.broadcast_to(
+            rows[:, None, :, la.latent:la.latent + la.rope],
+            (B, H, L, la.rope))], axis=-1)
+    q = jnp.concatenate([q_n, q_r], axis=-1)
+    return _masked_attention(q, k, kv[..., la.nope:], allowed,
+                             jnp.max(wpos))
+
+
+def _mla_absorbed(lp, q_n, q_r, kv_pages, bt, lengths, cfg):
+    """One query a row over the latent pages in place: ``W_kvb``'s key half
+    folded into the query, its value half applied to the weighted sum of
+    latents. ``q_n``, ``q_r`` (B, H, 1, .)."""
+    from ...ops.paged_attention import paged_attention_latent
+    la = cfg.latent
+    w = _mla_kvb(lp, cfg)
+    q_abs = jnp.einsum("bhn,lhn->bhl", q_n[:, :, 0], w[..., :la.nope],
+                       preferred_element_type=F32)
+    q_lat = jnp.concatenate([q_abs, q_r[:, :, 0].astype(F32)], axis=-1)
+    q_lat = jnp.pad(q_lat, ((0, 0), (0, 0),
+                            (0, kv_pages.shape[-1] - q_lat.shape[-1])))
+    ctx = paged_attention_latent(
+        q_lat, kv_pages, bt, lengths, v_width=la.latent,
+        scale=(la.nope + la.rope) ** -0.5)                  # (B, H, latent)
+    o = jnp.einsum("bhl,lhv->bhv", ctx.astype(cfg.dtype), w[..., la.nope:],
+                   preferred_element_type=F32)
+    return o[:, :, None]
+
+
+def _mla_contiguous(lp, x, wpos, n_valid, c, cfg):
+    q_n, q_r, row = _mla_inputs(lp, x, wpos, cfg)
+    W = x.shape[1]
+    L = c["kv"].shape[2]
+    dest = jnp.where(jnp.arange(W)[None] < n_valid[:, None], wpos, L)
+    rows = jax.vmap(lambda buf, val, idx: buf.at[idx].set(
+        val, mode="drop"))(c["kv"][:, 0], row, dest)
+    o = _mla_expanded(lp, q_n, q_r, rows, wpos, cfg)
+    return _head_gated_out(lp, x, o, cfg, norm=False), {"kv": rows[:, None]}
+
+
+def _mla_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel):
+    """An mla layer over its latent pages: the window's rows are written
+    through the block table (padding lanes and idle rows to trash page 0);
+    the decode tick then attends absorbed, in place; a window gathers its
+    row's pages and attends expanded."""
+    B, W, _ = x.shape
+    P = bt.shape[1]
+    q_n, q_r, row = _mla_inputs(lp, x, wpos, cfg)
+    lane_ok = jnp.arange(W)[None] < n_valid[:, None]
+    pg = jnp.take_along_axis(bt, jnp.clip(wpos // page, 0, P - 1), axis=1)
+    kv = c["kv"].at[jnp.where(lane_ok, pg, 0).reshape(-1, 1),
+                    jnp.zeros((1, 1), jnp.int32),
+                    (wpos % page).reshape(-1, 1)].set(
+                        row.reshape(B * W, 1, -1))
+    if kernel:
+        o = _mla_absorbed(lp, q_n, q_r, kv, bt,
+                          jnp.where(n_valid > 0, pos + 1, 0), cfg)
+    else:
+        o = _mla_expanded(lp, q_n, q_r,
+                          kv[bt][:, :, 0].reshape(B, P * page, -1), wpos, cfg)
+    return _head_gated_out(lp, x, o, cfg, norm=False), {"kv": kv}
+
+
 # ---- the window -------------------------------------------------------------
 
 def _finish(params, h, cfg, n_valid, last_only):
@@ -527,19 +922,46 @@ def _finish(params, h, cfg, n_valid, last_only):
     return hidden
 
 
-def _window(params, tokens, pos, cfg, n_valid, mixer, last_only):
+def _routed(lp, x32, cfg, n_valid):
+    """A layer's routed feed-forward on the window's normed rows ``x32``
+    (B, W, D) float32: the held experts' part plus the shared expert, and
+    the layer's routing counts (``parallel.moe.MOE_STATS``)."""
+    from ...parallel.moe import moe_topk_held
+    B, W, D = x32.shape
+    valid = (jnp.arange(W)[None] < n_valid[:, None]).reshape(B * W)
+    y, counts = moe_topk_held(x32.astype(cfg.dtype).reshape(B * W, D),
+                              x32.reshape(B * W, D), lp["moe"], cfg.routed,
+                              valid)
+    return y.reshape(B, W, D), counts
+
+
+def _window(params, tokens, pos, cfg, n_valid, mixer, last_only, stats=None):
     """The layer loop shared by both cache forms; ``mixer(kind, lp, x, wpos,
-    layer index)`` returns the mixer's output and records its new cache."""
+    layer index)`` returns the mixer's output and records its new cache.
+    The feed-forward is the layer's ``cfg.ffn`` kind, resolved here at trace
+    time; a caller that passes a dict as ``stats`` finds the routed layers'
+    counts under ``"moe"``: int32 in ``MOE_STATS``' order, summed over the
+    layers, the largest expert's load their maximum."""
     dt = cfg.dtype
     W = tokens.shape[1]
     wpos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)
     h = _embed(params, tokens, cfg) * jnp.asarray(cfg.embed_scale, dt)
     rs = jnp.asarray(cfg.residual_scale, dt)
+    counts = []
     for i, (kind, lp) in enumerate(zip(cfg.mixers, params["layers"])):
         x = _rms(h.astype(F32), lp["ln1"]).astype(dt)
         h = h + rs * mixer(i, kind, lp, x, wpos).astype(dt)
-        x = _rms(h.astype(F32), lp["ln2"]).astype(dt)
-        h = h + rs * _swiglu(lp, x, dt)
+        if _ffn_kind(cfg, i) == "moe":
+            y, c = _routed(lp, _rms(h.astype(F32), lp["ln2"]), cfg, n_valid)
+            counts.append(c)
+            h = h + rs * y
+        else:
+            x = _rms(h.astype(F32), lp["ln2"]).astype(dt)
+            h = h + rs * _swiglu(lp, x, dt)
+    if stats is not None and counts:
+        c = jnp.stack(counts)
+        stats["moe"] = jnp.concatenate([c[:, :-1].sum(axis=0),
+                                        c[:, -1:].max(axis=0)])
     return _finish(params, h, cfg, n_valid, last_only)
 
 
@@ -554,10 +976,10 @@ def _lanes(tokens, pos, n_valid, active):
 
 
 def _fresh(state, pos, n_valid):
-    """A row whose window starts at position 0 starts from a zero state:
-    what resets a reused slot."""
-    new = ((pos == 0) & (n_valid > 0))[:, None, None, None]
-    return jnp.where(new, 0.0, state)
+    """A row whose window starts at position 0 starts from a zero state
+    (and zero convolution tails): what resets a reused slot."""
+    new = ((pos == 0) & (n_valid > 0)).reshape((-1,) + (1,) * (state.ndim - 1))
+    return jnp.where(new, jnp.zeros((), state.dtype), state)
 
 
 def window_contiguous(params: Dict, tokens, pos, cache, cfg, *,
@@ -578,8 +1000,13 @@ def window_contiguous(params: Dict, tokens, pos, cache, cfg, *,
                                     n_valid)
             new_cache[i] = {"state": st}
             return _gated_out(lp, x, o, cfg, norm=True)
-        y, new_cache[i] = _sparse_contiguous(lp, x, wpos, pos, n_valid, c,
-                                             cfg)
+        if kind == "kda":
+            y, new_cache[i] = _kda_layer(lp, x, c, pos, n_valid, cfg, False)
+        elif kind == "mla":
+            y, new_cache[i] = _mla_contiguous(lp, x, wpos, n_valid, c, cfg)
+        else:
+            y, new_cache[i] = _sparse_contiguous(lp, x, wpos, pos, n_valid,
+                                                 c, cfg)
         return y
 
     hidden = _window(params, tokens, pos, cfg, n_valid, mixer, last_only)
@@ -588,14 +1015,15 @@ def window_contiguous(params: Dict, tokens, pos, cache, cfg, *,
 
 def window_paged(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
                  page_size: int, impl: str = "kernel", n_valid=None,
-                 active=None, slot=None, last_only=False):
+                 active=None, slot=None, last_only=False, stats=None):
     """The engine's window over its pool (:func:`pool_shapes`): pages
     through ``block_tables`` for the sparse layers' K/V; their compressed
     keys and the lightning layers' states are rows a slot — row ``b`` of
     the batch is slot ``b`` (the decode tick, every slot a row), or with
     ``slot`` the one row of a prefill chunk is that slot's. ``impl="kernel"`` runs the two Pallas
     decode kernels when the window is one token; a longer window, and
-    ``impl="gather"`` always, gather and mask. Returns ``(logits, bufs)``."""
+    ``impl="gather"`` always, gather and mask. Returns ``(logits, bufs)``;
+    ``stats``: :func:`_window`."""
     from ...ops.lightning_attention import lightning_decode_step
     check_config(cfg)
     pos, n_valid = _lanes(tokens, pos, n_valid, active)
@@ -608,6 +1036,19 @@ def window_paged(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
             y, new_bufs[i] = _sparse_paged(lp, x, wpos, pos, n_valid, c,
                                            block_tables, cfg, page_size,
                                            kernel, slot)
+            return y
+        if kind == "mla":
+            y, new_bufs[i] = _mla_paged(lp, x, wpos, pos, n_valid, c,
+                                        block_tables, cfg, page_size, kernel)
+            return y
+        if kind == "kda":
+            rows = c if slot is None else {
+                kk: jax.lax.dynamic_slice_in_dim(c[kk], slot, 1, axis=0)
+                for kk in c}
+            y, new = _kda_layer(lp, x, rows, pos, n_valid, cfg, kernel)
+            new_bufs[i] = new if slot is None else {
+                kk: jax.lax.dynamic_update_slice_in_dim(c[kk], new[kk], slot,
+                                                        axis=0) for kk in c}
             return y
         q, k, v = _lightning_qkv(lp, x, wpos, cfg)
         rows = (c["state"] if slot is None else
@@ -626,5 +1067,6 @@ def window_paged(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
                            c["state"], st, slot, axis=0)}
         return _gated_out(lp, x, o, cfg, norm=True)
 
-    hidden = _window(params, tokens, pos, cfg, n_valid, mixer, last_only)
+    hidden = _window(params, tokens, pos, cfg, n_valid, mixer, last_only,
+                     stats)
     return head(params, hidden), new_bufs

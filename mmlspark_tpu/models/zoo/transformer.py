@@ -25,7 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["TransformerConfig", "SparseAttention", "init_transformer", "transformer_apply",
+__all__ = ["TransformerConfig", "SparseAttention", "RoutedExperts",
+           "LatentAttention", "DeltaRule", "init_transformer",
+           "transformer_apply",
            "train_step", "param_shardings", "BERT_BASE", "BERT_MINI",
            "DECODER_MINI", "generate", "generate_cached",
            "decode_step", "init_kv_cache", "decode_window_ragged",
@@ -47,6 +49,55 @@ class SparseAttention(NamedTuple):
     window_size: int = 2048
     init_blocks: int = 1
     dense_len: int = 8192
+
+
+class RoutedExperts(NamedTuple):
+    """A routed feed-forward's sizes (``ffn`` kind ``"moe"``): a router over
+    ``experts`` scores every token (sigmoid, float32, a learned selection
+    bias), the experts stand in ``groups`` equal groups of which the
+    ``groups_kept`` best stay (a group scores the sum of its two best), the
+    ``per_token`` best experts among them are chosen and their scores,
+    normalised over all chosen, times ``scale`` weigh the experts' outputs.
+    THIS process holds experts ``first .. first + count - 1`` (its share of
+    an expert-parallel layer; ``count`` 0 = all): it routes over all of
+    them and adds up only what its own give. ``d_shared`` > 0 adds one
+    always-on expert of that width, computed on every share alike.
+    ``swiglu_limits`` are the published clamps of the layers held (expert
+    and shared, a layer after a layer): a non-zero one is refused, its form
+    is not built."""
+    experts: int = 8
+    first: int = 0
+    count: int = 0
+    per_token: int = 2
+    groups: int = 1
+    groups_kept: int = 1
+    scale: float = 1.0
+    d_expert: int = 0
+    d_shared: int = 0
+    swiglu_limits: tuple = ()
+
+    @property
+    def held(self) -> int:
+        return self.count or self.experts
+
+
+class LatentAttention(NamedTuple):
+    """Multi-head latent attention's sizes (the ``mla`` mixer): a token
+    caches ONE row of ``latent + rope`` values (the normed key/value latent
+    and a rotated key every head shares); a head's query is ``nope + rope``
+    wide, its value ``value``."""
+    latent: int = 512
+    nope: int = 128
+    rope: int = 64
+    value: int = 128
+
+
+class DeltaRule(NamedTuple):
+    """The ``kda`` mixer's sizes: a causal depthwise convolution of
+    ``conv_kernel`` taps on q, k and v, and a per-channel log-decay held in
+    ``(gate_floor, 0)`` a token."""
+    conv_kernel: int = 4
+    gate_floor: float = -5.0
 
 
 class TransformerConfig(NamedTuple):
@@ -89,6 +140,15 @@ class TransformerConfig(NamedTuple):
     #: SwiGLU, RMSNorm, and the three scalings below. Empty keeps the dense
     #: multi-head block above, unchanged.
     mixers: tuple = ()
+    #: two more kinds: ``"kda"`` (a delta-rule linear attention with a
+    #: per-channel decay gate and short convolutions, sizes in ``kda``) and
+    #: ``"mla"`` (latent attention, sizes in ``latent``). ``ffn`` names one
+    #: feed-forward kind a layer of a hybrid decoder, ``"dense"`` (SwiGLU of
+    #: ``d_ff``) or ``"moe"`` (``routed``); empty = dense everywhere.
+    ffn: tuple = ()
+    routed: Optional[RoutedExperts] = None
+    latent: Optional[LatentAttention] = None
+    kda: Optional[DeltaRule] = None
     #: KV heads of the sparse layers (0 = ``heads``) and an explicit head
     #: size (0 = ``d_model // heads``)
     kv_heads: int = 0
@@ -860,7 +920,8 @@ def decode_step_paged(params: Dict, tokens: jnp.ndarray, pos: jnp.ndarray,
                       page_size: int, length: int,
                       active: Optional[jnp.ndarray] = None,
                       impl: Optional[str] = None,
-                      mesh=None, slot_axis=None, head_axis=None):
+                      mesh=None, slot_axis=None, head_axis=None,
+                      stats: Optional[dict] = None):
     """One paged decode step: the ``W = 1`` window of
     :func:`decode_window_paged`, which describes the two implementations
     ``impl`` selects. ``tokens`` (B,), ``pos`` (B,), every ``pos`` <
@@ -868,7 +929,7 @@ def decode_step_paged(params: Dict, tokens: jnp.ndarray, pos: jnp.ndarray,
     logits, pages = decode_window_paged(
         params, tokens[:, None], pos, cache_pages, block_tables, cfg,
         page_size=page_size, length=length, active=active, impl=impl,
-        mesh=mesh, slot_axis=slot_axis, head_axis=head_axis)
+        mesh=mesh, slot_axis=slot_axis, head_axis=head_axis, stats=stats)
     return logits[:, 0], pages
 
 
@@ -879,7 +940,8 @@ def decode_window_paged(params: Dict, tokens: jnp.ndarray,
                         active: Optional[jnp.ndarray] = None,
                         impl: Optional[str] = None,
                         mesh=None, slot_axis=None, head_axis=None,
-                        n_valid=None, slot=None, last_only: bool = False):
+                        n_valid=None, slot=None, last_only: bool = False,
+                        stats: Optional[dict] = None):
     """Paged window decode — the decode tick (``W = 1``), the speculative
     verify and the chunked-prefill primitive. Row b's window writes
     positions ``pos[b]..pos[b]+W-1`` into its pages; every such position
@@ -904,7 +966,9 @@ def decode_window_paged(params: Dict, tokens: jnp.ndarray,
     ``cache_pages`` and takes three more arguments, which the dense block
     refuses: ``n_valid`` (B,), the real lanes of each row (padding must not
     reach a state); ``slot``, the state row of a one-row prefill window;
-    ``last_only``, logits (B, vocab) of lane ``n_valid - 1`` alone."""
+    ``last_only``, logits (B, vocab) of lane ``n_valid - 1`` alone. A dict
+    passed as ``stats`` receives what the traced window counts of itself
+    (``"moe"``: a routed decoder's pairs, ``hybrid._window``)."""
     from ...ops.paged_attention import paged_attention_window, resolve_impl
     if cfg.mixers:
         if mesh is not None:
@@ -913,7 +977,8 @@ def decode_window_paged(params: Dict, tokens: jnp.ndarray,
         return window_paged(params, tokens, pos, cache_pages, block_tables,
                             cfg, page_size=page_size,
                             impl=resolve_impl(impl), n_valid=n_valid,
-                            active=active, slot=slot, last_only=last_only)
+                            active=active, slot=slot, last_only=last_only,
+                            stats=stats)
     if n_valid is not None or slot is not None or last_only:
         raise ValueError("n_valid, slot and last_only belong to a hybrid "
                          "decoder's window")

@@ -116,7 +116,8 @@ from .pallas_kernels import _LANE, _round_up
 from .kv_quant import quantize_kv
 
 __all__ = ["paged_attention", "paged_attention_window",
-           "paged_attention_selected", "resolve_impl",
+           "paged_attention_selected", "paged_attention_latent",
+           "resolve_impl",
            "sublane_multiple", "aligned_page_size", "pack_kv", "split_kv",
            "stored_kv"]
 
@@ -212,13 +213,19 @@ def _finalize(o_ref, l_scr, acc_scr):
                 jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def _page_kv(kv_ref, ks_ref=None, vs_ref=None):
+def _page_kv(kv_ref, ks_ref=None, vs_ref=None, v_width=None):
     """One ``(1, H, page, 2*hd)`` page block as float32 ``(k, v)``, each
     ``(H, page, hd)``. With the page's two ``(1, H, page)`` scale blocks
     this is the IN-KERNEL dequant: they arrived through the same
     block-table index_map, so the multiply happens in VMEM right after
-    the page DMA and the quantized bytes are all HBM ever moves."""
-    k, v = split_kv(kv_ref[0])
+    the page DMA and the quantized bytes are all HBM ever moves. With
+    ``v_width`` the page is a LATENT one: the whole row is the key and its
+    first ``v_width`` values are the value too."""
+    if v_width is None:
+        k, v = split_kv(kv_ref[0])
+    else:
+        k = kv_ref[0]
+        v = k[..., :v_width]
     k, v = k.astype(jnp.float32), v.astype(jnp.float32)
     if ks_ref is not None:
         k = k * ks_ref[0].astype(jnp.float32)[:, :, None]
@@ -303,7 +310,7 @@ def _step(row_ref, page_ref, last_ref):
 
 
 def _pa_read_kernel(row_ref, page_ref, last_ref, bt_ref, len_ref, q_ref,
-                    kv_ref, *rest, scale, page, quant):
+                    kv_ref, *rest, scale, page, quant, v_width=None):
     from jax.experimental import pallas as pl
 
     scales, (o_ref, m_scr, l_scr, acc_scr) = rest[:2 * quant], rest[2 * quant:]
@@ -313,7 +320,8 @@ def _pa_read_kernel(row_ref, page_ref, last_ref, bt_ref, len_ref, q_ref,
 
     @pl.when(p * page < bound)
     def _compute():
-        _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref, *scales),
+        _pages_fold(m_scr, l_scr, acc_scr, q_ref,
+                    _page_kv(kv_ref, *scales, v_width=v_width),
                     p, bound, scale, page)
 
     pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
@@ -526,6 +534,36 @@ def _pa_read_call(q, kv_pages, block_tables, lengths, *scales,
     return call(*sweep, block_tables, lengths, q, kv_pages, *scales)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("v_width", "scale", "interpret"))
+def _pa_latent_call(q, kv_pages, block_tables, lengths, *, v_width, scale,
+                    interpret):
+    """The read kernel mounted on LATENT pages ``(N, 1, page, dk)``: one KV
+    head whose row is the key and, in its first ``v_width`` values, the
+    value; the kernel's window is the ``Hq`` query heads that share it, so
+    one page DMA serves them all. The same ragged sweep."""
+    from jax.experimental import pallas as pl
+
+    B, _, Hq, dk = q.shape
+    page = kv_pages.shape[2]
+    *sweep, total = _schedule(lengths, -1, page, block_tables.shape[1])
+    kernel = functools.partial(_pa_read_kernel, scale=scale, page=page,
+                               quant=False, v_width=v_width)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=_grid_spec(
+            2, total,
+            in_specs=[pl.BlockSpec((1, 1, Hq, dk), _row_map),
+                      pl.BlockSpec((1, 1, page, dk), _page_map)],
+            out_specs=pl.BlockSpec((1, 1, Hq, v_width), _row_map),
+            H=1, Wp=Hq, hd=v_width),
+        out_shape=jax.ShapeDtypeStruct((B, 1, Hq, v_width), q.dtype),
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+    )
+    return call(*sweep, block_tables, lengths, q, kv_pages)
+
+
 @functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
 def _pa_window_read_call(q, kv_new, kv_pages, block_tables, pos, *scales,
                          W, scale, interpret):
@@ -706,6 +744,24 @@ def paged_attention_selected(q, kv_pages, block_tables, sel_pages, lengths,
         q, kv_pages, block_tables.astype(jnp.int32),
         sel_pages.reshape(B * G, -1).astype(jnp.int32),
         lengths.astype(jnp.int32), scale=scale, interpret=bool(interpret))
+
+
+def paged_attention_latent(q, kv_pages, block_tables, lengths, *,
+                           v_width: int, scale: float,
+                           interpret: Optional[bool] = None):
+    """Decode attention over LATENT pages, read in place: ``q`` (B, Hq, dk),
+    one absorbed query a head a row, over the packed ``(N, 1, page, dk)``
+    pool whose row a token is its key and, in the first ``v_width`` values,
+    its value (multi-head latent attention's cache: every head reads the
+    same row). Keys at positions ``>= lengths[b]`` are masked; a row with
+    ``lengths[b] == 0`` yields zeros. ``Hq`` must be a multiple of the
+    query dtype's sublane tile. Returns (B, Hq, v_width) in ``q.dtype``."""
+    if interpret is None:
+        interpret = _auto_interpret()
+    return _pa_latent_call(
+        q[:, None], kv_pages, block_tables.astype(jnp.int32),
+        lengths.astype(jnp.int32), v_width=int(v_width), scale=float(scale),
+        interpret=bool(interpret))[:, 0]
 
 
 # ---- mesh mount (shard_map) -------------------------------------------------

@@ -26,7 +26,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["init_moe_params", "moe_ffn_local", "moe_ffn_sharded",
-           "moe_ffn_gspmd", "moe_shardings", "moe_capacity"]
+           "moe_ffn_gspmd", "moe_shardings", "moe_capacity",
+           "route_topk", "moe_topk_held", "held_tiles", "MOE_STATS"]
 
 
 def moe_capacity(tokens_per_shard: int, n_experts: int,
@@ -227,3 +228,117 @@ def moe_ffn_gspmd(t, params, n_experts: int, capacity: int,
     out = constrain(out, ep_axis, None, None, None)
     y = jnp.einsum("gecd,gtec->gtd", out, slot_dt)
     return y * gate_prob[..., None].astype(dt), aux
+
+
+# ---- top-k, dropless, over the experts held ---------------------------------
+# The serving path of a routed feed-forward whose layer is shared by expert
+# parallelism (``TransformerConfig.routed``): this process routes every token
+# over ALL the experts, keeps the (token, expert) pairs that land on the
+# experts it holds and adds up their part of the result; the other shares'
+# parts are theirs to add (across chips an exchange sums them; on one chip
+# the layer runs without it). No capacity: a pair is never dropped.
+
+#: what :func:`moe_topk_held` counts, in the order of its ``stats`` vector
+MOE_STATS = ("pairs_routed", "pairs_held", "pairs_dropped",
+             "pairs_misplaced", "experts_touched", "expert_load_max")
+
+
+def route_topk(x32, router_w, bias, spec):
+    """Group-limited top-k routing, float32 throughout. ``x32`` (T, D)
+    float32, ``router_w`` (D, experts), ``bias`` (experts,) the selection
+    bias. Returns ``(idx, weight)``, both (T, per_token): the chosen experts
+    and ``scale * s / sum(chosen s)`` of their UNBIASED sigmoid scores."""
+    f32 = jnp.float32
+    s = jax.nn.sigmoid(jnp.dot(x32.astype(f32), router_w.astype(f32),
+                               precision=jax.lax.Precision.HIGHEST))
+    sel = s + bias.astype(f32)
+    T, E = s.shape
+    G = spec.groups
+    per = E // G
+    best2 = jax.lax.top_k(sel.reshape(T, G, per), min(2, per))[0].sum(-1)
+    _, keep = jax.lax.top_k(best2, spec.groups_kept)        # (T, kept)
+    kept = (keep[:, :, None] == jnp.arange(G)[None, None]).any(axis=1)
+    sel = jnp.where(jnp.repeat(kept, per, axis=1), sel, -jnp.inf)
+    _, idx = jax.lax.top_k(sel, spec.per_token)
+    chosen = jnp.take_along_axis(s, idx, axis=1)
+    weight = chosen / chosen.sum(axis=-1, keepdims=True) * spec.scale
+    return idx.astype(jnp.int32), weight
+
+
+def held_tiles(pairs: int, held: int, tile: int) -> int:
+    """The most tiles ``pairs`` (token, expert) pairs over ``held`` experts
+    can fill: each expert's rows round up to whole tiles."""
+    return min(held, pairs) + pairs // tile
+
+
+def moe_topk_held(x, x32, p, spec, valid, interpret=None):
+    """The held experts' part of a routed feed-forward, plus the shared
+    expert. ``x`` (T, D) in the compute dtype, ``x32`` the same rows in
+    float32 (the router reads these: a rounded input swaps near-tied
+    experts), ``valid`` (T,) bool the real tokens (padding and idle rows
+    route nowhere and read no expert). ``p``: ``router.w`` (D, experts),
+    ``bias`` (experts,), ``experts.gate_up`` (held, D, 2F), ``experts.down``
+    (held, F, D), and ``shared.{gate,up,down}`` when the layer has a shared
+    expert. Returns ``(y (T, D) in x's dtype, stats int32[6])``, the stats
+    in :data:`MOE_STATS`' order: the live tokens' pairs over all experts,
+    those on held experts, held pairs given no row (0: no capacity), pairs
+    whose row lies in a tile of ANOTHER expert's weights (0: the layout's
+    own check), distinct held experts with a pair, the largest expert's
+    pairs.
+
+    The pairs on held experts are ranked within their expert by a running
+    count (no sort is needed for that), laid out expert after expert in
+    tiles of ``ops.grouped_matmul.TILE`` rows, multiplied by
+    :func:`~mmlspark_tpu.ops.grouped_matmul.grouped_swiglu`, which reads
+    only the experts that have a tile, and weighted back onto their tokens.
+    Rows are moved by products with 0/1 matrices (a gather of thousands of
+    small rows is a sequential loop on the chip)."""
+    from ..ops.grouped_matmul import TILE, grouped_swiglu
+    f32 = jnp.float32
+    T, D = x.shape
+    k, Eh = spec.per_token, spec.held
+    idx, weight = route_topk(x32, p["router"]["w"], p["bias"], spec)
+    local = idx - spec.first
+    held = (local >= 0) & (local < Eh) & valid[:, None]         # (T, k)
+    e = jnp.where(held, local, Eh).reshape(T * k)
+    onehot = e[:, None] == jnp.arange(Eh, dtype=jnp.int32)[None]   # (P, Eh)
+    counts = onehot.sum(axis=0, dtype=jnp.int32)
+    rank = jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1
+    tiles = -(-counts // TILE)
+    ends = jnp.cumsum(tiles)
+    first_row = (ends - tiles) * TILE
+    n_tiles = held_tiles(T * k, Eh, TILE)
+    R = n_tiles * TILE
+    row = jnp.where(onehot, rank + first_row[None], 0).sum(axis=1)
+    row = jnp.where(held.reshape(-1), row, R).reshape(T, k)     # R: nowhere
+    tile_expert = jnp.minimum(jnp.sum(
+        jnp.arange(n_tiles, dtype=jnp.int32)[:, None] >= ends[None], axis=1),
+        Eh - 1).astype(jnp.int32)
+    total = ends[-1]
+    rows = jnp.arange(R, dtype=jnp.int32)
+    place = row[None] == rows[:, None, None]                    # (R, T, k)
+    xs = jnp.dot(place.any(axis=2).astype(x.dtype), x)          # (R, D)
+    ys = grouped_swiglu(xs, tile_expert, total, p["experts"]["gate_up"],
+                        p["experts"]["down"], interpret=interpret)
+    # a tile past the bound was not written: whatever the buffer held
+    ys = jnp.where((rows < total * TILE)[:, None], ys, 0.0)
+    back = jnp.where(place, weight[None], 0.0).sum(axis=2)      # (R, T)
+    y = jnp.einsum("rt,rd->td", back, ys,
+                   precision=jax.lax.Precision.HIGHEST)
+    if "shared" in p:
+        sh = p["shared"]
+        dt = x.dtype
+        y = y + jnp.matmul(
+            jax.nn.silu(x @ sh["gate"]["w"].astype(dt))
+            * (x @ sh["up"]["w"].astype(dt)), sh["down"]["w"].astype(dt),
+            preferred_element_type=f32)
+    n_held = held.sum(dtype=jnp.int32)
+    placed = row < R
+    reads = (jnp.minimum(row, R - 1)[:, :, None] // TILE
+             == jnp.arange(n_tiles)[None, None]) @ tile_expert  # (T, k)
+    stats = jnp.stack([
+        valid.sum(dtype=jnp.int32) * k, n_held,
+        n_held - placed.sum(dtype=jnp.int32),
+        (placed & (reads != local)).sum(dtype=jnp.int32),
+        (counts > 0).sum(dtype=jnp.int32), counts.max()])
+    return y.astype(x.dtype), stats

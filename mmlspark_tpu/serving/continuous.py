@@ -234,10 +234,12 @@ def _tick_program(cfg, page, Lc, k, eos, sample, donate, attn="kernel",
              temp=None, topk=None, topp=None, key=None):
         def body(carry, _):
             tok, pos, active, bufs, remaining = carry
+            counted = {}
             logits, bufs = decode_step_paged(
                 params, tok, pos, bufs, bt, cfg,
                 page_size=page, length=Lc, active=active, impl=attn,
-                mesh=mesh, slot_axis=slot_axis, head_axis=head_axis)
+                mesh=mesh, slot_axis=slot_axis, head_axis=head_axis,
+                stats=counted)
             if sample:
                 # emit position is pos+1 — generate_cached's key
                 # schedule (fold_in by absolute emit position), so
@@ -255,7 +257,11 @@ def _tick_program(cfg, page, Lc, k, eos, sample, donate, attn="kernel",
             if eos_const is not None:
                 fin = fin | (nxt == eos_const)
             active = active & ~fin
-            return (nxt, pos, active, bufs, remaining), nxt
+            # a routed decoder's counts ride out with the step's tokens, as
+            # columns past the slots': one fetch a block, as before
+            out = (jnp.concatenate([nxt, counted["moe"]])
+                   if "moe" in counted else nxt)
+            return (nxt, pos, active, bufs, remaining), out
         carry, toks = jax.lax.scan(
             body, (tok, pos, active, bufs, remaining), None, length=k)
         return (*carry, toks)
@@ -684,13 +690,15 @@ class ContinuousDecoder:
             for given, why in (
                     (draft_params is not None,
                      "a draft model: the verify window would have to roll "
-                     "rejected tokens back out of a linear-attention state"),
+                     "rejected tokens back out of a linear-attention state "
+                     "(lightning or kda) and its convolution tails"),
                     (resolve_kv_dtype(kv_dtype) is not None,
-                     "kv_dtype: the sparse layers' compressed keys and the "
-                     "selected-block kernel read bf16 pages only"),
+                     "kv_dtype: the sparse layers' compressed keys, the "
+                     "selected-block kernel and an mla layer's latent pages "
+                     "are bf16 only"),
                     (mesh is not None,
-                     "a mesh: the state rows and the two decode kernels "
-                     "have no mount")):
+                     "a mesh: the state rows, the decode kernels and a "
+                     "routed feed-forward's exchange have no mount")):
                 if given:
                     raise ValueError(f"a hybrid decoder does not take {why}")
         #: speculative mode: a draft model proposes gamma greedy tokens per
@@ -2047,6 +2055,16 @@ class ContinuousDecoder:
             self._kv.note_attn_tick(
                 "sparse" if context > sp.dense_len else "dense", calls=calls)
 
+    def _note_mixer_ticks(self, calls: int) -> None:
+        """A model with kda or mla layers counts each decode call once more
+        for each, by the path its tick ran: ``kda`` / ``latent`` (the Pallas
+        step, the absorbed kernel) or ``kda_window`` / ``latent_window``
+        (the chunked form and the expanded attention, under ``gather``)."""
+        off = "" if self._attn_impl == "kernel" else "_window"
+        for mixer, label in (("kda", "kda"), ("mla", "latent")):
+            if mixer in self._cfg.mixers:
+                self._kv.note_attn_tick(label + off, calls=calls)
+
     def _note_token(self, req: _Request, tok: int):
         now = time.perf_counter()
         if req.first_token_at is None:
@@ -2186,6 +2204,7 @@ class ContinuousDecoder:
         self._note_sparse_ticks(
             max(self._slot_req[i].prompt.size + len(self._slot_req[i].tokens)
                 for i in decode_live), calls=self._k)
+        self._note_mixer_ticks(self._k)
         # a row's device position: its drained tokens plus those of the
         # blocks still in flight (a first-token block carries one, a tick's
         # k; this tick's is not yet pending)
@@ -2294,6 +2313,10 @@ class ContinuousDecoder:
         with _M_DRAIN_SECONDS.time(), _tracing.span("continuous.drain"), \
                 _watch("decoder_drain"):
             toks = np.asarray(toks_dev)
+        if toks.shape[1] > self._S:
+            # a routed decoder's tick: its counts beside its tokens
+            self._kv.note_moe(toks[:, self._S:])
+            toks = toks[:, :self._S]
         _get_ledger().charge_shares(
             "device_seconds", time.perf_counter() - drain_t0,
             [(req.cost_cls, req.cost_trace, 1.0)

@@ -111,6 +111,17 @@ M_KERNEL_TICKS = _metric_counter(
     "call selected blocks (sparse) or every row was still under dense_len "
     "(dense)",
     labelnames=("impl",))
+M_MOE = _metric_counter(
+    "mmlspark_kvpool_moe_total",
+    "A routed feed-forward's decode ticks, by count: pairs_routed ((token, "
+    "expert) pairs the live rows routed, over all experts and routed layers), "
+    "pairs_held (those on the experts this process holds), pairs_dropped "
+    "(held pairs given no row: always 0, routing is dropless), "
+    "pairs_misplaced (pairs multiplied by another expert's weights: always "
+    "0), experts_touched (distinct held experts with a pair, summed over layers "
+    "and ticks), expert_load_max (the largest expert's pairs in a tick, any "
+    "layer, summed over ticks)",
+    labelnames=("count",))
 M_STATE_SNAPSHOTS = _metric_counter(
     "mmlspark_kvpool_state_snapshots_total",
     "Linear-attention state snapshots kept with a cached prefix, by event: "
@@ -153,7 +164,9 @@ class PagedKVPool:
 
     ``buffers`` is the per-layer list of ``{"kv"}`` page arrays (plus
     ``{"k_scale","v_scale"}`` when quantized; for a hybrid decoder
-    ``{"kv","ck"}`` or ``{"state"}``, see ``models/zoo/hybrid.py``) the
+    ``{"kv","ck"}``, ``{"state"}``, ``{"state","conv"}`` or latent ``{"kv"}``
+    pages, each in the shape ``models/zoo/hybrid.py`` ``pool_shapes``
+    gives it) the
     engine threads through its jitted steps (reassigning after every
     dispatch, since XLA returns fresh buffers). Everything else is host
     bookkeeping: a free min-heap over pages ``[1, num_pages)``, per-page
@@ -183,8 +196,9 @@ class PagedKVPool:
         if self.hybrid:
             from ..models.zoo.hybrid import SLOT_KEYS, dims, pool_shapes
             if kv_dtype is not None or sharding is not None:
-                raise ValueError("a hybrid decoder's pool is bf16 pages on "
-                                 "one device (no kv_dtype, no mesh)")
+                raise ValueError("a hybrid decoder's pool is bf16 pages "
+                                 "(K beside V, or an mla layer's latent "
+                                 "rows) on one device (no kv_dtype, no mesh)")
             _, heads, hd = dims(cfg)
             self._layer_shapes = pool_shapes(
                 cfg, self.num_pages, self.page_size, int(slots),
@@ -654,6 +668,16 @@ class PagedKVPool:
         if gather_bytes:
             self.stats["gather_bytes"] += gather_bytes
             M_GATHER_BYTES.inc(gather_bytes)
+
+    def note_moe(self, counts) -> None:
+        """Account the routing counts of drained decode steps: ``counts``
+        (steps, 6) in ``parallel.moe.MOE_STATS``' order, as the tick carried
+        them out beside its tokens (no device read of their own)."""
+        from ..parallel.moe import MOE_STATS
+        for name, n in zip(MOE_STATS, np.asarray(counts).sum(axis=0)):
+            key = "moe_" + name
+            self.stats[key] = self.stats.get(key, 0) + int(n)
+            M_MOE.inc(int(n), count=name)
 
     def note_grid_steps(self, positions: Sequence[int], window: int,
                         rows: int) -> None:

@@ -19,9 +19,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from mmlspark_tpu.ops.flash_attention import flash_attention
+from mmlspark_tpu.ops.grouped_matmul import TILE, grouped_swiglu
+from mmlspark_tpu.ops.kda_attention import kda_decode_step
 from mmlspark_tpu.ops.lightning_attention import lightning_decode_step
 from mmlspark_tpu.ops.paged_attention import (aligned_page_size,
                                               paged_attention,
+                                              paged_attention_latent,
                                               paged_attention_selected,
                                               paged_attention_window)
 from mmlspark_tpu.ops.pallas_kernels import (level_histogram_pallas,
@@ -325,6 +328,135 @@ def test_hybrid_programs_compile_and_keep_the_pool_in_place(sala_programs,
     names = {"bfloat16": "bf16", "float32": "f32"}
     for layer in pool.buffers:
         for buf in layer.values():
+            shape = f"{names[buf.dtype.name]}[{','.join(map(str, buf.shape))}]"
+            copies = [ln.strip()[:120] for ln in text.splitlines()
+                      if f"= {shape}" in ln and " copy(" in ln]
+            assert not copies, copies
+
+
+# the routed cell (benchmarks/workloads/lingflash_reason_closed32.json):
+# Ling-3.0-flash's share at its published widths, 32 slots of 4,096 positions
+# in the pages the decoder derives (256: 16 a slot)
+LING = dict(slots=32, max_len=4096, page=256, chunk=256, heads=32, hd=128,
+            latent=512, row=640, hidden=2560, width=768, held=128, top=8)
+
+
+def test_kda_step_kernel_compiles_in_place(one_chip):
+    """The delta-rule decode step on 32 slots x 32 heads of (128 x 128)
+    float32 state, the donated state aliased in and out: no copy of it."""
+    z = LING
+    row = one_chip((z["slots"], z["heads"], z["hd"]), jnp.float32)
+    state = one_chip((z["slots"], z["heads"], z["hd"], z["hd"]), jnp.float32)
+    text = jax.jit(
+        functools.partial(kda_decode_step, interpret=False),
+        donate_argnums=(5,)).lower(
+            row, row, row, row, one_chip((z["slots"], z["heads"]),
+                                         jnp.float32),
+            state, one_chip((z["slots"],), bool)).compile().as_text()
+    assert "_kda_step_call" in text and "tpu_custom_call" in text
+    assert " copy(" not in text
+
+
+def test_latent_read_kernel_compiles(one_chip):
+    """32 absorbed query heads a row over one 640-wide latent row a cached
+    position (the first 512 values the value too): the mla layer's tick."""
+    z = LING
+    per = z["max_len"] // z["page"]
+    text = _compiled_text(
+        functools.partial(paged_attention_latent, v_width=z["latent"],
+                          scale=192 ** -0.5, interpret=False),
+        one_chip((z["slots"], z["heads"], z["row"]), jnp.float32),
+        one_chip((1 + z["slots"] * per, 1, z["page"], z["row"]),
+                 jnp.bfloat16),
+        one_chip((z["slots"], per), jnp.int32),
+        one_chip((z["slots"],), jnp.int32))
+    assert "_pa_latent_call" in text
+
+
+@pytest.mark.parametrize("tokens", [32, 256], ids=["tick", "prefill_chunk"])
+def test_grouped_expert_product_compiles(one_chip, tokens):
+    """The routed experts' product over 128 held experts of width 768 at
+    the most tiles a tick's (a chunk's) pairs can fill."""
+    from mmlspark_tpu.parallel.moe import held_tiles
+    z = LING
+    tiles = held_tiles(tokens * z["top"], z["held"], TILE)
+    text = _compiled_text(
+        functools.partial(grouped_swiglu, interpret=False),
+        one_chip((tiles * TILE, z["hidden"]), jnp.bfloat16),
+        one_chip((tiles,), jnp.int32), one_chip((), jnp.int32),
+        one_chip((z["held"], z["hidden"], 2 * z["width"]), jnp.bfloat16),
+        one_chip((z["held"], z["width"], z["hidden"]), jnp.bfloat16))
+    assert "_moe_experts_call" in text
+
+
+@pytest.fixture(scope="module")
+def ling_programs(one_chip):
+    """The routed engine's programs lowered at the cell's shapes (layers 0,
+    10 and 11 of the seven: kda under the dense feed-forward, kda and mla
+    under the routed one), as ``sala_programs`` does it."""
+    import json
+
+    from benchmarks import run as bench_run
+    from mmlspark_tpu.ops import paged_attention as pa
+    from mmlspark_tpu.serving import continuous as progs
+    from mmlspark_tpu.serving.kv_pool import PagedKVPool
+    z = LING
+    with open(os.path.join(bench_run.HERE, "configs",
+                           "ling3_flash_ep4_l7.json")) as fh:
+        config = json.load(fh)
+    config.update(num_hidden_layers=3, layers_held=[0, 10, 11])
+    cfg = bench_run.load_by_path("drivers", "generate_ling").program_config(
+        config, z["max_len"])
+    per = z["max_len"] // z["page"]
+    reference = bench_run.load_by_path("references", config["reference"])
+    params = jax.tree.map(
+        lambda a: one_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: reference.make_weights(config, 0)))
+    pool = PagedKVPool(cfg, page_size=z["page"], residency=False,
+                       make_buffer=one_chip, slots=z["slots"],
+                       slot_positions=z["max_len"],
+                       num_pages=1 + z["slots"] * per + z["slots"])
+    ints = lambda *dims: one_chip(dims, jnp.int32)          # noqa: E731
+    interpret = pa._auto_interpret
+    pa._auto_interpret = progs._pa_auto_interpret = lambda: False
+    try:
+        tick = progs._tick_program(
+            cfg, z["page"], z["max_len"], 1, None, False, True).lower(
+                params, ints(z["slots"]), ints(z["slots"]),
+                one_chip((z["slots"],), bool), pool.buffers,
+                ints(z["slots"], per), ints(z["slots"]))
+        extend = progs._extend_program(cfg, z["page"], z["max_len"], True)
+        chunk = extend.lower(params, ints(1, z["chunk"]), ints(1),
+                             pool.buffers, ints(1, per), ints(), ints(1))
+        yield {"tick": tick.compile().as_text(),
+               "chunk": chunk.compile().as_text()}, pool
+    finally:
+        pa._auto_interpret = progs._pa_auto_interpret = interpret
+        progs._tick_program.cache_clear()
+        progs._extend_program.cache_clear()
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_routed_programs_compile_and_keep_the_pool_in_place(ling_programs,
+                                                            program):
+    """The routed tick and a 256-token prefill chunk at the published widths
+    (32 slots, 4,096 positions): they compile for the chip, the tick holds
+    the three kernels and no sequential loop (a gather of rows), and neither
+    copies a pool-sized buffer: the states, the convolution tails' slots
+    aside (2 MB, laid out by the chip), or the latent pages, whose row is
+    padded to whole registers so that the pool stays row-major."""
+    texts, pool = ling_programs
+    text = texts[program]
+    if program == "tick":
+        for name in ("_moe_experts_call", "_kda_step_call",
+                     "_pa_latent_call"):
+            assert name in text
+        assert " while(" not in text
+    names = {"bfloat16": "bf16", "float32": "f32"}
+    for layer in pool.buffers:
+        for key, buf in layer.items():
+            if key == "conv":
+                continue
             shape = f"{names[buf.dtype.name]}[{','.join(map(str, buf.shape))}]"
             copies = [ln.strip()[:120] for ln in text.splitlines()
                       if f"= {shape}" in ln and " copy(" in ln]

@@ -69,7 +69,7 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     lines, _ = small_run
     phases = {ln["phase"]: ln for ln in lines if "phase" in ln}
     assert sorted(phases) == ["A.transform", "B.decode", "C.train",
-                              "F.hybrid"]
+                              "F.hybrid", "G.routed"]
     for ln in phases.values():
         assert ln["ok"] is True and ln["failed"] == []
         assert ln["small"] is True and ln["platform"] == "cpu"
@@ -83,6 +83,10 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     # tokens the plain float32 reference puts first
     assert phases["F.hybrid"]["attn_ticks_sparse"] > 0
     assert phases["F.hybrid"]["gap_max"] <= chip_smoke.TIE_TOL
+    # G: every tick on the three routed-decoder kernels, nothing dropped
+    assert phases["G.routed"]["attn_ticks_kda"] > 0
+    assert phases["G.routed"]["moe"]["pairs_dropped"] == 0
+    assert phases["G.routed"]["gap_mean"] <= chip_smoke.ROUTED_GAP_MEAN
     assert phases["C.train"]["pallas_histogram_traces"] > 0
     assert (phases["C.train"]["pallas_interpreted"]
             == phases["C.train"]["pallas_histogram_traces"])

@@ -196,6 +196,88 @@ def test_attn_ticks_are_labelled_by_path(hybrid_run, label):
     assert series(after) - series(before) == stats[f"attn_ticks_{label}"]
 
 
+#: what a routed decoder's tick counts of itself (pool ``stats["moe_.."]`` and
+#: ``mmlspark_kvpool_moe_total{count=..}``), and the labels its two mixers'
+#: kernels add to ``mmlspark_kvpool_kernel_ticks_total``
+MOE_COUNTERS = ["pairs_routed", "pairs_held", "pairs_dropped",
+                "pairs_misplaced", "experts_touched", "expert_load_max"]
+ROUTED_TICK_LABELS = ["kda", "latent"]
+
+
+@pytest.fixture(scope="module")
+def routed_run():
+    """A tiny routed decoder (a kda layer under a dense feed-forward, an mla
+    layer under 8 held of 16 routed experts): two requests, one a registered
+    prefix. What the pool and the registry counted."""
+    from mmlspark_tpu import observability as obs
+    from mmlspark_tpu.models.zoo.transformer import (
+        DeltaRule, LatentAttention, RoutedExperts, TransformerConfig,
+        init_transformer)
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    cfg = TransformerConfig(
+        vocab=64, layers=2, d_model=32, heads=2, d_ff=64, max_len=96,
+        causal=True, norm="rmsnorm", position="rope", dtype=jnp.float32,
+        mixers=("kda", "mla"), head_dim=16, ffn=("dense", "moe"),
+        routed=RoutedExperts(experts=16, first=0, count=8, per_token=2,
+                             groups=2, groups_kept=1, scale=2.5, d_expert=16,
+                             d_shared=16),
+        latent=LatentAttention(latent=16, nope=8, rope=8, value=8),
+        kda=DeltaRule())
+    before = obs.snapshot()
+    dec = ContinuousDecoder(init_transformer(cfg, seed=0), cfg, max_slots=2,
+                            max_len=96, page_size=8, prefill_chunk=16)
+    rng = np.random.default_rng(5)
+    doc = rng.integers(1, cfg.vocab, 24).astype(np.int32)
+    reqs = [dec.submit(np.concatenate(
+        [doc, rng.integers(1, cfg.vocab, n).astype(np.int32)]), 5,
+        prefix_key="doc", prefix_len=24) for n in (6, 9)]
+    while not all(r.done for r in reqs):
+        dec.step()
+    assert all(r.error is None for r in reqs)
+    return dec._kv.stats, before, obs.snapshot()
+
+
+@pytest.mark.parametrize("count", MOE_COUNTERS)
+def test_routing_counters_ride_out_with_the_tokens(routed_run, count):
+    stats, before, after = routed_run
+
+    def series(snap):
+        return sum(s["value"] for s in snap.get(
+            "mmlspark_kvpool_moe_total", {}).get("series", ())
+            if s["labels"].get("count") == count)
+    assert series(after) - series(before) == stats[f"moe_{count}"]
+    if count in ("pairs_dropped", "pairs_misplaced"):
+        assert stats[f"moe_{count}"] == 0
+    else:
+        assert stats[f"moe_{count}"] > 0
+    # two experts a token, one routed layer: a decode step of n live rows
+    # routes 2 n pairs, at most 2 rows a step
+    assert stats["moe_pairs_held"] <= stats["moe_pairs_routed"] \
+        <= 2 * 2 * stats["attn_ticks_kda"]
+
+
+@pytest.mark.parametrize("label", ROUTED_TICK_LABELS)
+def test_kda_and_latent_ticks_are_labelled(routed_run, label):
+    stats, before, after = routed_run
+    assert stats[f"attn_ticks_{label}"] \
+        == stats["attn_ticks_kernel"] - stats["prefill_chunks"] > 0
+    assert f"attn_ticks_{label}_window" not in stats
+
+    def series(snap):
+        return sum(s["value"] for s in snap.get(
+            "mmlspark_kvpool_kernel_ticks_total", {}).get("series", ())
+            if s["labels"].get("impl") == label)
+    assert series(after) - series(before) == stats[f"attn_ticks_{label}"]
+
+
+def test_snapshot_bytes_count_the_convolution_tails(routed_run):
+    """One kda layer: 2 heads of 16 x 16 float32 and 3 rows of 3 x 32
+    float32 tails."""
+    stats, *_ = routed_run
+    assert stats["state_snapshot_bytes_stored"] == 2 * 16 * 16 * 4 \
+        + 3 * 96 * 4
+
+
 def test_transform_spans_join_the_request_trace(transform_run):
     _, spans = transform_run
     names = [s.name for s in spans]
