@@ -43,7 +43,44 @@ Design notes (TPU-first):
   initialises and writes out its (meaningless) context. Blocks carry the
   full head dimension — a page block is ``(1, H, page, 2*hd)``, K and V of
   one page in one DMA — so each page is DMA'd ONCE per row per layer, not
-  once per head; the kernel splits the block on the lane axis in VMEM.
+  once per head.
+* WHAT A LIVE STEP COMPUTES ON ITS BLOCK (PR 37). The scores take the keys
+  AS STORED: a bf16 query meets bf16 keys, and the products of two bf16
+  values are exact in the float32 they are summed in, so the scores equal
+  a float32 copy's up to the order of the sum (a float32 query or
+  dequantized keys lift the other side). The weights ``p`` stay float32 and
+  the values are lifted to them: on the chip the MXU rounds both operands
+  to bf16 at its default precision whatever their type (the kernel alone
+  returns the same bits with ``p`` rounded first), and wherever it does not
+  (interpret mode: every test) a rounded ``p`` is another result. A window
+  of several queries folds a product a head: the block split on the lane
+  axis in VMEM, a state row a window row.
+* A ONE-QUERY WINDOW KEEPS ITS STATE A ROW A HEAD. The query block is
+  ``(1, H, Wp, hd)`` with ``Wp`` a register's sublanes (16 for bf16), so a
+  decode tick's one query a head would drag fifteen padding rows through
+  every update of ``m``, ``l`` and the accumulator: 150 registers of state
+  for GPT-2 XL's 25 heads, and THAT was a live step's cost, not its
+  products (PERF.md, PR 37). With ``W == 1`` (static; whatever the dtypes)
+  the state is ``(G, 8, .)``: the heads on the sublane axis, eight a group
+  (``_HEADS``: one float32 register each of ``m``, ``l``, accumulator). A
+  group is then ONE head whose window is its eight queries and whose keys
+  are all eight heads' ``8 * page`` packed rows laid head after head (a
+  free reshape of the block's leading axes) under a block-diagonal mask:
+  query ``h`` sees its own head's keys below the row's bound and weighs
+  every other head's by an exact 0. The packed row is not split there: the
+  query operand is zero over the V lanes (built once a row, at its first
+  step, into a fourth scratch) and ``p . [K | V]`` lands in an accumulator
+  ``2*hd`` wide whose V half is sliced ONCE a row, in ``_finalize``. All of
+  that adds exact zeros as long as ``0 x anything read`` is 0: every lane a
+  step reads is FINITE, because the pool is zero-initialised, only finite
+  rows are ever written, and a page no row needs is never visited (tests:
+  NaN in the unneeded pages, the largest finite bf16 past a row's bound in
+  the pages it needs). The K half of the accumulator is finite garbage
+  nobody reads. Groups are always WHOLE (:func:`_whole_groups`: where ``H``
+  is no multiple of eight the last eight heads are folded once more as the
+  last group), so every product has one shape whatever ``H`` is and a
+  head's context is the same bits whichever heads share the call: a mesh's
+  head shards return what one device does (tests).
 * the physical page for grid step ``s`` comes from the scalar-prefetched
   block table: the BlockSpec index_map reads ``bt[row_of[s], page_of[s]]``
   (``PrefetchScalarGridSpec``), which is exactly the indirection
@@ -53,7 +90,8 @@ Design notes (TPU-first):
   past the row's length are masked by the per-row bound.
 * running ``m``/``l`` live in VMEM scratch shaped ``(H, W, LANE)``
   (lane-replicated, as in ``flash_attention.py``); the f32 context
-  accumulator is ``(H, W, hd)``. Masked logits use ``-1e30`` — a fully
+  accumulator is ``(H, W, hd)`` (a one-query window: ``(G, 8, LANE)`` and
+  ``(G, 8, 2*hd)``). Masked logits use ``-1e30`` — a fully
   masked row yields ``l == 0`` and the final divide guards it to zeros
   rather than NaN.
 * the FUSED variant (:func:`paged_attention_window`) also scatters the
@@ -123,6 +161,11 @@ __all__ = ["paged_attention", "paged_attention_window",
 
 _NEG = -1e30
 
+#: heads a group where a call's window is ONE query: a float32 register's
+#: sublanes, so a group's running max, denominator and accumulator are one
+#: register each (:func:`_window_state`)
+_HEADS = 8
+
 #: env knob — process default for the paged-attention implementation.
 ENV_KNOB = "MMLSPARK_TPU_PAGED_ATTN"
 
@@ -184,10 +227,32 @@ def split_kv(kv):
     return kv[..., :hd], kv[..., hd:]
 
 
-def _fold(m_scr, l_scr, acc_scr, s, valid, v):
+def _scores(q, k, scale):
+    """``q . k^T * scale`` (H, W, K) in float32. The operands meet in the
+    wider of their two dtypes: a bf16 query takes bf16 keys AS STORED (the
+    products of two bf16 values are exact in the float32 they are summed
+    in), a float32 query or dequantized keys lift the other side."""
+    dt = jnp.promote_types(q.dtype, k.dtype)
+    return jax.lax.dot_general(
+        q.astype(dt), k.astype(dt), (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale
+
+
+def _weigh(p, v):
+    """``p . v`` (H, W, value width): the values are lifted to the float32
+    weights (PERF.md, PR 37: rounding ``p`` instead is worth nothing on
+    the chip, where the MXU rounds both anyway and the bits are the same,
+    and is a precision change wherever nothing rounds: interpret mode)."""
+    return jax.lax.dot_general(
+        p, v.astype(p.dtype), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+
+def _fold(m_scr, l_scr, acc_scr, s, valid, weigh):
     """One online-softmax update: fold the score block ``s`` (H, W, K)
-    with key-validity ``valid`` (broadcastable) and values ``v``
-    (H, K, hd) into the running (m, l, acc) VMEM state."""
+    with key-validity ``valid`` (broadcastable) into the running
+    (m, l, acc) VMEM state; ``weigh(p)`` is the weights' product with the
+    keys' values (:func:`_weigh`)."""
     s = jnp.where(valid, s, _NEG)
     m_prev = m_scr[..., 0:1]                           # (H, W, 1)
     l_prev = l_scr[..., 0:1]
@@ -196,41 +261,117 @@ def _fold(m_scr, l_scr, acc_scr, s, valid, v):
     # `valid` (not the _NEG sentinel) zeroes masked probabilities: for a
     # row with every key masked so far, m_new == _NEG and exp(s - m_new)
     # would be exp(0) == 1 on the masked entries.
-    p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)                      # <= 1
     l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)             # (H, W, hd)
-    acc_scr[...] = acc_scr[...] * corr + pv
+    acc_scr[...] = acc_scr[...] * corr + weigh(p)
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
-def _finalize(o_ref, l_scr, acc_scr):
+def _fold_keys(m_scr, l_scr, acc_scr, q, kv, valid, scale, v_width=None):
+    """Fold the keys of packed rows ``kv`` (H, K, .) that ``valid`` allows
+    under the queries ``q`` (H, W, hd), a product a head. The rows split on
+    the lane axis into key and value; with ``v_width`` they are LATENT: the
+    whole row is the key and its first ``v_width`` values the value."""
+    k, v = split_kv(kv) if v_width is None else (kv, kv[..., :v_width])
+    _fold(m_scr, l_scr, acc_scr, _scores(q, k, scale), valid,
+          functools.partial(_weigh, v=v))
+
+
+def _whole_groups(x):
+    """Heads ``(H, ...)`` as WHOLE groups of ``_HEADS``, a list of
+    ``(groups, _HEADS, ...)`` arrays cut from ``x`` without a copy: the
+    heads in order and, where ``H`` is no multiple, the LAST ``_HEADS``
+    heads once more as the last group (fewer heads than a group are padded
+    with zero heads). Every group's products then have one shape whatever
+    ``H`` is, so a head's bits do not depend on the heads beside it (module
+    docstring). :func:`_heads_of` is the way back."""
+    H, part = x.shape[0], x.shape[0] % _HEADS
+    if H < _HEADS:
+        x, H, part = jnp.pad(x, ((0, _HEADS - H),) + ((0, 0),) *
+                             (x.ndim - 1)), _HEADS, 0
+    return [t.reshape(-1, _HEADS, *x.shape[1:])
+            for t in (x[:H - part], x[H - _HEADS:] if part else x[:0])
+            if t.shape[0]]
+
+
+def _heads_of(rows, H):
+    """The ``H`` heads' rows ``(H, ...)`` of a heads-as-rows state
+    ``(G, _HEADS, ...)`` grouped by :func:`_whole_groups`."""
+    rows = rows.reshape(-1, *rows.shape[2:])
+    part = H % _HEADS
+    if H < _HEADS or not part:
+        return rows[:H]
+    return jnp.concatenate([rows[:H - part], rows[rows.shape[0] - part:]])
+
+
+def _heads_query(q_ref, width):
+    """The query operand of a ONE-query window, heads as rows: window row 0
+    of every head of the ``(1, H, Wp, hd)`` block, ``_HEADS`` heads a group
+    on the sublane axis (:func:`_whole_groups`) and zero-padded to the
+    packed row's ``width`` (its lanes past ``hd`` hold V, and meet zeros):
+    ``(G, _HEADS, width)``."""
+    hd = q_ref.shape[3]
+    # grouped in float32, whose register holds _HEADS rows: a free reshape
+    q = jnp.concatenate(_whole_groups(q_ref[0, :, 0, :].astype(jnp.float32)))
+    return jnp.pad(q, ((0, 0), (0, 0), (0, width - hd))).astype(q_ref.dtype)
+
+
+def _fold_heads(m_scr, l_scr, acc_scr, q, kv, n, scale):
+    """Fold the first ``n`` keys of every head of packed rows ``kv``
+    (H, K, 2*hd) into a heads-as-rows state ``(G, _HEADS, .)`` under the
+    query operand ``q`` (:func:`_heads_query`): a group's rows laid head
+    after head are one head's ``_HEADS * K`` keys under a block-diagonal
+    mask, the packed row unsplit (module docstring: exact zeros, as long as
+    every lane read is finite). The groups in order are ONE batched product
+    and the last, overlapping group one more: a product a group in a loop
+    runs group after group on the chip (PERF.md, PR 37)."""
+    K, width = kv.shape[1:]
+    parts, g = [], 0
+    for t in _whole_groups(kv):
+        parts.append((slice(g, g + t.shape[0]),
+                      t.reshape(t.shape[0], _HEADS * K, width)))
+        g += t.shape[0]
+    s = jnp.concatenate([_scores(q[gs], kvg, scale) for gs, kvg in parts])
+    lo = K * jax.lax.broadcasted_iota(jnp.int32, (1, _HEADS, 1), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _HEADS * K), 2)
+    own = (col >= lo) & (col < lo + jnp.clip(n, 0, K))
+    _fold(m_scr, l_scr, acc_scr, s, own, lambda p: jnp.concatenate(
+        [_weigh(p[gs], kvg) for gs, kvg in parts]))
+
+
+def _finalize(o_ref, l_scr, acc_scr, by_head=False):
+    """The context out: the accumulator over the denominator. ``by_head``:
+    the state's rows are the heads and its accumulator a packed row wide
+    (:func:`_fold_heads`); its V half is the context, which every window
+    row of a head takes (row 0 is the query's, the caller slices the
+    padding rows off)."""
     l = l_scr[..., 0:1]
-    o_ref[0] = (acc_scr[...] /
-                jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    ctx = acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
+    if by_head:
+        H, _, hd = o_ref.shape[1:]
+        ctx = jnp.broadcast_to(_heads_of(ctx[..., hd:], H)[:, None, :],
+                               o_ref.shape[1:])
+    o_ref[0] = ctx.astype(o_ref.dtype)
 
 
-def _page_kv(kv_ref, ks_ref=None, vs_ref=None, v_width=None):
-    """One ``(1, H, page, 2*hd)`` page block as float32 ``(k, v)``, each
-    ``(H, page, hd)``. With the page's two ``(1, H, page)`` scale blocks
-    this is the IN-KERNEL dequant: they arrived through the same
-    block-table index_map, so the multiply happens in VMEM right after
-    the page DMA and the quantized bytes are all HBM ever moves. With
-    ``v_width`` the page is a LATENT one: the whole row is the key and its
-    first ``v_width`` values are the value too."""
-    if v_width is None:
-        k, v = split_kv(kv_ref[0])
-    else:
-        k = kv_ref[0]
-        v = k[..., :v_width]
-    k, v = k.astype(jnp.float32), v.astype(jnp.float32)
-    if ks_ref is not None:
-        k = k * ks_ref[0].astype(jnp.float32)[:, :, None]
-        v = v * vs_ref[0].astype(jnp.float32)[:, :, None]
-    return k, v
+def _page_kv(kv_ref, ks_ref=None, vs_ref=None):
+    """One page block's packed rows ``(H, page, .)`` AS STORED: the
+    products take their operands from it (:func:`_scores`, :func:`_weigh`).
+    With the page's two ``(1, H, page)`` scale blocks this is the
+    IN-KERNEL dequant, to float32: they arrived through the same
+    block-table index_map, so the multiply (the K lanes by ``ks``, the V
+    lanes by ``vs``) happens in VMEM right after the page DMA and the
+    quantized bytes are all HBM ever moves."""
+    kv = kv_ref[0]
+    if ks_ref is None:
+        return kv
+    hd = kv.shape[-1] // 2
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 2 * hd), 2)
+    return kv.astype(jnp.float32) * jnp.where(
+        lane < hd, ks_ref[0].astype(jnp.float32)[:, :, None],
+        vs_ref[0].astype(jnp.float32)[:, :, None])
 
 
 def _init(m_scr, l_scr, acc_scr):
@@ -239,36 +380,53 @@ def _init(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _pages_fold(m_scr, l_scr, acc_scr, q_ref, kv, p, bound, scale, page):
+def _pages_fold(m_scr, l_scr, acc_scr, q, kv, p, bound, scale, page,
+                v_width=None):
     """Fold page ``p``'s keys ``p*page ..`` strictly below ``bound``."""
-    k, v = kv
-    q = q_ref[0].astype(jnp.float32)                    # (H, W, hd)
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale     # (H, W, page)
     t = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
-    _fold(m_scr, l_scr, acc_scr, s, t < bound, v)
+    _fold_keys(m_scr, l_scr, acc_scr, q, kv, t < bound, scale, v_width)
 
 
-def _window_fold(m_scr, l_scr, acc_scr, q_ref, kvn_ref, scale, W):
-    """The p == 0 window fold shared by the fused/window kernels: the
-    fresh rows arrive unquantized and packed like a page
-    (``(1, H, Wp, 2*hd)``: direct inputs, not pages), folded under the
-    in-window causal mask."""
-    Wp = q_ref.shape[2]
-    q = q_ref[0].astype(jnp.float32)                    # (H, Wp, hd)
-    kn, vn = split_kv(kvn_ref[0])
-    s = jax.lax.dot_general(
-        q, kn.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale     # (H, Wp, Wp)
-    row = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 2)
-    # query j sees window keys j' <= j; padding key rows never
-    # (padding QUERY rows keep every real key — they need a nonzero
-    # denominator and their output is sliced off host-side)
-    valid = jnp.logical_and(
-        jnp.logical_or(col <= row, row >= W), col < W)
-    _fold(m_scr, l_scr, acc_scr, s, valid, vn.astype(jnp.float32))
+def _window_attend(state, q_scr, q_ref, kvn_ref, page_kv, p, pos, scale,
+                   page, W):
+    """The attention of one grid step of a windowed kernel. A row's first
+    step (p == 0) starts its ``state`` and folds the window's own rows: they
+    arrive unquantized and packed like a page (``(1, H, Wp, 2*hd)``: direct
+    inputs, not pages), folded under the in-window causal mask. A page with
+    keys strictly below ``pos`` folds them; ``page_kv()`` reads its block.
+    A window of ONE query (``W == 1``, static: ``q_scr`` holds its query
+    operand, made once a row) folds heads as rows (:func:`_fold_heads`); its
+    causal mask is its first key."""
+    from jax.experimental import pallas as pl
+
+    by_head = W == 1
+    assert by_head == bool(q_scr)
+
+    @pl.when(p == 0)
+    def _init_and_window():
+        _init(*state)
+        if by_head:
+            q_scr[0][...] = _heads_query(q_ref, q_scr[0].shape[-1])
+            _fold_heads(*state, q_scr[0][...], kvn_ref[0], W, scale)
+        else:
+            Wp = q_ref.shape[2]
+            row = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 1)
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 2)
+            # query j sees window keys j' <= j; padding key rows never
+            # (padding QUERY rows keep every real key — they need a
+            # nonzero denominator and their output is sliced off
+            # host-side)
+            valid = jnp.logical_and(
+                jnp.logical_or(col <= row, row >= W), col < W)
+            _fold_keys(*state, q_ref[0], kvn_ref[0], valid, scale)
+
+    @pl.when(p * page < pos)
+    def _pages():
+        if by_head:
+            _fold_heads(*state, q_scr[0][...], page_kv(), pos - p * page,
+                        scale)
+        else:
+            _pages_fold(*state, q_ref[0], page_kv(), p, pos, scale, page)
 
 
 def _overlay(blk, new_ref, pos, p, page, W, ridx):
@@ -320,9 +478,9 @@ def _pa_read_kernel(row_ref, page_ref, last_ref, bt_ref, len_ref, q_ref,
 
     @pl.when(p * page < bound)
     def _compute():
-        _pages_fold(m_scr, l_scr, acc_scr, q_ref,
-                    _page_kv(kv_ref, *scales, v_width=v_width),
-                    p, bound, scale, page)
+        _pages_fold(m_scr, l_scr, acc_scr, q_ref[0],
+                    _page_kv(kv_ref, *scales), p, bound, scale, page,
+                    v_width)
 
     pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
 
@@ -331,53 +489,38 @@ def _pa_window_kernel(row_ref, page_ref, last_ref, bt_ref, pos_ref, q_ref,
                       kvn_ref, kv_ref, *rest, scale, page, W, quant):
     from jax.experimental import pallas as pl
 
-    scales, (o_ref, m_scr, l_scr, acc_scr) = rest[:2 * quant], rest[2 * quant:]
+    scales, (o_ref, m_scr, l_scr, acc_scr, *q_scr) = (rest[:2 * quant],
+                                                       rest[2 * quant:])
     b, p, last = _step(row_ref, page_ref, last_ref)
-    pos = pos_ref[b]
-
-    @pl.when(p == 0)
-    def _init_and_window():
-        _init(m_scr, l_scr, acc_scr)
-        _window_fold(m_scr, l_scr, acc_scr, q_ref, kvn_ref, scale, W)
-
-    @pl.when(p * page < pos)
-    def _pages():
-        _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref, *scales),
-                    p, pos, scale, page)
-
-    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
+    _window_attend((m_scr, l_scr, acc_scr), q_scr, q_ref, kvn_ref,
+                   lambda: _page_kv(kv_ref, *scales), p, pos_ref[b], scale,
+                   page, W)
+    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr, W == 1))
 
 
 def _pa_fused_kernel(row_ref, page_ref, last_ref, bt_ref, pos_ref, wlo_ref,
                      whi_ref, q_ref, kvn_ref, kv_ref, o_ref, kvo_ref,
-                     m_scr, l_scr, acc_scr, *, scale, page, W):
+                     m_scr, l_scr, acc_scr, *q_scr, scale, page, W):
     from jax.experimental import pallas as pl
 
     b, p, last = _step(row_ref, page_ref, last_ref)
     pos = pos_ref[b]
-
-    @pl.when(p == 0)
-    def _init_and_window():
-        _init(m_scr, l_scr, acc_scr)
-        _window_fold(m_scr, l_scr, acc_scr, q_ref, kvn_ref, scale, W)
-
-    @pl.when(p * page < pos)
-    def _pages():
-        _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref),
-                    p, pos, scale, page)
+    _window_attend((m_scr, l_scr, acc_scr), q_scr, q_ref, kvn_ref,
+                   lambda: _page_kv(kv_ref), p, pos, scale, page, W)
 
     @pl.when(jnp.logical_and(p >= wlo_ref[b], p <= whi_ref[b]))
     def _scatter():
         ridx = jax.lax.broadcasted_iota(jnp.int32, (1, page, 1), 1)
         kvo_ref[0] = _overlay(kv_ref[0], kvn_ref, pos, p, page, W, ridx)
 
-    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
+    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr, W == 1))
 
 
 def _pa_fused_kernel_q(row_ref, page_ref, last_ref, bt_ref, pos_ref, wlo_ref,
                        whi_ref, q_ref, kvn_ref, kvq_ref, ksn_ref, vsn_ref,
                        kv_ref, ks_ref, vs_ref, o_ref, kvo_ref, kso_ref,
-                       vso_ref, m_scr, l_scr, acc_scr, *, scale, page, W):
+                       vso_ref, m_scr, l_scr, acc_scr, *q_scr, scale, page,
+                       W):
     """The quantized fused kernel folds the window's UNQUANTIZED rows
     (``kvn``) and writes their quantized twins (``kvq`` with the per-head
     scales ``ksn``/``vsn``, all through :func:`quantize_kv` in the
@@ -386,16 +529,9 @@ def _pa_fused_kernel_q(row_ref, page_ref, last_ref, bt_ref, pos_ref, wlo_ref,
 
     b, p, last = _step(row_ref, page_ref, last_ref)
     pos = pos_ref[b]
-
-    @pl.when(p == 0)
-    def _init_and_window():
-        _init(m_scr, l_scr, acc_scr)
-        _window_fold(m_scr, l_scr, acc_scr, q_ref, kvn_ref, scale, W)
-
-    @pl.when(p * page < pos)
-    def _pages():
-        _pages_fold(m_scr, l_scr, acc_scr, q_ref,
-                    _page_kv(kv_ref, ks_ref, vs_ref), p, pos, scale, page)
+    _window_attend((m_scr, l_scr, acc_scr), q_scr, q_ref, kvn_ref,
+                   lambda: _page_kv(kv_ref, ks_ref, vs_ref), p, pos, scale,
+                   page, W)
 
     @pl.when(jnp.logical_and(p >= wlo_ref[b], p <= whi_ref[b]))
     def _scatter():
@@ -405,7 +541,7 @@ def _pa_fused_kernel_q(row_ref, page_ref, last_ref, bt_ref, pos_ref, wlo_ref,
         kso_ref[0] = _overlay(ks_ref[0], ksn_ref, pos, p, page, W, sidx)
         vso_ref[0] = _overlay(vs_ref[0], vsn_ref, pos, p, page, W, sidx)
 
-    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
+    pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr, W == 1))
 
 
 def _schedule(bound, whi, page, n_pages):
@@ -433,18 +569,37 @@ def _schedule(bound, whi, page, n_pages):
     return row_of, page_of, n - 1, ends[-1]
 
 
-def _grid_spec(n_scalar, total, in_specs, out_specs, H, Wp, hd):
+def _softmax_state(H, W, acc):
+    """The VMEM scratch of an online softmax over ``(H, W)`` state rows:
+    the running max ``m`` and denominator ``l`` (lane-replicated) and the
+    float32 context accumulator, ``acc`` wide."""
+    return [_vmem((H, W, _LANE), jnp.float32),
+            _vmem((H, W, _LANE), jnp.float32),
+            _vmem((H, W, acc), jnp.float32)]
+
+
+def _window_state(q, W):
+    """The VMEM scratch of a windowed call over queries ``q`` (B, H, Wp, hd)
+    of which ``W`` are real: a state row a window row a head; a window of
+    ONE query (module docstring) a row a HEAD, ``_HEADS`` heads a group, the
+    accumulator a packed row wide, and a fourth scratch for the row's query
+    operand (:func:`_heads_query`). The rule reads ``W`` alone."""
+    _, H, Wp, hd = q.shape
+    if W != 1:
+        return _softmax_state(H, Wp, hd)
+    G = -(-H // _HEADS)             # :func:`_whole_groups`
+    return [*_softmax_state(G, _HEADS, 2 * hd),
+            _vmem((G, _HEADS, 2 * hd), q.dtype)]
+
+
+def _grid_spec(n_scalar, total, in_specs, out_specs, state):
     """``n_scalar`` counts the call's own scalar-prefetch operands; the
-    schedule's three vectors go before them."""
+    schedule's three vectors go before them. ``state`` is the scratch
+    (:func:`_softmax_state`, :func:`_window_state`)."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 + n_scalar, grid=(total,),
-        in_specs=in_specs, out_specs=out_specs,
-        scratch_shapes=[
-            _vmem((H, Wp, _LANE), jnp.float32),   # running max m
-            _vmem((H, Wp, _LANE), jnp.float32),   # running denominator l
-            _vmem((H, Wp, hd), jnp.float32),      # f32 context accumulator
-        ])
+        in_specs=in_specs, out_specs=out_specs, scratch_shapes=state)
 
 
 #: scoped-VMEM ceiling handed to Mosaic. The compiler's default (16 MiB on
@@ -526,7 +681,8 @@ def _pa_read_call(q, kv_pages, block_tables, lengths, *scales,
     call = pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(2, total, in_specs=[row, pages, *scale_specs],
-                             out_specs=row, H=H, Wp=Wp, hd=hd),
+                             out_specs=row,
+                             state=_softmax_state(H, Wp, hd)),
         out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
@@ -556,7 +712,7 @@ def _pa_latent_call(q, kv_pages, block_tables, lengths, *, v_width, scale,
             in_specs=[pl.BlockSpec((1, 1, Hq, dk), _row_map),
                       pl.BlockSpec((1, 1, page, dk), _page_map)],
             out_specs=pl.BlockSpec((1, 1, Hq, v_width), _row_map),
-            H=1, Wp=Hq, hd=v_width),
+            state=_softmax_state(1, Hq, v_width)),
         out_shape=jax.ShapeDtypeStruct((B, 1, Hq, v_width), q.dtype),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
@@ -580,7 +736,7 @@ def _pa_window_read_call(q, kv_new, kv_pages, block_tables, pos, *scales,
         kernel,
         grid_spec=_grid_spec(2, total,
                              in_specs=[row, new, pages, *scale_specs],
-                             out_specs=row, H=H, Wp=Wp, hd=hd),
+                             out_specs=row, state=_window_state(q, W)),
         out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
@@ -610,7 +766,7 @@ def _pa_fused_call(q, kv_new, kv_pages, block_tables, pos, wlo, whi, *,
         grid_spec=_grid_spec(
             4, total, in_specs=[row, new, pages],
             out_specs=[row, pl.BlockSpec((1, H, page, 2 * hd), _write_map)],
-            H=H, Wp=Wp, hd=hd),
+            state=_window_state(q, W)),
         out_shape=[jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
                    jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype)],
         # operand indices COUNT the 7 scalar-prefetch args: the pool is
@@ -646,7 +802,7 @@ def _pa_fused_call_q(q, kv_new, kvq_new, ks_new, vs_new, kv_pages, k_scale,
             in_specs=[row, new, new, srow, srow, pages, *scale_specs],
             out_specs=[row, pl.BlockSpec((1, H, page, 2 * hd), _write_map),
                        swrite, swrite],
-            H=H, Wp=Wp, hd=hd),
+            state=_window_state(q, W)),
         out_shape=[jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
                    jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype),
                    scale_shape, scale_shape],
@@ -684,7 +840,7 @@ def _pa_select_kernel(bt_ref, sel_ref, len_ref, q_ref, kv_ref, o_ref,
     @pl.when(lp >= 0)
     def _compute():
         # the blocks are one head's: H = 1, the window the hg query heads
-        _pages_fold(m_scr, l_scr, acc_scr, q_ref, _page_kv(kv_ref),
+        _pages_fold(m_scr, l_scr, acc_scr, q_ref[0], _page_kv(kv_ref),
                     lp, len_ref[b], scale, page)
 
     pl.when(j == n_sel - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
@@ -711,9 +867,7 @@ def _pa_select_call(q, kv_pages, block_tables, sel, lengths, *,
             num_scalar_prefetch=3, grid=(B, G, n_sel),
             in_specs=[row, pl.BlockSpec((1, 1, page, 2 * hd), page_of)],
             out_specs=row,
-            scratch_shapes=[_vmem((1, hg, _LANE), jnp.float32),
-                            _vmem((1, hg, _LANE), jnp.float32),
-                            _vmem((1, hg, hd), jnp.float32)]),
+            scratch_shapes=_softmax_state(1, hg, hd)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3,

@@ -128,26 +128,32 @@ def test_paged_fused_window_kernel_compiles(one_chip, kv, rows, window, page):
     _compiled_text(fused, row, row, row, pages, bt, pos, active, *scales)
 
 
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("rows,window", [(8, 1), (1, 256)],
                          ids=["tick", "prefill_chunk"])
-def test_gpt2xl_fused_window_kernel_compiles(one_chip, rows, window):
+def test_gpt2xl_fused_window_kernel_compiles(one_chip, rows, window, kv):
     """The generation cell's own shape (benchmarks/workloads/
     gpt2xl_generate_closed.json): 8 slots, 25 heads of 64, pages of 64, 16 a
     slot; the ragged sweep's bound is traced, so one program serves every
-    batch of contexts."""
+    batch of contexts. Under ``_VMEM_LIMIT_BYTES`` (the compiler refuses a
+    kernel over it) over bf16 and int8 pages: the tick's state is a row a
+    head with an accumulator a packed row wide, the chunk's a row a
+    query."""
     heads, page, per_row = 25, 64, 16
     row = one_chip((rows, heads, window, HD), jnp.bfloat16)
+    n_pages = 1 + 8 * per_row + per_row
+    scales = ([one_chip((n_pages, heads, page), jnp.bfloat16)] * 2
+              if kv == "int8" else [])
 
-    def fused(q, kn, vn, kvp, bt, pos, active):
+    def fused(q, kn, vn, kvp, bt, pos, active, *scales):
         return paged_attention_window(q, kn, vn, kvp, bt, pos, active=active,
-                                      interpret=False)
+                                      interpret=False, **_scale_kw(scales))
 
     text = _compiled_text(
         fused, row, row, row,
-        one_chip((1 + 8 * per_row + per_row, heads, page, 2 * HD),
-                 jnp.bfloat16),
+        one_chip((n_pages, heads, page, 2 * HD), STORED[kv]),
         one_chip((rows, per_row), jnp.int32), one_chip((rows,), jnp.int32),
-        one_chip((rows,), jnp.bool_))
+        one_chip((rows,), jnp.bool_), *scales)
     assert "_pa_fused_call" in text         # the name the benchmark's trace finds
 
 

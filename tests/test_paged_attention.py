@@ -37,9 +37,11 @@ from mmlspark_tpu.models.zoo.transformer import (
 from mmlspark_tpu.ops.compile_cache import jit_cache_size
 from mmlspark_tpu.ops.kv_quant import SCALE_DTYPE, quantize_kv
 from mmlspark_tpu.ops.paged_attention import (
-    ENV_KNOB, _fused_schedule, _pool_write_rows, _schedule,
-    aligned_page_size, pack_kv, paged_attention, paged_attention_window,
-    split_kv, resolve_impl, sublane_multiple)
+    ENV_KNOB, _HEADS, _fused_schedule, _heads_of, _heads_query,
+    _pa_window_read_call, _pool_write_rows, _schedule, _scores,
+    _whole_groups, aligned_page_size, pack_kv, paged_attention,
+    paged_attention_latent, paged_attention_selected,
+    paged_attention_window, split_kv, resolve_impl, sublane_multiple)
 from mmlspark_tpu.serving.continuous import ContinuousDecoder
 
 CFG = TransformerConfig(vocab=128, layers=2, d_model=64, heads=4, d_ff=128,
@@ -455,6 +457,189 @@ class TestRaggedSweep:
             want = np.einsum("hk,hkd->hd", p / p.sum(-1, keepdims=True), vc)
             np.testing.assert_allclose(got[b, :, 0], want, rtol=2e-2,
                                        atol=2e-2)
+
+
+# the five paged mounts over bf16 pages: (heads, window). A one-query window
+# keeps its state a row a head, _HEADS a group: 11 heads are a whole group
+# and the last eight once more
+OPERAND_CASES = {"read": (3, 1), "window_w1": (11, 1), "window_w5": (3, 5),
+                 "fused_w1": (11, 1), "fused_w5": (3, 5), "select": (2, 1),
+                 "latent": (1, 1)}
+
+
+class TestOperandRule:
+    """What a live grid step computes on its page block (PR 37): the keys
+    enter the scores in the page's dtype, the values are lifted to the
+    float32 weights; a one-query window folds the whole packed row, heads
+    as rows (the query zero over the V lanes, an accumulator ``2*hd`` wide
+    whose V half is sliced once a row)."""
+
+    PAGE, P, HD = 8, 4, 16
+    # the context's own rounding to bf16 (2**-8 of values up to ~2)
+    TOL = 1e-2
+
+    def _pool(self, rng, B, H, width, huge_after=None):
+        """bf16 pages, rows' pages interleaved, page 0 the trash page. With
+        ``huge_after`` (B,) every position at or past a row's bound holds
+        the largest finite bf16, +-: in the pages a row NEEDS, where a
+        masked key's weight is an exact 0 and its K and V lanes still
+        reach both products."""
+        page, P = self.PAGE, self.P
+        bt = 1 + np.arange(P)[None, :] * B + np.arange(B)[:, None]
+        pool = rng.normal(0, 1, (1 + B * P, H, page, width))
+        if huge_after is not None:
+            big = float(jnp.finfo(jnp.bfloat16).max)
+            for b in range(B):
+                for t in range(int(huge_after[b]), P * page):
+                    pool[bt[b, t // page], :, t % page] = big * (-1) ** t
+        return jnp.asarray(pool, jnp.bfloat16), jnp.asarray(bt, jnp.int32)
+
+    @staticmethod
+    def _oracle(q, k, v, scale, causal=True):
+        """float32 softmax attention of (H, W, d) over (H, n, d), (H, n, dv);
+        under ``causal`` query j of W sees all but the last W - 1 - j keys
+        (a window's own rows are the last W), else every query every key."""
+        W, n = q.shape[1], k.shape[1]
+        if not n:
+            return np.zeros(q.shape[:2] + v.shape[2:], np.float32)
+        s = jnp.einsum("hwd,hkd->hwk", q, k) * scale
+        ok = jnp.arange(n)[None, :] <= (n - W + jnp.arange(W))[:, None]
+        p = jax.nn.softmax(jnp.where(ok | (not causal), s, -jnp.inf), axis=-1)
+        return jnp.einsum("hwk,hkd->hwd", p, v)
+
+    def _run(self, kind, huge):
+        H, W = OPERAND_CASES[kind]
+        page, P, hd = self.PAGE, self.P, self.HD
+        rng = np.random.default_rng(sum(map(ord, kind)))
+        pos = np.asarray([0, 3, 8, 21, 26])      # empty, mid, boundary, ...
+        B = len(pos)
+        f32 = lambda t: np.asarray(jnp.asarray(t).astype(jnp.float32))  # noqa: E731
+        cached = lambda pool, bt, b, n: f32(pool)[np.asarray(bt)[b]].transpose(  # noqa: E731
+            1, 0, 2, 3).reshape(pool.shape[1], -1, pool.shape[3])[:, :n]
+        if kind == "latent":
+            dk, vw, Hq = 48, 32, 8
+            pool, bt = self._pool(rng, B, 1, dk, pos if huge else None)
+            q = jnp.asarray(rng.normal(0, 1, (B, Hq, dk)), jnp.float32)
+            got = paged_attention_latent(q, pool, bt, jnp.asarray(pos),
+                                         v_width=vw, scale=dk ** -0.5,
+                                         interpret=True)
+            want = [self._oracle(
+                f32(q)[b][None], cached(pool, bt, b, n),
+                cached(pool, bt, b, n)[..., :vw], dk ** -0.5, False)[0]
+                for b, n in enumerate(pos)]
+            return f32(got), want
+        pool, bt = self._pool(rng, B, H, 2 * hd, pos + (W if kind.startswith(
+            ("window", "fused")) else 0) if huge else None)
+        scale = hd ** -0.5
+        if kind == "select":
+            hg = 4
+            q = jnp.asarray(rng.normal(0, 1, (B, H, hg, hd)), jnp.bfloat16)
+            # every page, in another order, and one entry that is no page
+            sel = np.tile(np.asarray([2, -1, 0, 3, 1]), (B, H, 1))
+            got = paged_attention_selected(q, pool, bt, jnp.asarray(sel),
+                                           jnp.asarray(pos), interpret=True)
+            return f32(got), [
+                self._oracle(f32(q)[b], *split_kv(cached(pool, bt, b, n)),
+                             scale, False) for b, n in enumerate(pos)]
+        q, kn, vn = (jnp.asarray(rng.normal(0, 1, (B, H, W, hd)),
+                                 jnp.bfloat16) for _ in range(3))
+        if kind == "read":
+            got = paged_attention(q, pool, bt, jnp.asarray(pos),
+                                  interpret=True)
+            new = np.zeros((B, H, 0, 2 * hd), np.float32)
+        elif kind.startswith("window"):
+            Wp = -(-W // 16) * 16
+            padw = lambda t: jnp.pad(t, ((0, 0), (0, 0), (0, Wp - W), (0, 0)))  # noqa: E731
+            got = _pa_window_read_call(
+                padw(q), padw(pack_kv(kn, vn)), pool, bt,
+                jnp.asarray(pos, jnp.int32), W=W, scale=scale,
+                interpret=True)[:, :, :W]
+            new = f32(pack_kv(kn, vn))
+        else:
+            got, _ = paged_attention_window(q, kn, vn, pool, bt,
+                                            jnp.asarray(pos), interpret=True)
+            new = f32(pack_kv(kn, vn))
+        return f32(got), [
+            self._oracle(f32(q)[b], *split_kv(np.concatenate(
+                [cached(pool, bt, b, n), new[b]], axis=1)), scale)
+            for b, n in enumerate(pos)]
+
+    @pytest.mark.parametrize("huge", [False, True],
+                             ids=["plain", "huge_past_the_bound"])
+    @pytest.mark.parametrize("kind", list(OPERAND_CASES))
+    def test_bf16_pages_match_the_float32_oracle(self, kind, huge):
+        """Every mount over bf16 pages against float32 softmax attention of
+        the same values, at the two roundings' tolerance; with the positions
+        past each row's bound, in pages it needs, at the largest finite
+        bf16: a masked key's weight is an exact 0 and, under a one-query
+        window, the zero half of the query meets its V lanes and the wide
+        accumulator its K lanes, and none of it may reach the context."""
+        got, want = self._run(kind, huge)
+        assert np.all(np.isfinite(got))
+        for g, w in zip(got, want):         # a row with no key yields zeros
+            np.testing.assert_allclose(g, np.asarray(w), rtol=self.TOL,
+                                       atol=self.TOL)
+
+    @pytest.mark.parametrize("heads", [3, 8, 11, 25])
+    def test_scores_of_bf16_operands_are_the_float32_copies_scores(
+            self, heads):
+        """Rung 1 is no precision change: bf16 x bf16 products are exact in
+        float32, so the scores of the operands as stored equal those of
+        float32 copies of the same values up to the order of a float32
+        sum. And the heads-as-rows query operand is window row 0 of every
+        head, whole groups of ``_HEADS`` (the last eight heads once more
+        where the count is no multiple, zero heads under eight), zero over
+        the V lanes; ``_heads_of`` is the way back."""
+        Wp, hd, K = 16, 16, 24
+        rng = np.random.default_rng(5)
+        q = jnp.asarray(rng.normal(0, 1, (1, heads, Wp, hd)), jnp.bfloat16)
+        qo = _heads_query(q, 2 * hd)
+        assert qo.shape == (-(-heads // _HEADS), _HEADS, 2 * hd)
+        assert qo.dtype == jnp.bfloat16
+        rows = np.asarray(_heads_of(qo, heads).astype(jnp.float32))
+        assert np.array_equal(rows[:, :hd],
+                              np.asarray(q.astype(jnp.float32))[0, :, 0])
+        assert not np.asarray(qo.astype(jnp.float32))[..., hd:].any()
+        groups = _whole_groups(jnp.arange(heads))
+        assert all(g.shape[1] == _HEADS for g in groups)
+        assert np.array_equal(
+            np.asarray(_heads_of(jnp.concatenate(groups), heads)),
+            np.arange(heads))
+        kv = jnp.asarray(rng.normal(0, 1, (qo.shape[0], K, 2 * hd)),
+                         jnp.bfloat16)
+        got = _scores(qo, kv, hd ** -0.5)
+        want = _scores(qo.astype(jnp.float32), kv.astype(jnp.float32),
+                       hd ** -0.5)
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16", "f32"])
+    @pytest.mark.parametrize("heads,cut", [(4, 2), (16, 8), (12, 6),
+                                           (25, 12)])
+    def test_a_heads_context_does_not_depend_on_the_heads_beside_it(
+            self, heads, cut, dtype):
+        """A one-query window folds eight heads a group, and a mesh mounts
+        the kernel a shard of the heads a device: the heads' contexts are
+        the same BITS whichever heads share the call (every group's
+        products have one shape, what crosses heads is an exact 0), so a
+        tensor-parallel engine's attention is one device's."""
+        page, P, hd, B = self.PAGE, self.P, self.HD, 3
+        rng = np.random.default_rng(heads)
+        pool, bt = self._pool(rng, B, heads, 2 * hd)
+        pool = pool.astype(dtype)
+        q, kn, vn = (jnp.asarray(rng.normal(0, 1, (B, heads, 1, hd)), dtype)
+                     for _ in range(3))
+        pos = jnp.asarray([5, 17, 30])
+        run = lambda hs: paged_attention_window(  # noqa: E731
+            q[:, hs], kn[:, hs], vn[:, hs], pool[:, hs], bt, pos,
+            interpret=True)[0]
+        whole = run(slice(None))
+        shards = jnp.concatenate([run(slice(0, cut)),
+                                  run(slice(cut, heads))], axis=1)
+        assert np.array_equal(np.asarray(whole.astype(jnp.float32)),
+                              np.asarray(shards.astype(jnp.float32)))
 
 
 class TestDecodeParity:
