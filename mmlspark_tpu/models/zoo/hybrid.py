@@ -54,6 +54,22 @@ a delta-rule linear attention and latent attention; ``cfg.kda``,
 * feed-forward ``"moe"`` - ``parallel.moe.moe_topk_held``: top-k dropless
   routing over all experts, the held experts' part of the result, a shared
   expert.
+
+And two mixers that end in ``W_o`` alone, no output gate (a decoder of gated
+short convolutions beside grouped-query attention; ``cfg.conv``):
+
+* ``conv`` - ``[B, C, u] = W_in x`` (thirds of ``3 d_model``), ``z = B * u``,
+  ``c_t = sum_j w_j z_(t - taps + 1 + j)`` (depthwise, causal, ``cfg.conv.taps``
+  taps a channel, zeros before the start, no activation), ``y = W_o(C *
+  c)``. The whole cache is ``{"conv"}``: the last ``taps - 1`` rows of ``z``,
+  a row a slot. No kernel: the taps fuse beside the two projections.
+* ``gqa`` - full softmax attention, ``heads`` queries over ``kv_heads`` keys
+  and values, q and k RMS-normed per head (``cfg.norm_eps``) and rotated
+  (rotate-half RoPE over the whole head), pages ``{"kv"}`` (pages, Hkv, page,
+  2 hd) as a sparse layer's and nothing a slot. A window scatters its K/V
+  through the block table and attends its row's gathered pages under the
+  causal mask; the decode tick is ONE fused launch,
+  ``ops.paged_attention.paged_attention_gqa``.
 """
 
 from __future__ import annotations
@@ -80,6 +96,8 @@ _NEG = -1e30
 #: layer's convolution tails. A cached prefix keeps a snapshot of these rows
 #: beside its pages.
 SLOT_KEYS = ("state", "ck", "conv")
+#: the mixer kinds ``cfg.mixers`` may name
+MIXERS = ("lightning", "sparse", "kda", "mla", "conv", "gqa")
 #: tokens a step of the chunked delta rule (:func:`kda_chunk`)
 KDA_CHUNK = 64
 #: keys of K/V a masked window folds at a time (a 32k context in one piece
@@ -96,10 +114,10 @@ def dims(cfg: TransformerConfig):
 def check_config(cfg: TransformerConfig) -> None:
     if len(cfg.mixers) != cfg.layers:
         raise ValueError(f"{len(cfg.mixers)} mixers for {cfg.layers} layers")
-    unknown = set(cfg.mixers) - {"lightning", "sparse", "kda", "mla"}
+    unknown = set(cfg.mixers) - set(MIXERS)
     if unknown:
         raise ValueError(f"unknown mixer kinds {sorted(unknown)} "
-                         "(lightning | sparse | kda | mla)")
+                         f"({' | '.join(MIXERS)})")
     if not cfg.causal or cfg.moe_experts or cfg.use_flash:
         raise ValueError("a hybrid decoder is causal, takes its routed "
                          "feed-forward from cfg.ffn / cfg.routed (not "
@@ -127,6 +145,9 @@ def check_config(cfg: TransformerConfig) -> None:
                     "clamp, whose form is not built (only limit 0)")
     if "kda" in cfg.mixers and cfg.kda is None:
         raise ValueError("kda layers need cfg.kda")
+    if "conv" in cfg.mixers and (cfg.conv is None or cfg.conv.taps < 2):
+        raise ValueError("conv layers need cfg.conv (taps >= 2: the cache "
+                         "is the taps - 1 rows before a token)")
     if "mla" in cfg.mixers:
         if cfg.latent is None:
             raise ValueError("mla layers need cfg.latent")
@@ -135,6 +156,9 @@ def check_config(cfg: TransformerConfig) -> None:
     H, Hkv, hd = dims(cfg)
     if H % Hkv or hd % 2:
         raise ValueError(f"heads {H} / kv_heads {Hkv} / head_dim {hd}")
+    if "gqa" in cfg.mixers and H // Hkv not in (1, 2, 4, 8):
+        raise ValueError(f"gqa layers: {H} heads over {Hkv} KV heads (the "
+                         "decode kernel folds 1, 2, 4 or 8 queries a KV head)")
     if "sparse" in cfg.mixers:
         sp = cfg.sparse
         if sp is None:
@@ -209,6 +233,15 @@ def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
             lp.update(kda_layer())
         elif kind == "mla":
             lp.update(mla_layer())
+        elif kind == "conv":
+            K = cfg.conv.taps
+            lp.update({"in": dense(D, 3 * D), "o": dense(D, D),
+                       "taps": rng.normal(0, K ** -0.5, (K, D)).astype(
+                           np.float32)})
+        elif kind == "gqa":
+            lp.update({"q": dense(D, H * hd), "k": dense(D, Hkv * hd),
+                       "v": dense(D, Hkv * hd), "o": dense(H * hd, D),
+                       "q_norm": ones(hd), "k_norm": ones(hd)})
         else:
             kv = H if kind == "lightning" else Hkv
             lp.update({"q": dense(D, H * hd), "k": dense(D, kv * hd),
@@ -243,9 +276,12 @@ def latent_row(cfg) -> int:
     return _round_up(cfg.latent.latent + cfg.latent.rope, 128)
 
 
-def _conv_shape(cfg, rows: int):
-    """A kda layer's convolution tails: the last ``conv_kernel - 1``
-    pre-convolution rows of q, k and v side by side, a row a slot."""
+def _conv_shape(cfg, kind: str, rows: int):
+    """A layer's convolution tails, a row a slot: a kda layer's last
+    ``conv_kernel - 1`` pre-convolution rows of q, k and v side by side, a
+    conv layer's last ``taps - 1`` rows of ``z``."""
+    if kind == "conv":
+        return (rows, cfg.conv.taps - 1, cfg.d_model)
     H, _, hd = dims(cfg)
     return (rows, cfg.kda.conv_kernel - 1, 3 * H * hd)
 
@@ -256,7 +292,8 @@ def init_hybrid_cache(cfg: TransformerConfig, batch: int, max_len: int):
     ``{"k", "v"}`` (B, Hkv, L, hd) and the compressed keys ``{"ck"}``
     (B, Hkv, L / stride, hd) for a sparse one, ``L`` being ``max_len``
     rounded up to whole blocks; ``{"kv"}`` (B, 1, max_len,
-    :func:`latent_row`) latent rows for an mla layer."""
+    :func:`latent_row`) latent rows for an mla layer; ``{"conv"}`` alone for
+    a conv layer; ``{"k", "v"}`` (B, Hkv, max_len, hd) for a gqa layer."""
     H, Hkv, hd = dims(cfg)
     out = []
     for kind in cfg.mixers:
@@ -264,8 +301,14 @@ def init_hybrid_cache(cfg: TransformerConfig, batch: int, max_len: int):
             out.append({"state": jnp.zeros((batch, H, hd, hd), F32)})
         elif kind == "kda":
             out.append({"state": jnp.zeros((batch, H, hd, hd), F32),
-                        "conv": jnp.zeros(_conv_shape(cfg, batch),
+                        "conv": jnp.zeros(_conv_shape(cfg, kind, batch),
                                           cfg.dtype)})
+        elif kind == "conv":
+            out.append({"conv": jnp.zeros(_conv_shape(cfg, kind, batch),
+                                          cfg.dtype)})
+        elif kind == "gqa":
+            kv = jnp.zeros((batch, Hkv, max_len, hd), cfg.dtype)
+            out.append({"k": kv, "v": kv})
         elif kind == "mla":
             out.append({"kv": jnp.zeros(
                 (batch, 1, max_len, latent_row(cfg)), cfg.dtype)})
@@ -285,7 +328,10 @@ def pool_shapes(cfg: TransformerConfig, num_pages: int, page_size: int,
     ``positions`` positions) for a sparse layer, one state row a slot for a
     lightning layer; a kda layer adds its convolution tails a slot; an mla
     layer holds latent pages ``(pages, 1, page, latent_row)``: one row a
-    token, nothing a head."""
+    token, nothing a head; a conv layer its tails a slot and nothing else; a
+    gqa layer pages and nothing a slot. What a layer holds is read from
+    these keys (``kv``: pages; :data:`SLOT_KEYS`: rows a slot), not from its
+    mixer's name."""
     H, Hkv, hd = dims(cfg)
     out = []
     for kind in cfg.mixers:
@@ -293,7 +339,12 @@ def pool_shapes(cfg: TransformerConfig, num_pages: int, page_size: int,
             out.append({"state": ((slots, H, hd, hd), F32)})
         elif kind == "kda":
             out.append({"state": ((slots, H, hd, hd), F32),
-                        "conv": (_conv_shape(cfg, slots), cfg.dtype)})
+                        "conv": (_conv_shape(cfg, kind, slots), cfg.dtype)})
+        elif kind == "conv":
+            out.append({"conv": (_conv_shape(cfg, kind, slots), cfg.dtype)})
+        elif kind == "gqa":
+            out.append({"kv": ((num_pages, Hkv, page_size, 2 * hd),
+                               cfg.dtype)})
         elif kind == "mla":
             out.append({"kv": ((num_pages, 1, page_size, latent_row(cfg)),
                         cfg.dtype)})
@@ -519,6 +570,37 @@ def _masked_attention(q, k, v, allowed, t_max):
     return out.reshape(B, Hq, W, dv)
 
 
+def _put(buf, val, idx):
+    """One row's ``(heads, L, .) <- (heads, W, .)`` at positions ``idx``; an
+    index of ``L`` or more is dropped."""
+    return buf.at[:, idx].set(val, mode="drop")
+
+
+def _put_window(c, k, v, wpos, n_valid):
+    """A contiguous cache's ``k`` and ``v`` with the window's real lanes
+    written at ``wpos`` (padding lanes are dropped)."""
+    W, L = k.shape[2], c["k"].shape[2]
+    dest = jnp.where(jnp.arange(W)[None] < n_valid[:, None], wpos, L)
+    return (jax.vmap(_put)(c["k"], k, dest), jax.vmap(_put)(c["v"], v, dest))
+
+
+def _scatter_pages(pool, k, v, bt, wpos, n_valid, page):
+    """The page pool with a window's K/V rows ``(B, Hkv, W, hd)`` written
+    through the block table (padding lanes and idle rows to trash page 0).
+    Every index names (page, head, offset) and the window is the minor axis
+    alone: a scatter over the page and offset axes with the heads sliced
+    makes the chip lay the whole pool out anew around it."""
+    from ...ops.paged_attention import pack_kv
+    B, Hkv, W, hd = k.shape
+    lane_ok = jnp.arange(W)[None] < n_valid[:, None]
+    pg = jnp.take_along_axis(
+        bt, jnp.clip(wpos // page, 0, bt.shape[1] - 1), axis=1)
+    rows = pack_kv(k, v).transpose(0, 2, 1, 3).reshape(B * W, Hkv, 2 * hd)
+    return pool.at[jnp.where(lane_ok, pg, 0).reshape(-1, 1),
+                   jnp.arange(Hkv)[None],
+                   (wpos % page).reshape(-1, 1)].set(rows)
+
+
 def _sparse_contiguous(lp, x, wpos, pos, n_valid, c, cfg):
     """A sparse layer over a contiguous cache: write the window's K/V and
     the compressed keys it completes, select, attend under the mask."""
@@ -527,21 +609,15 @@ def _sparse_contiguous(lp, x, wpos, pos, n_valid, c, cfg):
     W = x.shape[1]
     q, k, v = _sparse_qkv(lp, x, cfg)
     L = c["k"].shape[2]
-    lane_ok = jnp.arange(W)[None] < n_valid[:, None]
-    dest = jnp.where(lane_ok, wpos, L)          # padding lanes are dropped
-
-    def put(buf, val, idx):                     # (Hkv, L, hd) <- (Hkv, W, hd)
-        return buf.at[:, idx].set(val, mode="drop")
-
-    kc = jax.vmap(put)(c["k"], k, dest)
-    vc = jax.vmap(put)(c["v"], v, dest)
+    kc, vc = _put_window(c, k, v, wpos, n_valid)
+    put = jax.vmap(_put)
     e, ok = _ck_windows(pos, n_valid, W, sp)
     src = jnp.clip(e[..., None] - (ks - 1) + jnp.arange(ks), 0, L - 1)
     rows = jax.vmap(lambda kb, ib: kb[:, ib])(kc, src)   # (B,Hkv,n,ks,hd)
     means = rows.astype(F32).mean(axis=3).astype(cfg.dtype)
     Fn = c["ck"].shape[2]
     f = jnp.where(ok, (e + 1) // s - 1, Fn)
-    ck = jax.vmap(put)(c["ck"], means, f)
+    ck = put(c["ck"], means, f)
     idx, sel_ok = sparse_select(q, ck, wpos, sp)
     allowed = _allowed_keys(idx, sel_ok, wpos, sp, L)
     o = _masked_attention(q, kc, vc, allowed, jnp.max(wpos))
@@ -557,27 +633,15 @@ def _sparse_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel,
     decode tick (``kernel``: one query a row) hands the selected blocks to
     the Pallas kernel, which reads them in place; a window gathers its
     row's pages and masks."""
-    from ...ops.paged_attention import pack_kv, split_kv
+    from ...ops.paged_attention import split_kv
     sp = cfg.sparse
     s, ks = sp.kernel_stride, sp.kernel_size
     B, W, _ = x.shape
     H, Hkv, hd = dims(cfg)
     P = bt.shape[1]
     q, k, v = _sparse_qkv(lp, x, cfg)
-    lane_ok = jnp.arange(W)[None] < n_valid[:, None]
-
-    def phys(positions, okay):
-        pg = jnp.take_along_axis(bt, jnp.clip(positions // page, 0, P - 1),
-                                 axis=1)
-        return jnp.where(okay, pg, 0)
-
-    # every index names (page, head, offset) and the window is the minor
-    # axis alone: a scatter over the page and offset axes with the heads
-    # sliced makes the chip lay the whole pool out anew around it
     heads_ = jnp.arange(Hkv)[None]
-    rows = pack_kv(k, v).transpose(0, 2, 1, 3).reshape(B * W, Hkv, 2 * hd)
-    kv = c["kv"].at[phys(wpos, lane_ok).reshape(-1, 1), heads_,
-                    (wpos % page).reshape(-1, 1)].set(rows)
+    kv = _scatter_pages(c["kv"], k, v, bt, wpos, n_valid, page)
     # the compressed keys this write completes. Their keys lie in the few
     # pages over [pos - ks + 1, pos + W + s): whole pages gathered, one
     # slice a row, sums by stride (a gather row by row is a sequential loop
@@ -586,7 +650,8 @@ def _sparse_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel,
     n, m = e.shape[1], ks // s
     n_pg = -(-(page + W + ks + s) // page)
     first = jnp.maximum(pos - ks + 1, 0) // page
-    near = kv[phys((first[:, None] + jnp.arange(n_pg)) * page, True)]
+    near = kv[jnp.take_along_axis(
+        bt, jnp.clip(first[:, None] + jnp.arange(n_pg), 0, P - 1), axis=1)]
     near = near[..., :hd].transpose(0, 2, 1, 3, 4).reshape(
         B, Hkv, n_pg * page, hd)
     start = e[:, 0] - (ks - 1) - first * page
@@ -909,12 +974,98 @@ def _mla_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel):
     return _head_gated_out(lp, x, o, cfg, norm=False), {"kv": kv}
 
 
+# ---- conv and gqa: the mixers that end in W_o alone ---------------------------
+
+def _conv_layer(lp, x, tail, pos, n_valid, cfg, tick):
+    """A gated short convolution over a window ``x`` (B, W, D) continuing the
+    tails ``tail`` (B, taps - 1, D), the rows of ``z = B * u`` before the
+    window (zeroed for a window that starts at position 0; the decode
+    ``tick`` never does). Returns ``(W_o(C * c), the tails after lane n_valid
+    - 1)``: padding lanes never enter a tail."""
+    dt = cfg.dtype
+    K = cfg.conv.taps
+    W = x.shape[1]
+    if not tick:
+        tail = _fresh(tail, pos, n_valid)
+    b, c, u = jnp.split(_proj(x, lp["in"], dt), 3, axis=-1)
+    ext = jnp.concatenate([tail.astype(dt), b * u], axis=1)  # (B, K-1+W, D)
+    taps = lp["taps"].astype(F32)
+    mixed = sum(ext[:, j:j + W].astype(F32) * taps[j] for j in range(K))
+    if tick:
+        # every row shifts by its one lane or stays: a slice a row would be
+        # a gather, a sequential loop on the chip (PERF.md, PR 35)
+        new_tail = jnp.where((n_valid > 0)[:, None, None], ext[:, 1:],
+                             ext[:, :K - 1])
+    else:
+        new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
+            e, n, K - 1, axis=0))(ext, n_valid)
+    return _proj((c.astype(F32) * mixed).astype(dt), lp["o"], dt), new_tail
+
+
+def _gqa_qkv(lp, x, wpos, cfg):
+    """``(q (B, H, W, hd), k, v (B, Hkv, W, hd))`` in the compute dtype: q
+    and k RMS-normed a head, then rotated."""
+    H, Hkv, hd = dims(cfg)
+    dt = cfg.dtype
+    q = _head_rms(_heads(_proj(x, lp["q"], dt), H, hd), lp["q_norm"],
+                  cfg.norm_eps)
+    k = _head_rms(_heads(_proj(x, lp["k"], dt), Hkv, hd), lp["k_norm"],
+                  cfg.norm_eps)
+    v = _heads(_proj(x, lp["v"], dt), Hkv, hd)
+    cos, sin = _rope_tables(wpos, hd, cfg.rope_theta, F32)   # (B, W, hd/2)
+    cos, sin = cos[:, None], sin[:, None]
+    return (_rot_half(q, cos, sin).astype(dt),
+            _rot_half(k, cos, sin).astype(dt), v.astype(dt))
+
+
+def _heads_out(lp, o, cfg):
+    """``W_o`` of the heads' contexts ``o`` (B, H, W, hd), no gate."""
+    B, H, W, hd = o.shape
+    return _proj(o.transpose(0, 2, 1, 3).reshape(B, W, H * hd).astype(
+        cfg.dtype), lp["o"], cfg.dtype)
+
+
+def _causal(wpos, L):
+    """(B, 1, W, L) bool: key ``l`` is at or before the query's position."""
+    return (jnp.arange(L)[None, None] <= wpos[..., None])[:, None]
+
+
+def _gqa_contiguous(lp, x, wpos, n_valid, c, cfg):
+    q, k, v = _gqa_qkv(lp, x, wpos, cfg)
+    kc, vc = _put_window(c, k, v, wpos, n_valid)
+    o = _masked_attention(q, kc, vc, _causal(wpos, kc.shape[2]),
+                          jnp.max(wpos))
+    return _heads_out(lp, o, cfg), {"k": kc, "v": vc}
+
+
+def _gqa_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel):
+    """A gqa layer over its pages. The decode tick (``kernel``) is one fused
+    launch: the token's K/V row scattered and the live pages folded, four
+    query heads a KV head's block. A window writes its rows through the
+    block table (padding lanes and idle rows to trash page 0), gathers its
+    row's pages and masks."""
+    from ...ops.paged_attention import paged_attention_gqa, split_kv
+    B = x.shape[0]
+    _, Hkv, hd = dims(cfg)
+    q, k, v = _gqa_qkv(lp, x, wpos, cfg)
+    if kernel:
+        o, kv = paged_attention_gqa(q[:, :, 0], k[:, :, 0], v[:, :, 0],
+                                    c["kv"], bt, pos, active=n_valid > 0)
+        return _heads_out(lp, o[:, :, None], cfg), {"kv": kv}
+    kv = _scatter_pages(c["kv"], k, v, bt, wpos, n_valid, page)
+    L = bt.shape[1] * page
+    kc, vc = split_kv(kv[bt].transpose(0, 2, 1, 3, 4).reshape(
+        B, Hkv, L, 2 * hd))
+    o = _masked_attention(q, kc, vc, _causal(wpos, L), jnp.max(wpos))
+    return _heads_out(lp, o, cfg), {"kv": kv}
+
+
 # ---- the window -------------------------------------------------------------
 
 def _finish(params, h, cfg, n_valid, last_only):
     """Final norm with muP's logit scaling folded in: hidden states of every
     lane, or with ``last_only`` of lane ``n_valid - 1`` alone."""
-    hidden = (_rms(h.astype(F32), params["final_ln"])
+    hidden = (_rms(h.astype(F32), params["final_ln"], cfg.norm_eps)
               * cfg.logit_scale).astype(cfg.dtype)
     if last_only:
         last = jnp.maximum(n_valid - 1, 0)[:, None, None]
@@ -949,14 +1100,15 @@ def _window(params, tokens, pos, cfg, n_valid, mixer, last_only, stats=None):
     rs = jnp.asarray(cfg.residual_scale, dt)
     counts = []
     for i, (kind, lp) in enumerate(zip(cfg.mixers, params["layers"])):
-        x = _rms(h.astype(F32), lp["ln1"]).astype(dt)
+        x = _rms(h.astype(F32), lp["ln1"], cfg.norm_eps).astype(dt)
         h = h + rs * mixer(i, kind, lp, x, wpos).astype(dt)
         if _ffn_kind(cfg, i) == "moe":
-            y, c = _routed(lp, _rms(h.astype(F32), lp["ln2"]), cfg, n_valid)
+            y, c = _routed(lp, _rms(h.astype(F32), lp["ln2"], cfg.norm_eps),
+                           cfg, n_valid)
             counts.append(c)
             h = h + rs * y
         else:
-            x = _rms(h.astype(F32), lp["ln2"]).astype(dt)
+            x = _rms(h.astype(F32), lp["ln2"], cfg.norm_eps).astype(dt)
             h = h + rs * _swiglu(lp, x, dt)
     if stats is not None and counts:
         c = jnp.stack(counts)
@@ -1004,6 +1156,11 @@ def window_contiguous(params: Dict, tokens, pos, cache, cfg, *,
             y, new_cache[i] = _kda_layer(lp, x, c, pos, n_valid, cfg, False)
         elif kind == "mla":
             y, new_cache[i] = _mla_contiguous(lp, x, wpos, n_valid, c, cfg)
+        elif kind == "conv":
+            y, tail = _conv_layer(lp, x, c["conv"], pos, n_valid, cfg, False)
+            new_cache[i] = {"conv": tail}
+        elif kind == "gqa":
+            y, new_cache[i] = _gqa_contiguous(lp, x, wpos, n_valid, c, cfg)
         else:
             y, new_cache[i] = _sparse_contiguous(lp, x, wpos, pos, n_valid,
                                                  c, cfg)
@@ -1041,11 +1198,20 @@ def window_paged(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
             y, new_bufs[i] = _mla_paged(lp, x, wpos, pos, n_valid, c,
                                         block_tables, cfg, page_size, kernel)
             return y
-        if kind == "kda":
+        if kind == "gqa":
+            y, new_bufs[i] = _gqa_paged(lp, x, wpos, pos, n_valid, c,
+                                        block_tables, cfg, page_size, kernel)
+            return y
+        if kind in ("kda", "conv"):
             rows = c if slot is None else {
                 kk: jax.lax.dynamic_slice_in_dim(c[kk], slot, 1, axis=0)
                 for kk in c}
-            y, new = _kda_layer(lp, x, rows, pos, n_valid, cfg, kernel)
+            if kind == "kda":
+                y, new = _kda_layer(lp, x, rows, pos, n_valid, cfg, kernel)
+            else:
+                y, tail = _conv_layer(lp, x, rows["conv"], pos, n_valid, cfg,
+                                      tokens.shape[1] == 1)
+                new = {"conv": tail}
             new_bufs[i] = new if slot is None else {
                 kk: jax.lax.dynamic_update_slice_in_dim(c[kk], new[kk], slot,
                                                         axis=0) for kk in c}

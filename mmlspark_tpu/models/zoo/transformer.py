@@ -26,7 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["TransformerConfig", "SparseAttention", "RoutedExperts",
-           "LatentAttention", "DeltaRule", "init_transformer",
+           "LatentAttention", "DeltaRule", "ShortConv", "init_transformer",
            "transformer_apply",
            "train_step", "param_shardings", "BERT_BASE", "BERT_MINI",
            "DECODER_MINI", "generate", "generate_cached",
@@ -100,6 +100,14 @@ class DeltaRule(NamedTuple):
     gate_floor: float = -5.0
 
 
+class ShortConv(NamedTuple):
+    """The ``conv`` mixer's size (a gated short convolution): a causal
+    depthwise convolution of ``taps`` taps a channel over ``d_model``
+    channels (the published ``conv_L_cache``); a sequence caches the
+    ``taps - 1`` rows before its next token."""
+    taps: int = 3
+
+
 class TransformerConfig(NamedTuple):
     vocab: int = 30522
     layers: int = 12
@@ -149,8 +157,16 @@ class TransformerConfig(NamedTuple):
     routed: Optional[RoutedExperts] = None
     latent: Optional[LatentAttention] = None
     kda: Optional[DeltaRule] = None
-    #: KV heads of the sparse layers (0 = ``heads``) and an explicit head
-    #: size (0 = ``d_model // heads``)
+    #: and two that end in ``W_o`` alone, no output gate: ``"conv"`` (a gated
+    #: short convolution, size in ``conv``: its whole cache is the
+    #: convolution's tail, a row a slot) and ``"gqa"`` (full grouped-query
+    #: softmax attention with per-head QK RMSNorm and RoPE over plain pages)
+    conv: Optional[ShortConv] = None
+    #: the epsilon of a hybrid decoder's RMSNorms (the block's, the final
+    #: one, a gqa layer's per-head ones)
+    norm_eps: float = 1e-6
+    #: KV heads of the sparse and gqa layers (0 = ``heads``) and an explicit
+    #: head size (0 = ``d_model // heads``)
     kv_heads: int = 0
     head_dim: int = 0
     sparse: Optional[SparseAttention] = None

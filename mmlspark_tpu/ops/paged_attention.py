@@ -154,6 +154,7 @@ from .pallas_kernels import _LANE, _round_up
 from .kv_quant import quantize_kv
 
 __all__ = ["paged_attention", "paged_attention_window",
+           "paged_attention_gqa",
            "paged_attention_selected", "paged_attention_latent",
            "resolve_impl",
            "sublane_multiple", "aligned_page_size", "pack_kv", "split_kv",
@@ -279,20 +280,21 @@ def _fold_keys(m_scr, l_scr, acc_scr, q, kv, valid, scale, v_width=None):
           functools.partial(_weigh, v=v))
 
 
-def _whole_groups(x):
-    """Heads ``(H, ...)`` as WHOLE groups of ``_HEADS``, a list of
-    ``(groups, _HEADS, ...)`` arrays cut from ``x`` without a copy: the
-    heads in order and, where ``H`` is no multiple, the LAST ``_HEADS``
+def _whole_groups(x, size=_HEADS):
+    """Heads ``(H, ...)`` as WHOLE groups of ``size`` (``_HEADS`` query
+    heads; the KV heads they share where a KV head serves several), a list
+    of ``(groups, size, ...)`` arrays cut from ``x`` without a copy: the
+    heads in order and, where ``H`` is no multiple, the LAST ``size``
     heads once more as the last group (fewer heads than a group are padded
     with zero heads). Every group's products then have one shape whatever
     ``H`` is, so a head's bits do not depend on the heads beside it (module
     docstring). :func:`_heads_of` is the way back."""
-    H, part = x.shape[0], x.shape[0] % _HEADS
-    if H < _HEADS:
-        x, H, part = jnp.pad(x, ((0, _HEADS - H),) + ((0, 0),) *
-                             (x.ndim - 1)), _HEADS, 0
-    return [t.reshape(-1, _HEADS, *x.shape[1:])
-            for t in (x[:H - part], x[H - _HEADS:] if part else x[:0])
+    H, part = x.shape[0], x.shape[0] % size
+    if H < size:
+        x, H, part = jnp.pad(x, ((0, size - H),) + ((0, 0),) *
+                             (x.ndim - 1)), size, 0
+    return [t.reshape(-1, size, *x.shape[1:])
+            for t in (x[:H - part], x[H - size:] if part else x[:0])
             if t.shape[0]]
 
 
@@ -318,24 +320,33 @@ def _heads_query(q_ref, width):
     return jnp.pad(q, ((0, 0), (0, 0), (0, width - hd))).astype(q_ref.dtype)
 
 
-def _fold_heads(m_scr, l_scr, acc_scr, q, kv, n, scale):
+def _fold_heads(m_scr, l_scr, acc_scr, q, kv, n, scale, share=1):
     """Fold the first ``n`` keys of every head of packed rows ``kv``
-    (H, K, 2*hd) into a heads-as-rows state ``(G, _HEADS, .)`` under the
+    (Hkv, K, 2*hd) into a heads-as-rows state ``(G, _HEADS, .)`` under the
     query operand ``q`` (:func:`_heads_query`): a group's rows laid head
     after head are one head's ``_HEADS * K`` keys under a block-diagonal
     mask, the packed row unsplit (module docstring: exact zeros, as long as
     every lane read is finite). The groups in order are ONE batched product
     and the last, overlapping group one more: a product a group in a loop
-    runs group after group on the chip (PERF.md, PR 37)."""
+    runs group after group on the chip (PERF.md, PR 37).
+
+    Grouped queries: ``share`` query heads (a power of two up to ``_HEADS``)
+    read KV head ``h // share``, so a group's ``_HEADS`` state rows span
+    ``_HEADS // share`` KV heads and ``share`` neighbouring rows own the
+    same block of the mask."""
     K, width = kv.shape[1:]
+    span = _HEADS // share          # KV heads a group of state rows reads
     parts, g = [], 0
-    for t in _whole_groups(kv):
+    for t in _whole_groups(kv, span):
         parts.append((slice(g, g + t.shape[0]),
-                      t.reshape(t.shape[0], _HEADS * K, width)))
+                      t.reshape(t.shape[0], span * K, width)))
         g += t.shape[0]
     s = jnp.concatenate([_scores(q[gs], kvg, scale) for gs, kvg in parts])
-    lo = K * jax.lax.broadcasted_iota(jnp.int32, (1, _HEADS, 1), 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _HEADS * K), 2)
+    head = jax.lax.broadcasted_iota(jnp.int32, (1, _HEADS, 1), 1)
+    if share > 1:
+        head = head >> (share.bit_length() - 1)
+    lo = K * head
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, span * K), 2)
     own = (col >= lo) & (col < lo + jnp.clip(n, 0, K))
     _fold(m_scr, l_scr, acc_scr, s, own, lambda p: jnp.concatenate(
         [_weigh(p[gs], kvg) for gs, kvg in parts]))
@@ -388,26 +399,26 @@ def _pages_fold(m_scr, l_scr, acc_scr, q, kv, p, bound, scale, page,
 
 
 def _window_attend(state, q_scr, q_ref, kvn_ref, page_kv, p, pos, scale,
-                   page, W):
+                   page, W, share=1):
     """The attention of one grid step of a windowed kernel. A row's first
     step (p == 0) starts its ``state`` and folds the window's own rows: they
     arrive unquantized and packed like a page (``(1, H, Wp, 2*hd)``: direct
     inputs, not pages), folded under the in-window causal mask. A page with
     keys strictly below ``pos`` folds them; ``page_kv()`` reads its block.
     A window of ONE query (``W == 1``, static: ``q_scr`` holds its query
-    operand, made once a row) folds heads as rows (:func:`_fold_heads`); its
-    causal mask is its first key."""
+    operand, made once a row) folds heads as rows (:func:`_fold_heads`,
+    ``share`` query heads a KV head); its causal mask is its first key."""
     from jax.experimental import pallas as pl
 
     by_head = W == 1
-    assert by_head == bool(q_scr)
+    assert by_head == bool(q_scr) and (by_head or share == 1)
 
     @pl.when(p == 0)
     def _init_and_window():
         _init(*state)
         if by_head:
             q_scr[0][...] = _heads_query(q_ref, q_scr[0].shape[-1])
-            _fold_heads(*state, q_scr[0][...], kvn_ref[0], W, scale)
+            _fold_heads(*state, q_scr[0][...], kvn_ref[0], W, scale, share)
         else:
             Wp = q_ref.shape[2]
             row = jax.lax.broadcasted_iota(jnp.int32, (1, Wp, Wp), 1)
@@ -424,7 +435,7 @@ def _window_attend(state, q_scr, q_ref, kvn_ref, page_kv, p, pos, scale,
     def _pages():
         if by_head:
             _fold_heads(*state, q_scr[0][...], page_kv(), pos - p * page,
-                        scale)
+                        scale, share)
         else:
             _pages_fold(*state, q_ref[0], page_kv(), p, pos, scale, page)
 
@@ -500,13 +511,13 @@ def _pa_window_kernel(row_ref, page_ref, last_ref, bt_ref, pos_ref, q_ref,
 
 def _pa_fused_kernel(row_ref, page_ref, last_ref, bt_ref, pos_ref, wlo_ref,
                      whi_ref, q_ref, kvn_ref, kv_ref, o_ref, kvo_ref,
-                     m_scr, l_scr, acc_scr, *q_scr, scale, page, W):
+                     m_scr, l_scr, acc_scr, *q_scr, scale, page, W, share=1):
     from jax.experimental import pallas as pl
 
     b, p, last = _step(row_ref, page_ref, last_ref)
     pos = pos_ref[b]
     _window_attend((m_scr, l_scr, acc_scr), q_scr, q_ref, kvn_ref,
-                   lambda: _page_kv(kv_ref), p, pos, scale, page, W)
+                   lambda: _page_kv(kv_ref), p, pos, scale, page, W, share)
 
     @pl.when(jnp.logical_and(p >= wlo_ref[b], p <= whi_ref[b]))
     def _scatter():
@@ -660,11 +671,11 @@ def _block_specs(q, kv_pages, scales):
     from jax.experimental import pallas as pl
 
     _, H, Wp, hd = q.shape
-    page = kv_pages.shape[2]
+    Hkv, page = kv_pages.shape[1:3]     # H, but for grouped queries
     return (pl.BlockSpec((1, H, Wp, hd), _row_map),
-            pl.BlockSpec((1, H, Wp, 2 * hd), _row_map),
-            pl.BlockSpec((1, H, page, 2 * hd), _page_map),
-            [pl.BlockSpec((1, H, page), _scale_map)] * len(scales))
+            pl.BlockSpec((1, Hkv, Wp, 2 * hd), _row_map),
+            pl.BlockSpec((1, Hkv, page, 2 * hd), _page_map),
+            [pl.BlockSpec((1, Hkv, page), _scale_map)] * len(scales))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -750,22 +761,24 @@ def _fused_schedule(pos, wlo, whi, page, n_pages):
     return _schedule(jnp.where(wlo <= whi, pos, 0), whi, page, n_pages)
 
 
-@functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
-def _pa_fused_call(q, kv_new, kv_pages, block_tables, pos, wlo, whi, *,
-                   W, scale, interpret):
+def _fused_launch(q, kv_new, kv_pages, block_tables, pos, wlo, whi, *,
+                  W, scale, interpret):
+    """The fused launch both jitted names below make: ``q`` (B, H, Wp, hd)
+    over a pool of ``Hkv`` heads, ``H // Hkv`` query heads a KV head."""
     from jax.experimental import pallas as pl
 
     B, H, Wp, hd = q.shape
-    page = kv_pages.shape[2]
+    Hkv, page = kv_pages.shape[1:3]
     *sweep, total = _fused_schedule(pos, wlo, whi, page,
                                     block_tables.shape[1])
-    kernel = functools.partial(_pa_fused_kernel, scale=scale, page=page, W=W)
+    kernel = functools.partial(_pa_fused_kernel, scale=scale, page=page, W=W,
+                               share=H // Hkv)
     row, new, pages, _ = _block_specs(q, kv_pages, ())
     call = pl.pallas_call(
         kernel,
         grid_spec=_grid_spec(
             4, total, in_specs=[row, new, pages],
-            out_specs=[row, pl.BlockSpec((1, H, page, 2 * hd), _write_map)],
+            out_specs=[row, pl.BlockSpec((1, Hkv, page, 2 * hd), _write_map)],
             state=_window_state(q, W)),
         out_shape=[jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
                    jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype)],
@@ -776,6 +789,22 @@ def _pa_fused_call(q, kv_new, kv_pages, block_tables, pos, wlo, whi, *,
         interpret=interpret,
     )
     return call(*sweep, block_tables, pos, wlo, whi, q, kv_new, kv_pages)
+
+
+@functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
+def _pa_fused_call(q, kv_new, kv_pages, block_tables, pos, wlo, whi, *,
+                   W, scale, interpret):
+    return _fused_launch(q, kv_new, kv_pages, block_tables, pos, wlo, whi,
+                         W=W, scale=scale, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _pa_gqa_call(q, kv_new, kv_pages, block_tables, pos, wlo, whi, *,
+                 scale, interpret):
+    """The grouped-query decode call under its own name (a trace shows it
+    apart from the dense block's): one query a head a row."""
+    return _fused_launch(q, kv_new, kv_pages, block_tables, pos, wlo, whi,
+                         W=1, scale=scale, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
@@ -1061,6 +1090,56 @@ def paged_attention(q, kv_pages, block_tables, lengths, *,
     return out[:, :, :W]
 
 
+def _write_range(pos, W, page, active):
+    """``(first, last)`` logical page each row's ``W`` fresh rows land in."""
+    wlo = pos // page
+    whi = (pos + W - 1) // page
+    if active is not None:
+        # an empty write range (lo > hi): the index_map sends every page
+        # of the row to trash and the overlay never fires
+        wlo = jnp.where(active, wlo, 1)
+        whi = jnp.where(active, whi, 0)
+    return wlo.astype(jnp.int32), whi.astype(jnp.int32)
+
+
+def paged_attention_gqa(q, k_new, v_new, kv_pages, block_tables, pos, *,
+                        active=None, scale: Optional[float] = None,
+                        interpret: Optional[bool] = None):
+    """Fused GROUPED-QUERY decode attention + page scatter, one launch: the
+    decode tick of a layer whose ``H`` query heads share ``Hkv`` KV heads.
+
+    ``q`` (B, H, hd), one query a head a row at position ``pos[b]``;
+    ``k_new`` / ``v_new`` (B, Hkv, hd) that token's fresh row; ``kv_pages``
+    (N, Hkv, page, 2*hd) the packed pool. Query head ``h`` reads KV head
+    ``h // (H // Hkv)``: the ``H // Hkv`` (a power of two up to 8) query
+    heads of a KV head fold that head's page block, eight state rows a
+    group under the block-diagonal mask of :func:`_fold_heads`. The ragged
+    sweep, the in-launch scatter and the contract on ``active`` and on page
+    ownership are :func:`paged_attention_window`'s at ``W == 1``; at ``H ==
+    Hkv`` the context is that call's, bit for bit. Returns ``(ctx (B, H,
+    hd), kv_pages)``, the pool updated in place (aliased)."""
+    if interpret is None:
+        interpret = _auto_interpret()
+    B, H, hd = q.shape
+    Hkv, page = kv_pages.shape[1:3]
+    share = H // max(Hkv, 1)
+    if share * Hkv != H or _HEADS % share or k_new.shape[1] != Hkv:
+        raise ValueError(
+            f"{H} query heads over {Hkv} KV heads: a KV head serves 1, 2, 4 "
+            f"or {_HEADS} query heads")
+    if scale is None:
+        scale = float(1.0 / math.sqrt(hd))
+    pos = pos.astype(jnp.int32)
+    Wp = sublane_multiple(q.dtype)
+    out, pool = _pa_gqa_call(
+        _pad_window(q[:, :, None], Wp),
+        _pad_window(pack_kv(k_new, v_new)[:, :, None], Wp), kv_pages,
+        block_tables.astype(jnp.int32), pos, *_write_range(pos, 1, page,
+                                                           active),
+        scale=scale, interpret=bool(interpret))
+    return out[:, :, 0], pool
+
+
 def paged_attention_window(q, k_new, v_new, kv_pages, block_tables, pos, *,
                            active=None, k_scale=None, v_scale=None,
                            scale: Optional[float] = None,
@@ -1112,14 +1191,7 @@ def paged_attention_window(q, k_new, v_new, kv_pages, block_tables, pos, *,
             qp, kvn, kv_pages, bt, pos, *scales)
         return (ctx[:, :, :W], *_pool_write_rows(
             kv_pages, k_new, v_new, bt, pos, active, *scales))
-    wlo = pos // page
-    whi = (pos + W - 1) // page
-    if active is not None:
-        # an empty write range (lo > hi): the index_map sends every page
-        # of the row to trash and the overlay never fires
-        wlo = jnp.where(active, wlo, 1)
-        whi = jnp.where(active, whi, 0)
-    wlo, whi = wlo.astype(jnp.int32), whi.astype(jnp.int32)
+    wlo, whi = _write_range(pos, W, page, active)
     if scales:
         out, *pools = _pa_fused_call_q(
             qp, kvn, *(_pad_window(t, Wp) for t in stored_kv(

@@ -635,7 +635,8 @@ def derived_page_size(cfg: TransformerConfig, max_len: int) -> int:
     time is its count of steps: a block table 64 wide cost GPT-2 XL three
     quarters of its tick (PERF.md section 6, PR 32). A model with sparse
     layers keeps the page at its sparse block, which selection and the
-    compressed keys are laid out by."""
+    compressed keys are laid out by; any other paged layer of a hybrid
+    model (gqa's K beside V, mla's latent rows) is a dense pool."""
     if "sparse" in cfg.mixers:
         return cfg.sparse.block_size
     return min(256, max(16, bucket_size(int(max_len)) // 16))
@@ -691,14 +692,16 @@ class ContinuousDecoder:
                     (draft_params is not None,
                      "a draft model: the verify window would have to roll "
                      "rejected tokens back out of a linear-attention state "
-                     "(lightning or kda) and its convolution tails"),
+                     "(lightning or kda) and out of a kda or conv layer's "
+                     "convolution tails"),
                     (resolve_kv_dtype(kv_dtype) is not None,
                      "kv_dtype: the sparse layers' compressed keys, the "
-                     "selected-block kernel and an mla layer's latent pages "
-                     "are bf16 only"),
+                     "selected-block kernel, an mla layer's latent pages "
+                     "and a gqa layer's grouped-query kernel are bf16 only"),
                     (mesh is not None,
-                     "a mesh: the state rows, the decode kernels and a "
-                     "routed feed-forward's exchange have no mount")):
+                     "a mesh: the state rows, a conv layer's tails, the "
+                     "decode kernels (gqa's among them) and a routed "
+                     "feed-forward's exchange have no mount")):
                 if given:
                     raise ValueError(f"a hybrid decoder does not take {why}")
         #: speculative mode: a draft model proposes gamma greedy tokens per
@@ -2056,14 +2059,17 @@ class ContinuousDecoder:
                 "sparse" if context > sp.dense_len else "dense", calls=calls)
 
     def _note_mixer_ticks(self, calls: int) -> None:
-        """A model with kda or mla layers counts each decode call once more
-        for each, by the path its tick ran: ``kda`` / ``latent`` (the Pallas
-        step, the absorbed kernel) or ``kda_window`` / ``latent_window``
-        (the chunked form and the expanded attention, under ``gather``)."""
+        """A model with kda, mla, gqa or conv layers counts each decode call
+        once more for each, by the path its tick ran: ``kda`` / ``latent`` /
+        ``gqa`` (the Pallas step, the absorbed kernel, the grouped-query
+        kernel) or ``kda_window`` / ``latent_window`` / ``gqa_window`` (the
+        chunked form, the expanded attention and the gathered pages, under
+        ``gather``); ``conv`` has the one path."""
         off = "" if self._attn_impl == "kernel" else "_window"
-        for mixer, label in (("kda", "kda"), ("mla", "latent")):
+        for mixer, label in (("kda", "kda" + off), ("mla", "latent" + off),
+                             ("gqa", "gqa" + off), ("conv", "conv")):
             if mixer in self._cfg.mixers:
-                self._kv.note_attn_tick(label + off, calls=calls)
+                self._kv.note_attn_tick(label, calls=calls)
 
     def _note_token(self, req: _Request, tok: int):
         now = time.perf_counter()

@@ -86,6 +86,9 @@ M_DEFRAG_MOVES = _metric_counter(
 M_PREFILL_CHUNKS = _metric_counter(
     "mmlspark_kvpool_prefill_chunks_total",
     "Prefill chunks executed by the chunked-prefill scheduler")
+M_PREFILL_TOKENS = _metric_counter(
+    "mmlspark_kvpool_prefill_tokens_total",
+    "Prompt tokens the chunked-prefill scheduler's windows computed")
 M_ALLOC_FAILURES = _metric_counter(
     "mmlspark_kvpool_alloc_failures_total",
     "Page allocations that failed even after prefix eviction")
@@ -164,8 +167,8 @@ class PagedKVPool:
 
     ``buffers`` is the per-layer list of ``{"kv"}`` page arrays (plus
     ``{"k_scale","v_scale"}`` when quantized; for a hybrid decoder
-    ``{"kv","ck"}``, ``{"state"}``, ``{"state","conv"}`` or latent ``{"kv"}``
-    pages, each in the shape ``models/zoo/hybrid.py`` ``pool_shapes``
+    ``{"kv","ck"}``, ``{"state"}``, ``{"state","conv"}``, ``{"conv"}`` alone,
+    or plain or latent ``{"kv"}`` pages, each in the shape ``models/zoo/hybrid.py`` ``pool_shapes``
     gives it) the
     engine threads through its jitted steps (reassigning after every
     dispatch, since XLA returns fresh buffers). Everything else is host
@@ -197,8 +200,9 @@ class PagedKVPool:
             from ..models.zoo.hybrid import SLOT_KEYS, dims, pool_shapes
             if kv_dtype is not None or sharding is not None:
                 raise ValueError("a hybrid decoder's pool is bf16 pages "
-                                 "(K beside V, or an mla layer's latent "
-                                 "rows) on one device (no kv_dtype, no mesh)")
+                                 "(K beside V of a sparse or gqa layer, or "
+                                 "an mla layer's latent rows) on one device "
+                                 "(no kv_dtype, no mesh)")
             _, heads, hd = dims(cfg)
             self._layer_shapes = pool_shapes(
                 cfg, self.num_pages, self.page_size, int(slots),
@@ -259,7 +263,8 @@ class PagedKVPool:
         self.stats = {"page_size": self.page_size,
                       "pages_per_slot": self.pages_per_slot(slot_positions),
                       "prefix_share_hits": 0, "defrag_moves": 0,
-                      "prefill_chunks": 0, "alloc_failures": 0,
+                      "prefill_chunks": 0, "prefill_tokens": 0,
+                      "alloc_failures": 0,
                       "gather_bytes": 0, "attn_ticks_kernel": 0,
                       "attn_ticks_gather": 0, "grid_steps": 0,
                       "grid_steps_dense": 0, "quant_error_probes": 0,
@@ -654,7 +659,9 @@ class PagedKVPool:
 
     def note_prefill_chunk(self, ntok: int) -> None:
         self.stats["prefill_chunks"] += 1
+        self.stats["prefill_tokens"] += int(ntok)
         M_PREFILL_CHUNKS.inc()
+        M_PREFILL_TOKENS.inc(int(ntok))
 
     def note_attn_tick(self, impl: str, *, calls: int = 1,
                        gather_bytes: int = 0) -> None:

@@ -395,23 +395,21 @@ def test_grouped_expert_product_compiles(one_chip, tokens):
     assert "_moe_experts_call" in text
 
 
-@pytest.fixture(scope="module")
-def ling_programs(one_chip):
-    """The routed engine's programs lowered at the cell's shapes (layers 0,
-    10 and 11 of the seven: kda under the dense feed-forward, kda and mla
-    under the routed one), as ``sala_programs`` does it."""
+def _share_programs(one_chip, z, config_file, driver, cut):
+    """A stage-share engine's tick and prefill chunk lowered and compiled at
+    the cell's shapes ``z`` on three of its layers (``cut``), as
+    ``sala_programs`` does it. Yields ``({"tick", "chunk"}: compiled text,
+    the pool)``."""
     import json
 
     from benchmarks import run as bench_run
     from mmlspark_tpu.ops import paged_attention as pa
     from mmlspark_tpu.serving import continuous as progs
     from mmlspark_tpu.serving.kv_pool import PagedKVPool
-    z = LING
-    with open(os.path.join(bench_run.HERE, "configs",
-                           "ling3_flash_ep4_l7.json")) as fh:
+    with open(os.path.join(bench_run.HERE, "configs", config_file)) as fh:
         config = json.load(fh)
-    config.update(num_hidden_layers=3, layers_held=[0, 10, 11])
-    cfg = bench_run.load_by_path("drivers", "generate_ling").program_config(
+    config.update(num_hidden_layers=3, layers_held=[0, 10, 11], **cut)
+    cfg = bench_run.load_by_path("drivers", driver).program_config(
         config, z["max_len"])
     per = z["max_len"] // z["page"]
     reference = bench_run.load_by_path("references", config["reference"])
@@ -442,6 +440,15 @@ def ling_programs(one_chip):
         progs._extend_program.cache_clear()
 
 
+@pytest.fixture(scope="module")
+def ling_programs(one_chip):
+    """The routed engine's programs at the cell's shapes (layers 0, 10 and
+    11 of the seven: kda under the dense feed-forward, kda and mla under the
+    routed one)."""
+    yield from _share_programs(one_chip, LING, "ling3_flash_ep4_l7.json",
+                               "generate_ling", {})
+
+
 @pytest.mark.parametrize("program", ["tick", "chunk"])
 def test_routed_programs_compile_and_keep_the_pool_in_place(ling_programs,
                                                             program):
@@ -464,6 +471,87 @@ def test_routed_programs_compile_and_keep_the_pool_in_place(ling_programs,
             if key == "conv":
                 continue
             shape = f"{names[buf.dtype.name]}[{','.join(map(str, buf.shape))}]"
+            copies = [ln.strip()[:120] for ln in text.splitlines()
+                      if f"= {shape}" in ln and " copy(" in ln]
+            assert not copies, copies
+
+
+# the conv + grouped-query cell (lfm2_ragchat_closed32): 32 query heads over
+# 8 KV heads of 64, 32 slots of 5,120 positions in pages of 256, 64 experts
+# of 1536 all held, top-4, a 512-token prefill chunk
+LFM2 = dict(slots=32, heads=32, kv_heads=8, hd=64, page=256, max_len=5120,
+            hidden=2048, width=1536, held=64, top=4, chunk=512)
+
+
+def test_grouped_query_decode_kernel_compiles_in_place(one_chip):
+    """The gqa layer's tick: four query heads fold a KV head's page block,
+    the fresh K/V row scattered in the same launch, the pool aliased."""
+    from mmlspark_tpu.ops.paged_attention import paged_attention_gqa
+    z = LFM2
+    per = z["max_len"] // z["page"]
+    pool = (1 + z["slots"] * per, z["kv_heads"], z["page"], 2 * z["hd"])
+    text = jax.jit(functools.partial(paged_attention_gqa, interpret=False),
+                   donate_argnums=(3,)).lower(
+        one_chip((z["slots"], z["heads"], z["hd"]), jnp.bfloat16),
+        one_chip((z["slots"], z["kv_heads"], z["hd"]), jnp.bfloat16),
+        one_chip((z["slots"], z["kv_heads"], z["hd"]), jnp.bfloat16),
+        one_chip(pool, jnp.bfloat16), one_chip((z["slots"], per), jnp.int32),
+        one_chip((z["slots"],), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "_pa_gqa_call" in text
+    shape = f"bf16[{','.join(map(str, pool))}]"
+    assert not [ln for ln in text.splitlines()
+                if f"= {shape}" in ln and " copy(" in ln]
+
+
+@pytest.mark.parametrize("tokens", [32, 512], ids=["tick", "prefill_chunk"])
+def test_whole_layer_expert_product_compiles(one_chip, tokens):
+    """The routed product with every expert held: a grid step is one 18.9 MB
+    expert ((2048, 3072) + (1536, 2048) bf16), 37.7 MB double-buffered,
+    inside the 64 MiB the kernels are given."""
+    from mmlspark_tpu.ops import paged_attention as pa
+    from mmlspark_tpu.parallel.moe import held_tiles
+    z = LFM2
+    block = 2 * 3 * z["hidden"] * z["width"]
+    assert block == 18_874_368 and 2 * block < pa._VMEM_LIMIT_BYTES
+    tiles = held_tiles(tokens * z["top"], z["held"], TILE)
+    text = _compiled_text(
+        functools.partial(grouped_swiglu, interpret=False),
+        one_chip((tiles * TILE, z["hidden"]), jnp.bfloat16),
+        one_chip((tiles,), jnp.int32), one_chip((), jnp.int32),
+        one_chip((z["held"], z["hidden"], 2 * z["width"]), jnp.bfloat16),
+        one_chip((z["held"], z["width"], z["hidden"]), jnp.bfloat16))
+    assert "_moe_experts_call" in text
+
+
+@pytest.fixture(scope="module")
+def lfm2_programs(one_chip):
+    """The conv + gqa engine's programs at the cell's shapes (layers 0, 10
+    and 11 of the nine: conv under the dense feed-forward, gqa and conv
+    under the routed one)."""
+    yield from _share_programs(
+        one_chip, LFM2, "lfm2_24b_a2b_pp5_l9.json", "generate_lfm2",
+        dict(layer_types=["conv", "full_attention", "conv"]))
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_conv_gqa_programs_compile_and_keep_the_pool_in_place(lfm2_programs,
+                                                              program):
+    """The tick and a 512-token prefill chunk at the published widths (32
+    slots, 5,120 positions): they compile for the chip, the tick holds the
+    grouped-query kernel and the experts' product and no sequential loop (a
+    slice a row of the tails would be one), and neither copies the page
+    pool (the tails' slots aside: 0.26 MB a layer, laid out by the chip)."""
+    texts, pool = lfm2_programs
+    text = texts[program]
+    assert "_moe_experts_call" in text
+    if program == "tick":
+        assert "_pa_gqa_call" in text
+        assert " while(" not in text
+    for layer in pool.buffers:
+        for key, buf in layer.items():
+            if key == "conv":
+                continue
+            shape = f"bf16[{','.join(map(str, buf.shape))}]"
             copies = [ln.strip()[:120] for ln in text.splitlines()
                       if f"= {shape}" in ln and " copy(" in ln]
             assert not copies, copies

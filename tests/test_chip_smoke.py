@@ -69,7 +69,7 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     lines, _ = small_run
     phases = {ln["phase"]: ln for ln in lines if "phase" in ln}
     assert sorted(phases) == ["A.transform", "B.decode", "C.train",
-                              "F.hybrid", "G.routed"]
+                              "F.hybrid", "G.routed", "H.conv_gqa"]
     for ln in phases.values():
         assert ln["ok"] is True and ln["failed"] == []
         assert ln["small"] is True and ln["platform"] == "cpu"
@@ -87,6 +87,10 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     assert phases["G.routed"]["attn_ticks_kda"] > 0
     assert phases["G.routed"]["moe"]["pairs_dropped"] == 0
     assert phases["G.routed"]["gap_mean"] <= chip_smoke.ROUTED_GAP_MEAN
+    assert phases["H.conv_gqa"]["attn_ticks_gqa"] > 0
+    assert phases["H.conv_gqa"]["moe"]["pairs_held"] \
+        == phases["H.conv_gqa"]["moe"]["pairs_routed"] > 0
+    assert phases["H.conv_gqa"]["gap_mean"] <= chip_smoke.CONV_GQA_GAP_MEAN
     assert phases["C.train"]["pallas_histogram_traces"] > 0
     assert (phases["C.train"]["pallas_interpreted"]
             == phases["C.train"]["pallas_histogram_traces"])

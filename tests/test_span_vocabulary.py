@@ -278,6 +278,78 @@ def test_snapshot_bytes_count_the_convolution_tails(routed_run):
         + 3 * 96 * 4
 
 
+#: the labels a conv + grouped-query decoder's tick adds to
+#: ``mmlspark_kvpool_kernel_ticks_total``, and the prompt tokens its chunk
+#: windows computed (pool ``stats["prefill_tokens"]``,
+#: ``mmlspark_kvpool_prefill_tokens_total``)
+CONV_GQA_TICK_LABELS = ["conv", "gqa"]
+
+
+@pytest.fixture(scope="module")
+def conv_gqa_run():
+    """A tiny conv + grouped-query decoder (a conv layer under a dense
+    feed-forward, a gqa layer under 8 routed experts all held): two
+    requests one after the other, the first registering a prefix the second
+    restores. What the pool and the registry counted."""
+    from mmlspark_tpu import observability as obs
+    from mmlspark_tpu.models.zoo.transformer import (
+        RoutedExperts, ShortConv, TransformerConfig, init_transformer)
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    cfg = TransformerConfig(
+        vocab=64, layers=2, d_model=32, heads=4, kv_heads=2, d_ff=64,
+        max_len=96, causal=True, norm="rmsnorm", position="rope",
+        dtype=jnp.float32, mixers=("conv", "gqa"), head_dim=8,
+        ffn=("dense", "moe"), conv=ShortConv(taps=3), norm_eps=1e-5,
+        routed=RoutedExperts(experts=8, per_token=2, d_expert=16))
+    before = obs.snapshot()
+    dec = ContinuousDecoder(init_transformer(cfg, seed=0), cfg, max_slots=2,
+                            max_len=96, page_size=8, prefill_chunk=16)
+    rng = np.random.default_rng(5)
+    doc = rng.integers(1, cfg.vocab, 24).astype(np.int32)
+    prompts = [np.concatenate(
+        [doc, rng.integers(1, cfg.vocab, n).astype(np.int32)])
+        for n in (6, 9)]
+    for p in prompts:                       # a miss, then a hit
+        req = dec.submit(p, 5, prefix_key="doc", prefix_len=24)
+        while not req.done:
+            dec.step()
+        assert req.error is None
+    return dec._kv.stats, before, obs.snapshot(), prompts
+
+
+@pytest.mark.parametrize("label", CONV_GQA_TICK_LABELS)
+def test_conv_and_gqa_ticks_are_labelled(conv_gqa_run, label):
+    stats, before, after, _ = conv_gqa_run
+    assert stats[f"attn_ticks_{label}"] \
+        == stats["attn_ticks_kernel"] - stats["prefill_chunks"] > 0
+    assert "attn_ticks_gqa_window" not in stats
+
+    def series(snap):
+        return sum(s["value"] for s in snap.get(
+            "mmlspark_kvpool_kernel_ticks_total", {}).get("series", ())
+            if s["labels"].get("impl") == label)
+    assert series(after) - series(before) == stats[f"attn_ticks_{label}"]
+
+
+def test_prefill_tokens_count_what_the_chunk_windows_computed(conv_gqa_run):
+    """The first request prefills whole (30 tokens: the prefix's 24 in two
+    chunks, then 6); the second restores the prefix and computes its 9."""
+    stats, before, after, prompts = conv_gqa_run
+    assert stats["prefill_tokens"] == len(prompts[0]) + 9
+
+    def total(snap):
+        return sum(s["value"] for s in snap.get(
+            "mmlspark_kvpool_prefill_tokens_total", {}).get("series", ()))
+    assert total(after) - total(before) == stats["prefill_tokens"]
+
+
+def test_snapshot_bytes_are_a_conv_layers_tails_alone(conv_gqa_run):
+    """One conv layer: 2 rows of 32 float32 values; the gqa layer keeps
+    nothing a slot."""
+    stats, *_ = conv_gqa_run
+    assert stats["state_snapshot_bytes_stored"] == 2 * 32 * 4
+
+
 def test_transform_spans_join_the_request_trace(transform_run):
     _, spans = transform_run
     names = [s.name for s in spans]
