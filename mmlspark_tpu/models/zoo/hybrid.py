@@ -86,7 +86,7 @@ from .transformer import (TransformerConfig, _embed, _rms, _rope_tables,
 __all__ = ["check_config", "dims", "init_hybrid", "init_hybrid_cache",
            "init_hybrid_pool", "lightning_rates", "lightning_chunk",
            "sparse_select", "kda_chunk", "head", "window_contiguous",
-           "window_paged", "SLOT_KEYS"]
+           "window_paged", "tick_with_window", "SLOT_KEYS"]
 
 HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
@@ -537,6 +537,12 @@ def _masked_attention(q, k, v, allowed, t_max):
 
     def fold(carry, ks_, vs_, al):
         m, l, acc = carry
+        # a key no query may read can hold anything (the trash page behind
+        # a block table's unassigned entries takes whatever the fused decode
+        # kernel's idle output block held): its weight is 0, and 0 x NaN is
+        # NaN, so its value is 0 too
+        vs_ = jnp.where(al.any(axis=2)[..., None], vs_,
+                        jnp.zeros((), vs_.dtype))
         s = jnp.einsum("bghwd,bgud->bghwu", qg, ks_,
                        preferred_element_type=F32) * scale
         al = al[:, :, None]
@@ -1170,25 +1176,18 @@ def window_contiguous(params: Dict, tokens, pos, cache, cfg, *,
     return hidden, new_cache
 
 
-def window_paged(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
-                 page_size: int, impl: str = "kernel", n_valid=None,
-                 active=None, slot=None, last_only=False, stats=None):
-    """The engine's window over its pool (:func:`pool_shapes`): pages
-    through ``block_tables`` for the sparse layers' K/V; their compressed
-    keys and the lightning layers' states are rows a slot — row ``b`` of
-    the batch is slot ``b`` (the decode tick, every slot a row), or with
-    ``slot`` the one row of a prefill chunk is that slot's. ``impl="kernel"`` runs the two Pallas
-    decode kernels when the window is one token; a longer window, and
-    ``impl="gather"`` always, gather and mask. Returns ``(logits, bufs)``;
-    ``stats``: :func:`_window`."""
+def _paged_mixer(cfg, bufs, new_bufs, block_tables, pos, n_valid, page_size,
+                 kernel, slot, tick):
+    """The ``mixer`` :func:`_window` calls for rows of the engine's pool:
+    layer ``i`` reads its buffers as the walk's last writer left them
+    (``new_bufs[i]``, else ``bufs[i]``) and leaves them in ``new_bufs[i]``.
+    ``kernel``: the Pallas decode kernels (one token a row); ``slot``: the
+    state row of a one-row prefill window; ``tick``: one token a row, so a
+    conv layer shifts its tails with no slice a row."""
     from ...ops.lightning_attention import lightning_decode_step
-    check_config(cfg)
-    pos, n_valid = _lanes(tokens, pos, n_valid, active)
-    kernel = impl == "kernel" and tokens.shape[1] == 1
-    new_bufs = [None] * cfg.layers
 
     def mixer(i, kind, lp, x, wpos):
-        c = bufs[i]
+        c = bufs[i] if new_bufs[i] is None else new_bufs[i]
         if kind == "sparse":
             y, new_bufs[i] = _sparse_paged(lp, x, wpos, pos, n_valid, c,
                                            block_tables, cfg, page_size,
@@ -1210,7 +1209,7 @@ def window_paged(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
                 y, new = _kda_layer(lp, x, rows, pos, n_valid, cfg, kernel)
             else:
                 y, tail = _conv_layer(lp, x, rows["conv"], pos, n_valid, cfg,
-                                      tokens.shape[1] == 1)
+                                      tick)
                 new = {"conv": tail}
             new_bufs[i] = new if slot is None else {
                 kk: jax.lax.dynamic_update_slice_in_dim(c[kk], new[kk], slot,
@@ -1233,6 +1232,71 @@ def window_paged(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
                            c["state"], st, slot, axis=0)}
         return _gated_out(lp, x, o, cfg, norm=True)
 
+    return mixer
+
+
+def window_paged(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
+                 page_size: int, impl: str = "kernel", n_valid=None,
+                 active=None, slot=None, last_only=False, stats=None):
+    """The engine's window over its pool (:func:`pool_shapes`): pages
+    through ``block_tables`` for the sparse layers' K/V; their compressed
+    keys and the lightning layers' states are rows a slot — row ``b`` of
+    the batch is slot ``b`` (the decode tick, every slot a row), or with
+    ``slot`` the one row of a prefill chunk is that slot's. ``impl="kernel"`` runs the two Pallas
+    decode kernels when the window is one token; a longer window, and
+    ``impl="gather"`` always, gather and mask. Returns ``(logits, bufs)``;
+    ``stats``: :func:`_window`."""
+    check_config(cfg)
+    pos, n_valid = _lanes(tokens, pos, n_valid, active)
+    one = tokens.shape[1] == 1
+    new_bufs = [None] * cfg.layers
+    mixer = _paged_mixer(cfg, bufs, new_bufs, block_tables, pos, n_valid,
+                         page_size, impl == "kernel" and one, slot, one)
     hidden = _window(params, tokens, pos, cfg, n_valid, mixer, last_only,
                      stats)
     return head(params, hidden), new_bufs
+
+
+def tick_with_window(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
+                     page_size: int, chunk, impl: str = "kernel",
+                     active=None, stats=None):
+    """The decode tick with ONE prefill window riding it: ``tokens``, ``pos``
+    (S,), a row a slot, as :func:`window_paged`'s one-token window, and
+    ``chunk = (ids (1, W), start (1,), bt_row (1, P), slot, n_valid (1,))``,
+    that function's one-row window of a slot the tick holds inactive. ONE
+    layer walk over the ``S + W`` tokens, each its own row of one lane: the
+    token table, every feed-forward (a routed layer's experts among them)
+    and the head are read once for both; a layer's mixer runs the window's
+    lanes in their gathered and masked form and then the tick's rows on the
+    decode kernels, each on its rows of the pool. Routing is per token and
+    dropless, so a token's feed-forward does not depend on the rows beside
+    it. Returns ``(the tick's logits (S, vocab), the logits (1, vocab) of
+    the window's lane n_valid - 1, bufs)``; ``stats`` counts every row the
+    step routed."""
+    check_config(cfg)
+    ids, start, bt_row, slot, n_chunk = chunk
+    S, W = tokens.shape[0], ids.shape[1]
+    pos, n_tick = _lanes(tokens[:, None], pos, None, active)
+    start, n_chunk = _lanes(ids, start, n_chunk, None)
+    wpos = start[:, None] + jnp.arange(W, dtype=jnp.int32)
+    new_bufs = [None] * cfg.layers
+    window = _paged_mixer(cfg, bufs, new_bufs, bt_row, start, n_chunk,
+                          page_size, False, slot, False)
+    tick = _paged_mixer(cfg, bufs, new_bufs, block_tables, pos, n_tick,
+                        page_size, impl == "kernel", None, True)
+
+    def mixer(i, kind, lp, x, _):
+        # the window first, as the chunk program ran before the tick did
+        yw = window(i, kind, lp, x[S:].reshape(1, W, -1), wpos)
+        yt = tick(i, kind, lp, x[:S], pos[:, None])
+        return jnp.concatenate([yt, yw.reshape(W, 1, -1)], axis=0)
+
+    real = (jnp.arange(W) < n_chunk[0]).astype(jnp.int32)
+    hidden = _window(params, jnp.concatenate([tokens, ids[0]])[:, None],
+                     jnp.concatenate([pos, wpos[0]]), cfg,
+                     jnp.concatenate([n_tick, real]), mixer, False,
+                     stats)[:, 0]
+    last = jax.lax.dynamic_slice_in_dim(
+        hidden, S + jnp.maximum(n_chunk[0] - 1, 0), 1, axis=0)
+    logits = head(params, jnp.concatenate([hidden[:S], last], axis=0))
+    return logits[:S], logits[S:], new_bufs

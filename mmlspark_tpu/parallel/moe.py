@@ -297,6 +297,11 @@ def moe_topk_held(x, x32, p, spec, valid, interpret=None):
     f32 = jnp.float32
     T, D = x.shape
     k, Eh = spec.per_token, spec.held
+    # a row that is no token holds whatever its mixer left (an idle row's
+    # context is no context): the placement products below sum over every
+    # row, and 0 x NaN is NaN, so such a row is 0 before it meets another
+    x = jnp.where(valid[:, None], x, jnp.zeros((), x.dtype))
+    x32 = jnp.where(valid[:, None], x32, 0.0)
     idx, weight = route_topk(x32, p["router"]["w"], p["bias"], spec)
     local = idx - spec.first
     held = (local >= 0) & (local < Eh) & valid[:, None]         # (T, k)

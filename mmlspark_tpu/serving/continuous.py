@@ -52,6 +52,7 @@ speculative gamma with the measured acceptance rate and the chunk budget
 with live slot occupancy.
 """
 
+import contextlib
 import functools
 import threading
 import time
@@ -83,6 +84,7 @@ from ..models.zoo.transformer import (TransformerConfig,
                                       paged_scatter_rows,
                                       prefill_cache, shardings_for)
 from ..models.zoo.hybrid import SLOT_KEYS as _SLOT_KEYS
+from ..models.zoo.hybrid import tick_with_window
 from ..ops.padding import bucket_size
 from ..ops.paged_attention import (resolve_impl as _resolve_paged_attn,
                                    _auto_interpret as _pa_auto_interpret)
@@ -217,7 +219,7 @@ def _tick_compiler_options():
 @functools.lru_cache(maxsize=None)
 def _tick_program(cfg, page, Lc, k, eos, sample, donate, attn="kernel",
                   mesh=None, slot_axis=None, head_axis=None,
-                  kv_dtype=None):
+                  kv_dtype=None, chunk=False):
     """The decode tick: k paged steps fused in one lax.scan. ``attn``
     (part of the cache key — the impl is baked in at trace time) selects
     the Pallas paged-attention kernel or the gather fallback. ``mesh``
@@ -227,44 +229,71 @@ def _tick_program(cfg, page, Lc, k, eos, sample, donate, attn="kernel",
     share a trace — the kernel mounts via shard_map under a mesh.
     ``kv_dtype`` ("int8"/"fp8"/None) likewise: the quantized and bf16
     data planes differ in buffer pytree structure AND kernel choice, and
-    must never share a program."""
+    must never share a program.
+
+    ``chunk`` (a hybrid decoder at ``k == 1``): the tick carries ONE prefill
+    window, the chunk program's ``(ids, start, bt_row, slot, n_valid)`` after
+    the tick's own arguments, through the same layer walk
+    (``hybrid.tick_with_window``: one read of the feed-forward weights and
+    the head for both), and returns the window's last-lane logits after the
+    token block. Still a ``tick``: the device trace and the pool's counters
+    take it for one tick and one chunk."""
     eos_const = None if eos is None else jnp.int32(eos)
 
-    def tick(params, tok, pos, active, bufs, bt, remaining,
-             temp=None, topk=None, topp=None, key=None):
-        def body(carry, _):
-            tok, pos, active, bufs, remaining = carry
-            counted = {}
+    def step(params, carry, bt, window, temp, topk, topp, key):
+        tok, pos, active, bufs, remaining = carry
+        counted = {}
+        if window is None:
+            last = None
             logits, bufs = decode_step_paged(
                 params, tok, pos, bufs, bt, cfg,
                 page_size=page, length=Lc, active=active, impl=attn,
                 mesh=mesh, slot_axis=slot_axis, head_axis=head_axis,
                 stats=counted)
-            if sample:
-                # emit position is pos+1 — generate_cached's key
-                # schedule (fold_in by absolute emit position), so
-                # sampled outputs are request-for-request
-                # identical to the offline generator
-                folded = jax.vmap(jax.random.fold_in)(key, pos + 1)
-                nxt = _sample_rows(logits.astype(jnp.float32),
-                                   temp, topk, topp, folded)
-            else:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt = jnp.where(active, nxt, tok)
-            pos = jnp.where(active, pos + 1, pos)
-            remaining = jnp.where(active, remaining - 1, remaining)
-            fin = remaining <= 0
-            if eos_const is not None:
-                fin = fin | (nxt == eos_const)
-            active = active & ~fin
-            # a routed decoder's counts ride out with the step's tokens, as
-            # columns past the slots': one fetch a block, as before
-            out = (jnp.concatenate([nxt, counted["moe"]])
-                   if "moe" in counted else nxt)
-            return (nxt, pos, active, bufs, remaining), out
-        carry, toks = jax.lax.scan(
-            body, (tok, pos, active, bufs, remaining), None, length=k)
-        return (*carry, toks)
+        else:
+            logits, last, bufs = tick_with_window(
+                params, tok, pos, bufs, bt, cfg, page_size=page,
+                chunk=window, impl=attn, active=active, stats=counted)
+        if sample:
+            # emit position is pos+1 — generate_cached's key
+            # schedule (fold_in by absolute emit position), so
+            # sampled outputs are request-for-request
+            # identical to the offline generator
+            folded = jax.vmap(jax.random.fold_in)(key, pos + 1)
+            nxt = _sample_rows(logits.astype(jnp.float32),
+                               temp, topk, topp, folded)
+        else:
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        nxt = jnp.where(active, nxt, tok)
+        pos = jnp.where(active, pos + 1, pos)
+        remaining = jnp.where(active, remaining - 1, remaining)
+        fin = remaining <= 0
+        if eos_const is not None:
+            fin = fin | (nxt == eos_const)
+        active = active & ~fin
+        # a routed decoder's counts ride out with the step's tokens, as
+        # columns past the slots': one fetch a block, as before
+        out = (jnp.concatenate([nxt, counted["moe"]])
+               if "moe" in counted else nxt)
+        return (nxt, pos, active, bufs, remaining), out, last
+
+    if chunk:
+        def tick(params, tok, pos, active, bufs, bt, remaining,
+                 ids, start, bt_row, slot, n_valid,
+                 temp=None, topk=None, topp=None, key=None):
+            carry, out, last = step(
+                params, (tok, pos, active, bufs, remaining), bt,
+                (ids, start, bt_row, slot, n_valid), temp, topk, topp, key)
+            return (*carry, out[None], last)
+    else:
+        def tick(params, tok, pos, active, bufs, bt, remaining,
+                 temp=None, topk=None, topp=None, key=None):
+            def body(carry, _):
+                return step(params, carry, bt, None,
+                            temp, topk, topp, key)[:2]
+            carry, toks = jax.lax.scan(
+                body, (tok, pos, active, bufs, remaining), None, length=k)
+            return (*carry, toks)
 
     return jax.jit(tick, donate_argnums=(1, 2, 3, 4, 6) if donate else (),
                    compiler_options=_tick_compiler_options())
@@ -985,6 +1014,28 @@ class ContinuousDecoder:
             cfg, page, Lc, self._k, self._eos, True, donate,
             self._attn_impl, mesh, slot_axis, head_axis,
             self._kv_dtype))
+        #: a hybrid decoder at one step a dispatch runs every prefill window
+        #: INSIDE a tick (``_tick_program``'s ``chunk``): with decodes live
+        #: the window rides their tick, one layer walk and one read of the
+        #: feed-forward weights for both; with none the tick's rows are all
+        #: inactive and the program is the chunk program, so a window
+        #: bucket compiles once whoever rides
+        self._carries = self._hybrid and self._k == 1
+        #: the narrowest window such a decoder pads a chunk to. A program
+        #: that carries a window holds the tick's kernels too and costs the
+        #: host 1.5-2 s to trace, lower and load whatever its width (PERF.md
+        #: section 6, PR 39), while a window under 64 lanes costs the device
+        #: nothing less than one of 64: half the programs for the same work
+        self._window_floor = (min(64, bucket_size(self._chunk))
+                              if self._carries else 8)
+        if self._carries:
+            self._tick_chunk, self._tick_chunk_sampled = (
+                _audit_program(name, _tick_program(
+                    cfg, page, Lc, 1, self._eos, sample, donate,
+                    self._attn_impl, mesh, slot_axis, head_axis,
+                    self._kv_dtype, chunk=True))
+                for name, sample in (("tick", False),
+                                     ("tick_sampled", True)))
         # per-call KV HBM traffic of one full sweep over the cache at
         # worst-case length, in the bytes the pool ACTUALLY stores — the
         # quantized plane shrinks this ~2x (int8 values + bf16 scales vs
@@ -1778,9 +1829,11 @@ class ContinuousDecoder:
         return min(cap if cap is not None else self._L,
                    max(8, bucket_size(n)))
 
-    def _padded_ids(self, tokens: np.ndarray, cap: int) -> np.ndarray:
-        """(1, bucketed) right-padded id row."""
-        ids = np.zeros((1, self._bucket(tokens.size, cap)), np.int32)
+    def _padded_ids(self, tokens: np.ndarray, cap: int,
+                    floor: int = 8) -> np.ndarray:
+        """(1, bucketed) right-padded id row, ``floor`` lanes at least."""
+        ids = np.zeros((1, self._bucket(max(tokens.size, floor), cap)),
+                       np.int32)
         ids[0, :tokens.size] = tokens
         return ids
 
@@ -1975,14 +2028,26 @@ class ContinuousDecoder:
         self._chunking[slot] = [req, 0]
         return True
 
+    def _decoding_slots(self):
+        """The slots a tick decodes: occupied and past their prefill."""
+        return [i for i in range(self._S) if self._slot_req[i] is not None
+                and i not in self._chunking]
+
     def _advance_chunks(self):
         """Run ONE prefill chunk for the oldest prefilling slot — at most
         one window forward per engine tick, so decode ticks interleave
         with long-prompt prefill and no tick's prefill work exceeds the
         chunk budget. The final chunk computes the first token and
-        activates the slot through the state-only insert."""
+        activates the slot through the state-only insert.
+
+        Where the tick carries the window (``_carries``) and decodes are
+        live, the window RIDES this step's tick: one dispatch, and the
+        return value is ``(the slots that decoded, their token block, the
+        dispatch's seconds)`` for :meth:`_step_locked` to account as its
+        tick; a row whose final chunk rode joins the next tick. Else
+        None."""
         if not self._chunking:
-            return
+            return None
         slot = next(iter(self._chunking))
         req, off = self._chunking[slot]
         P = req.prompt.size
@@ -1990,30 +2055,40 @@ class ContinuousDecoder:
         boundary = self._registering.get(slot)
         if boundary is not None:
             w = min(w, boundary - off)      # a chunk ends where the prefix does
-        ids = self._padded_ids(req.prompt[off:off + w], self._L - off)
+        ids = self._padded_ids(req.prompt[off:off + w], self._L - off,
+                               self._window_floor)
+        decode_live = self._decoding_slots() if self._carries else []
+        riding = bool(decode_live)
         t0 = time.perf_counter()
-        with _tracing.span("continuous.prefill_chunk", slot=slot,
-                        offset=off, tokens=w):
-            args = (self._params, jnp.asarray(ids),
-                    jnp.asarray([off], jnp.int32),
-                    self._kv.buffers, self._bt[slot:slot + 1])
+        with (_tracing.span("decoder.tick", live=len(decode_live), k=self._k)
+              if riding else contextlib.nullcontext()), \
+                _tracing.span("continuous.prefill_chunk", slot=slot,
+                              offset=off, tokens=w, riding=riding):
+            window = (jnp.asarray(ids), jnp.asarray([off], jnp.int32),
+                      self._bt[slot:slot + 1])
             if self._hybrid:
-                last, bufs = self._extend_paged(
-                    *args, jnp.asarray(slot, jnp.int32),
-                    jnp.asarray([w], jnp.int32))
+                window += (jnp.asarray(slot, jnp.int32),
+                           jnp.asarray([w], jnp.int32))
+            if self._carries:
+                toks, last = self._dispatch_tick(decode_live, window)
+            elif self._hybrid:
+                last, self._kv.buffers = self._extend_paged(
+                    self._params, *window[:2], self._kv.buffers, *window[2:])
             else:
-                w_logits, bufs = self._extend_paged(*args)
+                w_logits, self._kv.buffers = self._extend_paged(
+                    self._params, *window[:2], self._kv.buffers, window[2])
                 last = w_logits[:, w - 1]
-        self._kv.buffers = bufs
-        _ledger_charge("device_seconds", time.perf_counter() - t0,
-                       cls=req.cost_cls, trace_id=req.cost_trace)
+        seconds = time.perf_counter() - t0
+        if not riding:
+            _ledger_charge("device_seconds", seconds,
+                           cls=req.cost_cls, trace_id=req.cost_trace)
         self._kv.note_attn_tick(
             self._attn_impl,
             gather_bytes=(self._gather_bytes_extend
                           if self._attn_impl == "gather" else 0))
         self._note_sparse_ticks(off + w)
         self._note_sweep([off], ids.shape[1], 1, 1)
-        self._kv.note_prefill_chunk(w)
+        self._kv.note_prefill_chunk(w, riding=riding)
         self._chunk_trace.append(w)
         _tracing.add_event("prefill_chunk", slot=slot, offset=off,
                            tokens=w)
@@ -2029,15 +2104,16 @@ class ContinuousDecoder:
                         "logits": last})
         if off < P:
             self._chunking[slot][1] = off
-            return
-        del self._chunking[slot]
-        self.stats["prefills"] += 1
-        _M_PREFILLS.inc()
-        # first token from the last REAL lane of the final window —
-        # logits after consuming prompt position P-1, sampled at emit
-        # position P: generate_cached's exact schedule
-        self._insert_chunk_locked([(slot, req)], last, [],
-                           self._draft_prompt_rows(req))
+        else:
+            del self._chunking[slot]
+            self.stats["prefills"] += 1
+            _M_PREFILLS.inc()
+            # first token from the last REAL lane of the final window —
+            # logits after consuming prompt position P-1, sampled at emit
+            # position P: generate_cached's exact schedule
+            self._insert_chunk_locked([(slot, req)], last, [],
+                                      self._draft_prompt_rows(req))
+        return (decode_live, toks, seconds) if riding else None
 
     def _note_sweep(self, positions, window: int, rows: int,
                     calls: int) -> None:
@@ -2166,7 +2242,7 @@ class ContinuousDecoder:
         # this IS the chunked-prefill scheduler: long prompts never run
         # more than chunk-budget prefill work in any one tick
         with _watch("decoder_prefill"):
-            self._advance_chunks()
+            rode = self._advance_chunks()
         live = [i for i in range(self._S) if self._slot_req[i] is not None]
         _M_LIVE_SLOTS.set(len(live))
         if not live:
@@ -2180,7 +2256,8 @@ class ContinuousDecoder:
         # they must stay out of the tick snapshot (their device lanes
         # would replay tok=0 repeats as real tokens) and out of the
         # temperature checks
-        decode_live = [i for i in live if i not in self._chunking]
+        # (a window that rode a tick: that tick's slots, as they were then)
+        decode_live = rode[0] if rode else self._decoding_slots()
         if self._tuner is not None:
             self._tuner.observe(
                 len(live), self._S,
@@ -2192,13 +2269,18 @@ class ContinuousDecoder:
             while len(self._pending) > self._depth_now():
                 self._drain_one()
             return len(live)
-        tick_t0 = time.perf_counter()
-        with _tracing.span("decoder.tick", live=len(decode_live), k=self._k):
-            toks = self._dispatch_tick(decode_live)
+        if rode:
+            _, toks, tick_seconds = rode
+        else:
+            tick_t0 = time.perf_counter()
+            with _tracing.span("decoder.tick", live=len(decode_live),
+                               k=self._k):
+                toks = self._dispatch_tick(decode_live)
+            tick_seconds = time.perf_counter() - tick_t0
         # one dispatch covers every live decode slot: apportion its wall
         # time equally across the requests that rode it
         _get_ledger().charge_shares(
-            "device_seconds", time.perf_counter() - tick_t0,
+            "device_seconds", tick_seconds,
             [(self._slot_req[i].cost_cls, self._slot_req[i].cost_trace, 1.0)
              for i in decode_live])
         # per-dispatch attention accounting: k paged calls rode this
@@ -2238,9 +2320,11 @@ class ContinuousDecoder:
             self._drain_one()
         return len(live)
 
-    def _dispatch_tick(self, decode_live):
+    def _dispatch_tick(self, decode_live, window=()):
         """Enqueue one decode block for the live slots (no host sync);
-        returns the device token block."""
+        returns the device token block. ``window``: the chunk program's
+        arguments of a prefill window the tick carries (``_carries``), whose
+        last-lane logits are returned after the block."""
         if self._spec:
             gamma_now = (self._tuner.gamma if self._tuner is not None
                          else self._gamma)
@@ -2269,22 +2353,19 @@ class ContinuousDecoder:
             # dispatched slots here would include lanes already retired
             # on device, skewing the autotuner's acceptance estimate
             # low for the whole pipeline_depth window
-        elif any(self._slot_req[i].temperature > 0.0 for i in decode_live):
-            with _watch("decoder_decode"):
-                (self._tok, self._pos, self._active, bufs,
-                 self._remaining, toks) = self._tick_sampled(
-                    self._params, self._tok, self._pos, self._active,
-                    self._kv.buffers, self._bt, self._remaining,
-                    self._temp, self._topk, self._topp, self._key)
-            self._kv.buffers = bufs
+            return toks
+        args = (self._params, self._tok, self._pos, self._active,
+                self._kv.buffers, self._bt, self._remaining, *window)
+        if any(self._slot_req[i].temperature > 0.0 for i in decode_live):
+            tick = self._tick_chunk_sampled if window else \
+                self._tick_sampled
+            args += (self._temp, self._topk, self._topp, self._key)
         else:
-            with _watch("decoder_decode"):
-                (self._tok, self._pos, self._active, bufs,
-                 self._remaining, toks) = self._tick(
-                    self._params, self._tok, self._pos, self._active,
-                    self._kv.buffers, self._bt, self._remaining)
-            self._kv.buffers = bufs
-        return toks
+            tick = self._tick_chunk if window else self._tick
+        with _watch("decoder_decode"):
+            (self._tok, self._pos, self._active, self._kv.buffers,
+             self._remaining, toks, *last) = tick(*args)
+        return (toks, *last) if window else toks
 
     def _depth_now(self) -> int:
         """The live pipeline-depth bound: the autotuner's pick when it is

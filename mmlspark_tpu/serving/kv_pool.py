@@ -89,6 +89,11 @@ M_PREFILL_CHUNKS = _metric_counter(
 M_PREFILL_TOKENS = _metric_counter(
     "mmlspark_kvpool_prefill_tokens_total",
     "Prompt tokens the chunked-prefill scheduler's windows computed")
+M_PREFILL_CHUNKS_RIDING_SHARE = _metric_gauge(
+    "mmlspark_kvpool_prefill_chunks_riding_share",
+    "Prefill chunks whose window rode a decode tick (one dispatch, one read "
+    "of the feed-forward weights for both) over the prefill chunks executed, "
+    "since the pool was built")
 M_ALLOC_FAILURES = _metric_counter(
     "mmlspark_kvpool_alloc_failures_total",
     "Page allocations that failed even after prefix eviction")
@@ -263,7 +268,8 @@ class PagedKVPool:
         self.stats = {"page_size": self.page_size,
                       "pages_per_slot": self.pages_per_slot(slot_positions),
                       "prefix_share_hits": 0, "defrag_moves": 0,
-                      "prefill_chunks": 0, "prefill_tokens": 0,
+                      "prefill_chunks": 0, "prefill_chunks_riding": 0,
+                      "prefill_tokens": 0,
                       "alloc_failures": 0,
                       "gather_bytes": 0, "attn_ticks_kernel": 0,
                       "attn_ticks_gather": 0, "grid_steps": 0,
@@ -657,11 +663,16 @@ class PagedKVPool:
         prefills whole (and registers, where the store has room)."""
         self.stats["prefix_misses"] = self.stats.get("prefix_misses", 0) + 1
 
-    def note_prefill_chunk(self, ntok: int) -> None:
+    def note_prefill_chunk(self, ntok: int, riding: bool = False) -> None:
+        """A prefill chunk of ``ntok`` prompt tokens; ``riding``: its window
+        ran inside a decode tick's dispatch, not in one of its own."""
         self.stats["prefill_chunks"] += 1
+        self.stats["prefill_chunks_riding"] += bool(riding)
         self.stats["prefill_tokens"] += int(ntok)
         M_PREFILL_CHUNKS.inc()
         M_PREFILL_TOKENS.inc(int(ntok))
+        M_PREFILL_CHUNKS_RIDING_SHARE.set(
+            self.stats["prefill_chunks_riding"] / self.stats["prefill_chunks"])
 
     def note_attn_tick(self, impl: str, *, calls: int = 1,
                        gather_bytes: int = 0) -> None:

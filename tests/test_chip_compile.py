@@ -270,6 +270,20 @@ def test_lightning_step_kernel_compiles_in_place(one_chip):
     assert " copy(" not in text
 
 
+def _riding_tick(progs, cfg, z, params, pool, ints, one_chip, width):
+    """The tick that carries a ``width``-token prefill window (what a hybrid
+    engine at one step a dispatch runs for every chunk), lowered at the
+    cell's shapes."""
+    per = z["max_len"] // z["page"]
+    return progs._tick_program(
+        cfg, z["page"], z["max_len"], 1, None, False, True,
+        chunk=True).lower(
+            params, ints(z["slots"]), ints(z["slots"]),
+            one_chip((z["slots"],), bool), pool.buffers,
+            ints(z["slots"], per), ints(z["slots"]),
+            ints(1, width), ints(1), ints(1, per), ints(), ints(1))
+
+
 @pytest.fixture(scope="module")
 def sala_programs(one_chip):
     """The hybrid engine's programs lowered at the cell's shapes (the first
@@ -312,6 +326,8 @@ def sala_programs(one_chip):
             lowered[name] = extend.lower(
                 params, ints(1, width), ints(1), pool.buffers, ints(1, per),
                 ints(), ints(1))
+        lowered["riding"] = _riding_tick(progs, cfg, z, params, pool, ints,
+                                         one_chip, 64)
         yield {name: low.compile().as_text()
                for name, low in lowered.items()}, pool
     finally:
@@ -320,16 +336,17 @@ def sala_programs(one_chip):
         progs._extend_program.cache_clear()
 
 
-@pytest.mark.parametrize("program", ["tick", "chunk", "suffix"])
+@pytest.mark.parametrize("program", ["tick", "chunk", "suffix", "riding"])
 def test_hybrid_programs_compile_and_keep_the_pool_in_place(sala_programs,
                                                             program):
-    """The hybrid tick, a 256-token prefill chunk and a 64-token suffix
-    window at the published widths (8 slots, 32,768 positions): they compile
-    for the chip, the tick holds both decode kernels, and none copies a
-    pool-sized buffer (pages, compressed keys or the state rows)."""
+    """The hybrid tick, a 256-token prefill chunk, a 64-token suffix window
+    and the tick that carries such a window (``riding``) at the published
+    widths (8 slots, 32,768 positions): they compile for the chip, the ticks
+    hold both decode kernels, and none copies a pool-sized buffer (pages,
+    compressed keys or the state rows)."""
     texts, pool = sala_programs
     text = texts[program]
-    if program == "tick":
+    if program in ("tick", "riding"):
         assert "_pa_select_call" in text and "_lightning_step_call" in text
     names = {"bfloat16": "bf16", "float32": "f32"}
     for layer in pool.buffers:
@@ -432,8 +449,11 @@ def _share_programs(one_chip, z, config_file, driver, cut):
         extend = progs._extend_program(cfg, z["page"], z["max_len"], True)
         chunk = extend.lower(params, ints(1, z["chunk"]), ints(1),
                              pool.buffers, ints(1, per), ints(), ints(1))
+        riding = _riding_tick(progs, cfg, z, params, pool, ints, one_chip,
+                              z["chunk"])
         yield {"tick": tick.compile().as_text(),
-               "chunk": chunk.compile().as_text()}, pool
+               "chunk": chunk.compile().as_text(),
+               "riding": riding.compile().as_text()}, pool
     finally:
         pa._auto_interpret = progs._pa_auto_interpret = interpret
         progs._tick_program.cache_clear()
@@ -449,22 +469,43 @@ def ling_programs(one_chip):
                                "generate_ling", {})
 
 
-@pytest.mark.parametrize("program", ["tick", "chunk"])
+def _one_read_of_the_experts(text, cfg):
+    """A compiled program multiplies each routed layer's experts in ONE
+    grouped product (the tick that carries a window too: its rows and the
+    window's lanes share it) and copies no array shaped as a layer's expert
+    weights."""
+    r = cfg.routed
+    shapes = [f"bf16[{r.held},{cfg.d_model},{2 * r.d_expert}]",
+              f"bf16[{r.held},{r.d_expert},{cfg.d_model}]"]
+    lines = text.splitlines()
+    calls = sum("tpu_custom_call" in ln and "_moe_experts_call" in ln
+                for ln in lines)
+    assert calls == sum(kind == "moe" for kind in cfg.ffn)
+    copies = [ln.strip()[:120] for ln in lines
+              if " copy(" in ln and any(f"= {sh}" in ln for sh in shapes)]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk", "riding"])
 def test_routed_programs_compile_and_keep_the_pool_in_place(ling_programs,
                                                             program):
-    """The routed tick and a 256-token prefill chunk at the published widths
-    (32 slots, 4,096 positions): they compile for the chip, the tick holds
-    the three kernels and no sequential loop (a gather of rows), and neither
-    copies a pool-sized buffer: the states, the convolution tails' slots
-    aside (2 MB, laid out by the chip), or the latent pages, whose row is
-    padded to whole registers so that the pool stays row-major."""
+    """The routed tick, a 256-token prefill chunk and the tick that carries
+    one (``riding``) at the published widths (32 slots, 4,096 positions):
+    they compile for the chip, the ticks hold the three kernels and the plain
+    one no sequential loop (a gather of rows), each routed layer's experts
+    are multiplied once and never copied, and none copies a pool-sized
+    buffer: the states, the convolution tails' slots aside (2 MB, laid out
+    by the chip), or the latent pages, whose row is padded to whole registers
+    so that the pool stays row-major."""
     texts, pool = ling_programs
     text = texts[program]
-    if program == "tick":
+    if program in ("tick", "riding"):
         for name in ("_moe_experts_call", "_kda_step_call",
                      "_pa_latent_call"):
             assert name in text
+    if program == "tick":
         assert " while(" not in text
+    _one_read_of_the_experts(text, pool.cfg)
     names = {"bfloat16": "bf16", "float32": "f32"}
     for layer in pool.buffers:
         for key, buf in layer.items():
@@ -533,19 +574,22 @@ def lfm2_programs(one_chip):
         dict(layer_types=["conv", "full_attention", "conv"]))
 
 
-@pytest.mark.parametrize("program", ["tick", "chunk"])
+@pytest.mark.parametrize("program", ["tick", "chunk", "riding"])
 def test_conv_gqa_programs_compile_and_keep_the_pool_in_place(lfm2_programs,
                                                               program):
-    """The tick and a 512-token prefill chunk at the published widths (32
-    slots, 5,120 positions): they compile for the chip, the tick holds the
-    grouped-query kernel and the experts' product and no sequential loop (a
-    slice a row of the tails would be one), and neither copies the page
-    pool (the tails' slots aside: 0.26 MB a layer, laid out by the chip)."""
+    """The tick, a 512-token prefill chunk and the tick that carries one
+    (``riding``) at the published widths (32 slots, 5,120 positions): they
+    compile for the chip, the ticks hold the grouped-query kernel and the
+    experts' product, ONE a routed layer with no copy of its weights, the
+    plain tick no sequential loop (a slice a row of the tails would be one),
+    and none copies the page pool (the tails' slots aside: 0.26 MB a layer,
+    laid out by the chip)."""
     texts, pool = lfm2_programs
     text = texts[program]
-    assert "_moe_experts_call" in text
-    if program == "tick":
+    _one_read_of_the_experts(text, pool.cfg)
+    if program in ("tick", "riding"):
         assert "_pa_gqa_call" in text
+    if program == "tick":
         assert " while(" not in text
     for layer in pool.buffers:
         for key, buf in layer.items():

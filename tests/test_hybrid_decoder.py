@@ -40,8 +40,7 @@ REFERENCE = bench_run.load_by_path("references", "minicpm_sala")
 DRIVER = bench_run.load_by_path("drivers", "generate_docs")
 
 
-@pytest.fixture(scope="module")
-def sizes():
+def tiny_sizes():
     """The benchmark's configuration file with its widths shrunk: every key
     the reference and the driver's mapping read is the real file's."""
     with open(os.path.join(bench_run.HERE, "configs",
@@ -55,6 +54,11 @@ def sizes():
         param_dtype="float32",
         mixer_types=["minicpm4", "lightning-attn", "lightning-attn",
                      "minicpm4"])
+
+
+@pytest.fixture(scope="module")
+def sizes():
+    return tiny_sizes()
 
 
 @pytest.fixture(scope="module")

@@ -38,8 +38,7 @@ F32 = jnp.float32
 CONFIG = os.path.join(bench_run.HERE, "configs", "lfm2_24b_a2b_pp5_l9.json")
 
 
-@pytest.fixture(scope="module")
-def sizes():
+def tiny_sizes():
     """The benchmark's configuration file with its widths shrunk: every key
     the reference and the driver's mapping read is the real file's."""
     with open(CONFIG) as fh:
@@ -51,6 +50,11 @@ def sizes():
         layer_types=["conv", "conv", "full_attention", "conv"],
         layers_held=[0, 8, 10, 11], num_hidden_layers=4,
         compute_dtype="float32", param_dtype="float32")
+
+
+@pytest.fixture(scope="module")
+def sizes():
+    return tiny_sizes()
 
 
 @pytest.fixture(scope="module")

@@ -40,8 +40,7 @@ DRIVER = bench_run.load_by_path("drivers", "generate_ling")
 F32 = jnp.float32
 
 
-@pytest.fixture(scope="module")
-def sizes():
+def tiny_sizes():
     """The benchmark's configuration file with its widths shrunk: every key
     the reference and the driver's mapping read is the real file's."""
     with open(os.path.join(bench_run.HERE, "configs",
@@ -56,6 +55,11 @@ def sizes():
         published=dict(config["published"], num_experts=32),
         vocab_size=VOCAB, layer_group_size=3, layers_held=[0, 2, 3, 4, 5],
         num_hidden_layers=5, compute_dtype="float32", param_dtype="float32")
+
+
+@pytest.fixture(scope="module")
+def sizes():
+    return tiny_sizes()
 
 
 @pytest.fixture(scope="module")

@@ -251,9 +251,11 @@ def test_routing_counters_ride_out_with_the_tokens(routed_run, count):
     else:
         assert stats[f"moe_{count}"] > 0
     # two experts a token, one routed layer: a decode step of n live rows
-    # routes 2 n pairs, at most 2 rows a step
+    # routes 2 n pairs, at most 2 rows a step; a step counts ALL the rows it
+    # routed, so a prefill window that rode a tick adds 2 a token of its own
+    assert stats["prefill_chunks_riding"] > 0
     assert stats["moe_pairs_held"] <= stats["moe_pairs_routed"] \
-        <= 2 * 2 * stats["attn_ticks_kda"]
+        <= 2 * (2 * stats["attn_ticks_kda"] + stats["prefill_tokens"])
 
 
 @pytest.mark.parametrize("label", ROUTED_TICK_LABELS)
