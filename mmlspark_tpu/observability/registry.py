@@ -22,9 +22,11 @@ constraints, in order:
 from __future__ import annotations
 
 import bisect
+import gc
 import sys
 import threading
 import time
+from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 __all__ = [
@@ -44,6 +46,9 @@ __all__ = [
     "exemplar_provider",
     "build_info",
     "process_uptime_seconds",
+    "recent_gc_pauses",
+    "GC_PAUSE_FLOOR_S",
+    "RECENT_GC_PAUSES",
 ]
 
 #: Wall-clock at first observability import — the process-uptime epoch
@@ -70,6 +75,72 @@ def exemplar_provider() -> Optional[Callable[[], Optional[str]]]:
 
 def process_uptime_seconds() -> float:
     return time.time() - _PROCESS_START
+
+
+# -- the collector's pauses ---------------------------------------------------
+# A collection of Python's garbage collector holds the GIL from start to
+# stop: every thread of the process stands still, which from outside looks
+# like a stalled device. ONE ``gc.callbacks`` hook times each collection.
+# It touches plain module state only and no lock: a collection can begin at
+# any bytecode of any thread, also of one that holds a metric's lock, so a
+# hook that took one could wait for itself. Collections do not nest (the
+# collector refuses to start while one runs), so one start time is enough.
+# The two counters are brought up to date when the registry is read.
+
+#: A pause this long is kept with its start (:func:`recent_gc_pauses`).
+GC_PAUSE_FLOOR_S = 1e-3
+#: How many such pauses :func:`recent_gc_pauses` reaches back.
+RECENT_GC_PAUSES = 4096
+_GC_PAUSES: deque = deque(maxlen=RECENT_GC_PAUSES)
+_GC_STARTED_AT = 0.0
+_GC_SECONDS = [0.0, 0.0, 0.0]       # by generation, since the hook went in
+_GC_COLLECTIONS = [0, 0, 0]
+#: (counter, help, what the hook counted, what the counter holds of it)
+_GC_SERIES = (
+    ("mmlspark_process_gc_pause_seconds_total",
+     "Seconds inside collections of Python's garbage collector, every "
+     "thread stopped", _GC_SECONDS, [0.0, 0.0, 0.0]),
+    ("mmlspark_process_gc_collections_total",
+     "Collections of Python's garbage collector", _GC_COLLECTIONS,
+     [0, 0, 0]))
+_GC_PUBLISH_LOCK = threading.Lock()
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    global _GC_STARTED_AT
+    if phase == "start":
+        _GC_STARTED_AT = time.perf_counter()
+        return
+    seconds = time.perf_counter() - _GC_STARTED_AT
+    generation = info["generation"]
+    _GC_SECONDS[generation] += seconds
+    _GC_COLLECTIONS[generation] += 1
+    if seconds >= GC_PAUSE_FLOOR_S:
+        _GC_PAUSES.append((_GC_STARTED_AT, seconds, generation))
+
+
+gc.callbacks.append(_on_gc)
+
+
+def recent_gc_pauses() -> List[Tuple[float, float, int]]:
+    """``[(started_at, seconds, generation), ...]``: the last
+    :data:`RECENT_GC_PAUSES` collections of Python's garbage collector that
+    took :data:`GC_PAUSE_FLOOR_S` or more, oldest first, ``started_at`` in
+    ``time.perf_counter()`` seconds. Every thread stood still for each; a
+    list shorter than :data:`RECENT_GC_PAUSES` has dropped none."""
+    return list(_GC_PAUSES)
+
+
+def _publish_gc() -> None:
+    """Add what the hook has counted since the last call to the two
+    counters of ``_GC_SERIES``."""
+    with _GC_PUBLISH_LOCK:
+        for name, help_, counted, published in _GC_SERIES:
+            metric = counter(name, help_, ("generation",))
+            for generation, value in enumerate(counted):
+                metric.inc(value - published[generation],
+                           generation=generation)
+                published[generation] = value
 
 #: Default histogram boundaries, tuned for batch-inference latencies: the
 #: sub-millisecond region resolves per-stage host work (coerce/pad), the
@@ -346,6 +417,8 @@ class MetricsRegistry:
                                    buckets=buckets)
 
     def metrics(self) -> List[_Metric]:
+        if self is _REGISTRY:
+            _publish_gc()
         with self._lock:
             return [self._metrics[n] for n in sorted(self._metrics)]
 

@@ -40,9 +40,11 @@ from __future__ import annotations
 
 import contextvars
 import functools
+import itertools
 import os
 import threading
 import time
+from array import array
 from collections import OrderedDict, deque
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -418,6 +420,68 @@ def propagate(fn: Callable) -> Callable:
 #: imports before jax); False where jax is not installed.
 _ANNOTATION: object = None
 
+class _SpanRing:
+    """The last ``maxlen`` closed spans ``(name, thread ident, start, end)``
+    in columns made once, at the first row: a name's reference and three
+    machine integers a row, 32 bytes and no object of its own (a deque of
+    tuples is ~190 bytes a row, and until it is full it takes new memory
+    for every row all through a run). ``append`` takes no lock: a slot is
+    claimed by one atomic step of a counter. ``rows`` is for a reader that
+    runs once the writers are quiet: beside a running writer it can return
+    a slot's older row."""
+
+    __slots__ = ("maxlen", "_next", "_names", "_threads", "_starts", "_ends",
+                 "_skipped", "_lock")
+
+    def __init__(self, maxlen: int):
+        self.maxlen = maxlen
+        self._lock = threading.Lock()
+        self.clear()
+
+    def clear(self) -> None:
+        self._names: Optional[list] = None
+        self._next = itertools.count()
+        #: the counter's steps that ``rows`` took to learn how far it is:
+        #: their slots hold no row of theirs
+        self._skipped: set = set()
+
+    def _columns(self) -> list:
+        with self._lock:
+            if self._names is None:
+                zeros = bytes(8 * self.maxlen)
+                self._threads = array("Q", zeros)
+                self._starts = array("q", zeros)
+                self._ends = array("q", zeros)
+                self._names = [None] * self.maxlen
+            return self._names
+
+    def append(self, row: Tuple[str, int, int, int]) -> None:
+        names = self._names
+        if names is None:
+            names = self._columns()
+        i = next(self._next) % self.maxlen
+        names[i], self._threads[i], self._starts[i], self._ends[i] = row
+
+    def rows(self) -> List[Tuple[str, int, int, int]]:
+        """Oldest first, in closing order."""
+        names = self._names
+        if names is None:
+            return []
+        with self._lock:
+            n = next(self._next)        # so many steps so far; this is one
+            first = max(0, n - self.maxlen)
+            self._skipped = {j for j in self._skipped if j >= first} | {n}
+            skipped = self._skipped
+        size = self.maxlen
+        return [(names[j % size], self._threads[j % size],
+                 self._starts[j % size], self._ends[j % size])
+                for j in range(first, n)
+                if j not in skipped and names[j % size] is not None]
+
+    def __len__(self) -> int:
+        return len(self.rows())
+
+
 #: STOPGAP, not an operator feature. The benchmark's captures are taken at
 #: ``host_tracer_level=0`` (no TraceMe recorded, so no annotation either),
 #: and its harness is not this module's to change: until it records host
@@ -425,16 +489,27 @@ _ANNOTATION: object = None
 #: spans are kept here, ``(name, thread ident, start, end)`` in
 #: ``time.time_ns()``, the clock the profiler stamps its events with, so a
 #: device-only trace can be laid over them. One constant ring, no knob:
-#: memory is bounded, appends take no lock. Goes when its one reader
-#: (``benchmarks/idle_gaps.py``) reads the trace's host plane instead.
-_SPAN_LOG: deque = deque(maxlen=65536)
+#: memory is bounded, appends take no lock. Goes when its readers
+#: (``benchmarks/idle_gaps.py`` and what is built on it) read the trace's
+#: host plane instead.
+#:
+#: Its size: the benchmark traces the FIRST seconds of a 51 s window and
+#: reads the ring after the window, its drain and the engine's stop, so
+#: the ring must hold rows a round x rounds a second x ~60 s and the traced
+#: stretch is then its oldest part. An engine round writes 10 rows (12 with
+#: a prefill chunk): 1,400 rows/s at GPT-2 XL's 133 rounds/s (86,896 rows
+#: at the read in a cold process), 1,050 at 32 streams' 100 rounds/s, the
+#: transform path ~120. 262,144 rows hold ~3 minutes of the fastest cell:
+#: 8 MB in all (32 bytes a row), taken when the first span closes. A reader
+#: that finds the ring's oldest row inside its stretch must give no number.
+_SPAN_LOG = _SpanRing(262144)
 
 
 def span_log() -> List[Tuple[str, int, int, int]]:
     """``[(name, thread ident, start_ns, end_ns), ...]`` of the last closed
     spans, in closing order (an inner span closes before the one around
     it). See ``_SPAN_LOG``: a stopgap for the benchmark."""
-    return list(_SPAN_LOG)
+    return _SPAN_LOG.rows()
 
 
 class _Span:

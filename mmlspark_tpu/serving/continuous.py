@@ -1103,7 +1103,13 @@ class ContinuousDecoder:
         #: adds the engine-facing key, LRU promotion and FIFO eviction
         self._prefix_store_cap = int(prefix_cache_size)
         #: observability: prefill vs prefix-hit counts (tests + ops)
-        self.stats = {"prefills": 0, "prefix_hits": 0}
+        #: ``ticks``: decode dispatches (counted where ``decoder.tick``
+        #: opens, a tick a window rides included); ``drain_seconds``: the
+        #: seconds inside ``continuous.drain``, the one wait for the device
+        #: (the sum ``mmlspark_continuous_drain_seconds`` keeps). The
+        #: engine's round log reads both (``generation.recent_rounds``).
+        self.stats = {"prefills": 0, "prefix_hits": 0, "ticks": 0,
+                      "drain_seconds": 0.0}
 
         # group insert + first tokens (see the module factories)
         self._insert_group_j = _audit_program(
@@ -1130,9 +1136,6 @@ class ContinuousDecoder:
         #: slot → prefix length at which a hybrid decoder's chunked prefill
         #: owes the prefix store a registration (pages plus state snapshot)
         self._registering: Dict[int, int] = {}
-        #: recent chunk sizes in tokens (tests + bench assert the budget
-        #: bound from this)
-        self._chunk_trace: List[int] = []
         self._prefix_store: Dict[str, tuple] = {}
         if self._spec:
             dshape, dcfg = self._d_cache_shape, self._d_cfg
@@ -2059,6 +2062,7 @@ class ContinuousDecoder:
                                self._window_floor)
         decode_live = self._decoding_slots() if self._carries else []
         riding = bool(decode_live)
+        self.stats["ticks"] += riding
         t0 = time.perf_counter()
         with (_tracing.span("decoder.tick", live=len(decode_live), k=self._k)
               if riding else contextlib.nullcontext()), \
@@ -2079,19 +2083,17 @@ class ContinuousDecoder:
                     self._params, *window[:2], self._kv.buffers, window[2])
                 last = w_logits[:, w - 1]
         seconds = time.perf_counter() - t0
-        if not riding:
-            _ledger_charge("device_seconds", seconds,
-                           cls=req.cost_cls, trace_id=req.cost_trace)
-        self._kv.note_attn_tick(
-            self._attn_impl,
-            gather_bytes=(self._gather_bytes_extend
-                          if self._attn_impl == "gather" else 0))
-        self._note_sparse_ticks(off + w)
-        self._note_sweep([off], ids.shape[1], 1, 1)
-        self._kv.note_prefill_chunk(w, riding=riding)
-        self._chunk_trace.append(w)
-        _tracing.add_event("prefill_chunk", slot=slot, offset=off,
-                           tokens=w)
+        with _tracing.span("decoder.account"):
+            if not riding:
+                _ledger_charge("device_seconds", seconds,
+                               cls=req.cost_cls, trace_id=req.cost_trace)
+            self._kv.note_attn_tick(
+                self._attn_impl,
+                gather_bytes=(self._gather_bytes_extend
+                              if self._attn_impl == "gather" else 0))
+            self._note_sparse_ticks(off + w)
+            self._note_sweep([off], ids.shape[1], 1, 1)
+            self._kv.note_prefill_chunk(w, riding=riding)
         off += w
         if off == boundary:
             del self._registering[slot]
@@ -2272,43 +2274,47 @@ class ContinuousDecoder:
         if rode:
             _, toks, tick_seconds = rode
         else:
+            self.stats["ticks"] += 1
             tick_t0 = time.perf_counter()
             with _tracing.span("decoder.tick", live=len(decode_live),
                                k=self._k):
                 toks = self._dispatch_tick(decode_live)
             tick_seconds = time.perf_counter() - tick_t0
-        # one dispatch covers every live decode slot: apportion its wall
-        # time equally across the requests that rode it
-        _get_ledger().charge_shares(
-            "device_seconds", tick_seconds,
-            [(self._slot_req[i].cost_cls, self._slot_req[i].cost_trace, 1.0)
-             for i in decode_live])
-        # per-dispatch attention accounting: k paged calls rode this
-        # dispatch; only the gather impl moves materialization bytes
-        self._kv.note_attn_tick(
-            self._attn_impl, calls=self._k,
-            gather_bytes=(self._k * self._gather_bytes_tick
-                          if self._attn_impl == "gather" else 0))
-        self._note_sparse_ticks(
-            max(self._slot_req[i].prompt.size + len(self._slot_req[i].tokens)
-                for i in decode_live), calls=self._k)
-        self._note_mixer_ticks(self._k)
-        # a row's device position: its drained tokens plus those of the
-        # blocks still in flight (a first-token block carries one, a tick's
-        # k; this tick's is not yet pending)
-        self._note_sweep(
-            [req.prompt.size + len(req.tokens) - 1
-             + sum(min(toks.shape[0], self._k)
-                   for toks, block in self._pending
-                   if any(r is req for _, r in block.values()))
-             for req in (self._slot_req[i] for i in decode_live)],
-            self._gamma + 1 if self._spec else 1, self._S, self._k)
-        # snapshot slot→REQUEST (not indices): by the time this block is
-        # drained, a slot may have been freed and re-admitted; tokens must
-        # go to the request that occupied the slot at DISPATCH time (its
-        # done guard discards the inactive-slot repeats)
-        self._pending.append((toks, {i: (i, self._slot_req[i])
-                                     for i in decode_live}))
+        with _tracing.span("decoder.account"):
+            # one dispatch covers every live decode slot: apportion its
+            # wall time equally across the requests that rode it
+            _get_ledger().charge_shares(
+                "device_seconds", tick_seconds,
+                [(self._slot_req[i].cost_cls, self._slot_req[i].cost_trace,
+                  1.0) for i in decode_live])
+            # per-dispatch attention accounting: k paged calls rode this
+            # dispatch; only the gather impl moves materialization bytes
+            self._kv.note_attn_tick(
+                self._attn_impl, calls=self._k,
+                gather_bytes=(self._k * self._gather_bytes_tick
+                              if self._attn_impl == "gather" else 0))
+            self._note_sparse_ticks(
+                max(self._slot_req[i].prompt.size
+                    + len(self._slot_req[i].tokens)
+                    for i in decode_live), calls=self._k)
+            self._note_mixer_ticks(self._k)
+            # a row's device position: its drained tokens plus those of
+            # the blocks still in flight (a first-token block carries one,
+            # a tick's k; this tick's is not yet pending)
+            self._note_sweep(
+                [req.prompt.size + len(req.tokens) - 1
+                 + sum(min(toks.shape[0], self._k)
+                       for toks, block in self._pending
+                       if any(r is req for _, r in block.values()))
+                 for req in (self._slot_req[i] for i in decode_live)],
+                self._gamma + 1 if self._spec else 1, self._S, self._k)
+            # snapshot slot→REQUEST (not indices): by the time this block
+            # is drained, a slot may have been freed and re-admitted;
+            # tokens must go to the request that occupied the slot at
+            # DISPATCH time (its done guard discards the inactive-slot
+            # repeats)
+            self._pending.append((toks, {i: (i, self._slot_req[i])
+                                         for i in decode_live}))
         # prefill-ahead: with the decode block dispatched (device busy for
         # k steps), background-prefill waiting prompts into the stage
         if self._stage_cap:
@@ -2397,17 +2403,27 @@ class ContinuousDecoder:
         # the np.asarray is the decode path's only host↔device sync — the
         # exact line a wedged device parks forever, so the watchdog covers it
         drain_t0 = time.perf_counter()
-        with _M_DRAIN_SECONDS.time(), _tracing.span("continuous.drain"), \
-                _watch("decoder_drain"):
+        with _tracing.span("continuous.drain"), _watch("decoder_drain"):
             toks = np.asarray(toks_dev)
+        drained = time.perf_counter() - drain_t0
+        _M_DRAIN_SECONDS.observe(drained)
+        self.stats["drain_seconds"] += drained
+        with _tracing.span("decoder.retire"):
+            self._retire(toks, snapshot, drained)
+
+    def _retire(self, toks, snapshot, drained: float):
+        """What the host does with a drained block: the routed counts, its
+        tokens to their requests, the journal, the slots of finished
+        requests released (``decoder.retire``)."""
         if toks.shape[1] > self._S:
             # a routed decoder's tick: its counts beside its tokens
             self._kv.note_moe(toks[:, self._S:])
             toks = toks[:, :self._S]
-        _get_ledger().charge_shares(
-            "device_seconds", time.perf_counter() - drain_t0,
-            [(req.cost_cls, req.cost_trace, 1.0)
-             for _, (_, req) in snapshot.items()])
+        with _tracing.span("decoder.account"):
+            _get_ledger().charge_shares(
+                "device_seconds", drained,
+                [(req.cost_cls, req.cost_trace, 1.0)
+                 for _, (_, req) in snapshot.items()])
         if self._spec and toks.shape[0] > 1:
             # spec blocks mark unemitted lanes -1. Both acceptance
             # counters come from THIS block so they cover the same

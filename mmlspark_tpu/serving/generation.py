@@ -19,18 +19,22 @@ from __future__ import annotations
 import json
 import logging
 import threading
+import time
 import traceback
 from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
+from ..observability import (counter as _metric_counter,
+                             histogram as _metric_histogram)
 from ..observability import tracing as _tracing
 from .continuous import ContinuousDecoder
 from .server import StreamingReply, WorkerServer
 
-__all__ = ["GenerationEngine", "recent_timelines", "RECENT_TIMELINES"]
+__all__ = ["GenerationEngine", "Round", "recent_rounds", "recent_timelines",
+           "RECENT_ROUNDS", "RECENT_TIMELINES"]
 
 _log = logging.getLogger("mmlspark_tpu.serving")
 
@@ -42,13 +46,68 @@ _RECENT: "deque[Dict[str, object]]" = deque(maxlen=RECENT_TIMELINES)
 def recent_timelines() -> List[Dict[str, object]]:
     """The timelines (``submitted_at``, ``admitted_at``, ``first_token_at``,
     ``finished_at`` in ``time.perf_counter()`` seconds, ``prompt_tokens``,
-    ``new_tokens``) of the last :data:`RECENT_TIMELINES` requests this
-    process's engines replied to, oldest first: what each request's root
-    span closed with. The histograms keep the distribution since start and
+    ``new_tokens``; a streamed request's also ``writes``,
+    ``write_lag_sum_s``, ``write_lag_max_s``: its chunks' wait in the
+    transport, ``StreamingReply.write_lag``, as the engine finished it)
+    of the last :data:`RECENT_TIMELINES` requests this process's engines
+    replied to, oldest first: what each request's root span closed with.
+    The histograms keep the distribution since start and
     the flight recorder the traces it judges worth an operator's look;
     a percentile over one stretch of traffic needs every request of it,
     and a list shorter than :data:`RECENT_TIMELINES` has dropped none."""
     return list(_RECENT)
+
+
+class Round(NamedTuple):
+    """One round of an engine's loop (admit, step, pump, reply), as the
+    engine's thread accounted for it: what a span's wall time cannot tell
+    apart, its seconds on the CPU, waiting for the device, and neither."""
+    #: ``time.perf_counter()`` at the round's end; the round began
+    #: ``wall_s`` before (rounds follow each other without a gap, but for
+    #: the loop's idle sleep, which belongs to none)
+    ended_at: float
+    wall_s: float
+    #: the engine thread's CPU seconds in the round (``time.thread_time``;
+    #: where the kernel accounts CPU time by its timer tick, 10 ms on the
+    #: benchmark's machine, a single round's is 0 or a tick: read sums)
+    cpu_s: float
+    #: its seconds inside ``continuous.drain``, waiting for the device;
+    #: ``wall_s - cpu_s - wait_s`` is what it spent off the CPU otherwise:
+    #: waiting for the GIL, for a lock, in a blocking call
+    wait_s: float
+    #: decode dispatches (``decoder.tick`` spans opened)
+    ticks: int
+    #: token events handed to streaming replies, and the tokens they carry
+    stream_events: int
+    stream_tokens: int
+
+
+#: How many rounds :func:`recent_rounds` reaches back: two minutes at 133
+#: rounds a second (the fastest benchmark cell's).
+RECENT_ROUNDS = 16384
+_ROUNDS: "deque[Round]" = deque(maxlen=RECENT_ROUNDS)
+
+_M_ROUND_SECONDS = _metric_histogram(
+    "mmlspark_generation_round_seconds",
+    "One round of the generation engine's loop (admit, step, pump, reply) "
+    "on the wall clock",
+    buckets=(0.0005, 0.001, 0.002, 0.003, 0.004, 0.006, 0.008, 0.01, 0.015,
+             0.02, 0.03, 0.05, 0.1, 0.25, 1.0, 5.0))
+_M_ROUND_CPU = _metric_counter(
+    "mmlspark_generation_round_cpu_seconds_total",
+    "CPU seconds of the generation engine's thread inside its rounds")
+_M_STREAM_EVENTS = _metric_counter(
+    "mmlspark_generation_stream_events_total",
+    "Token events handed to streaming replies")
+_M_STREAM_TOKENS = _metric_counter(
+    "mmlspark_generation_stream_tokens_total",
+    "Tokens those events carried")
+
+
+def recent_rounds() -> List[Round]:
+    """The last :data:`RECENT_ROUNDS` rounds of this process's engines,
+    oldest first (a list that long may have dropped some)."""
+    return list(_ROUNDS)
 
 
 @dataclass
@@ -95,6 +154,9 @@ class GenerationEngine:
         #: decoder rid -> _InFlight — ONE source of truth for in-flight
         #: work, mutated at one site per transition
         self._inflight: Dict[int, _InFlight] = {}
+        #: what ``_pump_streams`` has sent since the engine was built
+        self._stream_events = 0
+        self._stream_tokens = 0
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -152,6 +214,7 @@ class GenerationEngine:
 
     def _pump_streams(self) -> None:
         """Push newly emitted tokens on every streaming reply."""
+        events = tokens = 0
         for f in self._inflight.values():
             if f.stream is None:
                 continue
@@ -159,8 +222,16 @@ class GenerationEngine:
             if fresh:
                 f.stream.send_event({"tokens": list(fresh)})
                 f.sent += len(fresh)
+                events += 1
+                tokens += len(fresh)
+        self._stream_events += events
+        self._stream_tokens += tokens
 
     def _reply_finished(self) -> None:
+        """Answer the requests that are done. A streamed request's
+        timeline also says what its chunks waited in the transport
+        (``StreamingReply.write_lag``) AS OF NOW: the closing event is not
+        yet sent, and a chunk of this round's pump may not be written."""
         done = [drid for drid, f in self._inflight.items()
                 if f.ticket.done]
         for drid in done:
@@ -168,6 +239,8 @@ class GenerationEngine:
             rid, ticket, handle = f.rid, f.ticket, f.stream
             err = getattr(ticket, "error", None)
             timeline = ticket.timeline()
+            if handle is not None:
+                timeline.update(handle.write_lag())
             _RECENT.append(timeline)
             # the root span closes with the request's timeline: at the
             # stream's close, or with the one reply
@@ -191,8 +264,17 @@ class GenerationEngine:
         if done:
             self.server.commit_epoch()
 
+    def _marks(self):
+        """The clocks and the running sums a :class:`Round` is the
+        difference of, in its fields' order."""
+        stats = self.decoder.stats
+        return (time.perf_counter(), time.thread_time(),
+                stats["drain_seconds"], stats["ticks"],
+                self._stream_events, self._stream_tokens)
+
     def _loop(self) -> None:
         span = _tracing.span
+        mark = self._marks()
         while not self._stop.is_set():
             try:
                 with span("engine.admit_http"):
@@ -202,9 +284,19 @@ class GenerationEngine:
                     self._pump_streams()
                 with span("engine.reply_finished"):
                     self._reply_finished()
+                now = self._marks()
+                row = Round(now[0], *(b - a for a, b in zip(mark, now)))
+                mark = now
+                _ROUNDS.append(row)
+                _M_ROUND_SECONDS.observe(row.wall_s)
+                _M_ROUND_CPU.inc(row.cpu_s)
+                if row.stream_events:
+                    _M_STREAM_EVENTS.inc(row.stream_events)
+                    _M_STREAM_TOKENS.inc(row.stream_tokens)
                 if stepped == 0 and not self._inflight:
                     with span("engine.idle"):
                         self._stop.wait(0.005)
+                    mark = self._marks()    # the sleep is no round's
             except Exception:
                 _log.error("generation engine tick failed:\n%s",
                            traceback.format_exc())

@@ -81,6 +81,11 @@ _M_HBM_IN_USE = _metric_gauge(
 _M_SHED = _metric_counter(
     "mmlspark_requests_shed_total",
     "Requests rejected 429 by bounded-queue admission control")
+_M_WRITE_LAG = _metric_histogram(
+    "mmlspark_serving_stream_write_lag_seconds",
+    "The longest wait of one of a stream's chunks in the transport "
+    "(StreamingReply.send to the socket write's return), observed once a "
+    "stream, at its close, on the writer's thread")
 
 
 _STREAM_TIMEOUT_EVENT = b'data: {"error": "stream reply timeout"}\n\n'
@@ -135,10 +140,16 @@ class StreamingReply:
         self.content_type = content_type
         #: the request's root span: it ends when the stream closes
         self._trace_span = trace_span
+        #: ``(queued_at, bytes)`` chunks, then the close sentinel
         self._q: "queue.Queue" = queue.Queue()
         self._notify = None
         self._lock = new_lock("serving.server.StreamingReply._lock")
         self._closed = False
+        #: chunks the transport has written, and how long they lay between
+        #: ``send`` and the write's return (``write_lag``)
+        self._writes = 0
+        self._write_lag_sum_s = 0.0
+        self._write_lag_max_s = 0.0
 
     def send(self, data) -> None:
         if isinstance(data, str):
@@ -148,7 +159,8 @@ class StreamingReply:
                 return
             # _q is unbounded: put() never blocks, it only appends — the
             # lock pairs the closed-check with the enqueue
-            self._q.put(bytes(data))  # tpulint: disable=TPU014
+            self._q.put((time.perf_counter(),  # tpulint: disable=TPU014
+                         bytes(data)))
             notify = self._notify
         if notify is not None:
             notify()
@@ -174,7 +186,34 @@ class StreamingReply:
         if notify is not None:
             notify()
 
+    def write_lag(self) -> Dict[str, float]:
+        """``writes``, ``write_lag_sum_s``, ``write_lag_max_s``: the chunks
+        the transport has written so far and their wait between ``send``
+        and the socket write's return. A chunk still queued is not in
+        them."""
+        with self._lock:
+            return {"writes": self._writes,
+                    "write_lag_sum_s": self._write_lag_sum_s,
+                    "write_lag_max_s": self._write_lag_max_s}
+
     # -- transport side -----------------------------------------------------
+    def _written(self, queued_at: float) -> None:
+        """The transport's write of the chunk stamped ``queued_at`` has
+        returned (called on the writer's thread, once a chunk of every
+        stream: it holds the GIL the engine's thread is waiting for, so it
+        does the least it can, and the histogram is left to the close)."""
+        lag = time.perf_counter() - queued_at
+        with self._lock:
+            self._writes += 1
+            self._write_lag_sum_s += lag
+            if lag > self._write_lag_max_s:
+                self._write_lag_max_s = lag
+
+    def _stream_written(self) -> None:
+        """The transport has written the stream to its close."""
+        if self._writes:
+            _M_WRITE_LAG.observe(self._write_lag_max_s)
+
     def _register(self, notify) -> None:
         """Async transport: fire ``notify()`` (thread-safe) whenever a
         chunk lands; fires immediately if chunks are already queued."""
@@ -185,8 +224,8 @@ class StreamingReply:
             notify()
 
     def _get(self, timeout: Optional[float]):
-        """Blocking chunk fetch (threaded transport): bytes, the close
-        sentinel, or None on timeout."""
+        """Blocking chunk fetch (threaded transport): a ``(queued_at,
+        bytes)`` chunk, the close sentinel, or None on timeout."""
         try:
             return self._q.get(timeout=timeout)
         except queue.Empty:
@@ -381,22 +420,26 @@ class _Handler(BaseHTTPRequestHandler):
             self.end_headers()
             self.close_connection = True
             while True:
-                chunk = resp._get(ws.reply_timeout)
-                if chunk is StreamingReply._CLOSE:
+                item = resp._get(ws.reply_timeout)
+                if item is StreamingReply._CLOSE:
+                    resp._stream_written()
                     break
-                if chunk is None:
+                if item is None:
                     # per-chunk timeout: a silently truncated 200 would
                     # read as a short successful stream — emit an explicit
                     # final error event and stop accepting sends
                     resp.close()
-                    chunk = _STREAM_TIMEOUT_EVENT
+                    queued_at, chunk = None, _STREAM_TIMEOUT_EVENT
+                else:
+                    queued_at, chunk = item
                 try:
                     self.wfile.write(chunk)
                     self.wfile.flush()
                 except (ConnectionError, BrokenPipeError):
                     break
-                if chunk is _STREAM_TIMEOUT_EVENT:
+                if queued_at is None:
                     break
+                resp._written(queued_at)
             return
         payload = resp.entity.content if resp.entity else b""
         ws._observe_request("threaded", self.command,
@@ -660,12 +703,17 @@ class _AsyncHTTPServer:
                             await writer.drain()
                             break
                         ev.clear()
-                        for chunk in resp._drain_nowait():
-                            if chunk is StreamingReply._CLOSE:
+                        stamps = []
+                        for item in resp._drain_nowait():
+                            if item is StreamingReply._CLOSE:
                                 ended = True
                                 break
-                            writer.write(chunk)
+                            stamps.append(item[0])
+                            writer.write(item[1])
                         await writer.drain()
+                        for queued_at in stamps:
+                            resp._written(queued_at)
+                    resp._stream_written()
                     break                      # stream ends the connection
                 ws._observe_request("async", req.method,
                                     resp.status_line.status_code,

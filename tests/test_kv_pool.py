@@ -35,6 +35,7 @@ from mmlspark_tpu.models.zoo.transformer import (
     decode_window_paged, decode_window_ragged, generate_cached,
     init_kv_cache, init_paged_cache, init_transformer, paged_gather,
     paged_scatter_rows, prefill_cache)
+from mmlspark_tpu.observability import tracing
 from mmlspark_tpu.serving.continuous import ContinuousDecoder
 from mmlspark_tpu.serving.kv_pool import (KVAutotuner, PagedKVPool,
                                           PoolExhausted, prefix_hash)
@@ -540,12 +541,19 @@ class TestChunkedPrefill:
                                 page_size=4, prefill_chunk=budget)
         rng = np.random.default_rng(8)
         prompt = rng.integers(1, CFG.vocab, 37).astype(np.int32)
-        req = eng.submit(prompt, max_new_tokens=8)
-        while not req.done:
-            eng.step()
-        assert eng._chunk_trace, "long prompt must take the chunked path"
-        assert max(eng._chunk_trace) <= budget
-        assert eng._kv.stats["prefill_chunks"] == len(eng._chunk_trace)
+        # under a trace: a window's size is what its span carries
+        root = tracing.start_trace("chunked")
+        with tracing.activate(root):
+            req = eng.submit(prompt, max_new_tokens=8)
+            while not req.done:
+                eng.step()
+        root.end()
+        windows = [s.attrs["tokens"] for s in root.trace.spans
+                   if s.name == "continuous.prefill_chunk"]
+        assert windows, "long prompt must take the chunked path"
+        assert max(windows) <= budget
+        assert eng._kv.stats["prefill_chunks"] == len(windows)
+        assert eng._kv.stats["prefill_tokens"] == sum(windows) == len(prompt)
         want = generate_cached(params, prompt[None, :], CFG,
                                   max_new_tokens=8)
         assert req.tokens == list(np.asarray(want)[0, len(prompt):])
@@ -583,7 +591,7 @@ class TestChunkedPrefill:
                          max_new_tokens=4)
         while not req.done:
             eng.step()
-        assert eng._chunk_trace == []
+        assert eng._kv.stats["prefill_tokens"] == 0
         assert eng._kv.stats["prefill_chunks"] == 0
 
 

@@ -25,7 +25,7 @@ GENERATION_SPANS = ["engine.admit_http", "engine.pump_streams",
                     "decoder.admit", "decoder.tick",
                     "decoder.stage_prefills", "decoder.compact",
                     "continuous.prefill", "continuous.prefill_chunk",
-                    "continuous.drain"]
+                    "continuous.drain", "decoder.account", "decoder.retire"]
 #: a hybrid decoder's prefix is pages plus a state snapshot
 HYBRID_SPANS = ["decoder.state_snapshot", "decoder.state_restore"]
 
