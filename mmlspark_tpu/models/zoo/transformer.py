@@ -327,17 +327,63 @@ def _rope(q, k, theta: float):
     return _rot_half(q, cos, sin), _rot_half(k, cos, sin)
 
 
-def _embed(params, tokens, cfg: TransformerConfig, wpos=None):
+#: the most rows :func:`_rows` fetches one ``dynamic_slice`` each; past it
+#: one 0/1 product reads the table once (PERF.md §6, PR 41: the crossing
+#: on the chip, and the programs' text grows ten lines a sliced row)
+_SLICED_ROWS = 64
+
+
+def embed_read(width: int) -> str:
+    """How :func:`_embed` reads a table ``width`` columns wide on the chip:
+    ``"gather"`` or ``"in_place"`` (what a decoder's ``stats`` name)."""
+    return "gather" if width % 128 == 0 else "in_place"
+
+
+def _rows(table, idx, gather=False):
+    """``table[idx]`` for a (V, D) table, bit for bit, read where the table
+    lies. The chip keeps a table whose width is no multiple of its 128 lanes
+    column-major, a gather wants it row-major, and the compiler then relays
+    the WHOLE table in front of the gather in every program (GPT-2 XL:
+    161 MB read and written to fetch eight rows). So such a table is read in
+    a form the compiler takes in place: up to ``_SLICED_ROWS`` rows a
+    ``dynamic_slice`` each, more as a 0/1 product under a float32 sum (one
+    read of the table, nothing written). A width of whole lanes stays the
+    gather it was, as does a caller that says ``gather``: the training
+    forward (a row slice's transpose is a table-sized update a token).
+
+    An id outside the table reads what the gather reads, in every form:
+    a negative one counts from the end, one past the end (or under ``-V``)
+    reads the nearest row. The product selects exactly while the table is
+    finite (0 x Inf is NaN, in every other row) and gives a stored -0.0 as
+    +0.0."""
+    V, D = table.shape
+    if gather or embed_read(D) == "gather":
+        return table[idx]
+    idx = jnp.clip(jnp.where(idx < 0, idx + V, idx), 0, V - 1)
+    if idx.size <= _SLICED_ROWS:
+        rows = [jax.lax.dynamic_slice(table, (i, 0), (1, D))
+                for i in idx.reshape(-1)]
+        return jnp.concatenate(rows).reshape(*idx.shape, D)
+    hot = jax.nn.one_hot(idx, V, dtype=table.dtype)
+    return jnp.einsum("...v,vd->...d", hot, table,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32).astype(table.dtype)
+
+
+def _embed(params, tokens, cfg: TransformerConfig, wpos=None, gather=False):
     """Rows of the token table for ``tokens`` (B, W), in ``cfg.dtype``: the
     one read of the table every forward makes, both blocks' and the CPU
-    oracles'. The dense block adds its learned position rows, at ``wpos``
-    (B, W) or, with None, at 0..W-1 (a slice, not a gather); the hybrid block
-    has none and scales the rows itself."""
+    oracles' (:func:`_rows`: in place where the chip would relay the table).
+    The dense block adds its learned position rows, at ``wpos`` (B, W) or,
+    with None, at 0..W-1 (a slice, not a gather); the hybrid block has none
+    and scales the rows itself. ``gather`` keeps the plain gather whatever
+    the width: for the differentiated, mesh-sharded forward."""
     dt = cfg.dtype
-    h = params["embed"]["tok"].astype(dt)[tokens]
+    h = _rows(params["embed"]["tok"].astype(dt), tokens, gather)
     if cfg.position == "learned" and not cfg.mixers:
         rows = params["embed"]["pos"].astype(dt)
-        h = h + (rows[:tokens.shape[1]][None] if wpos is None else rows[wpos])
+        h = h + (rows[:tokens.shape[1]][None] if wpos is None
+                 else _rows(rows, wpos, gather))
     return h
 
 
@@ -432,7 +478,7 @@ def transformer_apply(params: Dict, ids: jnp.ndarray,
         return x
 
     moe_aux = {"balance": jnp.float32(0.0), "dropped": jnp.float32(0.0)}
-    h = _embed(params, ids, cfg)
+    h = _embed(params, ids, cfg, gather=True)   # differentiated, sharded
     # sequence-parallel region: activations sharded (dp, tp) on (B, S)
     h = constrain(h, P("dp", "tp", None))
 

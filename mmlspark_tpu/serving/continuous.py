@@ -80,7 +80,7 @@ from ..models.zoo.transformer import (TransformerConfig,
                                       _warp_scaled_rows,
                                       decode_step_ragged,
                                       decode_step_paged,
-                                      decode_window_paged,
+                                      decode_window_paged, embed_read,
                                       paged_scatter_rows,
                                       prefill_cache, shardings_for)
 from ..models.zoo.hybrid import SLOT_KEYS as _SLOT_KEYS
@@ -112,6 +112,12 @@ _M_TTFT = _metric_histogram(
 _M_LIVE_SLOTS = _metric_gauge(
     "mmlspark_continuous_live_slots",
     "Occupied decode slots at the latest step (batch size on device)")
+_M_EMBED_READ = _metric_gauge(
+    "mmlspark_continuous_embed_read",
+    "1 under the form in which the decoder built last reads its token "
+    "table: in_place (a width that is no multiple of the chip's 128 lanes: "
+    "row slices or a 0/1 product, no copy of the table) or gather",
+    labelnames=("form",))
 _M_PREFILLS = _metric_counter(
     "mmlspark_continuous_prefills_total",
     "Full prompt prefills executed (grouped prefills count once)")
@@ -1108,8 +1114,14 @@ class ContinuousDecoder:
         #: seconds inside ``continuous.drain``, the one wait for the device
         #: (the sum ``mmlspark_continuous_drain_seconds`` keeps). The
         #: engine's round log reads both (``generation.recent_rounds``).
+        #: ``embed_read``: how every program of this decoder reads the token
+        #: table (``transformer._rows``), named once here.
         self.stats = {"prefills": 0, "prefix_hits": 0, "ticks": 0,
-                      "drain_seconds": 0.0}
+                      "drain_seconds": 0.0,
+                      "embed_read": embed_read(
+                          params["embed"]["tok"].shape[1])}
+        for form in ("gather", "in_place"):
+            _M_EMBED_READ.set(form == self.stats["embed_read"], form=form)
 
         # group insert + first tokens (see the module factories)
         self._insert_group_j = _audit_program(

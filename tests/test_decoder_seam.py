@@ -13,6 +13,11 @@ looks for the mark in the entry point's output:
 * ``_embed`` -> the same read from a table whose rows are rolled by one: the
   output must equal the unpatched entry point's on parameters holding that
   table, and differ from its output on the real ones.
+
+``_embed`` itself (PR 41): a table whose width is no multiple of the chip's
+128 lanes is read in place (row slices, or a 0/1 product past
+``_SLICED_ROWS`` rows) and gives ``table[tokens]`` bit for bit; a width of
+whole lanes keeps the gather, its traced text as it was.
 """
 
 import jax
@@ -148,3 +153,126 @@ def test_every_cached_entry_point_runs_the_shared_seam(
     _replace(monkeypatch, "_embed",
              lambda params, *a, **kw: real(rolled, *a, **kw))
     np.testing.assert_array_equal(np.asarray(run(params)), want)
+
+
+# ---- the table read itself -------------------------------------------------
+
+#: a vocabulary no multiple of anything, positions enough for a 256-lane
+#: window; the widths are GPT-2 XL's (12.5 registers of 128 lanes: read in
+#: place) and LFM2's (16 registers: the gather)
+V, POSITIONS = 997, 300
+WIDTHS = {1600: "in_place", 2048: "gather"}
+
+
+def _tables(width):
+    rng = np.random.default_rng(width)
+    cfg = T.TransformerConfig(vocab=V, layers=1, d_model=width,
+                              heads=width // 64, d_ff=64, max_len=POSITIONS,
+                              causal=True, dtype=jnp.bfloat16)
+    draw = lambda rows: jnp.asarray(                        # noqa: E731
+        rng.normal(size=(rows, width)), jnp.bfloat16)
+    return cfg, {"embed": {"tok": draw(V), "pos": draw(POSITIONS)}}, rng
+
+
+def _bits(a):
+    return np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint16))
+
+
+@pytest.mark.parametrize("positions", ["leading", "wpos"])
+@pytest.mark.parametrize("window", [1, 256])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_embed_reads_the_rows_the_gather_reads(width, window, positions):
+    """Both tables' rows, bit for bit, in both in-place forms (3 rows: slices;
+    768: the product) and through the gather: the first id, the last, and a
+    row read twice among them."""
+    cfg, params, rng = _tables(width)
+    ids = rng.integers(0, V, (3, window))
+    ids[0, 0], ids[-1, -1], ids[1, 0] = 0, V - 1, ids[2, 0]
+    ids = jnp.asarray(ids, jnp.int32)
+    wpos = (None if positions == "leading" else
+            jnp.asarray(rng.integers(0, POSITIONS, (3, window)), jnp.int32))
+    tok, pos = params["embed"]["tok"], params["embed"]["pos"]
+    want = tok[ids] + (pos[:window][None] if wpos is None else pos[wpos])
+    got = jax.jit(lambda p, i, w: T._embed(p, i, cfg, w))(params, ids, wpos)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    assert T.embed_read(width) == WIDTHS[width]
+
+
+def _primitives(width, window, wpos, **kw):
+    cfg, params, _ = _tables(width)
+    ids = jnp.zeros((2, window), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p, i: T._embed(
+        p, i, cfg, None if not wpos else i, **kw))(params, ids)
+    names = [eqn.primitive.name for eqn in jaxpr.jaxpr.eqns]
+    return {n: names.count(n)
+            for n in ("gather", "dynamic_slice", "dot_general")}
+
+
+@pytest.mark.parametrize("window", [1, 256])
+def test_a_width_of_whole_lanes_keeps_its_gather(window):
+    """The three hybrid cells' tables (4096, 2560, 2048 wide) are read as
+    they were, so their programs' text cannot move: one gather a table, no
+    slice, no product; the hybrid block reads no position table."""
+    assert _primitives(2048, window, wpos=False) == {
+        "gather": 1, "dynamic_slice": 0, "dot_general": 0}
+    assert _primitives(2048, window, wpos=True) == {
+        "gather": 2, "dynamic_slice": 0, "dot_general": 0}
+    for width in (4096, 2560, 2048, 768, 128):
+        assert T.embed_read(width) == "gather"
+
+
+def test_the_form_follows_the_row_count_and_the_caller_may_keep_the_gather():
+    """At GPT-2 XL's width: a slice a row up to ``_SLICED_ROWS`` rows (a
+    tick's 8-64), one product past it (a window), and the plain gather for
+    the training forward, which is differentiated and sharded."""
+    few = T._SLICED_ROWS // 2
+    assert _primitives(1600, few, wpos=True) == {
+        "gather": 0, "dynamic_slice": 4 * few, "dot_general": 0}
+    assert _primitives(1600, 256, wpos=True) == {
+        "gather": 0, "dynamic_slice": 0, "dot_general": 2}
+    assert _primitives(1600, 256, wpos=True, gather=True) == {
+        "gather": 2, "dynamic_slice": 0, "dot_general": 0}
+
+
+@pytest.mark.parametrize("rows", [4, 128], ids=["slices", "product"])
+def test_an_id_outside_the_table_reads_what_the_gather_reads(rows):
+    """No form differs silently from the gather: a negative id counts from
+    the end, one past either end reads the nearest row."""
+    _, params, _ = _tables(1600)
+    table = params["embed"]["tok"]
+    ids = jnp.asarray(np.resize([-1, -V, -V - 3, V, V + 5, 0, V - 1, 7],
+                                rows), jnp.int32)
+    np.testing.assert_array_equal(_bits(T._rows(table, ids)),
+                                  _bits(T._rows(table, ids, gather=True)))
+
+
+def test_the_product_form_needs_a_finite_table():
+    """What the docstring says of the 0/1 product: 0 x Inf is NaN in that
+    column of every OTHER row and a stored -0.0 comes back +0.0; row slices
+    select bits whatever they are."""
+    _, params, _ = _tables(1600)
+    table = params["embed"]["tok"].at[5, 3].set(jnp.inf).at[6, 4].set(-0.0)
+    few, many = jnp.asarray([5, 6, 7]), jnp.arange(128) % 8
+    np.testing.assert_array_equal(_bits(T._rows(table, few)),
+                                  _bits(table[few]))
+    got = np.asarray(T._rows(table, many).astype(jnp.float32))
+    assert np.isnan(got[np.asarray(many) != 5, 3]).all()    # not its rows
+    assert np.isfinite(got[:, :3]).all()
+    assert not np.signbit(got[6, 4])
+    clean = jnp.nan_to_num(table, posinf=1.0)
+    np.testing.assert_array_equal(
+        np.asarray(T._rows(clean, many).astype(jnp.float32)),
+        np.asarray(clean[many].astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("width,form", [(32, "in_place"), (128, "gather")])
+def test_a_decoder_names_the_form_it_got(width, form):
+    """``stats["embed_read"]`` and the gauge, set once at construction."""
+    from mmlspark_tpu.serving import continuous
+    cfg = DENSE._replace(d_model=width)
+    dec = continuous.ContinuousDecoder(T.init_transformer(cfg, seed=0), cfg,
+                                       max_slots=2, max_len=L)
+    assert dec.stats["embed_read"] == form
+    for f in ("gather", "in_place"):
+        assert continuous._M_EMBED_READ.labels(form=f).get() == (f == form)

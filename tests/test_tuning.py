@@ -416,11 +416,14 @@ class TestBatchRunnerAuto:
         from mmlspark_tpu.models.onnx_model import ONNXModel
         from mmlspark_tpu.onnx import model_content_digest
 
+        alive = []      # a name is its node's address: no build may reuse one
+
         def build():
             import mmlspark_tpu.onnx as O
             rng = np.random.default_rng(7)
             w = rng.normal(0, 0.5, (8, 3)).astype(np.float32)
             nodes = [O.make_node("MatMul", ["x", "w"], ["logits"])]
+            alive.append(nodes)
             graph = O.make_graph(
                 nodes, "m",
                 inputs=[O.make_tensor_value_info("x", np.float32,
