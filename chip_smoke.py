@@ -21,6 +21,12 @@ reference computed in the same process:
                  feed-forward, a grouped-query layer and a convolution under
                  all 64 routed experts) on ``ContinuousDecoder``
 
+  I. latent    — three layers of GLM-4.7-Flash's share at their published
+                 widths (latent attention under the dense feed-forward and
+                 under all 64 routed experts with their shared expert) on
+                 ``ContinuousDecoder``: a context registered as a prefix of
+                 pages alone, then two callers on it at once
+
 ``--chips 4`` runs instead ONLY the two paths that exist across chips and what
 each is compared with: D. data-parallel GBDT over a 4-device ``data`` mesh,
 E. the paged decoder mounted on a ``dp2 x tp2`` mesh.
@@ -67,6 +73,11 @@ ROUTED_GAP_MEAN = 0.05
 #: on the CPU) and the fp8 control 0.53 (CPU, published widths; PERF.md,
 #: PR 38): the geometric middle
 CONV_GQA_GAP_MEAN = 0.15
+#: phase I's: the same top-4 of 64, beside a shared expert that every token
+#: takes and that steadies the layer. Three layers (two routed) read 0.0081 in
+#: the mean on the chip (my chip run, PR 42; the widest gap 0.76); the limit
+#: is phase G's
+LATENT_GAP_MEAN = 0.05
 QUANT_ERR_BOUND = 0.05   # tests/test_kv_quant.py's bound on the int8 probe
 LOGIT_TOL = 0.06         # bf16 ResNet-50 logits vs float32, relative to max|ref|
 
@@ -240,6 +251,17 @@ def sizes(small):
                           num_experts_per_tok=2, experts_held=[0, 8],
                           vocab_size=256, compute_dtype="float32",
                           param_dtype="float32"),
+            # phase I at toy widths: the tests' tiny all-latent decoder,
+            # a context of three pages of 16
+            latent=dict(hidden_size=64, intermediate_size=128,
+                        num_attention_heads=5, num_key_value_heads=5,
+                        q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=12,
+                        qk_rope_head_dim=8, v_head_dim=16,
+                        moe_intermediate_size=32, n_routed_experts=8,
+                        num_experts_per_tok=2, experts_held=[0, 8],
+                        vocab_size=256, compute_dtype="float32",
+                        param_dtype="float32"),
+            latent_len=160, latent_context=48,
             pool_decoder=TransformerConfig(vocab=256, layers=2, d_model=64,
                                            heads=4, d_ff=128, max_len=64,
                                            causal=True, dtype=jnp.bfloat16),
@@ -260,6 +282,9 @@ def sizes(small):
         hybrid_len=8704,
         # phase G: prompts across a chunk's end, 4 slots of 1,024 positions
         routed_len=1024, routed_prompts=[40, 300, 520],
+        # phase I: a 2,560-token context (ten pages of 256: past one
+        # 2,048-key tile of the window's fold), 4 slots of 4,096 positions
+        latent_len=4096, latent_context=2560,
         # the generation cell's decoder and engine (benchmarks/configs/
         # gpt2_xl.json, workloads/gpt2xl_generate_closed.json): GPT-2 XL,
         # 8 slots of 1024 positions in the pages the decoder derives from
@@ -439,6 +464,82 @@ def phase_conv_gqa(sz, seed, small):
     return ck, dict(detail,
                     attn_ticks_gqa=stats.get("attn_ticks_gqa", 0),
                     attn_ticks_conv=stats.get("attn_ticks_conv", 0))
+
+
+# ---------------------------------------------------------------------------
+# I. latent (the cell glmflash_repoctx_shared32's model, three layers of it)
+
+
+def phase_latent(sz, seed, small):
+    """Layers 0, 10 and 11 of the all-latent configuration at its published
+    widths (20 heads of 192 + 64 / 256 under a rank-768 query; the dense
+    feed-forward, then 64 routed experts beside a shared one): a context past
+    one tile of the window's fold goes in as a prefix miss (pages stored,
+    nothing a slot to snapshot), then two callers ask about it at once:
+    both hits, the context's pages in both block tables by reference, every
+    tick on the absorbed kernel with 20 heads padded to 24, every pair held.
+    All three requests' tokens are judged in the mean
+    (:data:`LATENT_GAP_MEAN`)."""
+    from benchmarks import run as bench_run
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    ck = Checks()
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "glm47_flash_l7.json")) as fh:
+        config = json.load(fh)
+    config.update(num_hidden_layers=3, layers_held=[0, 10, 11])
+    if small:
+        config.update(sz["latent"])
+    reference = bench_run.load_by_path("references", config["reference"])
+    cfg = bench_run.load_by_path("drivers", "generate_glm").program_config(
+        config, sz["latent_len"])
+    t0 = time.perf_counter()
+    params = reference.make_weights(config, seed)
+    dec = ContinuousDecoder(params, cfg, max_slots=4,
+                            max_len=sz["latent_len"], **sz["engine_kw"])
+    rng = np.random.default_rng(seed)
+    doc = rng.integers(1, cfg.vocab, sz["latent_context"]).astype(np.int32)
+    served = []
+    for group in ((24,), (40, 9)):              # a miss, then two hits at once
+        prompts = [np.concatenate(
+            [doc, rng.integers(1, cfg.vocab, n).astype(np.int32)])
+            for n in group]
+        reqs = [dec.submit(p, sz["max_new"], prefix_key="ctx",
+                           prefix_len=doc.size) for p in prompts]
+        drain(dec, reqs)
+        served += [(p, dec.result(r)) for p, r in zip(prompts, reqs)]
+    run_s = time.perf_counter() - t0
+    stats = dec._kv.stats
+    ck.require(doc.size % dec._page == 0
+               and dec.stats["prefix_hits"] == 2
+               and stats["prefix_tokens_shared"] == 2 * doc.size
+               and not any(k.startswith("state_snapshot") for k in stats),
+               f"the two callers did not share the context's pages alone: "
+               f"{dec.stats} {stats}")
+    ck.require(stats.get("attn_ticks_latent", 0) > 0
+               and not stats.get("attn_ticks_latent_window", 0)
+               and not stats["attn_ticks_gather"],
+               f"a tick left the absorbed latent kernel: {stats}")
+    ck.require(stats["latent_window_keys"]
+               >= stats["latent_window_context"] > 3 * doc.size,
+               f"the windows' keys were not counted: {stats}")
+    ck.require(stats.get("moe_pairs_held", 0) > 0
+               and stats["moe_pairs_held"] == stats["moe_pairs_routed"]
+               and stats["moe_pairs_dropped"] == 0
+               and stats["moe_pairs_misplaced"] == 0,
+               f"routed pairs dropped, misplaced or not held: {stats}")
+    t0 = time.perf_counter()
+    gaps = np.concatenate([reference.served_token_gaps(
+        params, config, p, o, sz["latent_len"]) for p, o in served])
+    require_gap_mean(ck, gaps, LATENT_GAP_MEAN)
+    return ck, dict(
+        run_s=run_s, reference_s=time.perf_counter() - t0,
+        gap_max=float(gaps.max()), gap_mean=float(gaps.mean()),
+        page=dec._page, context=int(doc.size), tile=dec._kv.latent_tile,
+        attn_ticks_latent=stats["attn_ticks_latent"],
+        latent_window_keys=stats["latent_window_keys"],
+        prefix_tokens_shared=stats["prefix_tokens_shared"],
+        moe={k[4:]: int(v) for k, v in stats.items()
+             if k.startswith("moe_")})
 
 
 # ---------------------------------------------------------------------------
@@ -927,7 +1028,7 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the two cross-chip paths (D, E)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--phase", action="append", choices=list("ABCDEFGH"),
+    ap.add_argument("--phase", action="append", choices=list("ABCDEFGHI"),
                     help="run only this phase (repeatable; for fault-finding)")
     args = ap.parse_args(argv)
 
@@ -992,6 +1093,8 @@ def main(argv=None):
                   "G": ("G.routed", lambda: phase_routed(
                       sz, args.seed, args.small)),
                   "H": ("H.conv_gqa", lambda: phase_conv_gqa(
+                      sz, args.seed, args.small)),
+                  "I": ("I.latent", lambda: phase_latent(
                       sz, args.seed, args.small))}
     for key, (name, run) in phases.items():
         if args.phase and key not in args.phase:

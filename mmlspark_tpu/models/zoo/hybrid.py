@@ -47,10 +47,14 @@ a delta-rule linear attention and latent attention; ``cfg.kda``,
 * ``mla`` - softmax attention whose cached row a token is the normed
   ``latent``-wide key/value latent beside one rotated ``rope``-wide key all
   heads share: pages ``(pages, 1, page, latent + rope)``. A window rebuilds
-  K and V from the latents it gathers (expanded); the decode tick folds
+  K and V from the latents (expanded) a tile of keys at a time, up to its
+  row's last written key (:func:`_mla_window_call`: what it holds and what
+  it costs follow the context, not ``max_len``); the decode tick folds
   ``W_kvb``'s key half into the query and applies its value half after the
   weighted sum of latents (absorbed), through
-  ``ops.paged_attention.paged_attention_latent``.
+  ``ops.paged_attention.paged_attention_latent``. ``cfg.latent.q_rank``
+  projects the query through a normed latent of its own; ``cfg.latent.gate``
+  says whether the layer ends in a sigmoid gate a head or in ``W_o`` alone.
 * feed-forward ``"moe"`` - ``parallel.moe.moe_topk_held``: top-k dropless
   routing over all experts, the held experts' part of the result, a shared
   expert.
@@ -74,6 +78,7 @@ short convolutions beside grouped-query attention; ``cfg.conv``):
 
 from __future__ import annotations
 
+import functools
 from typing import Dict
 
 import jax
@@ -204,11 +209,15 @@ def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
 
     def mla_layer():
         la = cfg.latent
-        return {"q": dense(D, H * (la.nope + la.rope)),
-                "kva": dense(D, la.latent + la.rope),
-                "c_norm": ones(la.latent),
-                "kvb": dense(la.latent, H * (la.nope + la.value)),
-                "z": dense(D, H), "o": dense(H * la.value, D)}
+        hq = H * (la.nope + la.rope)
+        q = ({"q_a": dense(D, la.q_rank), "q_norm": ones(la.q_rank),
+              "q_b": dense(la.q_rank, hq)} if la.q_rank
+             else {"q": dense(D, hq)})
+        return dict(q, kva=dense(D, la.latent + la.rope),
+                    c_norm=ones(la.latent),
+                    kvb=dense(la.latent, H * (la.nope + la.value)),
+                    o=dense(H * la.value, D),
+                    **({"z": dense(D, H)} if la.gate else {}))
 
     def moe_layer():
         r = cfg.routed
@@ -524,6 +533,42 @@ def _allowed_keys(idx, ok, t, sp, L):
     return causal & (dense | sel)
 
 
+def _fold_keys(carry, qg, ks_, vs_, al):
+    """One online-softmax update of ``carry = (m, l, acc)``: the queries
+    ``qg`` (B, G, hg, W, hd) meet a tile of keys ``ks_`` (B, G, T, hd) and
+    values ``vs_`` (B, G, T, dv) under ``al`` (B, G or 1, W, T)."""
+    m, l, acc = carry
+    # a key no query may read can hold anything (the trash page behind
+    # a block table's unassigned entries takes whatever the fused decode
+    # kernel's idle output block held): its weight is 0, and 0 x NaN is
+    # NaN, so its value is 0 too
+    vs_ = jnp.where(al.any(axis=2)[..., None], vs_,
+                    jnp.zeros((), vs_.dtype))
+    s = jnp.einsum("bghwd,bgud->bghwu", qg, ks_,
+                   preferred_element_type=F32) * qg.shape[-1] ** -0.5
+    al = al[:, :, None]
+    s = jnp.where(al, s, _NEG)
+    m_new = jnp.maximum(m, s.max(axis=-1))
+    p = jnp.exp(s - m_new[..., None]) * al
+    corr = jnp.exp(m - m_new)
+    l = l * corr + p.sum(axis=-1)
+    acc = acc * corr[..., None] + jnp.einsum(
+        "bghwu,bgud->bghwd", p.astype(vs_.dtype), vs_,
+        preferred_element_type=F32)
+    return m_new, l, acc
+
+
+def _fold_start(qg, dv):
+    shape = qg.shape[:-1]
+    return (jnp.full(shape, _NEG, F32), jnp.zeros(shape, F32),
+            jnp.zeros(shape + (dv,), F32))
+
+
+def _fold_end(carry, shape):
+    _, l, acc = carry
+    return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).reshape(shape)
+
+
 def _masked_attention(q, k, v, allowed, t_max):
     """Softmax attention of ``q`` (B, Hq, W, hd) over ``k`` (B, Hkv, L,
     hd) and ``v`` (B, Hkv, L, dv) under ``allowed`` (B, Hkv or 1, W, L),
@@ -532,35 +577,10 @@ def _masked_attention(q, k, v, allowed, t_max):
     B, Hq, W, hd = q.shape
     G, L, dv = k.shape[1], k.shape[2], v.shape[-1]
     qg = q.reshape(B, G, Hq // G, W, hd)
-    scale = hd ** -0.5
     T = min(L, _KEY_TILE)
-
-    def fold(carry, ks_, vs_, al):
-        m, l, acc = carry
-        # a key no query may read can hold anything (the trash page behind
-        # a block table's unassigned entries takes whatever the fused decode
-        # kernel's idle output block held): its weight is 0, and 0 x NaN is
-        # NaN, so its value is 0 too
-        vs_ = jnp.where(al.any(axis=2)[..., None], vs_,
-                        jnp.zeros((), vs_.dtype))
-        s = jnp.einsum("bghwd,bgud->bghwu", qg, ks_,
-                       preferred_element_type=F32) * scale
-        al = al[:, :, None]
-        s = jnp.where(al, s, _NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None]) * al
-        corr = jnp.exp(m - m_new)
-        l = l * corr + p.sum(axis=-1)
-        acc = acc * corr[..., None] + jnp.einsum(
-            "bghwu,bgud->bghwd", p.astype(vs_.dtype), vs_,
-            preferred_element_type=F32)
-        return m_new, l, acc
-
-    shape = (B, G, Hq // G, W)
-    init = (jnp.full(shape, _NEG, F32), jnp.zeros(shape, F32),
-            jnp.zeros(shape + (dv,), F32))
+    init = _fold_start(qg, dv)
     if L == T:
-        _, l, acc = fold(init, k, v, allowed)
+        out = _fold_keys(init, qg, k, v, allowed)
     else:
         short = -L % T                      # whole tiles (none at 32k)
         k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, short), (0, 0)))
@@ -570,10 +590,10 @@ def _masked_attention(q, k, v, allowed, t_max):
         def body(i, carry):
             def tile(a, axis):
                 return jax.lax.dynamic_slice_in_dim(a, i * T, T, axis=axis)
-            return fold(carry, tile(k, 2), tile(v, 2), tile(allowed, 3))
-        _, l, acc = jax.lax.fori_loop(0, t_max // T + 1, body, init)
-    out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
-    return out.reshape(B, Hq, W, dv)
+            return _fold_keys(carry, qg, tile(k, 2), tile(v, 2),
+                              tile(allowed, 3))
+        out = jax.lax.fori_loop(0, t_max // T + 1, body, init)
+    return _fold_end(out, (B, Hq, W, dv))
 
 
 def _put(buf, val, idx):
@@ -883,18 +903,24 @@ def _kda_layer(lp, x, c, pos, n_valid, cfg, kernel):
 
 def _mla_inputs(lp, x, wpos, cfg):
     """``(q_n (B, H, W, nope), q_r (B, H, W, rope) rotated, row (B, W,
-    latent_row))``: the heads' queries and the one row a token caches, the
+    latent_row))``: the heads' queries (one product, or with ``q_rank``
+    through their own normed latent) and the one row a token caches, the
     normed latent beside the rotated shared key (and zeros up to whole
     registers), in the compute dtype."""
     H = cfg.heads
     la = cfg.latent
     dt = cfg.dtype
-    q = _heads(_proj(x, lp["q"], dt), H, la.nope + la.rope)
+    xq = x
+    if la.q_rank:
+        xq = _rms(_proj(x, lp["q_a"], dt).astype(F32), lp["q_norm"],
+                  cfg.norm_eps).astype(dt)
+    q = _heads(_proj(xq, lp["q_b" if la.q_rank else "q"], dt), H,
+               la.nope + la.rope)
     ckr = _proj(x, lp["kva"], dt).astype(F32)
     cos, sin = _rope_tables(wpos, la.rope, cfg.rope_theta, F32)
     q_r = _rot_half(q[..., la.nope:].astype(F32), cos[:, None], sin[:, None])
     row = jnp.concatenate(
-        [_rms(ckr[..., :la.latent], lp["c_norm"]),
+        [_rms(ckr[..., :la.latent], lp["c_norm"], cfg.norm_eps),
          _rot_half(ckr[..., la.latent:], cos, sin)], axis=-1).astype(dt)
     row = jnp.pad(row, ((0, 0), (0, 0),
                         (0, latent_row(cfg) - la.latent - la.rope)))
@@ -912,18 +938,71 @@ def _mla_expanded(lp, q_n, q_r, rows, wpos, cfg):
     """Causal attention of queries at ``wpos`` (B, W) over cached rows
     ``rows`` (B, L, latent_row) with K and V REBUILT from the latents: what
     a window runs."""
-    la = cfg.latent
+    k, v = _mla_keys_values(rows, _mla_kvb(lp, cfg), cfg.latent)
+    q = jnp.concatenate([q_n, q_r], axis=-1)
+    return _masked_attention(q, k, v, _causal(wpos, rows.shape[1]),
+                             jnp.max(wpos))
+
+
+def _mla_keys_values(rows, w, la):
+    """``(K (B, H, L, nope + rope), V (B, H, L, value))`` REBUILT from cached
+    rows ``rows`` (B, L, latent_row) by ``w`` (:func:`_mla_kvb`): a head's
+    keys beside the rotated key all heads share."""
     B, L, _ = rows.shape
-    allowed = (jnp.arange(L)[None, None] <= wpos[..., None])[:, None]
-    H = cfg.heads
-    kv = jnp.einsum("bld,dhn->bhln", rows[..., :la.latent], _mla_kvb(lp, cfg))
+    kv = jnp.einsum("bld,dhn->bhln", rows[..., :la.latent], w)
     k = jnp.concatenate(
         [kv[..., :la.nope], jnp.broadcast_to(
             rows[:, None, :, la.latent:la.latent + la.rope],
-            (B, H, L, la.rope))], axis=-1)
-    q = jnp.concatenate([q_n, q_r], axis=-1)
-    return _masked_attention(q, k, kv[..., la.nope:], allowed,
-                             jnp.max(wpos))
+            (B, w.shape[1], L, la.rope))], axis=-1)
+    return k, kv[..., la.nope:]
+
+
+def window_tile(page: int, pages: int) -> int:
+    """Whole pages a tile of :func:`_mla_window_call`: ``_KEY_TILE`` keys, a
+    page at least, the slot's block table at most."""
+    return min(max(1, _KEY_TILE // page), pages)
+
+
+@functools.partial(jax.jit, static_argnames=("la",))
+def _mla_window_call(q, kv_pages, bt, wpos, n_valid, w, *, la):
+    """Causal attention of a window's queries ``q`` (B, H, W, nope + rope) at
+    ``wpos`` (B, W) over their rows' LATENT pages ``kv_pages`` (N, 1, page,
+    latent_row) through ``bt`` (B, P), K and V rebuilt by ``w``
+    (:func:`_mla_kvb`) a tile of :func:`window_tile` pages at a time inside
+    the fold, up to the tile that holds the last real lane's position
+    (``n_valid`` (B,) real lanes a row). The temporaries are a tile's
+    whatever ``P`` is, and the work follows the context. One jitted name, so
+    that a trace shows the window's attention apart. float32 (B, H, W,
+    value) out."""
+    B, H, W, _ = q.shape
+    page, P = kv_pages.shape[2], bt.shape[1]
+    per = window_tile(page, P)
+    T = per * page
+    qg = q[:, :, None]
+    real = jnp.arange(W)[None] < n_valid[:, None]
+    last = jnp.max(jnp.where(real, wpos, 0))
+    # entries past the table read trash page 0, at positions no lane reaches
+    bt = jnp.pad(bt, ((0, 0), (0, -P % per)))
+
+    def body(i, carry):
+        pages = jax.lax.dynamic_slice_in_dim(bt, i * per, per, axis=1)
+        k, v = _mla_keys_values(
+            kv_pages[pages][:, :, 0].reshape(B, T, -1), w, la)
+        t = i * T + jnp.arange(T)
+        return _fold_keys(carry, qg, k, v,
+                          (t[None, None] <= wpos[..., None])[:, None])
+
+    init = _fold_start(qg, la.value)
+    out = (body(0, init) if per == P else
+           jax.lax.fori_loop(0, last // T + 1, body, init))
+    return _fold_end(out, (B, H, W, la.value))
+
+
+def _mla_out(lp, x, o, cfg):
+    """The layer's end: a sigmoid gate a head (``cfg.latent.gate``) or
+    ``W_o`` alone."""
+    return (_head_gated_out(lp, x, o, cfg, norm=False) if cfg.latent.gate
+            else _heads_out(lp, o, cfg))
 
 
 def _mla_absorbed(lp, q_n, q_r, kv_pages, bt, lengths, cfg):
@@ -954,14 +1033,15 @@ def _mla_contiguous(lp, x, wpos, n_valid, c, cfg):
     rows = jax.vmap(lambda buf, val, idx: buf.at[idx].set(
         val, mode="drop"))(c["kv"][:, 0], row, dest)
     o = _mla_expanded(lp, q_n, q_r, rows, wpos, cfg)
-    return _head_gated_out(lp, x, o, cfg, norm=False), {"kv": rows[:, None]}
+    return _mla_out(lp, x, o, cfg), {"kv": rows[:, None]}
 
 
 def _mla_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel):
     """An mla layer over its latent pages: the window's rows are written
     through the block table (padding lanes and idle rows to trash page 0);
-    the decode tick then attends absorbed, in place; a window gathers its
-    row's pages and attends expanded."""
+    the decode tick then attends absorbed, in place; a window attends
+    expanded, a tile of its row's pages at a time
+    (:func:`_mla_window_call`)."""
     B, W, _ = x.shape
     P = bt.shape[1]
     q_n, q_r, row = _mla_inputs(lp, x, wpos, cfg)
@@ -975,9 +1055,9 @@ def _mla_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel):
         o = _mla_absorbed(lp, q_n, q_r, kv, bt,
                           jnp.where(n_valid > 0, pos + 1, 0), cfg)
     else:
-        o = _mla_expanded(lp, q_n, q_r,
-                          kv[bt][:, :, 0].reshape(B, P * page, -1), wpos, cfg)
-    return _head_gated_out(lp, x, o, cfg, norm=False), {"kv": kv}
+        o = _mla_window_call(jnp.concatenate([q_n, q_r], axis=-1), kv, bt,
+                             wpos, n_valid, _mla_kvb(lp, cfg), la=cfg.latent)
+    return _mla_out(lp, x, o, cfg), {"kv": kv}
 
 
 # ---- conv and gqa: the mixers that end in W_o alone ---------------------------

@@ -85,11 +85,16 @@ class LatentAttention(NamedTuple):
     """Multi-head latent attention's sizes (the ``mla`` mixer): a token
     caches ONE row of ``latent + rope`` values (the normed key/value latent
     and a rotated key every head shares); a head's query is ``nope + rope``
-    wide, its value ``value``."""
+    wide, its value ``value``. ``q_rank`` > 0 projects the query through a
+    normed latent of that width (``q_a``, RMSNorm, ``q_b``) instead of one
+    product; ``gate`` ends the layer in a sigmoid gate a head before
+    ``W_o``, False in ``W_o`` alone."""
     latent: int = 512
     nope: int = 128
     rope: int = 64
     value: int = 128
+    q_rank: int = 0
+    gate: bool = True
 
 
 class DeltaRule(NamedTuple):

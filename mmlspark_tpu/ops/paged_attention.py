@@ -937,14 +937,22 @@ def paged_attention_latent(q, kv_pages, block_tables, lengths, *,
     pool whose row a token is its key and, in the first ``v_width`` values,
     its value (multi-head latent attention's cache: every head reads the
     same row). Keys at positions ``>= lengths[b]`` are masked; a row with
-    ``lengths[b] == 0`` yields zeros. ``Hq`` must be a multiple of the
-    query dtype's sublane tile. Returns (B, Hq, v_width) in ``q.dtype``."""
+    ``lengths[b] == 0`` yields zeros. The kernel's window is the heads, so
+    a head count that is no multiple of the query dtype's sublane tile (20
+    float32 heads: 8) goes in padded with zero queries up to the next one;
+    their contexts are sliced off here and never stored, and a whole count
+    (32) reaches the call as it is. Returns (B, Hq, v_width) in
+    ``q.dtype``."""
     if interpret is None:
         interpret = _auto_interpret()
+    Hq = q.shape[1]
+    short = -Hq % sublane_multiple(q.dtype)
+    if short:
+        q = jnp.pad(q, ((0, 0), (0, short), (0, 0)))
     return _pa_latent_call(
         q[:, None], kv_pages, block_tables.astype(jnp.int32),
         lengths.astype(jnp.int32), v_width=int(v_width), scale=float(scale),
-        interpret=bool(interpret))[:, 0]
+        interpret=bool(interpret))[:, 0, :Hq]
 
 
 # ---- mesh mount (shard_map) -------------------------------------------------

@@ -1116,7 +1116,8 @@ class ContinuousDecoder:
         #: engine's round log reads both (``generation.recent_rounds``).
         #: ``embed_read``: how every program of this decoder reads the token
         #: table (``transformer._rows``), named once here.
-        self.stats = {"prefills": 0, "prefix_hits": 0, "ticks": 0,
+        self.stats = {"prefills": 0, "prefix_hits": 0,
+                      "prefix_hit_tokens": 0, "ticks": 0,
                       "drain_seconds": 0.0,
                       "embed_read": embed_read(
                           params["embed"]["tok"].shape[1])}
@@ -1873,7 +1874,8 @@ class ContinuousDecoder:
             if req.prefix_len is not None:
                 if self._hybrid and req.prefix_len < plen:
                     # a linear-attention state cannot be rolled back: the
-                    # snapshot exists at the stored length and nowhere else
+                    # snapshot (and, for a model of pages alone, the
+                    # boundary's logits) exists at the stored length only
                     raise ValueError(
                         f"prefix_key {req.prefix_key!r}: prefix_len "
                         f"{req.prefix_len} is shorter than the stored "
@@ -1914,16 +1916,20 @@ class ContinuousDecoder:
             # LRU promotion: the hit entry becomes the newest
             self._prefix_store[req.prefix_key] = \
                 self._prefix_store.pop(req.prefix_key)
+            self.stats["prefix_hit_tokens"] += plen
             if self._hybrid:
-                # the snapshot into the slot's state rows, then the suffix
-                # through the chunk scheduler (a state must not see a
-                # window's padding, and chunks interleave with the ticks)
-                with _tracing.span("decoder.state_restore", slot=slot,
-                                   tokens=plen):
-                    snap = self._kv.prefix_state(phash)
-                    self._kv.buffers = self._restore_j(
-                        self._kv.buffers, snap["rows"],
-                        jnp.asarray(slot, jnp.int32))
+                # the snapshot into the slot's state rows (a model whose
+                # every cache is pages has none: its prefix is the pages it
+                # now shares), then the suffix through the chunk scheduler
+                # (a state must not see a window's padding, and chunks
+                # interleave with the ticks)
+                snap = self._kv.prefix_state(phash)
+                if self._kv.snapshot_bytes:
+                    with _tracing.span("decoder.state_restore", slot=slot,
+                                       tokens=plen):
+                        self._kv.buffers = self._restore_j(
+                            self._kv.buffers, snap["rows"],
+                            jnp.asarray(slot, jnp.int32))
                 if P > plen:
                     self._chunking[slot] = [req, plen]
                 else:
@@ -2079,7 +2085,8 @@ class ContinuousDecoder:
         with (_tracing.span("decoder.tick", live=len(decode_live), k=self._k)
               if riding else contextlib.nullcontext()), \
                 _tracing.span("continuous.prefill_chunk", slot=slot,
-                              offset=off, tokens=w, riding=riding):
+                              offset=off, tokens=w, context=off + w,
+                              riding=riding):
             window = (jnp.asarray(ids), jnp.asarray([off], jnp.int32),
                       self._bt[slot:slot + 1])
             if self._hybrid:
@@ -2104,17 +2111,23 @@ class ContinuousDecoder:
                 gather_bytes=(self._gather_bytes_extend
                               if self._attn_impl == "gather" else 0))
             self._note_sparse_ticks(off + w)
+            self._kv.note_latent_window(off, w)
             self._note_sweep([off], ids.shape[1], 1, 1)
             self._kv.note_prefill_chunk(w, riding=riding)
         off += w
         if off == boundary:
             del self._registering[slot]
             if req.prefix_key not in self._prefix_store:
-                with _tracing.span("decoder.state_snapshot", slot=slot,
-                                   tokens=off):
+                # the boundary's logits always (a whole-prompt hit answers
+                # from them); the slot's rows where the model keeps any
+                stateful = self._kv.snapshot_bytes > 0
+                with (_tracing.span("decoder.state_snapshot", slot=slot,
+                                    tokens=off)
+                      if stateful else contextlib.nullcontext()):
                     self._store_prefix(slot, req, off, state={
                         "rows": self._snapshot_j(
-                            self._kv.buffers, jnp.asarray(slot, jnp.int32)),
+                            self._kv.buffers, jnp.asarray(slot, jnp.int32))
+                        if stateful else [],
                         "logits": last})
         if off < P:
             self._chunking[slot][1] = off

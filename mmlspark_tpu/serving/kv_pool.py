@@ -94,6 +94,17 @@ M_PREFILL_CHUNKS_RIDING_SHARE = _metric_gauge(
     "Prefill chunks whose window rode a decode tick (one dispatch, one read "
     "of the feed-forward weights for both) over the prefill chunks executed, "
     "since the pool was built")
+M_LATENT_WINDOW_KEYS = _metric_gauge(
+    "mmlspark_kvpool_latent_window_keys",
+    "Keys the prefill windows of a model with latent-attention layers have "
+    "attended over, in whole tiles of the window's fold (a window rebuilds K "
+    "and V of its row's context a tile at a time, up to its last key), since "
+    "the pool was built")
+M_PREFIX_TOKENS_SHARED = _metric_gauge(
+    "mmlspark_kvpool_prefix_tokens_shared",
+    "Tokens of stored prefix pages admitted requests took by reference "
+    "(whole shared pages; a boundary page is copied, not shared), since the "
+    "pool was built")
 M_ALLOC_FAILURES = _metric_counter(
     "mmlspark_kvpool_alloc_failures_total",
     "Page allocations that failed even after prefix eviction")
@@ -202,7 +213,8 @@ class PagedKVPool:
         #: is then pages plus a snapshot of those rows (``register_prefix``)
         self.hybrid = bool(getattr(cfg, "mixers", ()))
         if self.hybrid:
-            from ..models.zoo.hybrid import SLOT_KEYS, dims, pool_shapes
+            from ..models.zoo.hybrid import (SLOT_KEYS, dims, pool_shapes,
+                                             window_tile)
             if kv_dtype is not None or sharding is not None:
                 raise ValueError("a hybrid decoder's pool is bf16 pages "
                                  "(K beside V of a sparse or gqa layer, or "
@@ -222,6 +234,12 @@ class PagedKVPool:
             for layer in self._layer_shapes or ()
             for key, (shape, dt) in layer.items() if key in SLOT_KEYS)
         self.max_snapshots = int(max_snapshots)
+        #: keys a prefill window over latent pages folds at a time (0: the
+        #: model has no mla layer): what ``latent_window_keys`` counts by
+        self.latent_tile = (
+            self.page_size * window_tile(
+                self.page_size, self.pages_per_slot(slot_positions))
+            if self.hybrid and "mla" in cfg.mixers else 0)
         #: one K or V page as a session blob carries it, (H, page, hd);
         #: the pool's buffer packs the two side by side on the minor axis
         self._page_shape = (heads, self.page_size, hd)
@@ -269,7 +287,9 @@ class PagedKVPool:
                       "pages_per_slot": self.pages_per_slot(slot_positions),
                       "prefix_share_hits": 0, "defrag_moves": 0,
                       "prefill_chunks": 0, "prefill_chunks_riding": 0,
-                      "prefill_tokens": 0,
+                      "prefill_tokens": 0, "prefix_tokens_shared": 0,
+                      "latent_window_keys": 0, "latent_window_context": 0,
+                      "latent_window_pairs": 0,
                       "alloc_failures": 0,
                       "gather_bytes": 0, "attn_ticks_kernel": 0,
                       "attn_ticks_gather": 0, "grid_steps": 0,
@@ -453,6 +473,8 @@ class PagedKVPool:
             self._note_snapshot("stored")
 
     def _note_snapshot(self, event: str) -> None:
+        if not self.snapshot_bytes:
+            return      # pages alone: what is kept with them is no state
         for key, metric, n in (
                 ("state_snapshots_", M_STATE_SNAPSHOTS, 1),
                 ("state_snapshot_bytes_", M_STATE_SNAPSHOT_BYTES,
@@ -480,6 +502,8 @@ class PagedKVPool:
         if shared:
             self.stats["prefix_share_hits"] += len(shared)
             M_PREFIX_SHARE_HITS.inc(len(shared))
+            self.stats["prefix_tokens_shared"] += len(shared) * self.page_size
+            M_PREFIX_TOKENS_SHARED.set(self.stats["prefix_tokens_shared"])
         return pages, plen
 
     def release_prefix(self, phash: str) -> None:
@@ -673,6 +697,24 @@ class PagedKVPool:
         M_PREFILL_TOKENS.inc(int(ntok))
         M_PREFILL_CHUNKS_RIDING_SHARE.set(
             self.stats["prefill_chunks_riding"] / self.stats["prefill_chunks"])
+
+    def note_latent_window(self, offset: int, lanes: int) -> None:
+        """A prefill window of ``lanes`` real tokens at ``offset`` of a model
+        with latent pages (else nothing), folded :attr:`latent_tile` keys at
+        a time: ``latent_window_keys`` is what it attended over (whole tiles
+        up to its last key), ``latent_window_context`` the keys it had to
+        rebuild (``offset + lanes``) and ``latent_window_pairs`` the (query,
+        key) pairs its causal mask lets through: what the mathematics needs
+        of it."""
+        tile = self.latent_tile
+        if not tile:
+            return
+        last = offset + lanes - 1
+        self.stats["latent_window_keys"] += (last // tile + 1) * tile
+        self.stats["latent_window_context"] += offset + lanes
+        self.stats["latent_window_pairs"] += (lanes * offset
+                                              + lanes * (lanes + 1) // 2)
+        M_LATENT_WINDOW_KEYS.set(self.stats["latent_window_keys"])
 
     def note_attn_tick(self, impl: str, *, calls: int = 1,
                        gather_bytes: int = 0) -> None:

@@ -518,7 +518,7 @@ def _share_programs(one_chip, z, config_file, driver, cut):
     from mmlspark_tpu.serving.kv_pool import PagedKVPool
     with open(os.path.join(bench_run.HERE, "configs", config_file)) as fh:
         config = json.load(fh)
-    config.update(num_hidden_layers=3, layers_held=[0, 10, 11], **cut)
+    config.update(dict(num_hidden_layers=3, layers_held=[0, 10, 11]), **cut)
     cfg = bench_run.load_by_path("drivers", driver).program_config(
         config, z["max_len"])
     per = z["max_len"] // z["page"]
@@ -529,7 +529,8 @@ def _share_programs(one_chip, z, config_file, driver, cut):
     pool = PagedKVPool(cfg, page_size=z["page"], residency=False,
                        make_buffer=one_chip, slots=z["slots"],
                        slot_positions=z["max_len"],
-                       num_pages=1 + z["slots"] * per + z["slots"])
+                       num_pages=z.get("pages")
+                       or 1 + z["slots"] * per + z["slots"])
     ints = lambda *dims: one_chip(dims, jnp.int32)          # noqa: E731
     interpret = pa._auto_interpret
     pa._auto_interpret = progs._pa_auto_interpret = lambda: False
@@ -692,6 +693,68 @@ def test_conv_gqa_programs_compile_and_keep_the_pool_in_place(lfm2_programs,
             copies = [ln.strip()[:120] for ln in text.splitlines()
                       if f"= {shape}" in ln and " copy(" in ln]
             assert not copies, copies
+
+
+# the shared-context latent cell (glmflash_repoctx_shared32): 20 heads over
+# latent pages of 640-wide rows, 32 slots of 32,768 positions in pages of 256
+# (128 a slot), a pool of 1,024 pages, 64 experts of 1536 and a shared one,
+# a 512-token window
+GLM = dict(slots=32, heads=20, page=256, max_len=32768, pages=1024, row=640,
+           latent=512, chunk=512)
+
+
+def test_latent_read_kernel_compiles_at_twenty_heads(one_chip):
+    """20 absorbed float32 query heads a row, no multiple of the sublane
+    tile: they go in as 24 and 20 contexts come out."""
+    z = GLM
+    per = z["max_len"] // z["page"]
+    fn = functools.partial(paged_attention_latent, v_width=z["latent"],
+                           scale=256 ** -0.5, interpret=False)
+    args = (one_chip((z["slots"], z["heads"], z["row"]), jnp.float32),
+            one_chip((z["pages"], 1, z["page"], z["row"]), jnp.bfloat16),
+            one_chip((z["slots"], per), jnp.int32),
+            one_chip((z["slots"],), jnp.int32))
+    text = _compiled_text(fn, *args)
+    assert "_pa_latent_call" in text and "f32[32,1,24,640]" in text
+    assert jax.eval_shape(fn, *args).shape == (32, 20, 512)
+
+
+@pytest.fixture(scope="module")
+def glm_programs(one_chip):
+    """The all-latent engine's programs at the cell's shapes and at the
+    cell's DEPTH: layer 0 and the six routed layers."""
+    yield from _share_programs(
+        one_chip, GLM, "glm47_flash_l7.json", "generate_glm",
+        dict(num_hidden_layers=7, layers_held=[0, 7, 8, 9, 10, 11, 12]))
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk", "riding"])
+def test_latent_programs_compile_and_hold_a_tile_not_the_slot(glm_programs,
+                                                              program):
+    """The seven-layer tick, a 512-token window alone and the tick that
+    carries one (``riding``) at the published widths (32 slots of 32,768
+    positions over 1,024 pages): they compile for the chip; the ticks hold
+    ONE absorbed kernel an mla layer and one grouped product a routed layer
+    with no copy of its weights; the plain tick holds no sequential loop; a
+    window's attention is one ``while`` a layer under a traced bound whose
+    temporaries are a tile's: no array spans the slot's 32,768 positions (the
+    parent rebuilt ``bf16[1,20,32768,448]``, 0.59 GB a layer); and none
+    copies the latent page pool."""
+    texts, pool = glm_programs
+    text = texts[program]
+    lines = text.splitlines()
+    _one_read_of_the_experts(text, pool.cfg)
+    if program in ("tick", "riding"):
+        assert sum("tpu_custom_call" in ln and "_pa_latent_call" in ln
+                   for ln in lines) == 7
+    whiles = sum(" while(" in ln for ln in lines)
+    assert whiles == (0 if program == "tick" else 7)
+    assert not re.search(r"\[[0-9,]*32768[0-9,]*\]", text)
+    shape = f"bf16[{','.join(map(str, pool.buffers[0]['kv'].shape))}]"
+    assert shape == "bf16[1024,1,256,640]"
+    copies = [ln.strip()[:120] for ln in lines
+              if f"= {shape}" in ln and " copy(" in ln]
+    assert not copies, copies
 
 
 @pytest.mark.parametrize("stats", [None, "bfloat16"])

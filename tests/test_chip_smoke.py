@@ -69,7 +69,8 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     lines, _ = small_run
     phases = {ln["phase"]: ln for ln in lines if "phase" in ln}
     assert sorted(phases) == ["A.transform", "B.decode", "C.train",
-                              "F.hybrid", "G.routed", "H.conv_gqa"]
+                              "F.hybrid", "G.routed", "H.conv_gqa",
+                              "I.latent"]
     for ln in phases.values():
         assert ln["ok"] is True and ln["failed"] == []
         assert ln["small"] is True and ln["platform"] == "cpu"
@@ -91,6 +92,11 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     assert phases["H.conv_gqa"]["moe"]["pairs_held"] \
         == phases["H.conv_gqa"]["moe"]["pairs_routed"] > 0
     assert phases["H.conv_gqa"]["gap_mean"] <= chip_smoke.CONV_GQA_GAP_MEAN
+    latent = phases["I.latent"]
+    assert latent["attn_ticks_latent"] > 0
+    assert latent["prefix_tokens_shared"] == 2 * latent["context"]
+    assert latent["moe"]["pairs_held"] == latent["moe"]["pairs_routed"] > 0
+    assert latent["gap_mean"] <= chip_smoke.LATENT_GAP_MEAN
     assert phases["C.train"]["pallas_histogram_traces"] > 0
     assert (phases["C.train"]["pallas_interpreted"]
             == phases["C.train"]["pallas_histogram_traces"])

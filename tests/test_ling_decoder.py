@@ -96,7 +96,7 @@ def test_mapping_keeps_the_published_numbers(sizes, cfg):
     r = cfg.routed
     assert (r.experts, r.first, r.held, r.per_token, r.groups,
             r.groups_kept, r.scale) == (32, 0, 8, 4, 4, 2, 2.5)
-    assert cfg.latent == (32, 16, 8, 16) and cfg.kda == (4, -5.0)
+    assert cfg.latent == (32, 16, 8, 16, 0, True) and cfg.kda == (4, -5.0)
     assert r.swiglu_limits == (0,) * 8
 
 
@@ -111,7 +111,7 @@ def test_the_real_file_maps_at_its_published_widths():
     assert (cfg.d_model, cfg.heads, cfg.head_dim, cfg.d_ff, cfg.vocab) == (
         2560, 32, 128, 6144, 39296)
     assert cfg.routed[:9] == (512, 0, 128, 8, 8, 4, 2.5, 768, 768)
-    assert cfg.latent == (512, 128, 64, 128)
+    assert cfg.latent == (512, 128, 64, 128, 0, True)
 
 
 def test_full_forward_matches_the_reference(params, ids, cfg, want):

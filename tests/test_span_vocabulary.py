@@ -352,6 +352,90 @@ def test_snapshot_bytes_are_a_conv_layers_tails_alone(conv_gqa_run):
     assert stats["state_snapshot_bytes_stored"] == 2 * 32 * 4
 
 
+#: what a decoder whose every layer is latent attention adds: the keys its
+#: prefill windows attended over (pool ``stats["latent_window_keys"]``, the
+#: gauge ``mmlspark_kvpool_latent_window_keys``), the tokens of stored prefix
+#: pages its requests took by reference (``stats["prefix_tokens_shared"]``,
+#: ``mmlspark_kvpool_prefix_tokens_shared``), and the keys under a window on
+#: its span (``continuous.prefill_chunk``'s ``context=``)
+LATENT_GAUGES = [("mmlspark_kvpool_latent_window_keys", "latent_window_keys"),
+                 ("mmlspark_kvpool_prefix_tokens_shared",
+                  "prefix_tokens_shared")]
+
+
+@pytest.fixture(scope="module")
+def latent_run():
+    """A tiny all-latent decoder (5 heads of 12 + 8 / 16 under a rank-24
+    query, a dense and a routed feed-forward with a shared expert): a
+    request registers a prefix of 24 tokens (three pages of 8), two more take
+    it by reference at once, all under one request trace. What the pool and
+    the registry counted, and the trace's spans."""
+    from mmlspark_tpu import observability as obs
+    from mmlspark_tpu.models.zoo.transformer import (
+        LatentAttention, RoutedExperts, TransformerConfig, init_transformer)
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    cfg = TransformerConfig(
+        vocab=64, layers=2, d_model=32, heads=5, d_ff=64, max_len=96,
+        causal=True, norm="rmsnorm", position="rope", dtype=jnp.float32,
+        mixers=("mla", "mla"), ffn=("dense", "moe"), norm_eps=1e-5,
+        latent=LatentAttention(latent=32, nope=12, rope=8, value=16,
+                               q_rank=24, gate=False),
+        routed=RoutedExperts(experts=8, per_token=2, d_expert=16,
+                             d_shared=16, scale=1.8))
+    dec = ContinuousDecoder(init_transformer(cfg, seed=0), cfg, max_slots=2,
+                            max_len=96, page_size=8, prefill_chunk=16)
+    rng = np.random.default_rng(5)
+    doc = rng.integers(1, cfg.vocab, 24).astype(np.int32)
+    prompts = [np.concatenate(
+        [doc, rng.integers(1, cfg.vocab, n).astype(np.int32)])
+        for n in (6, 9, 11)]
+    tr._SPAN_LOG.clear()
+    root = tr.start_trace("latent")
+    with tr.activate(root):
+        for group in (prompts[:1], prompts[1:]):    # a miss, then two hits
+            reqs = [dec.submit(p, 5, prefix_key="doc", prefix_len=24)
+                    for p in group]
+            while not all(r.done for r in reqs):
+                dec.step()
+            assert all(r.error is None for r in reqs)
+    root.end()
+    return dec, obs.snapshot(), root.trace.spans
+
+
+@pytest.mark.parametrize("gauge,stat", LATENT_GAUGES)
+def test_latent_gauges_hold_the_pools_counts(latent_run, gauge, stat):
+    dec, snap, _ = latent_run
+    stats = dec._kv.stats
+    # windows of 16, 8 and 6 lanes (the miss) and of 9 and 11 (the hits), a
+    # tile the slot's 12 pages; two hits x three whole pages of 8 tokens
+    want = {"latent_window_keys": 5 * 96, "prefix_tokens_shared": 2 * 24}
+    assert stats[stat] == want[stat]
+    assert [s["value"] for s in snap[gauge]["series"]] == [want[stat]]
+    assert stats["latent_window_context"] == 16 + 24 + 30 + 33 + 35
+    assert dec.stats["prefix_hit_tokens"] == stats["prefix_tokens_shared"]
+
+
+def test_a_window_says_the_keys_under_it(latent_run):
+    _, _, spans = latent_run
+    chunks = [s for s in spans if s.name == "continuous.prefill_chunk"]
+    assert [(s.attrs["offset"], s.attrs["tokens"], s.attrs["context"])
+            for s in chunks] == [(0, 16, 16), (16, 8, 24), (24, 6, 30),
+                                 (24, 9, 33), (24, 11, 35)]
+    assert all("riding" in s.attrs for s in chunks)
+
+
+def test_a_prefix_of_pages_alone_opens_no_state_span(latent_run):
+    """Nothing a slot: the hits restore no snapshot and the registration
+    takes none; the tick's label is the absorbed kernel's."""
+    dec, _, spans = latent_run
+    names = {s.name for s in spans} | {n for n, *_ in tr.span_log()}
+    assert not names & set(HYBRID_SPANS)
+    stats = dec._kv.stats
+    assert not any(k.startswith("state_snapshot") for k in stats)
+    assert stats["attn_ticks_latent"] \
+        == stats["attn_ticks_kernel"] - stats["prefill_chunks"] > 0
+
+
 def test_transform_spans_join_the_request_trace(transform_run):
     _, spans = transform_run
     names = [s.name for s in spans]
