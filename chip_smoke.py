@@ -470,6 +470,117 @@ def phase_conv_gqa(sz, seed, small):
 # I. latent (the cell glmflash_repoctx_shared32's model, three layers of it)
 
 
+#: the absorbed kernel alone against a float32 oracle, relative L2 of a row's
+#: contexts: on the chip the MXU rounds the float32 query and weights to bf16
+#: (2**-8 a value), in interpret mode nothing does
+LATENT_SWEEP_TOL = 1e-2
+
+
+def latent_sweep_alone(ck, seed, small):
+    """The absorbed latent kernel ALONE at the shapes of
+    ``glmflash_repoctx_shared32``: 32 rows of 20 float32 heads over 640-wide
+    bf16 latent pages of 256 positions, 128 a slot, a pool of 1,024; eight
+    stored contexts of 64-120 pages, four rows on each BY REFERENCE, a few
+    pages of a row's own behind them. Every page no row needs holds NaN, the
+    trash page 0 and the table's stale entries with it. Three seeds against a
+    float32 oracle made from the same pages, and against the one-page sweep
+    (``k`` = 1); then microseconds a page a call, seven calls (the cell's
+    layers) a program, the one-page sweep beside the blocks the call picks
+    and beside twice as many."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops import paged_attention as pa
+
+    if small:
+        B, H, page, P, N, dk, dv, docs = 8, 5, 16, 32, 96, 128, 96, (9, 18)
+    else:
+        B, H, page, P, N, dk, dv, docs = (32, 20, 256, 128, 1024, 640, 512,
+                                          tuple(64 + 8 * i for i in range(8)))
+    scale, layers = 256 ** -0.5, 7
+    interpret = jax.devices()[0].platform != "tpu"
+
+    def case(seed):
+        rng = np.random.default_rng(seed)
+        free = iter(rng.permutation(np.arange(1, N)))
+        stored = [[next(free) for _ in range(n)] for n in docs]
+        bt = np.zeros((B, P), np.int32)         # stale entries: trash page 0
+        lengths = np.zeros(B, np.int32)
+        for b in range(B):
+            doc = stored[b * len(docs) // B]
+            own = int(rng.integers(1, 5))
+            lengths[b] = (len(doc) + own) * page - int(rng.integers(0, page))
+            bt[b, :len(doc) + own] = doc + [next(free) for _ in range(own)]
+        lengths[rng.integers(0, B)] -= lengths.min() % page + 1  # an edge
+        pool = rng.normal(0, 1, (N, 1, page, dk)).astype(np.float32)
+        live = np.zeros(N, bool)
+        for b in range(B):
+            live[bt[b, :-(-lengths[b] // page)]] = True
+        pool[~live] = np.nan
+        q = rng.normal(0, 1, (B, H, dk)).astype(np.float32)
+        return (jnp.asarray(q), jnp.asarray(pool, jnp.bfloat16),
+                jnp.asarray(bt), jnp.asarray(lengths))
+
+    @jax.jit
+    def oracle(q, pool, bt, lengths):
+        def row(args):
+            q, bt, n = args
+            rows = pool[bt, 0].reshape(P * page, dk).astype(jnp.float32)
+            ok = jnp.arange(P * page) < n
+            rows = jnp.where(ok[:, None], rows, 0.0)
+            s = jnp.einsum("hd,kd->hk", q, rows, precision="highest") * scale
+            p = jax.nn.softmax(jnp.where(ok[None, :], s, -jnp.inf), axis=-1)
+            return jnp.einsum("hk,kd->hd", p, rows[:, :dv],
+                              precision="highest")
+        return jax.lax.map(row, (q, bt, lengths))
+
+    shipped = functools.partial(pa.paged_attention_latent, v_width=dv,
+                                scale=scale)
+
+    def launch(k):
+        def one(q, pool, bt, lengths):
+            q = jnp.pad(q, ((0, 0), (0, -H % 8), (0, 0)))[:, None]
+            return pa._latent_launch(q, pool, bt, lengths, v_width=dv,
+                                     scale=scale, interpret=interpret,
+                                     k=k)[:, 0, :H]
+        return one
+
+    errs, apart, one_page = [], [], jax.jit(launch(1))
+    for sd in (seed, seed + 1, seed + 2):
+        args = case(sd)
+        want, got = np.asarray(oracle(*args)), np.asarray(shipped(*args))
+        one = np.asarray(one_page(*args))
+        ck.require(np.isfinite(got).all(),
+                   f"the absorbed kernel alone folded a NaN page (seed {sd})")
+        errs.append(float(np.max(np.linalg.norm(got - want, axis=-1)
+                                 / np.linalg.norm(want, axis=-1))))
+        apart.append(float(np.max(np.abs(got - one))))
+    ck.require(max(errs) < LATENT_SWEEP_TOL,
+               f"the absorbed kernel alone misses the float32 oracle: {errs}")
+
+    args = case(seed)
+    k = pa.latent_block(args[1][0].nbytes, P)
+    pages = int(np.sum(-(-np.asarray(args[3]) // page)))
+    qs = jnp.stack([args[0] * (1 + i) for i in range(layers)])
+    timed = {}
+    for name, fn in (("one_page", launch(1)), ("shipped", shipped),
+                     (f"k{2 * k}", launch(2 * k))):
+        prog = jax.jit(lambda qs, *rest, fn=fn: sum(
+            fn(q, *rest) for q in qs))
+        ref = np.asarray(prog(qs, *args[1:]))         # compiles
+        ck.require(np.isfinite(ref).all(), f"{name}: a NaN page was folded")
+        if interpret:
+            continue                    # a CPU run gives counts, never speeds
+        laps = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            prog(qs, *args[1:]).block_until_ready()
+            laps.append(time.perf_counter() - t0)
+        timed[name] = dict(us_a_page=min(laps) / (layers * pages) * 1e6,
+                           ms_a_call=min(laps) / layers * 1e3)
+    return dict(pages_a_step=k, pages=pages, rows=B,
+                oracle_rel_l2=errs, apart_from_one_page=apart, **timed)
+
+
 def phase_latent(sz, seed, small):
     """Layers 0, 10 and 11 of the all-latent configuration at its published
     widths (20 heads of 192 + 64 / 256 under a rank-768 query; the dense
@@ -531,8 +642,10 @@ def phase_latent(sz, seed, small):
     gaps = np.concatenate([reference.served_token_gaps(
         params, config, p, o, sz["latent_len"]) for p, o in served])
     require_gap_mean(ck, gaps, LATENT_GAP_MEAN)
+    reference_s = time.perf_counter() - t0
     return ck, dict(
-        run_s=run_s, reference_s=time.perf_counter() - t0,
+        run_s=run_s, reference_s=reference_s,
+        sweep_alone=latent_sweep_alone(ck, seed, small),
         gap_max=float(gaps.max()), gap_mean=float(gaps.mean()),
         page=dec._page, context=int(doc.size), tile=dec._kv.latent_tile,
         attn_ticks_latent=stats["attn_ticks_latent"],

@@ -30,20 +30,20 @@ Design notes (TPU-first):
   quantized pool keep their shape (1/64 of the bytes).
 * THE GRID IS THE BATCH'S LIVE PAGES: one ragged sweep of
   ``sum over rows of (pages that row needs)`` steps, not ``slots x pages a
-  slot``. A step that fetches a page and folds nothing costs ~0.5 us, one
-  that moves nothing ~0.1 us, and under ``max_len`` 1024 ten of a row's 16
-  were such (PERF.md, PR 34). :func:`_schedule` builds, on the device and
-  once a tick (the layers' calls share it), two scalar-prefetched int32
-  vectors that say which ``(row, page)`` step ``s`` is, a row's pages in
-  order and rows in order; the number of steps is a TRACED grid bound, so
-  one compiled program serves every batch of contexts. A row needs the
-  pages that hold its keys and, in a fused call, the pages its window
-  writes (at ``pos % page == 0`` the write page lies after the last page
-  with keys); a row with nothing to read or write takes one step, which
-  initialises and writes out its (meaningless) context. Blocks carry the
-  full head dimension — a page block is ``(1, H, page, 2*hd)``, K and V of
-  one page in one DMA — so each page is DMA'd ONCE per row per layer, not
-  once per head.
+  slot`` (a step that fetches a page and folds nothing costs ~0.5 us, one
+  that moves nothing ~0.1 us: PERF.md, PR 34). :func:`_schedule` builds, on
+  the device and once a tick (the layers' calls share it), the int32
+  vectors that say which ``(row, page)`` step ``s`` is, rows in order and a
+  row's pages in order; the number of steps is a TRACED grid bound, so one
+  compiled program serves every batch of contexts. A row needs the pages
+  that hold its keys and, in a fused call, those its window writes; a row
+  with nothing to read or write takes one step, which writes out its
+  (meaningless) context. A page block is ``(1, H, page, 2*hd)``, K and V of
+  every head in one DMA. A step of the LATENT sweep is a BLOCK of up to
+  ``k`` consecutive pages of one row (PR 44; ``k`` page operands on the one
+  pool, :func:`latent_block`): a whole block is one fold, a row's last
+  block folds page by page, and a page the row does not need is neither
+  fetched nor folded. A row of 94 pages is 24 steps at ``k`` = 4.
 * WHAT A LIVE STEP COMPUTES ON ITS BLOCK (PR 37). The scores take the keys
   AS STORED: a bf16 query meets bf16 keys, and the products of two bf16
   values are exact in the float32 they are summed in, so the scores equal
@@ -479,7 +479,7 @@ def _step(row_ref, page_ref, last_ref):
 
 
 def _pa_read_kernel(row_ref, page_ref, last_ref, bt_ref, len_ref, q_ref,
-                    kv_ref, *rest, scale, page, quant, v_width=None):
+                    kv_ref, *rest, scale, page, quant):
     from jax.experimental import pallas as pl
 
     scales, (o_ref, m_scr, l_scr, acc_scr) = rest[:2 * quant], rest[2 * quant:]
@@ -490,8 +490,8 @@ def _pa_read_kernel(row_ref, page_ref, last_ref, bt_ref, len_ref, q_ref,
     @pl.when(p * page < bound)
     def _compute():
         _pages_fold(m_scr, l_scr, acc_scr, q_ref[0],
-                    _page_kv(kv_ref, *scales), p, bound, scale, page,
-                    v_width)
+                    _page_kv(kv_ref, *scales), p, bound, scale,
+                    page)
 
     pl.when(last)(lambda: _finalize(o_ref, l_scr, acc_scr))
 
@@ -705,30 +705,30 @@ def _pa_read_call(q, kv_pages, block_tables, lengths, *scales,
                    static_argnames=("v_width", "scale", "interpret"))
 def _pa_latent_call(q, kv_pages, block_tables, lengths, *, v_width, scale,
                     interpret):
-    """The read kernel mounted on LATENT pages ``(N, 1, page, dk)``: one KV
+    """The absorbed kernel over LATENT pages ``(N, 1, page, dk)``: one KV
     head whose row is the key and, in its first ``v_width`` values, the
     value; the kernel's window is the ``Hq`` query heads that share it, so
-    one page DMA serves them all. The same ragged sweep."""
-    from jax.experimental import pallas as pl
+    one page DMA serves them all. The same ragged sweep, of BLOCKS of a
+    row's pages: a grid step fetches and folds up to ``k`` consecutive pages
+    of one row, so a long row pays a step's fixed cost once a block. ``k``
+    is no argument: it follows from what the call can see
+    (:func:`latent_block`), and a narrow table's short rows sweep page by
+    page, the program they always were. A page of a row's last block that
+    the row does not need is neither fetched nor folded: the trash page and
+    stale table entries hold NaN on the chip, and a masked key's zero
+    weight does not stop one (``0 x NaN``).
 
-    B, _, Hq, dk = q.shape
-    page = kv_pages.shape[2]
-    *sweep, total = _schedule(lengths, -1, page, block_tables.shape[1])
-    kernel = functools.partial(_pa_read_kernel, scale=scale, page=page,
-                               quant=False, v_width=v_width)
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=_grid_spec(
-            2, total,
-            in_specs=[pl.BlockSpec((1, 1, Hq, dk), _row_map),
-                      pl.BlockSpec((1, 1, page, dk), _page_map)],
-            out_specs=pl.BlockSpec((1, 1, Hq, v_width), _row_map),
-            state=_softmax_state(1, Hq, v_width)),
-        out_shape=jax.ShapeDtypeStruct((B, 1, Hq, v_width), q.dtype),
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )
-    return call(*sweep, block_tables, lengths, q, kv_pages)
+    The launch and the kernel's body are at the END of the file
+    (:func:`_latent_launch`) and this function keeps its length: a compiled
+    Pallas program's cache key holds the line numbers of the calls below.
+
+    The name is the trace's: the cells read this kernel's seconds under
+    ``jit_tick/_pa_latent_call``."""
+    return _latent_launch(
+        q, kv_pages, block_tables, lengths, v_width=v_width, scale=scale,
+        interpret=interpret, k=latent_block(
+            math.prod(kv_pages.shape[1:]) * kv_pages.dtype.itemsize,
+            block_tables.shape[1]))
 
 
 @functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
@@ -1211,3 +1211,145 @@ def paged_attention_window(q, k_new, v_new, kv_pages, block_tables, pos, *,
                                      W=W, scale=scale,
                                      interpret=bool(interpret))
     return (out[:, :, :W], *pools)
+
+
+# ---- the absorbed latent kernel: a BLOCK of a row's pages a grid step -------
+# Here, below every other call, so that their line numbers stand
+# (:func:`_pa_latent_call`).
+
+#: the most a grid step of the latent sweep fetches: four pages of 256 rows
+#: of 640 bf16 values. A step's fixed cost and its serial chain are paid once
+#: a block; past a megabyte or so a block more buys little (PERF.md, PR 44)
+_LATENT_BLOCK_BYTES = 5 << 18
+
+#: the fewest blocks a slot's block table holds. The page rule serves
+#: sixteen pages a slot and holds a page to 256 tokens
+#: (``serving/continuous.py`` ``derived_page_size``); where that cap leaves
+#: a slot more pages than sixteen its rows are long and the sweep takes up
+#: the slack in blocks, and a slot of sixteen pages keeps them one a step
+_LATENT_BLOCKS_A_SLOT = 16
+
+
+def latent_block(page_bytes: int, pages_a_slot: int) -> int:
+    """Pages a grid step of the latent sweep folds, from what a call can
+    see of its shapes: as many as :data:`_LATENT_BLOCK_BYTES` hold, a slot's
+    block table at least :data:`_LATENT_BLOCKS_A_SLOT` blocks wide, at least
+    one. The pool counts the sweep by the same rule
+    (``PagedKVPool.note_latent_sweep``)."""
+    return max(1, min(_LATENT_BLOCK_BYTES // page_bytes,
+                      pages_a_slot // _LATENT_BLOCKS_A_SLOT))
+
+
+def _block_holds(row_of, blk_of, lengths, page, n_pages, k):
+    """What each of a block's ``k`` page operands HOLDS at each step of the
+    block sweep, ``(k, steps)`` int32 of ``row * n_pages + page``: the page
+    ``k * blk + j`` of the step's row where the row needs it (it holds a
+    key below the row's length), else what the operand held a step before.
+    The pipeline fetches an operand's block when its index CHANGES, so a
+    page no row needs is never moved: a two-page row moves two pages. Before
+    the first step that needs it an operand holds row 0's first page."""
+    B = lengths.shape[0]
+    need_of = jnp.minimum(-(-lengths // page), n_pages)       # pages a row
+    mine = row_of[:, None] == jnp.arange(B, dtype=jnp.int32)[None, :]
+    needs = jnp.sum(jnp.where(mine, need_of[None, :], 0), axis=1)
+    p = k * blk_of[None, :] + jnp.arange(k, dtype=jnp.int32)[:, None]
+    flat = jnp.where(p < needs[None, :], row_of[None, :] * n_pages + p, -1)
+    return jnp.maximum(jax.lax.cummax(flat, axis=1), 0).astype(jnp.int32)
+
+
+def _held_page_map(j, n_pages):
+    """The index map of a block's page operand ``j``: the physical page of
+    what :func:`_block_holds` says it holds at step ``s``."""
+    def index_map(s, row, blk, last, bt, lengths, *holds):
+        f = holds[j][s]
+        return (bt[jax.lax.div(f, n_pages), jax.lax.rem(f, n_pages)], 0, 0, 0)
+    return index_map
+
+
+def _block_fold(m_scr, l_scr, acc_scr, q, pages, first, bound, scale, page,
+                v_width):
+    """Fold a WHOLE block, the row's pages ``first ..`` as ``pages`` (a list
+    of ``(1, page, dk)`` latent pages, every one needed), in ONE online
+    softmax update: the pages' scores side by side, one max, exp and sum
+    over the block's keys, the pages' value products added up. The
+    mathematics of a fold a page; the order of its float32 sums is another,
+    and the chain a step waits for is paid once a block."""
+    s = jnp.concatenate([_scores(q, kv, scale) for kv in pages], axis=-1)
+    t = first * page + jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, s.shape[-1]), 2)
+    _fold(m_scr, l_scr, acc_scr, s, t < bound, lambda p: sum(
+        _weigh(p[..., j * page:(j + 1) * page], kv[..., :v_width])
+        for j, kv in enumerate(pages)))
+
+
+def _pa_latent_kernel(row_ref, blk_ref, last_ref, bt_ref, len_ref, *rest,
+                      scale, page, v_width, k):
+    """One grid step = block ``blk`` of row ``b``: the row's pages
+    ``k * blk .. k * blk + k - 1``, each its own operand on the pool. A block
+    the row needs whole is one fold (:func:`_block_fold`). In a row's last
+    block a page it does not need was not fetched (:func:`_block_holds`: the
+    operand holds an older page, or on the chip NaN) and must not be folded
+    either, whatever its keys' mask: that block folds page by page, each
+    page under its own guard, which at ``k`` = 1 is all there is (the
+    one-page sweep, its program unchanged)."""
+    from jax.experimental import pallas as pl
+
+    rest = rest[k if k > 1 else 0:]     # past the holds: the index maps'
+    q_ref, pages, o_ref = rest[0], rest[1:1 + k], rest[1 + k]
+    state = rest[2 + k:]                # m, l, the accumulator
+    b, blk, last = _step(row_ref, blk_ref, last_ref)
+    pl.when(blk == 0)(lambda: _init(*state))
+    bound = len_ref[b]
+
+    def page_by_page():
+        for j, kv_ref in enumerate(pages):
+            p = blk if k == 1 else blk * k + j
+
+            @pl.when(p * page < bound)
+            def _page(kv_ref=kv_ref, p=p):
+                _pages_fold(*state, q_ref[0], kv_ref[0], p, bound, scale,
+                            page, v_width)
+
+    if k == 1:
+        page_by_page()
+    else:
+        whole = (blk * k + k - 1) * page < bound
+        pl.when(whole)(lambda: _block_fold(
+            *state, q_ref[0], [ref[0] for ref in pages], blk * k, bound,
+            scale, page, v_width))
+        pl.when(jnp.logical_not(whole))(page_by_page)
+    pl.when(last)(lambda: _finalize(o_ref, state[1], state[2]))
+
+
+def _latent_launch(q, kv_pages, block_tables, lengths, *, v_width, scale,
+                   interpret, k):
+    """The launch :func:`_pa_latent_call` makes, ``k`` pages a grid step."""
+    from jax.experimental import pallas as pl
+
+    B, _, Hq, dk = q.shape
+    page, n_pages = kv_pages.shape[2], block_tables.shape[1]
+    # a block is a page of k * page keys: the same sweep, of blocks
+    row_of, blk_of, last_of, total = _schedule(lengths, -1, k * page,
+                                               -(-n_pages // k))
+    if k == 1:
+        # every step needs its one page: the operand holds what the step says
+        holds, maps = (), [_page_map]
+    else:
+        holds = tuple(_block_holds(row_of, blk_of, lengths, page, n_pages, k))
+        maps = [_held_page_map(j, n_pages) for j in range(k)]
+    kernel = functools.partial(_pa_latent_kernel, scale=scale, page=page,
+                               v_width=v_width, k=k)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=_grid_spec(
+            2 + len(holds), total,
+            in_specs=[pl.BlockSpec((1, 1, Hq, dk), _row_map),
+                      *(pl.BlockSpec((1, 1, page, dk), m) for m in maps)],
+            out_specs=pl.BlockSpec((1, 1, Hq, v_width), _row_map),
+            state=_softmax_state(1, Hq, v_width)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, Hq, v_width), q.dtype),
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+    )
+    return call(row_of, blk_of, last_of, block_tables, lengths, *holds, q,
+                *[kv_pages] * k)

@@ -2152,6 +2152,16 @@ class ContinuousDecoder:
                 self._kv.note_grid_steps([pos + j for pos in positions],
                                          window, rows)
 
+    def _note_latent_sweep(self, positions, rows: int, calls: int) -> None:
+        """The grid steps of ``calls`` successive decode calls of the
+        absorbed latent kernel (pool ``latent_sweep_pages`` / ``_steps``; a
+        model with no mla layer counts nothing): a row at ``pos`` attends
+        its ``pos + 1`` keys, the token's own row among them."""
+        if self._attn_impl == "kernel" and self._hybrid:
+            for j in range(calls):
+                self._kv.note_latent_sweep(
+                    [pos + 1 + j for pos in positions], rows)
+
     def _note_sparse_ticks(self, context: int, calls: int = 1) -> None:
         """A model with sparse-attention layers counts each paged call a
         second time, by path: ``sparse`` when the longest context it served
@@ -2326,13 +2336,16 @@ class ContinuousDecoder:
             # a row's device position: its drained tokens plus those of
             # the blocks still in flight (a first-token block carries one,
             # a tick's k; this tick's is not yet pending)
-            self._note_sweep(
-                [req.prompt.size + len(req.tokens) - 1
-                 + sum(min(toks.shape[0], self._k)
-                       for toks, block in self._pending
-                       if any(r is req for _, r in block.values()))
-                 for req in (self._slot_req[i] for i in decode_live)],
-                self._gamma + 1 if self._spec else 1, self._S, self._k)
+            positions = [
+                req.prompt.size + len(req.tokens) - 1
+                + sum(min(toks.shape[0], self._k)
+                      for toks, block in self._pending
+                      if any(r is req for _, r in block.values()))
+                for req in (self._slot_req[i] for i in decode_live)]
+            self._note_sweep(positions,
+                             self._gamma + 1 if self._spec else 1, self._S,
+                             self._k)
+            self._note_latent_sweep(positions, self._S, self._k)
             # snapshot slot→REQUEST (not indices): by the time this block
             # is drained, a slot may have been freed and re-admitted;
             # tokens must go to the request that occupied the slot at

@@ -100,6 +100,16 @@ M_LATENT_WINDOW_KEYS = _metric_gauge(
     "attended over, in whole tiles of the window's fold (a window rebuilds K "
     "and V of its row's context a tile at a time, up to its last key), since "
     "the pool was built")
+M_LATENT_SWEEP_PAGES = _metric_gauge(
+    "mmlspark_kvpool_latent_sweep_pages",
+    "Pages the decode ticks' absorbed latent kernel had to fold (the pages "
+    "that hold a key of a live row, every mla layer's call), since the pool "
+    "was built")
+M_LATENT_SWEEP_STEPS = _metric_gauge(
+    "mmlspark_kvpool_latent_sweep_steps",
+    "Grid steps those calls swept (a step folds a block of a row's pages; a "
+    "row with nothing to read takes one), since the pool was built: pages "
+    "over steps is how full the blocks ran")
 M_PREFIX_TOKENS_SHARED = _metric_gauge(
     "mmlspark_kvpool_prefix_tokens_shared",
     "Tokens of stored prefix pages admitted requests took by reference "
@@ -215,6 +225,7 @@ class PagedKVPool:
         if self.hybrid:
             from ..models.zoo.hybrid import (SLOT_KEYS, dims, pool_shapes,
                                              window_tile)
+            from ..ops.paged_attention import latent_block
             if kv_dtype is not None or sharding is not None:
                 raise ValueError("a hybrid decoder's pool is bf16 pages "
                                  "(K beside V of a sparse or gqa layer, or "
@@ -240,6 +251,16 @@ class PagedKVPool:
             self.page_size * window_tile(
                 self.page_size, self.pages_per_slot(slot_positions))
             if self.hybrid and "mla" in cfg.mixers else 0)
+        #: the absorbed kernel's sweep as the scheduler counts it: the mla
+        #: layers (a call each a tick) and the pages a grid step folds, by
+        #: the rule the call itself reads its shapes with
+        mla = [layer["kv"] for layer, kind in zip(
+            self._layer_shapes or (), getattr(cfg, "mixers", ()))
+            if kind == "mla"]
+        self.latent_calls = len(mla)
+        self.latent_block = latent_block(
+            int(np.prod(mla[0][0][1:])) * jnp.dtype(mla[0][1]).itemsize,
+            self.pages_per_slot(slot_positions)) if mla else 0
         #: one K or V page as a session blob carries it, (H, page, hd);
         #: the pool's buffer packs the two side by side on the minor axis
         self._page_shape = (heads, self.page_size, hd)
@@ -290,6 +311,7 @@ class PagedKVPool:
                       "prefill_tokens": 0, "prefix_tokens_shared": 0,
                       "latent_window_keys": 0, "latent_window_context": 0,
                       "latent_window_pairs": 0,
+                      "latent_sweep_pages": 0, "latent_sweep_steps": 0,
                       "alloc_failures": 0,
                       "gather_bytes": 0, "attn_ticks_kernel": 0,
                       "attn_ticks_gather": 0, "grid_steps": 0,
@@ -715,6 +737,24 @@ class PagedKVPool:
         self.stats["latent_window_pairs"] += (lanes * offset
                                               + lanes * (lanes + 1) // 2)
         M_LATENT_WINDOW_KEYS.set(self.stats["latent_window_keys"])
+
+    def note_latent_sweep(self, lengths: Sequence[int], rows: int) -> None:
+        """Account the absorbed latent kernel's sweep of one decode call of
+        a model with mla layers (else nothing), from the scheduler's
+        numbers: a live row of ``lengths[i]`` cached keys needs the pages
+        that hold them and sweeps them :attr:`latent_block` a grid step,
+        each of the call's other ``rows`` one step; every mla layer's call
+        sweeps the same. ``latent_sweep_pages / latent_sweep_steps`` is how
+        full the blocks ran."""
+        if not self.latent_calls:
+            return
+        pages = [-(-n // self.page_size) for n in lengths]
+        self.stats["latent_sweep_pages"] += self.latent_calls * sum(pages)
+        self.stats["latent_sweep_steps"] += self.latent_calls * (
+            rows - len(pages)
+            + sum(max(1, -(-p // self.latent_block)) for p in pages))
+        M_LATENT_SWEEP_PAGES.set(self.stats["latent_sweep_pages"])
+        M_LATENT_SWEEP_STEPS.set(self.stats["latent_sweep_steps"])
 
     def note_attn_tick(self, impl: str, *, calls: int = 1,
                        gather_bytes: int = 0) -> None:

@@ -487,6 +487,26 @@ def test_latent_read_kernel_compiles(one_chip):
         one_chip((z["slots"], per), jnp.int32),
         one_chip((z["slots"],), jnp.int32))
     assert "_pa_latent_call" in text
+    # sixteen pages a slot: the sweep stays a page a step, the call the
+    # program it was before the sweep went in blocks (one page operand beside
+    # the grid bound, the sweep's three vectors, the table, the lengths and
+    # the query; tree against tree, jaxpr and lowered text: CHANGES.md, PR 44)
+    assert _latent_operands(text) == (8, 1)
+
+
+def _latent_operands(text):
+    """``(operands, of which the pool)`` of the compiled program's one
+    ``_pa_latent_call`` custom call."""
+    (line,) = [ln for ln in text.splitlines()
+               if "tpu_custom_call" in ln and "_pa_latent_call" in ln]
+    ops = _call_operands(line)
+    return len(ops), max(ops.count(op) for op in set(ops))
+
+
+def _call_operands(line):
+    """The operand names of a compiled text's ``custom-call(...)`` line."""
+    return re.findall(r"%[\w.\-]+", line.split("custom-call(")[1].split(
+        "), custom_call_target")[0])
 
 
 @pytest.mark.parametrize("tokens", [32, 256], ids=["tick", "prefill_chunk"])
@@ -717,6 +737,20 @@ def test_latent_read_kernel_compiles_at_twenty_heads(one_chip):
     text = _compiled_text(fn, *args)
     assert "_pa_latent_call" in text and "f32[32,1,24,640]" in text
     assert jax.eval_shape(fn, *args).shape == (32, 20, 512)
+    # 128 pages a slot: a grid step is a block of FOUR pages of one row, four
+    # page operands on the one pool (and what each holds a step: four more
+    # vectors), under a traced grid bound; the pool is not copied, and no
+    # array joins a block's pages (4 x 256 rows of 640): the scores of a
+    # whole block stand side by side, its pages never
+    assert _latent_operands(text) == (15, 4)
+    pool = "bf16[1024,1,256,640]"
+    assert not [ln for ln in text.splitlines()
+                if f"= {pool}" in ln and " copy(" in ln]
+    jaxpr = str(jax.make_jaxpr(fn)(*args))
+    assert "grid=(DynamicGridDim,)" in jaxpr
+    assert jaxpr.count("Blocked(block_size=256), Blocked(block_size=640)") == 4
+    assert "f32[1,24,1024]" in jaxpr            # a whole block's scores
+    assert not re.search(r"\[[0-9,]*1024,(640|512)\]", jaxpr.replace(pool, ""))
 
 
 @pytest.fixture(scope="module")
@@ -745,8 +779,16 @@ def test_latent_programs_compile_and_hold_a_tile_not_the_slot(glm_programs,
     lines = text.splitlines()
     _one_read_of_the_experts(text, pool.cfg)
     if program in ("tick", "riding"):
-        assert sum("tpu_custom_call" in ln and "_pa_latent_call" in ln
-                   for ln in lines) == 7
+        calls = [ln for ln in lines
+                 if "tpu_custom_call" in ln and "_pa_latent_call" in ln]
+        assert len(calls) == 7
+        # the block sweep is built once a tick: every layer's call takes the
+        # same grid bound, and in the plain tick the same sweep, table and
+        # lengths (its first six operands; around a window's temporaries the
+        # compiler brings the 4 KB vectors into VMEM again a layer)
+        same = 6 if program == "tick" else 1
+        assert len({tuple(_call_operands(ln)[:same]) for ln in calls}) == 1
+        assert all(len(_call_operands(ln)) == 15 for ln in calls)
     whiles = sum(" while(" in ln for ln in lines)
     assert whiles == (0 if program == "tick" else 7)
     assert not re.search(r"\[[0-9,]*32768[0-9,]*\]", text)

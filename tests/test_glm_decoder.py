@@ -249,16 +249,21 @@ def test_the_window_holds_a_tile_and_its_bound_is_traced(cfg):
 def latent_case(H, dtype=np.float32, seed=0, page=8, P=6, dk=128, dv=96):
     rng = np.random.default_rng(seed)
     B = 5
-    lengths = np.asarray([0, 1, 17, 38, 48], np.int32)
+    # a slot of six pages is swept a page a step, a wider one four (PR 44):
+    # its rows end inside a block, on its edge and at the table's end
+    lengths = np.asarray([0, 1, 17, 38, 48] if P == 6
+                         else [0, 31, 32, 8 * P, 301], np.int32)
     bt = 1 + rng.permutation(B * P).reshape(B, P).astype(np.int32)
     pool = rng.normal(size=(B * P + 1, 1, page, dk)).astype(dtype)
     q = rng.normal(size=(B, H, dk)).astype(np.float32)
     return q, pool, bt, lengths, dv
 
 
+@pytest.mark.parametrize("P", [6, 64], ids=["a_page_a_step", "four_a_step"])
 @pytest.mark.parametrize("H", [20, 5, 3, 8])
-def test_latent_kernel_against_a_float32_oracle_at_any_head_count(H):
-    q, pool, bt, lengths, dv = latent_case(H)
+def test_latent_kernel_against_a_float32_oracle_at_any_head_count(H, P):
+    q, pool, bt, lengths, dv = latent_case(H, P=P)
+    assert pa.latent_block(pool[0].nbytes, P) == (1 if P == 6 else 4)
     got = np.asarray(pa.paged_attention_latent(
         *map(jnp.asarray, (q, pool, bt, lengths)), v_width=dv, scale=0.25,
         interpret=True))
@@ -299,6 +304,26 @@ def test_a_whole_head_count_reaches_the_call_as_it_is(monkeypatch):
                        interpret=True)[:, 0, :H]
         assert seen[-1] == (5, 1, padded, 128)
         assert np.array_equal(np.asarray(got), np.asarray(direct))
+
+
+# ---- the sweep in blocks, as the pool counts it -----------------------------
+
+def test_the_pool_counts_the_sweep_by_the_calls_own_rule(cfg):
+    """A slot of 64 pages sweeps four a grid step (``latent_block``, the rule
+    the call reads its shapes with); a row with nothing to read takes one
+    step, as every idle row of the call does; every mla layer's call sweeps
+    the same."""
+    pool = PagedKVPool(cfg, num_pages=80, page_size=8, slots=8,
+                       slot_positions=512)
+    layers = cfg.mixers.count("mla")
+    assert (pool.latent_calls, pool.latent_block) == (layers, 4)
+    pool.note_latent_sweep([1, 8, 9, 33, 100, 0], rows=8)
+    # pages 1, 1, 2, 5, 13, 0; steps 1, 1, 1, 2, 4, 1 and two idle rows
+    assert pool.stats["latent_sweep_pages"] == layers * 22
+    assert pool.stats["latent_sweep_steps"] == layers * 12
+    pool.note_latent_sweep([512] * 8, rows=8)
+    assert pool.stats["latent_sweep_pages"] == layers * (22 + 8 * 64)
+    assert pool.stats["latent_sweep_steps"] == layers * (12 + 8 * 16)
 
 
 # ---- the routed feed-forward with its shared expert ----------------------------
@@ -368,6 +393,22 @@ def test_decoder_equals_the_reference_with_slots_reused(params, cfg, sizes,
     assert stats["latent_window_pairs"] == sum(
         w * o + w * (w + 1) // 2 for o, w in windows)
     assert decoder._kv.snapshot_bytes == 0
+    # the absorbed kernel's sweep as the scheduler counts it: a request's
+    # five ticks after its first token attend len + 1 .. len + 5 keys in each
+    # of the four mla layers (and a tick dispatched before its last token
+    # was drained one key more); 28 pages a slot are swept a page a step,
+    # every row of a tick at least one step
+    pool = decoder._kv
+    assert (pool.latent_calls, pool.latent_block) == (4, 1)
+    def pages(j):
+        return 4 * sum(-(-(len(p) + j) // 8) for p in prompts)
+    if impl == "kernel":
+        want = sum(pages(j) for j in range(1, 6))
+        assert want <= stats["latent_sweep_pages"] <= want + pages(6)
+        idle = stats["latent_sweep_steps"] - stats["latent_sweep_pages"]
+        assert idle % 4 == 0 and 0 < idle <= 4 * 3 * stats["attn_ticks_latent"]
+    else:
+        assert stats["latent_sweep_pages"] == stats["latent_sweep_steps"] == 0
 
 
 def test_four_rows_on_one_stored_prefix(params, cfg, sizes, ids):

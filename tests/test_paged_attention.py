@@ -21,6 +21,7 @@ The contract this file pins:
    steady-state recompiles once its tick program is warm.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -37,9 +38,10 @@ from mmlspark_tpu.models.zoo.transformer import (
 from mmlspark_tpu.ops.compile_cache import jit_cache_size
 from mmlspark_tpu.ops.kv_quant import SCALE_DTYPE, quantize_kv
 from mmlspark_tpu.ops.paged_attention import (
-    ENV_KNOB, _HEADS, _fused_schedule, _heads_of, _heads_query,
-    _pa_window_read_call, _pool_write_rows, _schedule, _scores,
-    _whole_groups, aligned_page_size, pack_kv, paged_attention,
+    ENV_KNOB, _HEADS, _block_holds, _fused_schedule, _heads_of,
+    _heads_query, _latent_launch, _pa_window_read_call, _pool_write_rows,
+    _schedule, _scores, _whole_groups, aligned_page_size, latent_block,
+    pack_kv, paged_attention,
     paged_attention_latent, paged_attention_selected,
     paged_attention_window, split_kv, resolve_impl, sublane_multiple)
 from mmlspark_tpu.serving.continuous import ContinuousDecoder
@@ -640,6 +642,173 @@ class TestOperandRule:
                                   run(slice(cut, heads))], axis=1)
         assert np.array_equal(np.asarray(whole.astype(jnp.float32)),
                               np.asarray(shards.astype(jnp.float32)))
+
+
+# what a call of the latent sweep sees and the pages a grid step folds by it:
+# (rows of a page, row width, bytes a value, pages a slot) -> k
+LATENT_BLOCKS = {
+    # glmflash_repoctx_shared32: 328 KB pages, 128 a slot: four a step
+    "glm_32k_slots": ((256, 640, 2, 128), 4),
+    # lingflash_reason_closed32: the same page, sixteen a slot: one a step
+    "ling_4k_slots": ((256, 640, 2, 16), 1),
+    "a_slot_of_32_pages": ((256, 640, 2, 32), 2),
+    "a_long_slot_is_held_by_the_bytes": ((256, 640, 2, 1024), 4),
+    "a_float32_pool_halves_the_block": ((256, 640, 4, 128), 2),
+    "a_page_past_the_block_is_one_a_step": ((512, 1024, 2, 128), 1),
+    "tiny_pages_are_held_by_the_table": ((8, 128, 4, 64), 4),
+}
+
+
+@pytest.mark.parametrize("name", list(LATENT_BLOCKS))
+def test_latent_block_follows_the_page_and_the_table(name):
+    (rows, width, itemsize, per), want = LATENT_BLOCKS[name]
+    assert latent_block(rows * width * itemsize, per) == want
+
+
+# (page, pages a slot, k, lengths): what each of a block's k operands holds
+# at each step, as (row, page) pairs, beside the sweep it rides
+HOLDS = {
+    # a two-page row moves two pages: operands 2 and 3 keep row 0's first
+    "a_two_page_row_moves_two_pages": (4, 8, 4, [8, 0, 3]),
+    # row 1's last block needs one page of four: the others keep row 1's
+    # previous block, and row 2's first block takes over operand by operand
+    "a_last_block_keeps_the_block_before": (4, 8, 4, [32, 17, 32]),
+    "k2_rows_of_every_parity": (4, 6, 2, [1, 8, 9, 24, 0, 13]),
+    "a_table_no_multiple_of_the_block": (4, 6, 4, [24, 5, 24]),
+}
+
+
+@pytest.mark.parametrize("name", list(HOLDS))
+def test_block_operands_hold_their_page_until_a_step_needs_another(name):
+    page, P, k, lengths = HOLDS[name]
+    lens = jnp.asarray(lengths, jnp.int32)
+    row_of, blk_of, last_of, total = _schedule(lens, -1, k * page,
+                                               -(-P // k))
+    need = [-(-n // page) for n in lengths]
+    rows, blks = _steps_of([max(1, -(-n // k)) for n in need])
+    total = int(total)
+    assert total == len(rows)
+    assert list(np.asarray(row_of)[:total]) == rows
+    assert list(np.asarray(blk_of)[:total]) == blks
+    holds = np.asarray(_block_holds(row_of, blk_of, lens, page, P, k))
+    assert holds.shape == (k, row_of.shape[0]) and holds.dtype == np.int32
+    fetched = 0
+    for j in range(k):
+        held = 0                        # before any need: row 0's first page
+        for s, (b, blk) in enumerate(zip(rows, blks)):
+            if k * blk + j < need[b]:
+                held = b * P + k * blk + j
+            assert holds[j, s] == held, (j, s)
+        # the pipeline moves an operand's block when its index changes
+        seen = holds[j, :total]
+        fetched += 1 + int(np.sum(seen[1:] != seen[:-1]))
+    # every needed page once, and an operand's first block where the first
+    # step has no need of it
+    assert sum(need) <= fetched <= sum(need) + k
+
+
+class TestLatentBlocks:
+    """The absorbed latent kernel a BLOCK of a row's pages a grid step
+    (PR 44) against the one-page sweep (``k`` = 1, the program it was) and a
+    float32 oracle: lengths at every edge of a page and of a block, rows of
+    unequal length in one call so that block and row boundaries interleave,
+    NaN in every page a row does not need (the unneeded pages of its last
+    block and trash page 0 among them), the largest finite bf16 past a
+    row's bound in the pages it needs."""
+
+    PAGE, P, DK, DV = 8, 10, 128, 96
+
+    def _case(self, H, k, dtype=jnp.bfloat16):
+        page, P = self.PAGE, self.P
+        lengths = np.asarray(
+            [k * page + 1, 0, page - 1, P * page, 1, k * page, page + 1,
+             k * page - 1, page, 2 * k * page + 3, P * page - 1], np.int32)
+        B = len(lengths)
+        rng = np.random.default_rng(17 * H + k)
+        bt = 1 + rng.permutation(B * P).reshape(B, P).astype(np.int32)
+        pool = rng.normal(0, 1, (1 + B * P, 1, page, self.DK))
+        big = float(jnp.finfo(jnp.bfloat16).max)
+        for b, n in enumerate(lengths):
+            for t in range(int(n), -(-int(n) // page) * page):
+                pool[bt[b, t // page], 0, t % page] = big * (-1) ** t
+            pool[bt[b, -(-int(n) // page):]] = np.nan
+        pool[0] = np.nan
+        q = rng.normal(0, 1, (B, H, self.DK)).astype(np.float32)
+        return (jnp.asarray(q), jnp.asarray(pool, dtype), jnp.asarray(bt),
+                jnp.asarray(lengths))
+
+    def _run(self, k, q, pool, bt, lengths):
+        H = q.shape[1]
+        qp = jnp.pad(q, ((0, 0), (0, -H % 8), (0, 0)))[:, None]
+        call = jax.jit(functools.partial(
+            _latent_launch, v_width=self.DV, scale=0.25, interpret=True,
+            k=k))
+        return np.asarray(call(qp, pool, bt, lengths))[:, 0, :H]
+
+    def _oracle(self, q, pool, bt, lengths):
+        q, pool = (np.asarray(t.astype(jnp.float32)) for t in (q, pool))
+        out = np.zeros(q.shape[:2] + (self.DV,), np.float32)
+        for b, n in enumerate(np.asarray(lengths)):
+            if n:
+                rows = np.concatenate(
+                    [pool[p, 0] for p in np.asarray(bt)[b]])[:n]
+                s = q[b] @ rows.T * 0.25
+                p = np.exp(s - s.max(axis=1, keepdims=True))
+                out[b] = (p / p.sum(axis=1, keepdims=True)) @ rows[:, :self.DV]
+        return out
+
+    @pytest.mark.parametrize("H", [20, 32, 5])
+    @pytest.mark.parametrize("k", [1, 2, 4])
+    def test_blocks_against_the_one_page_sweep_and_the_oracle(self, k, H):
+        """A row's last, partial block folds page by page, the one-page
+        sweep's folds in its order: rows of fewer pages than a block are
+        that sweep's contexts BIT FOR BIT. A whole block is one fold, the
+        same mathematics under another order of float32 sums: held to the
+        float32 oracle at the kernel's 2e-5, as the one-page sweep is."""
+        case = self._case(H, k)
+        got, one = self._run(k, *case), self._run(1, *case)
+        assert np.isfinite(got).all() and np.isfinite(one).all()
+        need = -(-np.asarray(case[3]) // self.PAGE)
+        assert (need < k).sum() >= (2 if k > 1 else 0)
+        assert np.array_equal(got[need < k], one[need < k])
+        if k == 1:
+            assert np.array_equal(got, one)
+        want = self._oracle(*case)
+        assert not got[1].any()                    # a row with no key
+        assert np.abs(got - want).max() < 2e-5
+        assert np.abs(one - want).max() < 2e-5
+
+    @pytest.mark.parametrize("k", [2, 4])
+    def test_float32_pages_fold_in_blocks_too(self, k):
+        case = self._case(8, k, jnp.float32)
+        got = self._run(k, *case)
+        assert np.isfinite(got).all()
+        assert np.abs(got - self._oracle(*case)).max() < 2e-5
+
+    def test_the_call_picks_its_block_from_its_shapes(self, monkeypatch):
+        """``paged_attention_latent`` through ``_pa_latent_call``: a table of
+        64 pages of 8 float32 rows gives four pages a step, one of 10 gives
+        one; no argument says so."""
+        import mmlspark_tpu.ops.paged_attention as pa
+        seen = []
+        inner = pa._latent_launch
+
+        def spy(*args, k, **kw):
+            seen.append(k)
+            return inner(*args, k=k, **kw)
+
+        monkeypatch.setattr(pa, "_latent_launch", spy)
+        q, pool, bt, lengths = self._case(8, 4, jnp.float32)
+        wide = jnp.pad(bt, ((0, 0), (0, 64 - self.P)))
+        for table, k in ((bt, 1), (wide, 4)):
+            pa._pa_latent_call.clear_cache()
+            got = np.asarray(paged_attention_latent(
+                q, pool, table, lengths, v_width=self.DV, scale=0.25,
+                interpret=True))
+            assert seen[-1] == k
+            assert np.abs(got - self._oracle(q, pool, bt, lengths)).max() \
+                < 2e-5
+        pa._pa_latent_call.clear_cache()
 
 
 class TestDecodeParity:

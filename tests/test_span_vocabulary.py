@@ -360,7 +360,9 @@ def test_snapshot_bytes_are_a_conv_layers_tails_alone(conv_gqa_run):
 #: its span (``continuous.prefill_chunk``'s ``context=``)
 LATENT_GAUGES = [("mmlspark_kvpool_latent_window_keys", "latent_window_keys"),
                  ("mmlspark_kvpool_prefix_tokens_shared",
-                  "prefix_tokens_shared")]
+                  "prefix_tokens_shared"),
+                 ("mmlspark_kvpool_latent_sweep_pages", "latent_sweep_pages"),
+                 ("mmlspark_kvpool_latent_sweep_steps", "latent_sweep_steps")]
 
 
 @pytest.fixture(scope="module")
@@ -408,7 +410,13 @@ def test_latent_gauges_hold_the_pools_counts(latent_run, gauge, stat):
     stats = dec._kv.stats
     # windows of 16, 8 and 6 lanes (the miss) and of 9 and 11 (the hits), a
     # tile the slot's 12 pages; two hits x three whole pages of 8 tokens
-    want = {"latent_window_keys": 5 * 96, "prefix_tokens_shared": 2 * 24}
+    # the absorbed kernel's sweep, counted a tick from the rows' lengths: two
+    # mla layers, a slot's 12 pages a page a step, an idle row one step
+    want = {"latent_window_keys": 5 * 96, "prefix_tokens_shared": 2 * 24,
+            "latent_sweep_pages": stats["latent_sweep_pages"],
+            "latent_sweep_steps": stats["latent_sweep_steps"]}
+    assert want["latent_sweep_steps"] >= want["latent_sweep_pages"] \
+        >= 2 * 4 * stats["attn_ticks_latent"]
     assert stats[stat] == want[stat]
     assert [s["value"] for s in snap[gauge]["series"]] == [want[stat]]
     assert stats["latent_window_context"] == 16 + 24 + 30 + 33 + 35
