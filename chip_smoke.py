@@ -26,6 +26,12 @@ reference computed in the same process:
                  under all 64 routed experts with their shared expert) on
                  ``ContinuousDecoder``: a context registered as a prefix of
                  pages alone, then two callers on it at once
+  J. state space — published layers 36-38 of Nemotron 3 Super's share at
+                 their published widths (the 32/2 grouped-query layer without
+                 positions, 128 of 512 relu^2 experts in the 1,024 latent
+                 beside the shared expert, one Mamba-2 layer with no
+                 feed-forward) on ``ContinuousDecoder``, then the state-space
+                 step alone at the cell's shapes
 
 ``--chips 4`` runs instead ONLY the two paths that exist across chips and what
 each is compared with: D. data-parallel GBDT over a 4-device ``data`` mesh,
@@ -78,6 +84,11 @@ CONV_GQA_GAP_MEAN = 0.15
 #: the mean on the chip (my chip run, PR 42; the widest gap 0.76); the limit
 #: is phase G's
 LATENT_GAP_MEAN = 0.05
+#: phase J's: 22 of 512 experts a token at ~5/22 of the weight each beside a
+#: shared expert, ONE routed layer of the three: a swapped 22nd expert moves
+#: a token less than a swapped 4th of 64. The limit is phase G's; the chip's
+#: reading is in PERF.md (PR 45)
+SSM_GAP_MEAN = 0.05
 QUANT_ERR_BOUND = 0.05   # tests/test_kv_quant.py's bound on the int8 probe
 LOGIT_TOL = 0.06         # bf16 ResNet-50 logits vs float32, relative to max|ref|
 
@@ -262,6 +273,17 @@ def sizes(small):
                         vocab_size=256, compute_dtype="float32",
                         param_dtype="float32"),
             latent_len=160, latent_context=48,
+            # phase J at toy widths: the tests' tiny state-space decoder
+            ssm=dict(hidden_size=64, num_attention_heads=16,
+                     num_key_value_heads=1, head_dim=8, mamba_num_heads=8,
+                     mamba_head_dim=8, ssm_state_size=16, n_groups=2,
+                     chunk_size=8, moe_latent_size=32,
+                     moe_intermediate_size=48,
+                     moe_shared_expert_intermediate_size=64,
+                     n_routed_experts=8, experts_held=[0, 8],
+                     published=dict(n_routed_experts=32),
+                     num_experts_per_tok=6, vocab_size=256,
+                     compute_dtype="float32", param_dtype="float32"),
             pool_decoder=TransformerConfig(vocab=256, layers=2, d_model=64,
                                            heads=4, d_ff=128, max_len=64,
                                            causal=True, dtype=jnp.bfloat16),
@@ -378,7 +400,7 @@ def serve_share(sz, seed, small, config_file, driver, cut, small_sizes):
     from mmlspark_tpu.serving.continuous import ContinuousDecoder
     with open(os.path.join(REPO, "benchmarks", "configs", config_file)) as fh:
         config = json.load(fh)
-    config.update(num_hidden_layers=3, layers_held=[0, 10, 11], **cut)
+    config.update(dict(num_hidden_layers=3, layers_held=[0, 10, 11]), **cut)
     if small:
         config.update(small_sizes)
     reference = bench_run.load_by_path("references", config["reference"])
@@ -464,6 +486,104 @@ def phase_conv_gqa(sz, seed, small):
     return ck, dict(detail,
                     attn_ticks_gqa=stats.get("attn_ticks_gqa", 0),
                     attn_ticks_conv=stats.get("attn_ticks_conv", 0))
+
+
+# ---------------------------------------------------------------------------
+# J. state space (the cell nemotronsuper_chat_closed32's model, three
+# published layers of it)
+
+
+def ssm_step_alone(ck, seed, small):
+    """The state-space step ALONE at the cell's shapes (32 rows of 128 heads
+    of 64 on a state 128 wide in 8 groups; toy shapes under ``--small``): one
+    step against the plain recurrence in float32, an inactive row's state
+    untouched, and (on the chip) its seconds a call over five calls a
+    program, the cell's five ``M`` layers, beside the bytes a call must move
+    at the chip's peak."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops.ssm_step import (pack_state, ssm_decode_step,
+                                           unpack_state)
+    B, H, P, N, G = (3, 8, 8, 16, 2) if small else (32, 128, 64, 128, 8)
+    k = jax.random.split(jax.random.key(seed), 6)
+    u = jax.random.normal(k[0], (B, H, P))
+    d = jax.random.uniform(k[1], (B, H), minval=0.001, maxval=0.3)
+    a = jnp.exp(-d * jax.random.uniform(k[2], (H,), minval=1.0, maxval=16.0))
+    b, c = (jax.random.normal(kk, (B, G, N)) for kk in k[3:5])
+    state = jax.random.normal(k[5], (B, H, P, N))
+    active = jnp.arange(B) != 1
+    y, new = ssm_decode_step(u * d[..., None], a, b, c, pack_state(state),
+                             active)
+    bh, ch = (jnp.repeat(t, H // G, axis=1) for t in (b, c))
+    want = (a[..., None, None] * state
+            + (u * d[..., None])[..., None] * bh[:, :, None, :])
+    want_y = jnp.einsum("bhpn,bhn->bhp", want, ch,
+                        precision=jax.lax.Precision.HIGHEST)
+    new = unpack_state(new)
+    err = float(jnp.abs(jnp.where(active[:, None, None], y - want_y,
+                                  0.0)).max())
+    err_s = float(jnp.abs(jnp.where(active[:, None, None, None], new - want,
+                                    0.0)).max())
+    ck.require(err < 1e-4 and err_s < 1e-5,
+               f"the step is {err:.2e} / {err_s:.2e} from the recurrence")
+    ck.require(bool(jnp.array_equal(new[1], state[1])),
+               "an inactive row's state moved")
+    detail = dict(rows=B, heads=H, y_err=err, state_err=err_s)
+    if not small:
+        calls = 5
+
+        @jax.jit
+        def many(du, a_, b_, c_, st):
+            for _ in range(calls):
+                y_, st = ssm_decode_step(du, a_, b_, c_, st, active)
+                du = du + 1e-3 * y_
+            return du, st
+        args = (u * d[..., None], a, b, c, pack_state(state))
+        jax.block_until_ready(many(*args))
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = many(*args)
+        jax.block_until_ready(out)
+        per_call = (time.perf_counter() - t0) / (20 * calls)
+        nbytes = 2 * (B - 1) * H * P * N * 4    # the live rows, in and out
+        detail.update(ms_a_call=1e3 * per_call,
+                      share_of_819_GB_s=nbytes / 819e9 / per_call)
+    return detail
+
+
+def phase_ssm(sz, seed, small):
+    """Published layers 36, 37 and 38 of the state-space configuration at
+    its published widths (the grouped-query layer at 32 heads over 2, 128 of
+    512 relu^2 experts in the 1,024 latent beside the shared expert, one
+    Mamba-2 layer with no feed-forward after it): every tick on the
+    state-space step, the grouped-query kernel and the grouped product, no
+    pair dropped; the tokens are judged in the mean (:data:`SSM_GAP_MEAN`);
+    then the step alone at the cell's shapes."""
+    ck = Checks()
+    stats, prompts, gaps, detail = serve_share(
+        sz, seed, small, "nemotron3_super_ep4_l11.json", "generate_nemotron",
+        dict(hybrid_override_pattern="*EM", layers_held=[36, 37, 38]),
+        sz.get("ssm"))
+    ck.require(stats.get("attn_ticks_ssm", 0) > 0
+               and stats.get("attn_ticks_gqa", 0) > 0
+               and not stats.get("attn_ticks_ssm_window", 0)
+               and not stats.get("attn_ticks_gqa_window", 0)
+               and not stats["attn_ticks_gather"],
+               f"a tick left the state-space step or the grouped-query "
+               f"kernel: {stats}")
+    ck.require(stats.get("moe_pairs_held", 0) > 0
+               and stats["moe_pairs_dropped"] == 0
+               and stats["moe_pairs_misplaced"] == 0,
+               f"routed pairs dropped or misplaced: {stats}")
+    ck.require(stats["ssm_state_rows"] > 0
+               and stats["prefill_tokens"] == sum(len(p) for p in prompts),
+               f"ssm_state_rows {stats['ssm_state_rows']}, prefill_tokens "
+               f"{stats['prefill_tokens']}")
+    require_gap_mean(ck, gaps, SSM_GAP_MEAN)
+    return ck, dict(detail, step_alone=ssm_step_alone(ck, seed, small),
+                    attn_ticks_ssm=stats.get("attn_ticks_ssm", 0),
+                    attn_ticks_gqa=stats.get("attn_ticks_gqa", 0),
+                    ssm_state_rows=stats["ssm_state_rows"])
 
 
 # ---------------------------------------------------------------------------
@@ -1141,7 +1261,7 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the two cross-chip paths (D, E)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--phase", action="append", choices=list("ABCDEFGHI"),
+    ap.add_argument("--phase", action="append", choices=list("ABCDEFGHIJ"),
                     help="run only this phase (repeatable; for fault-finding)")
     args = ap.parse_args(argv)
 
@@ -1208,6 +1328,8 @@ def main(argv=None):
                   "H": ("H.conv_gqa", lambda: phase_conv_gqa(
                       sz, args.seed, args.small)),
                   "I": ("I.latent", lambda: phase_latent(
+                      sz, args.seed, args.small)),
+                  "J": ("J.state_space", lambda: phase_ssm(
                       sz, args.seed, args.small))}
     for key, (name, run) in phases.items():
         if args.phase and key not in args.phase:

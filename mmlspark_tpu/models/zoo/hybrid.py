@@ -73,7 +73,30 @@ short convolutions beside grouped-query attention; ``cfg.conv``):
   2 hd) as a sparse layer's and nothing a slot. A window scatters its K/V
   through the block table and attends its row's gathered pages under the
   causal mask; the decode tick is ONE fused launch,
-  ``ops.paged_attention.paged_attention_gqa``.
+  ``ops.paged_attention.paged_attention_gqa``. With ``cfg.qk_positions``
+  False the layer is plain: no q/k norm, no rotation.
+
+A seventh mixer, a latent form of the routed feed-forward, and layers that
+are their mixer alone (a decoder of selective state-space layers, routed
+experts in a latent and one grouped-query layer in a period; ``cfg.ssm``):
+
+* ``ssm`` - Mamba-2: ``[z | xBC | dt] = W_in x``; ``xBC`` passes a causal
+  depthwise convolution of ``taps`` taps with a bias and a SiLU and splits
+  into ``u`` (``heads`` of ``head_dim``), ``B`` and ``C`` (``groups`` of
+  ``state``); a head, in float32: ``d_t = softplus(dt_t + dt_bias_h)``,
+  ``a_t = exp(d_t A_h)``, ``A_h = -exp(A_log_h)``, ``S_t = a_t S_(t-1) +
+  d_t u_t B_t^T`` on a ``head_dim x state`` state (``B``, ``C`` of the
+  head's group), ``y_t = S_t C_t + D_h u_t``; out ``W_out(GroupRMSNorm(y *
+  silu(z)))``, the norm over each of the ``groups`` groups of channels. The
+  cache entry is ``{"state", "conv"}``: the state a row a slot in the
+  step's layout (``ops.ssm_step``: transposed, two heads side by side,
+  ``(rows, heads / 2, state, 2 head_dim)`` float32) and the last ``taps -
+  1`` pre-convolution rows of ``xBC``. A window runs the chunked scan
+  (:func:`ssm_chunk`), the decode tick ``ops.ssm_step.ssm_decode_step``.
+* feed-forward ``"moe"`` with ``cfg.routed.latent`` / ``form "relu2"``: the
+  experts are ``relu(l W_1)^2 W_2`` on ``l = W_dn x``, the weighted sum goes
+  back through ``W_up`` (``parallel.moe.moe_topk_held``); ``"none"``: the
+  layer is ``x += mixer(norm(x))`` alone.
 """
 
 from __future__ import annotations
@@ -90,7 +113,8 @@ from .transformer import (TransformerConfig, _embed, _rms, _rope_tables,
 
 __all__ = ["check_config", "dims", "init_hybrid", "init_hybrid_cache",
            "init_hybrid_pool", "lightning_rates", "lightning_chunk",
-           "sparse_select", "kda_chunk", "head", "window_contiguous",
+           "sparse_select", "kda_chunk", "ssm_chunk", "head",
+           "window_contiguous",
            "window_paged", "tick_with_window", "SLOT_KEYS"]
 
 HI = jax.lax.Precision.HIGHEST
@@ -102,7 +126,7 @@ _NEG = -1e30
 #: beside its pages.
 SLOT_KEYS = ("state", "ck", "conv")
 #: the mixer kinds ``cfg.mixers`` may name
-MIXERS = ("lightning", "sparse", "kda", "mla", "conv", "gqa")
+MIXERS = ("lightning", "sparse", "kda", "mla", "conv", "gqa", "ssm")
 #: tokens a step of the chunked delta rule (:func:`kda_chunk`)
 KDA_CHUNK = 64
 #: keys of K/V a masked window folds at a time (a 32k context in one piece
@@ -128,8 +152,10 @@ def check_config(cfg: TransformerConfig) -> None:
                          "feed-forward from cfg.ffn / cfg.routed (not "
                          "moe_experts) and does not take use_flash")
     if cfg.ffn:
-        if len(cfg.ffn) != cfg.layers or set(cfg.ffn) - {"dense", "moe"}:
-            raise ValueError(f"ffn {cfg.ffn}: one of dense | moe a layer")
+        if (len(cfg.ffn) != cfg.layers
+                or set(cfg.ffn) - {"dense", "moe", "none"}):
+            raise ValueError(f"ffn {cfg.ffn}: one of dense | moe | none a "
+                             "layer")
         if "moe" in cfg.ffn:
             r = cfg.routed
             if r is None or not r.d_expert:
@@ -144,12 +170,23 @@ def check_config(cfg: TransformerConfig) -> None:
             if r.held < 8:
                 raise ValueError(f"{r.held} experts held: a share of a "
                                  "routed layer is at least 8")
+            if r.form not in ("swiglu", "relu2") or r.latent < 0:
+                raise ValueError(f"experts of form {r.form!r} in a latent "
+                                 f"of {r.latent}: swiglu | relu2, >= 0")
             if any(r.swiglu_limits):
                 raise ValueError(
                     f"swiglu limits {r.swiglu_limits}: a held layer names a "
                     "clamp, whose form is not built (only limit 0)")
     if "kda" in cfg.mixers and cfg.kda is None:
         raise ValueError("kda layers need cfg.kda")
+    if "ssm" in cfg.mixers:
+        m = cfg.ssm
+        if m is None or m.taps < 2:
+            raise ValueError("ssm layers need cfg.ssm (taps >= 2)")
+        if m.heads % (2 * m.groups):
+            raise ValueError(f"ssm: {m.heads} heads in {m.groups} groups "
+                             "(the state holds heads in pairs inside a "
+                             "group)")
     if "conv" in cfg.mixers and (cfg.conv is None or cfg.conv.taps < 2):
         raise ValueError("conv layers need cfg.conv (taps >= 2: the cache "
                          "is the taps - 1 rows before a token)")
@@ -161,9 +198,10 @@ def check_config(cfg: TransformerConfig) -> None:
     H, Hkv, hd = dims(cfg)
     if H % Hkv or hd % 2:
         raise ValueError(f"heads {H} / kv_heads {Hkv} / head_dim {hd}")
-    if "gqa" in cfg.mixers and H // Hkv not in (1, 2, 4, 8):
+    if "gqa" in cfg.mixers and H // Hkv not in (1, 2, 4, 8, 16):
         raise ValueError(f"gqa layers: {H} heads over {Hkv} KV heads (the "
-                         "decode kernel folds 1, 2, 4 or 8 queries a KV head)")
+                         "decode kernel folds 1, 2, 4, 8 or 16 queries a KV "
+                         "head)")
     if "sparse" in cfg.mixers:
         sp = cfg.sparse
         if sp is None:
@@ -222,24 +260,50 @@ def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
     def moe_layer():
         r = cfg.routed
         F = r.d_expert
+        L = r.latent or D           # the width the experts read and write
+        wide = F if r.form == "relu2" else 2 * F
+        s = np.sqrt(2.0 / (L + F))
         p = {"router": dense(D, r.experts),
              "bias": rng.normal(0, 0.01, r.experts).astype(np.float32),
              "experts": {
-                 "gate_up": rng.normal(0, np.sqrt(2.0 / (D + F)),
-                                       (r.held, D, 2 * F)).astype(np.float32),
-                 "down": rng.normal(0, np.sqrt(2.0 / (D + F)),
-                                    (r.held, F, D)).astype(np.float32)}}
+                 "up" if r.form == "relu2" else "gate_up":
+                     rng.normal(0, s, (r.held, L, wide)).astype(np.float32),
+                 "down": rng.normal(0, s, (r.held, F, L)).astype(np.float32)}}
+        if r.latent:
+            p["to_latent"] = dense(D, L)
+            p["from_latent"] = dense(L, D)
         if r.d_shared:
-            p["shared"] = {"gate": dense(D, r.d_shared),
-                           "up": dense(D, r.d_shared),
-                           "down": dense(r.d_shared, D)}
+            p["shared"] = dict(
+                {"gate": dense(D, r.d_shared)} if r.form != "relu2" else {},
+                up=dense(D, r.d_shared), down=dense(r.d_shared, D))
         return p
+
+    def ssm_layer():
+        m = cfg.ssm
+        inner, bc = m.heads * m.head_dim, 2 * m.groups * m.state
+        # the family's initialisation: A in [1, 16], the step log-uniform
+        # in [1e-3, 1e-1] (stored as its inverse softplus), D = 1
+        step = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), m.heads))
+        return {"in": dense(D, 2 * inner + bc + m.heads),
+                "conv": {"w": rng.normal(0, m.taps ** -0.5,
+                                         (m.taps, inner + bc)).astype(
+                                             np.float32),
+                         "b": rng.normal(0, 0.1, inner + bc).astype(
+                             np.float32)},
+                "dt_bias": (step + np.log(-np.expm1(-step))).astype(
+                    np.float32),
+                "a_log": np.log(rng.uniform(1, 16, m.heads)).astype(
+                    np.float32),
+                "d": np.ones(m.heads, np.float32),
+                "o_norm": ones(inner), "o": dense(inner, D)}
 
     layers = []
     for i, kind in enumerate(cfg.mixers):
-        lp = {"ln1": ones(D), "ln2": ones(D)}
+        lp = {"ln1": ones(D)}
         if kind == "kda":
             lp.update(kda_layer())
+        elif kind == "ssm":
+            lp.update(ssm_layer())
         elif kind == "mla":
             lp.update(mla_layer())
         elif kind == "conv":
@@ -249,8 +313,9 @@ def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
                            np.float32)})
         elif kind == "gqa":
             lp.update({"q": dense(D, H * hd), "k": dense(D, Hkv * hd),
-                       "v": dense(D, Hkv * hd), "o": dense(H * hd, D),
-                       "q_norm": ones(hd), "k_norm": ones(hd)})
+                       "v": dense(D, Hkv * hd), "o": dense(H * hd, D)})
+            if cfg.qk_positions:
+                lp.update({"q_norm": ones(hd), "k_norm": ones(hd)})
         else:
             kv = H if kind == "lightning" else Hkv
             lp.update({"q": dense(D, H * hd), "k": dense(D, kv * hd),
@@ -259,9 +324,12 @@ def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
                        "q_norm": ones(hd), "k_norm": ones(hd)})
             if kind == "lightning":
                 lp["o_norm"] = ones(H * hd)
-        if _ffn_kind(cfg, i) == "moe":
+        feed = _ffn_kind(cfg, i)
+        if feed != "none":
+            lp["ln2"] = ones(D)
+        if feed == "moe":
             lp["moe"] = moe_layer()
-        else:
+        elif feed == "dense":
             lp.update({"gate": dense(D, cfg.d_ff), "up": dense(D, cfg.d_ff),
                        "down": dense(cfg.d_ff, D)})
         layers.append(lp)
@@ -288,16 +356,29 @@ def latent_row(cfg) -> int:
 def _conv_shape(cfg, kind: str, rows: int):
     """A layer's convolution tails, a row a slot: a kda layer's last
     ``conv_kernel - 1`` pre-convolution rows of q, k and v side by side, a
-    conv layer's last ``taps - 1`` rows of ``z``."""
+    conv layer's last ``taps - 1`` rows of ``z``, an ssm layer's last ``taps
+    - 1`` pre-convolution rows of ``xBC``."""
     if kind == "conv":
         return (rows, cfg.conv.taps - 1, cfg.d_model)
+    if kind == "ssm":
+        m = cfg.ssm
+        return (rows, m.taps - 1,
+                m.heads * m.head_dim + 2 * m.groups * m.state)
     H, _, hd = dims(cfg)
     return (rows, cfg.kda.conv_kernel - 1, 3 * H * hd)
 
 
+def _ssm_state_shape(cfg, rows: int):
+    """An ssm layer's states, a row a slot, as the step holds them
+    (``ops.ssm_step``): heads in pairs, ``(rows, H / 2, N, 2 P)``."""
+    m = cfg.ssm
+    return (rows, m.heads // 2, m.state, 2 * m.head_dim)
+
+
 def init_hybrid_cache(cfg: TransformerConfig, batch: int, max_len: int):
     """Contiguous per-layer cache: ``{"state"}`` (B, H, hd, hd) float32 for
-    a lightning layer (a kda layer adds ``{"conv"}``, :func:`_conv_shape`);
+    a lightning layer (a kda layer adds ``{"conv"}``, :func:`_conv_shape`;
+    an ssm layer's pair is :func:`_ssm_state_shape` and its tails);
     ``{"k", "v"}`` (B, Hkv, L, hd) and the compressed keys ``{"ck"}``
     (B, Hkv, L / stride, hd) for a sparse one, ``L`` being ``max_len``
     rounded up to whole blocks; ``{"kv"}`` (B, 1, max_len,
@@ -308,8 +389,10 @@ def init_hybrid_cache(cfg: TransformerConfig, batch: int, max_len: int):
     for kind in cfg.mixers:
         if kind == "lightning":
             out.append({"state": jnp.zeros((batch, H, hd, hd), F32)})
-        elif kind == "kda":
-            out.append({"state": jnp.zeros((batch, H, hd, hd), F32),
+        elif kind in ("kda", "ssm"):
+            shape = ((batch, H, hd, hd) if kind == "kda"
+                     else _ssm_state_shape(cfg, batch))
+            out.append({"state": jnp.zeros(shape, F32),
                         "conv": jnp.zeros(_conv_shape(cfg, kind, batch),
                                           cfg.dtype)})
         elif kind == "conv":
@@ -335,7 +418,8 @@ def pool_shapes(cfg: TransformerConfig, num_pages: int, page_size: int,
     """Per layer ``{key: (shape, dtype)}`` of the engine's cache: pages
     (K beside V, as every pool) and a row of compressed keys a slot (for
     ``positions`` positions) for a sparse layer, one state row a slot for a
-    lightning layer; a kda layer adds its convolution tails a slot; an mla
+    lightning layer; a kda layer adds its convolution tails a slot, an ssm
+    layer holds its (not square) state and its tails a slot; an mla
     layer holds latent pages ``(pages, 1, page, latent_row)``: one row a
     token, nothing a head; a conv layer its tails a slot and nothing else; a
     gqa layer pages and nothing a slot. What a layer holds is read from
@@ -346,8 +430,10 @@ def pool_shapes(cfg: TransformerConfig, num_pages: int, page_size: int,
     for kind in cfg.mixers:
         if kind == "lightning":
             out.append({"state": ((slots, H, hd, hd), F32)})
-        elif kind == "kda":
-            out.append({"state": ((slots, H, hd, hd), F32),
+        elif kind in ("kda", "ssm"):
+            shape = ((slots, H, hd, hd) if kind == "kda"
+                     else _ssm_state_shape(cfg, slots))
+            out.append({"state": (shape, F32),
                         "conv": (_conv_shape(cfg, kind, slots), cfg.dtype)})
         elif kind == "conv":
             out.append({"conv": (_conv_shape(cfg, kind, slots), cfg.dtype)})
@@ -746,6 +832,20 @@ def _selected_decode(q, kv, bt, pos, n_valid, idx, sel_ok, cfg, page):
 
 # ---- kda --------------------------------------------------------------------
 
+def _next_tail(ext, n_valid, keep: int, tick: bool):
+    """The ``keep`` rows before a row's next token, of ``ext`` (B, keep + W,
+    C) = the tails before the window beside the window's rows: the rows that
+    end at lane ``n_valid - 1`` (padding lanes never enter a tail). A
+    ``tick`` (one token a row) shifts every row by its one lane or leaves it:
+    a slice a row there is a gather, a sequential loop on the chip (PERF.md,
+    PR 35)."""
+    if tick:
+        return jnp.where((n_valid > 0)[:, None, None], ext[:, 1:],
+                         ext[:, :keep])
+    return jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
+        e, n, keep, axis=0))(ext, n_valid)
+
+
 def _l2norm(t, eps=1e-6):
     return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + eps)
 
@@ -766,14 +866,7 @@ def _kda_inputs(lp, x, tail, n_valid, cfg):
                            axis=-1).astype(F32)             # (K, 3C)
     mixed = jax.nn.silu(sum(ext[:, j:j + W].astype(F32) * taps[j]
                             for j in range(K)))
-    if W == 1:
-        # the tick: every row shifts by its one lane or stays (a slice a
-        # row here is a gather, a sequential loop on the chip)
-        new_tail = jnp.where((n_valid > 0)[:, None, None], ext[:, 1:],
-                             ext[:, :K - 1])
-    else:
-        new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
-            e, n, K - 1, axis=0))(ext, n_valid)
+    new_tail = _next_tail(ext, n_valid, K - 1, W == 1)
     q, k, v = (_heads(t, H, hd) for t in jnp.split(mixed, 3, axis=-1))
     q = _l2norm(q) * hd ** -0.5
     k = _l2norm(k)
@@ -896,6 +989,121 @@ def _kda_layer(lp, x, c, pos, n_valid, cfg, kernel):
     else:
         o, state = kda_chunk(q, k, v, g, beta, state)
     return (_head_gated_out(lp, x, o, cfg, norm=True),
+            {"state": state, "conv": tail})
+
+
+# ---- ssm --------------------------------------------------------------------
+
+def _ssm_inputs(lp, x, tail, n_valid, cfg):
+    """What the state-space recurrence reads of a window ``x`` (B, W, D)
+    continuing the convolution tails ``tail`` (B, taps - 1, C): ``z`` (B, W,
+    H P) the gate, ``u`` (B, H, W, P), ``b``, ``c`` (B, G, W, N) and the
+    step ``d`` (B, H, W), all float32, and the tails after lane ``n_valid -
+    1``. A padding lane's step is 0: it neither decays nor feeds a state."""
+    m = cfg.ssm
+    dt = cfg.dtype
+    K, H, P, G, N = m.taps, m.heads, m.head_dim, m.groups, m.state
+    inner = H * P
+    B, W, _ = x.shape
+    zxd = _proj(x, lp["in"], dt)
+    z, pre, step = (zxd[..., :inner], zxd[..., inner:zxd.shape[-1] - H],
+                    zxd[..., zxd.shape[-1] - H:])
+    ext = jnp.concatenate([tail.astype(dt), pre], axis=1)   # (B, K-1+W, C)
+    taps = lp["conv"]["w"].astype(F32)
+    mixed = jax.nn.silu(sum(ext[:, j:j + W].astype(F32) * taps[j]
+                            for j in range(K)) + lp["conv"]["b"].astype(F32))
+    new_tail = _next_tail(ext, n_valid, K - 1, W == 1)
+    u = _heads(mixed[..., :inner], H, P)
+    b = _heads(mixed[..., inner:inner + G * N], G, N)
+    c = _heads(mixed[..., inner + G * N:], G, N)
+    d = jax.nn.softplus(step.astype(F32) + lp["dt_bias"].astype(F32))
+    live = jnp.arange(W)[None] < n_valid[:, None]               # (B, W)
+    d = jnp.where(live[..., None], d, 0.0).transpose(0, 2, 1)   # (B, H, W)
+    return z.astype(F32), u, b, c, d, new_tail
+
+
+def ssm_chunk(u, b, c, d, a_rate, state, chunk: int):
+    """The chunked scan over one window: ``u`` (B, H, W, P), ``b``, ``c``
+    (B, G, W, N), ``d`` (B, H, W) the steps (0 on a padding lane), ``a_rate``
+    (H,) the heads' ``A < 0``, all float32, ``state`` (B, H, P, N) the state
+    before the window. Returns ``(y (B, H, W, P) = S_t C_t, the state after
+    the window)``.
+
+    ``chunk`` tokens a step (the state-space duality's form): with ``L`` the
+    log-decays ``d A`` cumulated inside the chunk, token ``t`` reads ``sum_(s
+    <= t) exp(L_t - L_s) d_s (C_t . B_s) u_s`` of its own chunk and ``exp(L_t)
+    S C_t`` of the state before it; the chunk closes on ``exp(L_end) S +
+    sum_s exp(L_end - L_s) d_s u_s B_s^T``. Every decay is the exponential
+    of a DIFFERENCE of cumulated log-decays that is at most 0, never a ratio
+    of two powers."""
+    B, H, W, P = u.shape
+    G = b.shape[1]
+    C = min(chunk, W)
+    short = -W % C
+    if short:
+        pad = ((0, 0), (0, 0), (0, short))
+        u, b, c = (jnp.pad(t, pad + ((0, 0),)) for t in (u, b, c))
+        d = jnp.pad(d, pad)
+    n = (W + short) // C
+
+    def chunks(t):          # (B, X, n*C, ..) -> (n, B, X, C, ..)
+        return jnp.moveaxis(t.reshape(*t.shape[:2], n, C, *t.shape[3:]), 2, 0)
+
+    lower = jnp.tril(jnp.ones((C, C), bool))
+
+    def step(S, xs):
+        uc, bc, cc, dc = xs
+        L = jnp.cumsum(dc * a_rate[None, :, None], axis=2)      # (B, H, C)
+        decay = jnp.exp(jnp.where(lower, L[..., :, None] - L[..., None, :],
+                                  -jnp.inf))                    # (B,H,C,C)
+        cb = jnp.einsum("bgtn,bgsn->bgts", cc, bc, precision=HI)
+        w = jnp.repeat(cb, H // G, axis=1) * decay * dc[:, :, None, :]
+        ch = jnp.repeat(cc, H // G, axis=1)                     # (B,H,C,N)
+        y = (jnp.einsum("bhts,bhsp->bhtp", w, uc, precision=HI)
+             + jnp.exp(L)[..., None] * jnp.einsum(
+                 "bhpn,bhtn->bhtp", S, ch, precision=HI))
+        left = jnp.exp(L[..., -1:] - L) * dc                    # (B, H, C)
+        S = (jnp.exp(L[..., -1])[..., None, None] * S
+             + jnp.einsum("bhsp,bhsn->bhpn", uc * left[..., None],
+                          jnp.repeat(bc, H // G, axis=1), precision=HI))
+        return S, y
+
+    state, y = jax.lax.scan(step, state,
+                            tuple(chunks(t) for t in (u, b, c, d)))
+    y = jnp.moveaxis(y, 0, 2).reshape(B, H, n * C, P)
+    return y[:, :, :W], state
+
+
+def _ssm_layer(lp, x, c, pos, n_valid, cfg, kernel):
+    """An ssm layer over its rows of the cache ``c`` (``state``, ``conv``;
+    the caller has sliced a prefill window's slot out): the decode tick
+    (``kernel``: one token a row, none at position 0) runs the Pallas step,
+    a window the chunked scan from a state and tails zeroed at position 0.
+    Out: the gate BEFORE the norm, the norm a group of channels, ``W_o``."""
+    from ...ops.ssm_step import pack_state, ssm_decode_step, unpack_state
+    m = cfg.ssm
+    state, tail = c["state"], c["conv"]
+    if not kernel:
+        state, tail = _fresh(state, pos, n_valid), _fresh(tail, pos, n_valid)
+    z, u, b, cc, d, tail = _ssm_inputs(lp, x, tail, n_valid, cfg)
+    a_rate = -jnp.exp(lp["a_log"].astype(F32))
+    if kernel:
+        d1 = d[:, :, 0]
+        y, state = ssm_decode_step(u[:, :, 0] * d1[..., None],
+                                   jnp.exp(d1 * a_rate), b[:, :, 0],
+                                   cc[:, :, 0], state, n_valid > 0)
+        y = y[:, :, None]
+    else:
+        y, st = ssm_chunk(u, b, cc, d, a_rate, unpack_state(state), m.chunk)
+        state = pack_state(st)
+    y = y + lp["d"].astype(F32)[None, :, None, None] * u
+    B, H, W, P = y.shape
+    y = y.transpose(0, 2, 1, 3).reshape(B, W, H * P) * jax.nn.silu(z)
+    g = y.reshape(B, W, m.groups, -1)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    y = g.reshape(B, W, H * P) * lp["o_norm"]["scale"].astype(F32)
+    return (_proj(y.astype(cfg.dtype), lp["o"], cfg.dtype),
             {"state": state, "conv": tail})
 
 
@@ -1077,22 +1285,20 @@ def _conv_layer(lp, x, tail, pos, n_valid, cfg, tick):
     ext = jnp.concatenate([tail.astype(dt), b * u], axis=1)  # (B, K-1+W, D)
     taps = lp["taps"].astype(F32)
     mixed = sum(ext[:, j:j + W].astype(F32) * taps[j] for j in range(K))
-    if tick:
-        # every row shifts by its one lane or stays: a slice a row would be
-        # a gather, a sequential loop on the chip (PERF.md, PR 35)
-        new_tail = jnp.where((n_valid > 0)[:, None, None], ext[:, 1:],
-                             ext[:, :K - 1])
-    else:
-        new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
-            e, n, K - 1, axis=0))(ext, n_valid)
+    new_tail = _next_tail(ext, n_valid, K - 1, tick)
     return _proj((c.astype(F32) * mixed).astype(dt), lp["o"], dt), new_tail
 
 
 def _gqa_qkv(lp, x, wpos, cfg):
     """``(q (B, H, W, hd), k, v (B, Hkv, W, hd))`` in the compute dtype: q
-    and k RMS-normed a head, then rotated."""
+    and k RMS-normed a head, then rotated; as projected where the model's
+    attention takes no positions (``cfg.qk_positions`` False)."""
     H, Hkv, hd = dims(cfg)
     dt = cfg.dtype
+    if not cfg.qk_positions:
+        return (_heads(_proj(x, lp["q"], dt), H, hd),
+                _heads(_proj(x, lp["k"], dt), Hkv, hd),
+                _heads(_proj(x, lp["v"], dt), Hkv, hd))
     q = _head_rms(_heads(_proj(x, lp["q"], dt), H, hd), lp["q_norm"],
                   cfg.norm_eps)
     k = _head_rms(_heads(_proj(x, lp["k"], dt), Hkv, hd), lp["k_norm"],
@@ -1188,12 +1394,13 @@ def _window(params, tokens, pos, cfg, n_valid, mixer, last_only, stats=None):
     for i, (kind, lp) in enumerate(zip(cfg.mixers, params["layers"])):
         x = _rms(h.astype(F32), lp["ln1"], cfg.norm_eps).astype(dt)
         h = h + rs * mixer(i, kind, lp, x, wpos).astype(dt)
-        if _ffn_kind(cfg, i) == "moe":
+        feed = _ffn_kind(cfg, i)
+        if feed == "moe":
             y, c = _routed(lp, _rms(h.astype(F32), lp["ln2"], cfg.norm_eps),
                            cfg, n_valid)
             counts.append(c)
             h = h + rs * y
-        else:
+        elif feed == "dense":
             x = _rms(h.astype(F32), lp["ln2"], cfg.norm_eps).astype(dt)
             h = h + rs * _swiglu(lp, x, dt)
     if stats is not None and counts:
@@ -1240,6 +1447,8 @@ def window_contiguous(params: Dict, tokens, pos, cache, cfg, *,
             return _gated_out(lp, x, o, cfg, norm=True)
         if kind == "kda":
             y, new_cache[i] = _kda_layer(lp, x, c, pos, n_valid, cfg, False)
+        elif kind == "ssm":
+            y, new_cache[i] = _ssm_layer(lp, x, c, pos, n_valid, cfg, False)
         elif kind == "mla":
             y, new_cache[i] = _mla_contiguous(lp, x, wpos, n_valid, c, cfg)
         elif kind == "conv":
@@ -1281,12 +1490,14 @@ def _paged_mixer(cfg, bufs, new_bufs, block_tables, pos, n_valid, page_size,
             y, new_bufs[i] = _gqa_paged(lp, x, wpos, pos, n_valid, c,
                                         block_tables, cfg, page_size, kernel)
             return y
-        if kind in ("kda", "conv"):
+        if kind in ("kda", "conv", "ssm"):
             rows = c if slot is None else {
                 kk: jax.lax.dynamic_slice_in_dim(c[kk], slot, 1, axis=0)
                 for kk in c}
             if kind == "kda":
                 y, new = _kda_layer(lp, x, rows, pos, n_valid, cfg, kernel)
+            elif kind == "ssm":
+                y, new = _ssm_layer(lp, x, rows, pos, n_valid, cfg, kernel)
             else:
                 y, tail = _conv_layer(lp, x, rows["conv"], pos, n_valid, cfg,
                                       tick)

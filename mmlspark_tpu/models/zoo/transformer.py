@@ -26,7 +26,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["TransformerConfig", "SparseAttention", "RoutedExperts",
-           "LatentAttention", "DeltaRule", "ShortConv", "init_transformer",
+           "LatentAttention", "DeltaRule", "ShortConv", "StateSpace",
+           "init_transformer",
            "transformer_apply",
            "train_step", "param_shardings", "BERT_BASE", "BERT_MINI",
            "DECODER_MINI", "generate", "generate_cached",
@@ -64,7 +65,12 @@ class RoutedExperts(NamedTuple):
     always-on expert of that width, computed on every share alike.
     ``swiglu_limits`` are the published clamps of the layers held (expert
     and shared, a layer after a layer): a non-zero one is refused, its form
-    is not built."""
+    is not built. ``latent`` > 0 puts the experts in a latent of that width
+    all experts of a layer share (``l = W_dn x``; the weighted sum of the
+    experts' outputs goes back through ``W_up``; the router and the shared
+    expert read the model's row); ``form`` is an expert's body, ``"swiglu"``
+    (``(silu(x W_g) * x W_u) W_d``) or ``"relu2"`` (``relu(x W_1)^2 W_2``,
+    no gate; the shared expert takes the same form)."""
     experts: int = 8
     first: int = 0
     count: int = 0
@@ -75,6 +81,8 @@ class RoutedExperts(NamedTuple):
     d_expert: int = 0
     d_shared: int = 0
     swiglu_limits: tuple = ()
+    latent: int = 0
+    form: str = "swiglu"
 
     @property
     def held(self) -> int:
@@ -111,6 +119,22 @@ class ShortConv(NamedTuple):
     channels (the published ``conv_L_cache``); a sequence caches the
     ``taps - 1`` rows before its next token."""
     taps: int = 3
+
+
+class StateSpace(NamedTuple):
+    """The ``ssm`` mixer's sizes (a Mamba-2 selective state-space layer):
+    ``heads`` heads of ``head_dim`` channels on a state ``state`` wide a
+    channel (``S`` is ``head_dim x state`` a head, not square), ``groups``
+    groups of heads sharing one ``B`` and one ``C`` a token, a causal
+    depthwise convolution of ``taps`` taps over ``heads * head_dim + 2 *
+    groups * state`` channels, and ``chunk`` tokens a step of the chunked
+    scan a window runs."""
+    heads: int = 8
+    head_dim: int = 64
+    state: int = 128
+    groups: int = 1
+    taps: int = 4
+    chunk: int = 128
 
 
 class TransformerConfig(NamedTuple):
@@ -167,6 +191,13 @@ class TransformerConfig(NamedTuple):
     #: convolution's tail, a row a slot) and ``"gqa"`` (full grouped-query
     #: softmax attention with per-head QK RMSNorm and RoPE over plain pages)
     conv: Optional[ShortConv] = None
+    #: a seventh kind, ``"ssm"`` (a selective state-space layer, sizes in
+    #: ``ssm``: a float32 state and the convolution's tail, a row a slot).
+    #: ``ffn`` may also name ``"none"``: the layer is its mixer alone (no
+    #: second norm, no feed-forward). ``qk_positions`` False makes a gqa
+    #: layer plain: no per-head q/k norm and no rotation
+    ssm: Optional[StateSpace] = None
+    qk_positions: bool = True
     #: the epsilon of a hybrid decoder's RMSNorms (the block's, the final
     #: one, a gqa layer's per-head ones)
     norm_eps: float = 1e-6

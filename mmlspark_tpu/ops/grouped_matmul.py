@@ -12,6 +12,11 @@ vector, so a step DMAs one expert's ``(D, 2F)`` gate-and-up block and its
 expert no row reached is never read. A tile computes ``(silu(x W_g) * x W_u)
 W_d`` with float32 accumulation; rows past the bound are not written and
 hold whatever the buffer held: the caller masks them.
+
+``gated=False`` is the non-gated body ``relu(x W_1)^2 W_2`` (experts that
+live in a latent: ``x`` is the latent's rows, ``gate_up`` the ``(E, L, F)``
+up projections alone, ``down`` ``(E, F, L)``): the same grid, the same
+launch under the same jitted name.
 """
 
 from __future__ import annotations
@@ -39,21 +44,30 @@ def _experts_kernel(expert_ref, x_ref, gu_ref, dn_ref, o_ref):
                          preferred_element_type=F32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _moe_experts_call(tile_expert, total, x, gate_up, down, *, interpret):
+def _relu2_kernel(expert_ref, x_ref, up_ref, dn_ref, o_ref):
+    x = x_ref[...]                                       # (TILE, L)
+    h = jnp.maximum(jnp.dot(x, up_ref[0], preferred_element_type=F32), 0.0)
+    o_ref[...] = jnp.dot((h * h).astype(x.dtype), dn_ref[0],
+                         preferred_element_type=F32)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "gated"))
+def _moe_experts_call(tile_expert, total, x, gate_up, down, *, interpret,
+                      gated=True):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     R, D = x.shape
-    _, _, F2 = gate_up.shape
     call = pl.pallas_call(
-        _experts_kernel,
+        _experts_kernel if gated else _relu2_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(total,),
             in_specs=[
                 pl.BlockSpec((TILE, D), lambda s, e: (s, 0)),
-                pl.BlockSpec((1, D, F2), lambda s, e: (e[s], 0, 0)),
-                pl.BlockSpec((1, F2 // 2, D), lambda s, e: (e[s], 0, 0))],
+                pl.BlockSpec((1, D, gate_up.shape[2]),
+                             lambda s, e: (e[s], 0, 0)),
+                pl.BlockSpec((1, down.shape[1], D),
+                             lambda s, e: (e[s], 0, 0))],
             out_specs=pl.BlockSpec((TILE, D), lambda s, e: (s, 0))),
         out_shape=jax.ShapeDtypeStruct((R, D), F32),
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -63,14 +77,16 @@ def _moe_experts_call(tile_expert, total, x, gate_up, down, *, interpret):
     return call(tile_expert, x, gate_up, down)
 
 
-def grouped_swiglu(x, tile_expert, total, gate_up, down, interpret=None):
+def grouped_swiglu(x, tile_expert, total, gate_up, down, interpret=None,
+                   gated=True):
     """``x`` (R, D) rows in tiles of :data:`TILE`, tile ``s`` of them for
     expert ``tile_expert[s]`` (int32, whole up to ``total``, the traced
     number of tiles in use); ``gate_up`` (E, D, 2F) an expert's gate beside
     its up projection, ``down`` (E, F, D). Returns float32 (R, D); rows of
-    tiles past ``total`` are NOT written."""
+    tiles past ``total`` are NOT written. ``gated=False``: ``gate_up`` is
+    the up projection alone, (E, D, F), and the body ``relu(.)^2``."""
     if interpret is None:
         interpret = _pa._auto_interpret()
-    return _moe_experts_call(tile_expert.astype(jnp.int32),
-                             jnp.asarray(total, jnp.int32), x, gate_up, down,
-                             interpret=bool(interpret))
+    return _moe_experts_call(
+        tile_expert.astype(jnp.int32), jnp.asarray(total, jnp.int32), x,
+        gate_up, down, interpret=bool(interpret), gated=bool(gated))

@@ -327,13 +327,13 @@ def _fold_heads(m_scr, l_scr, acc_scr, q, kv, n, scale, share=1):
     after head are one head's ``_HEADS * K`` keys under a block-diagonal
     mask, the packed row unsplit (module docstring: exact zeros, as long as
     every lane read is finite). The groups in order are ONE batched product
-    and the last, overlapping group one more: a product a group in a loop
-    runs group after group on the chip (PERF.md, PR 37).
+    and the last, overlapping group one more (PERF.md, PR 37).
 
-    Grouped queries: ``share`` query heads (a power of two up to ``_HEADS``)
-    read KV head ``h // share``, so a group's ``_HEADS`` state rows span
-    ``_HEADS // share`` KV heads and ``share`` neighbouring rows own the
-    same block of the mask."""
+    Grouped queries: ``share`` query heads (a power of two) read KV head ``h
+    // share``: a group's ``_HEADS`` state rows span ``_HEADS // share`` KV
+    heads, ``share`` neighbouring rows own one block of the mask."""
+    if share > _HEADS:      # whole groups a KV head: one product a KV head
+        return _fold_wide(m_scr, l_scr, acc_scr, q, kv, n, scale, share)
     K, width = kv.shape[1:]
     span = _HEADS // share          # KV heads a group of state rows reads
     parts, g = [], 0
@@ -1119,22 +1119,22 @@ def paged_attention_gqa(q, k_new, v_new, kv_pages, block_tables, pos, *,
     ``q`` (B, H, hd), one query a head a row at position ``pos[b]``;
     ``k_new`` / ``v_new`` (B, Hkv, hd) that token's fresh row; ``kv_pages``
     (N, Hkv, page, 2*hd) the packed pool. Query head ``h`` reads KV head
-    ``h // (H // Hkv)``: the ``H // Hkv`` (a power of two up to 8) query
-    heads of a KV head fold that head's page block, eight state rows a
-    group under the block-diagonal mask of :func:`_fold_heads`. The ragged
-    sweep, the in-launch scatter and the contract on ``active`` and on page
-    ownership are :func:`paged_attention_window`'s at ``W == 1``; at ``H ==
-    Hkv`` the context is that call's, bit for bit. Returns ``(ctx (B, H,
-    hd), kv_pages)``, the pool updated in place (aliased)."""
+    ``h // (H // Hkv)``: the ``H // Hkv`` (a power of two up to 16) query
+    heads of a KV head fold that head's page block, eight state rows a group
+    under :func:`_fold_heads`' block-diagonal mask (16: two whole groups on
+    one head, :func:`_fold_wide`). Sweep, scatter, ``active`` and ownership:
+    :func:`paged_attention_window`'s at ``W == 1``; at ``H == Hkv`` the
+    context is that call's, bit for bit. Returns ``(ctx (B, H, hd),
+    kv_pages)``, the pool updated in place (aliased)."""
     if interpret is None:
         interpret = _auto_interpret()
     B, H, hd = q.shape
     Hkv, page = kv_pages.shape[1:3]
     share = H // max(Hkv, 1)
-    if share * Hkv != H or _HEADS % share or k_new.shape[1] != Hkv:
+    if share * Hkv != H or 2 * _HEADS % share or k_new.shape[1] != Hkv:
         raise ValueError(
-            f"{H} query heads over {Hkv} KV heads: a KV head serves 1, 2, 4 "
-            f"or {_HEADS} query heads")
+            f"{H} query heads over {Hkv} KV heads: a KV head serves 1, 2, 4, "
+            f"{_HEADS} or {2 * _HEADS} query heads")
     if scale is None:
         scale = float(1.0 / math.sqrt(hd))
     pos = pos.astype(jnp.int32)
@@ -1353,3 +1353,26 @@ def _latent_launch(q, kv_pages, block_tables, lengths, *, v_width, scale,
     )
     return call(row_of, blk_of, last_of, block_tables, lengths, *holds, q,
                 *[kv_pages] * k)
+
+
+# ---- a KV head that serves two groups of query heads ---------------------------
+
+def _fold_wide(m_scr, l_scr, acc_scr, q, kv, n, scale, share):
+    """:func:`_fold_heads` where a KV head serves ``share`` = a whole number
+    of groups of query heads (16: two groups): the ``share`` state rows of
+    KV head ``j`` are the ``share // _HEADS`` consecutive groups ``j *
+    share // _HEADS ..``, so the query operand and the weights regroup, in
+    float32 whose register holds a group's rows (free reshapes), to ``share``
+    rows a KV head and meet that head's ``K`` keys in ONE product: no mask
+    between heads, the first ``n`` keys alone."""
+    Hkv, K, width = kv.shape
+    G = q.shape[0]
+
+    def by_kv_head(t):      # (G, _HEADS, .) -> (Hkv, share, .)
+        return t.astype(jnp.float32).reshape(Hkv, share, t.shape[-1])
+
+    s = _scores(by_kv_head(q).astype(q.dtype), kv, scale).reshape(
+        G, _HEADS, K)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, K), 2)
+    _fold(m_scr, l_scr, acc_scr, s, col < jnp.clip(n, 0, K),
+          lambda p: _weigh(by_kv_head(p), kv).reshape(G, _HEADS, width))

@@ -292,7 +292,15 @@ def moe_topk_held(x, x32, p, spec, valid, interpret=None):
     :func:`~mmlspark_tpu.ops.grouped_matmul.grouped_swiglu`, which reads
     only the experts that have a tile, and weighted back onto their tokens.
     Rows are moved by products with 0/1 matrices (a gather of thousands of
-    small rows is a sequential loop on the chip)."""
+    small rows is a sequential loop on the chip).
+
+    With ``spec.latent`` the experts live in a latent all of them share:
+    ``p["to_latent"].w`` (D, L) is applied BEFORE the layout (the rows laid
+    out are ``L`` wide), ``p["from_latent"].w`` (L, D) AFTER the weighted
+    sum (linear, so the shares' results still add); the router and the
+    shared expert read the ``D``-wide row. ``spec.form == "relu2"``:
+    ``experts.up`` (held, L, F) in ``gate_up``'s place, an expert
+    ``relu(l W_1)^2 W_2``, the shared expert ``shared.{up,down}`` alike."""
     from ..ops.grouped_matmul import TILE, grouped_swiglu
     f32 = jnp.float32
     T, D = x.shape
@@ -322,21 +330,30 @@ def moe_topk_held(x, x32, p, spec, valid, interpret=None):
     total = ends[-1]
     rows = jnp.arange(R, dtype=jnp.int32)
     place = row[None] == rows[:, None, None]                    # (R, T, k)
-    xs = jnp.dot(place.any(axis=2).astype(x.dtype), x)          # (R, D)
-    ys = grouped_swiglu(xs, tile_expert, total, p["experts"]["gate_up"],
-                        p["experts"]["down"], interpret=interpret)
+    gated = spec.form != "relu2"
+    xl = x @ p["to_latent"]["w"].astype(x.dtype) if spec.latent else x
+    xs = jnp.dot(place.any(axis=2).astype(x.dtype), xl)         # (R, D | L)
+    ys = grouped_swiglu(xs, tile_expert, total,
+                        p["experts"]["gate_up" if gated else "up"],
+                        p["experts"]["down"], interpret=interpret,
+                        gated=gated)
     # a tile past the bound was not written: whatever the buffer held
     ys = jnp.where((rows < total * TILE)[:, None], ys, 0.0)
     back = jnp.where(place, weight[None], 0.0).sum(axis=2)      # (R, T)
     y = jnp.einsum("rt,rd->td", back, ys,
                    precision=jax.lax.Precision.HIGHEST)
+    if spec.latent:
+        y = jnp.matmul(y.astype(x.dtype),
+                       p["from_latent"]["w"].astype(x.dtype),
+                       preferred_element_type=f32)
     if "shared" in p:
         sh = p["shared"]
         dt = x.dtype
-        y = y + jnp.matmul(
-            jax.nn.silu(x @ sh["gate"]["w"].astype(dt))
-            * (x @ sh["up"]["w"].astype(dt)), sh["down"]["w"].astype(dt),
-            preferred_element_type=f32)
+        hid = (jax.nn.silu(x @ sh["gate"]["w"].astype(dt))
+               * (x @ sh["up"]["w"].astype(dt)) if gated
+               else jnp.square(jax.nn.relu(x @ sh["up"]["w"].astype(dt))))
+        y = y + jnp.matmul(hid, sh["down"]["w"].astype(dt),
+                           preferred_element_type=f32)
     n_held = held.sum(dtype=jnp.int32)
     placed = row < R
     reads = (jnp.minimum(row, R - 1)[:, :, None] // TILE

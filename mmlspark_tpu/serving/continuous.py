@@ -728,15 +728,17 @@ class ContinuousDecoder:
                      "a draft model: the verify window would have to roll "
                      "rejected tokens back out of a linear-attention state "
                      "(lightning or kda) and out of a kda or conv layer's "
-                     "convolution tails"),
+                     "convolution tails, or out of an ssm layer's state and "
+                     "tails"),
                     (resolve_kv_dtype(kv_dtype) is not None,
                      "kv_dtype: the sparse layers' compressed keys, the "
                      "selected-block kernel, an mla layer's latent pages "
-                     "and a gqa layer's grouped-query kernel are bf16 only"),
+                     "and a gqa layer's grouped-query kernel are bf16 only "
+                     "(an ssm layer's state is float32, its tails bf16)"),
                     (mesh is not None,
                      "a mesh: the state rows, a conv layer's tails, the "
-                     "decode kernels (gqa's among them) and a routed "
-                     "feed-forward's exchange have no mount")):
+                     "decode kernels (gqa's and the ssm step among them) "
+                     "and a routed feed-forward's exchange have no mount")):
                 if given:
                     raise ValueError(f"a hybrid decoder does not take {why}")
         #: speculative mode: a draft model proposes gamma greedy tokens per
@@ -1305,7 +1307,8 @@ class ContinuousDecoder:
             if export_kv and self._hybrid:
                 raise ValueError(
                     "a hybrid decoder's session does not export its KV "
-                    "(the linear-attention state is not in the blob yet): "
+                    "(a lightning, kda or ssm layer's state and a kda, conv "
+                    "or ssm layer's tails are not in the blob yet): "
                     "checkpoint with export_kv=False and restore cold")
             if export_kv and not req.done and not self._spec:
                 slot = next((i for i in range(self._S)
@@ -2172,15 +2175,17 @@ class ContinuousDecoder:
                 "sparse" if context > sp.dense_len else "dense", calls=calls)
 
     def _note_mixer_ticks(self, calls: int) -> None:
-        """A model with kda, mla, gqa or conv layers counts each decode call
-        once more for each, by the path its tick ran: ``kda`` / ``latent`` /
-        ``gqa`` (the Pallas step, the absorbed kernel, the grouped-query
-        kernel) or ``kda_window`` / ``latent_window`` / ``gqa_window`` (the
-        chunked form, the expanded attention and the gathered pages, under
-        ``gather``); ``conv`` has the one path."""
+        """A model with kda, mla, gqa, ssm or conv layers counts each decode
+        call once more for each, by the path its tick ran: ``kda`` /
+        ``latent`` / ``gqa`` / ``ssm`` (the Pallas step, the absorbed kernel,
+        the grouped-query kernel, the state-space step) or ``kda_window`` /
+        ``latent_window`` / ``gqa_window`` / ``ssm_window`` (the chunked
+        form, the expanded attention, the gathered pages and the chunked
+        scan, under ``gather``); ``conv`` has the one path."""
         off = "" if self._attn_impl == "kernel" else "_window"
         for mixer, label in (("kda", "kda" + off), ("mla", "latent" + off),
-                             ("gqa", "gqa" + off), ("conv", "conv")):
+                             ("gqa", "gqa" + off), ("conv", "conv"),
+                             ("ssm", "ssm" + off)):
             if mixer in self._cfg.mixers:
                 self._kv.note_attn_tick(label, calls=calls)
 
@@ -2346,6 +2351,8 @@ class ContinuousDecoder:
                              self._gamma + 1 if self._spec else 1, self._S,
                              self._k)
             self._note_latent_sweep(positions, self._S, self._k)
+            if self._attn_impl == "kernel":
+                self._kv.note_ssm_step(len(decode_live), self._k)
             # snapshot slot→REQUEST (not indices): by the time this block
             # is drained, a slot may have been freed and re-admitted;
             # tokens must go to the request that occupied the slot at

@@ -110,6 +110,10 @@ M_LATENT_SWEEP_STEPS = _metric_gauge(
     "Grid steps those calls swept (a step folds a block of a row's pages; a "
     "row with nothing to read takes one), since the pool was built: pages "
     "over steps is how full the blocks ran")
+M_SSM_STATE_ROWS = _metric_gauge(
+    "mmlspark_kvpool_ssm_state_rows",
+    "States the decode ticks' state-space step had to read and write (live "
+    "rows of a call, every ssm layer's), since the pool was built")
 M_PREFIX_TOKENS_SHARED = _metric_gauge(
     "mmlspark_kvpool_prefix_tokens_shared",
     "Tokens of stored prefix pages admitted requests took by reference "
@@ -261,6 +265,9 @@ class PagedKVPool:
         self.latent_block = latent_block(
             int(np.prod(mla[0][0][1:])) * jnp.dtype(mla[0][1]).itemsize,
             self.pages_per_slot(slot_positions)) if mla else 0
+        #: the state-space layers (a call of the step each a tick)
+        self.ssm_calls = sum(
+            kind == "ssm" for kind in getattr(cfg, "mixers", ()) or ())
         #: one K or V page as a session blob carries it, (H, page, hd);
         #: the pool's buffer packs the two side by side on the minor axis
         self._page_shape = (heads, self.page_size, hd)
@@ -312,7 +319,7 @@ class PagedKVPool:
                       "latent_window_keys": 0, "latent_window_context": 0,
                       "latent_window_pairs": 0,
                       "latent_sweep_pages": 0, "latent_sweep_steps": 0,
-                      "alloc_failures": 0,
+                      "ssm_state_rows": 0, "alloc_failures": 0,
                       "gather_bytes": 0, "attn_ticks_kernel": 0,
                       "attn_ticks_gather": 0, "grid_steps": 0,
                       "grid_steps_dense": 0, "quant_error_probes": 0,
@@ -755,6 +762,14 @@ class PagedKVPool:
             + sum(max(1, -(-p // self.latent_block)) for p in pages))
         M_LATENT_SWEEP_PAGES.set(self.stats["latent_sweep_pages"])
         M_LATENT_SWEEP_STEPS.set(self.stats["latent_sweep_steps"])
+
+    def note_ssm_step(self, rows: int, calls: int = 1) -> None:
+        """Account ``calls`` decode calls of a model with ssm layers (else
+        nothing): each of the ``rows`` live rows' states is read and written
+        once an ssm layer a call."""
+        if self.ssm_calls:
+            self.stats["ssm_state_rows"] += self.ssm_calls * rows * calls
+            M_SSM_STATE_ROWS.set(self.stats["ssm_state_rows"])
 
     def note_attn_tick(self, impl: str, *, calls: int = 1,
                        gather_bytes: int = 0) -> None:

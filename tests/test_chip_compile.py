@@ -799,6 +799,157 @@ def test_latent_programs_compile_and_hold_a_tile_not_the_slot(glm_programs,
     assert not copies, copies
 
 
+# the state-space cell (nemotronsuper_chat_closed32): Mamba-2 of 128 heads of
+# 64 on a state 128 wide in 8 groups, 32 query heads over 2 KV heads of 128,
+# 128 of 512 relu^2 experts of 2688 in a 1,024 latent at 22 a token, 32 slots
+# of 4,096 positions in pages of 256, a 256-token window
+NEMOTRON = dict(slots=32, page=256, max_len=4096, chunk=256, heads=32,
+                kv_heads=2, hd=128, ssm_heads=128, ssm_hd=64, state=128,
+                groups=8, held=128, latent=1024, width=2688)
+
+
+def test_ssm_step_kernel_compiles_in_place(one_chip):
+    """The state-space decode step on 32 slots x 128 heads of a (64 x 128)
+    float32 state held in pairs, the donated state aliased in and out: no
+    copy of it, and the step's blocks fit its VMEM limit."""
+    from mmlspark_tpu.ops.ssm_step import ssm_decode_step
+    z = NEMOTRON
+    state = (z["slots"], z["ssm_heads"] // 2, z["state"], 2 * z["ssm_hd"])
+    shared = one_chip((z["slots"], z["groups"], z["state"]), jnp.float32)
+    text = jax.jit(
+        functools.partial(ssm_decode_step, interpret=False),
+        donate_argnums=(4,)).lower(
+            one_chip((z["slots"], z["ssm_heads"], z["ssm_hd"]), jnp.float32),
+            one_chip((z["slots"], z["ssm_heads"]), jnp.float32), shared,
+            shared, one_chip(state, jnp.float32),
+            one_chip((z["slots"],), bool)).compile().as_text()
+    assert "_ssm_step_call" in text and "tpu_custom_call" in text
+    shape = f"f32[{','.join(map(str, state))}]"
+    assert not [ln for ln in text.splitlines()
+                if f"= {shape}" in ln and " copy(" in ln]
+
+
+def test_grouped_query_decode_kernel_compiles_at_sixteen_a_head(one_chip):
+    """The gqa layer's tick at 32 query heads over 2 KV heads: two whole
+    groups of state rows fold one KV head's page block, the pool aliased."""
+    from mmlspark_tpu.ops.paged_attention import paged_attention_gqa
+    z = NEMOTRON
+    per = z["max_len"] // z["page"]
+    pool = (1 + z["slots"] * per, z["kv_heads"], z["page"], 2 * z["hd"])
+    text = jax.jit(functools.partial(paged_attention_gqa, interpret=False),
+                   donate_argnums=(3,)).lower(
+        one_chip((z["slots"], z["heads"], z["hd"]), jnp.bfloat16),
+        one_chip((z["slots"], z["kv_heads"], z["hd"]), jnp.bfloat16),
+        one_chip((z["slots"], z["kv_heads"], z["hd"]), jnp.bfloat16),
+        one_chip(pool, jnp.bfloat16), one_chip((z["slots"], per), jnp.int32),
+        one_chip((z["slots"],), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "_pa_gqa_call" in text
+    shape = f"bf16[{','.join(map(str, pool))}]"
+    assert not [ln for ln in text.splitlines()
+                if f"= {shape}" in ln and " copy(" in ln]
+
+
+@pytest.mark.parametrize("tokens", [32, 32 + 256],
+                         ids=["tick", "carrying_tick"])
+def test_latent_expert_product_compiles(one_chip, tokens):
+    """The non-gated grouped product over 128 held experts of (1024 x 2688)
+    at 22 pairs a token: 172 tiles a plain tick can fill, 524 a tick that
+    carries a 256-token window; the blocks fit the kernel's VMEM limit."""
+    from mmlspark_tpu.ops.grouped_matmul import TILE, grouped_swiglu
+    from mmlspark_tpu.parallel.moe import held_tiles
+    z = NEMOTRON
+    tiles = held_tiles(tokens * 22, z["held"], TILE)
+    assert tiles == {32: 172, 288: 524}[tokens]
+    text = _compiled_text(
+        functools.partial(grouped_swiglu, interpret=False, gated=False),
+        one_chip((tiles * TILE, z["latent"]), jnp.bfloat16),
+        one_chip((tiles,), jnp.int32), one_chip((), jnp.int32),
+        one_chip((z["held"], z["latent"], z["width"]), jnp.bfloat16),
+        one_chip((z["held"], z["width"], z["latent"]), jnp.bfloat16))
+    assert "_moe_experts_call" in text
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(one_chip):
+    """The state-space engine's tick and the tick that carries a 256-token
+    window, lowered and compiled at the cell's shapes and at the cell's
+    DEPTH: all eleven published layers (six block layers)."""
+    import json
+
+    from benchmarks import run as bench_run
+    from mmlspark_tpu.ops import paged_attention as pa
+    from mmlspark_tpu.serving import continuous as progs
+    from mmlspark_tpu.serving.kv_pool import PagedKVPool
+    z = NEMOTRON
+    with open(os.path.join(bench_run.HERE, "configs",
+                           "nemotron3_super_ep4_l11.json")) as fh:
+        config = json.load(fh)
+    cfg = bench_run.load_by_path("drivers", "generate_nemotron"
+                                 ).program_config(config, z["max_len"])
+    reference = bench_run.load_by_path("references", config["reference"])
+    params = jax.tree.map(
+        lambda a: one_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: reference.make_weights(config, 0)))
+    per = z["max_len"] // z["page"]
+    pool = PagedKVPool(cfg, page_size=z["page"], residency=False,
+                       make_buffer=one_chip, slots=z["slots"],
+                       slot_positions=z["max_len"],
+                       num_pages=1 + z["slots"] * per + z["slots"])
+    ints = lambda *dims: one_chip(dims, jnp.int32)          # noqa: E731
+    interpret = pa._auto_interpret
+    pa._auto_interpret = progs._pa_auto_interpret = lambda: False
+    try:
+        tick = progs._tick_program(
+            cfg, z["page"], z["max_len"], 1, None, False, True).lower(
+                params, ints(z["slots"]), ints(z["slots"]),
+                one_chip((z["slots"],), bool), pool.buffers,
+                ints(z["slots"], per), ints(z["slots"]))
+        riding = _riding_tick(progs, cfg, z, params, pool, ints, one_chip,
+                              z["chunk"])
+        yield {"tick": tick.compile(), "riding": riding.compile()}, pool
+    finally:
+        pa._auto_interpret = progs._pa_auto_interpret = interpret
+        progs._tick_program.cache_clear()
+
+
+@pytest.mark.parametrize("program", ["tick", "riding"])
+def test_state_space_programs_compile_and_keep_the_pool_in_place(
+        nemotron_programs, program):
+    """The eleven-layer tick and the tick that carries a 256-token window at
+    the published widths (32 slots, 4,096 positions): they compile for the
+    chip and hold ONE state-space step an ``M`` layer, ONE grouped product an
+    ``E`` layer with no copy of its experts, ONE grouped-query call; the
+    plain tick holds no sequential loop (a slice a row of the tails would be
+    one); neither copies a layer's states (134 MB) or the page pool; and the
+    carrying tick's temporaries at 22 pairs a token stay a small part of the
+    chip (125 MB where the weights are 9.3 GB)."""
+    compiled, pool = nemotron_programs
+    text = compiled[program].as_text()
+    lines = text.splitlines()
+    for name, n in (("_ssm_step_call", 5), ("_moe_experts_call", 5),
+                    ("_pa_gqa_call", 1)):
+        assert sum("tpu_custom_call" in ln and name in ln
+                   for ln in lines) == n, name
+    if program == "tick":
+        assert " while(" not in text
+    z = NEMOTRON
+    shapes = [f"bf16[{z['held']},{z['latent']},{z['width']}]",
+              f"bf16[{z['held']},{z['width']},{z['latent']}]"]
+    names = {"bfloat16": "bf16", "float32": "f32"}
+    for layer in pool.buffers:
+        shapes += [
+            f"{names[buf.dtype.name]}[{','.join(map(str, buf.shape))}]"
+            for key, buf in layer.items() if key != "conv"]
+    assert "f32[32,64,128,128]" in shapes
+    copies = [ln.strip()[:120] for ln in lines
+              if " copy(" in ln and any(f"= {sh}" in ln for sh in shapes)]
+    assert not copies, copies
+    memory = compiled[program].memory_analysis()
+    assert memory.temp_size_in_bytes < 256 << 20
+    # the states and the pages are donated and updated in place
+    assert memory.alias_size_in_bytes > 5 * 32 * 64 * 128 * 128 * 4
+
+
 @pytest.mark.parametrize("stats", [None, "bfloat16"])
 def test_level_histogram_kernel_compiles(one_chip, stats):
     rb = tree_row_block(NODES, BINS)

@@ -70,7 +70,7 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     phases = {ln["phase"]: ln for ln in lines if "phase" in ln}
     assert sorted(phases) == ["A.transform", "B.decode", "C.train",
                               "F.hybrid", "G.routed", "H.conv_gqa",
-                              "I.latent"]
+                              "I.latent", "J.state_space"]
     for ln in phases.values():
         assert ln["ok"] is True and ln["failed"] == []
         assert ln["small"] is True and ln["platform"] == "cpu"
@@ -97,6 +97,14 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     assert latent["prefix_tokens_shared"] == 2 * latent["context"]
     assert latent["moe"]["pairs_held"] == latent["moe"]["pairs_routed"] > 0
     assert latent["gap_mean"] <= chip_smoke.LATENT_GAP_MEAN
+    # J: every tick on the state-space step and the grouped-query kernel,
+    # the step alone one step of the recurrence
+    ssm = phases["J.state_space"]
+    assert ssm["attn_ticks_ssm"] == ssm["attn_ticks_gqa"] > 0
+    assert ssm["ssm_state_rows"] > 0 == ssm["moe"]["pairs_dropped"]
+    assert 0 < ssm["moe"]["pairs_held"] < ssm["moe"]["pairs_routed"]
+    assert ssm["gap_mean"] <= chip_smoke.SSM_GAP_MEAN
+    assert ssm["step_alone"]["y_err"] < 1e-4
     assert phases["C.train"]["pallas_histogram_traces"] > 0
     assert (phases["C.train"]["pallas_interpreted"]
             == phases["C.train"]["pallas_histogram_traces"])

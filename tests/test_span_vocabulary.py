@@ -352,6 +352,79 @@ def test_snapshot_bytes_are_a_conv_layers_tails_alone(conv_gqa_run):
     assert stats["state_snapshot_bytes_stored"] == 2 * 32 * 4
 
 
+#: what a state-space decoder adds: tick labels ``ssm`` and ``gqa`` (pool
+#: ``stats["attn_ticks_<label>"]``, ``mmlspark_kvpool_kernel_ticks_total
+#: {impl=<label>}``) and the states its decode steps moved (pool
+#: ``stats["ssm_state_rows"]``, the gauge ``mmlspark_kvpool_ssm_state_rows``)
+SSM_TICK_LABELS = ["ssm", "gqa"]
+
+
+@pytest.fixture(scope="module")
+def ssm_run():
+    """A tiny state-space decoder (a plain gqa layer at 16 query heads a KV
+    head under 8 relu^2 experts in a latent, an ssm layer with no
+    feed-forward): two requests one after the other, the first registering a
+    prefix the second restores. What the pool and the registry counted."""
+    from mmlspark_tpu import observability as obs
+    from mmlspark_tpu.models.zoo.transformer import (
+        RoutedExperts, StateSpace, TransformerConfig, init_transformer)
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    cfg = TransformerConfig(
+        vocab=64, layers=2, d_model=32, heads=16, kv_heads=1, d_ff=64,
+        max_len=96, causal=True, norm="rmsnorm", position="rope",
+        dtype=jnp.float32, mixers=("gqa", "ssm"), head_dim=8,
+        ffn=("moe", "none"), norm_eps=1e-5, qk_positions=False,
+        ssm=StateSpace(heads=4, head_dim=8, state=16, groups=2, taps=4,
+                       chunk=8),
+        routed=RoutedExperts(experts=8, per_token=3, scale=5.0, d_expert=24,
+                             d_shared=16, latent=16, form="relu2"))
+    before = obs.snapshot()
+    dec = ContinuousDecoder(init_transformer(cfg, seed=0), cfg, max_slots=2,
+                            max_len=96, page_size=8, prefill_chunk=16)
+    rng = np.random.default_rng(5)
+    doc = rng.integers(1, cfg.vocab, 24).astype(np.int32)
+    for n in (6, 9):                        # a miss, then a hit
+        req = dec.submit(np.concatenate(
+            [doc, rng.integers(1, cfg.vocab, n).astype(np.int32)]), 5,
+            prefix_key="doc", prefix_len=24)
+        while not req.done:
+            dec.step()
+        assert req.error is None
+    return dec._kv.stats, before, obs.snapshot()
+
+
+@pytest.mark.parametrize("label", SSM_TICK_LABELS)
+def test_ssm_and_plain_gqa_ticks_are_labelled(ssm_run, label):
+    stats, before, after = ssm_run
+    assert stats[f"attn_ticks_{label}"] \
+        == stats["attn_ticks_kernel"] - stats["prefill_chunks"] > 0
+    assert f"attn_ticks_{label}_window" not in stats
+
+    def series(snap):
+        return sum(s["value"] for s in snap.get(
+            "mmlspark_kvpool_kernel_ticks_total", {}).get("series", ())
+            if s["labels"].get("impl") == label)
+    assert series(after) - series(before) == stats[f"attn_ticks_{label}"]
+
+
+def test_the_gauge_holds_the_states_the_steps_moved(ssm_run):
+    """One ssm layer, one live row a tick: a state a tick."""
+    stats, _, after = ssm_run
+    assert stats["ssm_state_rows"] == stats["attn_ticks_ssm"] > 0
+    series = after["mmlspark_kvpool_ssm_state_rows"]["series"]
+    assert [s["value"] for s in series] == [stats["ssm_state_rows"]]
+
+
+def test_snapshot_bytes_count_the_state_and_its_tails(ssm_run):
+    """One ssm layer: 4 heads of (8 x 16) float32 and 3 rows of 32 + 2 x 2 x
+    16 float32 values; the gqa layer keeps nothing a slot. Stored once,
+    restored once."""
+    stats, *_ = ssm_run
+    row = (4 * 8 * 16 + 3 * (32 + 64)) * 4
+    assert stats["state_snapshot_bytes_stored"] == row
+    assert stats["state_snapshot_bytes_restored"] == row
+
+
 #: what a decoder whose every layer is latent attention adds: the keys its
 #: prefill windows attended over (pool ``stats["latent_window_keys"]``, the
 #: gauge ``mmlspark_kvpool_latent_window_keys``), the tokens of stored prefix
