@@ -1591,3 +1591,20 @@ def tick_with_window(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
         hidden, S + jnp.maximum(n_chunk[0] - 1, 0), 1, axis=0)
     logits = head(params, jnp.concatenate([hidden[:S], last], axis=0))
     return logits[:S], logits[S:], new_bufs
+
+
+def select_walks(cfg: TransformerConfig, page_size: int, pages_a_slot: int,
+                 positions: int):
+    """The lengths, in pages, of the two lists :func:`_selected_decode` hands
+    the selected-block kernel for each (row, KV head) of a sparse layer's
+    tick: ``(the top-k walk's, the dense walk's)``, the second the longer and
+    taken while a row under ``dense_len`` holds more blocks than the first
+    lists. Here, below every traced function (a Pallas program's cache key
+    holds the line numbers of its callers), for the pool's count of the walk
+    (``PagedKVPool.note_select_walk``)."""
+    sp = cfg.sparse
+    pp = sp.block_size // page_size                 # pages a block
+    scored = -(-positions // sp.kernel_stride)      # compressed keys a slot
+    K = min(sp.topk, -(-scored // (sp.block_size // sp.kernel_stride)))
+    n_dense = min(-(-sp.dense_len // sp.block_size), -(-pages_a_slot // pp))
+    return K * pp, max(K, n_dense) * pp

@@ -850,59 +850,59 @@ def _pa_fused_call_q(q, kv_new, kvq_new, ks_new, vs_new, kv_pages, k_scale,
 # ---- block selection (grouped-query sparse decode) --------------------------
 # One query a row, and for each (row, KV head) a LIST of logical pages to
 # attend: the blocks a sparse layer's scorer chose (models/zoo/hybrid.py)
-# instead of the row's whole block table. Grid (rows, KV heads, listed
-# pages); the page block is ONE head's (1, 1, page, 2*hd) slice of a page,
-# its physical index read through the block table from the scalar-prefetched
-# list; the ``hg`` query heads that share the KV head are the kernel's
-# window, so one page DMA serves them all. An entry below 0 is no page: the
-# step folds nothing and its DMA is the previous step's page again. Read
-# only: the engine writes the token's K/V row before it selects.
+# instead of the row's whole block table. A page block is ONE head's
+# (1, 1, page, 2*hd) slice of a page, its physical index read through the
+# block table from the scalar-prefetched list; the ``hg`` query heads that
+# share the KV head are the kernel's window, so one page DMA serves them all.
+# An entry below 0 is no page: it is not folded, and its DMA is the row's
+# page 0. Read only: the engine writes the token's K/V row before it selects.
+# Grid (rows, KV heads, BLOCKS of ``k`` listed pages), ``k`` page operands on
+# the one pool (PR 46). The launch and the kernel are at the END of the file
+# (:func:`_select_launch`) and this section keeps its length: a compiled
+# Pallas program's cache key holds the line numbers of the calls below.
 
-def _pa_select_kernel(bt_ref, sel_ref, len_ref, q_ref, kv_ref, o_ref,
-                      m_scr, l_scr, acc_scr, *, scale, page, n_sel, G):
-    from jax.experimental import pallas as pl
+#: the most a grid step of the selected-block walk fetches. A head's slice of
+#: a listed page is small (64 x 256 bf16: 32 KB, 0.04 us of the HBM's time
+#: under a step's 0.45), so a walk pays its steps' fixed cost and the serial
+#: chain of their online-softmax updates, not its bytes: eight such pages a
+#: step (PERF.md, PR 46)
+_SELECT_BLOCK_BYTES = 1 << 18
 
-    b, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    pl.when(j == 0)(lambda: _init(m_scr, l_scr, acc_scr))
-    lp = sel_ref[b * G + g, j]
 
-    @pl.when(lp >= 0)
-    def _compute():
-        # the blocks are one head's: H = 1, the window the hg query heads
-        _pages_fold(m_scr, l_scr, acc_scr, q_ref[0], _page_kv(kv_ref),
-                    lp, len_ref[b], scale, page)
-
-    pl.when(j == n_sel - 1)(lambda: _finalize(o_ref, l_scr, acc_scr))
+def select_block(page_bytes: int, n_sel: int) -> int:
+    """Listed pages a grid step of the selected-block walk folds, from what
+    a call can see of its shapes: the largest of 8, 4, 2, 1 that divides the
+    list's ``n_sel`` entries and whose pages, ``page_bytes`` a head's slice
+    of one, :data:`_SELECT_BLOCK_BYTES` hold. The pool counts the walk by
+    the same rule (``PagedKVPool.note_select_walk``)."""
+    return next(k for k in (8, 4, 2, 1) if n_sel % k == 0
+                and (k == 1 or k * page_bytes <= _SELECT_BLOCK_BYTES))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _pa_select_call(q, kv_pages, block_tables, sel, lengths, *,
                     scale, interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    """The selected-block walk of ``q`` (B, G, hg, hd) over the ``sel``
+    (B * G, n_sel) listed pages of each (row, KV head), in BLOCKS: a grid
+    step fetches and folds ``k`` of the list's entries, so a walk pays a
+    step's fixed cost and its serial chain once a block (MiniCPM-SALA's
+    lists of 64 and 128 pages of 64 x 256 bf16: eight a step). ``k`` is no
+    argument: it follows from what the call can see (:func:`select_block`),
+    and a list that 2 does not divide walks page by page, the program it
+    always was. A block whose entries are all pages is ONE online-softmax
+    update; a block with an entry below 0 folds page by page, each page
+    under its own guard: such an entry's operand holds the row's page 0,
+    which for an idle row is the trash page, NaN on the chip, and a masked
+    key's zero weight does not stop one (``0 x NaN``). Top-k lists the
+    entries that are no block last: only a short row's last block.
 
-    B, G, hg, hd = q.shape
-    page, n_sel = kv_pages.shape[2], sel.shape[1]
-    kernel = functools.partial(_pa_select_kernel, scale=scale, page=page,
-                               n_sel=n_sel, G=G)
-    row = pl.BlockSpec((1, 1, hg, hd), lambda b, g, j, *_: (b, g, 0, 0))
-
-    def page_of(b, g, j, bt, sel_, *_):
-        return (bt[b, jnp.maximum(sel_[b * G + g, j], 0)], g, 0, 0)
-
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(B, G, n_sel),
-            in_specs=[row, pl.BlockSpec((1, 1, page, 2 * hd), page_of)],
-            out_specs=row,
-            scratch_shapes=_softmax_state(1, hg, hd)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",) * 3,
-            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        interpret=interpret)
-    return call(block_tables, sel, lengths, q, kv_pages)
+    The name is the trace's: the hybrid cell reads this kernel's seconds
+    under ``jit_tick/_pa_select_call``."""
+    return _select_launch(
+        q, kv_pages, block_tables, sel, lengths, scale=scale,
+        interpret=interpret, k=select_block(
+            math.prod(kv_pages.shape[2:]) * kv_pages.dtype.itemsize,
+            sel.shape[1]))
 
 
 def paged_attention_selected(q, kv_pages, block_tables, sel_pages, lengths,
@@ -1376,3 +1376,100 @@ def _fold_wide(m_scr, l_scr, acc_scr, q, kv, n, scale, share):
     col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, K), 2)
     _fold(m_scr, l_scr, acc_scr, s, col < jnp.clip(n, 0, K),
           lambda p: _weigh(by_kv_head(p), kv).reshape(G, _HEADS, width))
+
+
+# ---- the selected-block kernel: a BLOCK of listed pages a grid step ---------
+# Here, at the end, so that the calls above keep their line numbers
+# (:func:`_pa_select_call`).
+
+def _entry(j, i, k):
+    """The list column of entry ``i`` of block ``j``; at ``k`` = 1 the step
+    itself, with no arithmetic, so that the one-page walk's text stands."""
+    return j if k == 1 else j * k + i
+
+
+def _select_fold(m_scr, l_scr, acc_scr, q, pages, lps, bound, scale, page):
+    """Fold a WHOLE block, the listed pages ``lps`` (scalars, every one a
+    page) as ``pages`` (their ``(1, page, 2*hd)`` blocks), in ONE online
+    softmax update: the pages' rows one after another are one run of
+    ``k * page`` keys, so one product gives the block's scores, one max, exp
+    and sum go over them and one product weighs the values. The mathematics
+    of a fold a page; the order of its float32 sums is another, and the
+    chain a step waits for is paid once a block."""
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, len(lps) * page), 2)
+    # page i's keys are columns i * page ..: key c's position is
+    # lps[i] * page + c - i * page
+    first = lps[0] * page
+    for i, lp in enumerate(lps[1:], 1):
+        first = jnp.where(col >= i * page, (lp - i) * page, first)
+    _fold_keys(m_scr, l_scr, acc_scr, q, jnp.concatenate(pages, axis=1),
+               first + col < bound, scale)
+
+
+def _pa_select_kernel(bt_ref, sel_ref, len_ref, q_ref, *rest, scale, page,
+                      n_blk, G, k):
+    """One grid step = block ``j`` of the list of (row ``b``, KV head ``g``):
+    its entries ``k * j .. k * j + k - 1``, each its own operand on the pool.
+    A block whose entries are all pages is one fold (:func:`_select_fold`);
+    one with an entry below 0 folds page by page, each page under its own
+    guard, which at ``k`` = 1 is all there is (the one-page walk, its
+    program unchanged)."""
+    from jax.experimental import pallas as pl
+
+    pages, o_ref, state = rest[:k], rest[k], rest[k + 1:]
+    b, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    pl.when(j == 0)(lambda: _init(*state))
+    lps = [sel_ref[b * G + g, _entry(j, i, k)] for i in range(k)]
+
+    def page_by_page():
+        for lp, kv_ref in zip(lps, pages):
+            @pl.when(lp >= 0)
+            def _page(lp=lp, kv_ref=kv_ref):
+                # the blocks are one head's: H = 1, the window the hg heads
+                _pages_fold(*state, q_ref[0], _page_kv(kv_ref), lp,
+                            len_ref[b], scale, page)
+
+    if k == 1:
+        page_by_page()
+    else:
+        whole = functools.reduce(jnp.minimum, lps) >= 0
+        pl.when(whole)(lambda: _select_fold(
+            *state, q_ref[0], [_page_kv(ref) for ref in pages], lps,
+            len_ref[b], scale, page))
+        pl.when(jnp.logical_not(whole))(page_by_page)
+    pl.when(j == n_blk - 1)(lambda: _finalize(o_ref, state[1], state[2]))
+
+
+def _select_launch(q, kv_pages, block_tables, sel, lengths, *, scale,
+                   interpret, k):
+    """The launch :func:`_pa_select_call` makes, ``k`` listed pages a grid
+    step (``k`` divides the list)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, G, hg, hd = q.shape
+    page, n_sel = kv_pages.shape[2], sel.shape[1]
+    kernel = functools.partial(_pa_select_kernel, scale=scale, page=page,
+                               n_blk=n_sel // k, G=G, k=k)
+    row = pl.BlockSpec((1, 1, hg, hd), lambda b, g, j, *_: (b, g, 0, 0))
+
+    def page_of(i):
+        def index_map(b, g, j, bt, sel_, *_):
+            lp = sel_[b * G + g, _entry(j, i, k)]
+            return (bt[b, jnp.maximum(lp, 0)], g, 0, 0)
+        return index_map
+
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, G, n_sel // k),
+            in_specs=[row, *(pl.BlockSpec((1, 1, page, 2 * hd), page_of(i))
+                             for i in range(k))],
+            out_specs=row,
+            scratch_shapes=_softmax_state(1, hg, hd)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret)
+    return call(block_tables, sel, lengths, q, *[kv_pages] * k)

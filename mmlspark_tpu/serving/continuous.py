@@ -2155,15 +2155,15 @@ class ContinuousDecoder:
                 self._kv.note_grid_steps([pos + j for pos in positions],
                                          window, rows)
 
-    def _note_latent_sweep(self, positions, rows: int, calls: int) -> None:
-        """The grid steps of ``calls`` successive decode calls of the
-        absorbed latent kernel (pool ``latent_sweep_pages`` / ``_steps``; a
-        model with no mla layer counts nothing): a row at ``pos`` attends
-        its ``pos + 1`` keys, the token's own row among them."""
+    def _note_kernel_walks(self, positions, rows: int, calls: int) -> None:
+        """The grid steps of ``calls`` successive decode calls of the absorbed
+        latent and the selected-block kernel (pool ``latent_sweep_*`` /
+        ``select_walk_*``). Nine lines: a kernel's key holds its callers'."""
         if self._attn_impl == "kernel" and self._hybrid:
-            for j in range(calls):
-                self._kv.note_latent_sweep(
-                    [pos + 1 + j for pos in positions], rows)
+            for j in range(calls):      # a row at pos attends pos + 1 keys
+                at = [pos + j for pos in positions]
+                self._kv.note_latent_sweep([pos + 1 for pos in at], rows)
+                self._kv.note_select_walk(at, rows)
 
     def _note_sparse_ticks(self, context: int, calls: int = 1) -> None:
         """A model with sparse-attention layers counts each paged call a
@@ -2350,7 +2350,7 @@ class ContinuousDecoder:
             self._note_sweep(positions,
                              self._gamma + 1 if self._spec else 1, self._S,
                              self._k)
-            self._note_latent_sweep(positions, self._S, self._k)
+            self._note_kernel_walks(positions, self._S, self._k)
             if self._attn_impl == "kernel":
                 self._kv.note_ssm_step(len(decode_live), self._k)
             # snapshot slot→REQUEST (not indices): by the time this block

@@ -110,6 +110,16 @@ M_LATENT_SWEEP_STEPS = _metric_gauge(
     "Grid steps those calls swept (a step folds a block of a row's pages; a "
     "row with nothing to read takes one), since the pool was built: pages "
     "over steps is how full the blocks ran")
+M_SELECT_WALK_PAGES = _metric_gauge(
+    "mmlspark_kvpool_select_walk_pages",
+    "Pages the decode ticks' selected-block kernel had to fold (the pages "
+    "of the blocks listed for a live row's KV heads, every sparse layer's "
+    "call), since the pool was built")
+M_SELECT_WALK_STEPS = _metric_gauge(
+    "mmlspark_kvpool_select_walk_steps",
+    "Grid steps those calls walked (a step folds a block of a (row, KV "
+    "head)'s listed pages; an idle row walks its empty list), since the pool "
+    "was built: pages over steps is how full the blocks ran")
 M_SSM_STATE_ROWS = _metric_gauge(
     "mmlspark_kvpool_ssm_state_rows",
     "States the decode ticks' state-space step had to read and write (live "
@@ -228,7 +238,7 @@ class PagedKVPool:
         self.hybrid = bool(getattr(cfg, "mixers", ()))
         if self.hybrid:
             from ..models.zoo.hybrid import (SLOT_KEYS, dims, pool_shapes,
-                                             window_tile)
+                                             select_walks, window_tile)
             from ..ops.paged_attention import latent_block
             if kv_dtype is not None or sharding is not None:
                 raise ValueError("a hybrid decoder's pool is bf16 pages "
@@ -265,6 +275,23 @@ class PagedKVPool:
         self.latent_block = latent_block(
             int(np.prod(mla[0][0][1:])) * jnp.dtype(mla[0][1]).itemsize,
             self.pages_per_slot(slot_positions)) if mla else 0
+        #: the selected-block kernel's walk as the scheduler counts it: the
+        #: sparse layers (a call each a tick), the pages of the two lists a
+        #: call walks for a (row, KV head) (the top-k walk's, the dense
+        #: walk's) and the grid steps it walks each in, by the rule the call
+        #: itself reads its shapes with
+        self.select_calls = sum(
+            kind == "sparse" for kind in getattr(cfg, "mixers", ()) or ())
+        self.select_walks = self.select_steps = ()
+        if self.select_calls:
+            from ..ops.paged_attention import select_block
+            head_slice = (self.page_size * 2 * hd
+                          * jnp.dtype(cfg.dtype).itemsize)
+            self.select_walks = select_walks(
+                cfg, self.page_size, self.pages_per_slot(slot_positions),
+                int(slot_positions))
+            self.select_steps = tuple(n // select_block(head_slice, n)
+                                      for n in self.select_walks)
         #: the state-space layers (a call of the step each a tick)
         self.ssm_calls = sum(
             kind == "ssm" for kind in getattr(cfg, "mixers", ()) or ())
@@ -319,6 +346,7 @@ class PagedKVPool:
                       "latent_window_keys": 0, "latent_window_context": 0,
                       "latent_window_pairs": 0,
                       "latent_sweep_pages": 0, "latent_sweep_steps": 0,
+                      "select_walk_pages": 0, "select_walk_steps": 0,
                       "ssm_state_rows": 0, "alloc_failures": 0,
                       "gather_bytes": 0, "attn_ticks_kernel": 0,
                       "attn_ticks_gather": 0, "grid_steps": 0,
@@ -762,6 +790,34 @@ class PagedKVPool:
             + sum(max(1, -(-p // self.latent_block)) for p in pages))
         M_LATENT_SWEEP_PAGES.set(self.stats["latent_sweep_pages"])
         M_LATENT_SWEEP_STEPS.set(self.stats["latent_sweep_steps"])
+
+    def note_select_walk(self, positions: Sequence[int], rows: int) -> None:
+        """Account the selected-block kernel's walk of one decode call of a
+        model with sparse layers (else nothing), from the scheduler's
+        numbers: for each of its KV heads a live row at ``positions[i]``
+        lists the pages of ``min(topk, its blocks so far)`` blocks, or of
+        every block so far while it is under ``dense_len``
+        (``hybrid._selected_decode``); the call walks the lists of all its
+        ``rows``, an idle row's empty one too, a block of entries a grid
+        step, and walks the dense lists while a row under ``dense_len``
+        holds more blocks than the top-k walk lists; every sparse layer's
+        call walks the same. ``select_walk_pages / select_walk_steps`` is
+        how full the blocks ran."""
+        if not self.select_calls:
+            return
+        sp = self.cfg.sparse
+        pp = sp.block_size // self.page_size
+        short = self.select_walks[0]
+        listed = [(pos // sp.block_size + 1) * pp for pos in positions]
+        dense = [pos + 1 <= sp.dense_len for pos in positions]
+        widened = any(d and n > short for d, n in zip(dense, listed))
+        calls = self.select_calls * self._page_shape[0]      # x KV heads
+        self.stats["select_walk_pages"] += calls * sum(
+            n if d else min(n, short) for d, n in zip(dense, listed))
+        self.stats["select_walk_steps"] += (calls * rows
+                                            * self.select_steps[widened])
+        M_SELECT_WALK_PAGES.set(self.stats["select_walk_pages"])
+        M_SELECT_WALK_STEPS.set(self.stats["select_walk_steps"])
 
     def note_ssm_step(self, rows: int, calls: int = 1) -> None:
         """Account ``calls`` decode calls of a model with ssm layers (else

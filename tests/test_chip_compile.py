@@ -281,21 +281,36 @@ SALA = dict(slots=8, max_len=32768, page=64, chunk=256, heads=32, kv_heads=2,
             hd=128, topk=64)
 
 
-def test_selected_block_kernel_compiles(one_chip):
-    """One query a row over 64 listed pages of one KV head each, 16 query
-    heads a KV head: the sparse layers' decode attention."""
+@pytest.mark.parametrize("listed", [64, 128], ids=["top64", "dense128"])
+def test_selected_block_kernel_compiles(one_chip, listed):
+    """One query a row over ``listed`` pages of one KV head each (the top-64
+    blocks, or 128 while a row sits under ``dense_len``), 16 query heads a KV
+    head: the sparse layers' decode attention, a block of EIGHT listed pages
+    a grid step (``select_block``: 8 x 32 KB), eight page operands on the
+    one pool, which is not copied; inside ``_VMEM_LIMIT_BYTES`` (the compiler
+    refuses a kernel over it)."""
     z = SALA
     per = z["max_len"] // z["page"]
-    text = _compiled_text(
-        functools.partial(paged_attention_selected, interpret=False),
-        one_chip((z["slots"], z["kv_heads"], z["heads"] // z["kv_heads"],
-                  z["hd"]), jnp.bfloat16),
-        one_chip((1 + z["slots"] * per, z["kv_heads"], z["page"],
-                  2 * z["hd"]), jnp.bfloat16),
-        one_chip((z["slots"], per), jnp.int32),
-        one_chip((z["slots"], z["kv_heads"], z["topk"]), jnp.int32),
-        one_chip((z["slots"],), jnp.int32))
-    assert "_pa_select_call" in text        # the name the benchmark's trace finds
+    pool = (1 + z["slots"] * per, z["kv_heads"], z["page"], 2 * z["hd"])
+    fn = functools.partial(paged_attention_selected, interpret=False)
+    args = (one_chip((z["slots"], z["kv_heads"], z["heads"] // z["kv_heads"],
+                      z["hd"]), jnp.bfloat16),
+            one_chip(pool, jnp.bfloat16),
+            one_chip((z["slots"], per), jnp.int32),
+            one_chip((z["slots"], z["kv_heads"], listed), jnp.int32),
+            one_chip((z["slots"],), jnp.int32))
+    text = _compiled_text(fn, *args)
+    # the name the benchmark's trace finds
+    (line,) = [ln for ln in text.splitlines()
+               if "tpu_custom_call" in ln and "_pa_select_call" in ln]
+    ops = _call_operands(line)      # table, list, lengths, query, 8 x pool
+    assert (len(ops), max(ops.count(op) for op in set(ops))) == (12, 8)
+    assert not [ln for ln in text.splitlines()
+                if "= bf16[%s]" % ",".join(map(str, pool)) in ln
+                and " copy(" in ln]
+    jaxpr = str(jax.make_jaxpr(fn)(*args))
+    assert f"grid=(8, 2, {listed // 8})" in jaxpr
+    assert "f32[1,16,512]" in jaxpr             # a whole block's scores
 
 
 def test_lightning_step_kernel_compiles_in_place(one_chip):

@@ -436,6 +436,75 @@ def test_pool_counts_pages_states_and_snapshots(cfg):
                     slot_positions=64)
 
 
+# ---- the selected-block walk, as the pool counts it (PR 46) -----------------
+# tiny widths: blocks and pages of 8, topk 6, dense_len 64 (8 blocks), 2 KV
+# heads, 2 sparse layers, a page's slice 8 x 32 float32. (positions of the live
+# rows, rows of the call) -> (pages listed a KV head a layer, entries of the
+# list walked, entries a grid step)
+WALKS = {
+    # every row past dense_len with six blocks and more: whole lists of six
+    # pages, two a step
+    "whole_lists_past_dense_len": (([100, 150, 77], 3), (18, 6, 2)),
+    # a row of three blocks lists three: its second block folds page by page
+    "a_short_row_lists_its_blocks": (([100, 20], 2), (6 + 3, 6, 2)),
+    # two idle rows walk their empty lists
+    "idle_rows_take_their_steps": (([100], 3), (6, 6, 2)),
+    # a row under dense_len with eight blocks, more than the six a top-k
+    # walk lists: the call walks the dense lists, eight entries one step
+    "a_long_dense_row_widens_the_walk": (([63, 100], 2), (8 + 6, 8, 8)),
+    "at_dense_len_the_row_is_sparse": (([64, 100], 2), (6 + 6, 6, 2)),
+}
+
+
+@pytest.mark.parametrize("name", list(WALKS))
+def test_the_pool_counts_the_walk_by_the_calls_own_rule(cfg, name):
+    """``select_walk_pages`` / ``select_walk_steps`` from the rows' positions
+    as the scheduler holds them: the pages the lists hold, and the grid
+    ``(rows, KV heads, entries / select_block)`` of every sparse layer's
+    call. Whole lists read ``k``: pages over steps."""
+    (positions, rows), (pages, n_sel, k) = WALKS[name]
+    pool = PagedKVPool(cfg, num_pages=11, page_size=8, residency=False,
+                       slots=3, slot_positions=256)
+    assert (pool.select_calls, pool.select_walks) == (2, (6, 8))
+    assert pool.select_steps == (3, 1)
+    pool.note_select_walk(positions, rows)
+    stats = pool.stats
+    assert stats["select_walk_pages"] == 2 * 2 * pages
+    assert stats["select_walk_steps"] == 2 * 2 * rows * (n_sel // k)
+    if name == "whole_lists_past_dense_len":
+        assert stats["select_walk_pages"] / stats["select_walk_steps"] == k
+
+
+def test_the_pools_walks_are_the_lists_the_tick_hands_the_kernel(
+        cfg, monkeypatch):
+    """``hybrid.select_walks`` and ``select_block`` against what
+    ``_selected_decode`` traces: both branches of its ``lax.cond``, the
+    top-k walk and the dense walk, reach the launch with the pool's numbers."""
+    import mmlspark_tpu.ops.paged_attention as pa
+    seen = set()
+    inner = pa._select_launch
+
+    def spy(q, kv, bt, sel, lengths, *, k, **kw):
+        seen.add((sel.shape[1], k))
+        return inner(q, kv, bt, sel, lengths, k=k, **kw)
+
+    monkeypatch.setattr(pa, "_select_launch", spy)
+    pa._pa_select_call.clear_cache()
+    B, P, page, K = 3, 32, 8, 6
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    jax.eval_shape(
+        lambda q, kv, bt, pos, n, idx, ok: hybrid._selected_decode(
+            q, kv, bt, pos, n, idx, ok, cfg, page),
+        f32((B, 4, 1, 16)), f32((1 + B * P, 2, page, 32)), i32((B, P)),
+        i32((B,)), i32((B,)), i32((B, 2, 1, K)),
+        jax.ShapeDtypeStruct((B, 2, 1, K), bool))
+    pa._pa_select_call.clear_cache()
+    walks = hybrid.select_walks(cfg, page, P, P * page)
+    assert walks == (6, 8)
+    assert seen == {(n, pa.select_block(page * 32 * 4, n)) for n in walks}
+
+
 def test_compaction_moves_pages_and_leaves_states(decoder, params, cfg):
     """Defragmentation permutes pages and compressed keys together and never
     a state row: decoding goes on token for token."""

@@ -40,10 +40,11 @@ from mmlspark_tpu.ops.kv_quant import SCALE_DTYPE, quantize_kv
 from mmlspark_tpu.ops.paged_attention import (
     ENV_KNOB, _HEADS, _block_holds, _fused_schedule, _heads_of,
     _heads_query, _latent_launch, _pa_window_read_call, _pool_write_rows,
-    _schedule, _scores, _whole_groups, aligned_page_size, latent_block,
-    pack_kv, paged_attention,
+    _schedule, _scores, _select_launch, _whole_groups, aligned_page_size,
+    latent_block, pack_kv, paged_attention,
     paged_attention_latent, paged_attention_selected,
-    paged_attention_window, split_kv, resolve_impl, sublane_multiple)
+    paged_attention_window, select_block, split_kv, resolve_impl,
+    sublane_multiple)
 from mmlspark_tpu.serving.continuous import ContinuousDecoder
 
 CFG = TransformerConfig(vocab=128, layers=2, d_model=64, heads=4, d_ff=128,
@@ -809,6 +810,200 @@ class TestLatentBlocks:
             assert np.abs(got - self._oracle(q, pool, bt, lengths)).max() \
                 < 2e-5
         pa._pa_latent_call.clear_cache()
+
+
+# what a call of the selected-block walk sees and the listed pages a grid step
+# folds by it: (rows of a page, row width, bytes a value, entries a list) -> k
+SELECT_BLOCKS = {
+    # sala_docqa_closed8: a head's slice of a page 32 KB, top-64 blocks
+    "sala_top64": ((64, 256, 2, 64), 8),
+    # the same cell while a row sits under dense_len: 128 blocks listed
+    "sala_dense128": ((64, 256, 2, 128), 8),
+    "a_float32_pool_halves_the_block": ((64, 256, 4, 64), 4),
+    "a_list_that_8_does_not_divide": ((64, 256, 2, 12), 4),
+    "a_list_that_4_does_not_divide": ((8, 32, 4, 6), 2),
+    "an_odd_list_walks_page_by_page": ((8, 32, 4, 5), 1),
+    "a_slice_past_the_block_is_one_a_step": ((256, 1024, 2, 64), 1),
+}
+
+
+@pytest.mark.parametrize("name", list(SELECT_BLOCKS))
+def test_select_block_follows_the_page_and_the_list(name):
+    (rows, width, itemsize, n_sel), want = SELECT_BLOCKS[name]
+    assert select_block(rows * width * itemsize, n_sel) == want
+
+
+def _one_page_select_call(q, kv_pages, block_tables, sel, lengths, scale):
+    """The selected-block kernel as it stood before PR 46, a listed page a
+    grid step: the reference the walk in blocks is held to bit for bit
+    wherever it folds page by page."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    import mmlspark_tpu.ops.paged_attention as pa
+
+    B, G, hg, hd = q.shape
+    page, n_sel = kv_pages.shape[2], sel.shape[1]
+
+    def kernel(bt_ref, sel_ref, len_ref, q_ref, kv_ref, o_ref, m_scr, l_scr,
+               acc_scr):
+        b, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        pl.when(j == 0)(lambda: pa._init(m_scr, l_scr, acc_scr))
+        lp = sel_ref[b * G + g, j]
+
+        @pl.when(lp >= 0)
+        def _compute():
+            pa._pages_fold(m_scr, l_scr, acc_scr, q_ref[0],
+                           pa._page_kv(kv_ref), lp, len_ref[b], scale, page)
+
+        pl.when(j == n_sel - 1)(lambda: pa._finalize(o_ref, l_scr, acc_scr))
+
+    row = pl.BlockSpec((1, 1, hg, hd), lambda b, g, j, *_: (b, g, 0, 0))
+
+    def page_of(b, g, j, bt, sel_, *_):
+        return (bt[b, jnp.maximum(sel_[b * G + g, j], 0)], g, 0, 0)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, G, n_sel),
+            in_specs=[row, pl.BlockSpec((1, 1, page, 2 * hd), page_of)],
+            out_specs=row, scratch_shapes=pa._softmax_state(1, hg, hd)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=True)(block_tables, sel, lengths, q, kv_pages)
+
+
+class TestSelectBlocks:
+    """The selected-block kernel a BLOCK of a (row, KV head)'s listed pages
+    a grid step (PR 46) against the one-page walk it was and a float32
+    oracle. Rows: one past ``dense_len`` with every entry a page (whole
+    blocks alone); one with fewer blocks than the list, its entries that are
+    no page last, as top-k puts them (its last block with a page folds page
+    by page); an idle row that lists nothing and whose table's page 0 is the
+    trash page; one whose every other entry is no page (no block is whole at
+    any ``k``); one that lists a single page. NaN fills the trash page and
+    every page no row lists; the positions past a row's bound hold the
+    largest finite bf16 in the page it lists."""
+
+    PAGE, HD, HG, G = 8, 16, 4, 2
+    SCALE = 0.25
+    LONG, SHORT, IDLE, HOLES, ONE = range(5)
+
+    @staticmethod
+    @functools.lru_cache(maxsize=None)
+    def _case(n_sel, dtype):
+        page, G = TestSelectBlocks.PAGE, TestSelectBlocks.G
+        P = n_sel + 24
+        lengths = np.asarray([P * page - 3, (n_sel - 13) * page - 2, 0,
+                              (n_sel + 8) * page + 1, 5], np.int32)
+        B = len(lengths)
+        rng = np.random.default_rng(n_sel)
+        bt = 1 + rng.permutation(B * P).reshape(B, P).astype(np.int32)
+        bt[TestSelectBlocks.IDLE] = 0
+        pool = rng.normal(0, 1, (1 + B * P, G, page, 2 * TestSelectBlocks.HD))
+        sel = np.full((B, G, n_sel), -1, np.int32)
+        listed = np.zeros(1 + B * P, bool)
+        big = float(jnp.finfo(jnp.bfloat16).max)
+        for b, n in enumerate(lengths):
+            held = -(-int(n) // page)
+            for g in range(G):
+                pick = rng.permutation(held)[:n_sel]
+                if held:                # the page of the row's newest token
+                    pick[0] = held - 1
+                if b == TestSelectBlocks.HOLES:
+                    pick = pick[:n_sel // 2]
+                    sel[b, g, :2 * len(pick):2] = pick
+                else:
+                    sel[b, g, :len(pick)] = pick
+                listed[bt[b, pick]] = True
+            for t in range(int(n), held * page):
+                pool[bt[b, t // page], :, t % page] = big * (-1) ** t
+        pool[~listed] = np.nan
+        q = rng.normal(0, 1, (B, G, TestSelectBlocks.HG, TestSelectBlocks.HD))
+        return (jnp.asarray(q, dtype), jnp.asarray(pool, dtype),
+                jnp.asarray(bt), jnp.asarray(sel.reshape(B * G, n_sel)),
+                jnp.asarray(lengths))
+
+    @staticmethod
+    @functools.lru_cache(maxsize=None)
+    def _one_page(n_sel, dtype):
+        got = _one_page_select_call(*TestSelectBlocks._case(n_sel, dtype),
+                                    TestSelectBlocks.SCALE)
+        return np.asarray(got.astype(jnp.float32))
+
+    def _oracle(self, q, pool, bt, sel, lengths):
+        q, pool = (np.asarray(t.astype(jnp.float32)) for t in (q, pool))
+        bt, lengths = np.asarray(bt), np.asarray(lengths)
+        sel = np.asarray(sel).reshape(q.shape[0], q.shape[1], -1)
+        out = np.zeros_like(q)
+        for b, g in np.ndindex(*q.shape[:2]):
+            rows = [pool[bt[b, lp], g][:max(0, lengths[b] - lp * self.PAGE)]
+                    for lp in sel[b, g] if lp >= 0]
+            if rows:
+                rows = np.concatenate(rows)
+                s = q[b, g] @ rows[:, :self.HD].T * self.SCALE
+                p = np.exp(s - s.max(axis=1, keepdims=True))
+                out[b, g] = (p / p.sum(axis=1, keepdims=True)) @ \
+                    rows[:, self.HD:]
+        return out
+
+    @pytest.mark.parametrize("k", [1, 2, 4, 8])
+    @pytest.mark.parametrize("n_sel,dtype", [
+        (64, jnp.bfloat16), (128, jnp.bfloat16), (64, jnp.float32)],
+        ids=["top64_bf16", "dense128_bf16", "top64_float32"])
+    def test_blocks_against_the_one_page_walk_and_the_oracle(self, k, n_sel,
+                                                             dtype):
+        """``k`` = 1 is the one-page walk's program, and a block with an
+        entry that is no page folds its pages one by one in that walk's
+        order: both return its contexts BIT FOR BIT. A whole block is one
+        fold, the same mathematics under another order of float32 sums: held
+        to the float32 oracle at the tolerance the one-page walk is held to
+        (``TestOperandRule.TOL`` over bf16 pages, the kernels' 2e-5 over
+        float32 ones). The idle row over its NaN page yields zeros."""
+        case = self._case(n_sel, dtype)
+        call = jax.jit(functools.partial(
+            _select_launch, scale=self.SCALE, interpret=True, k=k))
+        got = np.asarray(call(*case).astype(jnp.float32))
+        one = self._one_page(n_sel, dtype)
+        assert np.isfinite(got).all() and np.isfinite(one).all()
+        by_page = [self.IDLE, self.HOLES, self.ONE]
+        assert np.array_equal(got[by_page], one[by_page])
+        if k == 1:
+            assert np.array_equal(got, one)
+        assert not got[self.IDLE].any()
+        tol = TestOperandRule.TOL if dtype == jnp.bfloat16 else 2e-5
+        want = self._oracle(*case)
+        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+        np.testing.assert_allclose(one, want, rtol=tol, atol=tol)
+
+    def test_the_call_picks_its_block_from_its_shapes(self, monkeypatch):
+        """``paged_attention_selected`` through ``_pa_select_call``: lists of
+        64 float32 pages of 8 x 32 walk eight a step, of 6 two, of 5 one; no
+        argument says so."""
+        import mmlspark_tpu.ops.paged_attention as pa
+        seen = []
+        inner = pa._select_launch
+
+        def spy(*args, k, **kw):
+            seen.append(k)
+            return inner(*args, k=k, **kw)
+
+        monkeypatch.setattr(pa, "_select_launch", spy)
+        q, pool, bt, sel, lengths = self._case(64, jnp.float32)
+        B = q.shape[0]
+        want = self._oracle(q, pool, bt, sel, lengths)
+        for n, k in ((64, 8), (6, 2), (5, 1)):
+            pa._pa_select_call.clear_cache()
+            lists = sel.reshape(B, self.G, -1)[..., :n]
+            got = np.asarray(paged_attention_selected(
+                q, pool, bt, lists, lengths, scale=self.SCALE,
+                interpret=True))
+            assert seen[-1] == k
+            if n == 64:
+                assert np.abs(got - want).max() < 2e-5
+            else:
+                assert np.abs(got - self._oracle(
+                    q, pool, bt, lists, lengths)).max() < 2e-5
+        pa._pa_select_call.clear_cache()
 
 
 class TestDecodeParity:

@@ -196,6 +196,19 @@ def test_attn_ticks_are_labelled_by_path(hybrid_run, label):
     assert series(after) - series(before) == stats[f"attn_ticks_{label}"]
 
 
+@pytest.mark.parametrize("gauge,stat", [
+    ("mmlspark_kvpool_select_walk_pages", "select_walk_pages"),
+    ("mmlspark_kvpool_select_walk_steps", "select_walk_steps")])
+def test_select_walk_gauges_hold_the_pools_counts(hybrid_run, gauge, stat):
+    """The selected-block kernel's walk, counted a tick from the rows'
+    positions: one sparse layer with one KV head, lists of ``topk`` = 4 pages
+    walked four a grid step (``select_block``), so a tick's two rows take a
+    step each and its one live row, past ``dense_len``, lists four pages."""
+    _, stats, _, after = hybrid_run
+    assert stats["select_walk_pages"] == 2 * stats["select_walk_steps"] > 0
+    assert [s["value"] for s in after[gauge]["series"]] == [stats[stat]]
+
+
 #: what a routed decoder's tick counts of itself (pool ``stats["moe_.."]`` and
 #: ``mmlspark_kvpool_moe_total{count=..}``), and the labels its two mixers'
 #: kernels add to ``mmlspark_kvpool_kernel_ticks_total``
