@@ -458,6 +458,76 @@ def phase_routed(sz, seed, small):
                     attn_ticks_latent=stats.get("attn_ticks_latent", 0))
 
 
+EXPERTS_ALONE_TOL = 1e-2
+
+
+def experts_product_alone(ck, seed, small):
+    """The grouped expert product ALONE at the shapes of
+    ``lfm2_ragchat_closed32`` (64 experts of (2048, 3072) + (1536, 2048)
+    bf16; toy shapes under ``--small``), on two layouts: a plain tick's (one
+    tile an expert, a product a tile) and a carrying step's (runs of 1, 2, 3,
+    ``RUN`` and ``RUN + 1`` tiles, a product a run), each with experts no row
+    reached (their weights NaN) and tiles past the bound. Rows under the
+    bound against a float32 product of the same bf16 operands; on the chip,
+    microseconds an expert read, eight calls (the cell's routed layers) a
+    program, beside the 23 us of an expert's bytes at the chip's peak."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops.grouped_matmul import RUN, TILE, grouped_swiglu
+
+    E, D, F = (12, 128, 64) if small else (64, 2048, 1536)
+    layers = 8
+    interpret = jax.devices()[0].platform != "tpu"
+    k = jax.random.split(jax.random.key(seed), 3)
+    gu = jax.random.normal(k[0], (E, D, 2 * F), jnp.bfloat16) * D ** -0.5
+    dn = jax.random.normal(k[1], (E, F, D), jnp.bfloat16) * F ** -0.5
+    layouts = {"tick": [1] * (E - 4) + [0] * 4,
+               "carrying": ([1, 2, 3, RUN, RUN + 1, 0] * E)[:E]}
+
+    @jax.jit
+    def oracle(x, e, gu, dn):
+        h = jnp.dot(x.astype(jnp.float32), gu[e].astype(jnp.float32),
+                    precision="highest")
+        h = (jax.nn.silu(h[:, :F]) * h[:, F:]).astype(jnp.bfloat16)
+        return jnp.dot(h.astype(jnp.float32), dn[e].astype(jnp.float32),
+                       precision="highest")
+
+    detail = {}
+    for name, tiles in layouts.items():
+        tiles = np.asarray(tiles)
+        idle = jnp.asarray(tiles == 0)[:, None, None]
+        total, spare = int(tiles.sum()), 3
+        experts = jnp.asarray(np.concatenate(
+            [np.repeat(np.arange(E), tiles), np.full(spare, E - 1)]), jnp.int32)
+        xs = jax.random.normal(k[2], (layers, (total + spare) * TILE, D),
+                               jnp.bfloat16)
+        args = (jnp.where(idle, jnp.nan, gu), jnp.where(idle, jnp.nan, dn))
+        prog = jax.jit(lambda xs, gu_, dn_: [grouped_swiglu(
+            x, experts, total, gu_, dn_) for x in xs])
+        got = np.asarray(prog(xs, *args)[0])[:total * TILE]
+        want = np.concatenate([np.asarray(oracle(
+            xs[0][s * TILE:(s + 1) * TILE], e, gu, dn))
+            for s, e in enumerate(np.asarray(experts)[:total])])
+        ck.require(np.isfinite(got).all(),
+                   f"{name}: the product read an expert no row reached")
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        ck.require(err < EXPERTS_ALONE_TOL,
+                   f"{name}: the product alone is {err:.2e} of the rows' "
+                   f"scale from the float32 product")
+        detail[name] = dict(tiles=total, experts=int((tiles > 0).sum()),
+                            steps=int(-(-tiles // RUN).sum()), err=err)
+        if interpret:
+            continue                    # a CPU run gives counts, never speeds
+        laps = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(prog(xs, *args))
+            laps.append(time.perf_counter() - t0)
+        detail[name]["us_an_expert"] = (
+            min(laps) / (layers * detail[name]["experts"]) * 1e6)
+    return detail
+
+
 def phase_conv_gqa(sz, seed, small):
     """Layers 0, 10 and 11 of the conv + grouped-query configuration at its
     published widths (a gated short convolution under the dense
@@ -485,7 +555,8 @@ def phase_conv_gqa(sz, seed, small):
     require_gap_mean(ck, gaps, CONV_GQA_GAP_MEAN)
     return ck, dict(detail,
                     attn_ticks_gqa=stats.get("attn_ticks_gqa", 0),
-                    attn_ticks_conv=stats.get("attn_ticks_conv", 0))
+                    attn_ticks_conv=stats.get("attn_ticks_conv", 0),
+                    product_alone=experts_product_alone(ck, seed, small))
 
 
 # ---------------------------------------------------------------------------

@@ -240,7 +240,8 @@ def moe_ffn_gspmd(t, params, n_experts: int, capacity: int,
 
 #: what :func:`moe_topk_held` counts, in the order of its ``stats`` vector
 MOE_STATS = ("pairs_routed", "pairs_held", "pairs_dropped",
-             "pairs_misplaced", "experts_touched", "expert_load_max")
+             "pairs_misplaced", "experts_touched", "tiles", "product_steps",
+             "expert_load_max")
 
 
 def route_topk(x32, router_w, bias, spec):
@@ -279,12 +280,14 @@ def moe_topk_held(x, x32, p, spec, valid, interpret=None):
     route nowhere and read no expert). ``p``: ``router.w`` (D, experts),
     ``bias`` (experts,), ``experts.gate_up`` (held, D, 2F), ``experts.down``
     (held, F, D), and ``shared.{gate,up,down}`` when the layer has a shared
-    expert. Returns ``(y (T, D) in x's dtype, stats int32[6])``, the stats
+    expert. Returns ``(y (T, D) in x's dtype, stats int32[8])``, the stats
     in :data:`MOE_STATS`' order: the live tokens' pairs over all experts,
     those on held experts, held pairs given no row (0: no capacity), pairs
     whose row lies in a tile of ANOTHER expert's weights (0: the layout's
-    own check), distinct held experts with a pair, the largest expert's
-    pairs.
+    own check), distinct held experts with a pair, the tiles in use, the
+    grid steps the product ran (an expert's run of tiles is one step, up to
+    ``ops.grouped_matmul.RUN`` of them: tiles over steps is how many tiles a
+    product folds), the largest expert's pairs.
 
     The pairs on held experts are ranked within their expert by a running
     count (no sort is needed for that), laid out expert after expert in
@@ -301,7 +304,8 @@ def moe_topk_held(x, x32, p, spec, valid, interpret=None):
     shared expert read the ``D``-wide row. ``spec.form == "relu2"``:
     ``experts.up`` (held, L, F) in ``gate_up``'s place, an expert
     ``relu(l W_1)^2 W_2``, the shared expert ``shared.{up,down}`` alike."""
-    from ..ops.grouped_matmul import TILE, grouped_swiglu
+    from ..ops.grouped_matmul import (TILE, grouped_swiglu,
+                                      product_steps)
     f32 = jnp.float32
     T, D = x.shape
     k, Eh = spec.per_token, spec.held
@@ -362,5 +366,6 @@ def moe_topk_held(x, x32, p, spec, valid, interpret=None):
         valid.sum(dtype=jnp.int32) * k, n_held,
         n_held - placed.sum(dtype=jnp.int32),
         (placed & (reads != local)).sum(dtype=jnp.int32),
-        (counts > 0).sum(dtype=jnp.int32), counts.max()])
+        (counts > 0).sum(dtype=jnp.int32), total, product_steps(tiles),
+        counts.max()])
     return y.astype(x.dtype), stats

@@ -162,8 +162,11 @@ M_MOE = _metric_counter(
     "(held pairs given no row: always 0, routing is dropless), "
     "pairs_misplaced (pairs multiplied by another expert's weights: always "
     "0), experts_touched (distinct held experts with a pair, summed over layers "
-    "and ticks), expert_load_max (the largest expert's pairs in a tick, any "
-    "layer, summed over ticks)",
+    "and ticks), tiles (16-row tiles of pairs in use, likewise), product_steps "
+    "(grid steps the experts' product ran, an expert's run of tiles a step: "
+    "tiles over product_steps is how many tiles a product folds), "
+    "expert_load_max (the largest expert's pairs in a tick, any layer, summed "
+    "over ticks)",
     labelnames=("count",))
 M_STATE_SNAPSHOTS = _metric_counter(
     "mmlspark_kvpool_state_snapshots_total",
@@ -842,7 +845,7 @@ class PagedKVPool:
 
     def note_moe(self, counts) -> None:
         """Account the routing counts of drained decode steps: ``counts``
-        (steps, 6) in ``parallel.moe.MOE_STATS``' order, as the tick carried
+        (steps, 8) in ``parallel.moe.MOE_STATS``' order, as the tick carried
         them out beside its tokens (no device read of their own)."""
         from ..parallel.moe import MOE_STATS
         for name, n in zip(MOE_STATS, np.asarray(counts).sum(axis=0)):

@@ -524,20 +524,50 @@ def _call_operands(line):
         "), custom_call_target")[0])
 
 
-@pytest.mark.parametrize("tokens", [32, 256], ids=["tick", "prefill_chunk"])
+def _expert_product_compiles(one_chip, tokens, top, held, d, f, gated=True):
+    """The grouped product's launch for the described chip at the most tiles
+    ``tokens`` rows of ``top`` pairs can fill over ``held`` experts of
+    ``(d, 2f | f)`` and ``(f, d)``: the call keeps its jitted name (the
+    benchmark's ``trace_ops`` read it), an expert's two weight blocks
+    double-buffered, a run's ``RUN`` row tiles, the two slots of its output
+    rows and the wider body's temporaries fit the kernels' VMEM limit, and
+    nothing beside the call copies an expert's weight block. Returns the
+    tiles."""
+    from mmlspark_tpu.ops import paged_attention as pa
+    from mmlspark_tpu.ops.grouped_matmul import RUN
+    from mmlspark_tpu.parallel.moe import held_tiles
+    wide = f * (2 if gated else 1)
+    rows = RUN * TILE
+    block = 2 * (d * wide + f * d)
+    vmem = (2 * block                           # the expert, and the next
+            + 2 * rows * d * 2 + 2 * rows * d * 4   # row tiles in, rows out
+            + rows * (wide * 4 + f * 6 + d * 4))    # x W, the hidden, the rows
+    assert vmem < pa._VMEM_LIMIT_BYTES, vmem
+    tiles = held_tiles(tokens * top, held, TILE)
+    text = _compiled_text(
+        functools.partial(grouped_swiglu, interpret=False, gated=gated),
+        one_chip((tiles * TILE, d), jnp.bfloat16),
+        one_chip((tiles,), jnp.int32), one_chip((), jnp.int32),
+        one_chip((held, d, wide), jnp.bfloat16),
+        one_chip((held, f, d), jnp.bfloat16))
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "_moe_experts_call" in calls[0]
+    shapes = [f"bf16[1,{d},{wide}]", f"bf16[1,{f},{d}]",
+              f"bf16[{held},{d},{wide}]", f"bf16[{held},{f},{d}]"]
+    copies = [ln.strip()[:120] for ln in text.splitlines()
+              if " copy(" in ln and any(f"= {sh}" in ln for sh in shapes)]
+    assert not copies, copies
+    return tiles, block
+
+
+@pytest.mark.parametrize("tokens", [32, 256, 32 + 256],
+                         ids=["tick", "prefill_chunk", "carrying_tick"])
 def test_grouped_expert_product_compiles(one_chip, tokens):
     """The routed experts' product over 128 held experts of width 768 at
-    the most tiles a tick's (a chunk's) pairs can fill."""
-    from mmlspark_tpu.parallel.moe import held_tiles
+    the most tiles a tick's (a chunk's, a carrying tick's) pairs can fill."""
     z = LING
-    tiles = held_tiles(tokens * z["top"], z["held"], TILE)
-    text = _compiled_text(
-        functools.partial(grouped_swiglu, interpret=False),
-        one_chip((tiles * TILE, z["hidden"]), jnp.bfloat16),
-        one_chip((tiles,), jnp.int32), one_chip((), jnp.int32),
-        one_chip((z["held"], z["hidden"], 2 * z["width"]), jnp.bfloat16),
-        one_chip((z["held"], z["width"], z["hidden"]), jnp.bfloat16))
-    assert "_moe_experts_call" in text
+    _expert_product_compiles(one_chip, tokens, z["top"], z["held"],
+                             z["hidden"], z["width"])
 
 
 def _share_programs(one_chip, z, config_file, driver, cut):
@@ -673,24 +703,18 @@ def test_grouped_query_decode_kernel_compiles_in_place(one_chip):
                 if f"= {shape}" in ln and " copy(" in ln]
 
 
-@pytest.mark.parametrize("tokens", [32, 512], ids=["tick", "prefill_chunk"])
+@pytest.mark.parametrize("tokens", [32, 512, 32 + 512],
+                         ids=["tick", "prefill_chunk", "carrying_tick"])
 def test_whole_layer_expert_product_compiles(one_chip, tokens):
     """The routed product with every expert held: a grid step is one 18.9 MB
     expert ((2048, 3072) + (1536, 2048) bf16), 37.7 MB double-buffered,
-    inside the 64 MiB the kernels are given."""
-    from mmlspark_tpu.ops import paged_attention as pa
-    from mmlspark_tpu.parallel.moe import held_tiles
+    beside a run's 128 rows in and out, inside the 64 MiB the kernels are
+    given; a carrying tick's 544 rows x 4 fill up to 200 tiles."""
     z = LFM2
-    block = 2 * 3 * z["hidden"] * z["width"]
-    assert block == 18_874_368 and 2 * block < pa._VMEM_LIMIT_BYTES
-    tiles = held_tiles(tokens * z["top"], z["held"], TILE)
-    text = _compiled_text(
-        functools.partial(grouped_swiglu, interpret=False),
-        one_chip((tiles * TILE, z["hidden"]), jnp.bfloat16),
-        one_chip((tiles,), jnp.int32), one_chip((), jnp.int32),
-        one_chip((z["held"], z["hidden"], 2 * z["width"]), jnp.bfloat16),
-        one_chip((z["held"], z["width"], z["hidden"]), jnp.bfloat16))
-    assert "_moe_experts_call" in text
+    tiles, block = _expert_product_compiles(
+        one_chip, tokens, z["top"], z["held"], z["hidden"], z["width"])
+    assert block == 18_874_368
+    assert tiles == {32: 72, 512: 192, 544: 200}[tokens]
 
 
 @pytest.fixture(scope="module")
@@ -870,18 +894,10 @@ def test_latent_expert_product_compiles(one_chip, tokens):
     """The non-gated grouped product over 128 held experts of (1024 x 2688)
     at 22 pairs a token: 172 tiles a plain tick can fill, 524 a tick that
     carries a 256-token window; the blocks fit the kernel's VMEM limit."""
-    from mmlspark_tpu.ops.grouped_matmul import TILE, grouped_swiglu
-    from mmlspark_tpu.parallel.moe import held_tiles
     z = NEMOTRON
-    tiles = held_tiles(tokens * 22, z["held"], TILE)
+    tiles, _ = _expert_product_compiles(one_chip, tokens, 22, z["held"],
+                                        z["latent"], z["width"], gated=False)
     assert tiles == {32: 172, 288: 524}[tokens]
-    text = _compiled_text(
-        functools.partial(grouped_swiglu, interpret=False, gated=False),
-        one_chip((tiles * TILE, z["latent"]), jnp.bfloat16),
-        one_chip((tiles,), jnp.int32), one_chip((), jnp.int32),
-        one_chip((z["held"], z["latent"], z["width"]), jnp.bfloat16),
-        one_chip((z["held"], z["width"], z["latent"]), jnp.bfloat16))
-    assert "_moe_experts_call" in text
 
 
 @pytest.fixture(scope="module")

@@ -92,6 +92,12 @@ def test_small_runs_every_phase_in_interpret_mode(small_run):
     assert phases["H.conv_gqa"]["moe"]["pairs_held"] \
         == phases["H.conv_gqa"]["moe"]["pairs_routed"] > 0
     assert phases["H.conv_gqa"]["gap_mean"] <= chip_smoke.CONV_GQA_GAP_MEAN
+    # the experts' product alone: a tile a step on a tick's layout, a run of
+    # tiles a step on a carrying step's
+    alone = phases["H.conv_gqa"]["product_alone"]
+    assert alone["tick"]["steps"] == alone["tick"]["tiles"]
+    assert alone["carrying"]["steps"] < alone["carrying"]["tiles"]
+    assert alone["carrying"]["err"] < chip_smoke.EXPERTS_ALONE_TOL
     latent = phases["I.latent"]
     assert latent["attn_ticks_latent"] > 0
     assert latent["prefix_tokens_shared"] == 2 * latent["context"]

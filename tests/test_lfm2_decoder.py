@@ -315,6 +315,30 @@ def test_no_pair_is_dropped_under_a_one_expert_router(params, sizes, cfg):
     assert np.abs(np.asarray(y - want)[:100]).max() < 2e-5
 
 
+@pytest.mark.parametrize("rows,folds", [(4, False), (4 + 64, True)],
+                         ids=["plain_tick", "carrying_step"])
+def test_a_product_folds_an_experts_run_of_tiles(params, sizes, cfg, rows,
+                                                 folds):
+    """``tiles`` and ``product_steps`` of the layer's counts: a tick's few
+    rows put one tile on an expert, a product a tile; a step that carries a
+    64-lane window puts ~17 pairs on each of the 8 experts, two tiles that
+    ONE product multiplies, and the layer's result is still the uncut
+    reference's."""
+    layer = params["layers"][1]["moe"]
+    x = jnp.asarray(np.random.default_rng(7).normal(0, 1, (rows, 64)), F32)
+    y, counts = moe_topk_held(x, x, layer, cfg.routed, jnp.ones(rows, bool),
+                              interpret=True)
+    by = dict(zip(MOE_STATS, np.asarray(counts)))
+    assert by["pairs_dropped"] == 0 == by["pairs_misplaced"]
+    assert by["product_steps"] == by["experts_touched"]
+    if folds:
+        assert by["tiles"] > by["product_steps"] == 8
+    else:
+        assert by["tiles"] == by["product_steps"]
+    want = REFERENCE.routed_ffn(x, layer, shape_of(sizes))
+    assert np.abs(np.asarray(y - want)).max() < 2e-5
+
+
 # ---- the engine --------------------------------------------------------------
 
 def greedy(params, sizes, prompt, n):

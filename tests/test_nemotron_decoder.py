@@ -29,7 +29,9 @@ from benchmarks import run as bench_run
 from mmlspark_tpu.models.zoo import hybrid
 from mmlspark_tpu.models.zoo.transformer import transformer_apply
 from mmlspark_tpu.ops import paged_attention as pa
-from mmlspark_tpu.ops.grouped_matmul import TILE, grouped_swiglu
+from mmlspark_tpu.ops.grouped_matmul import (RUN, TILE, _runs,
+                                             grouped_swiglu,
+                                             product_steps)
 from mmlspark_tpu.ops.ssm_step import (pack_state, ssm_decode_step,
                                        unpack_state)
 from mmlspark_tpu.parallel.moe import MOE_STATS, moe_topk_held
@@ -375,6 +377,134 @@ def test_the_relu2_product_is_the_plain_product():
         rows = x[s * TILE:(s + 1) * TILE]
         want = np.square(np.maximum(rows @ up[e], 0.0)) @ down[e]
         assert np.abs(got[s * TILE:(s + 1) * TILE] - want).max() < 1e-5
+
+
+def one_tile_walk(x, tile_expert, total, gate_up, down, gated):
+    """The product a tile a grid step, as it stood before a step became an
+    expert's run of tiles: the oracle a run's one product is held to, bit
+    for bit."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(expert_ref, x_ref, gu_ref, dn_ref, o_ref):
+        rows = x_ref[...]
+        gu = jnp.dot(rows, gu_ref[0], preferred_element_type=F32)
+        if gated:
+            f = gu.shape[1] // 2
+            h = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+        else:
+            h = jnp.square(jnp.maximum(gu, 0.0))
+        o_ref[...] = jnp.dot(h.astype(rows.dtype), dn_ref[0],
+                             preferred_element_type=F32)
+
+    @jax.jit
+    def call(tile_expert, total, x, gate_up, down):
+        R, D = x.shape
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(total,),
+                in_specs=[
+                    pl.BlockSpec((TILE, D), lambda s, e: (s, 0)),
+                    pl.BlockSpec((1, D, gate_up.shape[2]),
+                                 lambda s, e: (e[s], 0, 0)),
+                    pl.BlockSpec((1, down.shape[1], D),
+                                 lambda s, e: (e[s], 0, 0))],
+                out_specs=pl.BlockSpec((TILE, D), lambda s, e: (s, 0))),
+            out_shape=jax.ShapeDtypeStruct((R, D), F32),
+            interpret=True)(tile_expert, x, gate_up, down)
+
+    return call(tile_expert, jnp.asarray(total, jnp.int32), x, gate_up, down)
+
+
+#: tiles an expert (0: no row reached it) and tiles of the layout past the
+#: bound: a run is up to RUN tiles of one expert
+RUNS = {
+    "one_tile_each": ((1, 1, 1), 2),
+    "two": ((2,), 1),
+    "a_whole_run": ((RUN,), 1),
+    "a_run_and_a_tile": ((RUN + 1,), 1),
+    "two_runs_and_three": ((2 * RUN + 3,), 0),
+    "mixed_with_idle_experts": ((0, 3, 1, 0, RUN + 2, 2, 0), 3),
+    "ends_at_the_bound": ((1, 4), 0),
+    "nothing": ((0, 0), 2),
+}
+
+
+def run_case(tiles, spare, dtype, gated, seed=5):
+    """Rows, the layout's vectors and weights for experts of ``tiles`` tiles
+    each, ``spare`` tiles past the bound; NaN in the weights of every expert
+    no row reached."""
+    rng = np.random.default_rng(seed)
+    E, D, Fw = len(tiles), 32, 48
+    total = sum(tiles)
+    experts = np.concatenate([np.repeat(np.arange(E), tiles),
+                              np.full(spare, E - 1)]).astype(np.int32)
+    x = rng.normal(0, 1, ((total + spare) * TILE, D)).astype(np.float32)
+    up = rng.normal(0, 0.2, (E, D, 2 * Fw if gated else Fw)).astype(np.float32)
+    down = rng.normal(0, 0.2, (E, Fw, D)).astype(np.float32)
+    idle = np.asarray(tiles) == 0
+    up[idle], down[idle] = np.nan, np.nan
+    return (jnp.asarray(x, dtype), jnp.asarray(experts), total,
+            jnp.asarray(up, dtype), jnp.asarray(down, dtype))
+
+
+def plain_product(x, up, down, gated):
+    h = np.asarray(x, np.float32) @ np.asarray(up, np.float32)
+    f = h.shape[1] // 2
+    h = (h[:, :f] / (1 + np.exp(-h[:, :f])) * h[:, f:] if gated
+         else np.square(np.maximum(h, 0.0)))
+    return h @ np.asarray(down, np.float32)
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(RUNS))
+def test_a_run_of_tiles_is_one_product(case, dtype, gated):
+    """An expert's run of tiles multiplied in one grid step gives every row
+    under the bound what the tile-by-tile walk gave it, reads no expert
+    without a row (their weights hold NaN) and writes no tile past the
+    bound. Bit for bit in bfloat16, the served dtype, and in any run of one
+    tile; a float32 run of several is held to 1e-5 of the plain product:
+    XLA:CPU picks a float32 dot's summation order by its shape, 16 rows or
+    ``RUN * TILE``, and the last bit follows it."""
+    tiles, spare = RUNS[case]
+    x, experts, total, up, down = run_case(tiles, spare, dtype, gated)
+    got = np.asarray(grouped_swiglu(x, experts, total, up, down,
+                                    interpret=True, gated=gated))
+    want = np.asarray(one_tile_walk(x, experts, total, up, down, gated))
+    n = total * TILE
+    assert np.isfinite(got[:n]).all()
+    if dtype == "bfloat16" or max(tiles) <= 1:
+        np.testing.assert_array_equal(got[:n], want[:n])
+    for s, e in enumerate(np.asarray(experts)):
+        rows = slice(s * TILE, (s + 1) * TILE)
+        plain = plain_product(x[rows], up[e], down[e], gated)
+        if s >= total:
+            # whatever the buffer held, not these rows' product
+            assert not np.isclose(got[rows], plain, rtol=1e-3).any()
+        elif dtype == "float32":
+            assert np.abs(got[rows] - plain).max() < 1e-5
+            assert np.abs(want[rows] - plain).max() < 1e-5
+
+
+@pytest.mark.parametrize("case", sorted(RUNS))
+def test_the_schedule_is_the_layouts_runs(case):
+    """``_runs``: an expert's tiles RUN at a time, in the layout's order; an
+    operand of ``x`` moves only to a tile of the step's run."""
+    tiles, spare = RUNS[case]
+    _, experts, total, _, _ = run_case(tiles, spare, "float32", True)
+    steps, expert, first, count, tile_of = (
+        np.asarray(a) for a in _runs(experts, jnp.asarray(total, jnp.int32)))
+    want = [(e, sum(tiles[:e]) + at, min(RUN, n - at))
+            for e, n in enumerate(tiles) for at in range(0, n, RUN)]
+    assert steps == len(want) == int(product_steps(jnp.asarray(tiles)))
+    assert list(zip(expert[:steps], first[:steps], count[:steps])) == want
+    held = np.zeros(RUN, np.int64)
+    for k, (_, at, n) in enumerate(want):
+        held[:n] = at + np.arange(n)
+        np.testing.assert_array_equal(tile_of[:, k], held)
 
 
 def test_the_four_shares_add_up_to_the_uncut_layer(params, sizes, cfg):

@@ -213,7 +213,8 @@ def test_select_walk_gauges_hold_the_pools_counts(hybrid_run, gauge, stat):
 #: ``mmlspark_kvpool_moe_total{count=..}``), and the labels its two mixers'
 #: kernels add to ``mmlspark_kvpool_kernel_ticks_total``
 MOE_COUNTERS = ["pairs_routed", "pairs_held", "pairs_dropped",
-                "pairs_misplaced", "experts_touched", "expert_load_max"]
+                "pairs_misplaced", "experts_touched", "tiles",
+                "product_steps", "expert_load_max"]
 ROUTED_TICK_LABELS = ["kda", "latent"]
 
 
@@ -269,6 +270,10 @@ def test_routing_counters_ride_out_with_the_tokens(routed_run, count):
     assert stats["prefill_chunks_riding"] > 0
     assert stats["moe_pairs_held"] <= stats["moe_pairs_routed"] \
         <= 2 * (2 * stats["attn_ticks_kda"] + stats["prefill_tokens"])
+    # an expert with a pair has a tile, a run of its tiles is one grid step:
+    # the 16-lane windows that rode a tick put at most two tiles on an expert
+    assert stats["moe_experts_touched"] <= stats["moe_product_steps"] \
+        <= stats["moe_tiles"] <= 2 * stats["moe_product_steps"]
 
 
 @pytest.mark.parametrize("label", ROUTED_TICK_LABELS)
