@@ -838,7 +838,7 @@ def phase_latent(sz, seed, small):
         run_s=run_s, reference_s=reference_s,
         sweep_alone=latent_sweep_alone(ck, seed, small),
         gap_max=float(gaps.max()), gap_mean=float(gaps.mean()),
-        page=dec._page, context=int(doc.size), tile=dec._kv.latent_tile,
+        page=dec._page, context=int(doc.size), tile=dec._accountants[0].tile,
         attn_ticks_latent=stats["attn_ticks_latent"],
         latent_window_keys=stats["latent_window_keys"],
         prefix_tokens_shared=stats["prefix_tokens_shared"],

@@ -102,7 +102,7 @@ experts in a latent and one grouped-query layer in a period; ``cfg.ssm``):
 from __future__ import annotations
 
 import functools
-from typing import Dict
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -115,7 +115,9 @@ __all__ = ["check_config", "dims", "init_hybrid", "init_hybrid_cache",
            "init_hybrid_pool", "lightning_rates", "lightning_chunk",
            "sparse_select", "kda_chunk", "ssm_chunk", "head",
            "window_contiguous",
-           "window_paged", "tick_with_window", "SLOT_KEYS"]
+           "window_paged", "tick_with_window", "SLOT_KEYS", "KINDS",
+           "MIXERS", "Mixer", "Window", "Geometry", "accountants",
+           "required_page"]
 
 HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
@@ -125,13 +127,60 @@ _NEG = -1e30
 #: layer's convolution tails. A cached prefix keeps a snapshot of these rows
 #: beside its pages.
 SLOT_KEYS = ("state", "ck", "conv")
-#: the mixer kinds ``cfg.mixers`` may name
-MIXERS = ("lightning", "sparse", "kda", "mla", "conv", "gqa", "ssm")
 #: tokens a step of the chunked delta rule (:func:`kda_chunk`)
 KDA_CHUNK = 64
 #: keys of K/V a masked window folds at a time (a 32k context in one piece
 #: would hold a gigabyte of scores)
 _KEY_TILE = 2048
+
+
+class Window(NamedTuple):
+    """Whose cache a window of tokens continues, as a kind's window function
+    reads it; past ``n_valid``, over the engine's pool alone."""
+    cfg: TransformerConfig
+    pos: jax.Array                      # (B,) the rows' first positions
+    n_valid: jax.Array                  # (B,) their real lanes
+    bt: Optional[jax.Array] = None      # (B, P) the block table
+    page: int = 0
+    kernel: bool = False    # a one-token window on the Pallas decode kernels
+    #: the slot whose rows a one-row prefill window continues (None: row
+    #: ``b`` is slot ``b``)
+    slot: Optional[jax.Array] = None
+    tick: bool = False      # one token a row
+
+
+class Geometry(NamedTuple):
+    """What a kind's host accounting reads of the engine."""
+    page_size: int
+    pages_a_slot: int       # the width of a slot's block table
+    kernel: bool            # one-token windows run the Pallas decode kernels
+
+
+class Mixer(NamedTuple):
+    """What a mixer kind is, declared once (:data:`KINDS`); this module's
+    functions look a layer's kind up there and the engine reads
+    :func:`pool_shapes`, :func:`required_page` and :func:`accountants`, never
+    a kind's name. ``contiguous`` and ``paged`` are ``(lp, x, c, wpos, w)``
+    -> ``(y, c after)``: the window ``x`` (B, W, D) at ``wpos`` (B, W)
+    continuing the layer's entry ``c`` of either cache under ``w``
+    (:class:`Window`); the decode tick is the paged one-token window."""
+    #: ``(cfg, rng)``: the parameters a layer holds beside its norms and
+    #: feed-forward, drawn from the model's one generator
+    init: Callable
+    cache: Callable         # (cfg, batch, max_len): its contiguous entry
+    #: ``(cfg, num_pages, page_size, slots, positions)``: its entry in the
+    #: engine's pool, ``{key: (shape, dtype)}``; pages under ``kv``, rows a
+    #: slot under :data:`SLOT_KEYS`
+    pool: Callable
+    contiguous: Callable
+    paged: Callable
+    check: Optional[Callable] = None    # (cfg): its clause of check_config
+    #: what a decode call of the model counts under, once a kind: ``(on the
+    #: Pallas decode kernel, on the window's form)``
+    labels: Optional[Tuple[str, str]] = None
+    page: Optional[Callable] = None     # (cfg): the page it requires
+    #: ``(cfg, kind, geometry)``: its host accounting (:class:`TickCounts`)
+    counts: Optional[Callable] = None
 
 
 def dims(cfg: TransformerConfig):
@@ -177,165 +226,72 @@ def check_config(cfg: TransformerConfig) -> None:
                 raise ValueError(
                     f"swiglu limits {r.swiglu_limits}: a held layer names a "
                     "clamp, whose form is not built (only limit 0)")
-    if "kda" in cfg.mixers and cfg.kda is None:
-        raise ValueError("kda layers need cfg.kda")
-    if "ssm" in cfg.mixers:
-        m = cfg.ssm
-        if m is None or m.taps < 2:
-            raise ValueError("ssm layers need cfg.ssm (taps >= 2)")
-        if m.heads % (2 * m.groups):
-            raise ValueError(f"ssm: {m.heads} heads in {m.groups} groups "
-                             "(the state holds heads in pairs inside a "
-                             "group)")
-    if "conv" in cfg.mixers and (cfg.conv is None or cfg.conv.taps < 2):
-        raise ValueError("conv layers need cfg.conv (taps >= 2: the cache "
-                         "is the taps - 1 rows before a token)")
-    if "mla" in cfg.mixers:
-        if cfg.latent is None:
-            raise ValueError("mla layers need cfg.latent")
-        if cfg.latent.rope % 2:
-            raise ValueError(f"rope width {cfg.latent.rope}")
     H, Hkv, hd = dims(cfg)
     if H % Hkv or hd % 2:
         raise ValueError(f"heads {H} / kv_heads {Hkv} / head_dim {hd}")
-    if "gqa" in cfg.mixers and H // Hkv not in (1, 2, 4, 8, 16):
-        raise ValueError(f"gqa layers: {H} heads over {Hkv} KV heads (the "
-                         "decode kernel folds 1, 2, 4, 8 or 16 queries a KV "
-                         "head)")
-    if "sparse" in cfg.mixers:
-        sp = cfg.sparse
-        if sp is None:
-            raise ValueError("sparse layers need cfg.sparse")
-        if (sp.kernel_size % sp.kernel_stride
-                or sp.block_size % sp.kernel_stride):
-            raise ValueError("kernel_size and block_size must be multiples "
-                             "of kernel_stride")
-        forced = sp.init_blocks + sp.window_size // sp.block_size + 1
-        if forced > sp.topk:
-            raise ValueError(f"first blocks and window force {forced} "
-                             f"blocks, more than topk {sp.topk}")
+    for kind in dict.fromkeys(cfg.mixers):
+        if KINDS[kind].check:
+            KINDS[kind].check(cfg)
 
 
 def _ffn_kind(cfg: TransformerConfig, i: int) -> str:
     return cfg.ffn[i] if cfg.ffn else "dense"
 
 
+def _dense(rng, din, dout, scale=None):
+    s = scale or np.sqrt(2.0 / (din + dout))
+    return {"w": rng.normal(0, s, (din, dout)).astype(np.float32)}
+
+
+def _ones(n):
+    return {"scale": np.ones(n, np.float32)}
+
+
+def _moe_init(cfg, rng):
+    r = cfg.routed
+    D, F = cfg.d_model, r.d_expert
+    L = r.latent or D           # the width the experts read and write
+    wide = F if r.form == "relu2" else 2 * F
+    s = np.sqrt(2.0 / (L + F))
+    p = {"router": _dense(rng, D, r.experts),
+         "bias": rng.normal(0, 0.01, r.experts).astype(np.float32),
+         "experts": {
+             "up" if r.form == "relu2" else "gate_up":
+                 rng.normal(0, s, (r.held, L, wide)).astype(np.float32),
+             "down": rng.normal(0, s, (r.held, F, L)).astype(np.float32)}}
+    if r.latent:
+        p["to_latent"] = _dense(rng, D, L)
+        p["from_latent"] = _dense(rng, L, D)
+    if r.d_shared:
+        p["shared"] = dict(
+            {"gate": _dense(rng, D, r.d_shared)} if r.form != "relu2" else {},
+            up=_dense(rng, D, r.d_shared), down=_dense(rng, r.d_shared, D))
+    return p
+
+
 def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
-    """Random parameters in the pytree the hybrid block reads."""
+    """Random parameters in the pytree the hybrid block reads: a layer's
+    norms, what its mixer's kind holds (``Mixer.init``), its feed-forward."""
     check_config(cfg)
     rng = np.random.default_rng(seed)
-    H, Hkv, hd = dims(cfg)
     D = cfg.d_model
-
-    def dense(din, dout, scale=None):
-        s = scale or np.sqrt(2.0 / (din + dout))
-        return {"w": rng.normal(0, s, (din, dout)).astype(np.float32)}
-
-    def ones(n):
-        return {"scale": np.ones(n, np.float32)}
-
-    def kda_layer():
-        K = cfg.kda.conv_kernel
-        return {"q": dense(D, H * hd), "k": dense(D, H * hd),
-                "v": dense(D, H * hd), "f": dense(D, H * hd),
-                "b": dense(D, H), "z": dense(D, H), "o": dense(H * hd, D),
-                "dt_bias": rng.normal(0, 0.5, H * hd).astype(np.float32),
-                "a_log": rng.normal(0, 0.5, H).astype(np.float32),
-                "conv": {n: rng.normal(0, K ** -0.5, (K, H * hd)).astype(
-                    np.float32) for n in "qkv"},
-                "o_norm": ones(hd)}
-
-    def mla_layer():
-        la = cfg.latent
-        hq = H * (la.nope + la.rope)
-        q = ({"q_a": dense(D, la.q_rank), "q_norm": ones(la.q_rank),
-              "q_b": dense(la.q_rank, hq)} if la.q_rank
-             else {"q": dense(D, hq)})
-        return dict(q, kva=dense(D, la.latent + la.rope),
-                    c_norm=ones(la.latent),
-                    kvb=dense(la.latent, H * (la.nope + la.value)),
-                    o=dense(H * la.value, D),
-                    **({"z": dense(D, H)} if la.gate else {}))
-
-    def moe_layer():
-        r = cfg.routed
-        F = r.d_expert
-        L = r.latent or D           # the width the experts read and write
-        wide = F if r.form == "relu2" else 2 * F
-        s = np.sqrt(2.0 / (L + F))
-        p = {"router": dense(D, r.experts),
-             "bias": rng.normal(0, 0.01, r.experts).astype(np.float32),
-             "experts": {
-                 "up" if r.form == "relu2" else "gate_up":
-                     rng.normal(0, s, (r.held, L, wide)).astype(np.float32),
-                 "down": rng.normal(0, s, (r.held, F, L)).astype(np.float32)}}
-        if r.latent:
-            p["to_latent"] = dense(D, L)
-            p["from_latent"] = dense(L, D)
-        if r.d_shared:
-            p["shared"] = dict(
-                {"gate": dense(D, r.d_shared)} if r.form != "relu2" else {},
-                up=dense(D, r.d_shared), down=dense(r.d_shared, D))
-        return p
-
-    def ssm_layer():
-        m = cfg.ssm
-        inner, bc = m.heads * m.head_dim, 2 * m.groups * m.state
-        # the family's initialisation: A in [1, 16], the step log-uniform
-        # in [1e-3, 1e-1] (stored as its inverse softplus), D = 1
-        step = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), m.heads))
-        return {"in": dense(D, 2 * inner + bc + m.heads),
-                "conv": {"w": rng.normal(0, m.taps ** -0.5,
-                                         (m.taps, inner + bc)).astype(
-                                             np.float32),
-                         "b": rng.normal(0, 0.1, inner + bc).astype(
-                             np.float32)},
-                "dt_bias": (step + np.log(-np.expm1(-step))).astype(
-                    np.float32),
-                "a_log": np.log(rng.uniform(1, 16, m.heads)).astype(
-                    np.float32),
-                "d": np.ones(m.heads, np.float32),
-                "o_norm": ones(inner), "o": dense(inner, D)}
-
     layers = []
     for i, kind in enumerate(cfg.mixers):
-        lp = {"ln1": ones(D)}
-        if kind == "kda":
-            lp.update(kda_layer())
-        elif kind == "ssm":
-            lp.update(ssm_layer())
-        elif kind == "mla":
-            lp.update(mla_layer())
-        elif kind == "conv":
-            K = cfg.conv.taps
-            lp.update({"in": dense(D, 3 * D), "o": dense(D, D),
-                       "taps": rng.normal(0, K ** -0.5, (K, D)).astype(
-                           np.float32)})
-        elif kind == "gqa":
-            lp.update({"q": dense(D, H * hd), "k": dense(D, Hkv * hd),
-                       "v": dense(D, Hkv * hd), "o": dense(H * hd, D)})
-            if cfg.qk_positions:
-                lp.update({"q_norm": ones(hd), "k_norm": ones(hd)})
-        else:
-            kv = H if kind == "lightning" else Hkv
-            lp.update({"q": dense(D, H * hd), "k": dense(D, kv * hd),
-                       "v": dense(D, kv * hd), "g": dense(D, H * hd),
-                       "o": dense(H * hd, D),
-                       "q_norm": ones(hd), "k_norm": ones(hd)})
-            if kind == "lightning":
-                lp["o_norm"] = ones(H * hd)
+        lp = {"ln1": _ones(D)}
+        lp.update(KINDS[kind].init(cfg, rng))
         feed = _ffn_kind(cfg, i)
         if feed != "none":
-            lp["ln2"] = ones(D)
+            lp["ln2"] = _ones(D)
         if feed == "moe":
-            lp["moe"] = moe_layer()
+            lp["moe"] = _moe_init(cfg, rng)
         elif feed == "dense":
-            lp.update({"gate": dense(D, cfg.d_ff), "up": dense(D, cfg.d_ff),
-                       "down": dense(cfg.d_ff, D)})
+            lp.update({"gate": _dense(rng, D, cfg.d_ff),
+                       "up": _dense(rng, D, cfg.d_ff),
+                       "down": _dense(rng, cfg.d_ff, D)})
         layers.append(lp)
-    return {"embed": {"tok": dense(cfg.vocab, D, 0.02)["w"]},
-            "layers": layers, "final_ln": ones(D),
-            "lm_head": dense(D, cfg.vocab, 0.02)}
+    return {"embed": {"tok": _dense(rng, cfg.vocab, D, 0.02)["w"]},
+            "layers": layers, "final_ln": _ones(D),
+            "lm_head": _dense(rng, D, cfg.vocab, 0.02)}
 
 
 # ---- caches -----------------------------------------------------------------
@@ -344,122 +300,87 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def latent_row(cfg) -> int:
-    """Values a cached row of an mla layer holds: ``latent + rope`` rounded
-    up to whole 128-lane registers (zeros). Unpadded, the chip keeps the pool
-    with the page offset minor-most to save the padding and relays it, in
-    and out, around every call of the kernel, which takes row-major
-    operands only: two pool-sized copies a tick (PERF.md, PRs 28 and 35)."""
-    return _round_up(cfg.latent.latent + cfg.latent.rope, 128)
+def _zeros(shapes):
+    return {key: jnp.zeros(*sd) for key, sd in shapes.items()}
 
 
-def _conv_shape(cfg, kind: str, rows: int):
-    """A layer's convolution tails, a row a slot: a kda layer's last
-    ``conv_kernel - 1`` pre-convolution rows of q, k and v side by side, a
-    conv layer's last ``taps - 1`` rows of ``z``, an ssm layer's last ``taps
-    - 1`` pre-convolution rows of ``xBC``."""
-    if kind == "conv":
-        return (rows, cfg.conv.taps - 1, cfg.d_model)
-    if kind == "ssm":
-        m = cfg.ssm
-        return (rows, m.taps - 1,
-                m.heads * m.head_dim + 2 * m.groups * m.state)
-    H, _, hd = dims(cfg)
-    return (rows, cfg.kda.conv_kernel - 1, 3 * H * hd)
+def _slot_kind(rows, layer):
+    """The entries and the windows of a kind that holds rows a slot and no
+    page: ``rows(cfg, n)`` for the batch's rows or the engine's slots, and
+    one ``layer`` function over either."""
+    return dict(
+        cache=lambda cfg, batch, max_len: _zeros(rows(cfg, batch)),
+        pool=lambda cfg, num_pages, page_size, slots, positions:
+            rows(cfg, slots), contiguous=layer, paged=layer)
 
 
-def _ssm_state_shape(cfg, rows: int):
-    """An ssm layer's states, a row a slot, as the step holds them
-    (``ops.ssm_step``): heads in pairs, ``(rows, H / 2, N, 2 P)``."""
-    m = cfg.ssm
-    return (rows, m.heads // 2, m.state, 2 * m.head_dim)
+def _kv_pages(cfg, num_pages, page_size, slots, positions):
+    """Plain pages, K beside V, as every pool."""
+    _, Hkv, hd = dims(cfg)
+    return {"kv": ((num_pages, Hkv, page_size, 2 * hd), cfg.dtype)}
 
 
 def init_hybrid_cache(cfg: TransformerConfig, batch: int, max_len: int):
-    """Contiguous per-layer cache: ``{"state"}`` (B, H, hd, hd) float32 for
-    a lightning layer (a kda layer adds ``{"conv"}``, :func:`_conv_shape`;
-    an ssm layer's pair is :func:`_ssm_state_shape` and its tails);
-    ``{"k", "v"}`` (B, Hkv, L, hd) and the compressed keys ``{"ck"}``
-    (B, Hkv, L / stride, hd) for a sparse one, ``L`` being ``max_len``
-    rounded up to whole blocks; ``{"kv"}`` (B, 1, max_len,
-    :func:`latent_row`) latent rows for an mla layer; ``{"conv"}`` alone for
-    a conv layer; ``{"k", "v"}`` (B, Hkv, max_len, hd) for a gqa layer."""
-    H, Hkv, hd = dims(cfg)
-    out = []
-    for kind in cfg.mixers:
-        if kind == "lightning":
-            out.append({"state": jnp.zeros((batch, H, hd, hd), F32)})
-        elif kind in ("kda", "ssm"):
-            shape = ((batch, H, hd, hd) if kind == "kda"
-                     else _ssm_state_shape(cfg, batch))
-            out.append({"state": jnp.zeros(shape, F32),
-                        "conv": jnp.zeros(_conv_shape(cfg, kind, batch),
-                                          cfg.dtype)})
-        elif kind == "conv":
-            out.append({"conv": jnp.zeros(_conv_shape(cfg, kind, batch),
-                                          cfg.dtype)})
-        elif kind == "gqa":
-            kv = jnp.zeros((batch, Hkv, max_len, hd), cfg.dtype)
-            out.append({"k": kv, "v": kv})
-        elif kind == "mla":
-            out.append({"kv": jnp.zeros(
-                (batch, 1, max_len, latent_row(cfg)), cfg.dtype)})
-        else:
-            sp = cfg.sparse
-            L = _round_up(max_len, sp.block_size)
-            kv = jnp.zeros((batch, Hkv, L, hd), cfg.dtype)
-            out.append({"k": kv, "v": kv, "ck": jnp.zeros(
-                (batch, Hkv, L // sp.kernel_stride, hd), cfg.dtype)})
-    return out
+    """Contiguous per-layer cache, each layer's entry its kind's
+    (``Mixer.cache``): what a kind holds a slot of the pool it holds a row
+    of the batch here, and pages are ``{"k", "v"}`` (or an mla layer's
+    ``{"kv"}`` latent rows) ``max_len`` long."""
+    return [KINDS[kind].cache(cfg, batch, max_len) for kind in cfg.mixers]
 
 
 def pool_shapes(cfg: TransformerConfig, num_pages: int, page_size: int,
                 slots: int, positions: int):
-    """Per layer ``{key: (shape, dtype)}`` of the engine's cache: pages
-    (K beside V, as every pool) and a row of compressed keys a slot (for
-    ``positions`` positions) for a sparse layer, one state row a slot for a
-    lightning layer; a kda layer adds its convolution tails a slot, an ssm
-    layer holds its (not square) state and its tails a slot; an mla
-    layer holds latent pages ``(pages, 1, page, latent_row)``: one row a
-    token, nothing a head; a conv layer its tails a slot and nothing else; a
-    gqa layer pages and nothing a slot. What a layer holds is read from
-    these keys (``kv``: pages; :data:`SLOT_KEYS`: rows a slot), not from its
-    mixer's name."""
-    H, Hkv, hd = dims(cfg)
-    out = []
-    for kind in cfg.mixers:
-        if kind == "lightning":
-            out.append({"state": ((slots, H, hd, hd), F32)})
-        elif kind in ("kda", "ssm"):
-            shape = ((slots, H, hd, hd) if kind == "kda"
-                     else _ssm_state_shape(cfg, slots))
-            out.append({"state": (shape, F32),
-                        "conv": (_conv_shape(cfg, kind, slots), cfg.dtype)})
-        elif kind == "conv":
-            out.append({"conv": (_conv_shape(cfg, kind, slots), cfg.dtype)})
-        elif kind == "gqa":
-            out.append({"kv": ((num_pages, Hkv, page_size, 2 * hd),
-                               cfg.dtype)})
-        elif kind == "mla":
-            out.append({"kv": ((num_pages, 1, page_size, latent_row(cfg)),
-                        cfg.dtype)})
-        else:
-            s = cfg.sparse.kernel_stride
-            if cfg.sparse.block_size % page_size:
-                raise ValueError(
-                    f"page_size {page_size} must divide the sparse block "
-                    f"size {cfg.sparse.block_size}")
-            out.append({
-                "kv": ((num_pages, Hkv, page_size, 2 * hd), cfg.dtype),
-                "ck": ((slots, Hkv, -(-positions // s), hd), cfg.dtype)})
-    return out
+    """Per layer ``{key: (shape, dtype)}`` of the engine's cache, each
+    layer's entry its kind's (``Mixer.pool``). What a layer holds is read
+    from these keys (``kv``: pages; :data:`SLOT_KEYS`: rows a slot, for
+    ``positions`` positions), not from its mixer's name."""
+    return [KINDS[kind].pool(cfg, num_pages, page_size, slots, positions)
+            for kind in cfg.mixers]
 
 
 def init_hybrid_pool(cfg, num_pages: int, page_size: int, slots: int,
                      positions: int):
-    return [{k: jnp.zeros(*sd) for k, sd in layer.items()}
-            for layer in pool_shapes(cfg, num_pages, page_size, slots,
-                                     positions)]
+    return [_zeros(layer) for layer in pool_shapes(
+        cfg, num_pages, page_size, slots, positions)]
+
+
+def required_page(cfg: TransformerConfig) -> Optional[int]:
+    """The page a model's kinds require of the engine (``Mixer.page``), or
+    None where every kind takes the page it is given."""
+    for kind in dict.fromkeys(cfg.mixers):
+        if KINDS[kind].page:
+            return KINDS[kind].page(cfg)
+    return None
+
+
+class TickCounts:
+    """A kind's host accounting, built once an engine (:func:`accountants`).
+    Its calls return plain ``{pool stat: increment}`` dicts from the
+    scheduler's numbers, no device read: this one counts the decode calls
+    under the kind's label; a kind whose decode kernel walks a grid adds the
+    count of the walk beside the kernel's rule."""
+
+    def __init__(self, cfg, kind: str, geometry: Geometry):
+        self.layers = cfg.mixers.count(kind)
+        self.kernel = geometry.kernel
+        labels = KINDS[kind].labels
+        self.label = labels and "attn_ticks_" + labels[not geometry.kernel]
+
+    def decode(self, positions, rows: int, context: int):
+        """A decode call of ``rows`` rows, the live ones at ``positions``;
+        the longest ``context`` they have served."""
+        return {self.label: 1}
+
+    def window(self, offset: int, lanes: int):
+        """A prefill window of ``lanes`` real tokens at ``offset``."""
+        return {}
+
+
+def accountants(cfg: TransformerConfig, geometry: Geometry):
+    """The host accounting of a model's kinds, in :data:`KINDS`' order: one
+    for each kind the model has that counts anything."""
+    return [kind.counts(cfg, name, geometry) for name, kind in KINDS.items()
+            if name in cfg.mixers and kind.counts]
 
 
 # ---- shared pieces ----------------------------------------------------------
@@ -496,7 +417,43 @@ def _swiglu(lp, x, dt):
     return _proj(y, lp["down"], dt)
 
 
+def _slot_rows(c, slot):
+    """A layer's rows a slot as a window continues them: every row (row
+    ``b`` is slot ``b``), or the one row of a prefill window's ``slot``."""
+    return c if slot is None else {
+        kk: jax.lax.dynamic_slice_in_dim(c[kk], slot, 1, axis=0) for kk in c}
+
+
+def _slot_rows_back(c, new, slot):
+    """The layer's entry with the window's rows ``new`` where
+    :func:`_slot_rows` read them."""
+    return new if slot is None else {
+        kk: jax.lax.dynamic_update_slice_in_dim(c[kk], new[kk], slot, axis=0)
+        for kk in c}
+
+
+def _gated_attention_init(cfg, rng, kv):
+    """A lightning or sparse layer: ``kv`` key/value heads, q and k normed a
+    head, a sigmoid gate a channel."""
+    H, _, hd = dims(cfg)
+    D = cfg.d_model
+    return {"q": _dense(rng, D, H * hd), "k": _dense(rng, D, kv * hd),
+            "v": _dense(rng, D, kv * hd), "g": _dense(rng, D, H * hd),
+            "o": _dense(rng, H * hd, D),
+            "q_norm": _ones(hd), "k_norm": _ones(hd)}
+
+
 # ---- lightning --------------------------------------------------------------
+
+def _lightning_init(cfg, rng):
+    H, _, hd = dims(cfg)
+    return dict(_gated_attention_init(cfg, rng, H), o_norm=_ones(H * hd))
+
+
+def _lightning_rows(cfg, rows: int):
+    H, _, hd = dims(cfg)
+    return {"state": ((rows, H, hd, hd), F32)}
+
 
 def lightning_rates(H: int):
     """``s_h = 2^(-8h/H)``, h = 1..H: head h decays by ``exp(-s_h)`` a
@@ -545,7 +502,62 @@ def lightning_chunk(q, k, v, state, n_valid):
     return o * hd ** -0.5, new
 
 
+def _lightning_layer(lp, x, c, wpos, w):
+    """A lightning layer over its states ``c``: the decode tick
+    (``w.kernel``: one token a row) runs the Pallas step, a window the
+    chunked form from a state zeroed at position 0."""
+    from ...ops.lightning_attention import lightning_decode_step
+    q, k, v = _lightning_qkv(lp, x, wpos, w.cfg)
+    state = _slot_rows(c, w.slot)["state"]
+    if w.kernel:
+        # a decoding row is never at position 0 (a prompt has a token),
+        # so the tick needs no reset and no pass over the states for one
+        o, st = lightning_decode_step(q[:, :, 0], k[:, :, 0], v[:, :, 0],
+                                      state, w.n_valid > 0)
+        o = o[:, :, None]
+    else:
+        o, st = lightning_chunk(q, k, v, _fresh(state, w.pos, w.n_valid),
+                                w.n_valid)
+    new = _slot_rows_back(c, {"state": st}, w.slot)
+    return _gated_out(lp, x, o, w.cfg, norm=True), new
+
+
 # ---- sparse -----------------------------------------------------------------
+
+def _sparse_check(cfg):
+    sp = cfg.sparse
+    if sp is None:
+        raise ValueError("sparse layers need cfg.sparse")
+    if (sp.kernel_size % sp.kernel_stride
+            or sp.block_size % sp.kernel_stride):
+        raise ValueError("kernel_size and block_size must be multiples "
+                         "of kernel_stride")
+    forced = sp.init_blocks + sp.window_size // sp.block_size + 1
+    if forced > sp.topk:
+        raise ValueError(f"first blocks and window force {forced} "
+                         f"blocks, more than topk {sp.topk}")
+
+
+def _sparse_cache(cfg, batch, max_len):
+    """``L`` is ``max_len`` in whole blocks."""
+    _, Hkv, hd = dims(cfg)
+    sp = cfg.sparse
+    L = _round_up(max_len, sp.block_size)
+    kv = jnp.zeros((batch, Hkv, L, hd), cfg.dtype)
+    return {"k": kv, "v": kv, "ck": jnp.zeros(
+        (batch, Hkv, L // sp.kernel_stride, hd), cfg.dtype)}
+
+
+def _sparse_pool(cfg, num_pages, page_size, slots, positions):
+    _, Hkv, hd = dims(cfg)
+    if cfg.sparse.block_size % page_size:
+        raise ValueError(f"page_size {page_size} must divide the sparse "
+                         f"block size {cfg.sparse.block_size}")
+    return dict(
+        _kv_pages(cfg, num_pages, page_size, slots, positions),
+        ck=((slots, Hkv, -(-positions // cfg.sparse.kernel_stride), hd),
+            cfg.dtype))
+
 
 def _sparse_qkv(lp, x, cfg):
     H, Hkv, hd = dims(cfg)
@@ -713,9 +725,10 @@ def _scatter_pages(pool, k, v, bt, wpos, n_valid, page):
                    (wpos % page).reshape(-1, 1)].set(rows)
 
 
-def _sparse_contiguous(lp, x, wpos, pos, n_valid, c, cfg):
+def _sparse_contiguous(lp, x, c, wpos, w):
     """A sparse layer over a contiguous cache: write the window's K/V and
     the compressed keys it completes, select, attend under the mask."""
+    cfg, pos, n_valid = w.cfg, w.pos, w.n_valid
     sp = cfg.sparse
     s, ks = sp.kernel_stride, sp.kernel_size
     W = x.shape[1]
@@ -737,8 +750,7 @@ def _sparse_contiguous(lp, x, wpos, pos, n_valid, c, cfg):
                                                    "ck": ck}
 
 
-def _sparse_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel,
-                  slot):
+def _sparse_paged(lp, x, c, wpos, w):
     """A sparse layer over the page pool. K/V writes go through the block
     table (padding lanes and idle rows to trash page 0); the compressed keys
     are the rows' own (row ``slot`` for a one-row prefill window). The
@@ -746,6 +758,7 @@ def _sparse_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel,
     the Pallas kernel, which reads them in place; a window gathers its
     row's pages and masks."""
     from ...ops.paged_attention import split_kv
+    cfg, pos, n_valid, bt, page, kernel, slot, _ = w
     sp = cfg.sparse
     s, ks = sp.kernel_stride, sp.kernel_size
     B, W, _ = x.shape
@@ -790,6 +803,14 @@ def _sparse_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel,
     return _gated_out(lp, x, o, cfg, norm=False), {"kv": kv, "ck": ck}
 
 
+def _dense_walk(sp, page: int, pages_a_slot: int):
+    """``(pages a block, blocks of the dense walk)``: a row still under
+    ``dense_len`` lists every block up to its own, ``dense_len``'s blocks at
+    most and no more than a slot's block table holds."""
+    pp = sp.block_size // page
+    return pp, min(-(-sp.dense_len // sp.block_size), -(-pages_a_slot // pp))
+
+
 def _selected_decode(q, kv, bt, pos, n_valid, idx, sel_ok, cfg, page):
     """One query a row over the blocks chosen for each (row, KV group), read
     in place. A row still under ``dense_len`` lists every block up to its
@@ -799,9 +820,7 @@ def _selected_decode(q, kv, bt, pos, n_valid, idx, sel_ok, cfg, page):
     B, Hq, _, hd = q.shape
     G = idx.shape[1]
     K = idx.shape[-1]
-    pp = sp.block_size // page                  # pages a block
-    n_dense = min(-(-sp.dense_len // sp.block_size),
-                  -(-bt.shape[1] // pp))
+    pp, n_dense = _dense_walk(sp, page, bt.shape[1])
     cur = pos // sp.block_size
     dense_row = (pos + 1 <= sp.dense_len) & (n_valid > 0)
 
@@ -830,7 +849,79 @@ def _selected_decode(q, kv, bt, pos, n_valid, idx, sel_ok, cfg, page):
     return jax.lax.cond(wide, lambda: walk(n_dense), lambda: walk(K))
 
 
+class _SelectCounts(TickCounts):
+    """A model with sparse layers counts each paged call by path, ``sparse``
+    when the longest context it served was past ``dense_len`` (blocks were
+    selected), else ``dense``; and ``select_walk_pages`` / ``_steps``, the
+    selected-block kernel's walk of a decode call by
+    :func:`_selected_decode`'s own rule (docs/observability.md)."""
+
+    def __init__(self, cfg, kind, geometry):
+        from ...ops.paged_attention import select_block
+        super().__init__(cfg, kind, geometry)
+        _, Hkv, hd = dims(cfg)
+        self.sp = sp = cfg.sparse
+        page = geometry.page_size
+        # the two lists a call walks for a (row, KV head), in pages: the
+        # top-k walk's (K is sparse_select's, from the compressed keys a
+        # slot holds) and the dense walk's, the longer
+        self.pp, n_dense = _dense_walk(sp, page, geometry.pages_a_slot)
+        scored = -(-geometry.pages_a_slot * page // sp.kernel_stride)
+        K = min(sp.topk, -(-scored // (sp.block_size // sp.kernel_stride)))
+        self.walks = (K * self.pp, max(K, n_dense) * self.pp)
+        head_slice = page * 2 * hd * jnp.dtype(cfg.dtype).itemsize
+        self.steps = tuple(n // select_block(head_slice, n)
+                           for n in self.walks)
+        self.lists = self.layers * Hkv          # a call's lists a row
+
+    def _path(self, context):
+        return {"attn_ticks_sparse" if context > self.sp.dense_len
+                else "attn_ticks_dense": 1}
+
+    def window(self, offset, lanes):
+        return self._path(offset + lanes)
+
+    def decode(self, positions, rows, context):
+        out = self._path(context)
+        if self.kernel:
+            sp, short = self.sp, self.walks[0]
+            listed = [(pos // sp.block_size + 1) * self.pp
+                      for pos in positions]
+            dense = [pos + 1 <= sp.dense_len for pos in positions]
+            widened = any(d and n > short for d, n in zip(dense, listed))
+            out["select_walk_pages"] = self.lists * sum(
+                n if d else min(n, short) for d, n in zip(dense, listed))
+            out["select_walk_steps"] = (self.lists * rows
+                                        * self.steps[widened])
+        return out
+
+
 # ---- kda --------------------------------------------------------------------
+
+def _kda_check(cfg):
+    if cfg.kda is None:
+        raise ValueError("kda layers need cfg.kda")
+
+
+def _kda_init(cfg, rng):
+    H, _, hd = dims(cfg)
+    D, K = cfg.d_model, cfg.kda.conv_kernel
+    return {"q": _dense(rng, D, H * hd), "k": _dense(rng, D, H * hd),
+            "v": _dense(rng, D, H * hd), "f": _dense(rng, D, H * hd),
+            "b": _dense(rng, D, H), "z": _dense(rng, D, H),
+            "o": _dense(rng, H * hd, D),
+            "dt_bias": rng.normal(0, 0.5, H * hd).astype(np.float32),
+            "a_log": rng.normal(0, 0.5, H).astype(np.float32),
+            "conv": {n: rng.normal(0, K ** -0.5, (K, H * hd)).astype(
+                np.float32) for n in "qkv"},
+            "o_norm": _ones(hd)}
+
+
+def _kda_rows(cfg, rows: int):
+    H, _, hd = dims(cfg)
+    return {"state": ((rows, H, hd, hd), F32),
+            "conv": ((rows, cfg.kda.conv_kernel - 1, 3 * H * hd), cfg.dtype)}
+
 
 def _next_tail(ext, n_valid, keep: int, tick: bool):
     """The ``keep`` rows before a row's next token, of ``ext`` (B, keep + W,
@@ -971,13 +1062,15 @@ def _head_gated_out(lp, x, o, cfg, norm: bool):
     return o.reshape(B, W, H * dv).astype(dt) @ lp["o"]["w"].astype(dt)
 
 
-def _kda_layer(lp, x, c, pos, n_valid, cfg, kernel):
-    """A kda layer over its rows of the cache ``c`` (``state``, ``conv``;
-    the caller has sliced a prefill window's slot out): the decode tick
-    (``kernel``: one token a row, none at position 0) runs the Pallas step,
-    a window the chunked form from a state and tails zeroed at position 0."""
+def _kda_layer(lp, x, c, wpos, w):
+    """A kda layer over its rows of the cache ``c`` (``state``, ``conv``):
+    the decode tick (``w.kernel``: one token a row, none at position 0) runs
+    the Pallas step, a window the chunked form from a state and tails zeroed
+    at position 0."""
     from ...ops.kda_attention import kda_decode_step
-    state, tail = c["state"], c["conv"]
+    cfg, pos, n_valid, kernel = w.cfg, w.pos, w.n_valid, w.kernel
+    rows = _slot_rows(c, w.slot)
+    state, tail = rows["state"], rows["conv"]
     if not kernel:
         state, tail = _fresh(state, pos, n_valid), _fresh(tail, pos, n_valid)
     q, k, v, g, beta, tail = _kda_inputs(lp, x, tail, n_valid, cfg)
@@ -989,10 +1082,46 @@ def _kda_layer(lp, x, c, pos, n_valid, cfg, kernel):
     else:
         o, state = kda_chunk(q, k, v, g, beta, state)
     return (_head_gated_out(lp, x, o, cfg, norm=True),
-            {"state": state, "conv": tail})
+            _slot_rows_back(c, {"state": state, "conv": tail}, w.slot))
 
 
 # ---- ssm --------------------------------------------------------------------
+
+def _ssm_check(cfg):
+    m = cfg.ssm
+    if m is None or m.taps < 2:
+        raise ValueError("ssm layers need cfg.ssm (taps >= 2)")
+    if m.heads % (2 * m.groups):
+        raise ValueError(f"ssm: {m.heads} heads in {m.groups} groups "
+                         "(the state holds heads in pairs inside a group)")
+
+
+def _ssm_init(cfg, rng):
+    m = cfg.ssm
+    D = cfg.d_model
+    inner, bc = m.heads * m.head_dim, 2 * m.groups * m.state
+    # the family's initialisation: A in [1, 16], the step log-uniform
+    # in [1e-3, 1e-1] (stored as its inverse softplus), D = 1
+    step = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), m.heads))
+    return {"in": _dense(rng, D, 2 * inner + bc + m.heads),
+            "conv": {"w": rng.normal(0, m.taps ** -0.5,
+                                     (m.taps, inner + bc)).astype(np.float32),
+                     "b": rng.normal(0, 0.1, inner + bc).astype(np.float32)},
+            "dt_bias": (step + np.log(-np.expm1(-step))).astype(np.float32),
+            "a_log": np.log(rng.uniform(1, 16, m.heads)).astype(np.float32),
+            "d": np.ones(m.heads, np.float32),
+            "o_norm": _ones(inner), "o": _dense(rng, inner, D)}
+
+
+def _ssm_rows(cfg, rows: int):
+    """The state as the step holds it (``ops.ssm_step``): heads in pairs,
+    ``(rows, H / 2, N, 2 P)``."""
+    m = cfg.ssm
+    return {"state": ((rows, m.heads // 2, m.state, 2 * m.head_dim), F32),
+            "conv": ((rows, m.taps - 1,
+                      m.heads * m.head_dim + 2 * m.groups * m.state),
+                     cfg.dtype)}
+
 
 def _ssm_inputs(lp, x, tail, n_valid, cfg):
     """What the state-space recurrence reads of a window ``x`` (B, W, D)
@@ -1074,15 +1203,17 @@ def ssm_chunk(u, b, c, d, a_rate, state, chunk: int):
     return y[:, :, :W], state
 
 
-def _ssm_layer(lp, x, c, pos, n_valid, cfg, kernel):
-    """An ssm layer over its rows of the cache ``c`` (``state``, ``conv``;
-    the caller has sliced a prefill window's slot out): the decode tick
-    (``kernel``: one token a row, none at position 0) runs the Pallas step,
-    a window the chunked scan from a state and tails zeroed at position 0.
-    Out: the gate BEFORE the norm, the norm a group of channels, ``W_o``."""
+def _ssm_layer(lp, x, c, wpos, w):
+    """An ssm layer over its rows of the cache ``c`` (``state``, ``conv``):
+    the decode tick (``w.kernel``: one token a row, none at position 0) runs
+    the Pallas step, a window the chunked scan from a state and tails zeroed
+    at position 0. Out: the gate BEFORE the norm, the norm a group of
+    channels, ``W_o``."""
     from ...ops.ssm_step import pack_state, ssm_decode_step, unpack_state
+    cfg, pos, n_valid, kernel = w.cfg, w.pos, w.n_valid, w.kernel
     m = cfg.ssm
-    state, tail = c["state"], c["conv"]
+    rows = _slot_rows(c, w.slot)
+    state, tail = rows["state"], rows["conv"]
     if not kernel:
         state, tail = _fresh(state, pos, n_valid), _fresh(tail, pos, n_valid)
     z, u, b, cc, d, tail = _ssm_inputs(lp, x, tail, n_valid, cfg)
@@ -1104,10 +1235,54 @@ def _ssm_layer(lp, x, c, pos, n_valid, cfg, kernel):
                           + cfg.norm_eps)
     y = g.reshape(B, W, H * P) * lp["o_norm"]["scale"].astype(F32)
     return (_proj(y.astype(cfg.dtype), lp["o"], cfg.dtype),
-            {"state": state, "conv": tail})
+            _slot_rows_back(c, {"state": state, "conv": tail}, w.slot))
+
+
+class _StateCounts(TickCounts):
+    """``ssm_state_rows``: the states the decode calls' state-space step had
+    to read and write, each live row's once an ssm layer a call."""
+
+    def decode(self, positions, rows, context):
+        out = super().decode(positions, rows, context)
+        if self.kernel:
+            out["ssm_state_rows"] = self.layers * len(positions)
+        return out
 
 
 # ---- mla --------------------------------------------------------------------
+
+def _mla_check(cfg):
+    if cfg.latent is None:
+        raise ValueError("mla layers need cfg.latent")
+    if cfg.latent.rope % 2:
+        raise ValueError(f"rope width {cfg.latent.rope}")
+
+
+def _mla_init(cfg, rng):
+    la, H, D = cfg.latent, cfg.heads, cfg.d_model
+    hq = H * (la.nope + la.rope)
+    q = ({"q_a": _dense(rng, D, la.q_rank), "q_norm": _ones(la.q_rank),
+          "q_b": _dense(rng, la.q_rank, hq)} if la.q_rank
+         else {"q": _dense(rng, D, hq)})
+    return dict(q, kva=_dense(rng, D, la.latent + la.rope),
+                c_norm=_ones(la.latent),
+                kvb=_dense(rng, la.latent, H * (la.nope + la.value)),
+                o=_dense(rng, H * la.value, D),
+                **({"z": _dense(rng, D, H)} if la.gate else {}))
+
+
+def latent_row(cfg) -> int:
+    """Values a cached row of an mla layer holds: ``latent + rope`` rounded
+    up to whole 128-lane registers (zeros). Unpadded, the chip keeps the pool
+    with the page offset minor-most to save the padding and relays it, in
+    and out, around every call of the kernel, which takes row-major
+    operands only: two pool-sized copies a tick (PERF.md, PRs 28 and 35)."""
+    return _round_up(cfg.latent.latent + cfg.latent.rope, 128)
+
+
+def _mla_pool(cfg, num_pages, page_size, slots, positions):
+    return {"kv": ((num_pages, 1, page_size, latent_row(cfg)), cfg.dtype)}
+
 
 def _mla_inputs(lp, x, wpos, cfg):
     """``(q_n (B, H, W, nope), q_r (B, H, W, rope) rotated, row (B, W,
@@ -1233,7 +1408,12 @@ def _mla_absorbed(lp, q_n, q_r, kv_pages, bt, lengths, cfg):
     return o[:, :, None]
 
 
-def _mla_contiguous(lp, x, wpos, n_valid, c, cfg):
+def _mla_cache(cfg, batch, max_len):
+    return {"kv": jnp.zeros((batch, 1, max_len, latent_row(cfg)), cfg.dtype)}
+
+
+def _mla_contiguous(lp, x, c, wpos, w):
+    cfg, n_valid = w.cfg, w.n_valid
     q_n, q_r, row = _mla_inputs(lp, x, wpos, cfg)
     W = x.shape[1]
     L = c["kv"].shape[2]
@@ -1244,12 +1424,13 @@ def _mla_contiguous(lp, x, wpos, n_valid, c, cfg):
     return _mla_out(lp, x, o, cfg), {"kv": rows[:, None]}
 
 
-def _mla_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel):
+def _mla_paged(lp, x, c, wpos, w):
     """An mla layer over its latent pages: the window's rows are written
     through the block table (padding lanes and idle rows to trash page 0);
     the decode tick then attends absorbed, in place; a window attends
     expanded, a tile of its row's pages at a time
     (:func:`_mla_window_call`)."""
+    cfg, pos, n_valid, bt, page, kernel = w[:6]
     B, W, _ = x.shape
     P = bt.shape[1]
     q_n, q_r, row = _mla_inputs(lp, x, wpos, cfg)
@@ -1268,25 +1449,103 @@ def _mla_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel):
     return _mla_out(lp, x, o, cfg), {"kv": kv}
 
 
+class _LatentCounts(TickCounts):
+    """The prefill windows' fold, :func:`window_tile` pages of keys at a time
+    (``latent_window_keys``: whole tiles up to a window's last key;
+    ``_context``: the keys it had to rebuild; ``_pairs``: the (query, key)
+    pairs its causal mask lets through, what the mathematics needs), and
+    ``latent_sweep_pages`` / ``_steps``, the absorbed kernel's sweep of a
+    decode call by the rule the call reads its shapes with
+    (docs/observability.md)."""
+
+    def __init__(self, cfg, kind, geometry):
+        from ...ops.paged_attention import latent_block
+        super().__init__(cfg, kind, geometry)
+        self.page, per = geometry.page_size, geometry.pages_a_slot
+        self.tile = self.page * window_tile(self.page, per)
+        self.block = latent_block(
+            self.page * latent_row(cfg) * jnp.dtype(cfg.dtype).itemsize, per)
+
+    def window(self, offset, lanes):
+        last = offset + lanes - 1
+        return {"latent_window_keys": (last // self.tile + 1) * self.tile,
+                "latent_window_context": offset + lanes,
+                "latent_window_pairs": (lanes * offset
+                                        + lanes * (lanes + 1) // 2)}
+
+    def decode(self, positions, rows, context):
+        out = super().decode(positions, rows, context)
+        if self.kernel:
+            pages = [-(-(pos + 1) // self.page) for pos in positions]
+            out["latent_sweep_pages"] = self.layers * sum(pages)
+            out["latent_sweep_steps"] = self.layers * (
+                rows - len(pages)
+                + sum(max(1, -(-p // self.block)) for p in pages))
+        return out
+
+
 # ---- conv and gqa: the mixers that end in W_o alone ---------------------------
 
-def _conv_layer(lp, x, tail, pos, n_valid, cfg, tick):
+def _conv_check(cfg):
+    if cfg.conv is None or cfg.conv.taps < 2:
+        raise ValueError("conv layers need cfg.conv (taps >= 2: the cache "
+                         "is the taps - 1 rows before a token)")
+
+
+def _conv_init(cfg, rng):
+    D, K = cfg.d_model, cfg.conv.taps
+    return {"in": _dense(rng, D, 3 * D), "o": _dense(rng, D, D),
+            "taps": rng.normal(0, K ** -0.5, (K, D)).astype(np.float32)}
+
+
+def _conv_rows(cfg, rows: int):
+    return {"conv": ((rows, cfg.conv.taps - 1, cfg.d_model), cfg.dtype)}
+
+
+def _conv_layer(lp, x, c, wpos, w):
     """A gated short convolution over a window ``x`` (B, W, D) continuing the
-    tails ``tail`` (B, taps - 1, D), the rows of ``z = B * u`` before the
-    window (zeroed for a window that starts at position 0; the decode
-    ``tick`` never does). Returns ``(W_o(C * c), the tails after lane n_valid
-    - 1)``: padding lanes never enter a tail."""
+    tails ``c["conv"]`` (B, taps - 1, D), the rows of ``z = B * u`` before
+    the window (zeroed for a window that starts at position 0; the decode
+    tick, ``w.tick``, never does). Returns ``(W_o(C * c), the tails after
+    lane n_valid - 1)``: padding lanes never enter a tail."""
+    cfg, pos, n_valid, tick = w.cfg, w.pos, w.n_valid, w.tick
     dt = cfg.dtype
     K = cfg.conv.taps
     W = x.shape[1]
+    tail = _slot_rows(c, w.slot)["conv"]
     if not tick:
         tail = _fresh(tail, pos, n_valid)
-    b, c, u = jnp.split(_proj(x, lp["in"], dt), 3, axis=-1)
+    b, gate, u = jnp.split(_proj(x, lp["in"], dt), 3, axis=-1)
     ext = jnp.concatenate([tail.astype(dt), b * u], axis=1)  # (B, K-1+W, D)
     taps = lp["taps"].astype(F32)
     mixed = sum(ext[:, j:j + W].astype(F32) * taps[j] for j in range(K))
     new_tail = _next_tail(ext, n_valid, K - 1, tick)
-    return _proj((c.astype(F32) * mixed).astype(dt), lp["o"], dt), new_tail
+    return (_proj((gate.astype(F32) * mixed).astype(dt), lp["o"], dt),
+            _slot_rows_back(c, {"conv": new_tail}, w.slot))
+
+
+def _gqa_check(cfg):
+    H, Hkv, _ = dims(cfg)
+    if H // Hkv not in (1, 2, 4, 8, 16):
+        raise ValueError(f"gqa layers: {H} heads over {Hkv} KV heads (the "
+                         "decode kernel folds 1, 2, 4, 8 or 16 queries a KV "
+                         "head)")
+
+
+def _gqa_init(cfg, rng):
+    H, Hkv, hd = dims(cfg)
+    D = cfg.d_model
+    lp = {"q": _dense(rng, D, H * hd), "k": _dense(rng, D, Hkv * hd),
+          "v": _dense(rng, D, Hkv * hd), "o": _dense(rng, H * hd, D)}
+    if cfg.qk_positions:
+        lp.update({"q_norm": _ones(hd), "k_norm": _ones(hd)})
+    return lp
+
+
+def _gqa_cache(cfg, batch, max_len):
+    _, Hkv, hd = dims(cfg)
+    kv = jnp.zeros((batch, Hkv, max_len, hd), cfg.dtype)
+    return {"k": kv, "v": kv}
 
 
 def _gqa_qkv(lp, x, wpos, cfg):
@@ -1322,7 +1581,8 @@ def _causal(wpos, L):
     return (jnp.arange(L)[None, None] <= wpos[..., None])[:, None]
 
 
-def _gqa_contiguous(lp, x, wpos, n_valid, c, cfg):
+def _gqa_contiguous(lp, x, c, wpos, w):
+    cfg, n_valid = w.cfg, w.n_valid
     q, k, v = _gqa_qkv(lp, x, wpos, cfg)
     kc, vc = _put_window(c, k, v, wpos, n_valid)
     o = _masked_attention(q, kc, vc, _causal(wpos, kc.shape[2]),
@@ -1330,13 +1590,14 @@ def _gqa_contiguous(lp, x, wpos, n_valid, c, cfg):
     return _heads_out(lp, o, cfg), {"k": kc, "v": vc}
 
 
-def _gqa_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel):
+def _gqa_paged(lp, x, c, wpos, w):
     """A gqa layer over its pages. The decode tick (``kernel``) is one fused
     launch: the token's K/V row scattered and the live pages folded, four
     query heads a KV head's block. A window writes its rows through the
     block table (padding lanes and idle rows to trash page 0), gathers its
     row's pages and masks."""
     from ...ops.paged_attention import paged_attention_gqa, split_kv
+    cfg, pos, n_valid, bt, page, kernel = w[:6]
     B = x.shape[0]
     _, Hkv, hd = dims(cfg)
     q, k, v = _gqa_qkv(lp, x, wpos, cfg)
@@ -1350,6 +1611,44 @@ def _gqa_paged(lp, x, wpos, pos, n_valid, c, bt, cfg, page, kernel):
         B, Hkv, L, 2 * hd))
     o = _masked_attention(q, kc, vc, _causal(wpos, L), jnp.max(wpos))
     return _heads_out(lp, o, cfg), {"kv": kv}
+
+
+# ---- the kinds ---------------------------------------------------------------
+
+#: the mixer kinds ``cfg.mixers`` may name, a record each (:class:`Mixer`)
+KINDS: Dict[str, Mixer] = {
+    "lightning": Mixer(
+        init=_lightning_init,
+        **_slot_kind(_lightning_rows, _lightning_layer)),
+    "sparse": Mixer(
+        check=_sparse_check,
+        init=lambda cfg, rng: _gated_attention_init(cfg, rng, dims(cfg)[1]),
+        cache=_sparse_cache, pool=_sparse_pool,
+        contiguous=_sparse_contiguous, paged=_sparse_paged,
+        # selection and the compressed keys are laid out by the block
+        page=lambda cfg: cfg.sparse.block_size, counts=_SelectCounts),
+    "kda": Mixer(
+        check=_kda_check, init=_kda_init,
+        **_slot_kind(_kda_rows, _kda_layer),
+        labels=("kda", "kda_window"), counts=TickCounts),
+    "mla": Mixer(
+        check=_mla_check, init=_mla_init, cache=_mla_cache, pool=_mla_pool,
+        contiguous=_mla_contiguous, paged=_mla_paged,
+        labels=("latent", "latent_window"), counts=_LatentCounts),
+    "conv": Mixer(
+        check=_conv_check, init=_conv_init,
+        **_slot_kind(_conv_rows, _conv_layer),
+        labels=("conv", "conv"), counts=TickCounts),
+    "gqa": Mixer(
+        check=_gqa_check, init=_gqa_init, cache=_gqa_cache, pool=_kv_pages,
+        contiguous=_gqa_contiguous, paged=_gqa_paged,
+        labels=("gqa", "gqa_window"), counts=TickCounts),
+    "ssm": Mixer(
+        check=_ssm_check, init=_ssm_init,
+        **_slot_kind(_ssm_rows, _ssm_layer),
+        labels=("ssm", "ssm_window"), counts=_StateCounts),
+}
+MIXERS = tuple(KINDS)
 
 
 # ---- the window -------------------------------------------------------------
@@ -1436,29 +1735,10 @@ def window_contiguous(params: Dict, tokens, pos, cache, cfg, *,
     check_config(cfg)
     pos, n_valid = _lanes(tokens, pos, n_valid, active)
     new_cache = [None] * cfg.layers
+    w = Window(cfg, pos, n_valid)
 
     def mixer(i, kind, lp, x, wpos):
-        c = cache[i]
-        if kind == "lightning":
-            q, k, v = _lightning_qkv(lp, x, wpos, cfg)
-            o, st = lightning_chunk(q, k, v, _fresh(c["state"], pos, n_valid),
-                                    n_valid)
-            new_cache[i] = {"state": st}
-            return _gated_out(lp, x, o, cfg, norm=True)
-        if kind == "kda":
-            y, new_cache[i] = _kda_layer(lp, x, c, pos, n_valid, cfg, False)
-        elif kind == "ssm":
-            y, new_cache[i] = _ssm_layer(lp, x, c, pos, n_valid, cfg, False)
-        elif kind == "mla":
-            y, new_cache[i] = _mla_contiguous(lp, x, wpos, n_valid, c, cfg)
-        elif kind == "conv":
-            y, tail = _conv_layer(lp, x, c["conv"], pos, n_valid, cfg, False)
-            new_cache[i] = {"conv": tail}
-        elif kind == "gqa":
-            y, new_cache[i] = _gqa_contiguous(lp, x, wpos, n_valid, c, cfg)
-        else:
-            y, new_cache[i] = _sparse_contiguous(lp, x, wpos, pos, n_valid,
-                                                 c, cfg)
+        y, new_cache[i] = KINDS[kind].contiguous(lp, x, cache[i], wpos, w)
         return y
 
     hidden = _window(params, tokens, pos, cfg, n_valid, mixer, last_only)
@@ -1473,55 +1753,12 @@ def _paged_mixer(cfg, bufs, new_bufs, block_tables, pos, n_valid, page_size,
     ``kernel``: the Pallas decode kernels (one token a row); ``slot``: the
     state row of a one-row prefill window; ``tick``: one token a row, so a
     conv layer shifts its tails with no slice a row."""
-    from ...ops.lightning_attention import lightning_decode_step
+    w = Window(cfg, pos, n_valid, block_tables, page_size, kernel, slot, tick)
 
     def mixer(i, kind, lp, x, wpos):
         c = bufs[i] if new_bufs[i] is None else new_bufs[i]
-        if kind == "sparse":
-            y, new_bufs[i] = _sparse_paged(lp, x, wpos, pos, n_valid, c,
-                                           block_tables, cfg, page_size,
-                                           kernel, slot)
-            return y
-        if kind == "mla":
-            y, new_bufs[i] = _mla_paged(lp, x, wpos, pos, n_valid, c,
-                                        block_tables, cfg, page_size, kernel)
-            return y
-        if kind == "gqa":
-            y, new_bufs[i] = _gqa_paged(lp, x, wpos, pos, n_valid, c,
-                                        block_tables, cfg, page_size, kernel)
-            return y
-        if kind in ("kda", "conv", "ssm"):
-            rows = c if slot is None else {
-                kk: jax.lax.dynamic_slice_in_dim(c[kk], slot, 1, axis=0)
-                for kk in c}
-            if kind == "kda":
-                y, new = _kda_layer(lp, x, rows, pos, n_valid, cfg, kernel)
-            elif kind == "ssm":
-                y, new = _ssm_layer(lp, x, rows, pos, n_valid, cfg, kernel)
-            else:
-                y, tail = _conv_layer(lp, x, rows["conv"], pos, n_valid, cfg,
-                                      tick)
-                new = {"conv": tail}
-            new_bufs[i] = new if slot is None else {
-                kk: jax.lax.dynamic_update_slice_in_dim(c[kk], new[kk], slot,
-                                                        axis=0) for kk in c}
-            return y
-        q, k, v = _lightning_qkv(lp, x, wpos, cfg)
-        rows = (c["state"] if slot is None else
-                jax.lax.dynamic_slice_in_dim(c["state"], slot, 1, axis=0))
-        if kernel:
-            # a decoding row is never at position 0 (a prompt has a token),
-            # so the tick needs no reset and no pass over the states for one
-            o, st = lightning_decode_step(q[:, :, 0], k[:, :, 0], v[:, :, 0],
-                                          rows, n_valid > 0)
-            o = o[:, :, None]
-        else:
-            o, st = lightning_chunk(q, k, v, _fresh(rows, pos, n_valid),
-                                    n_valid)
-        new_bufs[i] = {"state": st if slot is None else
-                       jax.lax.dynamic_update_slice_in_dim(
-                           c["state"], st, slot, axis=0)}
-        return _gated_out(lp, x, o, cfg, norm=True)
+        y, new_bufs[i] = KINDS[kind].paged(lp, x, c, wpos, w)
+        return y
 
     return mixer
 
@@ -1591,20 +1828,3 @@ def tick_with_window(params: Dict, tokens, pos, bufs, block_tables, cfg, *,
         hidden, S + jnp.maximum(n_chunk[0] - 1, 0), 1, axis=0)
     logits = head(params, jnp.concatenate([hidden[:S], last], axis=0))
     return logits[:S], logits[S:], new_bufs
-
-
-def select_walks(cfg: TransformerConfig, page_size: int, pages_a_slot: int,
-                 positions: int):
-    """The lengths, in pages, of the two lists :func:`_selected_decode` hands
-    the selected-block kernel for each (row, KV head) of a sparse layer's
-    tick: ``(the top-k walk's, the dense walk's)``, the second the longer and
-    taken while a row under ``dense_len`` holds more blocks than the first
-    lists. Here, below every traced function (a Pallas program's cache key
-    holds the line numbers of its callers), for the pool's count of the walk
-    (``PagedKVPool.note_select_walk``)."""
-    sp = cfg.sparse
-    pp = sp.block_size // page_size                 # pages a block
-    scored = -(-positions // sp.kernel_stride)      # compressed keys a slot
-    K = min(sp.topk, -(-scored // (sp.block_size // sp.kernel_stride)))
-    n_dense = min(-(-sp.dense_len // sp.block_size), -(-pages_a_slot // pp))
-    return K * pp, max(K, n_dense) * pp

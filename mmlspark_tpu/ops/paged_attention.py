@@ -873,8 +873,8 @@ def select_block(page_bytes: int, n_sel: int) -> int:
     """Listed pages a grid step of the selected-block walk folds, from what
     a call can see of its shapes: the largest of 8, 4, 2, 1 that divides the
     list's ``n_sel`` entries and whose pages, ``page_bytes`` a head's slice
-    of one, :data:`_SELECT_BLOCK_BYTES` hold. The pool counts the walk by
-    the same rule (``PagedKVPool.note_select_walk``)."""
+    of one, :data:`_SELECT_BLOCK_BYTES` hold. The host counts the walk by
+    the same rule (``models/zoo/hybrid.py`` ``_SelectCounts``)."""
     return next(k for k in (8, 4, 2, 1) if n_sel % k == 0
                 and (k == 1 or k * page_bytes <= _SELECT_BLOCK_BYTES))
 
@@ -1234,8 +1234,8 @@ def latent_block(page_bytes: int, pages_a_slot: int) -> int:
     """Pages a grid step of the latent sweep folds, from what a call can
     see of its shapes: as many as :data:`_LATENT_BLOCK_BYTES` hold, a slot's
     block table at least :data:`_LATENT_BLOCKS_A_SLOT` blocks wide, at least
-    one. The pool counts the sweep by the same rule
-    (``PagedKVPool.note_latent_sweep``)."""
+    one. The host counts the sweep by the same rule
+    (``models/zoo/hybrid.py`` ``_LatentCounts``)."""
     return max(1, min(_LATENT_BLOCK_BYTES // page_bytes,
                       pages_a_slot // _LATENT_BLOCKS_A_SLOT))
 

@@ -84,7 +84,8 @@ from ..models.zoo.transformer import (TransformerConfig,
                                       paged_scatter_rows,
                                       prefill_cache, shardings_for)
 from ..models.zoo.hybrid import SLOT_KEYS as _SLOT_KEYS
-from ..models.zoo.hybrid import tick_with_window
+from ..models.zoo.hybrid import (Geometry, accountants, check_config,
+                                 required_page, tick_with_window)
 from ..ops.padding import bucket_size
 from ..ops.paged_attention import (resolve_impl as _resolve_paged_attn,
                                    _auto_interpret as _pa_auto_interpret)
@@ -668,13 +669,11 @@ def derived_page_size(cfg: TransformerConfig, max_len: int) -> int:
     a slot at ``max_len``, held to ``[16, 256]`` tokens. The page is how
     many keys one grid step of the decode kernel folds, and the kernel's
     time is its count of steps: a block table 64 wide cost GPT-2 XL three
-    quarters of its tick (PERF.md section 6, PR 32). A model with sparse
-    layers keeps the page at its sparse block, which selection and the
-    compressed keys are laid out by; any other paged layer of a hybrid
-    model (gqa's K beside V, mla's latent rows) is a dense pool."""
-    if "sparse" in cfg.mixers:
-        return cfg.sparse.block_size
-    return min(256, max(16, bucket_size(int(max_len)) // 16))
+    quarters of its tick (PERF.md section 6, PR 32). A hybrid model whose
+    layer kinds require a page (a sparse layer's block) keeps it; any other
+    paged layer (K beside V, latent rows) is a dense pool."""
+    return required_page(cfg) or min(
+        256, max(16, bucket_size(int(max_len)) // 16))
 
 
 class ContinuousDecoder:
@@ -720,7 +719,6 @@ class ContinuousDecoder:
         #: insertion and prefix store; what it cannot do yet it refuses here
         self._hybrid = bool(cfg.mixers)
         if self._hybrid:
-            from ..models.zoo.hybrid import check_config
             from ..ops.kv_quant import resolve_kv_dtype
             check_config(cfg)
             for given, why in (
@@ -965,6 +963,11 @@ class ContinuousDecoder:
                                sharding=pool_sharding, slots=self._S,
                                slot_positions=self._P_max * self._page,
                                max_snapshots=int(prefix_cache_size))
+        #: the host accounting of the model's layer kinds (none for the
+        #: dense block): their counts of a decode dispatch and of a prefill
+        #: window go to ``self._kv.note`` inside ``decoder.account``
+        self._accountants = accountants(
+            cfg, Geometry(self._page, self._P_max, impl == "kernel"))
         self._chunk = int(prefill_chunk)
         self._defrag_thr = (max(1, self._kv.num_pages // 4)
                             if defrag_threshold is None
@@ -2113,8 +2116,8 @@ class ContinuousDecoder:
                 self._attn_impl,
                 gather_bytes=(self._gather_bytes_extend
                               if self._attn_impl == "gather" else 0))
-            self._note_sparse_ticks(off + w)
-            self._kv.note_latent_window(off, w)
+            for kind in self._accountants:
+                self._kv.note(kind.window(off, w))
             self._note_sweep([off], ids.shape[1], 1, 1)
             self._kv.note_prefill_chunk(w, riding=riding)
         off += w
@@ -2154,40 +2157,6 @@ class ContinuousDecoder:
             for j in range(calls):
                 self._kv.note_grid_steps([pos + j for pos in positions],
                                          window, rows)
-
-    def _note_kernel_walks(self, positions, rows: int, calls: int) -> None:
-        """The grid steps of ``calls`` successive decode calls of the absorbed
-        latent and the selected-block kernel (pool ``latent_sweep_*`` /
-        ``select_walk_*``). Nine lines: a kernel's key holds its callers'."""
-        if self._attn_impl == "kernel" and self._hybrid:
-            for j in range(calls):      # a row at pos attends pos + 1 keys
-                at = [pos + j for pos in positions]
-                self._kv.note_latent_sweep([pos + 1 for pos in at], rows)
-                self._kv.note_select_walk(at, rows)
-
-    def _note_sparse_ticks(self, context: int, calls: int = 1) -> None:
-        """A model with sparse-attention layers counts each paged call a
-        second time, by path: ``sparse`` when the longest context it served
-        was past ``dense_len`` (blocks were selected), else ``dense``."""
-        sp = self._cfg.sparse
-        if self._hybrid and sp is not None:
-            self._kv.note_attn_tick(
-                "sparse" if context > sp.dense_len else "dense", calls=calls)
-
-    def _note_mixer_ticks(self, calls: int) -> None:
-        """A model with kda, mla, gqa, ssm or conv layers counts each decode
-        call once more for each, by the path its tick ran: ``kda`` /
-        ``latent`` / ``gqa`` / ``ssm`` (the Pallas step, the absorbed kernel,
-        the grouped-query kernel, the state-space step) or ``kda_window`` /
-        ``latent_window`` / ``gqa_window`` / ``ssm_window`` (the chunked
-        form, the expanded attention, the gathered pages and the chunked
-        scan, under ``gather``); ``conv`` has the one path."""
-        off = "" if self._attn_impl == "kernel" else "_window"
-        for mixer, label in (("kda", "kda" + off), ("mla", "latent" + off),
-                             ("gqa", "gqa" + off), ("conv", "conv"),
-                             ("ssm", "ssm" + off)):
-            if mixer in self._cfg.mixers:
-                self._kv.note_attn_tick(label, calls=calls)
 
     def _note_token(self, req: _Request, tok: int):
         now = time.perf_counter()
@@ -2333,11 +2302,6 @@ class ContinuousDecoder:
                 self._attn_impl, calls=self._k,
                 gather_bytes=(self._k * self._gather_bytes_tick
                               if self._attn_impl == "gather" else 0))
-            self._note_sparse_ticks(
-                max(self._slot_req[i].prompt.size
-                    + len(self._slot_req[i].tokens)
-                    for i in decode_live), calls=self._k)
-            self._note_mixer_ticks(self._k)
             # a row's device position: its drained tokens plus those of
             # the blocks still in flight (a first-token block carries one,
             # a tick's k; this tick's is not yet pending)
@@ -2350,9 +2314,15 @@ class ContinuousDecoder:
             self._note_sweep(positions,
                              self._gamma + 1 if self._spec else 1, self._S,
                              self._k)
-            self._note_kernel_walks(positions, self._S, self._k)
-            if self._attn_impl == "kernel":
-                self._kv.note_ssm_step(len(decode_live), self._k)
+            if self._accountants:
+                # the longest context served, as drained
+                context = max(self._slot_req[i].prompt.size
+                              + len(self._slot_req[i].tokens)
+                              for i in decode_live)
+                for j in range(self._k):    # each call a position on
+                    at = [pos + j for pos in positions]
+                    for kind in self._accountants:
+                        self._kv.note(kind.decode(at, self._S, context))
             # snapshot slot→REQUEST (not indices): by the time this block
             # is drained, a slot may have been freed and re-admitted;
             # tokens must go to the request that occupied the slot at

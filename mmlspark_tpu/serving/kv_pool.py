@@ -50,9 +50,11 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..core.residency import get_residency_manager
+from ..models.zoo.hybrid import SLOT_KEYS, pool_shapes
 from ..observability import (charge as _ledger_charge,
                              counter as _metric_counter,
                              gauge as _metric_gauge)
+from ..parallel.moe import MOE_STATS
 
 __all__ = ["PagedKVPool", "PoolExhausted", "KVAutotuner", "prefix_hash",
            "AFFINITY_HEADER", "affinity_headers"]
@@ -180,6 +182,17 @@ M_STATE_SNAPSHOT_BYTES = _metric_counter(
     labelnames=("event",))
 
 
+#: the gauge that follows a stat :meth:`PagedKVPool.note` adds to
+_GAUGES = {"latent_window_keys": M_LATENT_WINDOW_KEYS,
+           "latent_sweep_pages": M_LATENT_SWEEP_PAGES,
+           "latent_sweep_steps": M_LATENT_SWEEP_STEPS,
+           "select_walk_pages": M_SELECT_WALK_PAGES,
+           "select_walk_steps": M_SELECT_WALK_STEPS,
+           "ssm_state_rows": M_SSM_STATE_ROWS}
+#: the stats that count paged calls by implementation or path
+_TICKS = "attn_ticks_"
+
+
 def prefix_hash(tokens: Sequence[int]) -> str:
     """Stable content hash for a prompt prefix (the prefix-registry key)."""
     h = hashlib.sha1()
@@ -239,22 +252,17 @@ class PagedKVPool:
         #: layer's is a float32 state row a slot, no pages; a cached prefix
         #: is then pages plus a snapshot of those rows (``register_prefix``)
         self.hybrid = bool(getattr(cfg, "mixers", ()))
+        heads, hd = cfg.heads, cfg.d_model // cfg.heads
+        self._layer_shapes = None
         if self.hybrid:
-            from ..models.zoo.hybrid import (SLOT_KEYS, dims, pool_shapes,
-                                             select_walks, window_tile)
-            from ..ops.paged_attention import latent_block
             if kv_dtype is not None or sharding is not None:
                 raise ValueError("a hybrid decoder's pool is bf16 pages "
                                  "(K beside V of a sparse or gqa layer, or "
                                  "an mla layer's latent rows) on one device "
                                  "(no kv_dtype, no mesh)")
-            _, heads, hd = dims(cfg)
             self._layer_shapes = pool_shapes(
                 cfg, self.num_pages, self.page_size, int(slots),
                 int(slot_positions))
-        else:
-            heads, hd = cfg.heads, cfg.d_model // cfg.heads
-            self._layer_shapes = None
         #: device bytes of one prefix's state snapshot, and how many the
         #: engine's prefix store may hold (the reservation counts them)
         self.snapshot_bytes = sum(
@@ -262,42 +270,6 @@ class PagedKVPool:
             for layer in self._layer_shapes or ()
             for key, (shape, dt) in layer.items() if key in SLOT_KEYS)
         self.max_snapshots = int(max_snapshots)
-        #: keys a prefill window over latent pages folds at a time (0: the
-        #: model has no mla layer): what ``latent_window_keys`` counts by
-        self.latent_tile = (
-            self.page_size * window_tile(
-                self.page_size, self.pages_per_slot(slot_positions))
-            if self.hybrid and "mla" in cfg.mixers else 0)
-        #: the absorbed kernel's sweep as the scheduler counts it: the mla
-        #: layers (a call each a tick) and the pages a grid step folds, by
-        #: the rule the call itself reads its shapes with
-        mla = [layer["kv"] for layer, kind in zip(
-            self._layer_shapes or (), getattr(cfg, "mixers", ()))
-            if kind == "mla"]
-        self.latent_calls = len(mla)
-        self.latent_block = latent_block(
-            int(np.prod(mla[0][0][1:])) * jnp.dtype(mla[0][1]).itemsize,
-            self.pages_per_slot(slot_positions)) if mla else 0
-        #: the selected-block kernel's walk as the scheduler counts it: the
-        #: sparse layers (a call each a tick), the pages of the two lists a
-        #: call walks for a (row, KV head) (the top-k walk's, the dense
-        #: walk's) and the grid steps it walks each in, by the rule the call
-        #: itself reads its shapes with
-        self.select_calls = sum(
-            kind == "sparse" for kind in getattr(cfg, "mixers", ()) or ())
-        self.select_walks = self.select_steps = ()
-        if self.select_calls:
-            from ..ops.paged_attention import select_block
-            head_slice = (self.page_size * 2 * hd
-                          * jnp.dtype(cfg.dtype).itemsize)
-            self.select_walks = select_walks(
-                cfg, self.page_size, self.pages_per_slot(slot_positions),
-                int(slot_positions))
-            self.select_steps = tuple(n // select_block(head_slice, n)
-                                      for n in self.select_walks)
-        #: the state-space layers (a call of the step each a tick)
-        self.ssm_calls = sum(
-            kind == "ssm" for kind in getattr(cfg, "mixers", ()) or ())
         #: one K or V page as a session blob carries it, (H, page, hd);
         #: the pool's buffer packs the two side by side on the minor axis
         self._page_shape = (heads, self.page_size, hd)
@@ -758,77 +730,18 @@ class PagedKVPool:
         M_PREFILL_CHUNKS_RIDING_SHARE.set(
             self.stats["prefill_chunks_riding"] / self.stats["prefill_chunks"])
 
-    def note_latent_window(self, offset: int, lanes: int) -> None:
-        """A prefill window of ``lanes`` real tokens at ``offset`` of a model
-        with latent pages (else nothing), folded :attr:`latent_tile` keys at
-        a time: ``latent_window_keys`` is what it attended over (whole tiles
-        up to its last key), ``latent_window_context`` the keys it had to
-        rebuild (``offset + lanes``) and ``latent_window_pairs`` the (query,
-        key) pairs its causal mask lets through: what the mathematics needs
-        of it."""
-        tile = self.latent_tile
-        if not tile:
-            return
-        last = offset + lanes - 1
-        self.stats["latent_window_keys"] += (last // tile + 1) * tile
-        self.stats["latent_window_context"] += offset + lanes
-        self.stats["latent_window_pairs"] += (lanes * offset
-                                              + lanes * (lanes + 1) // 2)
-        M_LATENT_WINDOW_KEYS.set(self.stats["latent_window_keys"])
-
-    def note_latent_sweep(self, lengths: Sequence[int], rows: int) -> None:
-        """Account the absorbed latent kernel's sweep of one decode call of
-        a model with mla layers (else nothing), from the scheduler's
-        numbers: a live row of ``lengths[i]`` cached keys needs the pages
-        that hold them and sweeps them :attr:`latent_block` a grid step,
-        each of the call's other ``rows`` one step; every mla layer's call
-        sweeps the same. ``latent_sweep_pages / latent_sweep_steps`` is how
-        full the blocks ran."""
-        if not self.latent_calls:
-            return
-        pages = [-(-n // self.page_size) for n in lengths]
-        self.stats["latent_sweep_pages"] += self.latent_calls * sum(pages)
-        self.stats["latent_sweep_steps"] += self.latent_calls * (
-            rows - len(pages)
-            + sum(max(1, -(-p // self.latent_block)) for p in pages))
-        M_LATENT_SWEEP_PAGES.set(self.stats["latent_sweep_pages"])
-        M_LATENT_SWEEP_STEPS.set(self.stats["latent_sweep_steps"])
-
-    def note_select_walk(self, positions: Sequence[int], rows: int) -> None:
-        """Account the selected-block kernel's walk of one decode call of a
-        model with sparse layers (else nothing), from the scheduler's
-        numbers: for each of its KV heads a live row at ``positions[i]``
-        lists the pages of ``min(topk, its blocks so far)`` blocks, or of
-        every block so far while it is under ``dense_len``
-        (``hybrid._selected_decode``); the call walks the lists of all its
-        ``rows``, an idle row's empty one too, a block of entries a grid
-        step, and walks the dense lists while a row under ``dense_len``
-        holds more blocks than the top-k walk lists; every sparse layer's
-        call walks the same. ``select_walk_pages / select_walk_steps`` is
-        how full the blocks ran."""
-        if not self.select_calls:
-            return
-        sp = self.cfg.sparse
-        pp = sp.block_size // self.page_size
-        short = self.select_walks[0]
-        listed = [(pos // sp.block_size + 1) * pp for pos in positions]
-        dense = [pos + 1 <= sp.dense_len for pos in positions]
-        widened = any(d and n > short for d, n in zip(dense, listed))
-        calls = self.select_calls * self._page_shape[0]      # x KV heads
-        self.stats["select_walk_pages"] += calls * sum(
-            n if d else min(n, short) for d, n in zip(dense, listed))
-        self.stats["select_walk_steps"] += (calls * rows
-                                            * self.select_steps[widened])
-        M_SELECT_WALK_PAGES.set(self.stats["select_walk_pages"])
-        M_SELECT_WALK_STEPS.set(self.stats["select_walk_steps"])
-
-    def note_ssm_step(self, rows: int, calls: int = 1) -> None:
-        """Account ``calls`` decode calls of a model with ssm layers (else
-        nothing): each of the ``rows`` live rows' states is read and written
-        once an ssm layer a call."""
-        if self.ssm_calls:
-            self.stats["ssm_state_rows"] += self.ssm_calls * rows * calls
-            M_SSM_STATE_ROWS.set(self.stats["ssm_state_rows"])
+    def note(self, increments: Dict[str, int]) -> None:
+        """Add named increments to :attr:`stats`: the one door for counts
+        made outside the pool, from the scheduler's numbers (a layer kind's
+        host accounting, ``models/zoo/hybrid.py`` ``accountants``). A name
+        with a gauge (:data:`_GAUGES`) sets it; ``attn_ticks_<impl>`` also
+        moves the kernel-ticks counter under that label."""
+        for name, n in increments.items():
+            total = self.stats[name] = self.stats.get(name, 0) + n
+            if name in _GAUGES:
+                _GAUGES[name].set(total)
+            elif name.startswith(_TICKS):
+                M_KERNEL_TICKS.inc(n, impl=name[len(_TICKS):])
 
     def note_attn_tick(self, impl: str, *, calls: int = 1,
                        gather_bytes: int = 0) -> None:
@@ -836,9 +749,7 @@ class PagedKVPool:
         window invocations under ``impl`` ("kernel" or "gather"), plus the
         HBM bytes the gather impl moved materializing contiguous K/V
         (always 0 under the kernel — it reads pages in place)."""
-        key = f"attn_ticks_{impl}"
-        self.stats[key] = self.stats.get(key, 0) + calls
-        M_KERNEL_TICKS.inc(calls, impl=impl)
+        self.note({_TICKS + impl: calls})
         if gather_bytes:
             self.stats["gather_bytes"] += gather_bytes
             M_GATHER_BYTES.inc(gather_bytes)
@@ -847,7 +758,6 @@ class PagedKVPool:
         """Account the routing counts of drained decode steps: ``counts``
         (steps, 8) in ``parallel.moe.MOE_STATS``' order, as the tick carried
         them out beside its tokens (no device read of their own)."""
-        from ..parallel.moe import MOE_STATS
         for name, n in zip(MOE_STATS, np.asarray(counts).sum(axis=0)):
             key = "moe_" + name
             self.stats[key] = self.stats.get(key, 0) + int(n)
@@ -869,15 +779,6 @@ class PagedKVPool:
                                / self.stats["grid_steps_dense"])
 
     # -- kernel page-layout contract -----------------------------------------
-
-    @staticmethod
-    def kernel_page_multiple(dtype) -> int:
-        """Sublane tile the Pallas paged-attention kernel needs
-        ``page_size`` to be a multiple of on a real TPU: 8 (f32),
-        16 (bf16), 32 (int8) — the page dimension sits in the sublane
-        slot of the kernel's ``(1, heads, page, 2*head_dim)`` blocks."""
-        from ..ops.paged_attention import sublane_multiple
-        return sublane_multiple(dtype)
 
     @classmethod
     def kernel_aligned_page_size(cls, page_size: int, dtype) -> int:

@@ -276,3 +276,96 @@ def test_a_decoder_names_the_form_it_got(width, form):
     assert dec.stats["embed_read"] == form
     for f in ("gather", "in_place"):
         assert continuous._M_EMBED_READ.labels(form=f).get() == (f == form)
+
+
+# ---- the third seam: a mixer kind is one record -------------------------------
+
+#: every kind's own configuration beside the hybrid block's, at toy widths
+EVERY = HYBRID._replace(
+    max_len=48, kda=T.DeltaRule(conv_kernel=3),
+    latent=T.LatentAttention(latent=32, nope=16, rope=8, value=16),
+    conv=T.ShortConv(taps=3),
+    ssm=T.StateSpace(heads=4, head_dim=16, state=16, groups=2, taps=3,
+                     chunk=8))
+#: the label a kind's decode calls count under, on the Pallas decode kernels
+LABELS = {"lightning": None, "sparse": "dense", "kda": "kda", "mla": "latent",
+          "conv": "conv", "gqa": "gqa", "ssm": "ssm"}
+
+
+def test_the_registry_names_the_kinds_in_their_order():
+    assert hybrid.MIXERS == tuple(hybrid.KINDS) == tuple(LABELS)
+
+
+@pytest.mark.parametrize("kind", list(LABELS))
+def test_a_kind_is_one_complete_record(kind):
+    """A model of the kind alone is checked, built, cached, pooled, ticked
+    and counted from the kind's record: the cache and the pool hold the same
+    rows a slot and pages where the cache holds K and V, and an engine's
+    decode calls count under the kind's label."""
+    from mmlspark_tpu.serving.continuous import ContinuousDecoder
+    record = hybrid.KINDS[kind]
+    for field in ("init", "cache", "pool", "contiguous", "paged"):
+        assert callable(getattr(record, field)), field
+    cfg = EVERY._replace(layers=2, mixers=(kind,) * 2)
+    hybrid.check_config(cfg)
+    cache = hybrid.init_hybrid_cache(cfg, 2, 48)
+    pool = hybrid.pool_shapes(cfg, 13, 8, 2, 48)
+    for c, p in zip(cache, pool):
+        rows = set(c) & set(hybrid.SLOT_KEYS)
+        assert rows == set(p) & set(hybrid.SLOT_KEYS)
+        assert ("kv" in p) == bool(set(c) - rows)
+        for key in rows - {"ck"}:       # the scorer's keys follow the length
+            assert c[key].shape == p[key][0] and c[key].dtype == p[key][1]
+    params = hybrid.init_hybrid(cfg, 1)
+    assert all(set(record.init(cfg, np.random.default_rng(0))) <= set(lp)
+               for lp in params["layers"])
+    dec = ContinuousDecoder(params, cfg, max_slots=2, max_len=48,
+                            prefill_chunk=16)
+    assert dec._page == (hybrid.required_page(cfg) or 16)
+    req = dec.submit(_tokens(cfg, 20), 3)
+    while not req.done:
+        dec.step()
+    assert len(req.tokens) == 3
+    ticks = {k: v for k, v in dec._kv.stats.items()
+             if k.startswith("attn_ticks_")
+             and k not in ("attn_ticks_kernel", "attn_ticks_gather")}
+    label = LABELS[kind]
+    assert set(ticks) == ({"attn_ticks_" + label} if label else set())
+    if kind != "sparse":                # a sparse model counts windows too
+        assert all(n == dec.stats["ticks"] for n in ticks.values())
+    assert [type(a) for a in dec._accountants] == (
+        [record.counts] if record.counts else [])
+
+
+@pytest.mark.parametrize("module", ["kv_pool", "continuous"])
+def test_the_server_names_no_kind(module):
+    """The page pool and the scheduler read records: neither holds a kind's
+    name as a string, reads a kind's configuration, or imports more of the
+    model's block and its kernels than the allocation, the programs and the
+    page-alignment helpers."""
+    import ast
+    import mmlspark_tpu.serving as serving
+    path = f"{serving.__path__[0]}/{module}.py"
+    allowed = {
+        ("kv_pool", "hybrid"): {"SLOT_KEYS", "pool_shapes"},
+        ("kv_pool", "paged_attention"): {"sublane_multiple",
+                                         "aligned_page_size"},
+        ("continuous", "hybrid"): {"SLOT_KEYS", "Geometry", "accountants",
+                                   "check_config", "required_page",
+                                   "tick_with_window"},
+        ("continuous", "paged_attention"): {"resolve_impl",
+                                            "_auto_interpret"},
+    }
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Constant) and isinstance(node.value, str):
+            assert node.value not in hybrid.KINDS, (node.lineno, node.value)
+        if isinstance(node, ast.Attribute):
+            assert node.attr not in ("sparse", "latent", "kda", "conv",
+                                     "ssm"), (node.lineno, node.attr)
+        if isinstance(node, ast.ImportFrom) and node.module:
+            source = node.module.rsplit(".", 1)[-1]
+            if source in ("hybrid", "paged_attention"):
+                names = {a.name for a in node.names}
+                assert names <= allowed[module, source], (node.lineno, names)

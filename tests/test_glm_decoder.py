@@ -150,7 +150,8 @@ def test_the_mixer_alone_matches_the_reference(params, sizes, cfg):
     n = jnp.full(2, 45, jnp.int32)
     wpos = jnp.zeros((2, 1), jnp.int32) + jnp.arange(45)
     cache = hybrid.init_hybrid_cache(cfg, 2, 48)[1]
-    got, _ = hybrid._mla_contiguous(lp, x, wpos, n, cache, cfg)
+    got, _ = hybrid.KINDS["mla"].contiguous(
+        lp, x, cache, wpos, hybrid.Window(cfg, wpos[:, 0], n))
     for b in range(2):
         ref = REFERENCE.mla(x[b], lp, REFERENCE.shape_of(sizes), lambda a: a)
         assert np.abs(np.asarray(got[b] - ref)).max() < 1e-5
@@ -316,12 +317,15 @@ def test_the_pool_counts_the_sweep_by_the_calls_own_rule(cfg):
     pool = PagedKVPool(cfg, num_pages=80, page_size=8, slots=8,
                        slot_positions=512)
     layers = cfg.mixers.count("mla")
-    assert (pool.latent_calls, pool.latent_block) == (layers, 4)
-    pool.note_latent_sweep([1, 8, 9, 33, 100, 0], rows=8)
-    # pages 1, 1, 2, 5, 13, 0; steps 1, 1, 1, 2, 4, 1 and two idle rows
+    sweep, = hybrid.accountants(cfg, hybrid.Geometry(8, 64, True))
+    assert (sweep.layers, sweep.block) == (layers, 4)
+    # a row at position p attends p + 1 keys; an empty row sweeps one step
+    # as an idle one does
+    pool.note(sweep.decode([0, 7, 8, 32, 99], rows=8, context=100))
+    # pages 1, 1, 2, 5, 13; steps 1, 1, 1, 2, 4 and three idle rows
     assert pool.stats["latent_sweep_pages"] == layers * 22
     assert pool.stats["latent_sweep_steps"] == layers * 12
-    pool.note_latent_sweep([512] * 8, rows=8)
+    pool.note(sweep.decode([511] * 8, rows=8, context=512))
     assert pool.stats["latent_sweep_pages"] == layers * (22 + 8 * 64)
     assert pool.stats["latent_sweep_steps"] == layers * (12 + 8 * 16)
 
@@ -387,7 +391,8 @@ def test_decoder_equals_the_reference_with_slots_reused(params, cfg, sizes,
     # attends over one tile; context and pairs are the causal sums
     windows = [(off, min(32, len(p) - off))
                for p in prompts for off in range(0, len(p), 32)]
-    assert decoder._kv.latent_tile == 224
+    sweep, = decoder._accountants
+    assert sweep.tile == 224
     assert stats["latent_window_keys"] == 224 * len(windows)
     assert stats["latent_window_context"] == sum(o + w for o, w in windows)
     assert stats["latent_window_pairs"] == sum(
@@ -399,7 +404,7 @@ def test_decoder_equals_the_reference_with_slots_reused(params, cfg, sizes,
     # was drained one key more); 28 pages a slot are swept a page a step,
     # every row of a tick at least one step
     pool = decoder._kv
-    assert (pool.latent_calls, pool.latent_block) == (4, 1)
+    assert (sweep.layers, sweep.block) == (4, 1)
     def pages(j):
         return 4 * sum(-(-(len(p) + j) // 8) for p in prompts)
     if impl == "kernel":

@@ -465,9 +465,9 @@ def test_the_pool_counts_the_walk_by_the_calls_own_rule(cfg, name):
     (positions, rows), (pages, n_sel, k) = WALKS[name]
     pool = PagedKVPool(cfg, num_pages=11, page_size=8, residency=False,
                        slots=3, slot_positions=256)
-    assert (pool.select_calls, pool.select_walks) == (2, (6, 8))
-    assert pool.select_steps == (3, 1)
-    pool.note_select_walk(positions, rows)
+    walk, = hybrid.accountants(cfg, hybrid.Geometry(8, 32, True))
+    assert (walk.layers, walk.walks, walk.steps) == (2, (6, 8), (3, 1))
+    pool.note(walk.decode(positions, rows, context=max(positions) + 1))
     stats = pool.stats
     assert stats["select_walk_pages"] == 2 * 2 * pages
     assert stats["select_walk_steps"] == 2 * 2 * rows * (n_sel // k)
@@ -477,7 +477,7 @@ def test_the_pool_counts_the_walk_by_the_calls_own_rule(cfg, name):
 
 def test_the_pools_walks_are_the_lists_the_tick_hands_the_kernel(
         cfg, monkeypatch):
-    """``hybrid.select_walks`` and ``select_block`` against what
+    """The sparse kind's accountant and ``select_block`` against what
     ``_selected_decode`` traces: both branches of its ``lax.cond``, the
     top-k walk and the dense walk, reach the launch with the pool's numbers."""
     import mmlspark_tpu.ops.paged_attention as pa
@@ -500,7 +500,7 @@ def test_the_pools_walks_are_the_lists_the_tick_hands_the_kernel(
         i32((B,)), i32((B,)), i32((B, 2, 1, K)),
         jax.ShapeDtypeStruct((B, 2, 1, K), bool))
     pa._pa_select_call.clear_cache()
-    walks = hybrid.select_walks(cfg, page, P, P * page)
+    walks = hybrid.accountants(cfg, hybrid.Geometry(page, P, True))[0].walks
     assert walks == (6, 8)
     assert seen == {(n, pa.select_block(page * 32 * 4, n)) for n in walks}
 

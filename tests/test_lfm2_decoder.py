@@ -134,14 +134,16 @@ def test_a_mixer_alone_matches_the_reference(params, sizes, cfg, kind, layer):
     n = jnp.full(2, 45, jnp.int32)
     wpos = pos[:, None] + jnp.arange(45)
     if kind == "conv":
-        got, tail = hybrid._conv_layer(lp, x, jnp.zeros((2, 2, 64)), pos, n,
-                                       cfg, False)
+        got, new = hybrid._conv_layer(lp, x, {"conv": jnp.zeros((2, 2, 64))},
+                                      None, hybrid.Window(cfg, pos, n))
+        tail = new["conv"]
         ref = REFERENCE.short_conv
         b, _, u = jnp.split(x @ lp["in"]["w"], 3, axis=-1)
         assert np.allclose(tail, (b * u)[:, -2:], atol=1e-6)
     else:
         cache = hybrid.init_hybrid_cache(cfg, 2, 48)[layer]
-        got, _ = hybrid._gqa_contiguous(lp, x, wpos, n, cache, cfg)
+        got, _ = hybrid._gqa_contiguous(lp, x, cache, wpos,
+                                        hybrid.Window(cfg, pos, n))
         ref = REFERENCE.attention
     for b in range(2):
         want = ref(x[b], lp, shape_of(sizes), lambda a: a)

@@ -181,13 +181,15 @@ def test_a_mixer_alone_matches_the_reference(params, sizes, cfg, kind, layer):
     n = jnp.full(2, 45, jnp.int32)
     cache = hybrid.init_hybrid_cache(cfg, 2, 48)[layer]
     if kind == "ssm":
-        got, new = hybrid._ssm_layer(lp, x, cache, pos, n, cfg, False)
+        got, new = hybrid._ssm_layer(lp, x, cache, None,
+                                     hybrid.Window(cfg, pos, n))
         ref = REFERENCE.mamba
         pre = (x @ lp["in"]["w"])[..., 64:64 + 64 + 2 * 2 * 16]
         assert np.allclose(new["conv"], pre[:, -3:], atol=1e-6)
     else:
         wpos = pos[:, None] + jnp.arange(45)
-        got, _ = hybrid._gqa_contiguous(lp, x, wpos, n, cache, cfg)
+        got, _ = hybrid._gqa_contiguous(lp, x, cache, wpos,
+                                        hybrid.Window(cfg, pos, n))
         ref = REFERENCE.attention
     shape = REFERENCE.shape_of(sizes)
     for b in range(2):
@@ -639,7 +641,11 @@ def test_the_pool_holds_state_and_tails_a_slot(cfg):
     assert pool.buffers[2]["conv"].shape == (2, 3, 64 + 2 * 2 * 16)
     # a snapshot is the two ssm layers' state and tails of one slot
     assert pool.snapshot_bytes == 2 * (4 * 16 * 16 + 3 * 128) * 4
-    assert pool.ssm_calls == 2
+    label, states = hybrid.accountants(cfg, hybrid.Geometry(8, 8, True))
+    assert (label.layers, states.layers) == (1, 2)
+    pool.note(states.decode([5, 9, 30], rows=4, context=31))
+    assert pool.stats["ssm_state_rows"] == 2 * 3
+    assert pool.stats["attn_ticks_ssm"] == 1
 
 
 def test_the_trash_page_reaches_no_token(params, cfg, ids, want):
