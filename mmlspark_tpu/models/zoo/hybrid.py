@@ -97,6 +97,20 @@ experts in a latent and one grouped-query layer in a period; ``cfg.ssm``):
   experts are ``relu(l W_1)^2 W_2`` on ``l = W_dn x``, the weighted sum goes
   back through ``W_up`` (``parallel.moe.moe_topk_held``); ``"none"``: the
   layer is ``x += mixer(norm(x))`` alone.
+
+No eighth kind, but three of the model's own numbers on the kinds above (a
+decoder of nine ssm layers of ONE group to one plain gqa layer with a routed
+feed-forward in every layer, under muP's multipliers and a tied head):
+
+* ``cfg.routed.score == "softmax"``: the router is a bias-free linear map,
+  the ``per_token`` largest LOGITS are chosen and the weights are a softmax
+  over the chosen logits alone (``parallel.moe.route_topk``); the layer
+  holds no selection bias.
+* ``cfg.attn_scale``: what a gqa layer's scores are multiplied by in the
+  window's fold and in the decode kernel alike (0: ``head_dim ** -0.5``; a
+  muP model states ``1 / head_dim``).
+* ``cfg.tied_head``: the parameters hold no ``lm_head`` and
+  ``transformer.head`` reads ``embed.tok``, where it lies.
 """
 
 from __future__ import annotations
@@ -222,6 +236,9 @@ def check_config(cfg: TransformerConfig) -> None:
             if r.form not in ("swiglu", "relu2") or r.latent < 0:
                 raise ValueError(f"experts of form {r.form!r} in a latent "
                                  f"of {r.latent}: swiglu | relu2, >= 0")
+            if r.score not in ("sigmoid", "softmax"):
+                raise ValueError(f"router score {r.score!r}: sigmoid | "
+                                 "softmax")
             if any(r.swiglu_limits):
                 raise ValueError(
                     f"swiglu limits {r.swiglu_limits}: a held layer names a "
@@ -253,12 +270,13 @@ def _moe_init(cfg, rng):
     L = r.latent or D           # the width the experts read and write
     wide = F if r.form == "relu2" else 2 * F
     s = np.sqrt(2.0 / (L + F))
-    p = {"router": _dense(rng, D, r.experts),
-         "bias": rng.normal(0, 0.01, r.experts).astype(np.float32),
-         "experts": {
-             "up" if r.form == "relu2" else "gate_up":
-                 rng.normal(0, s, (r.held, L, wide)).astype(np.float32),
-             "down": rng.normal(0, s, (r.held, F, L)).astype(np.float32)}}
+    p = {"router": _dense(rng, D, r.experts)}
+    if r.score == "sigmoid":        # a softmax router selects by its logits
+        p["bias"] = rng.normal(0, 0.01, r.experts).astype(np.float32)
+    p["experts"] = {
+        "up" if r.form == "relu2" else "gate_up":
+            rng.normal(0, s, (r.held, L, wide)).astype(np.float32),
+        "down": rng.normal(0, s, (r.held, F, L)).astype(np.float32)}
     if r.latent:
         p["to_latent"] = _dense(rng, D, L)
         p["from_latent"] = _dense(rng, L, D)
@@ -289,9 +307,11 @@ def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
                        "up": _dense(rng, D, cfg.d_ff),
                        "down": _dense(rng, cfg.d_ff, D)})
         layers.append(lp)
-    return {"embed": {"tok": _dense(rng, cfg.vocab, D, 0.02)["w"]},
-            "layers": layers, "final_ln": _ones(D),
-            "lm_head": _dense(rng, D, cfg.vocab, 0.02)}
+    params = {"embed": {"tok": _dense(rng, cfg.vocab, D, 0.02)["w"]},
+              "layers": layers, "final_ln": _ones(D)}
+    if not cfg.tied_head:       # a tied head is the token table
+        params["lm_head"] = _dense(rng, D, cfg.vocab, 0.02)
+    return params
 
 
 # ---- caches -----------------------------------------------------------------
@@ -631,10 +651,11 @@ def _allowed_keys(idx, ok, t, sp, L):
     return causal & (dense | sel)
 
 
-def _fold_keys(carry, qg, ks_, vs_, al):
+def _fold_keys(carry, qg, ks_, vs_, al, scale=None):
     """One online-softmax update of ``carry = (m, l, acc)``: the queries
     ``qg`` (B, G, hg, W, hd) meet a tile of keys ``ks_`` (B, G, T, hd) and
-    values ``vs_`` (B, G, T, dv) under ``al`` (B, G or 1, W, T)."""
+    values ``vs_`` (B, G, T, dv) under ``al`` (B, G or 1, W, T); scores
+    times ``scale`` (None: ``hd ** -0.5``)."""
     m, l, acc = carry
     # a key no query may read can hold anything (the trash page behind
     # a block table's unassigned entries takes whatever the fused decode
@@ -643,7 +664,8 @@ def _fold_keys(carry, qg, ks_, vs_, al):
     vs_ = jnp.where(al.any(axis=2)[..., None], vs_,
                     jnp.zeros((), vs_.dtype))
     s = jnp.einsum("bghwd,bgud->bghwu", qg, ks_,
-                   preferred_element_type=F32) * qg.shape[-1] ** -0.5
+                   preferred_element_type=F32) * (scale
+                                                  or qg.shape[-1] ** -0.5)
     al = al[:, :, None]
     s = jnp.where(al, s, _NEG)
     m_new = jnp.maximum(m, s.max(axis=-1))
@@ -667,18 +689,19 @@ def _fold_end(carry, shape):
     return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).reshape(shape)
 
 
-def _masked_attention(q, k, v, allowed, t_max):
+def _masked_attention(q, k, v, allowed, t_max, scale=None):
     """Softmax attention of ``q`` (B, Hq, W, hd) over ``k`` (B, Hkv, L,
     hd) and ``v`` (B, Hkv, L, dv) under ``allowed`` (B, Hkv or 1, W, L),
     grouped-query, folded a tile of keys at a time up to position
-    ``t_max``. float32 out."""
+    ``t_max``; scores times ``scale`` (None: ``hd ** -0.5``). float32
+    out."""
     B, Hq, W, hd = q.shape
     G, L, dv = k.shape[1], k.shape[2], v.shape[-1]
     qg = q.reshape(B, G, Hq // G, W, hd)
     T = min(L, _KEY_TILE)
     init = _fold_start(qg, dv)
     if L == T:
-        out = _fold_keys(init, qg, k, v, allowed)
+        out = _fold_keys(init, qg, k, v, allowed, scale)
     else:
         short = -L % T                      # whole tiles (none at 32k)
         k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, short), (0, 0)))
@@ -689,7 +712,7 @@ def _masked_attention(q, k, v, allowed, t_max):
             def tile(a, axis):
                 return jax.lax.dynamic_slice_in_dim(a, i * T, T, axis=axis)
             return _fold_keys(carry, qg, tile(k, 2), tile(v, 2),
-                              tile(allowed, 3))
+                              tile(allowed, 3), scale)
         out = jax.lax.fori_loop(0, t_max // T + 1, body, init)
     return _fold_end(out, (B, Hq, W, dv))
 
@@ -1586,7 +1609,7 @@ def _gqa_contiguous(lp, x, c, wpos, w):
     q, k, v = _gqa_qkv(lp, x, wpos, cfg)
     kc, vc = _put_window(c, k, v, wpos, n_valid)
     o = _masked_attention(q, kc, vc, _causal(wpos, kc.shape[2]),
-                          jnp.max(wpos))
+                          jnp.max(wpos), cfg.attn_scale or None)
     return _heads_out(lp, o, cfg), {"k": kc, "v": vc}
 
 
@@ -1603,13 +1626,15 @@ def _gqa_paged(lp, x, c, wpos, w):
     q, k, v = _gqa_qkv(lp, x, wpos, cfg)
     if kernel:
         o, kv = paged_attention_gqa(q[:, :, 0], k[:, :, 0], v[:, :, 0],
-                                    c["kv"], bt, pos, active=n_valid > 0)
+                                    c["kv"], bt, pos, active=n_valid > 0,
+                                    scale=cfg.attn_scale or None)
         return _heads_out(lp, o[:, :, None], cfg), {"kv": kv}
     kv = _scatter_pages(c["kv"], k, v, bt, wpos, n_valid, page)
     L = bt.shape[1] * page
     kc, vc = split_kv(kv[bt].transpose(0, 2, 1, 3, 4).reshape(
         B, Hkv, L, 2 * hd))
-    o = _masked_attention(q, kc, vc, _causal(wpos, L), jnp.max(wpos))
+    o = _masked_attention(q, kc, vc, _causal(wpos, L), jnp.max(wpos),
+                          cfg.attn_scale or None)
     return _heads_out(lp, o, cfg), {"kv": kv}
 
 

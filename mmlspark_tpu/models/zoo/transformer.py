@@ -70,7 +70,10 @@ class RoutedExperts(NamedTuple):
     experts' outputs goes back through ``W_up``; the router and the shared
     expert read the model's row); ``form`` is an expert's body, ``"swiglu"``
     (``(silu(x W_g) * x W_u) W_d``) or ``"relu2"`` (``relu(x W_1)^2 W_2``,
-    no gate; the shared expert takes the same form)."""
+    no gate; the shared expert takes the same form). ``score`` is the
+    router's form: ``"sigmoid"`` (the above) or ``"softmax"``, a bias-free
+    linear map whose ``per_token`` largest LOGITS are chosen and whose
+    weights are ``scale`` times a softmax over the chosen logits alone."""
     experts: int = 8
     first: int = 0
     count: int = 0
@@ -83,6 +86,7 @@ class RoutedExperts(NamedTuple):
     swiglu_limits: tuple = ()
     latent: int = 0
     form: str = "swiglu"
+    score: str = "sigmoid"
 
     @property
     def held(self) -> int:
@@ -206,6 +210,14 @@ class TransformerConfig(NamedTuple):
     kv_heads: int = 0
     head_dim: int = 0
     sparse: Optional[SparseAttention] = None
+    #: what a gqa layer's scores are multiplied by (0: ``head_dim ** -0.5``;
+    #: a muP model states its own, 1 / head_dim), window and decode kernel
+    #: alike
+    attn_scale: float = 0.0
+    #: the head is the token table (``tie_word_embeddings``): a hybrid
+    #: model's parameters then hold no ``lm_head`` and :func:`head` reads
+    #: ``embed.tok``
+    tied_head: bool = False
     #: muP: the embedding is multiplied by ``embed_scale``, every residual
     #: branch by ``residual_scale``, the final hidden state by
     #: ``logit_scale`` before the head
@@ -425,7 +437,12 @@ def _embed(params, tokens, cfg: TransformerConfig, wpos=None, gather=False):
 
 def head(params, hidden):
     """float32 logits of final hidden states: the one output product of
-    cached decoding, both blocks'."""
+    cached decoding, both blocks'. Parameters without an ``lm_head`` are a
+    tied model's: the head is the token table, read where it lies (a
+    product over its columns, no transposed copy)."""
+    if "lm_head" not in params:
+        return jnp.einsum("...d,vd->...v", hidden.astype(jnp.float32),
+                          params["embed"]["tok"])
     return hidden.astype(jnp.float32) @ params["lm_head"]["w"]
 
 

@@ -22,10 +22,14 @@ are then ROWS (the pair's two heads side by side), the sum over ``N`` runs
 down the sublanes (vector adds), and the only columns are ``B`` and ``C``,
 which a whole group shares: two transposes a GROUP of heads, not two a head.
 
-Grid ``(rows, G / groups-a-step)``; a step holds ``groups-a-step`` groups'
-pairs in VMEM (2 MiB of state at most), aliased in and out so the buffer the
-engine donates is updated in place. Every product is a broadcast on the
-vector unit, exact in float32. A row that is not ``active`` keeps its state.
+Grid ``(rows, steps)``; a step holds a block of consecutive pairs in VMEM,
+2 MiB of state at most (:func:`pairs_a_step`): whole groups where a group's
+pairs fit (8 groups of 8 pairs at 64 KiB a pair: 4 groups a step, 2 steps a
+row), else an equal part of ONE group (1 group of 64 pairs: 32 pairs a step,
+2 steps a row, both reading the group's one ``B`` and ``C``). The state is
+aliased in and out so the buffer the engine donates is updated in place.
+Every product is a broadcast on the vector unit, exact in float32. A row
+that is not ``active`` keeps its state.
 
 The chunked form a prefill window runs is plain ``jnp``
 (``models.zoo.hybrid.ssm_chunk``).
@@ -40,7 +44,7 @@ import jax.numpy as jnp
 
 from . import paged_attention as _pa
 
-__all__ = ["ssm_decode_step", "pack_state", "unpack_state"]
+__all__ = ["ssm_decode_step", "pack_state", "unpack_state", "pairs_a_step"]
 
 F32 = jnp.float32
 #: the most state a grid step holds (in, and as much again out)
@@ -72,23 +76,29 @@ def _step_kernel(act_ref, a_ref, du_ref, b_ref, c_ref, s_ref, y_ref, so_ref,
     def column(row):        # (1, n) along the lanes -> [i, j] = row[i]
         return jnp.broadcast_to(row, (lanes, n)).T
 
-    for g in range(b_ref.shape[1]):                     # static, <= 4
+    # static loops: the block's groups (or the one group a part of whose
+    # pairs the block holds), then ``per`` pairs of each; 32 pairs a step
+    # at 64 KiB a pair, however they are grouped
+    for g in range(b_ref.shape[1]):
         b_col, c_col = column(b_ref[0, g]), column(c_ref[0, g])
-        for i in range(g * per, (g + 1) * per):         # static, 8
+        for i in range(g * per, (g + 1) * per):
             state = s_ref[0, i]                         # (n, lanes)
             new = a_ref[0, i] * state + b_col * du_ref[0, i]
             y_ref[0, i] = jnp.sum(c_col * new, axis=0, keepdims=True)
             so_ref[0, i] = jnp.where(live, new, state)
 
 
-def groups_a_step(groups: int, pair_bytes: int, per: int) -> int:
-    """Groups of heads a grid step holds: the largest divisor of ``groups``
-    whose pairs' state fits ``_STEP_BYTES``."""
-    best = 1
-    for n in range(1, groups + 1):
-        if groups % n == 0 and n * per * pair_bytes <= _STEP_BYTES:
-            best = n
-    return best
+def pairs_a_step(groups: int, pair_bytes: int, per: int):
+    """``(groups, pairs of each)`` a grid step holds, for ``groups`` groups
+    of ``per`` pairs: the most whole groups (a divisor of ``groups``) whose
+    state fits ``_STEP_BYTES``; where not even one group fits, the largest
+    equal part (a divisor of ``per``) of one group that does, a pair at
+    least."""
+    fit = max(1, _STEP_BYTES // pair_bytes)             # pairs that fit
+    if per > fit:
+        return 1, max(n for n in range(1, fit + 1) if per % n == 0)
+    return max(n for n in range(1, groups + 1)
+               if groups % n == 0 and n * per <= fit), per
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -98,15 +108,22 @@ def _ssm_step_call(active, a, du, b, c, state, *, interpret):
 
     B, half, N, lanes = state.shape
     G = b.shape[1]
-    per = half // G                                     # pairs a group
-    gb = groups_a_step(G, N * lanes * 4, per)
-    row = pl.BlockSpec((1, gb * per, 1, lanes), lambda r, g, *_: (r, g, 0, 0))
-    shared = pl.BlockSpec((1, gb, 1, N), lambda r, g, *_: (r, g, 0, 0))
-    mat = pl.BlockSpec((1, gb * per, N, lanes), lambda r, g, *_: (r, g, 0, 0))
+    gb, pb = pairs_a_step(G, N * lanes * 4, half // G)
+    parts = half // G // pb             # grid steps that share one group
+
+    def pairs(r, s, *_):
+        return (r, s, 0, 0)
+
+    def group(r, s, *_):
+        return (r, s if parts == 1 else s // parts, 0, 0)
+
+    row = pl.BlockSpec((1, gb * pb, 1, lanes), pairs)
+    shared = pl.BlockSpec((1, gb, 1, N), group)
+    mat = pl.BlockSpec((1, gb * pb, N, lanes), pairs)
     call = pl.pallas_call(
-        functools.partial(_step_kernel, per=per),
+        functools.partial(_step_kernel, per=pb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, G // gb),
+            num_scalar_prefetch=1, grid=(B, G // gb * parts),
             in_specs=[row, row, shared, shared, mat], out_specs=[row, mat]),
         out_shape=[jax.ShapeDtypeStruct((B, half, 1, lanes), F32),
                    jax.ShapeDtypeStruct(state.shape, F32)],

@@ -248,21 +248,31 @@ def route_topk(x32, router_w, bias, spec):
     """Group-limited top-k routing, float32 throughout. ``x32`` (T, D)
     float32, ``router_w`` (D, experts), ``bias`` (experts,) the selection
     bias. Returns ``(idx, weight)``, both (T, per_token): the chosen experts
-    and ``scale * s / sum(chosen s)`` of their UNBIASED sigmoid scores."""
+    and ``scale * s / sum(chosen s)`` of their UNBIASED sigmoid scores.
+    With ``spec.score == "softmax"`` the router is the linear map alone
+    (``bias`` None): the largest LOGITS are chosen and the weights are
+    ``scale`` times a softmax over the chosen logits."""
     f32 = jnp.float32
-    s = jax.nn.sigmoid(jnp.dot(x32.astype(f32), router_w.astype(f32),
-                               precision=jax.lax.Precision.HIGHEST))
-    sel = s + bias.astype(f32)
+    s = jnp.dot(x32.astype(f32), router_w.astype(f32),
+                precision=jax.lax.Precision.HIGHEST)
+    softmax = spec.score == "softmax"
+    if not softmax:
+        s = jax.nn.sigmoid(s)
+    sel = s if bias is None else s + bias.astype(f32)
     T, E = s.shape
     G = spec.groups
     per = E // G
-    best2 = jax.lax.top_k(sel.reshape(T, G, per), min(2, per))[0].sum(-1)
-    _, keep = jax.lax.top_k(best2, spec.groups_kept)        # (T, kept)
-    kept = (keep[:, :, None] == jnp.arange(G)[None, None]).any(axis=1)
-    sel = jnp.where(jnp.repeat(kept, per, axis=1), sel, -jnp.inf)
+    # one group of logits has no group to drop (the sigmoid routers keep
+    # the step at one group too: their programs stay as they were)
+    if G > 1 or not softmax:
+        best2 = jax.lax.top_k(sel.reshape(T, G, per), min(2, per))[0].sum(-1)
+        _, keep = jax.lax.top_k(best2, spec.groups_kept)        # (T, kept)
+        kept = (keep[:, :, None] == jnp.arange(G)[None, None]).any(axis=1)
+        sel = jnp.where(jnp.repeat(kept, per, axis=1), sel, -jnp.inf)
     _, idx = jax.lax.top_k(sel, spec.per_token)
     chosen = jnp.take_along_axis(s, idx, axis=1)
-    weight = chosen / chosen.sum(axis=-1, keepdims=True) * spec.scale
+    weight = (jax.nn.softmax(chosen, axis=-1) if softmax
+              else chosen / chosen.sum(axis=-1, keepdims=True)) * spec.scale
     return idx.astype(jnp.int32), weight
 
 
@@ -278,8 +288,9 @@ def moe_topk_held(x, x32, p, spec, valid, interpret=None):
     float32 (the router reads these: a rounded input swaps near-tied
     experts), ``valid`` (T,) bool the real tokens (padding and idle rows
     route nowhere and read no expert). ``p``: ``router.w`` (D, experts),
-    ``bias`` (experts,), ``experts.gate_up`` (held, D, 2F), ``experts.down``
-    (held, F, D), and ``shared.{gate,up,down}`` when the layer has a shared
+    ``bias`` (experts,; none under a softmax router), ``experts.gate_up``
+    (held, D, 2F), ``experts.down`` (held, F, D), and
+    ``shared.{gate,up,down}`` when the layer has a shared
     expert. Returns ``(y (T, D) in x's dtype, stats int32[8])``, the stats
     in :data:`MOE_STATS`' order: the live tokens' pairs over all experts,
     those on held experts, held pairs given no row (0: no capacity), pairs
@@ -314,7 +325,7 @@ def moe_topk_held(x, x32, p, spec, valid, interpret=None):
     # row, and 0 x NaN is NaN, so such a row is 0 before it meets another
     x = jnp.where(valid[:, None], x, jnp.zeros((), x.dtype))
     x32 = jnp.where(valid[:, None], x32, 0.0)
-    idx, weight = route_topk(x32, p["router"]["w"], p["bias"], spec)
+    idx, weight = route_topk(x32, p["router"]["w"], p.get("bias"), spec)
     local = idx - spec.first
     held = (local >= 0) & (local < Eh) & valid[:, None]         # (T, k)
     e = jnp.where(held, local, Eh).reshape(T * k)

@@ -683,14 +683,16 @@ LFM2 = dict(slots=32, heads=32, kv_heads=8, hd=64, page=256, max_len=5120,
             hidden=2048, width=1536, held=64, top=4, chunk=512)
 
 
-def test_grouped_query_decode_kernel_compiles_in_place(one_chip):
-    """The gqa layer's tick: four query heads fold a KV head's page block,
-    the fresh K/V row scattered in the same launch, the pool aliased."""
+def _gqa_decode_compiles(one_chip, z, scale=None):
+    """The grouped-query tick kernel lowered for the described chip at a
+    cell's shapes ``z``: the call under its jitted name, the pool (``pages``
+    of them, else every slot's at ``max_len``) aliased and never copied."""
     from mmlspark_tpu.ops.paged_attention import paged_attention_gqa
-    z = LFM2
     per = z["max_len"] // z["page"]
-    pool = (1 + z["slots"] * per, z["kv_heads"], z["page"], 2 * z["hd"])
-    text = jax.jit(functools.partial(paged_attention_gqa, interpret=False),
+    pool = (z.get("pages") or 1 + z["slots"] * per, z["kv_heads"], z["page"],
+            2 * z["hd"])
+    text = jax.jit(functools.partial(paged_attention_gqa, interpret=False,
+                                     scale=scale),
                    donate_argnums=(3,)).lower(
         one_chip((z["slots"], z["heads"], z["hd"]), jnp.bfloat16),
         one_chip((z["slots"], z["kv_heads"], z["hd"]), jnp.bfloat16),
@@ -701,6 +703,12 @@ def test_grouped_query_decode_kernel_compiles_in_place(one_chip):
     shape = f"bf16[{','.join(map(str, pool))}]"
     assert not [ln for ln in text.splitlines()
                 if f"= {shape}" in ln and " copy(" in ln]
+
+
+def test_grouped_query_decode_kernel_compiles_in_place(one_chip):
+    """The gqa layer's tick: four query heads fold a KV head's page block,
+    the fresh K/V row scattered in the same launch, the pool aliased."""
+    _gqa_decode_compiles(one_chip, LFM2)
 
 
 @pytest.mark.parametrize("tokens", [32, 512, 32 + 512],
@@ -847,12 +855,18 @@ NEMOTRON = dict(slots=32, page=256, max_len=4096, chunk=256, heads=32,
                 groups=8, held=128, latent=1024, width=2688)
 
 
-def test_ssm_step_kernel_compiles_in_place(one_chip):
+@pytest.mark.parametrize("cell", ["NEMOTRON", "GRANITE"])
+def test_ssm_step_kernel_compiles_in_place(one_chip, cell):
     """The state-space decode step on 32 slots x 128 heads of a (64 x 128)
     float32 state held in pairs, the donated state aliased in and out: no
-    copy of it, and the step's blocks fit its VMEM limit."""
-    from mmlspark_tpu.ops.ssm_step import ssm_decode_step
-    z = NEMOTRON
+    copy of it, and the step's blocks fit its VMEM limit. Eight groups: four
+    whole groups a grid step; ONE group of 64 pairs (4 MiB): two grid steps
+    of 32 that read the group's one ``B`` and ``C``."""
+    from mmlspark_tpu.ops.ssm_step import pairs_a_step, ssm_decode_step
+    z = globals()[cell]
+    assert pairs_a_step(z["groups"], z["state"] * 2 * z["ssm_hd"] * 4,
+                        z["ssm_heads"] // 2 // z["groups"]) == {
+        "NEMOTRON": (4, 8), "GRANITE": (1, 32)}[cell]
     state = (z["slots"], z["ssm_heads"] // 2, z["state"], 2 * z["ssm_hd"])
     shared = one_chip((z["slots"], z["groups"], z["state"]), jnp.float32)
     text = jax.jit(
@@ -871,21 +885,7 @@ def test_ssm_step_kernel_compiles_in_place(one_chip):
 def test_grouped_query_decode_kernel_compiles_at_sixteen_a_head(one_chip):
     """The gqa layer's tick at 32 query heads over 2 KV heads: two whole
     groups of state rows fold one KV head's page block, the pool aliased."""
-    from mmlspark_tpu.ops.paged_attention import paged_attention_gqa
-    z = NEMOTRON
-    per = z["max_len"] // z["page"]
-    pool = (1 + z["slots"] * per, z["kv_heads"], z["page"], 2 * z["hd"])
-    text = jax.jit(functools.partial(paged_attention_gqa, interpret=False),
-                   donate_argnums=(3,)).lower(
-        one_chip((z["slots"], z["heads"], z["hd"]), jnp.bfloat16),
-        one_chip((z["slots"], z["kv_heads"], z["hd"]), jnp.bfloat16),
-        one_chip((z["slots"], z["kv_heads"], z["hd"]), jnp.bfloat16),
-        one_chip(pool, jnp.bfloat16), one_chip((z["slots"], per), jnp.int32),
-        one_chip((z["slots"],), jnp.int32)).compile().as_text()
-    assert "tpu_custom_call" in text and "_pa_gqa_call" in text
-    shape = f"bf16[{','.join(map(str, pool))}]"
-    assert not [ln for ln in text.splitlines()
-                if f"= {shape}" in ln and " copy(" in ln]
+    _gqa_decode_compiles(one_chip, NEMOTRON)
 
 
 @pytest.mark.parametrize("tokens", [32, 32 + 256],
@@ -900,23 +900,21 @@ def test_latent_expert_product_compiles(one_chip, tokens):
     assert tiles == {32: 172, 288: 524}[tokens]
 
 
-@pytest.fixture(scope="module")
-def nemotron_programs(one_chip):
-    """The state-space engine's tick and the tick that carries a 256-token
+def _whole_depth_programs(one_chip, z, config_file, driver):
+    """A cell's tick and the tick that carries a ``z["chunk"]``-token
     window, lowered and compiled at the cell's shapes and at the cell's
-    DEPTH: all eleven published layers (six block layers)."""
+    DEPTH (every layer the configuration file holds). Yields ``({"tick",
+    "riding"}: compiled, the pool)``."""
     import json
 
     from benchmarks import run as bench_run
     from mmlspark_tpu.ops import paged_attention as pa
     from mmlspark_tpu.serving import continuous as progs
     from mmlspark_tpu.serving.kv_pool import PagedKVPool
-    z = NEMOTRON
-    with open(os.path.join(bench_run.HERE, "configs",
-                           "nemotron3_super_ep4_l11.json")) as fh:
+    with open(os.path.join(bench_run.HERE, "configs", config_file)) as fh:
         config = json.load(fh)
-    cfg = bench_run.load_by_path("drivers", "generate_nemotron"
-                                 ).program_config(config, z["max_len"])
+    cfg = bench_run.load_by_path("drivers", driver).program_config(
+        config, z["max_len"])
     reference = bench_run.load_by_path("references", config["reference"])
     params = jax.tree.map(
         lambda a: one_chip(a.shape, a.dtype),
@@ -925,7 +923,8 @@ def nemotron_programs(one_chip):
     pool = PagedKVPool(cfg, page_size=z["page"], residency=False,
                        make_buffer=one_chip, slots=z["slots"],
                        slot_positions=z["max_len"],
-                       num_pages=1 + z["slots"] * per + z["slots"])
+                       num_pages=z.get("pages")
+                       or 1 + z["slots"] * per + z["slots"])
     ints = lambda *dims: one_chip(dims, jnp.int32)          # noqa: E731
     interpret = pa._auto_interpret
     pa._auto_interpret = progs._pa_auto_interpret = lambda: False
@@ -941,6 +940,16 @@ def nemotron_programs(one_chip):
     finally:
         pa._auto_interpret = progs._pa_auto_interpret = interpret
         progs._tick_program.cache_clear()
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(one_chip):
+    """The state-space engine's tick and the tick that carries a 256-token
+    window at the cell's DEPTH: all eleven published layers (six block
+    layers)."""
+    yield from _whole_depth_programs(one_chip, NEMOTRON,
+                                     "nemotron3_super_ep4_l11.json",
+                                     "generate_nemotron")
 
 
 @pytest.mark.parametrize("program", ["tick", "riding"])
@@ -979,6 +988,95 @@ def test_state_space_programs_compile_and_keep_the_pool_in_place(
     assert memory.temp_size_in_bytes < 256 << 20
     # the states and the pages are donated and updated in place
     assert memory.alias_size_in_bytes > 5 * 32 * 64 * 128 * 128 * 4
+
+
+# 32 slots of 65,536 positions in pages of 256 (1,400 pages of 8 KV heads
+# of 128: 1 MiB each), Mamba-2 of 128 heads of 64 on a 128-wide state in
+# ONE group, 36 of 72 experts of 768 held, top-10, a 512-token prefill chunk
+GRANITE = dict(slots=32, page=256, max_len=65536, pages=1400, chunk=512,
+               heads=32, kv_heads=8, hd=128, ssm_heads=128, ssm_hd=64,
+               state=128, groups=1, hidden=4096, width=768, held=36, top=10,
+               vocab=50176)
+
+
+def test_grouped_query_decode_kernel_compiles_at_a_mebibyte_a_page(one_chip):
+    """The gqa layer's tick at 8 KV heads of 128 over block tables 256
+    pages wide, scores under the model's own scale: the pool (1.47 GB)
+    aliased, no copy of it."""
+    _gqa_decode_compiles(one_chip, GRANITE, scale=1 / 128)
+
+
+@pytest.mark.parametrize("tokens", [32, 32 + 512],
+                         ids=["tick", "carrying_tick"])
+def test_expert_product_compiles_at_thirty_six_of_seventy_two(one_chip,
+                                                              tokens):
+    """The grouped product over 36 held experts of (4096, 1536) + (768,
+    4096) bf16 (18.9 MB a grid step, LFM2's bytes in another shape) at 10
+    pairs a token: neither count a power of two or a multiple of 128."""
+    z = GRANITE
+    tiles, block = _expert_product_compiles(
+        one_chip, tokens, z["top"], z["held"], z["hidden"], z["width"])
+    assert block == 18_874_368
+    assert tiles == {32: 56, 544: 376}[tokens]
+
+
+@pytest.fixture(scope="module")
+def granite_programs(one_chip):
+    """The engine's tick and the tick that carries a 512-token window at the
+    cell's DEPTH: all ten layers held, nine ssm to one gqa, a routed
+    feed-forward in each."""
+    yield from _whole_depth_programs(one_chip, GRANITE,
+                                     "granite4_h_small_ep2_l10.json",
+                                     "generate_granite")
+
+
+@pytest.mark.parametrize("program", ["tick", "riding"])
+def test_one_group_programs_compile_and_keep_the_pool_in_place(
+        granite_programs, program):
+    """The ten-layer tick and the tick that carries a 512-token window at
+    the published widths (32 slots of 65,536 positions, 1,400 pages): they
+    compile for the chip and hold ONE state-space step an ssm layer, ONE
+    grouped product a layer with no copy of its experts, ONE grouped-query
+    call; the plain tick holds no sequential loop; neither copies a layer's
+    states (134 MB), the page pool (1.47 GB) or the token table that is the
+    head too (411 MB: the tied product reads it where it lies); and the
+    carrying tick's temporaries are the gqa window's: it gathers its row's
+    WHOLE block table (256 pages = 268 MB, gathered, laid out by head and
+    split into K and V: 1.65 GB at 64 lanes, 1.68 GB at 512, so the chunk's
+    width is not what they follow: ROADMAP S13), a tenth of the chip beside
+    12.2 GB of weights and pool."""
+    compiled, pool = granite_programs
+    text = compiled[program].as_text()
+    lines = text.splitlines()
+    for name, n in (("_ssm_step_call", 9), ("_moe_experts_call", 10),
+                    ("_pa_gqa_call", 1)):
+        assert sum("tpu_custom_call" in ln and name in ln
+                   for ln in lines) == n, name
+    if program == "tick":
+        assert " while(" not in text
+    z = GRANITE
+    shapes = [f"bf16[{z['held']},{z['hidden']},{2 * z['width']}]",
+              f"bf16[{z['held']},{z['width']},{z['hidden']}]",
+              f"bf16[{z['vocab']},{z['hidden']}]",
+              f"bf16[{z['hidden']},{z['vocab']}]",
+              f"f32[{z['vocab']},{z['hidden']}]"]
+    names = {"bfloat16": "bf16", "float32": "f32"}
+    for layer in pool.buffers:
+        shapes += [
+            f"{names[buf.dtype.name]}[{','.join(map(str, buf.shape))}]"
+            for key, buf in layer.items() if key != "conv"]
+    assert "f32[32,64,128,128]" in shapes
+    assert "bf16[1400,8,256,256]" in shapes
+    made = [ln.strip()[:120] for ln in lines
+            if any(f"= {sh}" in ln for sh in shapes)
+            and (" copy(" in ln or " convert(" in ln or " transpose(" in ln)]
+    assert not made, made
+    memory = compiled[program].memory_analysis()
+    assert memory.temp_size_in_bytes < {"tick": 256, "riding": 2048}[
+        program] << 20, memory.temp_size_in_bytes
+    # the states and the pages are donated and updated in place
+    assert memory.alias_size_in_bytes > (9 * 32 * 64 * 128 * 128 * 4
+                                         + 1400 * (1 << 20))
 
 
 @pytest.mark.parametrize("stats", [None, "bfloat16"])
