@@ -111,6 +111,18 @@ feed-forward in every layer, under muP's multipliers and a tied head):
   muP model states ``1 / head_dim``).
 * ``cfg.tied_head``: the parameters hold no ``lm_head`` and
   ``transformer.head`` reads ``embed.tok``, where it lies.
+
+Two parameter trees. The tree a caller hands in is :func:`init_hybrid`'s:
+every projection a leaf ``{"w": [in, out]}``, and so does every reference
+and every public entry point of ``transformer.py`` read it. The tree a
+``ContinuousDecoder`` SERVES from is :func:`serving_layout` of it, made once
+at construction: a ``lightning`` or ``sparse`` layer's ``q``, ``k`` and
+``v`` are held as ``{"wt": [heads x hd, in]}`` (``Mixer.served``), because
+the compiled product that feeds ``_heads`` reads its weight so and re-laid
+an ``[in, out]`` one on its way into VMEM in every program (PERF.md, PR 50);
+every other leaf is the caller's own array. :func:`_proj` reads either form
+by the leaf's key, ``wt`` contracted on its second axis: the same product,
+the same float32 accumulation. The caller's tree is never written.
 """
 
 from __future__ import annotations
@@ -128,7 +140,7 @@ from .transformer import (TransformerConfig, _embed, _rms, _rope_tables,
 __all__ = ["check_config", "dims", "init_hybrid", "init_hybrid_cache",
            "init_hybrid_pool", "lightning_rates", "lightning_chunk",
            "sparse_select", "kda_chunk", "ssm_chunk", "head",
-           "window_contiguous",
+           "serving_layout", "window_contiguous",
            "window_paged", "tick_with_window", "SLOT_KEYS", "KINDS",
            "MIXERS", "Mixer", "Window", "Geometry", "accountants",
            "required_page"]
@@ -195,6 +207,9 @@ class Mixer(NamedTuple):
     page: Optional[Callable] = None     # (cfg): the page it requires
     #: ``(cfg, kind, geometry)``: its host accounting (:class:`TickCounts`)
     counts: Optional[Callable] = None
+    #: the projections of ``init``'s a decoder holds as ``{"wt": [out, in]}``
+    #: (:func:`serving_layout`): those whose product reads its weight so
+    served: Tuple[str, ...] = ()
 
 
 def dims(cfg: TransformerConfig):
@@ -314,6 +329,21 @@ def init_hybrid(cfg: TransformerConfig, seed: int = 0) -> Dict:
     return params
 
 
+def serving_layout(cfg: TransformerConfig, params: Dict) -> Dict:
+    """The tree a decoder serves from: ``params`` with every projection a
+    layer's kind declares (``Mixer.served``) held as ``{"wt": w.T}``, laid
+    out ONCE as its product reads it (:func:`_proj`). Every other leaf, and
+    the whole tree of a model whose kinds declare nothing, is the caller's
+    own object; the caller's tree is not written. Shapes alone will do
+    (``jax.eval_shape``)."""
+    served = [KINDS[kind].served for kind in cfg.mixers]
+    if not any(served):
+        return params
+    return dict(params, layers=[
+        dict(lp, **{name: {"wt": lp[name]["w"].T} for name in names})
+        if names else lp for lp, names in zip(params["layers"], served)])
+
+
 # ---- caches -----------------------------------------------------------------
 
 def _round_up(n: int, m: int) -> int:
@@ -406,6 +436,13 @@ def accountants(cfg: TransformerConfig, geometry: Geometry):
 # ---- shared pieces ----------------------------------------------------------
 
 def _proj(x, p, dt):
+    """``x W``, by what the leaf holds: ``w`` is ``[in, out]`` as
+    :func:`init_hybrid` drew it, ``wt`` is ``[out, in]`` as
+    :func:`serving_layout` laid it for this product, contracted on its
+    second axis. The same product either way."""
+    if "wt" in p:
+        return jnp.tensordot(x, p["wt"].astype(dt),
+                             axes=((x.ndim - 1,), (1,)))
     return x @ p["w"].astype(dt)
 
 
@@ -461,6 +498,12 @@ def _gated_attention_init(cfg, rng, kv):
             "v": _dense(rng, D, kv * hd), "g": _dense(rng, D, H * hd),
             "o": _dense(rng, H * hd, D),
             "q_norm": _ones(hd), "k_norm": _ones(hd)}
+
+
+#: what a lightning and a sparse layer declare as ``Mixer.served``: the
+#: product that feeds :func:`_heads` reads its weight ``[heads x hd, in]``
+#: (the module's docstring, "Two parameter trees")
+_HEADS_MAJOR = ("q", "k", "v")
 
 
 # ---- lightning --------------------------------------------------------------
@@ -1643,10 +1686,10 @@ def _gqa_paged(lp, x, c, wpos, w):
 #: the mixer kinds ``cfg.mixers`` may name, a record each (:class:`Mixer`)
 KINDS: Dict[str, Mixer] = {
     "lightning": Mixer(
-        init=_lightning_init,
+        init=_lightning_init, served=_HEADS_MAJOR,
         **_slot_kind(_lightning_rows, _lightning_layer)),
     "sparse": Mixer(
-        check=_sparse_check,
+        check=_sparse_check, served=_HEADS_MAJOR,
         init=lambda cfg, rng: _gated_attention_init(cfg, rng, dims(cfg)[1]),
         cache=_sparse_cache, pool=_sparse_pool,
         contiguous=_sparse_contiguous, paged=_sparse_paged,

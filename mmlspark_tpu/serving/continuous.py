@@ -85,7 +85,8 @@ from ..models.zoo.transformer import (TransformerConfig,
                                       prefill_cache, shardings_for)
 from ..models.zoo.hybrid import SLOT_KEYS as _SLOT_KEYS
 from ..models.zoo.hybrid import (Geometry, accountants, check_config,
-                                 required_page, tick_with_window)
+                                 required_page, serving_layout,
+                                 tick_with_window)
 from ..ops.padding import bucket_size
 from ..ops.paged_attention import (resolve_impl as _resolve_paged_attn,
                                    _auto_interpret as _pa_auto_interpret)
@@ -817,6 +818,17 @@ class ContinuousDecoder:
         #: staged units: [requests, logits, row_cache, next-offset]
         self._staged: List[list] = []
         params = jax.tree.map(jnp.asarray, params)
+        #: bytes this decoder holds in another layout than it was handed: a
+        #: hybrid decoder serves from the tree its kinds declare, each
+        #: re-laid leaf in place of the caller's (``stats``)
+        relaid = 0
+        if self._hybrid:
+            handed = jax.tree.leaves(params)
+            params = serving_layout(cfg, params)
+            relaid = sum(ours.nbytes for ours, theirs in zip(
+                jax.tree.leaves(params), handed, strict=True)
+                if ours is not theirs)
+            del handed      # a re-laid leaf's original is the caller's alone
         hd = cfg.d_model // cfg.heads
         # speculative headroom: a verify window optimistically WRITES all
         # gamma+1 positions even when fewer remain before max_new; slot
@@ -1121,9 +1133,11 @@ class ContinuousDecoder:
         #: engine's round log reads both (``generation.recent_rounds``).
         #: ``embed_read``: how every program of this decoder reads the token
         #: table (``transformer._rows``), named once here.
+        #: ``serving_layout_bytes``: the weights held re-laid (above).
         self.stats = {"prefills": 0, "prefix_hits": 0,
                       "prefix_hit_tokens": 0, "ticks": 0,
                       "drain_seconds": 0.0,
+                      "serving_layout_bytes": relaid,
                       "embed_read": embed_read(
                           params["embed"]["tok"].shape[1])}
         for form in ("gather", "in_place"):

@@ -341,8 +341,8 @@ def test_a_kind_is_one_complete_record(kind):
 def test_the_server_names_no_kind(module):
     """The page pool and the scheduler read records: neither holds a kind's
     name as a string, reads a kind's configuration, or imports more of the
-    model's block and its kernels than the allocation, the programs and the
-    page-alignment helpers."""
+    model's block and its kernels than the allocation, the programs, the
+    layout its weights are served in and the page-alignment helpers."""
     import ast
     import mmlspark_tpu.serving as serving
     path = f"{serving.__path__[0]}/{module}.py"
@@ -352,7 +352,7 @@ def test_the_server_names_no_kind(module):
                                          "aligned_page_size"},
         ("continuous", "hybrid"): {"SLOT_KEYS", "Geometry", "accountants",
                                    "check_config", "required_page",
-                                   "tick_with_window"},
+                                   "serving_layout", "tick_with_window"},
         ("continuous", "paged_attention"): {"resolve_impl",
                                             "_auto_interpret"},
     }

@@ -24,9 +24,10 @@ import pytest
 from benchmarks import run as bench_run
 from mmlspark_tpu.models.zoo import hybrid
 from mmlspark_tpu.models.zoo.transformer import (
-    SparseAttention, TransformerConfig, decode_step_paged,
-    decode_window_paged, generate, generate_cached, init_paged_cache,
-    init_transformer, transformer_apply)
+    DeltaRule, LatentAttention, RoutedExperts, ShortConv, SparseAttention,
+    StateSpace, TransformerConfig, decode_step_paged, decode_window_paged,
+    generate, generate_cached, init_paged_cache, init_transformer,
+    transformer_apply)
 from mmlspark_tpu.ops.lightning_attention import lightning_decode_step
 from mmlspark_tpu.ops.paged_attention import paged_attention_selected
 from mmlspark_tpu.serving.continuous import ContinuousDecoder
@@ -175,6 +176,67 @@ def test_an_idle_row_keeps_its_state_and_its_pages(params, ids, cfg):
     # token of tick 6 at position 56, not a state three ticks stale
     assert np.array_equal(ticks[2][1], again[2][1])
     assert np.isfinite(ticks[7][1]).all()
+
+
+def test_serving_layout_relays_qkv_of_lightning_and_sparse_alone(cfg):
+    """Object for object the caller's tree, but ``q``, ``k``, ``v`` of a
+    lightning or a sparse layer, held as ``{"wt": w.T}``; a model with
+    neither kind gets its own tree back."""
+    every = cfg._replace(
+        layers=len(hybrid.MIXERS), mixers=hybrid.MIXERS, max_len=48,
+        kda=DeltaRule(conv_kernel=3), conv=ShortConv(taps=3),
+        latent=LatentAttention(latent=32, nope=16, rope=8, value=16),
+        ssm=StateSpace(heads=4, head_dim=16, state=16, groups=2, taps=3,
+                       chunk=8))
+    params = hybrid.init_hybrid(every, 2)
+    before = jax.tree.map(np.copy, params)
+    got = hybrid.serving_layout(every, params)
+    assert {k: got[k] is params[k] for k in params} == dict(
+        {k: True for k in params}, layers=False)
+    for kind, lp, sl in zip(every.mixers, params["layers"], got["layers"]):
+        relaid = {"q", "k", "v"} if kind in ("lightning", "sparse") else set()
+        assert (sl is lp) == (not relaid) and set(sl) == set(lp)
+        for name in lp:
+            if name in relaid:
+                assert set(sl[name]) == {"wt"}
+                assert np.array_equal(sl[name]["wt"], lp[name]["w"].T)
+            else:
+                assert sl[name] is lp[name], (kind, name)
+    jax.tree.map(np.testing.assert_array_equal, params, before)
+    rest = tuple(k for k in hybrid.MIXERS if k not in ("lightning", "sparse"))
+    plain = every._replace(layers=len(rest), mixers=rest)
+    tree = hybrid.init_hybrid(plain, 2)
+    assert hybrid.serving_layout(plain, tree) is tree
+    # shapes alone will do
+    shapes = jax.eval_shape(lambda: hybrid.serving_layout(
+        every, jax.tree.map(jnp.asarray, params)))
+    assert jax.tree.map(lambda a: a.shape, shapes) == jax.tree.map(
+        lambda a: a.shape, got)
+
+
+@pytest.mark.parametrize("path", ["contiguous", "kernel", "gather"])
+def test_the_served_layout_is_the_same_product(params, ids, cfg, want, path):
+    """The tree a decoder serves from (``hybrid.serving_layout``) through
+    the full forward, and through chunked prefill and decode under both
+    paged forms, against the caller's tree through the same path (the
+    parent's formulation) and the reference."""
+    served = hybrid.serving_layout(cfg, params)
+    if path == "contiguous":
+        got = program_logits(served, ids, cfg)
+        assert np.abs(got - program_logits(params, ids, cfg)).max() < TOL
+        assert np.abs(got - want).max() < TOL
+        return
+    lens = [100, 50, 77]
+    first, ticks = paged_run(served, ids, cfg, path, lens, 12)
+    first0, ticks0 = paged_run(params, ids, cfg, path, lens, 12)
+    for s, n in enumerate(lens):
+        assert np.abs(first[s] - first0[s]).max() < TOL
+        assert np.abs(first[s] - want[s, n - 1]).max() < TOL
+    for i, ((active, logits), (_, logits0)) in enumerate(zip(ticks, ticks0)):
+        assert np.abs(logits - logits0)[active].max() < TOL, i
+        for s, n in enumerate(lens):
+            if active[s] and not (s == 1 and i >= 3):
+                assert np.abs(logits[s] - want[s, n + i]).max() < TOL, (i, s)
 
 
 @pytest.mark.parametrize("chunk", [8, 32])
@@ -395,8 +457,57 @@ def test_prefix_hit_logits_equal_whole_prefill(params, cfg, document, want,
         drain(dec, [dec.submit(full, 2, prefix_key="d", prefix_len=100)])
     ref = np.asarray(REFERENCE.logits(params, sizes, full, [full.size - 1]))
     assert dec.stats["prefix_hits"] == 1 and len(seen) == 2
+    # the decoder serves the re-laid tree: beside the reference, the
+    # caller's own tree through the full forward
+    whole = program_logits(params, full[None], cfg)[0, -1]
+    assert dec.stats["serving_layout_bytes"] > 0
     for got in seen:
         assert np.abs(got[0] - ref[0]).max() < TOL
+        assert np.abs(got[0] - whole).max() < TOL
+
+
+def _routed_model():
+    cfg = TransformerConfig(
+        vocab=VOCAB, layers=2, d_model=64, heads=4, d_ff=128, max_len=48,
+        causal=True, dtype=jnp.float32, norm="rmsnorm", position="rope",
+        mixers=("kda", "mla"), ffn=("dense", "moe"), head_dim=16,
+        kda=DeltaRule(conv_kernel=3),
+        latent=LatentAttention(latent=32, nope=16, rope=8, value=16),
+        routed=RoutedExperts(experts=8, per_token=2, d_expert=32,
+                             d_shared=32))
+    return hybrid.init_hybrid(cfg, 1), cfg
+
+
+def _dense_model():
+    cfg = TransformerConfig(vocab=VOCAB, layers=1, d_model=32, heads=2,
+                            d_ff=64, causal=True, dtype=jnp.float32)
+    return init_transformer(cfg), cfg
+
+
+@pytest.mark.parametrize("model", ["hybrid", "routed", "dense"])
+def test_a_decoder_counts_what_it_holds_in_another_layout(params, cfg, model):
+    """``stats["serving_layout_bytes"]``: the re-laid leaves' bytes (float32
+    here: a sparse layer's q 64 x 64 and k, v 64 x 32, a lightning layer's
+    three 64 x 64, two layers each), held INSTEAD of the caller's, whose
+    tree stays as it was handed in; 0 where no kind declares a layout."""
+    tree, c = {"hybrid": lambda: (params, cfg), "routed": _routed_model,
+               "dense": _dense_model}[model]()
+    leaves, shape = jax.tree.flatten(tree)
+    dec = ContinuousDecoder(tree, c, max_slots=2, max_len=48, page_size=8,
+                            prefill_chunk=16)
+    after, shape_after = jax.tree.flatten(tree)
+    assert shape_after == shape
+    assert all(a is b for a, b in zip(after, leaves, strict=True))
+    if model != "hybrid":
+        assert dec.stats["serving_layout_bytes"] == 0
+        return
+    relaid = [lp[name]["w"] for lp in tree["layers"] for name in "qkv"]
+    assert dec.stats["serving_layout_bytes"] \
+        == sum(w.nbytes for w in relaid) \
+        == 2 * 4 * (64 * 64 + 2 * 64 * 32) + 2 * 4 * 3 * 64 * 64
+    held = [lp[name] for lp in dec._params["layers"] for name in "qkv"]
+    assert [set(p) for p in held] == [{"wt"}] * len(held)
+    assert [p["wt"].shape for p in held] == [w.shape[::-1] for w in relaid]
 
 
 def test_a_shorter_prefix_len_is_refused_alone(decoder, document):
