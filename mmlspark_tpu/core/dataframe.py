@@ -34,6 +34,7 @@ from typing import Callable, Dict, Iterable, Iterator, List, Optional, Sequence,
 
 import numpy as np
 
+from ..observability import counter as _counter
 from ..observability.tracing import propagate as _propagate
 from ..observability.tracing import span as _span
 from .residency import DeviceColumn, HostMirror, is_device_array, record_hit
@@ -484,6 +485,13 @@ class DataFrame:
         ``map_partitions`` issued from inside a pool worker runs
         sequentially instead of queueing on its own pool, which could
         deadlock.
+
+        The partitions are views of this frame's columns and the results go
+        through :func:`concat`, which joins parts that lie in order in one
+        buffer as a view: a column of the result **may share memory** with
+        this frame's column (one ``fn`` passed through) or be the one array
+        the partitions' results are row ranges of. ``fn`` must not write
+        into a column it was handed; a stage that does copies it first.
         """
         parts = list(self.partitions())
         if max_workers is None:
@@ -509,8 +517,11 @@ class DataFrame:
             # partition call so spans recorded there stay attributable
             results = list(ex.map(_propagate(wrapped), parts,
                                   range(len(parts))))
-        with _span("frame.concat", parts=len(results)):
-            out = concat(results, npartitions=self._npartitions)
+        with _span("frame.concat", parts=len(results)) as sp:
+            out, tally = _concat(results, npartitions=self._npartitions)
+            if sp is not None:
+                sp.set(bytes_copied=tally["copied"],
+                       bytes_viewed=tally["viewed"])
         # per-partition result sizes become the output boundaries, so uneven
         # splits (parquet row groups) survive a map_partitions round
         if len(results) > 1:
@@ -534,10 +545,82 @@ class DataFrame:
                 f"{self._npartitions} partitions: {self.schema()})")
 
 
+M_CONCAT_BYTES = _counter(
+    "mmlspark_frame_concat_bytes_total",
+    "bytes of host columns that concat joined, by how: copied into a new "
+    "array, or viewed (the parts lay in order in one buffer)", ("how",))
+
+
+def _covering_view(parts: Sequence[np.ndarray]) -> Optional[np.ndarray]:
+    """The rows of ``parts`` as ONE view, where they already lie in order in
+    one buffer: C-contiguous row ranges of the same array (same dtype, same
+    row shape), each beginning where the one before ends, as
+    ``DataFrame.partitions`` hands them out. None for anything else."""
+    first = parts[0]
+    if first.dtype.hasobject or any(
+            p.dtype != first.dtype or p.shape[1:] != first.shape[1:]
+            or not p.flags.c_contiguous for p in parts):
+        return None
+    rows = [p for p in parts if len(p)]     # an empty part lies anywhere
+    if len(rows) <= 1:
+        return rows[0] if rows else first
+    at, root = None, _root(rows[0])
+    for p in rows:
+        start = p.__array_interface__["data"][0]
+        # one allocation, not two that happen to touch
+        if (at is not None and start != at) or _root(p) is not root:
+            return None
+        at = start + p.nbytes
+    return np.lib.stride_tricks.as_strided(
+        rows[0], shape=(sum(len(p) for p in rows),) + first.shape[1:],
+        strides=(rows[0].nbytes // len(rows[0]),) + rows[0].strides[1:],
+        writeable=rows[0].flags.writeable)
+
+
+def _root(arr: np.ndarray) -> np.ndarray:
+    """The array ``arr`` is a view of, through every view between."""
+    while isinstance(arr.base, np.ndarray):
+        arr = arr.base
+    return arr
+
+
+def _join(parts: Sequence[np.ndarray], tally: Dict[str, int]) -> np.ndarray:
+    """One host column from its parts: the view that covers them, or
+    ``np.concatenate``'s copy; ``tally`` counts the bytes of either."""
+    out = _covering_view(parts)
+    how = "viewed"
+    if out is None:
+        # np.concatenate promotes mixed parts to object dtype on its own
+        out, how = np.concatenate(parts), "copied"
+    tally[how] += out.nbytes
+    return out
+
+
 def concat(dfs: Sequence[DataFrame], npartitions: Optional[int] = None) -> DataFrame:
+    """Join frames by rows.
+
+    A host column whose parts are, in order, adjacent row ranges of one
+    C-contiguous array (what ``partitions`` hands out, and what a stage
+    returns that wrote its partitions' rows into one shared array) is
+    returned as the view that covers them: **the result may share memory
+    with the inputs**, so a caller that writes into a column in place copies
+    it first. Anything else (a gap, another order, another buffer, an object
+    column, a device-born part) is copied. A frame without rows adds none
+    and is passed over, unless every frame is empty.
+    """
+    return _concat(dfs, npartitions)[0]
+
+
+def _concat(dfs, npartitions=None):
+    """``concat`` and its ``{"copied": bytes, "viewed": bytes}``."""
+    tally = {"copied": 0, "viewed": 0}
     dfs = [d for d in dfs if len(d.columns) > 0 or len(d) > 0]
     if not dfs:
-        return DataFrame({})
+        return DataFrame({}), tally
+    md = {}
+    for d in dfs:
+        md.update(d._metadata)
+    dfs = [d for d in dfs if len(d)] or dfs
     names = dfs[0].columns
     for d in dfs[1:]:
         if d.columns != names:
@@ -549,16 +632,15 @@ def concat(dfs: Sequence[DataFrame], npartitions: Optional[int] = None) -> DataF
             dev[n] = DeviceColumn.concatenate([d._device[n] for d in dfs])
             hosts = [d._columns[n] for d in dfs]
             if all(isinstance(h, np.ndarray) for h in hosts):
-                cols[n] = np.concatenate(hosts)  # host views are free
+                cols[n] = _join(hosts, tally)
             else:
                 cols[n] = None  # lazy mirror of the combined column
             continue
-        # np.concatenate promotes mixed parts to object dtype on its own;
         # d[n] materializes any mirrors (counted) — concat off-device is a
         # genuine host exit for device-born parts
-        cols[n] = np.concatenate([d[n] for d in dfs])
-    md = {}
-    for d in dfs:
-        md.update(d._metadata)
+        cols[n] = _join([d[n] for d in dfs], tally)
+    for how, nbytes in tally.items():
+        if nbytes:
+            M_CONCAT_BYTES.inc(nbytes, how=how)
     return DataFrame(cols, npartitions or dfs[0].npartitions, md,
-                     device_columns=dev)
+                     device_columns=dev), tally

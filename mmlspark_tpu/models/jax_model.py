@@ -19,6 +19,7 @@ by import path when it is a module-level function — the moral of
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Callable, Dict, List, Optional
 
@@ -31,7 +32,7 @@ from ..core.params import ComplexParam, Param
 from ..core.pipeline import Model
 from ..ops.compile_cache import StageCounters, warm_up_model
 from ..parallel.mesh import feed_placement
-from .runner import BatchRunner, StagingSlabPool
+from .runner import BatchRunner, FrameOutputs, StagingSlabPool, collect
 
 __all__ = ["JaxModel"]
 
@@ -240,10 +241,12 @@ class JaxModel(Model):
                   else self._params_for_device(placement.device))
         return placement, params
 
-    def _run_batches(self, part: DataFrame, pidx: int) -> DataFrame:
+    def _run_batches(self, part: DataFrame, pidx: int,
+                     outputs: FrameOutputs) -> DataFrame:
         """One partition through the shared feed/drain pipeline (see
         :class:`~mmlspark_tpu.models.runner.BatchRunner` — prefetch, async
-        h2d, overlapped d2h drain; the same machinery as ONNXModel)."""
+        h2d, overlapped d2h drain; the same machinery as ONNXModel), its
+        rows written to their place in the frame's ``outputs``."""
         jitted = self._ensure_jitted()
         feed = dict(self.feed_dict) or {"input": part.columns[0]}
         placement, params = self._placement_params(pidx)
@@ -272,19 +275,8 @@ class JaxModel(Model):
                              buckets=ladder,
                              model_sig=self.tuning_signature(),
                              placement_key=str(placement.key))
-        pending = runner.run_and_drain(len(part))
-
-        if not pending:
-            return part
-        out_cols = list(pending[0][0])
-        out = part
-        for col_name in out_cols:
-            chunks = [outs[col_name][:b] for outs, b in pending]
-            arr = np.concatenate(chunks)
-            if arr.dtype == jnp.bfloat16:
-                arr = arr.astype(np.float32)
-            out = out.with_column(col_name, arr)
-        return out
+        return part.with_columns(
+            collect(runner.drain_each(runner.run(len(part))), outputs, pidx))
 
     # -- AOT warm-up ---------------------------------------------------------
     def warm_up(self, input_specs: Dict[str, tuple],
@@ -312,7 +304,8 @@ class JaxModel(Model):
 
     def _transform(self, df: DataFrame) -> DataFrame:
         self._ensure_jitted()
-        return df.map_partitions(self._run_batches)
+        return df.map_partitions(functools.partial(
+            self._run_batches, outputs=FrameOutputs(df.partition_bounds())))
 
     # -- persistence --------------------------------------------------------
     def _load_extra(self, path: str) -> None:

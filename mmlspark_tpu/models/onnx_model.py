@@ -18,6 +18,7 @@ are padded to power-of-two buckets so the jit cache stays small
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Dict, List, Optional
 
@@ -34,7 +35,7 @@ from ..ops.compile_cache import (StageCounters, resolve_input_specs,
 from ..core.residency import DeviceColumn
 from ..observability import tracing as _tracing
 from ..parallel.mesh import feed_placement, local_devices
-from .runner import BatchRunner, StagingSlabPool
+from .runner import BatchRunner, FrameOutputs, StagingSlabPool, collect
 
 __all__ = ["ONNXModel"]
 
@@ -457,13 +458,15 @@ class ONNXModel(Model):
                   else self._params_for_device(placement.device))
         return placement, params
 
-    def _run_batches(self, part: DataFrame, pidx: int) -> DataFrame:
-        """One partition through the shared feed/drain pipeline.
+    def _run_batches(self, part: DataFrame, pidx: int,
+                     outputs: FrameOutputs) -> DataFrame:
+        """One partition through the shared feed/drain pipeline, its rows
+        written to their place in the frame's ``outputs``.
 
         :class:`BatchRunner` overlaps all three host boundaries: coerce/pad
         of batch k+1 on a prefetch worker, async host→device puts at
-        dispatch, ``copy_to_host_async`` per batch with ONE batched
-        ``jax.device_get`` at partition end (the reference's per-batch
+        dispatch, ``copy_to_host_async`` per batch, each batch written to
+        its place as its fetch lands (the reference's per-batch
         ``session.run`` + NIO-buffer marshalling, ``ONNXModel.scala:305-402``,
         is fully synchronous — this pipelining is the TPU-side throughput
         win).
@@ -508,10 +511,10 @@ class ONNXModel(Model):
                              buckets=ladder,
                              model_sig=self.tuning_signature(),
                              placement_key=str(placement.key))
+        pending = runner.run(len(part))
         if self.output_device:
             # keep outputs resident: no drain — the sink (DataFrame.to_host
             # or a downstream device stage) decides when to cross back
-            pending = runner.run(len(part))
             out = part
             for col_name in self._out_col_names:
                 chunks = [outs[col_name][:b] for outs, b in pending if b]
@@ -520,22 +523,16 @@ class ONNXModel(Model):
                 out = out.with_device_column(
                     col_name, DeviceColumn.from_device(chunks))
             return out
-        pending = runner.run_and_drain(len(part))
 
-        out = part
-        # slice off the padding, join the batches, widen bf16: host work on
-        # the partition's thread while the device has nothing of its own
+        # each batch as its fetch lands: the padding cut, bf16 widened, to
+        # the rows' place in the frame's arrays, under the device's work on
+        # the batches behind it
         with _tracing.span("onnx.collect", batches=len(pending)):
-            for col_name in self._out_col_names:
-                chunks = [outs[col_name][:b] for outs, b in pending]
-                arr = np.concatenate(chunks) if chunks \
-                    else np.zeros((0,), dtype=np.float32)
-                if arr.dtype == jnp.bfloat16:
-                    arr = arr.astype(np.float32)
-                if col_name in self._argmax_cols:
-                    arr = arr.astype(np.int64)
-                out = out.with_column(col_name, arr)
-        return out
+            cols = collect(runner.drain_each(pending), outputs, pidx,
+                           self._out_col_names)
+        return part.with_columns(
+            cols or {col_name: np.zeros((0,), dtype=np.float32)
+                     for col_name in self._out_col_names})
 
     # -- AOT warm-up ---------------------------------------------------------
     def warm_up(self, batch_sizes: Optional[List[int]] = None,
@@ -579,7 +576,11 @@ class ONNXModel(Model):
     def _transform(self, df: DataFrame) -> DataFrame:
         self._ensure_converted()
         self._ensure_jitted()
-        out = df.map_partitions(self._run_batches)
+        outputs = FrameOutputs(
+            df.partition_bounds(),
+            {col_name: np.int64 for col_name in self._argmax_cols})
+        out = df.map_partitions(
+            functools.partial(self._run_batches, outputs=outputs))
         # host fallback for post-ops whose source column does not come out of
         # the jitted graph (parity: softMaxTransform/argMaxTransform :519-562)
         for out_col, src_col in self.softmax_dict.items():

@@ -15,9 +15,13 @@ out:
 * **async feed** — host→device transfers enqueue immediately at dispatch
   time, overlapping the previous batch's compute;
 * **overlapped drain** — ``copy_to_host_async()`` is issued per output the
-  moment a batch is dispatched, so device→host transfers overlap compute,
-  and the partition-end drain is ONE batched ``jax.device_get`` over every
-  pending output instead of a per-batch-per-column ``np.asarray`` loop.
+  moment a batch is dispatched, so device→host transfers overlap compute.
+  The model stages take the batches in order as each one's fetch lands
+  (:meth:`BatchRunner.drain_each`) and write its rows ONCE, to their place
+  in the frame's one array a column (:class:`FrameOutputs`, :func:`collect`),
+  under the device's work on the batches behind it; ``drain`` is the one
+  batched ``jax.device_get`` over every pending output, for a caller that
+  wants them all.
 
 Every stage is instrumented through :class:`~mmlspark_tpu.ops.compile_cache.
 StageCounters` (coerce / pad / h2d / compile / dispatch / d2h), cheap enough
@@ -30,7 +34,8 @@ import threading
 
 from ..reliability.lock_sanitizer import new_lock
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import (Callable, Dict, Iterable, Iterator, List, Optional,
+                    Sequence, Tuple)
 
 import jax
 import numpy as np
@@ -46,7 +51,9 @@ from ..ops.compile_cache import (M_CACHE_HITS, M_CACHE_MISSES,
 from ..ops.padding import bucket_size, pad_axis, pad_axis_device
 from ..stages.batching import PrefetchIterator, batch_slices
 
-__all__ = ["BatchRunner", "StagingSlabPool"]
+__all__ = ["BatchRunner", "FrameOutputs", "StagingSlabPool", "collect"]
+
+_BFLOAT16 = np.dtype(jax.numpy.bfloat16)
 
 M_SLAB_ALLOCS = _metric_counter(
     "mmlspark_staging_slab_allocs_total",
@@ -112,6 +119,75 @@ class StagingSlabPool:
             total = self.allocs + self.reuses
             return {"allocs": self.allocs, "reuses": self.reuses,
                     "reuse_rate": (self.reuses / total) if total else None}
+
+
+class FrameOutputs:
+    """One host array a column for a whole frame's pass.
+
+    The partitions of one ``transform`` share it: each writes its batches'
+    rows to its own row range as they come off the device, and returns the
+    view of that range. The views of consecutive partitions are adjacent
+    slices of one buffer, which ``concat`` recognises and joins without a
+    copy, so an output row is copied on the host once. A column's array is
+    allocated when its first batch tells the row shape (``np.empty``: a page
+    costs nothing until the partition that owns it writes it). Rows come out
+    in ``dtypes[name]`` where the stage names one, bfloat16 widened to
+    float32, anything else as the device returned it.
+    """
+
+    def __init__(self, bounds: Sequence[Tuple[int, int]],
+                 dtypes: Optional[Dict[str, type]] = None):
+        #: ``DataFrame.partition_bounds()``: partition ``i`` owns rows
+        #: ``bounds[i][0]`` up to ``bounds[i][1]``
+        self.bounds = list(bounds)
+        self.nrows = self.bounds[-1][1] if self.bounds else 0
+        self._dtypes = dict(dtypes or {})
+        self._lock = new_lock("models.runner.FrameOutputs._lock")
+        self._columns: Dict[str, np.ndarray] = {}
+
+    def _column(self, name: str, chunk: np.ndarray) -> np.ndarray:
+        buf = self._columns.get(name)
+        if buf is None:
+            with self._lock:
+                buf = self._columns.get(name)
+                if buf is None:
+                    dtype = self._dtypes.get(name) or (
+                        np.float32 if chunk.dtype == _BFLOAT16
+                        else chunk.dtype)
+                    buf = self._columns[name] = np.empty(
+                        (self.nrows,) + chunk.shape[1:], dtype)
+        if buf.shape[1:] != chunk.shape[1:]:
+            raise ValueError(
+                f"output {name!r}: a batch of row shape {chunk.shape[1:]} "
+                f"after one of {buf.shape[1:]}")
+        return buf
+
+    def write(self, name: str, at: int, chunk: np.ndarray) -> None:
+        """``chunk``'s rows to rows ``at`` onward: the cast and the copy in
+        one pass over the bytes."""
+        buf = self._column(name, chunk)
+        np.copyto(buf[at:at + len(chunk)], chunk, casting="unsafe")
+
+    def rows(self, names: Iterable[str], lo: int, hi: int
+             ) -> Dict[str, np.ndarray]:
+        return {name: self._columns[name][lo:hi] for name in names}
+
+
+def collect(batches: Iterable[Tuple[Dict[str, np.ndarray], int]],
+            outputs: FrameOutputs, pidx: int,
+            names: Optional[Sequence[str]] = None) -> Dict[str, np.ndarray]:
+    """Partition ``pidx``'s drained ``batches`` (``[(host outputs, valid
+    rows)]``) into its rows of ``outputs``, the padding cut off; returns the
+    partition's view of each column (``names``, or every output; none for
+    a partition without rows)."""
+    at = lo = outputs.bounds[pidx][0]
+    for outs, b in batches:
+        if names is None:
+            names = list(outs)
+        for name in names:
+            outputs.write(name, at, outs[name][:b])
+        at += b
+    return outputs.rows(names, lo, at) if at > lo else {}
 
 
 class BatchRunner:
@@ -369,8 +445,40 @@ class BatchRunner:
         with _tracing.span("runner.d2h", batches=len(pending)), \
                 _watch("runner_drain"):
             host = jax.device_get([outs for outs, _ in pending])
-        elapsed = time.perf_counter() - t0
-        nbytes = sum(a.nbytes for outs in host for a in outs.values())
+        self._drained(time.perf_counter() - t0,
+                      sum(a.nbytes for outs in host for a in outs.values()))
+        return [(outs, b) for outs, (_, b) in zip(host, pending)]
+
+    def drain_each(self, pending: List[Tuple[dict, int]]
+                   ) -> Iterator[Tuple[Dict[str, np.ndarray], int]]:
+        """``drain`` a batch at a time, in order: each batch's host outputs
+        as soon as ITS fetch (started at dispatch) has landed, so what the
+        caller does with batch k runs under the device's work on the batches
+        behind it and only the last batch's remains when the device is
+        done. Counted as ``drain`` is: one ``d2h`` a partition, the seconds
+        this thread waited for the device."""
+        waited, nbytes = 0.0, 0
+        try:
+            for outs, b in pending:
+                t0 = time.perf_counter()
+                with _tracing.span("runner.d2h", batches=1), \
+                        _watch("runner_drain"):
+                    # tpulint: disable=TPU001 — the point of the loop: every
+                    # batch is dispatched already and its copy under way
+                    # (copy_to_host_async), so a fetch waits for ITS batch
+                    # alone and the host's work on it runs under the device's
+                    # on the batches behind it
+                    host = jax.device_get(outs)
+                waited += time.perf_counter() - t0
+                nbytes += sum(a.nbytes for a in host.values())
+                yield host, b
+        finally:
+            if pending:
+                self._drained(waited, nbytes)
+            else:
+                self._flush_samples()
+
+    def _drained(self, elapsed: float, nbytes: int) -> None:
         self.counters.add("d2h", elapsed, nbytes)
         # async dispatch settles inside device_get, so the drain wall time
         # IS device time — ledger device_seconds reconciles with the
@@ -384,7 +492,6 @@ class BatchRunner:
         for s in self._samples.values():
             s["seconds"] += elapsed * (s["rows"] / total_rows)
         self._flush_samples()
-        return [(outs, b) for outs, (_, b) in zip(host, pending)]
 
     def run_and_drain(self, n_rows: int
                       ) -> List[Tuple[Dict[str, np.ndarray], int]]:

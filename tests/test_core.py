@@ -168,6 +168,111 @@ class TestDataFrame:
         assert list(X[1]) == [2.0, 5.0, 6.0]
 
 
+def _concat_bytes():
+    from mmlspark_tpu.core.dataframe import M_CONCAT_BYTES
+    return {how: M_CONCAT_BYTES.labels(how=how).get()
+            for how in ("copied", "viewed")}
+
+
+_BASE = np.arange(48, dtype=np.float32).reshape(12, 4)
+_OBJECTS = np.array([str(i) for i in range(12)], dtype=object)
+
+#: (case, parts, whether the result may be a view of ``_BASE``)
+CONCAT_PARTS = [
+    ("adjacent", [_BASE[0:3], _BASE[3:8], _BASE[8:12]], True),
+    ("adjacent_inside", [_BASE[2:5], _BASE[5:9]], True),
+    ("adjacent_1d", [_BASE.reshape(-1)[0:10], _BASE.reshape(-1)[10:48]],
+     True),
+    ("views_of_a_view", [_BASE[2:][0:3], _BASE[2:][3:7]], True),
+    ("empty_in_the_middle", [_BASE[0:3], _BASE[3:3], _BASE[3:12]], True),
+    ("single", [_BASE[2:9]], True),
+    ("single_strided", [_BASE[::2]], False),
+    ("gap", [_BASE[0:3], _BASE[4:8]], False),
+    ("overlap", [_BASE[0:4], _BASE[3:8]], False),
+    ("swapped", [_BASE[3:8], _BASE[0:3]], False),
+    ("two_bases", [_BASE[0:3], _BASE.copy()[3:8]], False),
+    ("strided_rows", [_BASE[0:6:2], _BASE[6:12:2]], False),
+    ("column_sliced", [_BASE[0:3, :2], _BASE[3:8, :2]], False),
+    ("mixed_dtypes", [_BASE[0:3], _BASE[3:8].astype(np.float64)], False),
+    ("object_column", [_OBJECTS[0:5], _OBJECTS[5:12]], False),
+    ("all_empty", [_BASE[0:0], _BASE[5:5]], False),
+]
+
+
+class TestConcatViews:
+    """``concat`` returns the covering view of parts that lie in order in
+    one buffer and ``np.concatenate``'s copy of anything else."""
+
+    @pytest.mark.parametrize("case,parts,viewed", CONCAT_PARTS,
+                             ids=[c[0] for c in CONCAT_PARTS])
+    def test_rule(self, case, parts, viewed):
+        before = _concat_bytes()
+        out = concat([DataFrame({"x": p}) for p in parts])["x"]
+        want = np.concatenate(parts)
+        assert out.dtype == want.dtype and out.shape == want.shape
+        assert np.array_equal(out, want)
+        assert out.flags.c_contiguous
+        shares = bool(len(out)) and any(
+            np.shares_memory(out, p) for p in parts)
+        assert shares == viewed
+        moved = {k: v - before[k] for k, v in _concat_bytes().items()}
+        assert moved == {"viewed": want.nbytes * viewed,
+                         "copied": want.nbytes * (not viewed)}
+
+    def test_parts_of_other_row_widths_raise_as_before(self):
+        with pytest.raises(ValueError):
+            concat([DataFrame({"x": _BASE[0:3]}),
+                    DataFrame({"x": _BASE[3:8, :2]})])
+
+    def test_a_view_keeps_the_buffer_alive_and_its_writeability(self):
+        base = np.arange(20.0).reshape(10, 2)
+        base.setflags(write=False)
+        out = concat([DataFrame({"x": base[0:4]}),
+                      DataFrame({"x": base[4:10]})])["x"]
+        want = base.copy()
+        del base
+        assert not out.flags.writeable
+        assert np.array_equal(out, want)
+
+    def test_frames_without_rows_are_passed_over(self):
+        # a partition without rows cannot always know its outputs' shapes
+        full = DataFrame({"x": _BASE[0:3], "y": np.arange(3)})
+        none = DataFrame({"x": np.zeros((0,), np.float32)})
+        out = concat([none, full, none])
+        assert out.columns == ["x", "y"] and len(out) == 3
+        assert np.array_equal(out["x"], _BASE[0:3])
+        assert concat([none, none]).columns == ["x"]
+
+    @pytest.mark.parametrize("sizes", [[5, 0, 4, 3], [1, 11], [12]],
+                             ids=str)
+    def test_uneven_partition_sizes_survive_map_partitions(self, sizes):
+        df = DataFrame({"x": _BASE, "s": _OBJECTS}, partition_sizes=sizes)
+        out = df.map_partitions(lambda p, i: p.with_column(
+            "pid", np.full(len(p), i)))
+        assert [len(p) for p in out.partitions()] == sizes
+        assert np.shares_memory(out["x"], _BASE)      # passed through
+        assert np.array_equal(out["x"], _BASE)
+        assert list(out["s"]) == list(_OBJECTS)
+        assert list(out["pid"]) == [i for i, n in enumerate(sizes)
+                                    for _ in range(n)]
+
+    def test_span_and_counter_add_up_to_the_columns_bytes(self):
+        from mmlspark_tpu.observability import tracing as tr
+        df = DataFrame({"x": _BASE, "s": _OBJECTS}, npartitions=3)
+        before = _concat_bytes()
+        root = tr.start_trace("frame")
+        with tr.activate(root):
+            out = df.map_partitions(lambda p, i: p.with_column(
+                "y", p["x"] * 2))
+        root.end()
+        span, = [s for s in root.trace.spans if s.name == "frame.concat"]
+        moved = {k: v - before[k] for k, v in _concat_bytes().items()}
+        assert span.attrs["bytes_viewed"] == moved["viewed"] == _BASE.nbytes
+        assert span.attrs["bytes_copied"] == moved["copied"] \
+            == out["y"].nbytes + _OBJECTS.nbytes
+        assert span.attrs["parts"] == 3
+
+
 class TestPipeline:
     def test_fit_transform(self):
         df = DataFrame({"x": [1.0, 2.0, 3.0]})
